@@ -156,7 +156,7 @@ def main():
         entry = {
             "ts_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
             "platform": jax.default_backend(),
-            # Provenance labels (VERDICT r04 item 5): a virtual CPU mesh
+            # Provenance labels: a virtual CPU mesh
             # oversubscribes host cores, so its efficiency curve is a plumbing
             # canary, NOT scaling evidence; the north-star number is this same
             # sweep on a real multi-chip slice.

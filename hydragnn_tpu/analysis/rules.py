@@ -245,7 +245,7 @@ TRANSFORM_ENTRY_POINTS = frozenset(
         "jax.lax.switch",
         "lax.switch",
         "shard_map",
-        "jax.experimental.shard_map.shard_map",
+        "jax.shard_map",
         "pl.pallas_call",
         "pallas_call",
     }

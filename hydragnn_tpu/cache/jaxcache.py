@@ -1,0 +1,41 @@
+"""Where JAX's own persistent compilation cache lives (docs/COMPILE_CACHE.md).
+
+This is XLA's cache, keyed and read by JAX itself — a different store from
+graftcache (store.py / registry.py), which keeps whole executables under keys
+this package computes. Every entry point (run_training, run_prediction, the
+serve and route CLIs, bench.py, chip_smoke.py) calls :func:`place_jax_cache`
+before its first compile, so the rule lives in one place:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads it itself; the program sets
+  no cache directory in code.
+* unset — the cache goes to ONE fixed path inside the checkout. The path is
+  part of nothing's key but a directory that moves never hits, so it is not
+  derived from a run name, a store directory or a temporary directory.
+
+JAX decides once per process, at its first compile, whether the cache is in
+use: a call after that is too late to matter.
+"""
+
+from __future__ import annotations
+
+import os
+
+JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+
+def place_jax_cache() -> str:
+    """Put JAX's persistent compilation cache where the rule above says and
+    return the directory in effect. Idempotent; touches no backend."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    if jax.config.jax_compilation_cache_dir != JAX_CACHE_DIR:
+        jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR)
+    return JAX_CACHE_DIR

@@ -35,7 +35,6 @@ from .store import (
     CacheKey,
     ExecutableStore,
     deserialize_compiled,
-    enable_xla_fallback_cache,
     serialize_compiled,
 )
 
@@ -147,9 +146,10 @@ class ExecutableRegistry:
             return None
         sections, exe_format = got
         if exe_format != "pjrt":
-            # StableHLO-only entry: the XLA fallback cache (enabled when the
-            # entry was written) absorbs the compile wall; the entry itself
-            # exists for diagnostics and ls/verify. Treat as a miss here.
+            # StableHLO-only entry: JAX's own persistent cache (placed by
+            # cache/jaxcache.py at the entry point) absorbs the compile wall;
+            # the entry itself exists for diagnostics and ls/verify. Treat
+            # as a miss here.
             return None
         try:
             return deserialize_compiled(sections)
@@ -162,9 +162,11 @@ class ExecutableRegistry:
 
     def _persist(self, key: CacheKey, compiled: Any, lowered: Any = None) -> None:
         """Serialize + install one freshly compiled executable; on backends
-        without executable serialization, persist the StableHLO lowering and
-        enable JAX's built-in compilation cache instead. Store failures are
-        warnings — a full disk must not fail the train/serve path."""
+        without executable serialization, persist the StableHLO lowering
+        only — JAX's own persistent cache stays wherever the entry point put
+        it (cache/jaxcache.py; never re-pointed under the store). Store
+        failures are warnings — a full disk must not fail the train/serve
+        path."""
         assert self._store is not None
         t0 = time.perf_counter()
         try:
@@ -179,12 +181,11 @@ class ExecutableRegistry:
                     warnings.warn(
                         f"graftcache[{self.name}]: backend "
                         f"{key.backend!r} cannot serialize executables; "
-                        "persisting StableHLO and enabling JAX's built-in "
-                        "compilation_cache_dir fallback",
+                        "persisting StableHLO only — warm starts rely on "
+                        "JAX's own persistent compilation cache",
                         RuntimeWarning,
                         stacklevel=3,
                     )
-                enable_xla_fallback_cache(self._store.cache_dir)
                 hlo = _lowering_text(lowered if lowered is not None else compiled)
                 if hlo is not None:
                     self._store.put(

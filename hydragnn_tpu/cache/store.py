@@ -9,7 +9,8 @@ persists. A store entry is::
 
     <cache_dir>/<key-digest>.hexe       # v2 container:
         header:   {"kind": "graftcache-exe/v1", "exe_format": ..., "key": {...}}
-        sections: {"executable": <bytes>, "trees": <pickled treedefs>}
+        sections: {"executable": <bytes>, "trees": <pickled treedefs>,
+                   "devices": <json ids the program was compiled for>}
     <cache_dir>/manifest.json           # advisory index (ls/gc); lookups go
                                         # by key digest, so a lost manifest
                                         # update can never serve a wrong entry
@@ -18,9 +19,9 @@ persists. A store entry is::
 payload — deserialization fires NO XLA compile event, so the recompile
 sentinel and the telemetry ``jax/compiles`` counters stay truthful) or
 ``"stablehlo"`` (the lowering text, persisted where the backend cannot
-serialize executables; hydration then recompiles from StableHLO while JAX's
-built-in ``compilation_cache_dir`` — enabled under ``<cache_dir>/xla/`` —
-absorbs the XLA wall).
+serialize executables; hydration then recompiles while JAX's own persistent
+compilation cache — placed by cache/jaxcache.py at every entry point, never
+under ``<cache_dir>`` — absorbs the XLA wall).
 
 Corruption policy: a damaged entry (bad magic, torn container, digest
 mismatch, undecodable trees) is LOUD — ``FaultCounters['exec_cache_corrupt']``
@@ -468,50 +469,62 @@ def serialize_compiled(compiled: Any) -> Optional[Dict[str, bytes]]:
     Treedefs ride along pickled — custom pytree nodes (GraphBatch,
     TrainState, optax states) unpickle against the SAME registered types, so
     hydration must happen after the defining modules imported (they have:
-    the engine/trainer import them before any lookup)."""
+    the engine/trainer import them before any lookup). ``devices`` records
+    the ids of the devices the program was compiled for, in assignment
+    order — hydration loads the executable onto exactly those."""
     try:
         from jax.experimental import serialize_executable as se
 
         payload, in_tree, out_tree = se.serialize(compiled)
+        device_ids = [
+            int(d.id)
+            for d in compiled._executable._unloaded_executable.device_list
+        ]
         return {
             "executable": payload,
             "trees": pickle.dumps((in_tree, out_tree)),
+            "devices": json.dumps(device_ids).encode(),
         }
     except Exception:  # noqa: BLE001 — backend capability probe, not an error
         return None
 
 
 def deserialize_compiled(sections: Dict[str, bytes]) -> Any:
-    """Store sections → loaded executable. Raises :class:`CacheEntryError`
-    on any decode failure (the registry turns that into quarantine + fresh
-    compile). Deserialization fires NO XLA compile monitoring event — the
-    sentinel-truthfulness property tests/test_compile_cache.py pins."""
+    """Store sections → loaded executable, on the devices it was compiled
+    for (one chip for a single-device program, the mesh's devices for a mesh
+    program — left to its default, ``deserialize_and_load`` would load it
+    onto EVERY visible device and the first call would ask for one shard per
+    device; seen on the four-chip host, PR 21). Raises
+    :class:`CacheEntryError` on any decode failure and for a single-device
+    program compiled for a non-default device (the registry turns that into
+    quarantine + fresh compile). Deserialization
+    fires NO XLA compile monitoring event — the sentinel-truthfulness
+    property tests/test_compile_cache.py pins."""
+    import jax
     from jax.experimental import serialize_executable as se
 
     try:
         # graftlint: disable=pickle-load-outside-compat(pytree defs inside a GSHD cache container whose digest was verified before this call — no untrusted bytes reach the unpickler)
         in_tree, out_tree = pickle.loads(sections["trees"])
+        device_ids = json.loads(sections["devices"])
+        if len(device_ids) == 1 and device_ids[0] != jax.devices()[0].id:
+            # A single-device program is only handed back where the runtime
+            # puts it: on the TPU (jaxlib 0.9, four chips, PR 21) one
+            # compiled for chip 3 reports chip 3 after loading and dies at
+            # its first call, "replica is assigned to device TPU_0". Refuse
+            # it here, so the caller compiles fresh instead.
+            raise ValueError(
+                f"single-device program compiled for device {device_ids[0]}, "
+                f"not the default device {jax.devices()[0].id}"
+            )
+        by_id = {d.id: d for d in jax.devices()}
         return se.deserialize_and_load(
-            sections["executable"], in_tree, out_tree
+            sections["executable"],
+            in_tree,
+            out_tree,
+            execution_devices=[by_id[i] for i in device_ids],
         )
     except Exception as e:  # noqa: BLE001 — one failure class for callers
         raise CacheEntryError(
             f"executable deserialization failed ({type(e).__name__}: {e})"
         ) from e
-
-
-def enable_xla_fallback_cache(cache_dir: str) -> None:
-    """Point JAX's built-in persistent compilation cache at
-    ``<cache_dir>/xla`` — the warm-compile path on backends where executable
-    serialization is unavailable (entries then persist the lowering only).
-    Idempotent; thresholds dropped to zero so small programs cache too."""
-    import jax
-
-    xla_dir = os.path.join(cache_dir, "xla")
-    os.makedirs(xla_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", xla_dir)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 — knob names drift across jax versions
-        pass

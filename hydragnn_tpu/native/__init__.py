@@ -6,9 +6,12 @@ utils.py:51-123). Here the equivalent is a small C++ cell-list library,
 compiled on first use with the system toolchain (no pybind11 in the image —
 plain C ABI + ctypes keeps the build to one g++ invocation).
 
-``available()`` is False when compilation fails (or HYDRAGNN_NATIVE=0), and
-callers in preprocess/graph_build.py fall back to the numpy/cKDTree path; both
-paths produce identical edge sets (tests/test_native_neighborlist.py).
+``available()`` is False when compilation fails (with a warning that says
+why) or HYDRAGNN_NATIVE=0, and callers in preprocess/graph_build.py then take
+the numpy/cKDTree path; both paths produce identical edge sets
+(tests/test_native_neighborlist.py). ``rebuild()`` compiles again whatever
+binary is on disk — ``_neighborlist.so`` is untracked, so a copied checkout
+carries the binary of the machine it was copied from.
 """
 
 from __future__ import annotations
@@ -16,6 +19,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -35,7 +39,15 @@ def _compile() -> bool:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         return True
-    except (subprocess.SubprocessError, FileNotFoundError):
+    except (subprocess.SubprocessError, FileNotFoundError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        warnings.warn(
+            f"native neighbour list not built ({type(e).__name__}: "
+            f"{detail.decode(errors='replace')[-300:] or e}); graph "
+            "construction takes the numpy/cKDTree path",
+            RuntimeWarning,
+            stacklevel=3,
+        )
         return False
 
 
@@ -75,6 +87,19 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def rebuild() -> bool:
+    """Compile ``neighborlist.cc`` again and load the result, discarding any
+    ``_neighborlist.so`` already on disk. True when the native path is in
+    use afterwards."""
+    global _lib, _tried
+    _lib, _tried = None, False
+    try:
+        os.remove(_SO)
+    except FileNotFoundError:
+        pass
+    return available()
 
 
 def radius_graph(

@@ -31,12 +31,12 @@ so no dependence on jax_enable_x64. The segment value is recovered as
 is exactly rounded and its accumulated rounding error lives in err.
 Certified against the same f64 ground truth as the Pallas kernel (tests).
 
-OPT-IN (HYDRAGNN_SEGMENT_SORTED=1) until measured on TPU hardware — the
-sorted arm rides along automatically whenever ``certify_pallas`` runs on
-contiguous ids (bench.py each round; benchmarks/tune_kernel.py's first sweep
-arm; benchmarks/hw_watchdog.sh's bench_sorted step measures it in the real
-train step). Convs request it via ``sorted_ids=True`` (+ the batch's
-``row_ptr``) on the fused_* wrappers — since PR 7 that includes GAT, whose
+The default route on the TPU (``sorted_enabled``; HYDRAGNN_SEGMENT_SORTED=1/0
+overrides either way). The sorted arm rides along whenever ``certify_pallas``
+runs on contiguous ids (bench.py, chip_smoke.py's kernels stage,
+benchmarks/tune_kernel.py's first sweep arm). Convs request it via
+``sorted_ids=True`` (+ the batch's ``row_ptr``) on the fused_* wrappers —
+since PR 7 that includes GAT, whose
 self-loops became an explicit self-attention term instead of the
 sort-breaking ``[edges; self-loops]`` concat (models/convs.py:GATv2Conv).
 """

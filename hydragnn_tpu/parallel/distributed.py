@@ -168,6 +168,20 @@ def _local_device_slot():
     return None
 
 
+def tpu_expected() -> bool:
+    """Whether this process will come up on the TPU backend, decided WITHOUT
+    initialising it (a process that has touched the backend holds the chips,
+    and the checks that call this must run before that): the platform list
+    (``JAX_PLATFORMS`` / ``jax_platforms``) names ``tpu``, or names nothing
+    and a TPU chip is attached over PCI — JAX's own probe."""
+    platforms = jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS", "")
+    if platforms:
+        return "tpu" in platforms.split(",")
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0] > 0
+
+
 def _distributed_active() -> bool:
     """Whether jax.distributed.initialize already ran — checked WITHOUT
     touching jax.process_count(), which would initialize the XLA backend and
@@ -189,16 +203,29 @@ def setup_ddp(coordinator_address: Optional[str] = None) -> Tuple[int, int]:
     """
     world_size, world_rank = init_comm_size_and_rank()
     if world_size > 1 and not _distributed_active():
+        kwargs = {}
+        slot = _local_device_slot()
+        if slot is not None:
+            if tpu_expected() and not os.getenv("TPU_VISIBLE_CHIPS"):
+                # local_device_ids restricts CUDA/ROCm devices only. On TPU
+                # every rank would claim every chip of the host: the first to
+                # start takes them and the rest hang.
+                raise RuntimeError(
+                    f"rank {world_rank}: {get_local_size()} processes share "
+                    "this TPU host, but a TPU chip can only be hidden from a "
+                    "process BEFORE JAX starts. Launch one process per host "
+                    "(it drives all local chips through the mesh), or have "
+                    "the launcher set TPU_VISIBLE_CHIPS=<local rank> (with "
+                    "the matching TPU_PROCESS_BOUNDS / "
+                    "TPU_CHIPS_PER_PROCESS_BOUNDS) for each rank."
+                )
+            # Reference 1-rank-per-device placement (distributed.py:181-189):
+            # with several processes per host each claims its own
+            # local-device slot instead of all of them.
+            kwargs["local_device_ids"] = [slot]
         try:
             if coordinator_address is None:
                 coordinator_address = resolve_coordinator_address()
-            kwargs = {}
-            slot = _local_device_slot()
-            if slot is not None:
-                # Reference 1-rank-per-device placement (distributed.py:
-                # 181-189): with several processes per host each claims its
-                # own local-device slot instead of all of them.
-                kwargs["local_device_ids"] = [slot]
             jax.distributed.initialize(
                 coordinator_address=coordinator_address,
                 num_processes=world_size,

@@ -24,6 +24,8 @@ import argparse
 import sys
 from typing import List, Optional
 
+from ..cache.jaxcache import place_jax_cache
+
 
 def parse_classes(spec: str) -> Optional[dict]:
     """``--classes "fast=2.0,ensemble=15.0"`` -> {name: {deadline_s}}."""
@@ -151,6 +153,7 @@ def _build_replicas(args, ladder, replicas, procs) -> None:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    place_jax_cache()
     n_replicas = args.replicas + len(args.replica_url) + args.spawn
     if n_replicas < 1:
         print(
@@ -159,6 +162,22 @@ def main(argv=None) -> int:
             file=sys.stderr,
         )
         return 2
+    if args.spawn:
+        from ..parallel.distributed import tpu_expected
+
+        if tpu_expected():
+            # Checked before anything here touches the backend. A serve child
+            # claims every chip of the host, so a second child (or a child of
+            # a router that built --replicas first) finds them taken.
+            print(
+                "router --spawn cannot place its children on a TPU host: "
+                "each spawned serve process claims every chip. Start each "
+                "`python -m hydragnn_tpu.serve` yourself with its one chip "
+                "made visible before JAX starts (TPU_VISIBLE_CHIPS=<n>) and "
+                "attach it with --replica-url.",
+                file=sys.stderr,
+            )
+            return 2
 
     from ..analysis.contracts import gate_config
     from ..graphs.packing import resolve_ladder_spec

@@ -188,6 +188,7 @@ class InProcessReplica(Replica):
             "queue_depth": engine._queue.qsize(),
             "queue_limit": engine.queue_limit,
             "compiled_buckets": engine.compiled_buckets,
+            "device": engine.device,
             "precision": engine.precision,
             "model_version": engine.model_version,
             "weight_swaps": counters["weight_swaps_total"],

@@ -9,6 +9,7 @@ import json
 import os
 from functools import singledispatch
 
+from .cache.jaxcache import place_jax_cache
 from .models.create import create_model_config, init_model_variables
 from .parallel.distributed import setup_ddp
 from .postprocess.postprocess import output_denormalize
@@ -36,6 +37,7 @@ def _(config_file: str, mesh=None):
 @run_prediction.register
 def _(config: dict, mesh=None):
     os.environ.setdefault("SERIALIZED_DATA_PATH", os.getcwd())
+    place_jax_cache()
     world_size, _rank = setup_ddp()
     # Same static contract gate as run_training, in prediction mode: the
     # epoch-loop Training knobs are not required and only the forward path

@@ -10,8 +10,10 @@ import json
 import os
 from functools import singledispatch
 
+import jax
 import numpy as np
 
+from .cache.jaxcache import place_jax_cache
 from .models.create import create_model_config, init_model_variables
 from .parallel.distributed import barrier, setup_ddp
 from .preprocess.load_data import dataset_loading_and_splitting
@@ -26,7 +28,7 @@ from .utils.model import (
     save_model,
 )
 from .utils.optimizer import ReduceLROnPlateau, select_optimizer
-from .utils.print_utils import print_distributed, setup_log
+from .utils.print_utils import log, print_distributed, setup_log
 from .utils.profile import Profiler
 from .utils.time_utils import print_timers
 
@@ -70,6 +72,7 @@ def _(config: dict, mesh=None, supervise=False, max_restarts=3):
 
         return run_supervised(config, max_restarts=max_restarts)
     os.environ.setdefault("SERIALIZED_DATA_PATH", os.getcwd())
+    place_jax_cache()
 
     # Bootstrap BEFORE anything touches jax (setup_log rank-prefixes via
     # jax.process_index(), which initializes the XLA backend —
@@ -110,9 +113,7 @@ def _(config: dict, mesh=None, supervise=False, max_restarts=3):
                 ),
             )
             if transition is not None:
-                from .utils.print_utils import log as _log
-
-                _log(
+                log(
                     f"elastic restart: world_size "
                     f"{transition['from_world']} -> {transition['to_world']} "
                     f"({transition['kind']}) — loader re-shards and the mesh "
@@ -136,6 +137,20 @@ def _(config: dict, mesh=None, supervise=False, max_restarts=3):
         from .parallel.distributed import make_mesh
 
         mesh = make_mesh(graph_axis=graph_axis)
+    # Say which devices the run holds: one process without a mesh trains on
+    # device 0 alone, however many chips the host has.
+    n_used = mesh.devices.size if mesh is not None else 1
+    n_visible = jax.device_count()
+    dev0 = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    log(
+        f"devices: using {n_used} of {n_visible} "
+        f"({dev0.platform}, {dev0.device_kind})"
+        + (
+            " — pass mesh=make_mesh() to run_training to train on all of them"
+            if n_used < n_visible
+            else ""
+        )
+    )
 
     verbosity = config["Verbosity"]["level"]
     train_loader, val_loader, test_loader, sampler_list = (

@@ -16,6 +16,7 @@ from __future__ import annotations
 import argparse
 import sys
 
+from ..cache.jaxcache import place_jax_cache
 from .engine import InferenceEngine
 from .server import InferenceServer
 
@@ -235,6 +236,7 @@ def batch_main(argv) -> int:
 def main(argv=None) -> int:
     if argv is None:
         argv = sys.argv[1:]
+    place_jax_cache()
     if argv and argv[0] == "router":
         # The front-router subcommand (hydragnn_tpu/route/__main__.py):
         # one CLI surface for both the single engine and the fleet.

@@ -368,6 +368,15 @@ class InferenceEngine:
 
         params = jax.device_put(variables["params"])
         bstats = jax.device_put(variables.get("batch_stats", {}))
+        # Where the weights actually landed (/healthz reports it): the
+        # default device of this process, out of however many it can see.
+        dev = next(iter(jax.tree_util.tree_leaves(params)[0].devices()))
+        self.device = {
+            "platform": dev.platform,
+            "kind": dev.device_kind,
+            "id": dev.id,
+            "visible": jax.device_count(),
+        }
         self._jit = jax.jit(
             lambda params, bstats, batch: _apply_model(
                 model, params, bstats, batch, train=False

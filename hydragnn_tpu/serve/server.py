@@ -202,6 +202,8 @@ class _Handler(RequestPlumbing, BaseHTTPRequestHandler):
                     "queue_depth": engine._queue.qsize(),
                     "queue_limit": engine.queue_limit,
                     "compiled_buckets": engine.compiled_buckets,
+                    # The device the weights live on, as JAX reports it.
+                    "device": engine.device,
                     # Serving arm (docs/PRECISION.md): operators must see at
                     # a glance whether this replica answers under the
                     # bit-exactness contract or a tolerance gate.

@@ -566,8 +566,6 @@ def make_train_step_dp(
     segment completes (``grad_bucket_mb`` sizes the buckets), overlapping
     all-reduce with backward compute. ``loss_scaling`` arms the bf16 dynamic
     loss-scale state machine with the backoff update in lockstep post-psum."""
-    from jax.experimental.shard_map import shard_map
-
     from ..parallel.overlap import resolve_grad_sync
     from ..utils.optimizer import ValueFnTransformation
 
@@ -655,20 +653,18 @@ def _wrap_dp_step(local, mesh, graph_sharded: bool, donate: bool):
     """shard_map + jit wrapper shared by every DP train-step arm (one
     definition so the graftmesh arms and the historical body can never
     diverge in specs/donation/platform pinning)."""
-    from jax.experimental.shard_map import shard_map
-
     platform = _mesh_platform(mesh)
 
     def step(state, batch, rng):
         # Tracing happens inside this call: pin the Pallas gate to the mesh's
         # execution platform for the duration.
         with pallas_platform(platform):
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 local,
                 mesh=mesh,
                 in_specs=(P(), _batch_pspec(batch, graph_sharded), P()),
                 out_specs=(P(), P()),
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(state, batch, rng)
 
@@ -676,8 +672,6 @@ def _wrap_dp_step(local, mesh, graph_sharded: bool, donate: bool):
 
 
 def make_eval_step_dp(model: HydraGNN, mesh) -> Callable:
-    from jax.experimental.shard_map import shard_map
-
     graph_sharded = model.graph_axis is not None and mesh.shape.get("graph", 1) > 1
 
     def _local(state, batch):
@@ -701,12 +695,12 @@ def make_eval_step_dp(model: HydraGNN, mesh) -> Callable:
 
     def step(state, batch):
         with pallas_platform(platform):
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 _local,
                 mesh=mesh,
                 in_specs=(P(), _batch_pspec(batch, graph_sharded)),
                 out_specs=(P(), [P("data") for _ in model.output_dim]),
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(state, batch)
 

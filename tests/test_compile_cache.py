@@ -397,3 +397,120 @@ def pytest_supervisor_restart_resumes_with_warm_store(tmp_path, monkeypatch):
         if line.startswith("hydragnn_cache_hydrate_total")
     ]
     assert hydrates and hydrates[0] > 0, prom[:2000]
+
+
+# ------------------------------------------ hydration lands on the right devices
+@pytest.mark.mpi_skip
+def pytest_hydrated_executable_runs_on_the_devices_it_was_compiled_for():
+    """On a multi-device host (the 8-device virtual CPU host here; four chips
+    under HYDRAGNN_TPU_TESTS=1): a program on the default device, a mesh over
+    every device and a mesh over a strict subset each hydrate onto exactly
+    their devices and run — the default of deserialize_and_load loads them
+    onto ALL devices and dies at the first call asking for a shard per
+    device. A single-device program compiled for a NON-default device is
+    refused at load (CacheEntryError -> the registry compiles fresh): the TPU
+    runtime hands it back assigned to chip 0 and it would die at its first
+    call (all four cases seen on the four-chip host, PR 21)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from hydragnn_tpu.cache import CacheEntryError
+    from hydragnn_tpu.cache.store import deserialize_compiled, serialize_compiled
+
+    devices = jax.devices()
+    if len(devices) < 4:
+        pytest.skip("needs a host with at least 4 devices")
+    fn = jax.jit(lambda x: x * 2.0 + 1.0)
+    expect = np.arange(8.0, dtype=np.float32) * 2 + 1
+
+    def compiled_for(group):
+        if len(group) == 1:
+            x = jax.device_put(jnp.arange(8.0), group[0])
+        else:
+            mesh = Mesh(np.array(group), ("data",))
+            x = jax.device_put(jnp.arange(8.0), NamedSharding(mesh, P("data")))
+        sections = serialize_compiled(fn.lower(x).compile())
+        assert json.loads(sections["devices"]) == [d.id for d in group]
+        return sections, x
+
+    for group in (devices[:1], devices, devices[1 : 1 + len(devices) // 2]):
+        sections, x = compiled_for(group)
+        out = deserialize_compiled(sections)(x)
+        np.testing.assert_array_equal(np.asarray(out), expect)
+        assert out.sharding.device_set == set(group)
+
+    sections, _ = compiled_for(devices[-1:])
+    with pytest.raises(CacheEntryError, match="not the default device"):
+        deserialize_compiled(sections)
+
+
+# ------------------------------------------- where JAX's own cache is placed
+@pytest.fixture
+def _jax_cache_dir_restored():
+    import jax
+
+    saved = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", saved)
+
+
+def pytest_jax_cache_env_set_program_sets_nothing(
+    monkeypatch, tmp_path, _jax_cache_dir_restored
+):
+    import jax
+
+    from hydragnn_tpu.cache.jaxcache import place_jax_cache
+
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert place_jax_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir is None  # untouched in code
+
+
+def pytest_jax_cache_env_unset_one_fixed_path_in_the_checkout(
+    monkeypatch, _jax_cache_dir_restored
+):
+    import subprocess
+
+    import jax
+
+    from hydragnn_tpu.cache.jaxcache import JAX_CACHE_DIR, place_jax_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert place_jax_cache() == JAX_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == JAX_CACHE_DIR
+    ignored = subprocess.run(
+        ["git", "check-ignore", "-q", ".jax_cache/x"], cwd=repo
+    )
+    if os.path.isdir(os.path.join(repo, ".git")):
+        assert ignored.returncode == 0, ".jax_cache/ must be gitignored"
+
+
+@pytest.mark.mpi_skip
+def pytest_graftcache_never_repoints_jax_cache(
+    monkeypatch, tmp_path, _jax_cache_dir_restored
+):
+    """A backend that cannot serialize executables makes the registry persist
+    StableHLO only; it used to re-point jax_compilation_cache_dir under the
+    store (a drill's TemporaryDirectory). It now leaves it alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.cache import registry as registry_mod
+
+    monkeypatch.setattr(registry_mod, "serialize_compiled", lambda c: None)
+    jax.config.update("jax_compilation_cache_dir", "/somewhere/fixed")
+    reg = ExecutableRegistry(ExecutableStore(str(tmp_path / "store")))
+    key = CacheKey.for_environment("prog", "fp")
+    x = jnp.ones(4)
+    with pytest.warns(RuntimeWarning, match="cannot serialize"):
+        _exe, outcome, _s = reg.lookup_or_compile(
+            "k", key, lambda: jax.jit(lambda v: v + 1).lower(x)
+        )
+    assert outcome == "compiled"
+    assert jax.config.jax_compilation_cache_dir == "/somewhere/fixed"
+    rows = ExecutableStore(str(tmp_path / "store")).ls()
+    assert [r["exe_format"] for r in rows] == ["stablehlo"]
+    assert not os.path.exists(tmp_path / "store" / "xla")

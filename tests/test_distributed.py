@@ -248,3 +248,57 @@ def pytest_hostlist_and_tasks_grammar(monkeypatch):
     assert _local_device_slot() == 1
     monkeypatch.setenv("SLURM_NTASKS_PER_NODE", "garbled")
     assert _local_device_slot() is None  # unparseable → default placement
+
+
+def pytest_tpu_expected_reads_the_platform_list_without_a_backend(monkeypatch):
+    """Decided from JAX_PLATFORMS / jax_platforms alone when either names
+    platforms — the checks that call it run before anything may touch (and so
+    claim) a chip."""
+    from hydragnn_tpu.parallel import distributed as dist
+
+    saved = jax.config.jax_platforms
+    try:
+        jax.config.update("jax_platforms", "tpu,cpu")
+        assert dist.tpu_expected()
+        jax.config.update("jax_platforms", "cpu")
+        assert not dist.tpu_expected()
+        jax.config.update("jax_platforms", None)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        assert dist.tpu_expected()
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert not dist.tpu_expected()
+    finally:
+        jax.config.update("jax_platforms", saved)
+
+
+def pytest_several_ranks_per_tpu_host_fail_fast(monkeypatch):
+    """local_device_ids hides CUDA/ROCm devices only: on a TPU host every
+    rank would claim every chip. setup_ddp refuses before it initialises
+    anything, unless the launcher already hid the chips."""
+    from hydragnn_tpu.parallel import distributed as dist
+
+    for var, val in (
+        ("OMPI_COMM_WORLD_SIZE", "2"), ("OMPI_COMM_WORLD_RANK", "1"),
+        ("OMPI_COMM_WORLD_LOCAL_RANK", "1"), ("OMPI_COMM_WORLD_LOCAL_SIZE", "2"),
+    ):
+        monkeypatch.setenv(var, val)
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    monkeypatch.setattr(dist, "tpu_expected", lambda: True)
+    monkeypatch.setattr(dist, "_distributed_active", lambda: False)
+
+    def never(**kw):
+        raise AssertionError("jax.distributed.initialize must not be reached")
+
+    monkeypatch.setattr(jax.distributed, "initialize", never)
+    with pytest.raises(RuntimeError, match="TPU_VISIBLE_CHIPS"):
+        dist.setup_ddp()
+
+
+def pytest_router_spawn_refused_on_a_tpu_host(monkeypatch, capsys):
+    from hydragnn_tpu.parallel import distributed as dist
+    from hydragnn_tpu.route.__main__ import main as router_main
+
+    monkeypatch.setattr(dist, "tpu_expected", lambda: True)
+    rc = router_main(["--config", "unused.json", "--spawn", "2"])
+    assert rc == 2
+    assert "--replica-url" in capsys.readouterr().err

@@ -304,6 +304,15 @@ def pytest_non_transient_transfer_failure_propagates_immediately():
     assert FaultCounters.get("transfer_retries") == 0
     assert feed.join(5)
 
+    # Neither is HBM exhaustion (what RESOURCE_EXHAUSTED means on a chip
+    # attached to the host) nor an error that merely names a transport.
+    from hydragnn_tpu.train.pipeline import transfer_error_is_transient
+
+    assert not transfer_error_is_transient(
+        RuntimeError("RESOURCE_EXHAUSTED: out of memory allocating 2.1G")
+    )
+    assert not transfer_error_is_transient(RuntimeError("UNAVAILABLE: x"))
+
 
 def pytest_transfer_retries_exhausted_propagates():
     def always_down(x):

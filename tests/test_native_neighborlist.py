@@ -130,3 +130,24 @@ def pytest_pbc_max_neighbours_cap():
     assert np.all(counts == 8)
     # kept edges are the nearest shell
     assert float(ln.max()) < a
+
+
+@needs_native
+def pytest_rebuild_discards_the_binary_on_disk(monkeypatch):
+    """_neighborlist.so is untracked, so a copied checkout carries another
+    machine's binary: rebuild() compiles neighborlist.cc again whatever is on
+    disk. And a failed build says so instead of silently taking the numpy
+    path."""
+    import os
+
+    before = os.stat(native._SO).st_mtime_ns
+    assert native.rebuild() and native.available()
+    assert os.stat(native._SO).st_mtime_ns > before
+
+    monkeypatch.setattr(native, "_SRC", native._SRC + ".missing")
+    try:
+        with pytest.warns(RuntimeWarning, match="numpy/cKDTree path"):
+            assert not native.rebuild()
+    finally:
+        monkeypatch.undo()
+        assert native.rebuild()

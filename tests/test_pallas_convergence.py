@@ -1,4 +1,4 @@
-"""End-to-end convergence under the fused Pallas kernel (VERDICT r04 item 3).
+"""End-to-end convergence under the fused Pallas kernel.
 
 Trains the flagship matrix cell (PNA + ci_multihead — the one whose head 3
 sits closest to its gate) with HYDRAGNN_PALLAS=1 and asserts every head's

@@ -205,7 +205,6 @@ def pytest_fused_ops_differentiable_under_shard_map(monkeypatch):
     carrier in segment_sum_count's residuals picked up an inconsistent XLA
     sharding and crashed the backward)."""
     monkeypatch.setenv("HYDRAGNN_PALLAS", "1")
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("graph",))
@@ -220,9 +219,9 @@ def pytest_fused_ops_differentiable_under_shard_map(monkeypatch):
         m = ps.fused_segment_mean(l_, ids_, n, axis_name="graph")
         return jax.lax.psum((s ** 2).sum() + (a ** 2).sum() + (m ** 2).sum(), "graph")
 
-    f = shard_map(
+    f = jax.shard_map(
         local, mesh=mesh, in_specs=(P("graph"), P("graph")), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     g = jax.grad(lambda l: f(l, ids))(logits)
     assert bool(jnp.all(jnp.isfinite(g)))

@@ -1,7 +1,7 @@
 """World-safe exercise of the top-level ``run_prediction`` surface — the
 4-tuple return contract and the denormalize path — designed to run under the
 2-process launcher (tests/run_suite_2proc.py) as well as serially
-(VERDICT r04 item 6; reference /root/reference/hydragnn/run_prediction.py:27-80
+(reference /root/reference/hydragnn/run_prediction.py:27-80
 returns (error, error_rmse_task, true_values, predicted_values)).
 
 test_graphs.py already drives run_prediction under 2 ranks, but always with

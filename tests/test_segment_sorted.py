@@ -235,7 +235,6 @@ def pytest_sorted_path_under_graph_shard_map(monkeypatch):
     sums compose via psum. Values (not just finiteness) must match the
     single-device sorted result, and gradients must flow."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from hydragnn_tpu.ops import pallas_segment as ps
@@ -257,9 +256,9 @@ def pytest_sorted_path_under_graph_shard_map(monkeypatch):
         )
         return total, mean, std, count
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh, in_specs=(P("graph"), P("graph")),
-        out_specs=(P(), P(), P(), P()), check_rep=False,
+        out_specs=(P(), P(), P(), P()), check_vma=False,
     )
     out = sharded(data, ids)
     for a, b in zip(ref, out):

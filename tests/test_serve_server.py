@@ -67,6 +67,9 @@ def pytest_serve_http_predict_healthz_metrics_end_to_end():
         with urllib.request.urlopen(base + "/healthz", timeout=10) as resp:
             health = json.loads(resp.read())
         assert health["ok"] is True and health["compiled_buckets"] >= 1
+        # Where the weights live, as JAX reports it (never a hidden device).
+        assert health["device"]["platform"] == "cpu"
+        assert health["device"]["visible"] >= 1
         # The fault-tolerance surface: healthy AND un-degraded, with the
         # restart/bad-batch counters exposed (docs/FAULT_TOLERANCE.md).
         assert health["degraded"] is False

@@ -1,5 +1,4 @@
-"""Numerical parity of the torch-checkpoint importer, per conv family
-(VERDICT r04 item 4).
+"""Numerical parity of the torch-checkpoint importer, per conv family.
 
 The round-trip test (test_torch_import.py) checks placement and that the
 imported model RUNS; it cannot catch a wrong assumption about PyG's tensor
