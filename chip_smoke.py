@@ -14,7 +14,8 @@ accelerator, or away from the rest of the repo, it exits non-zero and prints
 no result.
 
     python3 chip_smoke.py                    # one chip, every stage
-    python3 chip_smoke.py --chips 4          # four-chip host: mesh stages
+    python3 chip_smoke.py --chips 4          # four-chip host: run_training on
+                                             # a data mesh of 4, then on 2x2
     python3 chip_smoke.py --rehearse-on-cpu  # tiny sizes on the CPU; checks
                                              # the control flow, not the chip
 
@@ -43,7 +44,6 @@ NEEDS = (
     "hydragnn_tpu/run_training.py",
     "tests/deterministic_graph_data.py",
     "tests/inputs/ci_multihead.json",
-    "__graft_entry__.py",
 )
 
 # The task is tests/inputs/ci_multihead.json; these are the sizes laid over
@@ -70,6 +70,11 @@ SIZES = {
 # multiplies in bf16 (2^-8 relative an operand): measured 5.4e-3 at most on
 # the chip (PR 21). A wrong checkpoint or a mis-wired head is off by O(1).
 SERVE_ATOL = SERVE_RTOL = 2e-2
+# run_prediction on one checkpoint, edge-sharded over the 2x2 mesh against one
+# device: tests/test_largegraph.py's check (error and per-head RMSE of one
+# forward pass), at the tolerance above for the same reasons — each edge
+# shard runs its own prefix sums.
+MESH_RTOL = 2e-2
 # bf16 policy against f32 on the same batches: the repo's own gate
 # (bench.py --precision, tests/test_mixed_precision.py) on the loss, relative
 # to the f32 loss of the first epoch.
@@ -804,12 +809,14 @@ def _child_kernels(args) -> dict:
 class _DeviceWatch:
     """Samples, while a run is going, where its arrays live: for each count
     of devices an array is spread over, the most arrays and bytes seen at
-    once; and each device's bytes_in_use."""
+    once; for each mesh an array is laid over, the most arrays at once and
+    how many of them are split along 'graph'; each device's bytes_in_use."""
 
     def __init__(self):
         import threading
 
         self.arrays_by_device_count: dict = {}
+        self.arrays_by_mesh: dict = {}
         self.max_bytes_in_use: list = []
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True)
@@ -822,18 +829,36 @@ class _DeviceWatch:
         self._stop.set()
         self._thread.join(timeout=10)
 
+    def report(self) -> dict:
+        return {
+            "live_arrays_by_device_count": self.arrays_by_device_count,
+            "live_arrays_by_mesh": self.arrays_by_mesh,
+            "max_bytes_in_use": self.max_bytes_in_use,
+        }
+
     def _run(self):
         import jax
 
         while not self._stop.wait(0.25):
             seen: dict = {}
+            meshes: dict = {}
             for a in jax.live_arrays():
                 k = len(a.sharding.device_set)
                 n, b = seen.get(k, (0, 0))
                 seen[k] = (n + 1, b + a.nbytes)
-            for k, (n, b) in seen.items():
-                old = self.arrays_by_device_count.get(k, [0, 0])
-                self.arrays_by_device_count[k] = [max(old[0], n), max(old[1], b)]
+                if isinstance(a.sharding, jax.sharding.NamedSharding):
+                    name = "x".join(
+                        f"{ax}:{n}" for ax, n in a.sharding.mesh.shape.items()
+                    )
+                    n, g = meshes.get(name, (0, 0))
+                    split = "graph" in str(a.sharding.spec)
+                    meshes[name] = (n + 1, g + split)
+            for into, now in (
+                (self.arrays_by_device_count, seen), (self.arrays_by_mesh, meshes)
+            ):
+                for k, (x, y) in now.items():
+                    old = into.get(k, [0, 0])
+                    into[k] = [max(old[0], x), max(old[1], y)]
             now = [
                 (d.memory_stats() or {}).get("bytes_in_use")
                 for d in jax.devices()
@@ -848,15 +873,19 @@ class _DeviceWatch:
 
 
 def _child_mesh(args) -> dict:
-    """Four chips in one process: run_training over make_mesh() (data 4),
-    then one step of the full train step on the 2x2 ('data','graph') mesh."""
-    import jax
+    """Four chips in one process, through run_training both times: the data
+    mesh of 4 (mesh=make_mesh()), then the 2x2 ('data','graph') mesh the
+    config's own Training.graph_axis asks for, at the one-chip smoke's batch
+    a data shard — so the same padded shapes, each one's edges split over two
+    chips — and run_prediction on that checkpoint, edge-sharded against one
+    device."""
+    import numpy as np
 
     device = _require_platform(args)
-    import __graft_entry__ as entry
-    from hydragnn_tpu import run_training
+    from hydragnn_tpu import run_prediction, run_training
     from hydragnn_tpu.cache.jaxcache import place_jax_cache
     from hydragnn_tpu.parallel import make_mesh
+    from hydragnn_tpu.preprocess.load_data import dataset_loading_and_splitting
 
     place_jax_cache()
     mesh = make_mesh()
@@ -868,18 +897,15 @@ def _child_mesh(args) -> dict:
     out = {
         "device": device,
         "mesh": {k: int(v) for k, v in mesh.shape.items()},
-        "loss_per_epoch": [float(v) for v in history["total_loss_train"]],
-        "xla_compiles_per_epoch": list(history["xla_compiles"]),
-        "live_arrays_by_device_count": watch.arrays_by_device_count,
-        "max_bytes_in_use": watch.max_bytes_in_use,
         "peak_bytes_in_use": _peak_bytes(),
     }
+    out.update(watch.report())
+    out.update(_check_history(history, "run_training(mesh=make_mesh())"))
     say(
         "data mesh of 4 — arrays alive during training by how many devices "
         "hold them {devices: [arrays, bytes]}, bytes_in_use per device (max "
         f"seen) and peak_bytes_in_use per device: {json.dumps(out)}"
     )
-    _check_history(history, "run_training(mesh=make_mesh())")
     if 4 not in watch.arrays_by_device_count:
         raise SystemExit("[chip_smoke] mesh: no array lived on all 4 devices")
     # Device 0 also ran the model's init, so its peak may be the largest; a
@@ -892,8 +918,58 @@ def _child_mesh(args) -> dict:
             )
     elif not args.rehearse_on_cpu:
         raise SystemExit("[chip_smoke] mesh: backend reports no memory_stats")
-    entry.dryrun_multichip(4, devices=jax.devices())
-    out["mesh_2x2_step"] = "ok"
+
+    config = _load_config(args)
+    training = config["NeuralNetwork"]["Training"]
+    training["graph_axis"] = 2
+    training["batch_size"] = _sizes(args)["batch_size"]
+    training["num_epoch"] = 2
+    loader = dataset_loading_and_splitting(copy.deepcopy(config))[0]
+    shapes: dict = {}
+    for b in loader:
+        key = f"{b.node_features.shape[0]}x{b.senders.shape[0]}"
+        shapes[key] = shapes.get(key, 0) + 1
+    two = out["mesh_2x2"] = {
+        "hidden_dim": config["NeuralNetwork"]["Architecture"]["hidden_dim"],
+        "num_conv_layers": config["NeuralNetwork"]["Architecture"]["num_conv_layers"],
+        "batch_size_per_data_shard": training["batch_size"],
+        "batch_shapes_nodes_x_edges": shapes,
+        "loader_batches_per_epoch": sum(shapes.values()),  # two a step
+    }
+    say(f"2x2 mesh — Training.graph_axis=2, sizes: {json.dumps(two)}")
+
+    def split_along_graph(watch, what):
+        on_2x2 = watch.arrays_by_mesh.get("data:2xgraph:2")
+        if not on_2x2 or not on_2x2[1]:
+            raise SystemExit(
+                f"[chip_smoke] mesh: {what} split no array along 'graph' of "
+                f"a 2x2 mesh {watch.arrays_by_mesh}"
+            )
+
+    def evaluate(cfg):
+        error, rmse_task, _true, _pred = run_prediction(copy.deepcopy(cfg))
+        return [float(error)] + [float(r) for r in np.asarray(rmse_task).ravel()]
+
+    with _DeviceWatch() as watch:
+        history = run_training(copy.deepcopy(config))
+    two.update(watch.report())
+    two.update(_check_history(history, "run_training(Training.graph_axis=2)"))
+    split_along_graph(watch, "run_training")
+    with _DeviceWatch() as watch:
+        sharded = evaluate(config)
+    split_along_graph(watch, "run_prediction")
+    del training["graph_axis"]
+    single = evaluate(config)
+    two["error_and_rmse_per_head_2x2"] = sharded
+    two["error_and_rmse_per_head_one_device"] = single
+    two["rtol"] = MESH_RTOL
+    say(f"2x2 mesh: {json.dumps(two)}")
+    if not np.allclose(sharded, single, rtol=MESH_RTOL, atol=0.0):
+        raise SystemExit(
+            "[chip_smoke] mesh: run_prediction edge-sharded over 2x2 "
+            f"{sharded} != one device {single} on the same checkpoint "
+            f"(rtol {MESH_RTOL})"
+        )
     return out
 
 

@@ -35,6 +35,7 @@ from .store import (
     CacheKey,
     ExecutableStore,
     deserialize_compiled,
+    hydratable,
     serialize_compiled,
 )
 
@@ -102,6 +103,8 @@ class ExecutableRegistry:
 
         if callable(key):
             key = key()
+        if key is not None and not hydratable(key.devices):
+            key = None  # compiled every time, never stored (store.hydratable)
         outcome = OUTCOME_COMPILED
         seconds = 0.0
         exe = None
@@ -152,7 +155,7 @@ class ExecutableRegistry:
             # as a miss here.
             return None
         try:
-            return deserialize_compiled(sections)
+            return deserialize_compiled(sections, key.devices)
         except CacheEntryError as e:
             # Verified bytes that still fail to load (jax minor drift inside
             # an identical version string, foreign-arch payload): quarantine
@@ -168,6 +171,23 @@ class ExecutableRegistry:
         failures are warnings — a full disk must not fail the train/serve
         path."""
         assert self._store is not None
+        # The key's devices are the caller's word; the executable knows. An
+        # entry under a key that names other devices would be loaded there.
+        import jax
+
+        local = {d.id for d in jax.local_devices()}
+        built_for = tuple(
+            d.id for d in compiled.runtime_executable().local_devices()
+        )
+        if built_for != tuple(i for i in key.devices if i in local):
+            warnings.warn(
+                f"graftcache[{self.name}]: {key.program} was compiled for "
+                f"devices {list(built_for)} but keyed for "
+                f"{list(key.devices)}; not stored",
+                RuntimeWarning,
+                stacklevel=3,
+            )
+            return
         t0 = time.perf_counter()
         try:
             sections = serialize_compiled(compiled)

@@ -8,9 +8,8 @@ fsync'd unique-tmp + atomic-rename install (checkpoint/io.write_checkpoint_blob)
 persists. A store entry is::
 
     <cache_dir>/<key-digest>.hexe       # v2 container:
-        header:   {"kind": "graftcache-exe/v1", "exe_format": ..., "key": {...}}
-        sections: {"executable": <bytes>, "trees": <pickled treedefs>,
-                   "devices": <json ids the program was compiled for>}
+        header:   {"kind": "graftcache-exe/v2", "exe_format": ..., "key": {...}}
+        sections: {"executable": <bytes>, "trees": <pickled treedefs>}
     <cache_dir>/manifest.json           # advisory index (ls/gc); lookups go
                                         # by key digest, so a lost manifest
                                         # update can never serve a wrong entry
@@ -54,7 +53,9 @@ from ..checkpoint import format as ckpt_format
 from ..checkpoint.format import CheckpointCorruptError, param_fingerprint
 from ..checkpoint.io import atomic_write_json, write_checkpoint_blob
 
-ENTRY_KIND = "graftcache-exe/v1"
+# v2: the key names the devices the program runs on, so every digest moved and
+# a v1 entry (which does not say where it was compiled) is never looked up.
+ENTRY_KIND = "graftcache-exe/v2"
 ENTRY_SUFFIX = ".hexe"
 MANIFEST = "manifest.json"
 
@@ -127,8 +128,13 @@ class CacheKey:
     shard_map programs compiled for one mesh shape must never hydrate
     another's entries even when every array shape agrees (the environment
     topology pins the device COUNT; this pins the axis FACTORIZATION).
-    Empty = single-device program — omitted from the canonical JSON so every
-    pre-graftmesh store digest (and warm store) is preserved."""
+    Empty = single-device program.
+
+    ``devices`` is the ids of the devices the program runs on, in assignment
+    order: the mesh's for a mesh program, the one chip for a single-device
+    program. A serialized executable is bound to them, so replicas on
+    different chips of one host, or meshes over different chips, never share
+    an entry; hydration loads the executable onto exactly these."""
 
     program: str
     jax_version: str
@@ -140,6 +146,7 @@ class CacheKey:
     bucket: Tuple[int, int, int] = (0, 0, 0)
     args_digest: str = ""
     mesh: str = ""
+    devices: Tuple[int, ...] = ()
 
     @classmethod
     def for_environment(
@@ -151,8 +158,15 @@ class CacheKey:
         args_digest: str = "",
         env: Optional[Dict[str, str]] = None,
         mesh: str = "",
+        devices: Optional[Tuple[int, ...]] = None,
     ) -> "CacheKey":
+        """``devices=None`` means the process's default device, where a jit
+        over uncommitted arguments runs."""
         env = env if env is not None else environment_fingerprint()
+        if devices is None:
+            import jax
+
+            devices = (jax.devices()[0].id,)
         return cls(
             program=program,
             jax_version=env["jax_version"],
@@ -164,16 +178,14 @@ class CacheKey:
             bucket=(int(bucket[0]), int(bucket[1]), int(bucket[2])),
             args_digest=args_digest,
             mesh=str(mesh),
+            devices=tuple(int(i) for i in devices),
         )
 
     def to_json(self) -> Dict[str, Any]:
         doc = asdict(self)
         doc["flags"] = list(self.flags)
         doc["bucket"] = list(self.bucket)
-        if not self.mesh:
-            # Canonical-JSON stability: single-device keys keep their
-            # pre-graftmesh digests, so existing stores stay warm.
-            doc.pop("mesh")
+        doc["devices"] = list(self.devices)
         return doc
 
     @classmethod
@@ -190,6 +202,7 @@ class CacheKey:
             bucket=(int(bucket[0]), int(bucket[1]), int(bucket[2])),
             args_digest=doc.get("args_digest", ""),
             mesh=doc.get("mesh", ""),
+            devices=tuple(int(i) for i in doc.get("devices") or ()),
         )
 
     def digest(self) -> str:
@@ -463,66 +476,58 @@ class ExecutableStore:
 
 
 # ------------------------------------------------- executable (de)serialization
+def hydratable(devices: Tuple[int, ...]) -> bool:
+    """Whether a program on ``devices`` can come back from the store. A
+    single-device program on a NON-default device cannot: loaded with its own
+    chip as the execution device, the TPU runtime (jaxlib 0.9, four chips,
+    PR 21) reports that chip and then dies at the first call, "replica is
+    assigned to device TPU_0". The registry neither stores nor looks up such
+    a program; it compiles."""
+    import jax
+
+    return len(devices) != 1 or devices[0] == jax.devices()[0].id
+
+
 def serialize_compiled(compiled: Any) -> Optional[Dict[str, bytes]]:
     """``jax.stages.Compiled`` → store sections, or None when the backend
     cannot serialize executables (the StableHLO fallback engages then).
     Treedefs ride along pickled — custom pytree nodes (GraphBatch,
     TrainState, optax states) unpickle against the SAME registered types, so
     hydration must happen after the defining modules imported (they have:
-    the engine/trainer import them before any lookup). ``devices`` records
-    the ids of the devices the program was compiled for, in assignment
-    order — hydration loads the executable onto exactly those."""
+    the engine/trainer import them before any lookup)."""
     try:
         from jax.experimental import serialize_executable as se
 
         payload, in_tree, out_tree = se.serialize(compiled)
-        device_ids = [
-            int(d.id)
-            for d in compiled._executable._unloaded_executable.device_list
-        ]
         return {
             "executable": payload,
             "trees": pickle.dumps((in_tree, out_tree)),
-            "devices": json.dumps(device_ids).encode(),
         }
     except Exception:  # noqa: BLE001 — backend capability probe, not an error
         return None
 
 
-def deserialize_compiled(sections: Dict[str, bytes]) -> Any:
-    """Store sections → loaded executable, on the devices it was compiled
-    for (one chip for a single-device program, the mesh's devices for a mesh
-    program — left to its default, ``deserialize_and_load`` would load it
-    onto EVERY visible device and the first call would ask for one shard per
-    device; seen on the four-chip host, PR 21). Raises
-    :class:`CacheEntryError` on any decode failure and for a single-device
-    program compiled for a non-default device (the registry turns that into
-    quarantine + fresh compile). Deserialization
-    fires NO XLA compile monitoring event — the sentinel-truthfulness
-    property tests/test_compile_cache.py pins."""
+def deserialize_compiled(sections: Dict[str, bytes], devices: Tuple[int, ...]) -> Any:
+    """Store sections → executable loaded onto ``devices``, the key's: the
+    one chip of a single-device program, the mesh's devices for a mesh
+    program. Left to its default, ``deserialize_and_load`` loads onto EVERY
+    visible device and the first call asks for one shard per device (seen on
+    the four-chip host, PR 21). Raises :class:`CacheEntryError` on any decode
+    failure (the registry turns that into quarantine + fresh compile).
+    Deserialization fires NO XLA compile monitoring event — the
+    sentinel-truthfulness property tests/test_compile_cache.py pins."""
     import jax
     from jax.experimental import serialize_executable as se
 
+    by_id = {d.id: d for d in jax.devices()}
     try:
         # graftlint: disable=pickle-load-outside-compat(pytree defs inside a GSHD cache container whose digest was verified before this call — no untrusted bytes reach the unpickler)
         in_tree, out_tree = pickle.loads(sections["trees"])
-        device_ids = json.loads(sections["devices"])
-        if len(device_ids) == 1 and device_ids[0] != jax.devices()[0].id:
-            # A single-device program is only handed back where the runtime
-            # puts it: on the TPU (jaxlib 0.9, four chips, PR 21) one
-            # compiled for chip 3 reports chip 3 after loading and dies at
-            # its first call, "replica is assigned to device TPU_0". Refuse
-            # it here, so the caller compiles fresh instead.
-            raise ValueError(
-                f"single-device program compiled for device {device_ids[0]}, "
-                f"not the default device {jax.devices()[0].id}"
-            )
-        by_id = {d.id: d for d in jax.devices()}
         return se.deserialize_and_load(
             sections["executable"],
             in_tree,
             out_tree,
-            execution_devices=[by_id[i] for i in device_ids],
+            execution_devices=[by_id[i] for i in devices],
         )
     except Exception as e:  # noqa: BLE001 — one failure class for callers
         raise CacheEntryError(

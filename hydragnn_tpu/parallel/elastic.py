@@ -647,6 +647,7 @@ class ElasticTrainer:
             from ..cache import CacheKey, tree_signature
 
             descriptor = mesh_descriptor(mesh)
+            device_ids = tuple(d.id for d in mesh.devices.flat)
 
             def dispatch(state, batch, rng, _step=step, _md=descriptor):
                 exe, _outcome, _s = reg.lookup_or_compile(
@@ -657,6 +658,7 @@ class ElasticTrainer:
                         flags=(f"grad_sync={self.grad_sync}",),
                         args_digest=tree_signature((state, batch, rng)),
                         mesh=_md,
+                        devices=device_ids,
                     ),
                     lambda: _step.lower(state, batch, rng),
                 )

@@ -975,6 +975,7 @@ class InferenceEngine:
             flags=self._key_flags,
             bucket=bucket,
             args_digest=tree_signature((params, bstats, batch)),
+            devices=(self.device["id"],),
         )
 
     def _executable_for(self, dev_batch, params, bstats):

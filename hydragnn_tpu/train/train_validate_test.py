@@ -313,10 +313,12 @@ class TrainingDriver:
         self._cache_fingerprint = ""
         self._cache_flags: tuple = ()
         self._cache_mesh = ""
+        self._cache_devices = None  # the default device
         if mesh is not None:
             from ..parallel.distributed import mesh_descriptor
 
             self._cache_mesh = mesh_descriptor(mesh)
+            self._cache_devices = tuple(d.id for d in mesh.devices.flat)
         if cache_dir:
             import hashlib
 
@@ -424,6 +426,7 @@ class TrainingDriver:
                 flags=self._cache_flags,
                 args_digest=tree_signature(args),
                 mesh=self._cache_mesh,
+                devices=self._cache_devices,
             ),
             lambda: fn.lower(*args),
         )

@@ -36,9 +36,6 @@ def pytest_collection_modifyitems(config, items):
     ./serialized_dataset paths when every rank runs them."""
     import pytest
 
-    # Stable sort: everything keeps its order, runs_last tests go to the end.
-    items.sort(key=lambda item: "runs_last" in item.keywords)
-
     world = int(
         os.environ.get("HYDRAGNN_WORLD_SIZE")
         or os.environ.get("OMPI_COMM_WORLD_SIZE")

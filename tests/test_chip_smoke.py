@@ -1,7 +1,8 @@
 """chip_smoke.py (repo root) — what the CPU can check of it: it refuses
-anything but a TPU, it refuses to run away from the repo, and its explicit
-CPU rehearsal drives every stage end to end at a tiny size. What it proves
-about the chip, only a chip run shows."""
+anything but a TPU, it refuses to run away from the repo (both tier-1), and
+its explicit CPU rehearsal drives every stage end to end at a tiny size
+(`slow`: five JAX processes, 130 s here; `pytest tests/test_chip_smoke.py`
+runs it). What it proves about the chip, only a chip run shows."""
 
 import json
 import os
@@ -46,7 +47,7 @@ def pytest_chip_smoke_alone_in_a_directory_fails(tmp_path):
 
 
 @pytest.mark.mpi_skip
-@pytest.mark.runs_last
+@pytest.mark.slow
 def pytest_chip_smoke_rehearsal_passes_end_to_end(tmp_path):
     """Every stage — device probe, train + predict, serve over HTTP, every
     aggregation arm + bf16, warm start — at the tiny size, on the CPU, with

@@ -124,8 +124,8 @@ def pytest_cache_key_roundtrip_digest_and_store_cli(tmp_path):
 @pytest.mark.mpi_skip
 def pytest_fingerprint_mismatch_forces_miss(tmp_path):
     """Every key component is load-bearing: a changed jax version, device
-    topology, config fingerprint, or donation flag reads as a MISS — the
-    store can never hand a stale program to a changed environment."""
+    topology, config fingerprint, donation flag or device reads as a MISS —
+    the store can never hand a stale program to a changed environment."""
     store = ExecutableStore(str(tmp_path))
     env = environment_fingerprint()
     base = CacheKey.for_environment(
@@ -151,6 +151,10 @@ def pytest_fingerprint_mismatch_forces_miss(tmp_path):
         ),
         CacheKey.for_environment(  # different bucket shape
             "prog", "cfg", flags=("donate",), bucket=(128, 512, 5), env=env
+        ),
+        CacheKey.for_environment(  # another chip of the same host
+            "prog", "cfg", flags=("donate",), bucket=(64, 512, 5), env=env,
+            devices=(base.devices[0] + 1,),
         ),
     ]
     for variant in variants:
@@ -401,48 +405,78 @@ def pytest_supervisor_restart_resumes_with_warm_store(tmp_path, monkeypatch):
 
 # ------------------------------------------ hydration lands on the right devices
 @pytest.mark.mpi_skip
-def pytest_hydrated_executable_runs_on_the_devices_it_was_compiled_for():
+def pytest_hydrated_executable_runs_on_the_devices_it_was_compiled_for(tmp_path):
     """On a multi-device host (the 8-device virtual CPU host here; four chips
     under HYDRAGNN_TPU_TESTS=1): a program on the default device, a mesh over
-    every device and a mesh over a strict subset each hydrate onto exactly
-    their devices and run — the default of deserialize_and_load loads them
-    onto ALL devices and dies at the first call asking for a shard per
-    device. A single-device program compiled for a NON-default device is
-    refused at load (CacheEntryError -> the registry compiles fresh): the TPU
-    runtime hands it back assigned to chip 0 and it would die at its first
-    call (all four cases seen on the four-chip host, PR 21)."""
+    every device and a mesh over a strict subset each come back from the
+    store onto exactly the devices their key names, and run — the default of
+    deserialize_and_load loads them onto ALL devices and dies at the first
+    call asking for a shard per device. Keys that differ only in devices are
+    different entries. A single-device program on a NON-default device is
+    compiled every time and never stored — no entry, no quarantine, no
+    corrupt count: the TPU runtime hands such a program back assigned to
+    chip 0 (all four cases seen on the four-chip host, PR 21)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from hydragnn_tpu.cache import CacheEntryError
-    from hydragnn_tpu.cache.store import deserialize_compiled, serialize_compiled
 
     devices = jax.devices()
     if len(devices) < 4:
         pytest.skip("needs a host with at least 4 devices")
     fn = jax.jit(lambda x: x * 2.0 + 1.0)
     expect = np.arange(8.0, dtype=np.float32) * 2 + 1
+    store_dir = str(tmp_path / "store")
+    corrupt_before = FaultCounters.snapshot().get("exec_cache_corrupt", 0)
 
-    def compiled_for(group):
+    def lookup(group):
         if len(group) == 1:
             x = jax.device_put(jnp.arange(8.0), group[0])
         else:
             mesh = Mesh(np.array(group), ("data",))
             x = jax.device_put(jnp.arange(8.0), NamedSharding(mesh, P("data")))
-        sections = serialize_compiled(fn.lower(x).compile())
-        assert json.loads(sections["devices"]) == [d.id for d in group]
-        return sections, x
-
-    for group in (devices[:1], devices, devices[1 : 1 + len(devices) // 2]):
-        sections, x = compiled_for(group)
-        out = deserialize_compiled(sections)(x)
+        key = CacheKey.for_environment(
+            "prog", "fp", devices=tuple(d.id for d in group)
+        )
+        reg = ExecutableRegistry(ExecutableStore(store_dir))  # a new process
+        exe, outcome, _s = reg.lookup_or_compile("k", key, lambda: fn.lower(x))
+        out = exe(x)
         np.testing.assert_array_equal(np.asarray(out), expect)
         assert out.sharding.device_set == set(group)
+        return outcome
 
-    sections, _ = compiled_for(devices[-1:])
-    with pytest.raises(CacheEntryError, match="not the default device"):
-        deserialize_compiled(sections)
+    groups = (devices[:1], devices, devices[1 : 1 + len(devices) // 2])
+    assert [lookup(g) for g in groups] == ["compiled"] * 3
+    assert [lookup(g) for g in groups] == ["disk"] * 3
+    assert len(ExecutableStore(store_dir).ls()) == 3
+
+    assert [lookup(devices[-1:]) for _ in range(2)] == ["compiled"] * 2
+    assert len(ExecutableStore(store_dir).ls()) == 3
+    assert not [f for f in os.listdir(store_dir) if f.endswith(".corrupt")]
+    assert (
+        FaultCounters.snapshot().get("exec_cache_corrupt", 0) == corrupt_before
+    )
+
+
+@pytest.mark.mpi_skip
+def pytest_program_keyed_for_the_wrong_devices_is_not_stored(tmp_path):
+    """The key's devices are the caller's word; the executable knows where it
+    was built. A disagreement is a warning and no entry — stored, it would
+    later be loaded onto devices it was not compiled for."""
+    import jax
+    import jax.numpy as jnp
+
+    devices = jax.devices()
+    if len(devices) < 2:
+        pytest.skip("needs a host with at least 2 devices")
+    x = jax.device_put(jnp.arange(8.0), devices[1])
+    reg = ExecutableRegistry(ExecutableStore(str(tmp_path / "store")))
+    key = CacheKey.for_environment("prog", "fp")  # says: the default device
+    with pytest.warns(RuntimeWarning, match="not stored"):
+        _exe, outcome, _s = reg.lookup_or_compile(
+            "k", key, lambda: jax.jit(lambda v: v + 1).lower(x)
+        )
+    assert outcome == "compiled"
+    assert ExecutableStore(str(tmp_path / "store")).ls() == []
 
 
 # ------------------------------------------- where JAX's own cache is placed
