@@ -8,9 +8,10 @@ times):
 1. ablation: steady-epoch wall time with the REAL loader vs with the same
    batches pre-materialized in memory (zero feed cost). The difference is the
    true feed overhead — robust under async dispatch, where span timings lie.
-2. spans: one epoch through the per-step path with a timing profiler stub
-   counting "feed" (prefetcher queue wait + lift) vs "train_step" (dispatch)
-   wall time — the same spans a real jax.profiler trace annotates.
+2. spans: one epoch through the per-step path, summing the program's own
+   graftel spans: "feed_wait" (consumer blocked on the device queue) vs
+   "device_step" (dispatch + readback) vs "h2d" (transfer thread) — the
+   same spans a real jax.profiler trace shows as host events.
 
 Optionally captures a jax.profiler trace of one steady epoch (--trace) for
 TensorBoard/Perfetto. Writes a JSON artifact (--out, e.g. PROFILE_r04.json).
@@ -22,7 +23,6 @@ Usage: python benchmarks/profile_epoch.py [--platform cpu|tpu] [--batch 256]
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import os
 import sys
@@ -32,26 +32,23 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, REPO)
 
 
-class _TimingSpans:
-    """Profiler stand-in for TrainingDriver.train_epoch: accumulates wall
-    time per annotation name. ``active=True`` routes the driver onto the
-    per-step path (the scan path hides step boundaries)."""
+class _PerStep:
+    """Profiler stand-in for TrainingDriver.train_epoch: ``active=True``
+    routes the driver onto the per-step path (the scan path hides step
+    boundaries). The seconds come from the graftel spans that path opens."""
 
     active = True
 
-    def __init__(self):
-        self.acc = {}
-
-    @contextlib.contextmanager
-    def annotate(self, name):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.acc[name] = self.acc.get(name, 0.0) + time.perf_counter() - t0
-
     def step(self):
         pass
+
+
+def _span_seconds(records):
+    acc = {}
+    for r in records:
+        if r["kind"] == "span":
+            acc[r["name"]] = acc.get(r["name"], 0.0) + r["dur_s"]
+    return acc
 
 
 def main():
@@ -106,9 +103,15 @@ def main():
     # above compiled only epoch_scan; the per-step train_step is a separate
     # jit, so run one discarded per-step epoch first or its compile would
     # land inside the measured "train_step" span.
-    driver.train_epoch(train_loader, profiler=_TimingSpans())
-    spans = _TimingSpans()
-    driver.train_epoch(train_loader, profiler=spans)
+    from hydragnn_tpu import telemetry
+
+    driver.train_epoch(train_loader, profiler=_PerStep())
+    was_collecting = telemetry.collecting()
+    telemetry.configure(collect=True)
+    before = len(telemetry.collected_records())
+    driver.train_epoch(train_loader, profiler=_PerStep())
+    spans = _span_seconds(telemetry.collected_records()[before:])
+    telemetry.configure(collect=was_collecting)
 
     trace_dir = None
     if args.trace:
@@ -131,9 +134,9 @@ def main():
         "steady_epoch_s_cached_feed": round(cached_s, 4),
         "feed_overhead_share": round(feed_overhead, 4),
         "graphs_per_sec_production": round(n_graphs / real_s, 1),
-        "span_feed_wait_s": round(spans.acc.get("feed", 0.0), 4),
-        "span_train_dispatch_s": round(spans.acc.get("train_step", 0.0), 4),
-        "span_h2d_s": round(spans.acc.get("h2d", 0.0), 4),
+        "span_feed_wait_s": round(spans.get("feed_wait", 0.0), 4),
+        "span_train_dispatch_s": round(spans.get("device_step", 0.0), 4),
+        "span_h2d_s": round(spans.get("h2d", 0.0), 4),
         "pipeline_split_last_epoch": feed_split,
         "trace_dir": trace_dir,
     }

@@ -71,9 +71,14 @@ def environment_fingerprint() -> Dict[str, str]:
     Codegen-affecting environment (XLA_FLAGS, LIBTPU_INIT_ARGS, x64 mode)
     folds into the topology string: an executable compiled under different
     compiler flags must read as a MISS, exactly as JAX's own compilation
-    cache keys compile options (the bit-exact-vs-fresh-compile contract)."""
+    cache keys compile options (the bit-exact-vs-fresh-compile contract). So
+    does the version of the named-scope vocabulary (telemetry/scopes.py): the
+    names are in the executable's operation metadata, and one compiled under
+    other names would show them in every trace."""
     import jax
     import jaxlib
+
+    from ..telemetry import scopes
 
     devices = jax.devices()
     codegen = hashlib.sha256(
@@ -82,6 +87,7 @@ def environment_fingerprint() -> Dict[str, str]:
                 os.environ.get("XLA_FLAGS", ""),
                 os.environ.get("LIBTPU_INIT_ARGS", ""),
                 f"x64={bool(jax.config.jax_enable_x64)}",
+                f"scopes={scopes.VERSION}",
             )
         ).encode()
     ).hexdigest()[:12]

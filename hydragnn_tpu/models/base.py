@@ -16,12 +16,14 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
 from ..graphs.batch import GraphBatch
 from ..ops import pallas_segment
 from ..ops import segment as seg
+from ..telemetry import scopes
 from .layers import MLP, MaskedBatchNorm
 from .convs import CGConv, GATv2Conv, GINConv, MFCConv, PNAConv, SAGEConv
 
@@ -50,10 +52,11 @@ class MLPNode(nn.Module):
         n, f = x.shape
         # Node position within its graph: nodes are contiguous per graph by
         # collation, so pos = arange - start_of_my_graph.
-        counts = seg.segment_count(batch.node_graph, batch.num_graphs_pad)
-        starts = jnp.concatenate([jnp.zeros(1), jnp.cumsum(counts)[:-1]])
-        pos = (jnp.arange(n) - starts[batch.node_graph]).astype(jnp.int32)
-        pos = jnp.clip(pos, 0, self.num_nodes - 1)
+        with jax.named_scope(scopes.POOL):
+            counts = seg.segment_count(batch.node_graph, batch.num_graphs_pad)
+            starts = jnp.concatenate([jnp.zeros(1), jnp.cumsum(counts)[:-1]])
+            pos = (jnp.arange(n) - starts[batch.node_graph]).astype(jnp.int32)
+            pos = jnp.clip(pos, 0, self.num_nodes - 1)
         h = x
         in_dim = f
         for li, d in enumerate(dims):
@@ -61,7 +64,9 @@ class MLPNode(nn.Module):
                 f"w_{li}", nn.initializers.lecun_normal(), (self.num_nodes, in_dim, d)
             )
             b = self.param(f"b_{li}", nn.initializers.zeros, (self.num_nodes, d))
-            h = jnp.einsum("nf,nfo->no", h, w[pos]) + b[pos]
+            with jax.named_scope(scopes.GATHER):  # per-slot weight rows
+                w_n, b_n = w[pos], b[pos]
+            h = jnp.einsum("nf,nfo->no", h, w_n) + b_n
             if li < len(dims) - 1:
                 h = nn.relu(h)
             in_dim = d
@@ -308,10 +313,11 @@ class HydraGNN(nn.Module):
 
         # Masked global mean pool (Base.py:247-250); graph_ptr is the CSR
         # boundary array over node_graph (nodes are contiguous per graph).
-        x_graph = pallas_segment.fused_segment_mean(
-            x, batch.node_graph, batch.num_graphs_pad, mask=batch.node_mask,
-            sorted_ids=True, row_ptr=batch.graph_ptr,
-        )
+        with jax.named_scope(scopes.POOL):
+            x_graph = pallas_segment.fused_segment_mean(
+                x, batch.node_graph, batch.num_graphs_pad, mask=batch.node_mask,
+                sorted_ids=True, row_ptr=batch.graph_ptr,
+            )
 
         outputs = []
         inode = 0

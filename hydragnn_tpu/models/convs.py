@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from ..ops import pallas_segment
+from ..telemetry import scopes
 
 # Conv families whose aggregation rides the sorted/CSR edge layout end to end
 # (every family since PR 7 — GAT's sort-breaking [edges; self-loops] concat
@@ -46,7 +47,9 @@ class SAGEConv(nn.Module):
     @nn.compact
     def __call__(self, x, senders, receivers, edge_attr, edge_mask, node_mask, train=False, row_ptr=None):
         n = x.shape[0]
-        nbr = pallas_segment.fused_segment_mean(x[senders], receivers, n, mask=edge_mask, axis_name=self.axis_name, sorted_ids=True, row_ptr=row_ptr)
+        with jax.named_scope(scopes.GATHER):
+            x_j = x[senders]
+        nbr = pallas_segment.fused_segment_mean(x_j, receivers, n, mask=edge_mask, axis_name=self.axis_name, sorted_ids=True, row_ptr=row_ptr)
         return nn.Dense(self.out_dim, name="lin_nbr")(nbr) + nn.Dense(
             self.out_dim, name="lin_self"
         )(x)
@@ -64,7 +67,9 @@ class GINConv(nn.Module):
     def __call__(self, x, senders, receivers, edge_attr, edge_mask, node_mask, train=False, row_ptr=None):
         n = x.shape[0]
         eps = self.param("eps", nn.initializers.constant(self.eps_init), ())
-        agg = pallas_segment.fused_segment_sum(x[senders], receivers, n, mask=edge_mask, axis_name=self.axis_name, sorted_ids=True, row_ptr=row_ptr)
+        with jax.named_scope(scopes.GATHER):
+            x_j = x[senders]
+        agg = pallas_segment.fused_segment_sum(x_j, receivers, n, mask=edge_mask, axis_name=self.axis_name, sorted_ids=True, row_ptr=row_ptr)
         h = (1.0 + eps) * x + agg
         h = nn.Dense(self.out_dim, name="mlp_0")(h)
         h = nn.relu(h)
@@ -90,15 +95,21 @@ class MFCConv(nn.Module):
         )
         w_nbr = self.param("w_nbr", nn.initializers.lecun_normal(), (d, f, self.out_dim))
         b = self.param("bias", nn.initializers.zeros, (d, self.out_dim))
+        with jax.named_scope(scopes.GATHER):
+            x_j = x[senders]
         agg, deg_f = pallas_segment.fused_segment_sum_count(
-            x[senders], receivers, n, mask=edge_mask, axis_name=self.axis_name,
+            x_j, receivers, n, mask=edge_mask, axis_name=self.axis_name,
             sorted_ids=True, row_ptr=row_ptr,
         )
         deg = jnp.clip(deg_f.astype(jnp.int32), 0, self.max_degree)
-        out = jnp.einsum("nf,nfo->no", x, w_self[deg]) + jnp.einsum(
-            "nf,nfo->no", agg, w_nbr[deg]
+        # The degree-indexed weights are row gathers too (backward: scatter-
+        # adds into [d, f, o]).
+        with jax.named_scope(scopes.GATHER):
+            w_self_n, w_nbr_n, b_n = w_self[deg], w_nbr[deg], b[deg]
+        out = jnp.einsum("nf,nfo->no", x, w_self_n) + jnp.einsum(
+            "nf,nfo->no", agg, w_nbr_n
         )
-        return out + b[deg]
+        return out + b_n
 
 
 class GATv2Conv(nn.Module):
@@ -133,9 +144,9 @@ class GATv2Conv(nn.Module):
         x_dst = nn.Dense(h * f, name="lin_dst")(x).reshape(n, h, f)
 
         att = self.param("att", nn.initializers.lecun_normal(), (h, f))
-        pre = nn.leaky_relu(
-            x_src[senders] + x_dst[receivers], self.negative_slope
-        )  # [E, h, f]
+        with jax.named_scope(scopes.GATHER):
+            x_j, x_i = x_src[senders], x_dst[receivers]
+        pre = nn.leaky_relu(x_j + x_i, self.negative_slope)  # [E, h, f]
         logits = jnp.einsum("ehf,hf->eh", pre, att)  # [E, h]
         # Self term: the diagonal of the attention matrix, computed densely
         # (x_src[i] + x_dst[i] — no gather, no extra edges).
@@ -156,9 +167,9 @@ class GATv2Conv(nn.Module):
             axis_name=self.axis_name,
         )  # [N, h]
         m = jax.lax.stop_gradient(jnp.maximum(edge_max, logit_self))
-        exp_e = jnp.where(
-            edge_mask[:, None], jnp.exp(logits - m[receivers]), 0.0
-        )  # [E, h]
+        with jax.named_scope(scopes.GATHER):
+            m_e = m[receivers]
+        exp_e = jnp.where(edge_mask[:, None], jnp.exp(logits - m_e), 0.0)  # [E, h]
         exp_self = jnp.where(
             node_mask[:, None], jnp.exp(logit_self - m), 0.0
         )  # [N, h]
@@ -171,7 +182,9 @@ class GATv2Conv(nn.Module):
             exp_e, receivers, n, mask=edge_mask, axis_name=self.axis_name,
             sorted_ids=True, row_ptr=row_ptr,
         ) + exp_self
-        alpha = exp_e / jnp.maximum(denom[receivers], 1e-16)  # [E, h]
+        with jax.named_scope(scopes.GATHER):
+            denom_e = denom[receivers]
+        alpha = exp_e / jnp.maximum(denom_e, 1e-16)  # [E, h]
         alpha_self = exp_self / jnp.maximum(denom, 1e-16)  # [N, h]
         if train and self.dropout > 0.0:
             rng = self.make_rng("dropout")
@@ -184,7 +197,9 @@ class GATv2Conv(nn.Module):
             alpha_self = jnp.where(
                 keep[:n], alpha_self / (1.0 - self.dropout), 0.0
             )
-        msgs = x_src[senders] * alpha[..., None]  # [E, h, f]
+        with jax.named_scope(scopes.GATHER):
+            x_j = x_src[senders]  # gathered again, as before the scopes
+        msgs = x_j * alpha[..., None]  # [E, h, f]
         msgs = jnp.where(edge_mask[:, None, None], msgs, 0.0)
         out = pallas_segment.fused_segment_sum(
             msgs, receivers, n, axis_name=self.axis_name, sorted_ids=True,
@@ -211,7 +226,8 @@ class CGConv(nn.Module):
     @nn.compact
     def __call__(self, x, senders, receivers, edge_attr, edge_mask, node_mask, train=False, row_ptr=None):
         n, f = x.shape
-        z = [x[receivers], x[senders]]
+        with jax.named_scope(scopes.GATHER):
+            z = [x[receivers], x[senders]]
         if self.edge_dim and edge_attr is not None:
             z.append(edge_attr)
         z = jnp.concatenate(z, axis=-1)
@@ -244,7 +260,8 @@ class PNAConv(nn.Module):
     @nn.compact
     def __call__(self, x, senders, receivers, edge_attr, edge_mask, node_mask, train=False, row_ptr=None):
         n, f = x.shape
-        z = [x[receivers], x[senders]]
+        with jax.named_scope(scopes.GATHER):
+            z = [x[receivers], x[senders]]
         if self.edge_dim and edge_attr is not None:
             z.append(edge_attr)
         z = jnp.concatenate(z, axis=-1)

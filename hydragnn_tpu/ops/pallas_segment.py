@@ -53,6 +53,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import scopes
 from . import segment as seg
 from . import segment_sorted as srt
 
@@ -792,22 +793,27 @@ def fused_segment_stats(
     use_sorted, use_csr_kernel, row_ptr = _sorted_route(
         sorted_ids, row_ptr, axis_name, num_local_edges=segment_ids.shape[0]
     )
-    if use_sorted or use_csr_kernel:
-        # Sorted/CSR contract: zero masked rows, keep RAW (sorted) ids — a -1
-        # marker would break the non-decreasing order the path requires.
-        srt.attach_layout_check(ids)
+    # Neither route taken, this bundle runs the one-hot kernel whatever
+    # pallas_enabled() says: pna_aggregate asks first.
+    arm = _arm(use_sorted, use_csr_kernel, row_ptr, otherwise="pallas")
+    with scopes.agg_scope("stats", arm):
+        if use_sorted or use_csr_kernel:
+            # Sorted/CSR contract: zero masked rows, keep RAW (sorted) ids —
+            # a -1 marker would break the non-decreasing order the path
+            # requires.
+            srt.attach_layout_check(ids)
+            if mask is not None:
+                data = jnp.where(mask[:, None], data, 0)
+            return _stats(
+                data.astype(jnp.float32), ids, num_segments, eps, axis_name,
+                interpret, want_std, use_sorted, row_ptr,
+            )
         if mask is not None:
-            data = jnp.where(mask[:, None], data, 0)
+            ids = jnp.where(mask, ids, -1)
         return _stats(
-            data.astype(jnp.float32), ids, num_segments, eps, axis_name,
-            interpret, want_std, use_sorted, row_ptr,
+            data, ids, num_segments, eps, axis_name, interpret, want_std,
+            False, None,
         )
-    if mask is not None:
-        ids = jnp.where(mask, ids, -1)
-    return _stats(
-        data, ids, num_segments, eps, axis_name, interpret, want_std, False,
-        None,
-    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -816,11 +822,16 @@ def segment_extrema(data, ids, num_segments: int, axis_name: Optional[str] = Non
     to every row equal to its segment's extremum (the standard subgradient),
     avoiding XLA's scatter-heavy segment_min/max VJP on TPU. ``ids`` < 0 marks
     masked rows; empty segments yield 0."""
-    mask = ids >= 0
-    safe_ids = jnp.where(mask, ids, 0)
-    mn = seg.segment_min(data, safe_ids, num_segments, mask=mask, axis_name=axis_name)
-    mx = seg.segment_max(data, safe_ids, num_segments, mask=mask, axis_name=axis_name)
-    return mn, mx
+    # This IS the custom_vjp, so the scope is opened inside it and again in
+    # its backward: JAX traces both when it pleases, under the caller's name
+    # stack, and a scope round the call alone would be written twice wherever
+    # the forward is traced after that scope has closed (the scan step).
+    with scopes.agg_scope("extrema", "xla"):
+        mask = ids >= 0
+        safe_ids = jnp.where(mask, ids, 0)
+        mn = seg.segment_min(data, safe_ids, num_segments, mask=mask, axis_name=axis_name)
+        mx = seg.segment_max(data, safe_ids, num_segments, mask=mask, axis_name=axis_name)
+        return mn, mx
 
 
 def _extrema_fwd(data, ids, num_segments, axis_name):
@@ -831,15 +842,16 @@ def _extrema_fwd(data, ids, num_segments, axis_name):
 def _extrema_bwd(num_segments, axis_name, res, cots):
     data, ids, mn, mx = res
     d_mn, d_mx = cots
-    if axis_name is not None:
-        d_mn = jax.lax.psum(d_mn, axis_name)
-        d_mx = jax.lax.psum(d_mx, axis_name)
-    valid = (ids >= 0)[:, None]
-    idx = jnp.clip(ids, 0, num_segments - 1)
-    d_data = jnp.where(valid & (data == mn[idx]), d_mn[idx], 0.0) + jnp.where(
-        valid & (data == mx[idx]), d_mx[idx], 0.0
-    )
-    return d_data.astype(data.dtype), jnp.zeros(ids.shape, jax.dtypes.float0)
+    with scopes.agg_scope("extrema", "xla"):
+        if axis_name is not None:
+            d_mn = jax.lax.psum(d_mn, axis_name)
+            d_mx = jax.lax.psum(d_mx, axis_name)
+        valid = (ids >= 0)[:, None]
+        idx = jnp.clip(ids, 0, num_segments - 1)
+        d_data = jnp.where(valid & (data == mn[idx]), d_mn[idx], 0.0) + jnp.where(
+            valid & (data == mx[idx]), d_mx[idx], 0.0
+        )
+        return d_data.astype(data.dtype), jnp.zeros(ids.shape, jax.dtypes.float0)
 
 
 segment_extrema.defvjp(_extrema_fwd, _extrema_bwd)
@@ -1229,6 +1241,16 @@ def _sorted_route(sorted_ids: bool, row_ptr, axis_name, num_local_edges=None):
     return use_sorted, use_csr_kernel, row_ptr
 
 
+def _arm(use_sorted, use_csr_kernel, row_ptr, otherwise=None) -> str:
+    """The route as the scope names it (telemetry/scopes.py AGG_ARMS), from
+    what :func:`_sorted_route` resolved."""
+    if use_sorted:
+        return "csr" if row_ptr is not None else "sorted"
+    if use_csr_kernel:
+        return "pallas_csr"
+    return otherwise or ("pallas" if pallas_enabled() else "xla")
+
+
 def fused_segment_sum(
     data, segment_ids, num_segments: int, mask=None, axis_name=None,
     sorted_ids: bool = False, row_ptr=None,
@@ -1241,9 +1263,9 @@ def fused_segment_sum(
     see pallas_enabled for why the default is the XLA path since r05), the
     masked XLA segment op otherwise. Accepts any [E, ...] float data
     (trailing dims flattened for the kernel)."""
-    total, _ = fused_segment_sum_count(
-        data, segment_ids, num_segments, mask=mask, axis_name=axis_name,
-        sorted_ids=sorted_ids, row_ptr=row_ptr,
+    total, _ = _fused_sum_count(
+        "sum", data, segment_ids, num_segments, mask, axis_name, sorted_ids,
+        row_ptr,
     )
     return total
 
@@ -1262,51 +1284,63 @@ def fused_segment_sum_count(
     under that contract. ``row_ptr`` carries the contract's precomputed CSR
     boundaries (LOCALIZED per shard under ``axis_name`` — graftmesh's
     halo/edge-cut contract, see :func:`localize_row_ptr`)."""
+    return _fused_sum_count(
+        "sum_count", data, segment_ids, num_segments, mask, axis_name,
+        sorted_ids, row_ptr,
+    )
+
+
+def _fused_sum_count(
+    what, data, segment_ids, num_segments, mask, axis_name, sorted_ids, row_ptr
+):
+    """:func:`fused_segment_sum_count` under the scope of the entry point that
+    was called (``what``: sum, sum_count) and the arm the route resolved."""
     use_sorted, use_csr_kernel, row_ptr = _sorted_route(
         sorted_ids, row_ptr, axis_name, num_local_edges=segment_ids.shape[0]
     )
-    if use_sorted or use_csr_kernel:
-        # Sorted/CSR contract prep: zero masked rows, RAW (sorted) ids.
-        srt.attach_layout_check(segment_ids)
+    with scopes.agg_scope(what, _arm(use_sorted, use_csr_kernel, row_ptr)):
+        if use_sorted or use_csr_kernel:
+            # Sorted/CSR contract prep: zero masked rows, RAW (sorted) ids.
+            srt.attach_layout_check(segment_ids)
+            flat, unflatten = _flatten_trailing(data)
+            if mask is not None:
+                flat = jnp.where(mask[:, None], flat, 0)
+            if use_sorted:
+                total, count = srt.segment_sum_count_auto(
+                    flat.astype(jnp.float32), segment_ids.astype(jnp.int32),
+                    num_segments, row_ptr=row_ptr,
+                )
+                if axis_name is not None:
+                    total = jax.lax.psum(total, axis_name)
+                    count = jax.lax.psum(count, axis_name)
+            else:
+                # CSR run-walk kernel (HYDRAGNN_PALLAS opt-in, row_ptr present).
+                total, count = csr_segment_sum_count(
+                    flat.astype(jnp.float32), row_ptr,
+                    segment_ids.astype(jnp.int32), num_segments,
+                    _platform() != "tpu", _wants_split(flat.dtype),
+                )
+            return unflatten(total.astype(data.dtype)), count
+        if not pallas_enabled():
+            return (
+                seg.segment_sum(
+                    data, segment_ids, num_segments, mask=mask, axis_name=axis_name
+                ),
+                seg.segment_count(
+                    segment_ids, num_segments, mask=mask, axis_name=axis_name
+                ),
+            )
         flat, unflatten = _flatten_trailing(data)
+        ids = segment_ids.astype(jnp.int32)
         if mask is not None:
-            flat = jnp.where(mask[:, None], flat, 0)
-        if use_sorted:
-            total, count = srt.segment_sum_count_auto(
-                flat.astype(jnp.float32), segment_ids.astype(jnp.int32),
-                num_segments, row_ptr=row_ptr,
-            )
-            if axis_name is not None:
-                total = jax.lax.psum(total, axis_name)
-                count = jax.lax.psum(count, axis_name)
-        else:
-            # CSR run-walk kernel (HYDRAGNN_PALLAS opt-in, row_ptr present).
-            total, count = csr_segment_sum_count(
-                flat.astype(jnp.float32), row_ptr,
-                segment_ids.astype(jnp.int32), num_segments,
-                _platform() != "tpu", _wants_split(flat.dtype),
-            )
-        return unflatten(total.astype(data.dtype)), count
-    if not pallas_enabled():
-        return (
-            seg.segment_sum(
-                data, segment_ids, num_segments, mask=mask, axis_name=axis_name
-            ),
-            seg.segment_count(
-                segment_ids, num_segments, mask=mask, axis_name=axis_name
-            ),
+            ids = jnp.where(mask, ids, -1)
+        total, count = segment_sum_count(
+            flat, ids, num_segments, _platform() != "tpu", _wants_split(flat.dtype)
         )
-    flat, unflatten = _flatten_trailing(data)
-    ids = segment_ids.astype(jnp.int32)
-    if mask is not None:
-        ids = jnp.where(mask, ids, -1)
-    total, count = segment_sum_count(
-        flat, ids, num_segments, _platform() != "tpu", _wants_split(flat.dtype)
-    )
-    if axis_name is not None:
-        total = jax.lax.psum(total, axis_name)
-        count = jax.lax.psum(count, axis_name)
-    return unflatten(total.astype(data.dtype)), count
+        if axis_name is not None:
+            total = jax.lax.psum(total, axis_name)
+            count = jax.lax.psum(count, axis_name)
+        return unflatten(total.astype(data.dtype)), count
 
 
 def fused_segment_mean(
@@ -1319,29 +1353,30 @@ def fused_segment_mean(
     # Route decision only — the UN-localized row_ptr forwards to
     # fused_segment_sum_count, which performs the per-shard localization
     # itself (localizing here too would shift the boundaries twice).
-    use_sorted, use_csr_kernel, _ = _sorted_route(
+    use_sorted, use_csr_kernel, local_ptr = _sorted_route(
         sorted_ids, row_ptr, axis_name, num_local_edges=segment_ids.shape[0]
     )
-    if use_sorted or use_csr_kernel:
+    with scopes.agg_scope("mean", _arm(use_sorted, use_csr_kernel, local_ptr)):
+        if use_sorted or use_csr_kernel:
+            total, count = fused_segment_sum_count(
+                data, segment_ids, num_segments, mask=mask, axis_name=axis_name,
+                sorted_ids=True, row_ptr=row_ptr,
+            )
+            safe = jnp.maximum(count, 1.0).reshape(
+                count.shape + (1,) * (total.ndim - count.ndim)
+            )
+            return (total / safe).astype(data.dtype)
+        if not pallas_enabled():
+            return seg.segment_mean(
+                data, segment_ids, num_segments, mask=mask, axis_name=axis_name
+            ).astype(data.dtype)
         total, count = fused_segment_sum_count(
-            data, segment_ids, num_segments, mask=mask, axis_name=axis_name,
-            sorted_ids=True, row_ptr=row_ptr,
+            data, segment_ids, num_segments, mask=mask, axis_name=axis_name
         )
         safe = jnp.maximum(count, 1.0).reshape(
             count.shape + (1,) * (total.ndim - count.ndim)
         )
         return (total / safe).astype(data.dtype)
-    if not pallas_enabled():
-        return seg.segment_mean(
-            data, segment_ids, num_segments, mask=mask, axis_name=axis_name
-        ).astype(data.dtype)
-    total, count = fused_segment_sum_count(
-        data, segment_ids, num_segments, mask=mask, axis_name=axis_name
-    )
-    safe = jnp.maximum(count, 1.0).reshape(
-        count.shape + (1,) * (total.ndim - count.ndim)
-    )
-    return (total / safe).astype(data.dtype)
 
 
 def fused_segment_softmax(
@@ -1361,7 +1396,7 @@ def fused_segment_softmax(
     (models/convs.py:GATv2Conv). This stays the entry point for plain
     edge-only segment softmaxes; ``sorted_ids``/``row_ptr`` declare the CSR
     batch contract for the denominator sum."""
-    use_sorted, use_csr_kernel, _ = _sorted_route(
+    use_sorted, use_csr_kernel, local_ptr = _sorted_route(
         sorted_ids, row_ptr, axis_name, num_local_edges=segment_ids.shape[0]
     )
     use_fast = pallas_enabled() or use_sorted or use_csr_kernel
@@ -1372,10 +1407,12 @@ def fused_segment_softmax(
                 d, i, n, mask=mask, axis_name=axis_name,
                 sorted_ids=sorted_ids, row_ptr=row_ptr,
             )
-    return seg.segment_softmax(
-        logits, segment_ids, num_segments, mask=mask, axis_name=axis_name,
-        sum_fn=sum_fn,
-    )
+    # The arm is the denominator sum's; the max is XLA's on every arm.
+    with scopes.agg_scope("softmax", _arm(use_sorted, use_csr_kernel, local_ptr)):
+        return seg.segment_softmax(
+            logits, segment_ids, num_segments, mask=mask, axis_name=axis_name,
+            sum_fn=sum_fn,
+        )
 
 
 def pna_aggregate(
@@ -1395,43 +1432,44 @@ def pna_aggregate(
     Pallas kernel when enabled; min/max always via XLA segment extrema.
     Falls back entirely to the masked XLA segment ops otherwise.
     """
-    n = num_segments
-    use_sorted = sorted_ids and srt.sorted_enabled()
-    if pallas_enabled() or use_sorted:
-        fused = {}
-        count = None
-        if any(a in ("mean", "std", "sum") for a in aggregators):
-            total, mean, std, count = fused_segment_stats(
-                msg, receivers, n, mask=mask, axis_name=axis_name,
-                want_std="std" in aggregators, sorted_ids=sorted_ids,
-                row_ptr=row_ptr,
-            )
-            fused = {"mean": mean, "std": std, "sum": total}
-        if "min" in aggregators or "max" in aggregators:
-            ids = receivers.astype(jnp.int32)
-            if mask is not None:
-                ids = jnp.where(mask, ids, -1)
-            mn, mx = segment_extrema(msg, ids, n, axis_name)
-            fused["min"], fused["max"] = mn, mx
-    else:
-        fused = {}
-        count = None
-    aggs = []
-    for a in aggregators:
-        if a in fused:
-            aggs.append(fused[a])
-        elif a == "mean":
-            aggs.append(seg.segment_mean(msg, receivers, n, mask=mask, axis_name=axis_name))
-        elif a == "sum":
-            aggs.append(seg.segment_sum(msg, receivers, n, mask=mask, axis_name=axis_name))
-        elif a == "std":
-            aggs.append(seg.segment_std(msg, receivers, n, mask=mask, axis_name=axis_name))
-        elif a == "min":
-            aggs.append(seg.segment_min(msg, receivers, n, mask=mask, axis_name=axis_name))
-        elif a == "max":
-            aggs.append(seg.segment_max(msg, receivers, n, mask=mask, axis_name=axis_name))
+    with jax.named_scope(scopes.AGG_PNA):
+        n = num_segments
+        use_sorted = sorted_ids and srt.sorted_enabled()
+        if pallas_enabled() or use_sorted:
+            fused = {}
+            count = None
+            if any(a in ("mean", "std", "sum") for a in aggregators):
+                total, mean, std, count = fused_segment_stats(
+                    msg, receivers, n, mask=mask, axis_name=axis_name,
+                    want_std="std" in aggregators, sorted_ids=sorted_ids,
+                    row_ptr=row_ptr,
+                )
+                fused = {"mean": mean, "std": std, "sum": total}
+            if "min" in aggregators or "max" in aggregators:
+                ids = receivers.astype(jnp.int32)
+                if mask is not None:
+                    ids = jnp.where(mask, ids, -1)
+                mn, mx = segment_extrema(msg, ids, n, axis_name)
+                fused["min"], fused["max"] = mn, mx
         else:
-            raise ValueError(f"Unknown aggregator {a}")
-    if count is None:
-        count = seg.segment_count(receivers, n, mask=mask, axis_name=axis_name)
-    return jnp.stack(aggs, axis=1), count
+            fused = {}
+            count = None
+        aggs = []
+        for a in aggregators:
+            if a in fused:
+                aggs.append(fused[a])
+            elif a == "mean":
+                aggs.append(seg.segment_mean(msg, receivers, n, mask=mask, axis_name=axis_name))
+            elif a == "sum":
+                aggs.append(seg.segment_sum(msg, receivers, n, mask=mask, axis_name=axis_name))
+            elif a == "std":
+                aggs.append(seg.segment_std(msg, receivers, n, mask=mask, axis_name=axis_name))
+            elif a == "min":
+                aggs.append(seg.segment_min(msg, receivers, n, mask=mask, axis_name=axis_name))
+            elif a == "max":
+                aggs.append(seg.segment_max(msg, receivers, n, mask=mask, axis_name=axis_name))
+            else:
+                raise ValueError(f"Unknown aggregator {a}")
+        if count is None:
+            count = seg.segment_count(receivers, n, mask=mask, axis_name=axis_name)
+        return jnp.stack(aggs, axis=1), count

@@ -22,6 +22,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.scopes import agg_scope
+
 _BIG = 1e30
 
 # Platform the op-gating decisions see (fused-kernel and sorted-path
@@ -70,12 +72,13 @@ def segment_sum(
     mask: Optional[jnp.ndarray] = None,
     axis_name: Optional[str] = None,
 ) -> jnp.ndarray:
-    if mask is not None:
-        data = jnp.where(_expand(mask, data), data, 0)
-    out = jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
-    if axis_name is not None:
-        out = jax.lax.psum(out, axis_name)
-    return out
+    with agg_scope("sum", "xla"):
+        if mask is not None:
+            data = jnp.where(_expand(mask, data), data, 0)
+        out = jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
+        if axis_name is not None:
+            out = jax.lax.psum(out, axis_name)
+        return out
 
 
 def segment_count(
@@ -84,10 +87,11 @@ def segment_count(
     mask: Optional[jnp.ndarray] = None,
     axis_name: Optional[str] = None,
 ) -> jnp.ndarray:
-    ones = jnp.ones(segment_ids.shape[0], dtype=jnp.float32)
-    if mask is not None:
-        ones = jnp.where(mask, ones, 0.0)
-    return segment_sum(ones, segment_ids, num_segments, axis_name=axis_name)
+    with agg_scope("count", "xla"):
+        ones = jnp.ones(segment_ids.shape[0], dtype=jnp.float32)
+        if mask is not None:
+            ones = jnp.where(mask, ones, 0.0)
+        return segment_sum(ones, segment_ids, num_segments, axis_name=axis_name)
 
 
 def segment_mean(
@@ -97,11 +101,12 @@ def segment_mean(
     mask: Optional[jnp.ndarray] = None,
     axis_name: Optional[str] = None,
 ) -> jnp.ndarray:
-    total = segment_sum(data, segment_ids, num_segments, mask, axis_name)
-    count = segment_count(segment_ids, num_segments, mask, axis_name)
-    return total / jnp.maximum(count, 1.0).reshape(
-        count.shape + (1,) * (total.ndim - count.ndim)
-    )
+    with agg_scope("mean", "xla"):
+        total = segment_sum(data, segment_ids, num_segments, mask, axis_name)
+        count = segment_count(segment_ids, num_segments, mask, axis_name)
+        return total / jnp.maximum(count, 1.0).reshape(
+            count.shape + (1,) * (total.ndim - count.ndim)
+        )
 
 
 def segment_max(
@@ -112,14 +117,16 @@ def segment_max(
     fill: float = 0.0,
     axis_name: Optional[str] = None,
 ) -> jnp.ndarray:
-    if mask is not None:
-        data = jnp.where(_expand(mask, data), data, -_BIG)
-    out = jax.ops.segment_max(data, segment_ids, num_segments=num_segments)
-    if axis_name is not None:
-        out = _pmax(out, axis_name)
-    # Empty segments come back as -inf/-BIG: replace with `fill` so downstream
-    # matmuls stay finite (isolated nodes have no incoming messages).
-    return jnp.where(out <= -_BIG / 2, fill, out)
+    with agg_scope("extrema", "xla"):
+        if mask is not None:
+            data = jnp.where(_expand(mask, data), data, -_BIG)
+        out = jax.ops.segment_max(data, segment_ids, num_segments=num_segments)
+        if axis_name is not None:
+            out = _pmax(out, axis_name)
+        # Empty segments come back as -inf/-BIG: replace with `fill` so
+        # downstream matmuls stay finite (isolated nodes have no incoming
+        # messages).
+        return jnp.where(out <= -_BIG / 2, fill, out)
 
 
 def segment_min(
@@ -130,12 +137,13 @@ def segment_min(
     fill: float = 0.0,
     axis_name: Optional[str] = None,
 ) -> jnp.ndarray:
-    if mask is not None:
-        data = jnp.where(_expand(mask, data), data, _BIG)
-    out = jax.ops.segment_min(data, segment_ids, num_segments=num_segments)
-    if axis_name is not None:
-        out = _pmin(out, axis_name)
-    return jnp.where(out >= _BIG / 2, fill, out)
+    with agg_scope("extrema", "xla"):
+        if mask is not None:
+            data = jnp.where(_expand(mask, data), data, _BIG)
+        out = jax.ops.segment_min(data, segment_ids, num_segments=num_segments)
+        if axis_name is not None:
+            out = _pmin(out, axis_name)
+        return jnp.where(out >= _BIG / 2, fill, out)
 
 
 def segment_std(
@@ -148,12 +156,13 @@ def segment_std(
 ) -> jnp.ndarray:
     """Per-segment standard deviation, sqrt(relu(E[x^2]-E[x]^2) + eps) like PyG's
     PNA 'std' aggregator (uses a small eps for a finite gradient at zero)."""
-    mean = segment_mean(data, segment_ids, num_segments, mask, axis_name)
-    mean_sq = segment_mean(
-        jnp.square(data), segment_ids, num_segments, mask, axis_name
-    )
-    var = jax.nn.relu(mean_sq - jnp.square(mean))
-    return jnp.sqrt(var + eps)
+    with agg_scope("stats", "xla"):
+        mean = segment_mean(data, segment_ids, num_segments, mask, axis_name)
+        mean_sq = segment_mean(
+            jnp.square(data), segment_ids, num_segments, mask, axis_name
+        )
+        var = jax.nn.relu(mean_sq - jnp.square(mean))
+        return jnp.sqrt(var + eps)
 
 
 def segment_softmax(
@@ -172,29 +181,30 @@ def segment_softmax(
     ``sum_fn(data, ids, n, mask=, axis_name=)`` overrides the denominator's
     segment sum (must return the globally-reduced sum) — the hook the fused
     Pallas kernel plugs into so both paths share ONE stabilization body."""
-    if mask is not None:
-        logits = jnp.where(_expand(mask, logits), logits, -_BIG)
-    seg_max = jax.ops.segment_max(logits, segment_ids, num_segments=num_segments)
-    if axis_name is not None:
-        seg_max = _pmax(seg_max, axis_name)
-    seg_max = jnp.where(seg_max <= -_BIG / 2, 0.0, seg_max)
-    # Softmax is shift-invariant, so the max is analytically a constant:
-    # stop_gradient gives the identical gradient while skipping
-    # segment_max's scatter-heavy TPU VJP (jax.nn.softmax does the same).
-    seg_max = jax.lax.stop_gradient(seg_max)
-    shifted = logits - seg_max[segment_ids]
-    exp = jnp.exp(shifted)
-    if mask is not None:
-        exp = jnp.where(_expand(mask, exp), exp, 0.0)
-    if sum_fn is not None:
-        denom = sum_fn(
-            exp, segment_ids, num_segments, mask=mask, axis_name=axis_name
-        )
-    else:
-        denom = jax.ops.segment_sum(exp, segment_ids, num_segments=num_segments)
+    with agg_scope("softmax", "xla"):
+        if mask is not None:
+            logits = jnp.where(_expand(mask, logits), logits, -_BIG)
+        seg_max = jax.ops.segment_max(logits, segment_ids, num_segments=num_segments)
         if axis_name is not None:
-            denom = jax.lax.psum(denom, axis_name)
-    return exp / jnp.maximum(denom[segment_ids], 1e-16)
+            seg_max = _pmax(seg_max, axis_name)
+        seg_max = jnp.where(seg_max <= -_BIG / 2, 0.0, seg_max)
+        # Softmax is shift-invariant, so the max is analytically a constant:
+        # stop_gradient gives the identical gradient while skipping
+        # segment_max's scatter-heavy TPU VJP (jax.nn.softmax does the same).
+        seg_max = jax.lax.stop_gradient(seg_max)
+        shifted = logits - seg_max[segment_ids]
+        exp = jnp.exp(shifted)
+        if mask is not None:
+            exp = jnp.where(_expand(mask, exp), exp, 0.0)
+        if sum_fn is not None:
+            denom = sum_fn(
+                exp, segment_ids, num_segments, mask=mask, axis_name=axis_name
+            )
+        else:
+            denom = jax.ops.segment_sum(exp, segment_ids, num_segments=num_segments)
+            if axis_name is not None:
+                denom = jax.lax.psum(denom, axis_name)
+        return exp / jnp.maximum(denom[segment_ids], 1e-16)
 
 
 def masked_mean(data: jnp.ndarray, mask: jnp.ndarray, axis=None) -> jnp.ndarray:

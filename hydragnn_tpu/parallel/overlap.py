@@ -40,6 +40,8 @@ from typing import Any, Callable, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import scopes
+
 GRAD_SYNC_MODES = ("single", "bucketed", "ring")
 DEFAULT_BUCKET_MB = 4.0
 
@@ -156,7 +158,9 @@ def attach_grad_sync(
     out = list(leaves)
     for bucket in plan:
         sync = _make_bucket_sync(reduce_fn)
-        synced = sync(tuple(out[i] for i in bucket))
+        # The hook's backward (the collective) takes this call's name stack.
+        with jax.named_scope(scopes.GRAD_SYNC):
+            synced = sync(tuple(out[i] for i in bucket))
         for j, i in enumerate(bucket):
             out[i] = synced[j]
     return jax.tree_util.tree_unflatten(treedef, out)

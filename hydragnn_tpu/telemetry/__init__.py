@@ -17,6 +17,7 @@ synthetic train and schema-validates every exporter (the CI smoke step);
 
 from __future__ import annotations
 
+from . import scopes
 from .export import (
     export_chrome_trace,
     export_events_jsonl,
@@ -46,6 +47,7 @@ from .graftel import (
     gauge,
     gauges_snapshot,
     install_jax_hooks,
+    jax_annotations,
     new_context,
     new_request_id,
     record_span,
@@ -79,11 +81,13 @@ __all__ = [
     "gauge",
     "gauges_snapshot",
     "install_jax_hooks",
+    "jax_annotations",
     "new_context",
     "new_request_id",
     "record_span",
     "render_prometheus",
     "reset",
+    "scopes",
     "snapshot_records",
     "span",
     "span_counts",

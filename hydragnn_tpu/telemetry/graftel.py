@@ -42,7 +42,9 @@ is the hub they all emit into:
 
 Zero-surprise defaults: the ring and registry are always live (host-side,
 one uncontended lock acquisition per record — measured < 2% of a steady CPU
-train epoch, ``bench.py --trace``); full span COLLECTION for the JSONL /
+train epoch by ``bench.py --trace``, a CPU count in ``TRACE_r06.json``; on the
+chip the traced-against-untraced distance of every benchmark cell is in
+PERF.md §6); full span COLLECTION for the JSONL /
 Chrome-trace exporters is opt-in (``configure(collect=True)``, the
 ``Telemetry`` config block, or ``HYDRAGNN_TRACE=1``). ``enabled=False``
 silences span/event recording entirely while keeping the counter registry
@@ -385,6 +387,13 @@ def configured_run_dir() -> Optional[str]:
 def collecting() -> bool:
     with _lock:
         return _collected is not None
+
+
+def jax_annotations() -> bool:
+    """Whether spans open a ``jax.profiler.TraceAnnotation`` (the bridge
+    ``utils/profile.Profiler`` switches on while its trace is open)."""
+    with _lock:
+        return _jax_annotations
 
 
 def reset(keep_config: bool = False) -> None:

@@ -1,0 +1,85 @@
+"""The one vocabulary of ``jax.named_scope`` names the compiled programs carry
+(docs/OBSERVABILITY.md, "Device time by scope").
+
+A named scope is operation metadata: it emits no instruction, and with the
+profiler off it costs nothing. With it on, XLA writes each operation's
+``op_name`` — ``jit(step)/jvp(hydragnn.train_step)/HydraGNN/conv_1/
+hydragnn.agg.stats.csr/...`` — into the ``tf_op`` stat of that operation's
+event metadata in the trace, where ``graftbench/xplane_scopes.py`` reads it.
+flax writes the module path (``conv_1``, ``bn_0``, ``head_2``) itself, and
+differentiation wraps the path in ``jvp(...)`` / ``transpose(...)``; so the
+names here say only what neither does: which program this is, and which
+operations are edge gathers, segment reductions (and by which arm), the
+read-out, the loss, the optimizer and the gradient all-reduce.
+
+All names are ``hydragnn.<layer>[.<what>[.<arm>]]``. Every site imports its
+name from here; ``tests/test_scopes.py`` holds the compiled programs to this
+table.
+"""
+
+from __future__ import annotations
+
+import contextlib
+from contextvars import ContextVar
+
+# Bump when a name changes meaning. It is folded into the compile-cache keys
+# (cache/jaxcache.py, cache/graftcache) because JAX leaves operation metadata
+# out of them: an executable cached under the old names would otherwise be
+# served for the new program, scopes and all.
+VERSION = 1
+
+# Roots: which program an operation belongs to.
+TRAIN_STEP = "hydragnn.train_step"
+TRAIN_EPOCH_SCAN = "hydragnn.train_epoch_scan"
+EVAL_STEP = "hydragnn.eval_step"
+ROOTS = (TRAIN_STEP, TRAIN_EPOCH_SCAN, EVAL_STEP)
+
+# Leaves.
+GATHER = "hydragnn.gather"  # node -> edge row gathers (backward: scatter-adds)
+POOL = "hydragnn.pool"  # graph read-out
+LOSS = "hydragnn.loss"
+OPTIMIZER = "hydragnn.optimizer"  # update, apply, loss-scale and guard selects
+GRAD_SYNC = "hydragnn.grad_sync"  # the mesh step's psums of gradients/counts
+AGG_PNA = "hydragnn.agg.pna"  # PNA's bundle; its stats/extrema nest inside
+
+AGG_WHATS = ("sum", "count", "sum_count", "mean", "stats", "extrema", "softmax")
+# The route Python took at trace time: masked XLA segment ops, the sorted
+# prefix path with searched or with precomputed (CSR) boundaries, the one-hot
+# Pallas kernel, the CSR run-walk Pallas kernel.
+AGG_ARMS = ("xla", "sorted", "csr", "pallas", "pallas_csr")
+
+
+def agg(what: str, arm: str) -> str:
+    if what not in AGG_WHATS or arm not in AGG_ARMS:
+        raise ValueError(f"not in the scope vocabulary: agg {what!r} {arm!r}")
+    return f"hydragnn.agg.{what}.{arm}"
+
+
+VOCABULARY = frozenset(
+    ROOTS
+    + (GATHER, POOL, LOSS, OPTIMIZER, GRAD_SYNC, AGG_PNA)
+    + tuple(agg(w, a) for w in AGG_WHATS for a in AGG_ARMS)
+)
+
+_IN_AGG: ContextVar[bool] = ContextVar("hydragnn_agg_scope_open", default=False)
+
+
+@contextlib.contextmanager
+def agg_scope(what: str, arm: str):
+    """``hydragnn.agg.<what>.<arm>`` round a segment reduction's entry point.
+    The entry points call each other (``fused_segment_sum`` is
+    ``fused_segment_sum_count``'s first output, ``segment_mean`` is a sum over
+    a count): the OUTERMOST one, the one a conv called, names the operations,
+    and a nested one adds nothing. A ``custom_vjp``'s backward is traced after
+    this context has closed, and JAX gives it the call site's name stack."""
+    if _IN_AGG.get():
+        yield
+        return
+    import jax  # lazily, like graftel: the telemetry package imports no jax
+
+    token = _IN_AGG.set(True)
+    try:
+        with jax.named_scope(agg(what, arm)):
+            yield
+    finally:
+        _IN_AGG.reset(token)

@@ -205,18 +205,17 @@ class FeedStats:
             self.feed_wait_s = 0.0  # guarded-by: self._lock
             self.step_s = 0.0  # guarded-by: self._lock
 
-    def record_h2d(self, nbytes: int, seconds: float):
+    def record_h2d(self, nbytes: int, seconds: float) -> int:
+        """Add one transfer; returns its number in the epoch. The graftel
+        ``h2d`` span is the caller's (``TrainingDriver._put_timed``), open
+        while the transfer runs, so it is an event of a captured trace too."""
         with self._lock:
             self.h2d_bytes += int(nbytes)
             self.h2d_s += seconds
             self.h2d_transfers += 1
             idx = self.h2d_transfers
             tsan.shared_access("FeedStats.fields")
-        # graftel emitter (docs/OBSERVABILITY.md): the transfer thread's wire
-        # time becomes a retroactive "h2d" span, parented to the epoch
-        # context the DeviceFeed attached to this thread — the flight
-        # recorder's per-batch H2D timeline.
-        telemetry.record_span("h2d", seconds, index=idx, bytes=int(nbytes))
+        return idx
 
     def credit(self, field: str, seconds: float) -> None:
         """Add consumer-side seconds to ``feed_wait_s``/``step_s`` (the

@@ -480,20 +480,21 @@ class TrainingDriver:
             return iterable
         return self.fault_plan.wrap_batches(iterable)
 
-    def _put_timed(self, payload, prof=None):
+    def _put_timed(self, payload):
         """The transfer stage: ONE blocking device_put per payload, on the
         pipeline's transfer thread. Batch k+1 commits (DMA) while step k
         computes; blocking here records true wire seconds, not dispatch.
         Transient failures (including the fault plan's injected transfer
         crashes, consulted here) are retried by the DeviceFeed's backoff
-        wrapper around this function."""
+        wrapper around this function. The wire time is the graftel ``h2d``
+        span, parented to the epoch context the DeviceFeed attached to this
+        thread (a host event of the trace when one is open), and the same
+        seconds are what ``FeedStats`` adds up."""
         if self.fault_plan is not None:
             self.fault_plan.on_transfer()
-        span = (
-            prof.annotate("h2d") if prof is not None else contextlib.nullcontext()
-        )
-        t0 = time.perf_counter()
-        with span:
+        nbytes = self._tree_nbytes(payload)
+        with telemetry.span("h2d", bytes=int(nbytes)) as span:
+            t0 = time.perf_counter()
             if self.multihost:
                 dev = self._lift(payload)
             elif self.mesh is not None:
@@ -501,9 +502,9 @@ class TrainingDriver:
             else:
                 dev = jax.device_put(payload)
             jax.block_until_ready(dev)
-        self.feed_stats.record_h2d(
-            self._tree_nbytes(payload), time.perf_counter() - t0
-        )
+            span.attrs["index"] = self.feed_stats.record_h2d(
+                nbytes, time.perf_counter() - t0
+            )
         return dev
 
     def _put_chunk(self, item):
@@ -599,7 +600,6 @@ class TrainingDriver:
             if self.mesh is None and not (profiler and profiler.active):
                 return self._train_epoch_scan(loader, ep.ctx)
             metrics = EpochMetrics()
-            prof = profiler or Profiler()
             # Two-stage device feed: collation thread -> transfer thread
             # (device_put with the step's placement) -> this consumer. Batch
             # k+1 is committed device memory while step k executes.
@@ -609,24 +609,24 @@ class TrainingDriver:
                 )
                 if self.mesh is not None
                 else traced_batches(self._wrap_faults(iter(loader))),
-                transfer=lambda b: self._put_timed(b, prof),
+                transfer=self._put_timed,
                 ctx=ep.ctx,
             )
             batch_iter = iter(iterate_tqdm(batches, self.verbosity))
             bi = 0
             try:
                 while True:
-                    # "feed" covers batch ACQUISITION (the device-queue wait
-                    # — where an input-bound pipeline actually stalls);
+                    # "feed_wait" covers batch ACQUISITION (the device-queue
+                    # wait — where an input-bound pipeline actually stalls);
                     # collation, the multi-host lift, and the H2D transfer
                     # all already happened on the pipeline threads.
-                    with prof.annotate("feed"), timed_consume(
+                    with telemetry.span("feed_wait"), timed_consume(
                         self.feed_stats, "feed_wait_s"
                     ):
                         batch = next(batch_iter, None)
                     if batch is None:
                         break
-                    with prof.annotate("train_step"), telemetry.span(
+                    with telemetry.span(
                         "device_step", index=bi
                     ), timed_consume(self.feed_stats, "step_s"):
                         self.state, m = self._dispatch(
@@ -820,16 +820,15 @@ class TrainingDriver:
         return sink
 
     # ------------------------------------------------------------------- eval
-    def evaluate(self, loader, return_values: bool = False, profiler=None):
+    def evaluate(self, loader, return_values: bool = False):
         """validate()/test() analog. With return_values, also gathers per-head
         (true, predicted) arrays over real rows (test(), reference
         train_validate_test.py:267-304)."""
         with telemetry.span("evaluate") as ep:
-            return self._evaluate(loader, return_values, profiler, ep.ctx)
+            return self._evaluate(loader, return_values, ep.ctx)
 
-    def _evaluate(self, loader, return_values, profiler, ctx=None):
+    def _evaluate(self, loader, return_values, ctx=None):
         self.feed_stats.reset()
-        prof = profiler or Profiler()
         metrics = EpochMetrics()
         num_heads = len(self.model.output_dim)
         true_values: List[List[np.ndarray]] = [[] for _ in range(num_heads)]
@@ -872,7 +871,7 @@ class TrainingDriver:
             cached = None
         if cached is not None and cached.get("batches") is not None:
             for ei, (host_b, dev_b) in enumerate(cached["batches"]):
-                with prof.annotate("eval_step"), telemetry.span(
+                with telemetry.span(
                     "eval_step", index=ei, cached=True
                 ), timed_consume(self.feed_stats, "step_s"):
                     m, outputs = self._dispatch(
@@ -899,12 +898,21 @@ class TrainingDriver:
             # batch, cache build included.
             batches = DeviceFeed(
                 self._device_groups(loader) if self.mesh is not None else iter(loader),
-                transfer=lambda b: (b, self._put_timed(b, prof)),
+                transfer=lambda b: (b, self._put_timed(b)),
                 ctx=ctx,
             )
+            batch_iter = iter(batches)
+            ei = 0
             try:
-                for ei, (batch, dev_b) in enumerate(batches):
-                    with prof.annotate("eval_step"), telemetry.span(
+                while True:
+                    with telemetry.span("feed_wait"), timed_consume(
+                        self.feed_stats, "feed_wait_s"
+                    ):
+                        item = next(batch_iter, None)
+                    if item is None:
+                        break
+                    batch, dev_b = item
+                    with telemetry.span(
                         "eval_step", index=ei
                     ), timed_consume(self.feed_stats, "step_s"):
                         m, outputs = self._dispatch(
@@ -922,6 +930,7 @@ class TrainingDriver:
                             sink["bytes"] += nbytes
                         else:
                             sink = None
+                    ei += 1
             finally:
                 self._drain_feed(batches, "eval")
             if cacheable:
@@ -1016,8 +1025,8 @@ def train_validate_test(
             train_loss, train_rmses = driver.train_epoch(train_loader, profiler)
             train_wall_s = time.perf_counter() - t_epoch0
             train_split = driver.feed_stats.as_dict()
-            val_loss, val_rmses = driver.evaluate(val_loader, profiler=profiler)
-            test_loss, test_rmses = driver.evaluate(test_loader, profiler=profiler)
+            val_loss, val_rmses = driver.evaluate(val_loader)
+            test_loss, test_rmses = driver.evaluate(test_loader)
 
             # Per-epoch training gauges (rendered by telemetry.
             # render_prometheus; served by /metrics in a co-resident serve

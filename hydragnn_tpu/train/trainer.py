@@ -30,6 +30,7 @@ from ..graphs.batch import GraphBatch
 from ..models.base import HydraGNN
 from ..models.loss import multihead_rmse_loss
 from ..ops.pallas_segment import pallas_platform
+from ..telemetry import scopes
 
 
 def _mesh_platform(mesh) -> str:
@@ -105,9 +106,10 @@ def _loss_and_metrics(model: HydraGNN, params, batch_stats, batch, dropout_key):
         mutable=["batch_stats"],
         rngs={"dropout": dropout_key},
     )
-    loss, rmses = multihead_rmse_loss(
-        outputs, batch, model.output_type, model.task_weights
-    )
+    with jax.named_scope(scopes.LOSS):
+        loss, rmses = multihead_rmse_loss(
+            outputs, batch, model.output_type, model.task_weights
+        )
     return loss, (mut["batch_stats"], rmses)
 
 
@@ -188,46 +190,47 @@ def _step_body(
             has_aux=True,
         )
         (loss, (new_bstats, rmses)), grads = grad_fn(state.params)
-        if needs_value_fn:
-            # LBFGS zoom linesearch: update() re-evaluates the loss along the
-            # search direction via value_fn (deterministic eval — same batch,
-            # same dropout key).
-            def value_fn(p):
-                return _loss_and_metrics(
-                    model, p, state.batch_stats, batch, dropout_key
-                )[0]
+        with jax.named_scope(scopes.OPTIMIZER):
+            if needs_value_fn:
+                # LBFGS zoom linesearch: update() re-evaluates the loss along the
+                # search direction via value_fn (deterministic eval — same batch,
+                # same dropout key).
+                def value_fn(p):
+                    return _loss_and_metrics(
+                        model, p, state.batch_stats, batch, dropout_key
+                    )[0]
 
-            updates, new_opt = optimizer.update(
-                grads,
-                state.opt_state,
-                state.params,
-                value=loss,
-                grad=grads,
-                value_fn=value_fn,
+                updates, new_opt = optimizer.update(
+                    grads,
+                    state.opt_state,
+                    state.params,
+                    value=loss,
+                    grad=grads,
+                    value_fn=value_fn,
+                )
+            else:
+                updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+            new_params = jax.tree_util.tree_map(
+                lambda p, u: p + u, state.params, updates
             )
-        else:
-            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = jax.tree_util.tree_map(
-            lambda p, u: p + u, state.params, updates
-        )
-        count = batch.count_real_graphs().astype(jnp.float32)
-        if guard:
-            ok = _all_finite(loss, grads)
-            new_params = _keep_if(ok, new_params, state.params)
-            new_opt = _keep_if(ok, new_opt, state.opt_state)
-            new_bstats = _keep_if(ok, new_bstats, state.batch_stats)
-            okf = ok.astype(jnp.float32)
-            count = count * okf
-            # Zero the VALUES before weighting: NaN * 0 is NaN, so a bad
-            # step's loss must be selected away, not merely zero-weighted.
-            metrics = {
-                "loss": jnp.where(ok, loss, 0.0) * count,
-                "rmses": jnp.where(ok, rmses, jnp.zeros_like(rmses)) * count,
-                "count": count,
-                "bad": 1.0 - okf,
-            }
-        else:
-            metrics = {"loss": loss * count, "rmses": rmses * count, "count": count}
+            count = batch.count_real_graphs().astype(jnp.float32)
+            if guard:
+                ok = _all_finite(loss, grads)
+                new_params = _keep_if(ok, new_params, state.params)
+                new_opt = _keep_if(ok, new_opt, state.opt_state)
+                new_bstats = _keep_if(ok, new_bstats, state.batch_stats)
+                okf = ok.astype(jnp.float32)
+                count = count * okf
+                # Zero the VALUES before weighting: NaN * 0 is NaN, so a bad
+                # step's loss must be selected away, not merely zero-weighted.
+                metrics = {
+                    "loss": jnp.where(ok, loss, 0.0) * count,
+                    "rmses": jnp.where(ok, rmses, jnp.zeros_like(rmses)) * count,
+                    "count": count,
+                    "bad": 1.0 - okf,
+                }
+            else:
+                metrics = {"loss": loss * count, "rmses": rmses * count, "count": count}
         new_state = TrainState(
             params=new_params,
             batch_stats=new_bstats,
@@ -269,31 +272,32 @@ def _scaled_step_body(
         (_, (loss, (new_bstats, rmses))), sgrads = jax.value_and_grad(
             scaled_loss, has_aux=True
         )(state.params)
-        inv = 1.0 / ls.scale
-        # Unscale in the grads' own (f32 master) dtype: inf/NaN from an
-        # overflowed backward survive the divide, so the finite check below
-        # sees them; finite grads come out exactly scale-free.
-        grads = jax.tree_util.tree_map(lambda g: g * inv, sgrads)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = jax.tree_util.tree_map(
-            lambda p, u: p + u, state.params, updates
-        )
-        ok = _all_finite(loss, grads)
-        new_params = _keep_if(ok, new_params, state.params)
-        new_opt = _keep_if(ok, new_opt, state.opt_state)
-        new_bstats = _keep_if(ok, new_bstats, state.batch_stats)
-        new_ls, grew = loss_scale_update(ls, ok, loss_scaling)
-        okf = ok.astype(jnp.float32)
-        count = batch.count_real_graphs().astype(jnp.float32) * okf
-        metrics = {
-            "loss": jnp.where(ok, loss, 0.0) * count,
-            "rmses": jnp.where(ok, rmses, jnp.zeros_like(rmses)) * count,
-            "count": count,
-            "overflow": 1.0 - okf,
-            "scale_growths": grew.astype(jnp.float32),
-        }
-        if guard:
-            metrics["bad"] = 1.0 - okf
+        with jax.named_scope(scopes.OPTIMIZER):
+            inv = 1.0 / ls.scale
+            # Unscale in the grads' own (f32 master) dtype: inf/NaN from an
+            # overflowed backward survive the divide, so the finite check below
+            # sees them; finite grads come out exactly scale-free.
+            grads = jax.tree_util.tree_map(lambda g: g * inv, sgrads)
+            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+            new_params = jax.tree_util.tree_map(
+                lambda p, u: p + u, state.params, updates
+            )
+            ok = _all_finite(loss, grads)
+            new_params = _keep_if(ok, new_params, state.params)
+            new_opt = _keep_if(ok, new_opt, state.opt_state)
+            new_bstats = _keep_if(ok, new_bstats, state.batch_stats)
+            new_ls, grew = loss_scale_update(ls, ok, loss_scaling)
+            okf = ok.astype(jnp.float32)
+            count = batch.count_real_graphs().astype(jnp.float32) * okf
+            metrics = {
+                "loss": jnp.where(ok, loss, 0.0) * count,
+                "rmses": jnp.where(ok, rmses, jnp.zeros_like(rmses)) * count,
+                "count": count,
+                "overflow": 1.0 - okf,
+                "scale_growths": grew.astype(jnp.float32),
+            }
+            if guard:
+                metrics["bad"] = 1.0 - okf
         new_state = TrainState(
             params=new_params,
             batch_stats=new_bstats,
@@ -320,10 +324,14 @@ def make_train_step(
     def step(state: TrainState, batch: GraphBatch, rng):
         # The compiled-step half of the graftel trace bridge
         # (docs/OBSERVABILITY.md): a named scope is pure op metadata — the
-        # emitted computation is numerically identical — but XLA carries it
-        # into the profiler, so a captured Perfetto trace shows device ops
-        # under the same name the host-side telemetry spans use.
-        with jax.named_scope("hydragnn.train_step"):
+        # emitted computation is numerically identical — and XLA writes it
+        # into every operation's ``op_name``. In a captured trace that is the
+        # ``tf_op`` stat of the operation's EVENT METADATA on the device
+        # plane's ``XLA Ops`` line (``jax.profiler.ProfileData`` shows an
+        # event's own stats only, so it hides it;
+        # ``graftbench/xplane_scopes.py`` reads it). telemetry/scopes.py has
+        # the vocabulary.
+        with jax.named_scope(scopes.TRAIN_STEP):
             return body(state, batch, rng)
 
     return jax.jit(step, donate_argnums=(0,) if donate else ())
@@ -332,13 +340,14 @@ def make_train_step(
 def make_eval_step(model: HydraGNN) -> Callable:
     @jax.jit
     def step(state: TrainState, batch: GraphBatch):
-        with jax.named_scope("hydragnn.eval_step"):
+        with jax.named_scope(scopes.EVAL_STEP):
             outputs = _apply_model(
                 model, state.params, state.batch_stats, batch, train=False
             )
-            loss, rmses = multihead_rmse_loss(
-                outputs, batch, model.output_type, model.task_weights
-            )
+            with jax.named_scope(scopes.LOSS):
+                loss, rmses = multihead_rmse_loss(
+                    outputs, batch, model.output_type, model.task_weights
+                )
             count = batch.count_real_graphs().astype(jnp.float32)
         return (
             {"loss": loss * count, "rmses": rmses * count, "count": count},
@@ -372,7 +381,7 @@ def make_train_epoch_scan(
     def epoch(state: TrainState, batches: GraphBatch, rng):
         # Trace-annotation bridge: same metadata-only scope as
         # make_train_step, so scanned epochs attribute identically.
-        with jax.named_scope("hydragnn.train_epoch_scan"):
+        with jax.named_scope(scopes.TRAIN_EPOCH_SCAN):
             state, metrics = jax.lax.scan(
                 lambda s, b: body(s, b, rng), state, batches
             )
@@ -452,7 +461,8 @@ def _dp_local_graftmesh(
         )
         ls = state.loss_scale
         count = batch.count_real_graphs().astype(jnp.float32)
-        count_total = jax.lax.psum(count, "data")
+        with jax.named_scope(scopes.GRAD_SYNC):
+            count_total = jax.lax.psum(count, "data")
         denom = jnp.maximum(count_total, 1.0)
         scale = ls.scale if scaled else jnp.float32(1.0)
 
@@ -466,11 +476,12 @@ def _dp_local_graftmesh(
             (_, (loss, new_bstats, rmses)), sgrads = jax.value_and_grad(
                 fn, has_aux=True
             )(state.params)
-            grads = jax.tree_util.tree_map(
-                lambda g: jax.lax.psum(g * count, "data") / denom, sgrads
-            )
-            if graph:
-                grads = jax.lax.pmean(grads, "graph")
+            with jax.named_scope(scopes.GRAD_SYNC):
+                grads = jax.tree_util.tree_map(
+                    lambda g: jax.lax.psum(g * count, "data") / denom, sgrads
+                )
+                if graph:
+                    grads = jax.lax.pmean(grads, "graph")
         else:
             w = count / denom
             plan = overlap.plan_buckets(
@@ -495,41 +506,47 @@ def _dp_local_graftmesh(
             # Unscale AFTER the reduction in the grads' f32 master dtype —
             # inf/NaN from an overflowed shard survives the psum and the
             # divide, so the lockstep finite check below sees it everywhere.
-            inv = 1.0 / ls.scale
-            grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
-        new_bstats = jax.tree_util.tree_map(
-            lambda s: jax.lax.psum(s * count, "data") / denom, new_bstats
-        )
-        if graph:
-            new_bstats = jax.lax.pmean(new_bstats, "graph")
-        loss_sum = jax.lax.psum(loss * count, "data")
-        rmses_sum = jax.lax.psum(rmses * count, "data")
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = jax.tree_util.tree_map(
-            lambda p, u: p + u, state.params, updates
-        )
+            with jax.named_scope(scopes.OPTIMIZER):
+                inv = 1.0 / ls.scale
+                grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
+        with jax.named_scope(scopes.GRAD_SYNC):
+            new_bstats = jax.tree_util.tree_map(
+                lambda s: jax.lax.psum(s * count, "data") / denom, new_bstats
+            )
+            if graph:
+                new_bstats = jax.lax.pmean(new_bstats, "graph")
+            loss_sum = jax.lax.psum(loss * count, "data")
+            rmses_sum = jax.lax.psum(rmses * count, "data")
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, new_opt = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = jax.tree_util.tree_map(
+                lambda p, u: p + u, state.params, updates
+            )
         metrics = {"loss": loss_sum, "rmses": rmses_sum, "count": count_total}
         new_ls = ls
         if scaled or guard:
-            # Post-reduction flag: every shard computes the SAME verdict from
-            # the reduced values, so skip/keep (and the scale update) apply
-            # in lockstep — no shard can diverge.
-            ok = _all_finite(loss_sum, grads)
-            new_params = _keep_if(ok, new_params, state.params)
-            new_opt = _keep_if(ok, new_opt, state.opt_state)
-            new_bstats = _keep_if(ok, new_bstats, state.batch_stats)
-            okf = ok.astype(jnp.float32)
-            metrics = {
-                "loss": jnp.where(ok, loss_sum, 0.0),
-                "rmses": jnp.where(ok, rmses_sum, jnp.zeros_like(rmses_sum)),
-                "count": count_total * okf,
-            }
-            if scaled:
-                new_ls, grew = loss_scale_update(ls, ok, loss_scaling)
-                metrics["overflow"] = 1.0 - okf
-                metrics["scale_growths"] = grew.astype(jnp.float32)
-            if guard:
-                metrics["bad"] = 1.0 - okf
+            with jax.named_scope(scopes.OPTIMIZER):
+                # Post-reduction flag: every shard computes the SAME verdict from
+                # the reduced values, so skip/keep (and the scale update) apply
+                # in lockstep — no shard can diverge.
+                ok = _all_finite(loss_sum, grads)
+                new_params = _keep_if(ok, new_params, state.params)
+                new_opt = _keep_if(ok, new_opt, state.opt_state)
+                new_bstats = _keep_if(ok, new_bstats, state.batch_stats)
+                okf = ok.astype(jnp.float32)
+                metrics = {
+                    "loss": jnp.where(ok, loss_sum, 0.0),
+                    "rmses": jnp.where(ok, rmses_sum, jnp.zeros_like(rmses_sum)),
+                    "count": count_total * okf,
+                }
+                if scaled:
+                    new_ls, grew = loss_scale_update(ls, ok, loss_scaling)
+                    metrics["overflow"] = 1.0 - okf
+                    metrics["scale_growths"] = grew.astype(jnp.float32)
+                if guard:
+                    metrics["bad"] = 1.0 - okf
         new_state = TrainState(
             params=new_params,
             batch_stats=new_bstats,
@@ -601,42 +618,50 @@ def make_train_step_dp(
         # Gradient allreduce (the DDP-allreduce analog, over ICI), weighted by
         # real-graph count so all-masked tail-padding batches contribute zero
         # weight instead of diluting the step (count=0 ⇒ zero numerator term).
-        count_total = jax.lax.psum(count, "data")
-        grads = jax.tree_util.tree_map(
-            lambda g: jax.lax.psum(g * count, "data")
-            / jnp.maximum(count_total, 1.0),
-            grads,
-        )
-        new_bstats = jax.tree_util.tree_map(
-            lambda s: jax.lax.psum(s * count, "data")
-            / jnp.maximum(count_total, 1.0),
-            new_bstats,
-        )
-        if "graph" in grad_axes:
-            # Edge-shard contributions sum under pmean (psum-transpose rule).
-            grads = jax.lax.pmean(grads, "graph")
-            new_bstats = jax.lax.pmean(new_bstats, "graph")
-        loss_sum = jax.lax.psum(loss * count, "data")
-        rmses_sum = jax.lax.psum(rmses * count, "data")
-        count_sum = jax.lax.psum(count, "data")
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = jax.tree_util.tree_map(lambda p, u: p + u, state.params, updates)
-        metrics = {"loss": loss_sum, "rmses": rmses_sum, "count": count_sum}
-        if guard:
-            # Checked AFTER the psum: a NaN on any shard propagates into the
-            # reduced grads/metrics, so every device computes the SAME flag
-            # and skips (or keeps) the replicated state update in lockstep.
-            ok = _all_finite(loss_sum, grads)
-            new_params = _keep_if(ok, new_params, state.params)
-            new_opt = _keep_if(ok, new_opt, state.opt_state)
-            new_bstats = _keep_if(ok, new_bstats, state.batch_stats)
-            okf = ok.astype(jnp.float32)
-            metrics = {
-                "loss": jnp.where(ok, loss_sum, 0.0),
-                "rmses": jnp.where(ok, rmses_sum, jnp.zeros_like(rmses_sum)),
-                "count": count_sum * okf,
-                "bad": 1.0 - okf,
-            }
+        with jax.named_scope(scopes.GRAD_SYNC):
+            count_total = jax.lax.psum(count, "data")
+            grads = jax.tree_util.tree_map(
+                lambda g: jax.lax.psum(g * count, "data")
+                / jnp.maximum(count_total, 1.0),
+                grads,
+            )
+            new_bstats = jax.tree_util.tree_map(
+                lambda s: jax.lax.psum(s * count, "data")
+                / jnp.maximum(count_total, 1.0),
+                new_bstats,
+            )
+            if "graph" in grad_axes:
+                # Edge-shard contributions sum under pmean (psum-transpose
+                # rule).
+                grads = jax.lax.pmean(grads, "graph")
+                new_bstats = jax.lax.pmean(new_bstats, "graph")
+            loss_sum = jax.lax.psum(loss * count, "data")
+            rmses_sum = jax.lax.psum(rmses * count, "data")
+            count_sum = jax.lax.psum(count, "data")
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, new_opt = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = jax.tree_util.tree_map(
+                lambda p, u: p + u, state.params, updates
+            )
+            metrics = {"loss": loss_sum, "rmses": rmses_sum, "count": count_sum}
+            if guard:
+                # Checked AFTER the psum: a NaN on any shard propagates into
+                # the reduced grads/metrics, so every device computes the
+                # SAME flag and skips (or keeps) the replicated state update
+                # in lockstep.
+                ok = _all_finite(loss_sum, grads)
+                new_params = _keep_if(ok, new_params, state.params)
+                new_opt = _keep_if(ok, new_opt, state.opt_state)
+                new_bstats = _keep_if(ok, new_bstats, state.batch_stats)
+                okf = ok.astype(jnp.float32)
+                metrics = {
+                    "loss": jnp.where(ok, loss_sum, 0.0),
+                    "rmses": jnp.where(ok, rmses_sum, jnp.zeros_like(rmses_sum)),
+                    "count": count_sum * okf,
+                    "bad": 1.0 - okf,
+                }
         new_state = TrainState(
             params=new_params,
             batch_stats=new_bstats,
@@ -657,8 +682,9 @@ def _wrap_dp_step(local, mesh, graph_sharded: bool, donate: bool):
 
     def step(state, batch, rng):
         # Tracing happens inside this call: pin the Pallas gate to the mesh's
-        # execution platform for the duration.
-        with pallas_platform(platform):
+        # execution platform for the duration. The root scope is the
+        # one-device step's (telemetry/scopes.py): one name a program.
+        with pallas_platform(platform), jax.named_scope(scopes.TRAIN_STEP):
             sharded = jax.shard_map(
                 local,
                 mesh=mesh,
@@ -679,9 +705,10 @@ def make_eval_step_dp(model: HydraGNN, mesh) -> Callable:
         outputs = _apply_model(
             model, state.params, state.batch_stats, batch, train=False
         )
-        loss, rmses = multihead_rmse_loss(
-            outputs, batch, model.output_type, model.task_weights
-        )
+        with jax.named_scope(scopes.LOSS):
+            loss, rmses = multihead_rmse_loss(
+                outputs, batch, model.output_type, model.task_weights
+            )
         count = batch.count_real_graphs().astype(jnp.float32)
         metrics = {
             "loss": jax.lax.psum(loss * count, "data"),
@@ -694,7 +721,7 @@ def make_eval_step_dp(model: HydraGNN, mesh) -> Callable:
     platform = _mesh_platform(mesh)
 
     def step(state, batch):
-        with pallas_platform(platform):
+        with pallas_platform(platform), jax.named_scope(scopes.EVAL_STEP):
             sharded = jax.shard_map(
                 _local,
                 mesh=mesh,

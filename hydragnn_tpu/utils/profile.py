@@ -9,16 +9,24 @@ untraced (compile/cache effects settle), then exactly ``active`` steps are
 captured. ``active: 0`` falls back to tracing the whole epoch. The trace
 lands under ./logs/<name>/profiler_output for TensorBoard / Perfetto.
 
-``annotate(name)`` opens a named span (torch ``record_function`` analog);
-the TrainingDriver wraps feed / train_step / eval_step with it."""
+One annotation path (docs/OBSERVABILITY.md): while its trace is open the
+profiler switches graftel's ``jax.profiler.TraceAnnotation`` bridge on, so the
+host events of a captured trace ARE the program's graftel spans, under the
+names every other reader uses (``train_epoch``, ``collate``, ``h2d``,
+``feed_wait``, ``device_step``, ``evaluate``, ``eval_step``); the training
+loop opens no annotation of its own. ``annotate(name)`` (torch
+``record_function`` analog) is a graftel span too, for a caller's own region.
+Device time by named scope of such a trace:
+``python3 -m graftbench.xplane_scopes <trace dir>``."""
 
 from __future__ import annotations
 
-import contextlib
 import os
 from typing import Optional
 
 import jax
+
+from ..telemetry import graftel as telemetry
 
 
 class Profiler:
@@ -32,6 +40,7 @@ class Profiler:
         self.active_steps = 3
         self._armed = False  # inside the target epoch
         self._tracing = False  # jax trace window open
+        self._bridge_was = False  # graftel's annotation bridge before _start
         self._step = 0
 
     def setup(self, config: Optional[dict]) -> None:
@@ -75,17 +84,19 @@ class Profiler:
             self._stop_trace()
 
     def annotate(self, name: str):
-        """Named span (record_function analog) inside the trace."""
-        if self._armed:
-            return jax.profiler.TraceAnnotation(name)
-        return contextlib.nullcontext()
+        """Named region (record_function analog): a graftel span, which is a
+        host event of the trace while one is open."""
+        return telemetry.span(name)
 
     def _start(self) -> None:
         os.makedirs(self.trace_dir, exist_ok=True)
         jax.profiler.start_trace(self.trace_dir)
+        self._bridge_was = telemetry.jax_annotations()
+        telemetry.configure(jax_annotations=True)
         self._tracing = True
 
     def _stop_trace(self) -> None:
+        telemetry.configure(jax_annotations=self._bridge_was)
         jax.profiler.stop_trace()
         self._tracing = False
 

@@ -8,7 +8,6 @@ path (no flag computed at all), guards enabled with no faults = bit-identical
 results to guards-off. The supervised kill/restart drill lives in
 tests/test_checkpoint.py (it shares that file's subprocess harness)."""
 
-import contextlib
 
 import numpy as np
 import pytest
@@ -93,9 +92,6 @@ class _ActiveProf:
     """Active-profiler stub: routes train_epoch onto the per-batch path."""
 
     active = True
-
-    def annotate(self, name):
-        return contextlib.nullcontext()
 
     def step(self):
         pass

@@ -66,12 +66,20 @@ def pytest_profiler_epoch_window(tmp_path):
     assert prof.enabled and not prof.active
     prof.set_current_epoch(0)
     assert not prof.active
+    from hydragnn_tpu import telemetry
+
+    assert not telemetry.jax_annotations()
     prof.set_current_epoch(1)
     assert prof.active
-    with prof.annotate("span"):
+    # While the trace is open graftel's TraceAnnotation bridge is on, and an
+    # annotation IS a graftel span (one path, docs/OBSERVABILITY.md).
+    assert telemetry.jax_annotations()
+    with prof.annotate("span") as sp:
         pass
+    assert isinstance(sp, telemetry.span)
     prof.set_current_epoch(2)  # window closes
     assert not prof.active
+    assert not telemetry.jax_annotations()
     assert os.path.isdir(prof.trace_dir)
     # trace files actually written
     found = any(files for _, _, files in os.walk(prof.trace_dir))
@@ -107,10 +115,30 @@ def pytest_profiler_step_schedule(tmp_path, monkeypatch):
     assert events == ["start", "stop"]
 
 
+def pytest_profiler_bridge_restored_to_what_it_was(tmp_path, monkeypatch):
+    """The profiler switches graftel's annotation bridge on for its trace
+    and puts back what it found: off after a plain run, still on where the
+    caller (graftbench's traced run) had it on already."""
+    from hydragnn_tpu import telemetry
+
+    monkeypatch.setattr("jax.profiler.start_trace", lambda d: None)
+    monkeypatch.setattr("jax.profiler.stop_trace", lambda: None)
+    for before in (False, True):
+        telemetry.configure(jax_annotations=before)
+        prof = Profiler(str(tmp_path))
+        prof.setup({"enable": 1, "target_epoch": 0, "active": 0})
+        prof.set_current_epoch(0)
+        assert telemetry.jax_annotations()
+        prof.stop()
+        assert telemetry.jax_annotations() is before
+    telemetry.configure(jax_annotations=False)
+
+
 def pytest_profiler_spans_in_trace(tmp_path):
-    """Drive a real train epoch under the profiler and assert the
-    feed/train_step span names (and eval_step via evaluate) land in the
-    written trace — the record_function-parity check."""
+    """Drive a real train epoch and an evaluation under a "Profile"-armed
+    profiler and assert that the program's graftel spans are host events of
+    the written trace, under the names every other reader uses: the ONE
+    annotation path (the loop opens no TraceAnnotation of its own)."""
     import jax
     import numpy as np
 
@@ -159,18 +187,27 @@ def pytest_profiler_spans_in_trace(tmp_path):
     prof = Profiler(str(tmp_path))
     prof.setup({"enable": 1, "target_epoch": 0, "active": 0})
     prof.set_current_epoch(0)
-    driver.train_epoch(loader, prof)
-    driver.evaluate(loader, profiler=prof)
-    prof.stop()
+    from hydragnn_tpu import telemetry
 
-    blobs = b""
-    for root, _, files in os.walk(prof.trace_dir):
-        for f in files:
-            with open(os.path.join(root, f), "rb") as fh:
-                blobs += fh.read()
-    assert b"train_step" in blobs, "train_step span missing from trace"
-    assert b"feed" in blobs, "feed span missing from trace"
-    assert b"eval_step" in blobs, "eval_step span missing from trace"
+    driver.train_epoch(loader, prof)
+    driver.evaluate(loader)
+    prof.stop()
+    assert not telemetry.jax_annotations(), "bridge left on after stop()"
+
+    from graftbench import xplane_scopes
+    from graftbench.trace_reduce import find_xplane
+
+    names = {
+        name for name, _, _ in
+        xplane_scopes.host_events(xplane_scopes.parse(find_xplane(prof.trace_dir)))
+    }
+    wanted = {
+        "train_epoch", "collate", "h2d", "feed_wait", "device_step",
+        "evaluate", "eval_step",
+    }
+    assert wanted <= names, f"missing from the trace: {wanted - names}"
+    # The second annotation path is gone: its names are not in the trace.
+    assert not {"train_step", "feed"} & names
 
 
 def pytest_profiler_disabled_noop(tmp_path):

@@ -4,7 +4,6 @@ cancellation/exception propagation through the two stages, head-spec
 generation invalidation of the driver's device caches, and the
 single-transfer cache build (one jax.device_put per chunk/batch)."""
 
-import contextlib
 
 import numpy as np
 import pytest
@@ -62,9 +61,6 @@ class _ActiveProf:
     (non-scan) path, like benchmarks/profile_epoch.py's span profiler."""
 
     active = True
-
-    def annotate(self, name):
-        return contextlib.nullcontext()
 
     def step(self):
         pass

@@ -1,0 +1,369 @@
+"""The named-scope vocabulary (hydragnn_tpu/telemetry/scopes.py) in the
+compiled programs: every conv family's train step, and the mesh step on four
+virtual devices, are lowered and compiled here and held, from the ``op_name``
+of each HLO instruction, to
+
+(a) every gather / scatter / reduce-window / sort / custom-call sitting under
+    ``hydragnn.gather``, ``hydragnn.agg.*`` or ``hydragnn.pool``, forward and
+    ``transpose(`` alike, the ``custom_vjp`` arms included;
+(b) the arm in the name being the arm the routing chose;
+(c) the scopes being metadata only (same optimized HLO with them switched
+    off);
+(d) every name used being in the vocabulary.
+
+CPU, tiny sizes: op names and counts, never a time."""
+
+import contextlib
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from hydragnn_tpu.graphs import GraphSample, collate_graphs
+from hydragnn_tpu.models import create_model, init_model_variables
+from hydragnn_tpu.ops import pallas_segment as ps
+from hydragnn_tpu.ops import segment_sorted as srt
+from hydragnn_tpu.telemetry import scopes
+from hydragnn_tpu.train.trainer import (
+    create_train_state,
+    make_eval_step,
+    make_train_epoch_scan,
+    make_train_step,
+    make_train_step_dp,
+    stack_batches,
+)
+from hydragnn_tpu.utils.optimizer import select_optimizer
+
+FAMILIES = ("SAGE", "GIN", "MFC", "GAT", "CGCNN", "PNA")
+# route -> (environment, keep the batch's CSR pointers, arm of the sums)
+ROUTES = {
+    "xla": ({"HYDRAGNN_SEGMENT_SORTED": "0"}, True, "xla"),
+    "sorted": ({"HYDRAGNN_SEGMENT_SORTED": "1"}, False, "sorted"),
+    "csr": ({"HYDRAGNN_SEGMENT_SORTED": "1"}, True, "csr"),
+}
+_DATA_MOVERS = re.compile(r"\s(gather|scatter|reduce-window|sort|custom-call)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HYDRAGNN = re.compile(r"hydragnn\.[\w.]+")
+
+
+def _heads(node_type="mlp"):
+    return {
+        "graph": {
+            "num_sharedlayers": 1, "dim_sharedlayers": 4,
+            "num_headlayers": 1, "dim_headlayers": [4],
+        },
+        "node": {"num_headlayers": 1, "dim_headlayers": [4], "type": node_type},
+    }
+
+
+def _batch(csr=True):
+    rng = np.random.default_rng(0)
+    graphs = []
+    for _ in range(4):
+        n = 6
+        x = rng.normal(size=(n, 1)).astype(np.float32)
+        ei = np.stack([np.arange(n), (np.arange(n) + 1) % n]).astype(np.int32)
+        ei = np.concatenate([ei, ei[::-1]], axis=1)
+        ea = rng.random((ei.shape[1], 1)).astype(np.float32) + 0.1
+        y = np.concatenate([[x.sum()], x[:, 0]]).astype(np.float32)
+        graphs.append(GraphSample(
+            x=x, pos=np.zeros((n, 3), np.float32), y=y,
+            y_loc=np.array([[0, 1, 1 + n]], dtype=np.int64),
+            edge_index=ei, edge_attr=ea,
+        ))
+    batch = collate_graphs(graphs, ("graph", "node"), (1, 1), edge_dim=1)
+    return batch if csr else batch.replace(row_ptr=None, graph_ptr=None)
+
+
+def _model(conv, node_type="mlp", **kw):
+    kw.setdefault("edge_dim", 1)
+    if conv == "PNA":
+        kw["pna_deg"] = [0, 1, 2, 4, 2, 1]
+    if conv == "MFC":
+        kw["max_neighbours"] = 8
+    if node_type == "mlp_per_node":
+        kw["num_nodes"] = 6
+    return create_model(
+        conv, 1, 8, (1, 1), ("graph", "node"), _heads(node_type), [1.0, 1.0],
+        2, **kw,
+    )
+
+
+def _compiled_text(conv, batch, build=make_train_step, stacked=None, **model_kw):
+    """Optimized HLO of ``build``'s step; ``stacked`` is what the scanned
+    epoch is lowered for (``batch`` still initializes the model)."""
+    model = _model(conv, **model_kw)
+    opt = select_optimizer("AdamW", 1e-3)
+    state = create_train_state(model, init_model_variables(model, batch), opt)
+    step = build(model, opt, donate=False)
+    return step.lower(
+        state, batch if stacked is None else stacked, jax.random.PRNGKey(0)
+    ).compile().as_text()
+
+
+def _op_names(text):
+    """[(the data-moving opcode or None, op_name)] of every instruction."""
+    out = []
+    for line in text.splitlines():
+        name = _OP_NAME.search(line)
+        if name:
+            mover = _DATA_MOVERS.search(line.split("metadata=")[0])
+            out.append((mover.group(1) if mover else None, name.group(1)))
+    return out
+
+
+def _check_movers_scoped(names, root):
+    movers = [(op, n) for op, n in names if op]
+    assert movers, "no gather or scatter compiled: nothing was checked"
+    for op, name in movers:
+        assert root in name, f"{op} outside the root scope: {name}"
+        assert (
+            scopes.GATHER in name or "hydragnn.agg." in name
+            or scopes.POOL in name
+        ), f"{op} under no gather/agg/pool scope: {name}"
+    # The backward pass is held too, not only the forward.
+    assert any("transpose(" in n for _, n in movers)
+
+
+def _used(names):
+    return {m for _, n in names for m in _HYDRAGNN.findall(n)}
+
+
+def _arms(names):
+    """{what: {arms}} over the ``hydragnn.agg.<what>.<arm>`` names used."""
+    out = {}
+    for name in _used(names):
+        parts = name.split(".")
+        if parts[:2] == ["hydragnn", "agg"] and len(parts) == 4:
+            out.setdefault(parts[2], set()).add(parts[3])
+    return out
+
+
+# ------------------------------------------------------------- (a), (b), (d)
+@pytest.mark.parametrize("route", list(ROUTES))
+@pytest.mark.parametrize("conv", FAMILIES)
+def pytest_train_step_movers_scoped_and_arm_named(conv, route, monkeypatch):
+    env, csr, arm = ROUTES[route]
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
+    names = _op_names(_compiled_text(conv, _batch(csr)))
+    _check_movers_scoped(names, scopes.TRAIN_STEP)
+    used = _used(names)
+    assert used <= scopes.VOCABULARY, used - scopes.VOCABULARY  # (d)
+    assert {scopes.TRAIN_STEP, scopes.GATHER, scopes.POOL, scopes.LOSS,
+            scopes.OPTIMIZER} <= used
+    # (b): what the routing chose is what the names say.
+    assert srt.sorted_enabled() is (route != "xla")
+    assert not ps.pallas_enabled()
+    arms = _arms(names)
+    for what, got in arms.items():
+        # Extrema run on XLA's segment_max/min on every route.
+        assert got == ({"xla"} if what == "extrema" else {arm}), (what, got)
+    expected = {
+        "SAGE": {"mean"}, "GIN": {"sum", "mean"}, "MFC": {"sum_count", "mean"},
+        "GAT": {"sum", "extrema", "mean"}, "CGCNN": {"sum", "mean"},
+        "PNA": {"mean", "stats", "extrema"},
+    }[conv]
+    assert set(arms) == expected, (set(arms), expected)
+    if conv == "PNA":
+        assert scopes.AGG_PNA in used
+
+
+@pytest.mark.parametrize("csr_kernel", ["0", "1"])
+def pytest_pallas_arms_named_and_backward_scoped(csr_kernel, monkeypatch):
+    """The two kernel arms (interpreted here): their ``custom_vjp`` backward
+    gathers sit under the same ``hydragnn.agg.*`` scope, inside
+    ``transpose(``."""
+    monkeypatch.setenv("HYDRAGNN_PALLAS", "1")
+    monkeypatch.setenv("HYDRAGNN_PALLAS_CSR", csr_kernel)
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "0")
+    names = _op_names(_compiled_text("PNA", _batch()))
+    arm = "pallas_csr" if csr_kernel == "1" else "pallas"
+    assert ps.csr_kernel_enabled() is (csr_kernel == "1")
+    arms = _arms(names)
+    assert arms["stats"] == {arm} and arms["mean"] == {arm}, arms
+    assert arms["extrema"] == {"xla"}
+    assert _used(names) <= scopes.VOCABULARY
+    stats = scopes.agg("stats", arm)
+    assert any(stats in n and "transpose(" in n for _, n in names)
+    assert any(stats in n and "transpose(" not in n for _, n in names)
+
+
+def pytest_custom_vjp_backward_carries_the_scope(monkeypatch):
+    """``segment_sum_count_csr``, ``_stats`` and ``segment_extrema`` trace
+    their ``_bwd`` apart from the call site: the backward gathers carry the
+    forward's scope all the same."""
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    names = _op_names(_compiled_text("PNA", _batch()))
+    for scope in (scopes.agg("stats", "csr"), scopes.agg("extrema", "xla"),
+                  scopes.agg("mean", "csr")):
+        bwd = [n for op, n in names if op == "gather" and scope in n
+               and "transpose(" in n]
+        assert bwd, f"no backward gather under {scope}"
+        # Written once, not once by the call site and again by the function.
+        assert all(n.count(scope) == 1 for _, n in names if scope in n)
+    names = _op_names(_compiled_text("GIN", _batch(csr=False)))
+    scope = scopes.agg("sum", "sorted")
+    assert any(op == "gather" and scope in n and "transpose(" in n
+               for op, n in names)
+
+
+def pytest_module_path_and_scopes_survive_remat_and_scan(monkeypatch):
+    """flax writes ``conv_0`` into the op name under ``nn.remat`` too, and the
+    scanned epoch carries the same names under its own root."""
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    names = _op_names(_compiled_text("PNA", _batch(), remat=True))
+    recomputed = [n for _, n in names if "rematted_computation" in n]
+    assert any("/conv_0/" + scopes.GATHER in n for n in recomputed)
+    assert any("/conv_1/" + scopes.AGG_PNA in n for n in recomputed)
+    assert any("/conv_0/pre_nn/" in n for n in recomputed)
+    batch = _batch()
+    stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), batch, batch)
+    names = _op_names(_compiled_text(
+        "PNA", batch, build=make_train_epoch_scan, stacked=stacked
+    ))
+    _check_movers_scoped(names, scopes.TRAIN_EPOCH_SCAN)
+    assert scopes.TRAIN_STEP not in _used(names)
+    assert _used(names) <= scopes.VOCABULARY
+
+
+def pytest_eval_step_root_and_per_node_head(monkeypatch):
+    """The evaluation step is told from the train step by its root, and the
+    per-node head's position gathers are ``hydragnn.pool`` / ``gather``."""
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "0")
+    batch = _batch()
+    model = _model("SAGE", node_type="mlp_per_node")
+    opt = select_optimizer("AdamW", 1e-3)
+    state = create_train_state(model, init_model_variables(model, batch), opt)
+    names = _op_names(
+        make_eval_step(model).lower(state, batch).compile().as_text()
+    )
+    used = _used(names)
+    assert scopes.EVAL_STEP in used and scopes.TRAIN_STEP not in used
+    assert {scopes.POOL, scopes.LOSS, scopes.GATHER} <= used
+    assert used <= scopes.VOCABULARY
+    for op, name in names:
+        if op:
+            assert scopes.EVAL_STEP in name and (
+                scopes.GATHER in name or "hydragnn.agg." in name
+                or scopes.POOL in name
+            ), name
+    assert any("/head_1/" + scopes.POOL in n for _, n in names)
+
+
+# ------------------------------------------------------------------- the mesh
+def pytest_mesh_step_on_four_devices_is_rooted_and_scoped(monkeypatch):
+    """The program of the four-chip cell: it had no scope at all."""
+    from hydragnn_tpu.parallel import make_mesh
+
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    devices = jax.devices()[:4]
+    assert len(devices) == 4
+    mesh = make_mesh(devices=devices)
+    batch = _batch()
+    model = _model("PNA")
+    opt = select_optimizer("AdamW", 1e-3)
+    state = create_train_state(model, init_model_variables(model, batch), opt)
+    step = make_train_step_dp(model, opt, mesh, donate=False)
+    text = step.lower(
+        state, stack_batches([batch] * 4, 4), jax.random.PRNGKey(0)
+    ).compile().as_text()
+    names = _op_names(text)
+    _check_movers_scoped(names, scopes.TRAIN_STEP)
+    used = _used(names)
+    assert used <= scopes.VOCABULARY
+    assert {scopes.GRAD_SYNC, scopes.OPTIMIZER, scopes.LOSS} <= used
+    reduces = [
+        _OP_NAME.search(line).group(1) for line in text.splitlines()
+        if re.search(r"\sall-reduce(-start)?\(", line.split("metadata=")[0])
+        and _OP_NAME.search(line)
+    ]
+    assert reduces, "no all-reduce compiled"
+    assert all(scopes.GRAD_SYNC in n for n in reduces), reduces
+
+
+# ------------------------------------------------------------------------ (c)
+def _stripped(text):
+    """Optimized HLO without what a scope may change: instruction metadata
+    and the source tables it points into."""
+    body = text[text.index("\n\n", text.index("StackFrames")):] \
+        if "StackFrames" in text else text
+    return re.sub(r",? ?metadata=\{[^}]*\}", "", body)
+
+
+@pytest.mark.parametrize("conv", ["PNA", "GAT"])
+def pytest_scopes_are_metadata_only(conv, monkeypatch):
+    """Same optimized HLO, text for text, with every scope of the vocabulary
+    switched off: a scope emits no instruction and moves no fusion."""
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    batch = _batch()
+    with_scopes = _compiled_text(conv, batch)
+    assert "hydragnn.agg." in with_scopes
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    without = _compiled_text(conv, batch)
+    assert "hydragnn." not in without
+    assert _stripped(with_scopes) == _stripped(without)
+
+
+# ------------------------------------------------------------- the vocabulary
+def pytest_vocabulary_table():
+    assert scopes.agg("stats", "csr") == "hydragnn.agg.stats.csr"
+    assert all(n.startswith("hydragnn.") for n in scopes.VOCABULARY)
+    assert set(scopes.ROOTS) < scopes.VOCABULARY
+    with pytest.raises(ValueError):
+        scopes.agg("stats", "fast")
+    with pytest.raises(ValueError):
+        scopes.agg("variance", "xla")
+
+
+def pytest_outermost_entry_point_names_the_operation():
+    """``fused_segment_sum`` is ``..._sum_count``'s first output and
+    ``segment_mean`` a sum over a count: one name an operation, the entry
+    point's that was called."""
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.ops import segment as seg
+
+    data = jnp.ones((8, 2))
+    ids = jnp.array([0, 0, 1, 1, 2, 2, 3, 3])
+
+    def names_of(fn):
+        text = jax.jit(fn).lower(data).compile().as_text()
+        return {m for _, n in _op_names(text) for m in _HYDRAGNN.findall(n)}
+
+    assert names_of(lambda d: seg.segment_mean(d, ids, 4)) == {
+        scopes.agg("mean", "xla")
+    }
+    assert names_of(lambda d: seg.segment_std(d, ids, 4)) == {
+        scopes.agg("stats", "xla")
+    }
+    assert names_of(lambda d: ps.fused_segment_sum(d, ids, 4)) == {
+        scopes.agg("sum", "xla")
+    }
+    assert names_of(lambda d: ps.fused_segment_softmax(d[:, 0], ids, 4)) == {
+        scopes.agg("softmax", "xla")
+    }
+
+
+def pytest_compile_cache_keys_carry_the_vocabulary_version(monkeypatch):
+    """JAX leaves operation metadata out of its persistent cache's key, so an
+    executable compiled under other scope names would be served with them
+    (seen on the chip, PERF.md §6 PR 23): ``place_jax_cache`` folds
+    ``scopes.VERSION`` into JAX's key through JAX's own hook, graftcache into
+    its environment fingerprint."""
+    from jax._src import cache_key
+
+    from hydragnn_tpu.cache import jaxcache, store
+
+    monkeypatch.setattr(cache_key, "custom_hook", lambda: "")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/set/by/the/machine")
+    assert jaxcache.place_jax_cache() == "/set/by/the/machine"
+    jaxcache.place_jax_cache()  # idempotent: the tag goes in once
+    assert cache_key.custom_hook() == f"hydragnn-scopes-v{scopes.VERSION}"
+    before = store.environment_fingerprint()["topology"]
+    monkeypatch.setattr(scopes, "VERSION", scopes.VERSION + 1)
+    assert store.environment_fingerprint()["topology"] != before
+    jaxcache.place_jax_cache()
+    assert cache_key.custom_hook().endswith(f"-v{scopes.VERSION}")

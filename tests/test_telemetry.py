@@ -247,6 +247,53 @@ def pytest_nan_grad_drill_dump_has_offending_step_spans(tmp_path):
     assert {s.get("parent_id") for s in by_name["h2d"]} == epoch_parent
 
 
+def pytest_feed_wait_and_h2d_are_spans_of_the_epoch():
+    """The consumer's blocking ``next()`` is a ``feed_wait`` span beside
+    ``FeedStats.feed_wait_s`` (same region, same seconds), in the per-step
+    train loop and in ``evaluate``; the transfer is ONE live ``h2d`` span a
+    batch (its number and bytes as attributes) that ``FeedStats.h2d_s`` agrees
+    with. All hang off the epoch's span."""
+
+    class _PerStep:  # routes train_epoch onto the per-step path
+        active = True
+
+        def step(self):
+            pass
+
+    telemetry.configure(collect=True)
+    loader = _loader(_dataset(np.random.default_rng(0)))
+    d = _driver_for(loader)
+    d.train_epoch(loader, _PerStep())
+    train_stats = d.feed_stats.as_dict()
+    spans = [r for r in telemetry.collected_records() if r["kind"] == "span"]
+    by_name = {}
+    for r in spans:
+        by_name.setdefault(r["name"], []).append(r)
+    (epoch,) = by_name["train_epoch"]
+    batches = len(by_name["device_step"])
+    assert batches == 3
+    # One wait a batch and the one that finds the feed exhausted.
+    assert len(by_name["feed_wait"]) == batches + 1
+    assert {r["parent_id"] for r in by_name["feed_wait"]} == {epoch["span_id"]}
+    assert sum(r["dur_s"] for r in by_name["feed_wait"]) == pytest.approx(
+        train_stats["feed_wait_s"], abs=2e-3
+    )
+    assert [r["attrs"]["index"] for r in by_name["h2d"]] == [1, 2, 3]
+    assert all(r["attrs"]["bytes"] > 0 for r in by_name["h2d"])
+    assert {r["parent_id"] for r in by_name["h2d"]} == {epoch["span_id"]}
+    assert sum(r["dur_s"] for r in by_name["h2d"]) == pytest.approx(
+        train_stats["h2d_s"], abs=2e-3
+    )
+
+    telemetry.reset(keep_config=True)
+    d.evaluate(loader)
+    spans = [r for r in telemetry.collected_records() if r["kind"] == "span"]
+    (evaluate,) = [r for r in spans if r["name"] == "evaluate"]
+    waits = [r for r in spans if r["name"] == "feed_wait"]
+    assert len(waits) == len([r for r in spans if r["name"] == "eval_step"]) + 1
+    assert {r["parent_id"] for r in waits} == {evaluate["span_id"]}
+
+
 def pytest_engine_poison_dumps_flight_recorder(tmp_path):
     telemetry.configure(run_dir=str(tmp_path))
     engine, graphs = _serve_engine()
