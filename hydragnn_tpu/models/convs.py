@@ -15,6 +15,13 @@ row_ptr: [N_pad + 1] CSR boundaries over the destination-sorted receivers (the
 PR-7 batch contract, graphs/csr.py) or None — when present, every sorted-path
 aggregation consumes precomputed boundaries (zero in-step searchsorted) and
 the Pallas opt-in routes to the CSR run-walk kernels.
+
+Every row a conv gathers, and so every row its backward scatter-adds, is
+rank 2: [N_pad, width] -> [E_pad, width]. GATv2's heads included: its rows
+are [N, h·f] and [E, h·f], never [·, h, f], because a [h, f] row pads to a
+whole (8, 128) tile on the TPU and gathers and scatter-adds pay by the padded
+row (tests/test_scopes.py and tests/test_tpu_compile.py hold the compiled
+step to it).
 """
 
 from __future__ import annotations
@@ -112,6 +119,21 @@ class MFCConv(nn.Module):
         return out + b_n
 
 
+def _head_blocks(w):
+    """[h, f] -> [h·f, h], block-diagonal: column i holds ``w[i]`` in rows
+    i·f … (i+1)·f. ``rows @ _head_blocks(w)`` is Σ_f rows[r, i, f]·w[i, f] and
+    ``per_head @ _head_blocks(ones).T`` repeats a head's value over its f
+    columns: the head axis as a 2-D matmul, so no [R, h, f] array exists."""
+    h, f = w.shape
+    return (w[:, :, None] * jnp.eye(h, dtype=w.dtype)[:, None, :]).reshape(h * f, h)
+
+
+# The per-head contraction was a float32 multiply-and-sum (XLA lowered the
+# einsum ``ehf,hf->eh`` to one); as a matmul it must not drop to the MXU's
+# single bf16 pass.
+_HEAD_PRECISION = jax.lax.Precision.HIGHEST
+
+
 class GATv2Conv(nn.Module):
     """GATv2 multi-head attention over incoming edges, with implicit self-loops and
     masked segment softmax (reference GATStack.py:88-97; heads=6,
@@ -125,7 +147,15 @@ class GATv2Conv(nn.Module):
     identical to concatenating one identity edge per node (parity-locked in
     tests/test_csr_contract.py), but the edge array keeps collation's
     destination-sorted order — GAT rides the sorted/CSR aggregation path
-    like every other family instead of being the one scatter-bound holdout."""
+    like every other family instead of being the one scatter-bound holdout.
+
+    Layout: every row array is FLAT. ``x_src``, ``x_dst`` are [N, h·f] and
+    ``x_j``, ``x_i``, ``pre``, ``msgs`` [E, h·f], from the gathers to the
+    aggregation and in what the backward saves, because a [h, f] row pads to
+    a whole (8, 128) tile on the TPU (1.5 KB in 4 KB at 6 × 64) and a
+    gather's backward scatter-add pays by the padded row. The head axis
+    exists only in the [·, h] arrays (logits, ``alpha``, the dropout mask);
+    :func:`_head_blocks` carries it across as a matmul."""
 
     out_dim: int  # per-head output dim
     heads: int = 6
@@ -140,18 +170,23 @@ class GATv2Conv(nn.Module):
 
         n = x.shape[0]
         h, f = self.heads, self.out_dim
-        x_src = nn.Dense(h * f, name="lin_src")(x).reshape(n, h, f)
-        x_dst = nn.Dense(h * f, name="lin_dst")(x).reshape(n, h, f)
+        x_src = nn.Dense(h * f, name="lin_src")(x)  # [N, h·f]
+        x_dst = nn.Dense(h * f, name="lin_dst")(x)
 
         att = self.param("att", nn.initializers.lecun_normal(), (h, f))
+        att_blocks = _head_blocks(att)  # [h·f, h]
+        repeat = _head_blocks(jnp.ones_like(att)).T  # [h, h·f], 0/1
+        # Each source is gathered ONCE: x_j feeds the logits and the
+        # messages, so its two cotangents add over [E, h·f] before the one
+        # scatter-add of the backward.
         with jax.named_scope(scopes.GATHER):
             x_j, x_i = x_src[senders], x_dst[receivers]
-        pre = nn.leaky_relu(x_j + x_i, self.negative_slope)  # [E, h, f]
-        logits = jnp.einsum("ehf,hf->eh", pre, att)  # [E, h]
+        pre = nn.leaky_relu(x_j + x_i, self.negative_slope)  # [E, h·f]
+        logits = jnp.dot(pre, att_blocks, precision=_HEAD_PRECISION)  # [E, h]
         # Self term: the diagonal of the attention matrix, computed densely
         # (x_src[i] + x_dst[i] — no gather, no extra edges).
         pre_self = nn.leaky_relu(x_src + x_dst, self.negative_slope)
-        logit_self = jnp.einsum("nhf,hf->nh", pre_self, att)  # [N, h]
+        logit_self = jnp.dot(pre_self, att_blocks, precision=_HEAD_PRECISION)  # [N, h]
 
         # Stabilized softmax over edges ∪ self. The per-node shift is the
         # TRUE max of the contributing logits (stop_gradient like
@@ -197,20 +232,19 @@ class GATv2Conv(nn.Module):
             alpha_self = jnp.where(
                 keep[:n], alpha_self / (1.0 - self.dropout), 0.0
             )
-        with jax.named_scope(scopes.GATHER):
-            x_j = x_src[senders]  # gathered again, as before the scopes
-        msgs = x_j * alpha[..., None]  # [E, h, f]
-        msgs = jnp.where(edge_mask[:, None, None], msgs, 0.0)
+        msgs = x_j * jnp.dot(alpha, repeat, precision=_HEAD_PRECISION)  # [E, h·f]
+        msgs = jnp.where(edge_mask[:, None], msgs, 0.0)
         out = pallas_segment.fused_segment_sum(
             msgs, receivers, n, axis_name=self.axis_name, sorted_ids=True,
             row_ptr=row_ptr,
-        )  # [N, h, f]
-        out = out + x_src * alpha_self[..., None]  # the self-loop message
+        )  # [N, h·f]
+        # The self-loop message.
+        out = out + x_src * jnp.dot(alpha_self, repeat, precision=_HEAD_PRECISION)
         if self.concat:
-            out = out.reshape(n, h * f)
             bias = self.param("bias", nn.initializers.zeros, (h * f,))
         else:
-            out = out.mean(axis=1)
+            # The mean over heads, on the NODE-level result.
+            out = out.reshape(n, h, f).mean(axis=1)
             bias = self.param("bias", nn.initializers.zeros, (f,))
         return out + bias
 

@@ -16,6 +16,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
@@ -330,6 +331,129 @@ def pytest_gat_isolated_node_keeps_self_attention():
     # alpha_self == 1 everywhere real ⇒ out = x_src (flattened) + bias.
     want = np.asarray(x_src.reshape(n_pad, heads * f) + p["bias"])
     np.testing.assert_allclose(out[:2], want[:2], rtol=1e-6, atol=1e-6)
+
+
+class _Rank3GATv2Conv(nn.Module):
+    """The formulation ``GATv2Conv`` had up to PR 23, as a module of the same
+    parameter tree: rows as ``[·, h, f]`` arrays, the logits an einsum, and
+    ``x_src[senders]`` gathered a second time for the messages. The flat
+    ``GATv2Conv`` is held to it below."""
+
+    out_dim: int
+    heads: int = 6
+    negative_slope: float = 0.05
+    concat: bool = True
+    dropout: float = 0.25
+
+    @nn.compact
+    def __call__(self, x, senders, receivers, edge_attr, edge_mask, node_mask, train=False, row_ptr=None):
+        n = x.shape[0]
+        h, f = self.heads, self.out_dim
+        x_src = nn.Dense(h * f, name="lin_src")(x).reshape(n, h, f)
+        x_dst = nn.Dense(h * f, name="lin_dst")(x).reshape(n, h, f)
+        att = self.param("att", nn.initializers.lecun_normal(), (h, f))
+        x_j, x_i = x_src[senders], x_dst[receivers]
+        pre = nn.leaky_relu(x_j + x_i, self.negative_slope)
+        logits = jnp.einsum("ehf,hf->eh", pre, att)
+        pre_self = nn.leaky_relu(x_src + x_dst, self.negative_slope)
+        logit_self = jnp.einsum("nhf,hf->nh", pre_self, att)
+        edge_max = seg.segment_max(logits, receivers, n, mask=edge_mask, fill=-1e9)
+        m = jax.lax.stop_gradient(jnp.maximum(edge_max, logit_self))
+        exp_e = jnp.where(edge_mask[:, None], jnp.exp(logits - m[receivers]), 0.0)
+        exp_self = jnp.where(node_mask[:, None], jnp.exp(logit_self - m), 0.0)
+        denom = ps.fused_segment_sum(
+            exp_e, receivers, n, mask=edge_mask, sorted_ids=True, row_ptr=row_ptr,
+        ) + exp_self
+        alpha = exp_e / jnp.maximum(denom[receivers], 1e-16)
+        alpha_self = exp_self / jnp.maximum(denom, 1e-16)
+        if train and self.dropout > 0.0:
+            keep = jax.random.bernoulli(
+                self.make_rng("dropout"), 1.0 - self.dropout,
+                (n + alpha.shape[0],) + alpha.shape[1:],
+            )
+            alpha = jnp.where(keep[n:], alpha / (1.0 - self.dropout), 0.0)
+            alpha_self = jnp.where(keep[:n], alpha_self / (1.0 - self.dropout), 0.0)
+        x_j = x_src[senders]
+        msgs = jnp.where(edge_mask[:, None, None], x_j * alpha[..., None], 0.0)
+        out = ps.fused_segment_sum(msgs, receivers, n, sorted_ids=True, row_ptr=row_ptr)
+        out = out + x_src * alpha_self[..., None]
+        if self.concat:
+            out = out.reshape(n, h * f)
+            bias = self.param("bias", nn.initializers.zeros, (h * f,))
+        else:
+            out = out.mean(axis=1)
+            bias = self.param("bias", nn.initializers.zeros, (f,))
+        return out + bias
+
+
+@pytest.mark.parametrize("train", [False, True], ids=["eval", "dropout"])
+@pytest.mark.parametrize("concat", [True, False], ids=["concat", "headmean"])
+@pytest.mark.parametrize("heads,f", [(6, 64), (6, 5)])
+def pytest_gat_flat_rows_match_rank3_formulation(heads, f, concat, train):
+    """``GATv2Conv`` keeps every row array flat ([N, h·f], [E, h·f]) and
+    gathers each source once; the rank-3 formulation it replaced, from the
+    SAME parameters and the same dropout key (the mask's shape (n + E, h) is
+    unchanged, so the masks are identical), gives the same output and the
+    same gradients with respect to ``x`` and every parameter, at lane-wide
+    rows (6 × 64) and at the CI toys' narrow ones (6 × 5), on seeded graphs
+    with isolated nodes and padding rows. float32 on the CPU, 1e-5."""
+    from hydragnn_tpu.models.convs import GATv2Conv
+
+    rng = np.random.default_rng(5)
+    batch = collate_graphs(_random_graphs(rng, fdim=7), ["graph"], [1])
+    real_n, real_e = np.asarray(batch.node_mask), np.asarray(batch.edge_mask)
+    indegree = np.bincount(
+        np.asarray(batch.receivers)[real_e], minlength=len(real_n)
+    )
+    assert (indegree[real_n] == 0).any(), "no isolated real node in the data"
+    assert (~real_n).any() and (~real_e).any(), "no padding rows in the data"
+
+    kw = dict(out_dim=f, heads=heads, negative_slope=0.05, concat=concat)
+    flat, rank3 = GATv2Conv(**kw), _Rank3GATv2Conv(**kw)
+    x = jnp.asarray(batch.node_features)
+    graph = (batch.senders, batch.receivers, None, batch.edge_mask, batch.node_mask)
+    params = flat.init(jax.random.PRNGKey(0), x, *graph, train=False)["params"]
+    # A zero bias and its zero gradient would compare as equal whatever ran.
+    params = dict(params, bias=jnp.asarray(
+        rng.normal(size=params["bias"].shape).astype(np.float32)
+    ))
+    assert jax.tree_util.tree_structure(params) == jax.tree_util.tree_structure(
+        rank3.init(jax.random.PRNGKey(0), x, *graph, train=False)["params"]
+    )
+    weight = jnp.asarray(
+        rng.normal(size=(x.shape[0], heads * f if concat else f)).astype(np.float32)
+    ) * real_n[:, None]
+
+    def run(conv):
+        def loss(params, x):
+            out = conv.apply(
+                {"params": params}, x, *graph, train=train,
+                row_ptr=batch.row_ptr, rngs={"dropout": jax.random.PRNGKey(7)},
+            )
+            return (out * weight).sum(), out
+
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            params, x
+        )
+        return out, grads
+
+    out, (g_params, g_x) = run(flat)
+    out_ref, (g_params_ref, g_x_ref) = run(rank3)
+
+    def close(got, want, what):
+        got, want = np.asarray(got), np.asarray(want)
+        np.testing.assert_allclose(
+            got, want, rtol=1e-5, atol=1e-5 * np.abs(want).max(), err_msg=what
+        )
+
+    close(out[real_n], out_ref[real_n], "output")
+    close(g_x, g_x_ref, "d/dx")
+    assert float(jnp.abs(g_x_ref).max()) > 0.0
+    for name in ("lin_src", "lin_dst"):
+        for leaf in ("kernel", "bias"):
+            close(g_params[name][leaf], g_params_ref[name][leaf], f"d/d{name}.{leaf}")
+    close(g_params["att"], g_params_ref["att"], "d/datt")
+    close(g_params["bias"], g_params_ref["bias"], "d/dbias")
 
 
 def pytest_gat_rides_sorted_path_with_zero_searchsorted(monkeypatch):

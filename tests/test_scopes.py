@@ -253,6 +253,64 @@ def pytest_eval_step_root_and_per_node_head(monkeypatch):
     assert any("/head_1/" + scopes.POOL in n for _, n in names)
 
 
+# ------------------------------------------------------- GATv2's row gathers
+_SHAPE = re.compile(r"= \(?f32\[([\d,]*)\]")
+
+
+def _row_dims(line):
+    """The result shape of an instruction without its unit dimensions (the
+    CPU's gather writes ``[E, 1, width]``)."""
+    return tuple(
+        int(d) for d in _SHAPE.search(line).group(1).split(",") if d != "1"
+    )
+
+
+@pytest.mark.parametrize("route", ["xla", "csr"])
+def pytest_gat_gathers_flat_rows_once_a_layer(route, monkeypatch):
+    """The counter of PR 24's mechanism. In the optimized GATv2 train step
+    every gather and scatter under ``hydragnn.gather`` moves rank-2 rows; a
+    conv layer has exactly two row gathers of width h·f forward
+    (``x_src[senders]``, ``x_dst[receivers]``) and two scatter-adds into
+    ``[N_pad, h·f]`` backward; and NO instruction anywhere, inside a fusion or
+    out, has the shape ``[E_pad, h, f]``: a ``[h, f]`` row pads to a whole
+    (8, 128) tile on the TPU, and a reshape that brings it back fails here
+    (stricter than "no fusion outputs one": the CPU compiler this runs on
+    would keep such a reshape inside a fusion where the TPU's writes it out).
+    """
+    env, csr, _ = ROUTES[route]
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    batch = _batch(csr)
+    n_pad, e_pad = batch.node_features.shape[0], batch.senders.shape[0]
+    heads, f = 6, 8  # create_model's GAT: six heads of hidden_dim
+    text = _compiled_text("GAT", batch)
+    rows = {}  # (conv layer, backward?, opcode) -> [result dims]
+    for line in text.splitlines():
+        head = line.split("metadata=")[0]
+        mover, name = _DATA_MOVERS.search(head), _OP_NAME.search(line)
+        if not (mover and name and scopes.GATHER in name.group(1)):
+            continue
+        layer = re.search(r"/(conv_\d+)/", name.group(1)).group(1)
+        dims = _row_dims(head)
+        assert len(dims) == 2, f"rank-{len(dims)} rows under hydragnn.gather: {line}"
+        rows.setdefault(
+            (layer, "transpose(" in name.group(1), mover.group(1)), []
+        ).append(dims)
+    assert {k[0] for k in rows} == {"conv_0", "conv_1"}
+    for layer in ("conv_0", "conv_1"):
+        forward = rows[(layer, False, "gather")]
+        assert sorted(forward) == sorted(
+            [(e_pad, heads * f)] * 2 + [(e_pad, heads)] * 2
+        ), (layer, forward)  # x_j, x_i; the softmax's shift and denominator
+        backward = rows[(layer, True, "scatter")]
+        assert sorted(backward) == sorted(
+            [(n_pad, heads * f)] * 2 + [(n_pad, heads)]
+        ), (layer, backward)  # the shift carries no gradient
+        assert (layer, False, "scatter") not in rows
+    rank3 = re.compile(rf"f32\[{e_pad},(1,)?{heads},(1,)?{f}\]")
+    assert not rank3.search(text), rank3.search(text).group(0)
+
+
 # ------------------------------------------------------------------- the mesh
 def pytest_mesh_step_on_four_devices_is_rooted_and_scoped(monkeypatch):
     """The program of the four-chip cell: it had no scope at all."""

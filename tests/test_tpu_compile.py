@@ -1,0 +1,87 @@
+"""Programs compiled HERE for a described TPU v5e (no chip attached): what the
+chip's compiler makes of the main path's shapes, which the CPU compiler of
+the other tests cannot show (tiled layouts, what gets written out between
+fusions). Shapes and instruction counts, never a time.
+
+Every compile for the described chip lives in this one file, and the topology
+is described inside a fixture: only the worker that runs this file loads the
+TPU's library (the `on-chip-measurement` guide, section 2)."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch):
+    """One ``GATv2Conv``, forward and backward, at the shapes of the cell
+    ``gatv2_h64x6_md17like.train_b512`` (16384 × 262144, six heads of 64) on
+    the sorted/CSR route the chip takes: the row gathers come out as
+    ``f32[262144,384]``, the backward holds exactly two scatter-adds into
+    ``f32[16384,384]``, and no array with a ``[6,64]`` row or a transposed
+    ``[262144,384]{0,1}`` is written anywhere (PERF.md §6, PR 24: a reshape
+    inside the per-head reduce makes the chip's compiler write both)."""
+    from hydragnn_tpu.models.convs import GATv2Conv
+
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
+    n, e, h, f = 16384, 262144, 6, 64
+    conv = GATv2Conv(out_dim=f, heads=h)
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    small = (
+        jnp.zeros((8, h * f)), jnp.zeros((16,), jnp.int32),
+        jnp.zeros((16,), jnp.int32), None, jnp.ones((16,), bool),
+        jnp.ones((8,), bool),
+    )
+    params = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: conv.init(jax.random.PRNGKey(0), *small)),
+    )
+
+    def loss(params, x, senders, receivers, edge_mask, node_mask, row_ptr, key):
+        out = conv.apply(
+            params, x, senders, receivers, None, edge_mask, node_mask,
+            train=True, row_ptr=row_ptr, rngs={"dropout": key},
+        )
+        return (out * out).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, shaped((n, h * f)), shaped((e,), jnp.int32),
+        shaped((e,), jnp.int32), shaped((e,), jnp.bool_),
+        shaped((n,), jnp.bool_), shaped((n + 1,), jnp.int32),
+        shaped((2,), jnp.uint32),
+    ).compile().as_text()
+
+    rank3 = re.search(rf"\[\d+,{h},{f}\]", text)
+    assert not rank3, f"an array with [6,64] rows is back: {rank3.group(0)}"
+    assert f"[{e},{h * f}]{{0,1" not in text, "a transposed copy of the edge rows"
+    entry = text[text.index("ENTRY"):]
+    moved = [
+        (m.group(1), m.group(2))
+        for m in re.finditer(
+            r"= f32\[([\d,]+)\]\S* fusion\(.*op_name=\"[^\"]*hydragnn\.gather/([\w-]+)\"",
+            entry,
+        )
+    ]
+    wide = [(shape, op) for shape, op in moved if shape.endswith(f",{h * f}")]
+    assert sorted(wide) == sorted(
+        [(f"{e},{h * f}", "gather")] * 2 + [(f"{n},{h * f}", "scatter-add")] * 2
+    ), moved
