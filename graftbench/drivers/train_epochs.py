@@ -114,7 +114,7 @@ def smallest(samples, count: int):
     return [samples[i] for i in order[:count]]
 
 
-def check_against_reference(model, samples, forward, variables):
+def check_against_reference(model, samples, forward, variables, atol, rtol):
     """The program's forward on a few seeded graphs at full width against
     the plain float32 reference. ``forward(samples)`` returns per-graph lists
     of per-head arrays computed with ``variables``. Returns (worst abs
@@ -130,11 +130,10 @@ def check_against_reference(model, samples, forward, variables):
                 return worst, f"graph {g} head {h}: shape or non-finite output"
             err = np.abs(a - b)
             worst = max(worst, float(err.max()))
-            if (err > reference.ATOL + reference.RTOL * np.abs(b)).any():
+            if (err > atol + rtol * np.abs(b)).any():
                 fail = (
                     f"graph {g} head {h}: |program - reference| "
-                    f"{float(err.max()):.3e} beyond atol={reference.ATOL} "
-                    f"rtol={reference.RTOL}"
+                    f"{float(err.max()):.3e} beyond atol={atol} rtol={rtol}"
                 )
     return worst, fail
 
@@ -192,14 +191,15 @@ def run(cell) -> dict:
     why_not = []
 
     check_vars = shaken(variables, cell.seed)
+    atol, rtol = reference.tolerance(model.conv_type)
     worst, fail = check_against_reference(
         model, smallest(test_loader.dataset, 8),
         _program_forward(model, check_vars, train_loader),
-        check_vars,
+        check_vars, atol, rtol,
     )
     print(
         f"[graftbench] program vs plain float32 reference on 8 graphs: max "
-        f"|diff| {worst:.3e} (atol {reference.ATOL}, rtol {reference.RTOL})",
+        f"|diff| {worst:.3e} (atol {atol}, rtol {rtol})",
         flush=True,
     )
     if fail:
@@ -264,6 +264,9 @@ def run(cell) -> dict:
     facts = dict(
         epochs=0, train_graphs=0, epoch_wall_s=0.0, train_epoch_wall_s=0.0,
         feed_wait_s=0.0, h2d_s=0.0, step_s=0.0, init_s=init_s,
+        # Seconds of each epoch of the window, and of its train part: a run
+        # that reads far off shows here whether one epoch stalled or all did.
+        epoch_s=[], train_epoch_s=[],
     )
     train_loader.reset_padding_stats()
     programs.recording = False
@@ -272,8 +275,10 @@ def run(cell) -> dict:
         t_e = time.perf_counter()
         with telemetry.span("graftbench.epoch", epoch=epoch):
             one_epoch()
-        facts["epoch_wall_s"] += time.perf_counter() - t_e
+        facts["epoch_s"].append(time.perf_counter() - t_e)
+        facts["epoch_wall_s"] += facts["epoch_s"][-1]
         gauges = telemetry.gauges_snapshot()
+        facts["train_epoch_s"].append(gauges["train/epoch_wall_s"])
         facts["train_epoch_wall_s"] += gauges["train/epoch_wall_s"]
         facts["feed_wait_s"] += gauges["train/feed_wait_s_per_epoch"]
         facts["h2d_s"] += gauges["train/h2d_s_per_epoch"]
@@ -284,6 +289,9 @@ def run(cell) -> dict:
 
     pad = train_loader.padding_stats()
     steps = pad["batches"] // max(driver.n_devices, 1)
+    counted = flops.train_step(
+        b["arch"], pad["real_nodes"], pad["real_edges"], pad["real_graphs"]
+    )
     facts.update(
         window_s=window_s,
         eval_wall_s=facts["epoch_wall_s"] - facts["train_epoch_wall_s"],
@@ -291,16 +299,22 @@ def run(cell) -> dict:
         real_nodes=pad["real_nodes"], pad_nodes=pad["pad_nodes"],
         real_edges=pad["real_edges"], pad_edges=pad["pad_edges"],
         real_graphs=pad["real_graphs"],
-        step_ops=flops.train_step(
-            b["arch"], pad["real_nodes"], pad["real_edges"], pad["real_graphs"]
-        )["ops"] / max(steps, 1),
+        step_ops=counted["ops"] / max(steps, 1),
+        # Counted bytes a step of the gathers and the aggregation, forward
+        # and backward, all chips together (graftbench/flops.py's convention).
+        step_bytes={
+            scope: {d: v / max(steps, 1) for d, v in counted["bytes"][scope].items()}
+            for scope in ("gather", "agg")
+        },
         chips=len(cell.devices),
     )
     losses = [float(v) for v in history["total_loss_train"]]
     print(
         f"[graftbench] {facts['epochs']} epochs, {steps} steps, "
         f"{facts['train_graphs']} train graphs in {window_s:.3f}s; train "
-        f"loss per epoch {[round(v, 6) for v in losses]}", flush=True,
+        f"loss per epoch {[round(v, 6) for v in losses]}; seconds per epoch "
+        f"{[round(v, 3) for v in facts['epoch_s']]}, of them training "
+        f"{[round(v, 3) for v in facts['train_epoch_s']]}", flush=True,
     )
     # Epoch losses at this learning rate swing by a third from one epoch to
     # the next (0.146, 0.203, 0.133, 0.156 on the chip), so "the last epoch

@@ -106,6 +106,21 @@ def pytest_cells_find_their_files_and_report_what_they_must():
             assert set(m.get("workloads", ())) <= cells, m["name"]
 
 
+def pytest_every_configuration_has_its_family_file():
+    """The plain reference and the counts of a configuration are found by
+    its ``model_type`` (``graftbench/families/<model_type>.py``)."""
+    from graftbench import families
+
+    for c in _bench()["configs"]:
+        with open(os.path.join(REPO, c["file"])) as f:
+            kind = json.load(f)["NeuralNetwork"]["Architecture"]["model_type"]
+        assert os.path.exists(
+            os.path.join(BENCH_DIR, "families", kind.lower() + ".py")
+        ), (c["name"], kind)
+        family = families.load(kind)
+        assert callable(family.encode) and callable(family.counts), kind
+
+
 def pytest_peaks_table_names_its_sources():
     with open(os.path.join(BENCH_DIR, "peaks.json")) as f:
         peaks = json.load(f)

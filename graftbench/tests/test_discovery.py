@@ -1,11 +1,13 @@
 """A later PR adds a configuration, a traffic mix and a per-layer metric as
 files of their own plus entries in ``BENCHMARK.json``, editing no file that
 is there. Shown on a temporary copy: three new files, three appended
-entries, one run."""
+entries, one run. So with a model family the program has and the benchmark
+lacks: a family file, a configuration, two entries, one run."""
 
 import hashlib
 import json
 import os
+import shutil
 
 import tiny
 
@@ -75,3 +77,56 @@ def pytest_new_cell_config_traffic_and_metric_as_files_only(tmp_path):
     after = _digests(root)
     assert {p: d for p, d in after.items() if p in before} == before
     assert len(after) == len(before) + 3
+
+
+def pytest_new_model_family_as_files_only(tmp_path):
+    """SAGE: the program has it (``models/convs.py``), the benchmark has no
+    ``families/sage.py``. Its plain reference and its counts arrive as ONE
+    file (``later_family_sage.py`` here), its cell as a configuration file
+    and two entries."""
+    root = tiny.make_copy(str(tmp_path))
+    before = _digests(root)
+    bench_dir = os.path.join(root, "graftbench")
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    old = next(w for w in bench["workloads"] if w["name"] == tiny.cell(root, "train_epochs"))
+    with open(os.path.join(bench_dir, "configs", old["config"] + ".json")) as f:
+        config = json.load(f)
+    config["NeuralNetwork"]["Architecture"]["model_type"] = "SAGE"
+    with open(os.path.join(bench_dir, "configs", "later_sage.json"), "w") as f:
+        json.dump(config, f)
+    bench["configs"].append(dict(
+        name="later_sage", source="a later PR", why="discovery self-test",
+        file="graftbench/configs/later_sage.json", reduced=["num_conv_layers"],
+    ))
+    bench["workloads"].append(dict(
+        name="later_sage.cell", config="later_sage", traffic=old["traffic"],
+        chips=1, why="discovery self-test",
+    ))
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if old["name"] in m.get("workloads", ()):
+            m["workloads"].append("later_sage.cell")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    # Without its file the family fails with the name of the file to add.
+    rc, line, text = tiny.run_cell(root, "later_sage.cell", seconds=0.5)
+    assert rc != 0 and line is None
+    assert "add graftbench/families/sage.py" in text, text[-3000:]
+
+    shutil.copy(
+        os.path.join(tiny.HERE, "later_family_sage.py"),
+        os.path.join(bench_dir, "families", "sage.py"),
+    )
+    rc, line, text = tiny.run_cell(root, "later_sage.cell", seconds=0.5, trace=1)
+    assert rc == 0, text[-3000:]
+    assert "program vs plain float32 reference on 8 graphs" in text
+    assert not [l for l in text.splitlines() if "NOT CORRECT: reference:" in l], text[-3000:]
+    with open(os.path.join(bench_dir, "out", "later_sage.cell", "last_run.json")) as f:
+        facts = json.load(f)["facts"]
+    assert facts["step_ops"] > 0
+    assert all(v > 0 for v in facts["step_bytes"]["gather"].values())
+    assert all(v > 0 for v in facts["step_bytes"]["agg"].values())
+    after = _digests(root)
+    assert {p: d for p, d in after.items() if p in before} == before
+    assert len(after) == len(before) + 2

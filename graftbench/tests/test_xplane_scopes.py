@@ -9,10 +9,10 @@ import types
 
 import pytest
 
-from graftbench import trace_reduce, xplane_scopes
+from graftbench import flops, trace_reduce, xplane_scopes
 from graftbench.layer_metrics import (
-    agg_step_ms, gather_step_ms, model_dense_step_ms, optimizer_step_ms,
-    scope_coverage,
+    agg_roofline_share, agg_step_ms, gather_roofline_share, gather_step_ms,
+    model_dense_step_ms, optimizer_step_ms, scope_coverage,
 )
 
 DATA = os.path.join(
@@ -168,6 +168,53 @@ def pytest_step_readers_add_up_to_device_step_ms(scoped, monkeypatch):
     assert scope_coverage.read(run) == pytest.approx(100 * scoped["coverage"])
 
 
+def pytest_roofline_readers_on_the_scoped_trace(scoped, monkeypatch):
+    """The recorded step (``testdata/record_scoped.py``) gathers 65,536 rows
+    of a learned [4096, 128] table and segment-sums 65,536 messages of 128
+    into 4,096 rows, forward and backward: the counted bytes over the device
+    time of each scope over the v5e's 819 GB/s."""
+    n, e, f = 4096, 65536, 128
+    counted = flops.total(
+        [flops.gather(n, e, f), flops.segment_reduce(e, n, f)]
+    )["bytes"]
+    assert counted["gather"] == {"fwd": 4 * (2 * e * f + e), "bwd": 4 * (e * f + e + n * f)}
+    assert counted["agg"] == {"fwd": 4 * (e * f + e + n * f), "bwd": 4 * (n * f + e + e * f)}
+    run = _run()
+    run.facts.update(step_bytes={k: counted[k] for k in ("gather", "agg")}, chips=1)
+    run.peaks = {"hbm_bytes_per_s": 819e9}
+    scoped = dict(scoped, step_ms=xplane_scopes.step_split(scoped, 3))
+    monkeypatch.setattr(xplane_scopes, "table", lambda _run: scoped)
+    for reader, ms, scope in ((gather_roofline_share, gather_step_ms, "gather"),
+                              (agg_roofline_share, agg_step_ms, "agg")):
+        share = reader.read(run)
+        gb_s = sum(counted[scope].values()) / (ms.read(run) * 1e-3) / 1e9
+        assert share == pytest.approx(100 * gb_s / 819)
+        assert 1.0 < share < 100.0, (scope, share)
+    # The split ``scopes.json`` keeps: the same seconds and bytes by
+    # direction, XLA's figure beside the counted one.
+    split = xplane_scopes.bytes_split(scoped, run.facts)
+    assert set(split) == {"gather", "agg"}
+    for scope, directions in split.items():
+        assert set(directions) == {"fwd", "bwd"}
+        assert sum(d["ms"] for d in directions.values()) == pytest.approx(
+            scoped["step_ms"][scope]
+        )
+        for direction, d in directions.items():
+            assert d["counted_bytes"] == counted[scope][direction]
+            assert d["xla_bytes"] > 0
+            assert d["counted_gb_s"] == pytest.approx(
+                d["counted_bytes"] / d["ms"] / 1e6
+            )
+    # Two chips: the counted bytes are all chips', the seconds a chip's mean.
+    run.facts["chips"] = 2
+    assert gather_roofline_share.read(run) == pytest.approx(
+        50 * sum(counted["gather"].values()) / (gather_step_ms.read(run) * 1e-3) / 819e9
+    )
+    # No count (a driver that gives none), no reading.
+    del run.facts["step_bytes"]
+    assert gather_roofline_share.read(run) is None
+
+
 def pytest_readers_return_nothing_without_scopes_or_trace(monkeypatch, tmp_path):
     """On a program that opens no leaf scope (the parent of PR 23) the agg
     and gather readers return None and nothing raises; with no trace at all
@@ -176,15 +223,18 @@ def pytest_readers_return_nothing_without_scopes_or_trace(monkeypatch, tmp_path)
     small = dict(small, step_ms=xplane_scopes.step_split(small, 3))
     run = _run()
     monkeypatch.setattr(xplane_scopes, "table", lambda _run: small)
-    assert agg_step_ms.read(run) is None
-    assert gather_step_ms.read(run) is None
+    run.facts["step_bytes"] = {"gather": {"fwd": 1, "bwd": 1}, "agg": {"fwd": 1, "bwd": 1}}
+    run.peaks = {"hbm_bytes_per_s": 819e9}
+    assert agg_step_ms.read(run) is None and agg_roofline_share.read(run) is None
+    assert gather_step_ms.read(run) is None and gather_roofline_share.read(run) is None
     assert model_dense_step_ms.read(run) is None
     assert optimizer_step_ms.read(run) > 0  # rooted, no module
     assert scope_coverage.read(run) == 0.0
     monkeypatch.undo()
     run.cell.trace_dir = str(tmp_path)
     for reader in (agg_step_ms, gather_step_ms, model_dense_step_ms,
-                   optimizer_step_ms, scope_coverage):
+                   optimizer_step_ms, scope_coverage, agg_roofline_share,
+                   gather_roofline_share):
         assert reader.read(run) is None
 
 

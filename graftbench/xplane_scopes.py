@@ -38,11 +38,15 @@ by ``hlo_category`` and shape under ``uncovered``.
 
 ``flops`` and ``bytes_accessed`` are XLA's own figures for each operation,
 summed over the executions of LEAF events (an event that contained others, a
-``while`` or a call, adds none: its children carry them). Nobody has checked
-them against a hand count for a gather or an in-place scatter: they are
-recorded so that a later ``agg_roofline_share`` can be defined from numbers.
+``while`` or a call, adds none: its children carry them). They are not the
+traffic the algorithm needs: over padded rows and tiles they pass the chip's
+HBM peak in places, and an in-place scatter-add is charged a fifth of the
+rows it rewrites (PERF.md section 6, PR 25). So no metric reads them:
+``gather_roofline_share`` and ``agg_roofline_share`` divide the bytes
+``graftbench/flops.py`` counts by hand from real rows by the seconds found
+here, and ``scopes.json`` keeps both figures side by side (``bytes_a_step``).
 
-``table(run)`` is what the five readers in ``layer_metrics/`` share: one
+``table(run)`` is what the by-scope readers in ``layer_metrics/`` share: one
 parse a trace (memoised by path), written whole to
 ``graftbench/out/<cell>/scopes.json``, its ten largest rows printed on a
 ``[graftbench]`` line. By hand, on any trace directory (a ``"Profile"`` run's
@@ -392,12 +396,14 @@ def table(run):
         steps = run.facts.get("steps") or 0
         result = by_scope(path)
         result = dict(result, steps=steps, step_ms=step_split(result, steps))
+        result["bytes_a_step"] = bytes_split(result, run.facts)
         _TABLES[path] = result
         out = os.path.join(run.cell.out_dir, "scopes.json")
         with open(out, "w") as f:
             json.dump(result, f, indent=1)
         print("[graftbench] scopes: " + json.dumps({
             "coverage": result["coverage"], "step_ms": result["step_ms"],
+            "bytes_a_step": result["bytes_a_step"],
             "device_step_ms_by_host_span": device_step_ms.read(run),
             "top": [
                 [r["root"], r["direction"], r["module"], r["scope"],
@@ -424,11 +430,56 @@ def step_split(result: dict, steps: int) -> dict:
     return out
 
 
+def bytes_split(result: dict, facts: dict) -> dict:
+    """For the gathers and the aggregation of the train root, forward and
+    backward, a step and a chip: device milliseconds, the bytes
+    ``graftbench/flops.py`` counts by hand (``facts["step_bytes"]``) and
+    XLA's ``bytes_accessed``, each also as the GB/s it stands for. Side by
+    side so that the two figures can be compared by eye; only the counted
+    one is read by a metric."""
+    steps, chips = facts.get("steps"), facts.get("chips", 1)
+    counted = facts.get("step_bytes")
+    out = {}
+    if not steps or not counted:
+        return out
+    for row in result["rows"]:
+        layer = bucket(row)
+        if row["root"] != "train" or layer not in counted:
+            continue
+        cell = out.setdefault(layer, {}).setdefault(
+            row["direction"], {"ms": 0.0, "xla_bytes": 0.0}
+        )
+        cell["ms"] += 1e3 * row["seconds"] / steps
+        cell["xla_bytes"] += row["bytes_accessed"] / steps
+    for layer, directions in out.items():
+        for direction, cell in directions.items():
+            cell["counted_bytes"] = counted[layer][direction] / chips
+            for key in ("counted", "xla"):
+                cell[key + "_gb_s"] = cell[key + "_bytes"] / cell["ms"] / 1e6
+    return out
+
+
 def step_ms(run, layer: str):
     """What ``<layer>_step_ms`` reads; None where the trace holds nothing of
     that layer (a program without the scopes, as before PR 23)."""
     result = table(run)
     return None if result is None else result["step_ms"].get(layer) or None
+
+
+def roofline_share(run, layer: str):
+    """What ``<layer>_roofline_share`` reads: the bytes a train step of
+    ``layer`` needs (``graftbench/flops.py``: counted by hand from real rows,
+    forward and backward, a chip) over what the chip's HBM could move in the
+    device time the layer took (``<layer>_step_ms``). Bytes bound both
+    layers: a gather has no operations and a reduction one an element. None
+    where ``<layer>_step_ms`` is. Not clamped: a reading over 100% means the
+    count is wrong, not the chip fast."""
+    ms = step_ms(run, layer)
+    counted = (run.facts.get("step_bytes") or {}).get(layer)
+    if not ms or not counted or not run.peaks:
+        return None
+    per_chip = sum(counted.values()) / run.facts.get("chips", 1)
+    return 100.0 * per_chip / (ms * 1e-3 * run.peaks["hbm_bytes_per_s"])
 
 
 if __name__ == "__main__":
