@@ -174,6 +174,7 @@ def check_config(
         _check_pilot(pilot, errors)
     _check_donation(training, errors)
     _check_aggregation_path(arch, errors)
+    _check_position_family(arch, errors)
 
     eval_shape_s = None
     if not errors and not deep:
@@ -747,6 +748,7 @@ def _expected_param_fingerprint(arch) -> Optional[str]:
         arch["output_type"],
         edge_dim=arch.get("edge_dim"),
         num_nodes=int(arch.get("num_nodes") or 8),
+        with_positions=model.needs_positions,
     )
     batch_sds = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype),
@@ -1327,6 +1329,43 @@ def _check_aggregation_path(arch, errors):
     )
 
 
+# ----------------------------------------------------------- position families
+def _check_position_family(arch, errors):
+    """The families that compute their edge geometry in the step from
+    ``GraphBatch.positions`` (models/convs.py:POSITION_FAMILIES — PaiNN):
+    the cutoff and the basis size must be there, and the edge vector must be
+    the difference of the two positions, which it is not across a periodic
+    cell boundary."""
+    from ..models.convs import POSITION_FAMILIES
+
+    mt = arch.get("model_type")
+    if mt not in POSITION_FAMILIES:
+        return
+    radius, num_radial = arch.get("radius"), arch.get("num_radial")
+    if not isinstance(radius, (int, float)) or radius <= 0:
+        errors.append(
+            ("bad-arch", f"model_type={mt} needs Architecture.radius > 0 (the cutoff)")
+        )
+    if not isinstance(num_radial, int) or num_radial < 1:
+        errors.append(
+            (
+                "bad-arch",
+                f"model_type={mt} needs Architecture.num_radial >= 1 (the "
+                "number of radial basis functions)",
+            )
+        )
+    if arch.get("periodic_boundary_conditions"):
+        errors.append(
+            (
+                "bad-arch",
+                f"model_type={mt} computes r_ij = pos[j] - pos[i] inside the "
+                "step; under periodic_boundary_conditions an edge across the "
+                "cell boundary has another vector — the batch carries no "
+                "cell shifts yet",
+            )
+        )
+
+
 # ------------------------------------------------------------------- donation
 def _check_donation(training, errors):
     if str(training.get("optimizer", "")).upper() == "LBFGS" and int(
@@ -1424,7 +1463,7 @@ def _check_shapes(config, arch, voi, training, mode, completed, errors, skipped)
 
     example = make_example_batch(
         input_dim, output_dim, output_type, edge_dim=edge_dim,
-        num_nodes=num_nodes,
+        num_nodes=num_nodes, with_positions=model.needs_positions,
     )
     # CSR batch contract (graphs/csr.py): the example batch carries the same
     # row pointers production collation emits — validate length, endpoints,

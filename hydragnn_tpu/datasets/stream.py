@@ -285,6 +285,7 @@ class StreamingGraphLoader(GraphDataLoader):
         ladder_step: str = "pow2",
         ring_depth: int = 2,
         resident_shards: int = 8,
+        with_positions: bool = False,
     ):
         if reshuffle not in ("sample", "batch"):
             raise ValueError(
@@ -314,6 +315,9 @@ class StreamingGraphLoader(GraphDataLoader):
             )
             edge_dim = width or None
         self.edge_dim = edge_dim
+        # GraphBatch.positions for the families that read them; config
+        # completion sets it from the model family, as it sets edge_dim.
+        self.with_positions = with_positions
         self.reshuffle = reshuffle
         self.packing = bool(packing)
         self.ladder_step = ladder_step
@@ -600,6 +604,7 @@ class StreamingGraphLoader(GraphDataLoader):
                 num_edges_pad=e_pad,
                 num_graphs_pad=g_pad,
                 edge_dim=self.edge_dim,
+                with_positions=self.with_positions,
             )
             return self._maybe_cache(pos, batch)
         first = int(sids[0])
@@ -616,6 +621,7 @@ class StreamingGraphLoader(GraphDataLoader):
                 num_edges_pad=e_pad,
                 num_graphs_pad=g_pad,
                 edge_dim=self.edge_dim,
+                with_positions=self.with_positions,
             )
         else:
             samples = [
@@ -630,6 +636,7 @@ class StreamingGraphLoader(GraphDataLoader):
                 num_edges_pad=e_pad,
                 num_graphs_pad=g_pad,
                 edge_dim=self.edge_dim,
+                with_positions=self.with_positions,
             )
         return self._maybe_cache(pos, batch)
 

@@ -51,6 +51,12 @@ class GraphBatch:
                       re-searching ids every layer.
       graph_ptr:      [G_pad + 1] int32 or None — the same boundaries over
                       ``node_graph`` (node→graph readout pooling).
+      positions:      [N_pad, 3] float32 or None — node coordinates (padding
+                      rows zero), carried only for the families that compute
+                      their edge geometry inside the step
+                      (``models/convs.py:POSITION_FAMILIES``). None is an
+                      empty subtree: every other family's batch, program and
+                      host-to-device bytes are what they were without it.
       num_graphs_pad: static python int (G_pad). Needed as a static segment count.
     """
 
@@ -65,6 +71,7 @@ class GraphBatch:
     targets: Tuple[jnp.ndarray, ...] = ()
     row_ptr: Optional[jnp.ndarray] = None
     graph_ptr: Optional[jnp.ndarray] = None
+    positions: Optional[jnp.ndarray] = None
     num_graphs_pad: int = struct.field(pytree_node=False, default=0)
 
     @property

@@ -10,6 +10,7 @@ on the host, once per batch.
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -62,6 +63,7 @@ def collate_graphs(
     num_edges_pad: Optional[int] = None,
     num_graphs_pad: Optional[int] = None,
     edge_dim: Optional[int] = None,
+    with_positions: bool = False,
 ) -> GraphBatch:
     """Pack graphs into one padded GraphBatch (numpy arrays, host-side).
 
@@ -78,6 +80,7 @@ def collate_graphs(
         num_edges_pad=num_edges_pad,
         num_graphs_pad=num_graphs_pad,
         edge_dim=edge_dim,
+        with_positions=with_positions,
     )
 
 
@@ -114,6 +117,9 @@ class GraphArena:
         self.x_all = np.concatenate(
             [np.asarray(s.x, dtype=np.float32) for s in graphs]
         )
+        # Kept for ``pos_all`` alone, which only a family that reads positions
+        # asks for: the serving engine builds an arena a flush.
+        self._graphs = graphs
         with_edges = [s for s in graphs if s.num_edges]
         if with_edges:
             self.ei_all = np.concatenate(
@@ -179,6 +185,16 @@ class GraphArena:
                 [np.asarray(s.y_loc, dtype=np.int64).reshape(-1) for s in graphs]
             )
 
+    @functools.cached_property
+    def pos_all(self) -> Optional[np.ndarray]:
+        """[total nodes, 3] node coordinates (``collate(with_positions=...)``);
+        None where a sample has none."""
+        if any(s.pos is None for s in self._graphs):
+            return None
+        return np.concatenate(
+            [np.asarray(s.pos, dtype=np.float32).reshape(-1, 3) for s in self._graphs]
+        )
+
     @staticmethod
     def _ragged_rows(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
         """Flat arena row indices for per-sample ranges [start, start+len)."""
@@ -196,9 +212,12 @@ class GraphArena:
         num_edges_pad: Optional[int] = None,
         num_graphs_pad: Optional[int] = None,
         edge_dim: Optional[int] = None,
+        with_positions: bool = False,
     ) -> GraphBatch:
         """Pack the samples at ``idx`` — same output as ``collate_graphs`` on
-        the corresponding GraphSample list (parity-tested)."""
+        the corresponding GraphSample list (parity-tested). ``with_positions``
+        adds ``positions`` [N_pad, 3] (padding rows zero, so a padding edge
+        has length 0: the families that read them guard the division)."""
         idx = np.asarray(idx, dtype=np.int64)
         g = len(idx)
         ns, es = self.ns[idx], self.es[idx]
@@ -229,6 +248,15 @@ class GraphArena:
         node_features[:tot_nodes] = self.x_all[node_rows]
         node_graph[:tot_nodes] = np.repeat(np.arange(g, dtype=np.int32), ns)
         node_mask[:tot_nodes] = True
+        positions = None
+        if with_positions:
+            if self.pos_all is None:
+                raise ValueError(
+                    "positions requested but the dataset has samples "
+                    "without pos"
+                )
+            positions = np.zeros((n_pad, 3), dtype=np.float32)
+            positions[:tot_nodes] = self.pos_all[node_rows]
 
         if edge_dim is None:
             has_edge_attr = self.ea_all is not None
@@ -315,6 +343,7 @@ class GraphArena:
             targets=tuple(targets),
             row_ptr=row_ptr,
             graph_ptr=graph_ptr,
+            positions=positions,
             num_graphs_pad=g_pad,
         )
 

@@ -1,10 +1,15 @@
 """HydraGNN multi-headed GNN — the flax re-design of the reference architecture
 core (/root/reference/hydragnn/models/Base.py:20-372 plus the per-conv Stack
-subclasses). One module covers all six conv families; the conv flavor is a static
+subclasses). One module covers all seven families; the conv flavor is a static
 field, so each (conv_type, dims) combination compiles to one XLA program.
 
 Architecture (mirrors reference semantics under padding):
-  encoder:   num_conv_layers × [conv → MaskedBatchNorm → ReLU]
+  encoder:   batch → [N, enc_dim]. The six classic families:
+             num_conv_layers × [conv → MaskedBatchNorm → ReLU] over ONE
+             array. PaiNN: num_conv_layers × [message → update] over a scalar
+             and a vector state, the edge geometry computed once from
+             ``batch.positions``; no norm, no ReLU (models/painn.py). The
+             read-out and the heads read the scalar state.
   readout:   masked segment-mean over nodes per graph (global_mean_pool analog)
   heads:     graph heads = shared MLP ("graph_shared") + per-head MLP;
              node heads = shared MLPNode ('mlp' / 'mlp_per_node') or a conv chain
@@ -25,9 +30,12 @@ from ..ops import pallas_segment
 from ..ops import segment as seg
 from ..telemetry import scopes
 from .layers import MLP, MaskedBatchNorm
-from .convs import CGConv, GATv2Conv, GINConv, MFCConv, PNAConv, SAGEConv
+from . import painn
+from .convs import (
+    POSITION_FAMILIES, CGConv, GATv2Conv, GINConv, MFCConv, PNAConv, SAGEConv,
+)
 
-CONV_TYPES = ("PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE")
+CONV_TYPES = ("PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE", "PAINN")
 
 
 class MLPNode(nn.Module):
@@ -42,12 +50,13 @@ class MLPNode(nn.Module):
     out_dim: int
     node_type: str  # 'mlp' | 'mlp_per_node'
     num_nodes: Optional[int] = None
+    precision: Any = None  # matmul precision, as ``MLP.precision``
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, batch: GraphBatch) -> jnp.ndarray:
         dims = tuple(self.hidden_dims) + (self.out_dim,)
         if self.node_type == "mlp":
-            return MLP(dims, name="mlp")(x)
+            return MLP(dims, precision=self.precision, name="mlp")(x)
         assert self.num_nodes is not None, "mlp_per_node requires fixed graph size"
         n, f = x.shape
         # Node position within its graph: nodes are contiguous per graph by
@@ -66,7 +75,7 @@ class MLPNode(nn.Module):
             b = self.param(f"b_{li}", nn.initializers.zeros, (self.num_nodes, d))
             with jax.named_scope(scopes.GATHER):  # per-slot weight rows
                 w_n, b_n = w[pos], b[pos]
-            h = jnp.einsum("nf,nfo->no", h, w_n) + b_n
+            h = jnp.einsum("nf,nfo->no", h, w_n, precision=self.precision) + b_n
             if li < len(dims) - 1:
                 h = nn.relu(h)
             in_dim = d
@@ -107,10 +116,28 @@ class HydraGNN(nn.Module):
     mfc_max_degree: int = 10
     gat_heads: int = 6  # create.py:113
     gat_negative_slope: float = 0.05  # create.py:114
+    # PaiNN: the cutoff (Architecture.radius) and the number of radial basis
+    # functions (Architecture.num_radial).
+    radius: Optional[float] = None
+    num_radial: Optional[int] = None
 
     @property
     def use_edge_attr(self) -> bool:
         return self.edge_dim is not None and self.edge_dim > 0
+
+    @property
+    def head_precision(self):
+        """Matmul precision of the heads: PaiNN's pooled state passes no norm
+        layer (rms 1.1-1.4 on outputs of O(0.3) at F 128), and one bf16 pass
+        over it in the shared and head MLPs alone reads 2e-3 to 6.7e-3 from
+        the plain reference (models/painn.py has the chip's readings)."""
+        return painn.PRECISION if self.conv_type == "PAINN" else None
+
+    @property
+    def needs_positions(self) -> bool:
+        """Whether the batch must carry ``positions`` (the loaders and the
+        serving engine ask)."""
+        return self.conv_type in POSITION_FAMILIES
 
     @property
     def enc_dim(self) -> int:
@@ -155,9 +182,12 @@ class HydraGNN(nn.Module):
             )
         raise ValueError(f"Unknown conv_type {ct}")
 
-    def setup(self):
-        if self.conv_type not in CONV_TYPES:
-            raise ValueError(f"Unknown conv_type {self.conv_type}")
+    # The encoder's helpers are ``nowrap``: flax would write a wrapped
+    # method's name into every operation's name stack, and the module column
+    # of the device-time table (graftbench/xplane_scopes.py) reads that stack.
+    @nn.nowrap
+    def _setup_conv_encoder(self):
+        """The classic families: ``conv_<i>`` and ``bn_<i>``."""
         gat = self.conv_type == "GAT"
         h = self.gat_heads
 
@@ -187,6 +217,28 @@ class HydraGNN(nn.Module):
         self.convs = convs
         self.batch_norms = bns
 
+    @nn.nowrap
+    def _setup_painn_encoder(self):
+        """PaiNN: a Dense of the input features for s⁰ and one block a layer.
+        The names keep the ``conv_`` prefix so that ``freeze_conv_layers``
+        (utils/optimizer.py) freezes the whole encoder, as for the others."""
+        block = nn.remat(painn.PaiNNBlock) if self.remat else painn.PaiNNBlock
+        self.conv_embed = painn.Dense(self.hidden_dim)
+        self.convs = [
+            block(self.hidden_dim, axis_name=self.graph_axis, name=f"conv_{i}")
+            for i in range(self.num_conv_layers)
+        ]
+
+    def setup(self):
+        if self.conv_type not in CONV_TYPES:
+            raise ValueError(f"Unknown conv_type {self.conv_type}")
+        gat = self.conv_type == "GAT"
+        h = self.gat_heads
+        if self.conv_type == "PAINN":
+            self._setup_painn_encoder()
+        else:
+            self._setup_conv_encoder()
+
         node_head_idx = [i for i, t in enumerate(self.output_type) if t == "node"]
         self.node_nn_type = (
             self.config_heads.get("node", {}).get("type") if node_head_idx else None
@@ -196,10 +248,12 @@ class HydraGNN(nn.Module):
         # override GATStack.py:48-86; CGCNN forbids 'conv' CGCNNStack.py:53-75) ---
         nch, ncb, nco, ncob = [], [], [], []
         if node_head_idx and self.node_nn_type == "conv":
-            if self.conv_type == "CGCNN":
+            if self.conv_type in ("CGCNN", "PAINN"):
+                # CGCNN preserves channels; a PaiNN block has two states and
+                # no width to narrow to a head's output.
                 raise ValueError(
-                    '"conv" node decoder is not supported for CGCNN; use "mlp" or '
-                    '"mlp_per_node"'
+                    f'"conv" node decoder is not supported for {self.conv_type}; '
+                    'use "mlp" or "mlp_per_node"'
                 )
             hd = list(self.config_heads["node"]["dim_headlayers"])
             nlayers = self.config_heads["node"]["num_headlayers"]
@@ -250,6 +304,7 @@ class HydraGNN(nn.Module):
                 tuple([gcfg["dim_sharedlayers"]] * gcfg["num_sharedlayers"]),
                 activate_final=True,
                 inner_activation=layout != "reference",
+                precision=self.head_precision,
                 name="graph_shared",
             )
 
@@ -264,6 +319,7 @@ class HydraGNN(nn.Module):
                     MLP(
                         dims,
                         final_bias_value=self.initial_bias,
+                        precision=self.head_precision,
                         name=f"head_{ihead}",
                     )
                 )
@@ -276,6 +332,7 @@ class HydraGNN(nn.Module):
                             hdim,
                             self.node_nn_type,
                             num_nodes=self.num_nodes,
+                            precision=self.head_precision,
                             name=f"head_{ihead}",
                         )
                     )
@@ -290,7 +347,8 @@ class HydraGNN(nn.Module):
                 raise ValueError(f"Unknown head type {htype}")
         self.heads_nn = heads
 
-    def __call__(self, batch: GraphBatch, train: bool = False):
+    @nn.nowrap
+    def _encode_convs(self, batch: GraphBatch, train: bool):
         x = batch.node_features
         edge_attr = batch.edge_features if self.use_edge_attr else None
         # Reference encoder loop: x = relu(bn(conv(x))) (Base.py:236-243).
@@ -310,6 +368,32 @@ class HydraGNN(nn.Module):
                 batch.row_ptr,
             )
             x = nn.relu(bn(c, batch.node_mask, train))
+        return x
+
+    @nn.nowrap
+    def _encode_painn(self, batch: GraphBatch):
+        if batch.positions is None:
+            raise ValueError(
+                "PAINN reads GraphBatch.positions: collate with "
+                "with_positions=True (config completion and the serving "
+                "engine do, from the model family)"
+            )
+        geom = painn.edge_geometry(
+            batch.positions, batch.senders, batch.receivers, batch.edge_mask,
+            self.radius, self.num_radial,
+        )
+        s = self.conv_embed(batch.node_features)
+        v = jnp.zeros((s.shape[0], 3 * self.hidden_dim), s.dtype)
+        for block in self.convs:
+            s, v = block(s, v, geom, batch.senders, batch.receivers, batch.row_ptr)
+        # Padding rows at zero, as the batch norms leave them for the others.
+        return jnp.where(batch.node_mask[:, None], s, 0.0)
+
+    def __call__(self, batch: GraphBatch, train: bool = False):
+        if self.conv_type == "PAINN":
+            x = self._encode_painn(batch)
+        else:
+            x = self._encode_convs(batch, train)
 
         # Masked global mean pool (Base.py:247-250); graph_ptr is the CSR
         # boundary array over node_graph (nodes are contiguous per graph).

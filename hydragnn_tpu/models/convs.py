@@ -41,7 +41,13 @@ from ..telemetry import scopes
 # this registry: a future family missing here would silently fall back to
 # the unsorted scatter path on TPU, which the contract checker now rejects
 # instead (analysis/contracts.py).
-SORTED_PATH_FAMILIES = frozenset({"SAGE", "GIN", "MFC", "GAT", "CGCNN", "PNA"})
+SORTED_PATH_FAMILIES = frozenset(
+    {"SAGE", "GIN", "MFC", "GAT", "CGCNN", "PNA", "PAINN"}
+)
+# Families that compute their edge geometry inside the step, from
+# ``GraphBatch.positions``; the loaders carry positions for these alone
+# (utils/config_utils.py, serve/engine.py).
+POSITION_FAMILIES = frozenset({"PAINN"})
 
 
 class SAGEConv(nn.Module):

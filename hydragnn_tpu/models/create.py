@@ -37,6 +37,8 @@ def create_model_config(
         max_neighbours=config.get("max_neighbours"),
         edge_dim=config.get("edge_dim"),
         pna_deg=config.get("pna_deg"),
+        radius=config.get("radius"),
+        num_radial=config.get("num_radial"),
         compute_dtype=config.get("compute_dtype"),
         remat=config.get("remat", False),
         verbosity=verbosity,
@@ -58,6 +60,8 @@ def create_model(
     max_neighbours: Optional[int] = None,
     edge_dim: Optional[int] = None,
     pna_deg: Optional[Sequence[float]] = None,
+    radius: Optional[float] = None,
+    num_radial: Optional[int] = None,
     compute_dtype: Optional[str] = None,
     remat: bool = False,
     verbosity: int = 0,
@@ -81,6 +85,13 @@ def create_model(
         kwargs.update(mfc_max_degree=int(max_neighbours))
     elif model_type == "CGCNN":
         hidden_dim = input_dim  # CGCNN preserves channels (CGCNNStack.py:31-42)
+    elif model_type == "PAINN":
+        if radius is None or num_radial is None:
+            raise ValueError(
+                "PAINN requires radius (the cutoff) and num_radial (the "
+                "number of radial basis functions) in Architecture."
+            )
+        kwargs.update(radius=float(radius), num_radial=int(num_radial))
     return HydraGNN(
         conv_type=model_type,
         input_dim=input_dim,
@@ -113,8 +124,10 @@ def make_example_batch(
     output_type: Sequence[str],
     edge_dim: Optional[int] = None,
     num_nodes: int = 4,
+    with_positions: bool = False,
 ) -> GraphBatch:
-    """A tiny structurally-valid batch for shape inference / init."""
+    """A tiny structurally-valid batch for shape inference / init
+    (``with_positions`` for the families of ``convs.POSITION_FAMILIES``)."""
     from ..graphs.sample import GraphSample
 
     n = num_nodes
@@ -132,11 +145,14 @@ def make_example_batch(
     for i, (d, t) in enumerate(zip(output_dim, output_type)):
         off += d if t == "graph" else d * n
         y_loc[0, i + 1] = off
-    s = GraphSample(x=x, pos=np.zeros((n, 3), np.float32), y=y, y_loc=y_loc,
-                    edge_index=ei, edge_attr=ea)
+    # Atoms a unit apart on a line: no edge of length 0.
+    pos = np.zeros((n, 3), np.float32)
+    pos[:, 0] = np.arange(n)
+    s = GraphSample(x=x, pos=pos, y=y, y_loc=y_loc, edge_index=ei, edge_attr=ea)
     return collate_graphs(
         [s],
         head_types=output_type,
         head_dims=output_dim,
         edge_dim=edge_dim,
+        with_positions=with_positions,
     )

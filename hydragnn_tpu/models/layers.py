@@ -9,7 +9,7 @@ averages (momentum 0.1, i.e. decay 0.9) for eval mode.
 
 from __future__ import annotations
 
-from typing import Sequence
+from typing import Any, Sequence
 
 import jax.numpy as jnp
 import flax.linen as nn
@@ -69,6 +69,9 @@ class MLP(nn.Module):
     activate_final: bool = False
     final_bias_value: float | None = None
     inner_activation: bool = True
+    # Matmul precision of every layer (None: the backend's default, one bf16
+    # pass on the TPU). PaiNN's heads run at HIGHEST (models/painn.py).
+    precision: Any = None
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -78,10 +81,11 @@ class MLP(nn.Module):
                 x = nn.Dense(
                     d,
                     bias_init=nn.initializers.constant(self.final_bias_value),
+                    precision=self.precision,
                     name=f"dense_{i}",
                 )(x)
             else:
-                x = nn.Dense(d, name=f"dense_{i}")(x)
+                x = nn.Dense(d, precision=self.precision, name=f"dense_{i}")(x)
             if (last and self.activate_final) or (
                 not last and self.inner_activation
             ):

@@ -240,6 +240,7 @@ def _shard_loader_view(loader, world_size: int, rank: int):
         reshuffle=loader.reshuffle,
         packing=loader.packing,
         ladder_step=loader.ladder_step,
+        with_positions=loader.with_positions,
     )
 
 

@@ -92,6 +92,7 @@ class GraphDataLoader:
         fault_plan=None,
         packing: bool = False,
         ladder_step: str = "pow2",
+        with_positions: Optional[bool] = None,
     ):
         """``reshuffle`` picks the per-epoch shuffling granularity:
 
@@ -125,6 +126,17 @@ class GraphDataLoader:
         convergence parity is locked by tests/test_packing.py.
         ``ladder_step`` picks the pad round-up ladder (``"pow2"`` historical,
         ``"mult64"``: multiples of 64 above 256 — docs/INPUT_PIPELINE.md).
+
+        ``with_positions`` puts the node coordinates into every batch
+        (``GraphBatch.positions``) for the families that compute their edge
+        geometry in the step. Config completion sets it from the model
+        family, as it sets ``edge_dim``; the attribute, ``GraphArena.collate``
+        and the streaming loader take a plain bool, False unless asked. Only
+        this argument has a None, "nobody said", resolved here to whether
+        every sample has ``pos``: the benchmark's reference check builds its
+        loader from ``head_types``/``head_dims``/``edge_dim`` alone
+        (graftbench/drivers/train_epochs.py ``_program_forward``; PERF.md
+        section 7 has the one-line edit that lets the default become False).
         """
         if reshuffle not in ("sample", "batch"):
             raise ValueError(
@@ -144,6 +156,11 @@ class GraphDataLoader:
         self.head_types = tuple(head_types) if head_types else None
         self.head_dims = tuple(head_dims) if head_dims else None
         self.edge_dim = edge_dim
+        self.with_positions = (
+            bool(self.dataset) and all(s.pos is not None for s in self.dataset)
+            if with_positions is None
+            else bool(with_positions)
+        )
         self.reshuffle = reshuffle
         self.packing = bool(packing)
         self.ladder_step = ladder_step
@@ -478,6 +495,7 @@ class GraphDataLoader:
                 num_edges_pad=e_pad,
                 num_graphs_pad=g_pad,
                 edge_dim=self.edge_dim,
+                with_positions=self.with_positions,
             )
             if pos is not None:
                 # Frozen membership (reshuffle="batch"): the collation is

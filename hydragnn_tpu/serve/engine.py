@@ -353,6 +353,9 @@ class InferenceEngine:
         self._y_minmax = y_minmax
         self._g_pad = self.max_batch_graphs + 1
         self._edge_dim = model.edge_dim if model.use_edge_attr else 0
+        # Node coordinates ride in the batch for the families that compute
+        # their edge geometry in the step (PaiNN).
+        self._with_positions = model.needs_positions
         # The bucket ladder is published like the weights: ONE sorted-list
         # reference, rebound atomically under _lock by warmup()'s merge and
         # swap_ladder() (the flywheel's drift-refit path). The batcher takes
@@ -758,6 +761,13 @@ class InferenceEngine:
                 raise ValueError(
                     "sample.edge_index references nodes outside the graph"
                 )
+        if self._with_positions and (
+            sample.pos is None or np.shape(sample.pos) != (sample.num_nodes, 3)
+        ):
+            raise ValueError(
+                f"model reads node positions: sample.pos must be "
+                f"[{sample.num_nodes}, 3]"
+            )
         if self._edge_dim and sample.num_edges:
             # The model consumes per-edge features: a missing attr would
             # silently zero-fill (wrong predictions with a 200), a wrong
@@ -920,6 +930,7 @@ class InferenceEngine:
                 num_edges_pad=e_pad,
                 num_graphs_pad=self._g_pad,
                 edge_dim=self._edge_dim,
+                with_positions=self._with_positions,
             )
         self.metrics.observe("collate", time.perf_counter() - t0)
         self.metrics.record_batch(
@@ -1286,6 +1297,7 @@ class InferenceEngine:
             num_edges_pad=e_pad,
             num_graphs_pad=self._g_pad,
             edge_dim=self._edge_dim,
+            with_positions=self._with_positions,
         )
 
     # ------------------------------------------------------ hot ladder swap
@@ -1596,6 +1608,7 @@ class InferenceEngine:
             num_edges_pad=e_pad,
             num_graphs_pad=self._g_pad,
             edge_dim=self._edge_dim,
+            with_positions=self._with_positions,
         )
         dev = jax.device_put(batch)
         quant = [
@@ -1705,6 +1718,7 @@ class InferenceEngine:
             arch["output_type"],
             edge_dim=arch.get("edge_dim"),
             num_nodes=arch.get("num_nodes") or 4,
+            with_positions=model.needs_positions,
         )
         variables = init_model_variables(model, example)
 

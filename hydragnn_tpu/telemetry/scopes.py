@@ -37,6 +37,10 @@ ROOTS = (TRAIN_STEP, TRAIN_EPOCH_SCAN, EVAL_STEP)
 # Leaves.
 GATHER = "hydragnn.gather"  # node -> edge row gathers (backward: scatter-adds)
 POOL = "hydragnn.pool"  # graph read-out
+# What depends on positions alone (PaiNN): edge vectors, lengths, the radial
+# basis, the cutoff, and each block's filter Dense over them. Its two
+# position gathers stay under GATHER (the innermost name wins).
+GEOM = "hydragnn.geom"
 LOSS = "hydragnn.loss"
 OPTIMIZER = "hydragnn.optimizer"  # update, apply, loss-scale and guard selects
 GRAD_SYNC = "hydragnn.grad_sync"  # the mesh step's psums of gradients/counts
@@ -57,7 +61,7 @@ def agg(what: str, arm: str) -> str:
 
 VOCABULARY = frozenset(
     ROOTS
-    + (GATHER, POOL, LOSS, OPTIMIZER, GRAD_SYNC, AGG_PNA)
+    + (GATHER, POOL, GEOM, LOSS, OPTIMIZER, GRAD_SYNC, AGG_PNA)
     + tuple(agg(w, a) for w in AGG_WHATS for a in AGG_ARMS)
 )
 

@@ -110,7 +110,8 @@ def _loss_and_metrics(model: HydraGNN, params, batch_stats, batch, dropout_key):
         loss, rmses = multihead_rmse_loss(
             outputs, batch, model.output_type, model.task_weights
         )
-    return loss, (mut["batch_stats"], rmses)
+    # A family without batch norm (PaiNN) has no such collection.
+    return loss, (mut.get("batch_stats", batch_stats), rmses)
 
 
 def state_donation_safe(state: TrainState) -> bool:
@@ -416,6 +417,8 @@ def _batch_pspec(batch: GraphBatch, graph_sharded: bool) -> GraphBatch:
         # zero-searchsorted).
         row_ptr=None if batch.row_ptr is None else P("data"),
         graph_ptr=None if batch.graph_ptr is None else P("data"),
+        # Node-indexed, replicated across 'graph' like the features.
+        positions=None if batch.positions is None else P("data"),
         num_graphs_pad=batch.num_graphs_pad,
     )
 

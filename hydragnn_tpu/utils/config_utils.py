@@ -176,11 +176,15 @@ def _stage_defaults(config, ctx):
 
 
 def _stage_push_head_spec(config, ctx):
-    """Loaders need the inferred head spec to emit per-head dense targets."""
+    """Loaders need the inferred head spec to emit per-head dense targets,
+    and the model family to know what else a batch carries."""
+    from ..models.convs import POSITION_FAMILIES
+
     arch = _at(config, ("NeuralNetwork", "Architecture"))
     for loader in ctx.loaders:
         loader.set_head_spec(arch["output_type"], arch["output_dim"])
         loader.edge_dim = arch["edge_dim"]
+        loader.with_positions = arch["model_type"] in POSITION_FAMILIES
 
 
 _PIPELINE = (

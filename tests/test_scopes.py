@@ -84,6 +84,8 @@ def _model(conv, node_type="mlp", **kw):
         kw["max_neighbours"] = 8
     if node_type == "mlp_per_node":
         kw["num_nodes"] = 6
+    if conv == "PAINN":
+        kw.update(radius=1.5, num_radial=4)
     return create_model(
         conv, 1, 8, (1, 1), ("graph", "node"), _heads(node_type), [1.0, 1.0],
         2, **kw,
@@ -309,6 +311,96 @@ def pytest_gat_gathers_flat_rows_once_a_layer(route, monkeypatch):
         assert (layer, False, "scatter") not in rows
     rank3 = re.compile(rf"f32\[{e_pad},(1,)?{heads},(1,)?{f}\]")
     assert not rank3.search(text), rank3.search(text).group(0)
+
+
+# ------------------------------------------------- PaiNN: geometry, flat rows
+def _painn_batch(csr=True):
+    """``_batch``'s rings with coordinates: neighbours a unit apart."""
+    rng = np.random.default_rng(0)
+    graphs = []
+    for _ in range(4):
+        n = 6
+        angle = 2 * np.pi * np.arange(n) / n
+        pos = np.stack([np.cos(angle), np.sin(angle), 0.1 * rng.normal(size=n)], 1)
+        ei = np.stack([np.arange(n), (np.arange(n) + 1) % n]).astype(np.int32)
+        x = rng.normal(size=(n, 1)).astype(np.float32)
+        graphs.append(GraphSample(
+            x=x, pos=pos.astype(np.float32),
+            y=np.concatenate([[x.sum()], x[:, 0]]).astype(np.float32),
+            y_loc=np.array([[0, 1, 1 + n]], dtype=np.int64),
+            edge_index=np.concatenate([ei, ei[::-1]], axis=1),
+        ))
+    batch = collate_graphs(graphs, ("graph", "node"), (1, 1), with_positions=True)
+    return batch if csr else batch.replace(row_ptr=None, graph_ptr=None)
+
+
+@pytest.mark.parametrize("route", list(ROUTES))
+def pytest_painn_step_geometry_scoped_and_edge_rows_flat(route, monkeypatch):
+    """PaiNN's train step on every route: the movers scoped as for the other
+    families; the edge geometry and each block's filter Dense under
+    ``hydragnn.geom``, its two position gathers under ``hydragnn.gather``
+    inside it; a block gathers two 3F-wide rows and scatter-adds as many (the
+    first block's ``v`` is zero: XLA drops that gather's backward); and NO
+    instruction has the shape ``[E_pad, 3, F]``: ``v`` is flat."""
+    env, csr, arm = ROUTES[route]
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
+    batch = _painn_batch(csr)
+    n_pad, e_pad, f = batch.node_features.shape[0], batch.senders.shape[0], 8
+    text = _compiled_text("PAINN", batch, edge_dim=None)
+    names = _op_names(text)
+    _check_movers_scoped(names, scopes.TRAIN_STEP)
+    used = _used(names)
+    assert used <= scopes.VOCABULARY, used - scopes.VOCABULARY
+    assert {scopes.TRAIN_STEP, scopes.GATHER, scopes.GEOM, scopes.POOL,
+            scopes.LOSS, scopes.OPTIMIZER} <= used
+    assert _arms(names) == {"sum": {arm}, "mean": {arm}}
+    positions = [n for op, n in names
+                 if op == "gather" and f"{scopes.GEOM}/{scopes.GATHER}" in n]
+    assert positions and all("/conv_" not in n for n in positions)
+    assert any(f"/conv_1/{scopes.GEOM}/filter/" in n for _, n in names)
+    assert any(f"/conv_0/{scopes.GEOM}/filter/" in n and "transpose(" in n
+               for _, n in names)
+    rows = {}  # (block, backward?, opcode) -> [result dims]
+    for line in text.splitlines():
+        head = line.split("metadata=")[0]
+        mover, name = _DATA_MOVERS.search(head), _OP_NAME.search(line)
+        if not (mover and name and scopes.GATHER in name.group(1)):
+            continue
+        block = re.search(r"/(conv_\d+)/", name.group(1))
+        if block is None:
+            continue  # the position gathers
+        dims = _row_dims(head)
+        assert len(dims) == 2, f"rank-{len(dims)} rows under hydragnn.gather: {line}"
+        rows.setdefault(
+            (block.group(1), "transpose(" in name.group(1), mover.group(1)), []
+        ).append(dims)
+    for block in ("conv_0", "conv_1"):
+        assert (e_pad, 3 * f) in rows[(block, False, "gather")], rows
+        assert set(rows[(block, True, "scatter")]) == {(n_pad, 3 * f)}, rows
+    assert len(rows[("conv_1", True, "scatter")]) == 2  # x and v
+    rank3 = re.compile(rf"f32\[{e_pad},(1,)?3,(1,)?{f}\]")
+    assert not rank3.search(text), rank3.search(text).group(0)
+
+
+def pytest_painn_eval_and_scan_roots_carry_the_geometry(monkeypatch):
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    batch = _painn_batch()
+    model = _model("PAINN", edge_dim=None)
+    opt = select_optimizer("AdamW", 1e-3)
+    state = create_train_state(model, init_model_variables(model, batch), opt)
+    assert state.batch_stats == {}  # no batch norm anywhere in this family
+    names = _op_names(make_eval_step(model).lower(state, batch).compile().as_text())
+    used = _used(names)
+    assert {scopes.EVAL_STEP, scopes.GEOM, scopes.GATHER, scopes.POOL} <= used
+    assert scopes.TRAIN_STEP not in used and used <= scopes.VOCABULARY
+    stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), batch, batch)
+    names = _op_names(_compiled_text(
+        "PAINN", batch, build=make_train_epoch_scan, stacked=stacked, edge_dim=None
+    ))
+    _check_movers_scoped(names, scopes.TRAIN_EPOCH_SCAN)
+    assert scopes.GEOM in _used(names) and _used(names) <= scopes.VOCABULARY
 
 
 # ------------------------------------------------------------------- the mesh

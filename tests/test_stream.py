@@ -137,15 +137,21 @@ def pytest_gshd_damage_taxonomy(tmp_path):
 @pytest.mark.parametrize(
     "knobs",
     [
-        dict(shuffle=True, num_buckets=1, reshuffle="sample", packing=False),
-        dict(shuffle=True, num_buckets=2, reshuffle="batch", packing=True),
-        dict(shuffle=False, num_buckets=1, reshuffle="sample", packing=False),
+        dict(shuffle=True, num_buckets=1, reshuffle="sample", packing=False,
+             with_positions=False),
+        dict(shuffle=True, num_buckets=2, reshuffle="batch", packing=True,
+             with_positions=True),
+        dict(shuffle=False, num_buckets=1, reshuffle="sample", packing=False,
+             with_positions=False),
     ],
 )
 def pytest_streamed_collation_bit_exact_vs_in_memory(tmp_path, knobs):
     """The streamed loader's batches are BIT-identical to the in-memory
     loader's at matched seed/knobs — both on the warm resident path and on
-    the Belady replay path (resident_shards below the epoch's shard set)."""
+    the Belady replay path (resident_shards below the epoch's shard set).
+    ``with_positions`` is such a knob (config completion sets it on both):
+    said, because the in-memory constructor's unsaid default follows the
+    samples and the streamed one's is False (PR 26)."""
     import jax
 
     from hydragnn_tpu.datasets.stream import StreamingGraphLoader

@@ -85,3 +85,62 @@ def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch
     assert sorted(wide) == sorted(
         [(f"{e},{h * f}", "gather")] * 2 + [(f"{n},{h * f}", "scatter-add")] * 2
     ), moved
+
+
+def pytest_painn_block_at_cell_size_keeps_the_vector_state_flat(one_chip, monkeypatch):
+    """One ``PaiNNBlock`` (message + update), forward and backward, at the
+    shapes of the cell ``painn_f128.train_b512`` (16384 × 262144, F 128, 20
+    basis functions) on the sorted/CSR route the chip takes: the two sources
+    come out as ``f32[262144,384]`` row gathers with two scatter-adds into
+    ``f32[16384,384]`` behind them, the two states are summed as ONE
+    ``[262144,512]`` array, and no array with a ``[3,128]`` row or a
+    transposed ``[262144,384]{0,1}`` is written anywhere: ``v`` stays flat
+    ``[·, 3F]``, xyz-major, from the gather to what the backward saves."""
+    from hydragnn_tpu.models.painn import EdgeGeometry, PaiNNBlock
+
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
+    n, e, f, radial = 16384, 262144, 128, 20
+    block = PaiNNBlock(f)
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    small = (
+        jnp.zeros((8, f)), jnp.zeros((8, 3 * f)),
+        EdgeGeometry(jnp.zeros((16, radial)), jnp.zeros((16, 1)), jnp.zeros((16, 3))),
+        jnp.zeros((16,), jnp.int32), jnp.zeros((16,), jnp.int32),
+    )
+    params = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), *small)),
+    )
+
+    def loss(params, s, v, basis, cutoff, unit, senders, receivers, row_ptr):
+        s, v = block.apply(
+            params, s, v, EdgeGeometry(basis, cutoff, unit), senders, receivers,
+            row_ptr,
+        )
+        return (s * s).sum() + (v * v).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        params, shaped((n, f)), shaped((n, 3 * f)), shaped((e, radial)),
+        shaped((e, 1)), shaped((e, 3)), shaped((e,), jnp.int32),
+        shaped((e,), jnp.int32), shaped((n + 1,), jnp.int32),
+    ).compile().as_text()
+
+    rank3 = re.search(rf"\[\d+,3,{f}\]", text)
+    assert not rank3, f"an array with [3,128] rows: {rank3.group(0)}"
+    assert f"[{e},{3 * f}]{{0,1" not in text, "a transposed copy of the edge rows"
+    entry = text[text.index("ENTRY"):]
+    moved = [
+        (m.group(1), m.group(2))
+        for m in re.finditer(
+            r"= f32\[([\d,]+)\]\S* fusion\(.*op_name=\"[^\"]*hydragnn\.gather/([\w-]+)\"",
+            entry,
+        )
+    ]
+    assert sorted(moved) == sorted(
+        [(f"{e},{3 * f}", "gather")] * 2 + [(f"{n},{3 * f}", "scatter-add")] * 2
+    ), moved
+    assert re.search(rf"f32\[{e},{4 * f}\]\S* fusion\(.*hydragnn\.agg\.sum\.csr", entry)
