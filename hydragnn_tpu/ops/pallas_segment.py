@@ -23,11 +23,21 @@ scatters on the MXU.
 
 Measured on TPU v5e (E=16k, F=64, N=4k) on the ROUND-2 kernel: XLA
 mean/min/max/std/count bundle ~88us; the fused path ~50us with min/max still
-on XLA ``segment_max/min`` (elementwise extrema cannot ride the MXU and their
-scatters are not the bottleneck). The round-4 rework (f-packing + block-skip)
-did NOT hold that win on its first hardware contact (TUNE_KERNEL_r05:
-0.41-0.98x vs XLA, certification failing) — hence the opt-in default; see
-pallas_enabled.
+on XLA ``segment_max/min`` (elementwise extrema cannot ride the MXU). The
+round-4 rework (f-packing + block-skip) did NOT hold that win on its first
+hardware contact (TUNE_KERNEL_r05: 0.41-0.98x vs XLA, certification failing)
+— hence the opt-in default; see pallas_enabled.
+
+Those two scatters WERE the bottleneck once the sums had left theirs: at the
+benchmark's PNA cell (32768 x 524288 x 256) ``agg.extrema.xla`` was 67.7 of a
+180.9 ms train step and 43% of every evaluation step (PERF_LEDGER.jsonl, PR
+26; PERF.md). Since PR 27 min and max over sorted receiver runs come from
+ONE streamed pass with no scatter, a segmented scan that never leaves VMEM
+(``_extrema_scan_kernel``): 3.6 ms where the two scatters took 20.3 at that
+shape (PERF.md, PR 27). It needs no opt-in and is not behind HYDRAGNN_PALLAS:
+``pna_aggregate`` takes it wherever the sorted arm has the batch's
+``row_ptr`` and no edge-sharded axis, and XLA's scatters elsewhere. It is the
+first Pallas code a benchmark cell runs.
 
 The custom VJP keeps the backward on plain XLA gathers (gathers are fast on
 TPU; only scatter is slow): for (sum, count) the data cotangent is
@@ -816,17 +826,159 @@ def fused_segment_stats(
         )
 
 
+# ------------------------------------------- extrema over sorted receiver runs
+# Edge rows a grid step streams through VMEM, and rows of them scanned in
+# registers at a time ([_XC, 128] of min, of max and of ids are 2 vregs each).
+_XB = 512
+_XC = 16
+
+
+def _extrema_scan_kernel(
+    ids_ref, data_ref, mn_ref, mx_ref, idt_ref, cid_ref, cmn_ref, cmx_ref
+):
+    """One block of the inclusive SEGMENTED (min, max) scan down the rows.
+
+    ids are non-decreasing, so ``ids[i - s] == ids[i]`` says rows ``i - s .. i``
+    are one run: ``log2(_XC)`` shift-compare-select steps scan a chunk in
+    registers (a shift that wraps round the chunk combines rows of ONE run
+    only, so the run's last row still ends up with the run's extrema and
+    nothing else is read), then the chunk joins the ``(id, min, max)`` row
+    carried from the chunk before it, through the fori_loop inside a block and
+    through scratch from block to block (the grid is sequential). The last
+    row of a run holds the run's min and max; a run of any length, the
+    padding node's included, costs what its rows cost."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f = data_ref.shape[1]
+    lane = cid_ref.shape[1]
+    slabs = [(c0, min(lane, f - c0)) for c0 in range(0, f, lane)]
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        cid_ref[...] = jnp.full(cid_ref.shape, -1, jnp.int32)  # no id is < 0
+        cmn_ref[...] = jnp.zeros(cmn_ref.shape, jnp.float32)
+        cmx_ref[...] = jnp.zeros(cmx_ref.shape, jnp.float32)
+
+    # The ids arrive lane-major (a [1, _XB] row: no padded [E, 1] copy in HBM)
+    # and are wanted down the sublanes, the same id in every lane.
+    idt_ref[...] = jnp.broadcast_to(ids_ref[...], (128, _XB)).T
+
+    def chunk(c, carry):
+        cid, cmns, cmxs = carry
+        r0 = pl.multiple_of(c * _XC, _XC)
+        ids = idt_ref[pl.ds(r0, _XC), :][:, :lane]
+        steps = []
+        s = 1
+        while s < _XC:
+            steps.append((s, pltpu.roll(ids, s, 0) == ids))
+            s *= 2
+        joined = ids == cid
+        last_mn, last_mx = [], []
+        for (c0, w), cmn, cmx in zip(slabs, cmns, cmxs):
+            mn = mx = data_ref[pl.ds(r0, _XC), c0:c0 + w].astype(jnp.float32)
+            for s, same in steps:
+                same = same[:, :w]
+                mn = jnp.where(same, jnp.minimum(mn, pltpu.roll(mn, s, 0)), mn)
+                mx = jnp.where(same, jnp.maximum(mx, pltpu.roll(mx, s, 0)), mx)
+            mn = jnp.where(joined[:, :w], jnp.minimum(mn, cmn), mn)
+            mx = jnp.where(joined[:, :w], jnp.maximum(mx, cmx), mx)
+            mn_ref[pl.ds(r0, _XC), c0:c0 + w] = mn.astype(mn_ref.dtype)
+            mx_ref[pl.ds(r0, _XC), c0:c0 + w] = mx.astype(mx_ref.dtype)
+            last_mn.append(mn[_XC - 1:, :])
+            last_mx.append(mx[_XC - 1:, :])
+        return ids[_XC - 1:, :], tuple(last_mn), tuple(last_mx)
+
+    # The carried rows stay one array a 128-lane slab: Mosaic refuses a lane
+    # slice of a loop-carried [1, f] value.
+    cid, cmns, cmxs = jax.lax.fori_loop(
+        0, _XB // _XC, chunk,
+        (
+            cid_ref[...],
+            tuple(cmn_ref[:, c0:c0 + w] for c0, w in slabs),
+            tuple(cmx_ref[:, c0:c0 + w] for c0, w in slabs),
+        ),
+    )
+    cid_ref[...] = cid
+    for (c0, w), cmn, cmx in zip(slabs, cmns, cmxs):
+        cmn_ref[:, c0:c0 + w] = cmn
+        cmx_ref[:, c0:c0 + w] = cmx
+
+
+def _extrema_csr(data, ids, row_ptr, num_segments: int, interpret: bool):
+    """(min, max) of each receiver's contiguous run of ``data`` rows from ONE
+    streamed pass and no scatter: the scan kernel above, then the row at
+    ``row_ptr[n + 1] - 1`` of each output for node ``n`` (one N-row gather
+    each), 0 where the run is empty. Min and max do not round, so this is
+    bit-equal to ``jax.ops.segment_min`` / ``segment_max`` on every run."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    e, f = data.shape
+    if e == 0:
+        zeros = jnp.zeros((num_segments, f), data.dtype)
+        return zeros, zeros
+    e_pad = _round_up(e, _XB)
+    if e_pad != e:
+        # Rows past the end form a run of their own that no node points into.
+        data = jnp.pad(data, ((0, e_pad - e), (0, 0)))
+        ids = jnp.pad(ids, (0, e_pad - e), constant_values=num_segments)
+    lane = min(f, 128)
+    rows = pl.BlockSpec((_XB, f), lambda j: (j, 0))
+    scanned_mn, scanned_mx = pl.pallas_call(
+        _extrema_scan_kernel,
+        grid=(e_pad // _XB,),
+        in_specs=[pl.BlockSpec((1, _XB), lambda j: (0, j)), rows],
+        out_specs=[rows, rows],
+        out_shape=[jax.ShapeDtypeStruct((e_pad, f), data.dtype)] * 2,
+        scratch_shapes=[
+            pltpu.VMEM((_XB, 128), jnp.int32),
+            pltpu.VMEM((1, lane), jnp.int32),
+            pltpu.VMEM((1, f), jnp.float32),
+            pltpu.VMEM((1, f), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+    )(ids.reshape(1, e_pad), data)
+    row_ptr = row_ptr.astype(jnp.int32)
+    last = jnp.maximum(row_ptr[1:] - 1, 0)
+    filled = (row_ptr[1:] > row_ptr[:-1])[:, None]
+    return (
+        jnp.where(filled, jnp.take(scanned_mn, last, axis=0), 0),
+        jnp.where(filled, jnp.take(scanned_mx, last, axis=0), 0),
+    )
+
+
+def _extrema_arm(row_ptr) -> str:
+    return "xla" if row_ptr is None else "pallas_csr"
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def segment_extrema(data, ids, num_segments: int, axis_name: Optional[str] = None):
+def segment_extrema(
+    data, ids, num_segments: int, axis_name: Optional[str] = None, row_ptr=None
+):
     """(min, max) per segment with a gather-based backward: the cotangent flows
     to every row equal to its segment's extremum (the standard subgradient),
-    avoiding XLA's scatter-heavy segment_min/max VJP on TPU. ``ids`` < 0 marks
-    masked rows; empty segments yield 0."""
+    avoiding XLA's scatter-heavy segment_min/max VJP on TPU. Empty segments
+    yield 0.
+
+    With ``row_ptr`` (the CSR batch contract: ``ids`` RAW and non-decreasing,
+    masked rows in the padding segments' runs, whose outputs nobody reads, and
+    no ``axis_name``) the forward is :func:`_extrema_csr`, one streamed pass
+    and no scatter. Without it ``ids`` < 0 marks masked rows and the forward
+    is XLA's two scatters. The backward is the same either way."""
     # This IS the custom_vjp, so the scope is opened inside it and again in
     # its backward: JAX traces both when it pleases, under the caller's name
     # stack, and a scope round the call alone would be written twice wherever
     # the forward is traced after that scope has closed (the scan step).
-    with scopes.agg_scope("extrema", "xla"):
+    with scopes.agg_scope("extrema", _extrema_arm(row_ptr)):
+        if row_ptr is not None:
+            srt.attach_layout_check(ids)
+            return _extrema_csr(
+                data, ids, row_ptr, num_segments, _platform() != "tpu"
+            )
         mask = ids >= 0
         safe_ids = jnp.where(mask, ids, 0)
         mn = seg.segment_min(data, safe_ids, num_segments, mask=mask, axis_name=axis_name)
@@ -834,15 +986,15 @@ def segment_extrema(data, ids, num_segments: int, axis_name: Optional[str] = Non
         return mn, mx
 
 
-def _extrema_fwd(data, ids, num_segments, axis_name):
-    mn, mx = segment_extrema(data, ids, num_segments, axis_name)
-    return (mn, mx), (data, ids, mn, mx)
+def _extrema_fwd(data, ids, num_segments, axis_name, row_ptr=None):
+    mn, mx = segment_extrema(data, ids, num_segments, axis_name, row_ptr)
+    return (mn, mx), (data, ids, mn, mx, row_ptr)
 
 
 def _extrema_bwd(num_segments, axis_name, res, cots):
-    data, ids, mn, mx = res
+    data, ids, mn, mx, row_ptr = res
     d_mn, d_mx = cots
-    with scopes.agg_scope("extrema", "xla"):
+    with scopes.agg_scope("extrema", _extrema_arm(row_ptr)):
         if axis_name is not None:
             d_mn = jax.lax.psum(d_mn, axis_name)
             d_mx = jax.lax.psum(d_mx, axis_name)
@@ -851,7 +1003,12 @@ def _extrema_bwd(num_segments, axis_name, res, cots):
         d_data = jnp.where(valid & (data == mn[idx]), d_mn[idx], 0.0) + jnp.where(
             valid & (data == mx[idx]), d_mx[idx], 0.0
         )
-        return d_data.astype(data.dtype), jnp.zeros(ids.shape, jax.dtypes.float0)
+        return (
+            d_data.astype(data.dtype),
+            jnp.zeros(ids.shape, jax.dtypes.float0),
+            None if row_ptr is None
+            else jnp.zeros(row_ptr.shape, jax.dtypes.float0),
+        )
 
 
 segment_extrema.defvjp(_extrema_fwd, _extrema_bwd)
@@ -1429,12 +1586,18 @@ def pna_aggregate(
 
     Routes the sum/mean/std family through the scatter-free sorted path
     (precomputed CSR boundaries when ``row_ptr`` is present) or the fused
-    Pallas kernel when enabled; min/max always via XLA segment extrema.
-    Falls back entirely to the masked XLA segment ops otherwise.
+    Pallas kernel when enabled. min/max: on the sorted path with ``row_ptr``
+    and no ``axis_name``, the one-pass scan kernel over receiver runs
+    (:func:`_extrema_csr`); XLA's segment extrema on every other route (no
+    boundaries; an edge-sharded axis, where a run is cut across shards; off
+    the sorted path). Falls back entirely to the masked XLA segment ops
+    otherwise.
     """
     with jax.named_scope(scopes.AGG_PNA):
         n = num_segments
         use_sorted = sorted_ids and srt.sorted_enabled()
+        # The scan kernel wants whole runs: an edge-sharded axis cuts them.
+        extrema_ptr = row_ptr if use_sorted and axis_name is None else None
         if pallas_enabled() or use_sorted:
             fused = {}
             count = None
@@ -1447,9 +1610,11 @@ def pna_aggregate(
                 fused = {"mean": mean, "std": std, "sum": total}
             if "min" in aggregators or "max" in aggregators:
                 ids = receivers.astype(jnp.int32)
-                if mask is not None:
+                # CSR contract: RAW sorted ids, the masked edges' rows in the
+                # padding node's run. The scatters take -1 for a masked row.
+                if extrema_ptr is None and mask is not None:
                     ids = jnp.where(mask, ids, -1)
-                mn, mx = segment_extrema(msg, ids, n, axis_name)
+                mn, mx = segment_extrema(msg, ids, n, axis_name, extrema_ptr)
                 fused["min"], fused["max"] = mn, mx
         else:
             fused = {}

@@ -49,7 +49,8 @@ AGG_PNA = "hydragnn.agg.pna"  # PNA's bundle; its stats/extrema nest inside
 AGG_WHATS = ("sum", "count", "sum_count", "mean", "stats", "extrema", "softmax")
 # The route Python took at trace time: masked XLA segment ops, the sorted
 # prefix path with searched or with precomputed (CSR) boundaries, the one-hot
-# Pallas kernel, the CSR run-walk Pallas kernel.
+# Pallas kernel, a Pallas kernel over the CSR boundaries (the run-walk one
+# for sums; for extrema the scan over receiver runs).
 AGG_ARMS = ("xla", "sorted", "csr", "pallas", "pallas_csr")
 
 

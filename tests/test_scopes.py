@@ -160,9 +160,12 @@ def pytest_train_step_movers_scoped_and_arm_named(conv, route, monkeypatch):
     assert srt.sorted_enabled() is (route != "xla")
     assert not ps.pallas_enabled()
     arms = _arms(names)
+    # PNA's extrema: the scan kernel over receiver runs where the sorted arm
+    # has the batch's row pointers, XLA's segment_max/min on the other routes.
+    # GAT's logits' max is XLA's segment_max on every route.
+    extrema = "pallas_csr" if (conv, route) == ("PNA", "csr") else "xla"
     for what, got in arms.items():
-        # Extrema run on XLA's segment_max/min on every route.
-        assert got == ({"xla"} if what == "extrema" else {arm}), (what, got)
+        assert got == ({extrema} if what == "extrema" else {arm}), (what, got)
     expected = {
         "SAGE": {"mean"}, "GIN": {"sum", "mean"}, "MFC": {"sum_count", "mean"},
         "GAT": {"sum", "extrema", "mean"}, "CGCNN": {"sum", "mean"},
@@ -186,6 +189,8 @@ def pytest_pallas_arms_named_and_backward_scoped(csr_kernel, monkeypatch):
     assert ps.csr_kernel_enabled() is (csr_kernel == "1")
     arms = _arms(names)
     assert arms["stats"] == {arm} and arms["mean"] == {arm}, arms
+    # HYDRAGNN_PALLAS is the one-hot and run-walk SUM kernels' opt-in: without
+    # the sorted arm the extrema stay XLA's, row pointers or not.
     assert arms["extrema"] == {"xla"}
     assert _used(names) <= scopes.VOCABULARY
     stats = scopes.agg("stats", arm)
@@ -198,14 +203,25 @@ def pytest_custom_vjp_backward_carries_the_scope(monkeypatch):
     their ``_bwd`` apart from the call site: the backward gathers carry the
     forward's scope all the same."""
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+
+    def check(names, *held):
+        for scope in held:
+            bwd = [n for op, n in names if op == "gather" and scope in n
+                   and "transpose(" in n]
+            assert bwd, f"no backward gather under {scope}"
+            # Written once, not once by the call site and again by the function.
+            assert all(n.count(scope) == 1 for _, n in names if scope in n)
+
     names = _op_names(_compiled_text("PNA", _batch()))
-    for scope in (scopes.agg("stats", "csr"), scopes.agg("extrema", "xla"),
-                  scopes.agg("mean", "csr")):
-        bwd = [n for op, n in names if op == "gather" and scope in n
-               and "transpose(" in n]
-        assert bwd, f"no backward gather under {scope}"
-        # Written once, not once by the call site and again by the function.
-        assert all(n.count(scope) == 1 for _, n in names if scope in n)
+    check(names, scopes.agg("stats", "csr"), scopes.agg("extrema", "pallas_csr"),
+          scopes.agg("mean", "csr"))
+    # The kernel's forward (its row fetches here, interpreted) and the
+    # unchanged backward both carry the new arm's name, and the old one is gone.
+    kernel = scopes.agg("extrema", "pallas_csr")
+    assert any(kernel in n and "transpose(" not in n for _, n in names)
+    assert scopes.agg("extrema", "xla") not in _used(names)
+    names = _op_names(_compiled_text("PNA", _batch(csr=False)))
+    check(names, scopes.agg("stats", "sorted"), scopes.agg("extrema", "xla"))
     names = _op_names(_compiled_text("GIN", _batch(csr=False)))
     scope = scopes.agg("sum", "sorted")
     assert any(op == "gather" and scope in n and "transpose(" in n

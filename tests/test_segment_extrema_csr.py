@@ -1,0 +1,124 @@
+"""PNA's min and max over sorted receiver runs in one streamed pass
+(``ops/pallas_segment.py``: ``_extrema_scan_kernel``, interpreted here): the
+outputs are held bit-equal to ``jax.ops.segment_min`` / ``segment_max``, and
+the gradient through ``pna_aggregate`` to the XLA route's, whose backward it
+shares.
+
+CPU, small sizes: values and routes, never a time."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hydragnn_tpu.ops import pallas_segment as ps
+
+# Rows a run of each segment holds, in segment order. The kernel's block is
+# ps._XB rows and its in-register chunk ps._XC; the names say what each
+# layout puts across those.
+LAYOUTS = {
+    # E = 14: one partial block; empty segments first, in the middle, last.
+    "empties_and_single_rows": [0, 0, 3, 1, 0, 1, 1, 6, 0, 2, 0, 0],
+    # E = 2 * _XB exactly; a run of 40 rows over the boundary between blocks.
+    "crosses_one_block_boundary": (
+        [7] * 70 + [ps._XB - 490 + 18] + [9] * 54 + [ps._XB - 18 - 486]
+    ),
+    # The padding node's: real runs, empty nodes, then one run over the rest
+    # of block 0, all of blocks 1 and 2 and part of 3. E not a multiple.
+    "padding_run_spans_three_blocks": (
+        [5, 1, 12, 1, 1, 30] * 6 + [0] * 9 + [3 * ps._XB + 77 - 300]
+    ),
+    # Every run one chunk long and aligned to it, then single rows.
+    "chunk_aligned_runs": [ps._XC] * 20 + [1] * 37 + [0, 2 * ps._XC + 1],
+    # An empty edge set: every segment comes back 0, as from segment_min/max.
+    "no_edges": [0, 0, 0],
+}
+assert sum(LAYOUTS["crosses_one_block_boundary"]) == 2 * ps._XB
+assert sum(LAYOUTS["crosses_one_block_boundary"][:70]) < ps._XB < sum(
+    LAYOUTS["crosses_one_block_boundary"][:71]
+)
+
+
+def _problem(layout, f, values, seed=0):
+    counts = np.asarray(LAYOUTS[layout])
+    n, e = len(counts), int(counts.sum())
+    ids = np.repeat(np.arange(n), counts).astype(np.int32)
+    row_ptr = np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)
+    # Rounded to a quarter: ties inside a run, so the backward's "every row
+    # equal to its extremum" has rows to find.
+    data = np.round(np.random.default_rng(seed).normal(size=(e, f)) * 4) / 4
+    if values == "negative":
+        data = -np.abs(data) - 0.25
+    dtype = jnp.bfloat16 if values == "bf16" else jnp.float32
+    return jnp.asarray(data, dtype), jnp.asarray(ids), jnp.asarray(row_ptr), counts
+
+
+CASES = [(layout, f, "normal") for layout in LAYOUTS for f in (1, 6, 256)] + [
+    ("padding_run_spans_three_blocks", f, values)
+    for values in ("negative", "bf16") for f in (1, 6, 256)
+]
+
+
+@pytest.mark.parametrize("layout,f,values", CASES)
+def pytest_csr_extrema_bit_equal_to_segment_min_max(layout, f, values):
+    data, ids, row_ptr, counts = _problem(layout, f, values)
+    n = len(counts)
+    assert data.shape[0] % ps._XB or layout in ("crosses_one_block_boundary", "no_edges")
+    mn, mx = jax.jit(
+        lambda d: ps.segment_extrema(d, ids, n, None, row_ptr)
+    )(data)
+    assert mn.dtype == data.dtype and mx.dtype == data.dtype
+    filled = (counts > 0)[:, None]
+    want_mn = np.where(filled, jax.ops.segment_min(data, ids, n), 0)
+    want_mx = np.where(filled, jax.ops.segment_max(data, ids, n), 0)
+    assert np.array_equal(np.asarray(mn), want_mn)
+    assert np.array_equal(np.asarray(mx), want_mx)
+    assert np.isfinite(np.asarray(mn, np.float32)).all()
+    if values == "negative":
+        assert (np.asarray(mx)[counts > 0] < 0).all()  # no 0 fill leaked in
+    # The XLA arm on the same rows (masked ids are its own convention).
+    xla_mn, xla_mx = ps.segment_extrema(data, ids, n)
+    assert np.array_equal(np.asarray(mn), np.asarray(xla_mn))
+    assert np.array_equal(np.asarray(mx), np.asarray(xla_mx))
+
+
+@pytest.mark.parametrize("f", [1, 256])
+@pytest.mark.parametrize(
+    "aggregators", [("min", "max"), ("mean", "min", "max", "std")]
+)
+def pytest_gradient_on_the_kernel_route_equals_the_xla_routes(
+    aggregators, f, monkeypatch
+):
+    """``pna_aggregate`` with ``row_ptr`` takes the kernel, without it XLA's
+    scatters; the backward is one piece of code reading the residuals
+    ``(data, ids, mn, mx)``, so the gradients are equal to the bit. The last
+    segment is the padding node: its run holds the masked edges and nothing
+    reads its output, as in a collated batch."""
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
+    data, ids, row_ptr, counts = _problem("padding_run_spans_three_blocks", f, "normal")
+    n = len(counts)
+    mask = ids < n - 1
+    real = jnp.asarray(counts > 0).at[n - 1].set(False)
+    weights = jnp.asarray(
+        np.random.default_rng(1).normal(size=(n, len(aggregators), f)), jnp.float32
+    )
+
+    def loss(d, ptr):
+        agg, _ = ps.pna_aggregate(
+            d, ids, n, aggregators, mask=mask, sorted_ids=True, row_ptr=ptr
+        )
+        return jnp.sum(jnp.where(real[:, None, None], agg * weights, 0.0))
+
+    def arms(ptr):
+        text = jax.jit(jax.grad(loss)).lower(data, ptr).as_text(debug_info=True)
+        return {a for a in ("xla", "pallas_csr") if f"agg.extrema.{a}" in text}
+
+    assert arms(row_ptr) == {"pallas_csr"} and arms(None) == {"xla"}
+    value, grad = jax.value_and_grad(loss)(data, row_ptr)
+    want_value, want_grad = jax.value_and_grad(loss)(data, None)
+    assert np.array_equal(np.asarray(grad), np.asarray(want_grad))
+    assert np.asarray(grad)[np.asarray(mask)].any()
+    assert not np.asarray(grad)[~np.asarray(mask)].any()
+    if aggregators == ("min", "max"):
+        assert float(value) == float(want_value)

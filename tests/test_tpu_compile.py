@@ -144,3 +144,77 @@ def pytest_painn_block_at_cell_size_keeps_the_vector_state_flat(one_chip, monkey
         [(f"{e},{3 * f}", "gather")] * 2 + [(f"{n},{3 * f}", "scatter-add")] * 2
     ), moved
     assert re.search(rf"f32\[{e},{4 * f}\]\S* fusion\(.*hydragnn\.agg\.sum\.csr", entry)
+
+
+def _combiners(text):
+    """{computation name: its body} of an HLO module's text."""
+    return {
+        m.group(1): m.group(2)
+        for m in re.finditer(r"^%?([\w.-]+) \([^\n]*\{\n(.*?)^\}", text, re.S | re.M)
+    }
+
+
+def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monkeypatch):
+    """One ``PNAConv``, forward and backward, at the large bucket of the cell
+    ``pna_multihead_h256.train_b512`` (32768 × 524288, hidden 256) on the CSR
+    route the chip takes: min and max come from ONE Mosaic kernel in the
+    forward, under ``hydragnn.agg.extrema.pallas_csr``; the backward holds
+    none; and no scatter that combines by minimum or maximum is left anywhere
+    (the one scatter into ``f32[32768,256]`` that stays is the centered
+    sum of squares of ``std``)."""
+    from hydragnn_tpu.models.convs import PNAConv
+    from hydragnn_tpu.ops import pallas_segment as ps
+    from hydragnn_tpu.telemetry import scopes
+
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
+    n, e, f = 32768, 524288, 256
+    conv = PNAConv(out_dim=f, deg_avg_log=2.5, deg_avg_lin=14.0, edge_dim=1)
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    small = (
+        jnp.zeros((8, f)), jnp.zeros((16,), jnp.int32),
+        jnp.zeros((16,), jnp.int32), jnp.zeros((16, 1)), jnp.ones((16,), bool),
+        jnp.ones((8,), bool),
+    )
+    params = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: conv.init(jax.random.PRNGKey(0), *small)),
+    )
+
+    def loss(params, x, senders, receivers, edge_attr, edge_mask, node_mask, row_ptr):
+        out = conv.apply(
+            params, x, senders, receivers, edge_attr, edge_mask, node_mask,
+            row_ptr=row_ptr,
+        )
+        return (jnp.where(node_mask[:, None], out, 0.0) ** 2).sum()
+
+    # The kernel is interpreted wherever the step's platform is not the TPU:
+    # this program is for the described chip.
+    with ps.pallas_platform("tpu"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, shaped((n, f)), shaped((e,), jnp.int32),
+            shaped((e,), jnp.int32), shaped((e, 1)), shaped((e,), jnp.bool_),
+            shaped((n,), jnp.bool_), shaped((n + 1,), jnp.int32),
+        ).compile().as_text()
+
+    kernels = [
+        re.search(r'op_name="([^"]*)"', line).group(1)
+        for line in text.splitlines() if "tpu_custom_call" in line
+    ]
+    assert len(kernels) == 1, kernels
+    assert scopes.agg("extrema", "pallas_csr") in kernels[0]
+    assert "transpose(" not in kernels[0]
+    assert scopes.agg("extrema", "xla") not in text
+    bodies = _combiners(text)
+    scatters = [
+        (m.group(1), bodies[m.group(2)])
+        for m in re.finditer(
+            r"= (\w+\[[\d,]*\])\S* scatter\(.*to_apply=%?([\w.-]+)", text
+        )
+    ]
+    assert scatters, "no scatter compiled: nothing was checked"
+    assert not [s for s, body in scatters if re.search(r"(min|max)imum\(", body)], scatters
+    assert [s for s, body in scatters if s == f"f32[{n},{f}]" and " add(" in body]
