@@ -611,150 +611,6 @@ def _last_known_packing(search_dir: "str | None" = None) -> "dict | None":
     return _latest_artifact_block("BENCH_*_packing.json", extract, search_dir)
 
 
-def _last_known_kernels(search_dir: "str | None" = None) -> "dict | None":
-    """Most recent completed kernel-fight round from any committed KERNELS_*
-    artifact. A
-    failed ``--kernels`` round embeds this block with ``provenance:
-    "stale"``."""
-
-    def extract(doc):
-        if doc.get("metric") != "kernel_fight" or not doc.get("arms"):
-            return None
-        return {
-            "value": doc.get("value"),
-            "backend": doc.get("backend"),
-            "arms": {
-                name: {
-                    k: arm.get(k)
-                    for k in ("ms", "ok", "speedup_vs_xla")
-                }
-                for name, arm in doc["arms"].items()
-            },
-        }
-
-    return _latest_artifact_block("KERNELS_*.json", extract, search_dir)
-
-
-def kernels_main() -> int:
-    """``python bench.py --kernels``: ONE per-round artifact for the
-    message-passing kernel fight (ROADMAP item 2) — the four aggregation
-    arms (XLA scatter bundle, legacy one-hot Pallas kernel, CSR run-walk
-    Pallas kernel, scatter-free sorted prefix path) certified against the
-    same f64 ground truth and timed on the flagship aggregation shape, plus
-    a digest of the newest convergence-matrix artifact
-    (benchmarks/pallas_matrix.py). Replaces the four loose
-    PALLAS_MATRIX/TUNE_KERNEL/CERTIFY/BENCH_sorted JSONs with a single
-    KERNELS_rNN.json trajectory file; failure embeds the last known round,
-    stale-labeled, per the established convention."""
-    result = {
-        "metric": "kernel_fight",
-        "value": 0.0,
-        "unit": "best_certified_speedup_vs_xla",
-    }
-    from hydragnn_tpu.utils.artifacts import round_tag
-
-    repo = os.path.dirname(os.path.abspath(__file__))
-    out_path = os.path.join(repo, f"KERNELS_r{round_tag()}.json")
-    try:
-        import jax
-
-        from hydragnn_tpu.ops.pallas_segment import certify_pallas
-
-        result.update(_device_block())
-        backend = result["backend"]
-        on_tpu = backend == "tpu"
-        # Flagship aggregation shape on hardware; a small-but-multi-block
-        # shape through the interpreter on CPU (grid loops run in Python —
-        # the full 16k-edge shape would take minutes for zero timing value).
-        shape = (
-            dict(e=16384, f=64, n=4096, reps=20)
-            if on_tpu
-            else dict(e=2048, f=24, n=256, reps=2)
-        )
-        result["workload"] = shape
-        result["timings_meaningful"] = on_tpu
-        cert = certify_pallas(contiguous=True, **shape)
-        result["arms"] = {
-            "xla": {
-                "ms": cert["xla_ms"],
-                "ok": True,  # the incumbent defines the parity reference
-                "err_fwd": cert["xla_err_fwd"],
-                "err_grad": cert["xla_err_grad"],
-                "speedup_vs_xla": 1.0,
-            },
-            "pallas_onehot": {
-                "ms": cert["pallas_ms"],
-                "ok": cert["ok"],
-                "err_fwd": cert["max_err_fwd"],
-                "err_grad": cert["max_err_grad"],
-                "speedup_vs_xla": cert["speedup"],
-            },
-            "pallas_csr": {
-                "ms": cert.get("csr_ms"),
-                "ok": cert.get("csr_ok"),
-                "err_fwd": cert.get("csr_err_fwd"),
-                "err_grad": cert.get("csr_err_grad"),
-                "speedup_vs_xla": cert.get("csr_speedup_vs_xla"),
-            },
-            "sorted": {
-                "ms": cert.get("sorted_ms"),
-                "ok": cert.get("sorted_ok"),
-                "err_fwd": cert.get("sorted_err_fwd"),
-                "err_grad": cert.get("sorted_err_grad"),
-                "speedup_vs_xla": cert.get("sorted_speedup_vs_xla"),
-            },
-        }
-        result["tol"] = {"fwd": cert["tol"], "grad": cert["tol_grad"]}
-        # Gate: every arm must certify — the artifact is the single
-        # trajectory file the next hardware round reads, and an uncertified
-        # arm's timing is noise.
-        certified = [
-            a for a in result["arms"].values() if a["ok"] and a["ms"]
-        ]
-        result["all_arms_certified"] = all(
-            a["ok"] for a in result["arms"].values()
-        )
-        result["value"] = round(
-            max(a["speedup_vs_xla"] for a in certified), 3
-        )
-        # Fold in the newest convergence-matrix digest so the kernel fight
-        # has one file per round instead of four loose JSONs.
-        matrix = _latest_artifact_block(
-            "PALLAS_MATRIX_*.json",
-            lambda doc: {
-                "arm": doc.get("arm", "pallas" if doc.get("pallas") else "xla"),
-                "cells": len(doc.get("matrix", ())),
-                "pass_scatter_allowance": sum(
-                    1
-                    for r in doc.get("matrix", ())
-                    if r.get("pass_scatter_allowance")
-                ),
-            }
-            if doc.get("matrix")
-            else None,
-        )
-        if matrix is not None:
-            result["pallas_matrix_last"] = matrix
-        with open(out_path, "w") as f:
-            json.dump(result, f, indent=2)
-        result["artifact"] = os.path.basename(out_path)
-    except Exception as e:
-        import traceback
-
-        result["error"] = f"{type(e).__name__}: {e}"
-        result["trace_tail"] = traceback.format_exc()[-1500:]
-        try:
-            stale = _last_known_kernels()
-            if stale is not None:
-                result["last_known_kernels"] = stale
-        except Exception:
-            pass
-        print(json.dumps(result))
-        return 1
-    print(json.dumps(result))
-    return 0 if result["all_arms_certified"] else 1
-
-
 def _last_known_trace(search_dir: "str | None" = None) -> "dict | None":
     """Most recent completed tracer-overhead A/B from any committed TRACE_*
     artifact. A failed
@@ -2114,53 +1970,6 @@ def main():
         result.update(_cached_epoch_workload())
         # MFU at a hardware-meaningful model size (see _mfu_workload).
         result.update(_mfu_workload())
-        # Re-certify the fused Pallas kernel on every benchmark run:
-        # forward/grad accuracy vs f64 ground truth + the time of the bundle
-        # against the XLA segment bundle.
-        from hydragnn_tpu.ops.pallas_segment import certify_pallas
-
-        cert = certify_pallas()
-        result["pallas_ok"] = cert["ok"]
-        result["pallas_speedup"] = cert["speedup"]
-        result["pallas_ms"] = cert["pallas_ms"]
-        # Whether the benchmarked workload itself used the kernel
-        # (HYDRAGNN_PALLAS=0 would certify a kernel production skips).
-        result["pallas_enabled"] = cert["pallas_enabled"]
-        result["pallas_max_err"] = max(
-            cert["max_err_fwd"], cert["max_err_grad"]
-        )
-        # CONTIGUOUS (sorted) ids — the production collation pattern and the
-        # only shape on which block skipping is possible — with the
-        # scatter-free sorted arm (ops/segment_sorted.py) riding along.
-        base_c = certify_pallas(contiguous=True)
-        result["pallas_ms_contiguous"] = base_c["pallas_ms"]
-        result["sorted_ok"] = base_c.get("sorted_ok")
-        result["sorted_ms"] = base_c.get("sorted_ms")
-        result["sorted_err_grad"] = base_c.get("sorted_err_grad")
-        result["sorted_speedup_vs_xla"] = base_c.get("sorted_speedup_vs_xla")
-        if not cert["pallas_skip"]:
-            # The block-skip variant (default off in production —
-            # ops/pallas_segment.py:pallas_skip_enabled), same ids.
-            saved = os.environ.get("HYDRAGNN_PALLAS_SKIP")
-            try:
-                os.environ["HYDRAGNN_PALLAS_SKIP"] = "1"
-                skip_c = certify_pallas(contiguous=True, sorted_arm=False)
-            finally:
-                if saved is None:
-                    os.environ.pop("HYDRAGNN_PALLAS_SKIP", None)
-                else:
-                    os.environ["HYDRAGNN_PALLAS_SKIP"] = saved
-            result["pallas_skip_ok"] = skip_c["ok"]
-            result["pallas_skip_ms_contiguous"] = skip_c["pallas_ms"]
-            result["pallas_skip_speedup"] = round(
-                base_c["pallas_ms"] / skip_c["pallas_ms"], 3
-            )
-        failed = [
-            k for k in ("pallas_ok", "sorted_ok", "pallas_skip_ok")
-            if result.get(k) is False
-        ]
-        if failed:
-            raise RuntimeError(f"kernel certification failed: {failed}")
     except Exception as e:  # diagnostic JSON instead of a bare traceback
         import traceback
 
@@ -2189,8 +1998,6 @@ if __name__ == "__main__":
         sys.exit(faults_main())
     if "--packing" in sys.argv:
         sys.exit(packing_main())
-    if "--kernels" in sys.argv:
-        sys.exit(kernels_main())
     if "--trace" in sys.argv:
         sys.exit(trace_main())
     if "--compile-cache" in sys.argv:

@@ -353,12 +353,8 @@ def main() -> int:
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["SERIALIZED_DATA_PATH"] = args.out
-    for flag in (
-        "HYDRAGNN_PALLAS", "HYDRAGNN_PALLAS_CSR", "HYDRAGNN_PALLAS_SKIP",
-        "HYDRAGNN_PALLAS_BE", "HYDRAGNN_SEGMENT_SORTED",
-        "HYDRAGNN_COMPILE_CACHE",
-    ):
-        env.pop(flag, None)  # stages run the defaults, then set arms themselves
+    for flag in ("HYDRAGNN_SEGMENT_SORTED", "HYDRAGNN_COMPILE_CACHE"):
+        env.pop(flag, None)  # stages run the defaults
     if args.rehearse_on_cpu:
         say("REHEARSAL on the CPU at tiny sizes: nothing below is a chip result")
         env["JAX_PLATFORMS"] = "cpu"
@@ -671,9 +667,9 @@ def _child_warm(args) -> dict:
 
 
 def _child_kernels(args) -> dict:
-    """Every aggregation arm an option can select, compiled for this platform
-    (Mosaic on the TPU — never the interpreter there) and held to
-    certify_pallas's tolerances against an f64 ground truth, forward and
+    """Every aggregation arm the sorted route can take, on this platform (the
+    extrema scan kernel is Mosaic's on the TPU, never the interpreter there),
+    held to ops/certify.py's gates against an f64 ground truth, forward and
     gradient, next to ops/segment.py's own error on the same data; then the
     bf16 training policy against the f32 run."""
     import jax
@@ -683,98 +679,64 @@ def _child_kernels(args) -> dict:
     device = _require_platform(args)
     from hydragnn_tpu import run_training
     from hydragnn_tpu.cache.jaxcache import place_jax_cache
-    from hydragnn_tpu.ops import pallas_segment as ps
+    from hydragnn_tpu.ops import aggregate as agg
+    from hydragnn_tpu.ops import segment_sorted as srt
+    from hydragnn_tpu.ops.certify import certify_aggregation
 
     place_jax_cache()
     on_tpu = device["platform"] == "tpu"
-    if on_tpu and ps._platform() != "tpu":
-        raise SystemExit("[chip_smoke] kernels: Pallas gate not on tpu")
+    if not on_tpu:
+        # The rehearsal: the chip's arm under this CPU, by the one override.
+        os.environ["HYDRAGNN_SEGMENT_SORTED"] = "1"
+    if not srt.sorted_enabled():
+        raise SystemExit("[chip_smoke] kernels: the sorted arm is not on")
     shape = _sizes(args)["certify"]
     out: dict = {"device": device, "shape": shape, "arms": {}}
     failed = []
 
-    def mosaic_calls(env: dict, sorted_ids: bool) -> int:
-        """tpu_custom_call sites in the lowering of the arm `env` selects."""
+    def mosaic_calls(fn) -> int:
+        """tpu_custom_call sites in the lowering of ``fn(data, ids, row_ptr)``."""
         e, f, n = shape["e"], shape["f"], shape["n"]
         ids = jnp.sort(jax.random.randint(jax.random.PRNGKey(0), (e,), 0, n))
         row_ptr = jnp.searchsorted(ids, jnp.arange(n + 1)).astype(jnp.int32)
-        saved = {k: os.environ.get(k) for k in env}
-        os.environ.update(env)
-        try:
-            text = jax.jit(
-                lambda d: ps.fused_segment_stats(
-                    d, ids, n, sorted_ids=sorted_ids,
-                    row_ptr=row_ptr if sorted_ids else None,
-                )
-            ).lower(jnp.ones((e, f), jnp.float32)).as_text()
-        finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
+        text = jax.jit(lambda d: fn(d, ids, row_ptr)).lower(
+            jnp.ones((e, f), jnp.float32)
+        ).as_text()
         return text.count("tpu_custom_call")
 
-    def record(name, option, ok, fwd, grad, mosaic, ref_fwd, ref_grad):
-        arm = {
-            "option": option, "ok": bool(ok), "err_fwd": fwd, "err_grad": grad,
-            "ops_segment_err_fwd": ref_fwd, "ops_segment_err_grad": ref_grad,
-            "mosaic_custom_calls": mosaic,
-        }
+    n = shape["n"]
+    rep = certify_aggregation(**shape)
+    out["tolerance"] = {"fwd": rep["tol"], "grad": rep["tol_grad"]}
+    out["ops_segment"] = rep["xla"]
+    arms = {
+        # name: (selected by, the certifier's verdict, Mosaic kernels expected)
+        "sorted": (
+            "the sorted arm, no row_ptr", rep["arms"]["sorted"],
+            mosaic_calls(lambda d, i, p: agg.fused_segment_stats(d, i, n)), 0,
+        ),
+        "csr": (
+            "the sorted arm, the batch's row_ptr", rep["arms"]["csr"],
+            mosaic_calls(
+                lambda d, i, p: agg.fused_segment_stats(d, i, n, row_ptr=p)
+            ), 0,
+        ),
+        "extrema_scan": (
+            "the sorted arm, row_ptr, no edge-sharded axis",
+            rep["extrema_scan"],
+            mosaic_calls(lambda d, i, p: agg.segment_extrema(d, i, n, None, p)),
+            1,
+        ),
+    }
+    for name, (selected_by, verdict, mosaic, want_mosaic) in arms.items():
+        arm = {"selected_by": selected_by, **verdict, "mosaic_custom_calls": mosaic}
         out["arms"][name] = arm
         say(f"arm {name}: {json.dumps(arm)}")
-        if not ok:
+        if not verdict["ok"]:
             failed.append(f"{name} outside tolerance")
-        if on_tpu and mosaic is not None and mosaic < 1:
-            failed.append(f"{name} lowered without a Mosaic kernel")
-
-    pallas_on = {"HYDRAGNN_PALLAS": "1", "HYDRAGNN_SEGMENT_SORTED": "0"}
-    # One-hot MXU kernel (two-matmul split at f > 64), the sorted prefix path
-    # (the TPU default, XLA only) and the CSR run-walk kernel.
-    rep = ps.certify_pallas(contiguous=True, reps=3, **shape)
-    out["tolerance"] = {"fwd": rep["tol"], "grad": rep["tol_grad"]}
-    record(
-        "onehot_split", "HYDRAGNN_PALLAS=1 HYDRAGNN_PALLAS_CSR=0",
-        rep["ok"], max(rep["max_err_fwd"], rep["wide_err_fwd"]),
-        max(rep["max_err_grad"], rep["wide_err_grad"]),
-        mosaic_calls({**pallas_on, "HYDRAGNN_PALLAS_CSR": "0"}, True),
-        rep["xla_err_fwd"], rep["xla_err_grad"],
-    )
-    record(
-        "sorted_prefix", "default on tpu (HYDRAGNN_SEGMENT_SORTED=1 elsewhere)",
-        rep["sorted_ok"], rep["sorted_err_fwd"], rep["sorted_err_grad"], None,
-        rep["xla_err_fwd"], rep["xla_err_grad"],
-    )
-    record(
-        "csr_run_walk", "HYDRAGNN_PALLAS=1 HYDRAGNN_SEGMENT_SORTED=0",
-        rep["csr_ok"], rep["csr_err_fwd"], rep["csr_err_grad"],
-        mosaic_calls(pallas_on, True), rep["xla_err_fwd"], rep["xla_err_grad"],
-    )
-    # The packed one-hot kernel (hi/lo side by side when f <= 64).
-    packed_shape = dict(shape, f=min(shape["f"], 64))
-    rep = ps.certify_pallas(
-        contiguous=True, reps=3, sorted_arm=False, csr_arm=False, **packed_shape
-    )
-    record(
-        "onehot_packed", f"HYDRAGNN_PALLAS=1 at f={packed_shape['f']}",
-        rep["ok"], rep["max_err_fwd"], rep["max_err_grad"], None,
-        rep["xla_err_fwd"], rep["xla_err_grad"],
-    )
-    # The block-skip variant of the one-hot kernel.
-    os.environ["HYDRAGNN_PALLAS_SKIP"] = "1"
-    try:
-        rep = ps.certify_pallas(
-            contiguous=True, reps=3, sorted_arm=False, csr_arm=False, **shape
-        )
-        skip_calls = mosaic_calls(pallas_on, False)
-    finally:
-        os.environ.pop("HYDRAGNN_PALLAS_SKIP")
-    record(
-        "onehot_skip", "HYDRAGNN_PALLAS=1 HYDRAGNN_PALLAS_SKIP=1",
-        rep["ok"], max(rep["max_err_fwd"], rep["wide_err_fwd"]),
-        max(rep["max_err_grad"], rep["wide_err_grad"]), skip_calls,
-        rep["xla_err_fwd"], rep["xla_err_grad"],
-    )
+        if on_tpu and mosaic != want_mosaic:
+            failed.append(
+                f"{name} lowered with {mosaic} Mosaic kernels, not {want_mosaic}"
+            )
 
     # Training.precision "bf16": the same data and seed through run_training,
     # one epoch, against the f32 run's first epoch.

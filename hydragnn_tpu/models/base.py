@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from ..graphs.batch import GraphBatch
-from ..ops import pallas_segment
+from ..ops import aggregate
 from ..ops import segment as seg
 from ..telemetry import scopes
 from .layers import MLP, MaskedBatchNorm
@@ -398,9 +398,9 @@ class HydraGNN(nn.Module):
         # Masked global mean pool (Base.py:247-250); graph_ptr is the CSR
         # boundary array over node_graph (nodes are contiguous per graph).
         with jax.named_scope(scopes.POOL):
-            x_graph = pallas_segment.fused_segment_mean(
+            x_graph = aggregate.fused_segment_mean(
                 x, batch.node_graph, batch.num_graphs_pad, mask=batch.node_mask,
-                sorted_ids=True, row_ptr=batch.graph_ptr,
+                row_ptr=batch.graph_ptr,
             )
 
         outputs = []

@@ -12,9 +12,9 @@ Call convention (all convs):
              row_ptr=None)
 with x: [N_pad, F], senders/receivers: [E_pad], edge_attr: [E_pad, D] or None,
 row_ptr: [N_pad + 1] CSR boundaries over the destination-sorted receivers (the
-PR-7 batch contract, graphs/csr.py) or None — when present, every sorted-path
+PR-7 batch contract, graphs/csr.py) or None — when present, every sorted-arm
 aggregation consumes precomputed boundaries (zero in-step searchsorted) and
-the Pallas opt-in routes to the CSR run-walk kernels.
+PNA's min and max come from the scan kernel (ops/aggregate.py has the table).
 
 Every row a conv gathers, and so every row its backward scatter-adds, is
 rank 2: [N_pad, width] -> [E_pad, width]. GATv2's heads included: its rows
@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from ..ops import pallas_segment
+from ..ops import aggregate
 from ..telemetry import scopes
 
 # Conv families whose aggregation rides the sorted/CSR edge layout end to end
@@ -62,7 +62,7 @@ class SAGEConv(nn.Module):
         n = x.shape[0]
         with jax.named_scope(scopes.GATHER):
             x_j = x[senders]
-        nbr = pallas_segment.fused_segment_mean(x_j, receivers, n, mask=edge_mask, axis_name=self.axis_name, sorted_ids=True, row_ptr=row_ptr)
+        nbr = aggregate.fused_segment_mean(x_j, receivers, n, mask=edge_mask, axis_name=self.axis_name, row_ptr=row_ptr)
         return nn.Dense(self.out_dim, name="lin_nbr")(nbr) + nn.Dense(
             self.out_dim, name="lin_self"
         )(x)
@@ -82,7 +82,7 @@ class GINConv(nn.Module):
         eps = self.param("eps", nn.initializers.constant(self.eps_init), ())
         with jax.named_scope(scopes.GATHER):
             x_j = x[senders]
-        agg = pallas_segment.fused_segment_sum(x_j, receivers, n, mask=edge_mask, axis_name=self.axis_name, sorted_ids=True, row_ptr=row_ptr)
+        agg = aggregate.fused_segment_sum(x_j, receivers, n, mask=edge_mask, axis_name=self.axis_name, row_ptr=row_ptr)
         h = (1.0 + eps) * x + agg
         h = nn.Dense(self.out_dim, name="mlp_0")(h)
         h = nn.relu(h)
@@ -110,9 +110,9 @@ class MFCConv(nn.Module):
         b = self.param("bias", nn.initializers.zeros, (d, self.out_dim))
         with jax.named_scope(scopes.GATHER):
             x_j = x[senders]
-        agg, deg_f = pallas_segment.fused_segment_sum_count(
+        agg, deg_f = aggregate.fused_segment_sum_count(
             x_j, receivers, n, mask=edge_mask, axis_name=self.axis_name,
-            sorted_ids=True, row_ptr=row_ptr,
+            row_ptr=row_ptr,
         )
         deg = jnp.clip(deg_f.astype(jnp.int32), 0, self.max_degree)
         # The degree-indexed weights are row gathers too (backward: scatter-
@@ -219,9 +219,9 @@ class GATv2Conv(nn.Module):
         # identical on every shard (nodes replicated) and added AFTER the
         # reduction, so it is counted exactly once — the replacement for the
         # old shard-0-only self-loop mask.
-        denom = pallas_segment.fused_segment_sum(
+        denom = aggregate.fused_segment_sum(
             exp_e, receivers, n, mask=edge_mask, axis_name=self.axis_name,
-            sorted_ids=True, row_ptr=row_ptr,
+            row_ptr=row_ptr,
         ) + exp_self
         with jax.named_scope(scopes.GATHER):
             denom_e = denom[receivers]
@@ -240,9 +240,8 @@ class GATv2Conv(nn.Module):
             )
         msgs = x_j * jnp.dot(alpha, repeat, precision=_HEAD_PRECISION)  # [E, h·f]
         msgs = jnp.where(edge_mask[:, None], msgs, 0.0)
-        out = pallas_segment.fused_segment_sum(
-            msgs, receivers, n, axis_name=self.axis_name, sorted_ids=True,
-            row_ptr=row_ptr,
+        out = aggregate.fused_segment_sum(
+            msgs, receivers, n, axis_name=self.axis_name, row_ptr=row_ptr,
         )  # [N, h·f]
         # The self-loop message.
         out = out + x_src * jnp.dot(alpha_self, repeat, precision=_HEAD_PRECISION)
@@ -276,7 +275,7 @@ class CGConv(nn.Module):
         msgs = gate * core
         # Padding edges carry nonzero softplus output — mask before aggregation.
         msgs = jnp.where(edge_mask[:, None], msgs, 0.0)
-        return x + pallas_segment.fused_segment_sum(msgs, receivers, n, axis_name=self.axis_name, sorted_ids=True, row_ptr=row_ptr)
+        return x + aggregate.fused_segment_sum(msgs, receivers, n, axis_name=self.axis_name, row_ptr=row_ptr)
 
 
 class PNAConv(nn.Module):
@@ -307,12 +306,11 @@ class PNAConv(nn.Module):
         z = jnp.concatenate(z, axis=-1)
         msg = nn.Dense(f, name="pre_nn")(z)  # [E, f]
 
-        # Fused Pallas moments kernel on TPU (one pass over msg for mean/std),
-        # masked XLA segment ops elsewhere — see ops/pallas_segment.py.
-        agg, deg = pallas_segment.pna_aggregate(
+        # The sorted arm's stats bundle and extrema on the TPU, masked XLA
+        # segment ops elsewhere — see ops/aggregate.py.
+        agg, deg = aggregate.pna_aggregate(
             msg, receivers, n, self.aggregators,
-            mask=edge_mask, axis_name=self.axis_name, sorted_ids=True,
-            row_ptr=row_ptr,
+            mask=edge_mask, axis_name=self.axis_name, row_ptr=row_ptr,
         )  # agg: [N, A, f]
 
         deg = jnp.maximum(deg, 1.0)

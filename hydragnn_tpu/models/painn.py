@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from ..ops import pallas_segment
+from ..ops import aggregate
 from ..telemetry import scopes
 
 
@@ -106,9 +106,9 @@ class PaiNNBlock(nn.Module):
         ]
         # ONE 4F-wide sum for both states: the sorted arm's prefix passes and
         # boundary reads are paid once (PERF.md §6, PR 26 has the measurement).
-        agg = pallas_segment.fused_segment_sum(
+        agg = aggregate.fused_segment_sum(
             jnp.concatenate([a] + dv, axis=-1), receivers, n,
-            axis_name=self.axis_name, sorted_ids=True, row_ptr=row_ptr,
+            axis_name=self.axis_name, row_ptr=row_ptr,
         ).astype(s.dtype)
         s = s + agg[:, :f]
         v = v + agg[:, f:]

@@ -1,1 +1,1 @@
-from . import pallas_segment, segment
+from . import aggregate, segment

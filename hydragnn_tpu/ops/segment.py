@@ -26,13 +26,13 @@ from ..telemetry.scopes import agg_scope
 
 _BIG = 1e30
 
-# Platform the op-gating decisions see (fused-kernel and sorted-path
-# defaults). jax.default_backend() is process-global and WRONG in
+# Platform the arm decision of ops/aggregate.py sees (the sorted arm's default,
+# whether the extrema kernel is compiled or interpreted). jax.default_backend() is process-global and WRONG in
 # mixed-platform environments (a TPU-attached host tracing a step for a CPU
 # mesh): the gate must reflect the devices that will execute the op. Step
 # builders pin it for the duration of tracing via platform_override().
-# Defined here (the lowest-level ops module) so pallas_segment and
-# segment_sorted share one source of truth without a circular import.
+# Defined here (the lowest-level ops module) so aggregate and segment_sorted
+# share one source of truth without a circular import.
 _PLATFORM_OVERRIDE: ContextVar[Optional[str]] = ContextVar(
     "hydragnn_execution_platform", default=None
 )
@@ -179,8 +179,9 @@ def segment_softmax(
     weights are for the LOCAL edge shard.
 
     ``sum_fn(data, ids, n, mask=, axis_name=)`` overrides the denominator's
-    segment sum (must return the globally-reduced sum) — the hook the fused
-    Pallas kernel plugs into so both paths share ONE stabilization body."""
+    segment sum (must return the globally-reduced sum) — the hook
+    ``aggregate.fused_segment_softmax`` plugs the sorted arm's sum into, so
+    both arms share ONE stabilization body."""
     with agg_scope("softmax", "xla"):
         if mask is not None:
             logits = jnp.where(_expand(mask, logits), logits, -_BIG)

@@ -29,16 +29,16 @@ across chunks as an UNEVALUATED hi+err pair via error-free TwoSum — no f64,
 so no dependence on jax_enable_x64. The segment value is recovered as
 (hi_r - hi_l) + (err_r - err_l) + (local_r - local_l): the hi cancellation
 is exactly rounded and its accumulated rounding error lives in err.
-Certified against the same f64 ground truth as the Pallas kernel (tests).
+Certified against an f64 ground truth (ops/certify.py).
 
-The default route on the TPU (``sorted_enabled``; HYDRAGNN_SEGMENT_SORTED=1/0
-overrides either way). The sorted arm rides along whenever ``certify_pallas``
-runs on contiguous ids (bench.py, chip_smoke.py's kernels stage,
-benchmarks/tune_kernel.py's first sweep arm). Convs request it via
-``sorted_ids=True`` (+ the batch's ``row_ptr``) on the fused_* wrappers —
-since PR 7 that includes GAT, whose
-self-loops became an explicit self-attention term instead of the
-sort-breaking ``[edges; self-loops]`` concat (models/convs.py:GATv2Conv).
+The TPU's arm (``sorted_enabled``): ``ops/aggregate.py`` routes every conv
+family's sums, means and PNA's stats here when it is on, with the batch's
+``row_ptr`` where the batch carries one (scope arm ``csr``) and the two
+searches where it does not (``sorted``). ``ops/certify.py`` holds both to an
+f64 ground truth, forward and gradient. What the arms cost on the chip is in
+PERF.md §5 and PERF_LEDGER.jsonl (``agg.sum.csr``, ``agg.stats.csr``,
+``agg.mean.csr``); against XLA's scatters inside a train step: not measured
+since the benchmark of PR 22 replaced the harness that had compared them.
 """
 
 from __future__ import annotations
@@ -71,18 +71,19 @@ def _host_assert_sorted(ids, what="segment ids"):
         k = int(np.argmax(np.diff(arr) < 0))
         raise RuntimeError(
             f"sorted-layout contract violated: {what} decrease at row {k} "
-            f"({int(arr[k])} -> {int(arr[k + 1])}) — a caller passed "
-            "sorted_ids=True on an unsorted layout (HYDRAGNN_DEBUG_LAYOUT "
-            "check)"
+            f"({int(arr[k])} -> {int(arr[k + 1])}) — a caller of "
+            "ops/aggregate.py passed an unsorted layout "
+            "(HYDRAGNN_DEBUG_LAYOUT check)"
         )
 
 
 def attach_layout_check(ids: jnp.ndarray, what: str = "segment ids") -> None:
     """Debug-mode runtime assertion that ``ids`` really is non-decreasing.
 
-    The ``fused_*`` wrappers accept ``sorted_ids=True`` on the caller's word;
-    collation validates its own batches once per arena (graphs/csr.py), but a
-    NEW caller with a broken layout would silently corrupt aggregation. Under
+    The entry points of ``ops/aggregate.py`` take sorted ids as their
+    precondition; collation validates its own batches once per arena
+    (graphs/csr.py), but a NEW caller with a broken layout would silently
+    corrupt aggregation. Under
     ``HYDRAGNN_DEBUG_LAYOUT=1`` (read at trace time, like every other gate
     here) each sorted-path op embeds a host callback that raises on the first
     unsorted batch; default off — zero cost in production steps."""
@@ -93,18 +94,16 @@ def attach_layout_check(ids: jnp.ndarray, what: str = "segment ids") -> None:
 
 
 def sorted_enabled() -> bool:
-    """Trace-time gate, like HYDRAGNN_PALLAS (set before the first step).
+    """Whether the aggregation takes the sorted arm. Read at trace time.
 
-    DEFAULT ON for TPU execution since round 5: the first full hardware
-    bench of the three aggregation candidates (BENCH_r05_sorted.json, TPU
-    v5e) measured the sorted path at 926,028 graphs/s/chip on the flagship
-    workload vs the 812,122 XLA-scatter baseline pin (+14%; steady step
-    0.276 ms vs 0.315 ms; the hidden=256 model stepped 1.65x faster), with
-    hardware-certified accuracy (CERTIFY_r05.json sorted arm: fwd 3.0e-5,
-    grad 1.5e-4 — the only arm that met every gate before the kernel fix).
-    Off-TPU the default stays the XLA scatter bundle (CPU scatters are
-    cheap and the exact-gate reference-parity tests pin that path).
-    HYDRAGNN_SEGMENT_SORTED=1/0 overrides either way."""
+    On where the step executes on a TPU (``ops/segment.py``
+    ``execution_platform``, which the step builders pin to the mesh's
+    platform), off elsewhere: a scatter is the TPU's slow operation and a
+    CPU's cheap one, and the exact-gate reference-parity tests pin the XLA
+    ops on the CPU. ``HYDRAGNN_SEGMENT_SORTED=1/0`` overrides either way: it
+    is how the tests put the chip's arm under a CPU, and the only variable
+    that selects an aggregation arm. Speed of one arm against the other on
+    the chip: not measured (PERF.md §7)."""
     env = os.environ.get("HYDRAGNN_SEGMENT_SORTED")
     if env is not None:
         return env not in ("0", "false", "False")
@@ -218,7 +217,7 @@ def segment_sum_count_sorted(data, ids, num_segments: int):
 
 def _fwd(data, ids, num_segments):
     # Zero-size carrier keeps the input dtype in the residuals (a raw dtype
-    # object is not a JAX type) — same trick as pallas_segment's VJP.
+    # object is not a JAX type).
     carrier = jnp.zeros((0,), data.dtype)
     return _sum_count_sorted(data, ids, num_segments), (ids, carrier)
 
@@ -266,7 +265,7 @@ segment_sum_count_csr.defvjp(_csr_fwd, _csr_bwd)
 
 def segment_sum_count_auto(data, ids, num_segments: int, row_ptr=None):
     """Dispatch between the precomputed-boundary and searchsorted variants —
-    the single entry the fused wrappers route sorted traffic through."""
+    the single entry ``ops/aggregate.py`` routes sorted traffic through."""
     if row_ptr is not None:
         return segment_sum_count_csr(data, row_ptr, ids, num_segments)
     return segment_sum_count_sorted(data, ids, num_segments)

@@ -240,7 +240,7 @@ class InferenceEngine:
         symmetric int8 grid (precision/quantize.py). Both quantized arms
         REQUIRE a positive ``tolerance`` — the bit-exactness gate relaxes to
         :meth:`check_tolerance` (max-abs-diff vs a retained f32 reference,
-        shared machinery with certify_pallas) for quantized mode only. The
+        shared machinery with ops/certify.py) for quantized mode only. The
         arm is a CacheKey policy component: quantized executables can never
         hydrate an f32 entry or vice versa.
     compile_cache:
@@ -1544,8 +1544,8 @@ class InferenceEngine:
         """The quantized-arm gate (docs/PRECISION.md): collate one probe
         batch, run it through BOTH the serving executable (bf16/int8) and a
         retained f32 reference forward, and compare with the shared tolerance
-        machinery (precision/tolerance.py — the same helpers certify_pallas
-        gates kernels with). Within the bound: returns the verdict report
+        machinery (precision/tolerance.py — the same helpers ops/certify.py
+        gates the aggregation arms with). Within the bound: returns the verdict report
         (also folded into ``hydragnn_serve_precision_*`` metrics). Beyond it:
         raises :class:`PrecisionToleranceError` — a quantized arm that cannot
         meet its declared tolerance must not take traffic.

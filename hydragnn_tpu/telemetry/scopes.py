@@ -47,11 +47,11 @@ GRAD_SYNC = "hydragnn.grad_sync"  # the mesh step's psums of gradients/counts
 AGG_PNA = "hydragnn.agg.pna"  # PNA's bundle; its stats/extrema nest inside
 
 AGG_WHATS = ("sum", "count", "sum_count", "mean", "stats", "extrema", "softmax")
-# The route Python took at trace time: masked XLA segment ops, the sorted
-# prefix path with searched or with precomputed (CSR) boundaries, the one-hot
-# Pallas kernel, a Pallas kernel over the CSR boundaries (the run-walk one
-# for sums; for extrema the scan over receiver runs).
-AGG_ARMS = ("xla", "sorted", "csr", "pallas", "pallas_csr")
+# The route ops/aggregate.py took at trace time: masked XLA segment ops, the
+# sorted prefix path with searched or with precomputed (CSR) boundaries, the
+# Pallas kernel over the CSR boundaries (the extrema's scan over receiver
+# runs, ops/extrema_scan.py).
+AGG_ARMS = ("xla", "sorted", "csr", "pallas_csr")
 
 
 def agg(what: str, arm: str) -> str:

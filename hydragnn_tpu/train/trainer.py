@@ -29,14 +29,14 @@ from jax.sharding import PartitionSpec as P
 from ..graphs.batch import GraphBatch
 from ..models.base import HydraGNN
 from ..models.loss import multihead_rmse_loss
-from ..ops.pallas_segment import pallas_platform
+from ..ops.segment import platform_override
 from ..telemetry import scopes
 
 
 def _mesh_platform(mesh) -> str:
-    """Platform of the devices a mesh's step will execute on — what the Pallas
-    gating must key off (jax.default_backend() lies when a TPU-attached host
-    traces a step for a CPU-device mesh)."""
+    """Platform of the devices a mesh's step will execute on — what the
+    aggregation's arm must key off (jax.default_backend() lies when a
+    TPU-attached host traces a step for a CPU-device mesh)."""
     return next(iter(mesh.devices.flat)).platform
 
 
@@ -412,7 +412,7 @@ def _batch_pspec(batch: GraphBatch, graph_sharded: bool) -> GraphBatch:
         targets=tuple(P("data") for _ in batch.targets),
         # CSR boundaries are node-/graph-indexed (never edge-sharded;
         # replicated across 'graph', where the ops layer LOCALIZES them per
-        # edge shard — pallas_segment.localize_row_ptr, the graftmesh
+        # edge shard — ops/aggregate.py localize_row_ptr, the graftmesh
         # halo/edge-cut contract — so graph-partitioned steps stay
         # zero-searchsorted).
         row_ptr=None if batch.row_ptr is None else P("data"),
@@ -684,10 +684,10 @@ def _wrap_dp_step(local, mesh, graph_sharded: bool, donate: bool):
     platform = _mesh_platform(mesh)
 
     def step(state, batch, rng):
-        # Tracing happens inside this call: pin the Pallas gate to the mesh's
-        # execution platform for the duration. The root scope is the
+        # Tracing happens inside this call: pin the aggregation's platform to
+        # the mesh's for the duration. The root scope is the
         # one-device step's (telemetry/scopes.py): one name a program.
-        with pallas_platform(platform), jax.named_scope(scopes.TRAIN_STEP):
+        with platform_override(platform), jax.named_scope(scopes.TRAIN_STEP):
             sharded = jax.shard_map(
                 local,
                 mesh=mesh,
@@ -724,7 +724,7 @@ def make_eval_step_dp(model: HydraGNN, mesh) -> Callable:
     platform = _mesh_platform(mesh)
 
     def step(state, batch):
-        with pallas_platform(platform), jax.named_scope(scopes.EVAL_STEP):
+        with platform_override(platform), jax.named_scope(scopes.EVAL_STEP):
             sharded = jax.shard_map(
                 _local,
                 mesh=mesh,

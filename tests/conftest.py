@@ -4,7 +4,8 @@ Tests run hermetically on the host CPU with 8 virtual devices, so the
 distributed (data-parallel mesh) paths are exercised the way the reference CI
 exercises DDP with 2 MPI ranks (/root/reference/.github/workflows/CI.yml:47-52).
 On a machine with a chip, HYDRAGNN_TPU_TESTS=1 leaves the chip as the default
-backend for the TPU-gated suites (tests/test_pallas_tpu.py).
+backend (no suite is gated on it now; the arms are certified on the chip by
+chip_smoke.py's kernels stage).
 
 JAX's persistent compilation cache is OFF for the session, and for every
 child process a test starts: the entry points place it at a fixed path in the
@@ -23,8 +24,7 @@ os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import jax
 
-# HYDRAGNN_TPU_TESTS=1 leaves the real accelerator as the default backend so
-# the TPU-gated suites (tests/test_pallas_tpu.py) run on hardware.
+# HYDRAGNN_TPU_TESTS=1 leaves the real accelerator as the default backend.
 if os.environ.get("HYDRAGNN_TPU_TESTS") != "1":
     jax.config.update("jax_platforms", "cpu")
 

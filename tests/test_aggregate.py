@@ -1,0 +1,311 @@
+"""The aggregation entry points of ``hydragnn_tpu/ops/aggregate.py`` on the
+arms that exist, against the masked XLA segment ops of ``ops/segment.py`` and,
+where those are themselves inexact, against float64.
+
+``route`` puts the chip's arm under this CPU through the one override
+(``HYDRAGNN_SEGMENT_SORTED=1``): ``sorted`` is the arm without the batch's
+``row_ptr``, ``csr`` the arm with it. Problems follow the batch contract: ids
+non-decreasing, the masked rows in the last (padding) segment's run, whose
+outputs nobody reads. Values and routes, never a time."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hydragnn_tpu.graphs.csr import build_row_ptr
+from hydragnn_tpu.ops import aggregate as agg
+from hydragnn_tpu.ops import segment as seg
+
+ROUTES = ("sorted", "csr")
+# The prefix sums' error at these sizes (ops/segment_sorted.py: compensated,
+# ~1e-5 absolute), well inside the 5e-4 gate of ops/certify.py.
+_ATOL = 3e-4
+_RTOL = 1e-4
+
+
+@pytest.fixture
+def route(request, monkeypatch):
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    return request.param
+
+
+def _problem(rng, route, e=300, n=40, f=17, padding=40):
+    """(data, ids, mask, n, row_ptr, real): ``padding`` masked rows at the end,
+    in the run of segment ``n - 1``; ``real`` the segments somebody reads."""
+    ids = np.sort(rng.integers(0, n - 1, size=e)).astype(np.int32)
+    ids[e - padding:] = n - 1
+    mask = np.arange(e) < e - padding
+    data = rng.normal(size=(e, f)).astype(np.float32)
+    row_ptr = jnp.asarray(build_row_ptr(ids, n)) if route == "csr" else None
+    return (
+        jnp.asarray(data), jnp.asarray(ids), jnp.asarray(mask), n, row_ptr,
+        np.arange(n) < n - 1,
+    )
+
+
+@pytest.mark.parametrize("route", ROUTES, indirect=True)
+def pytest_fused_stats_match_xla(route):
+    data, ids, mask, n, row_ptr, real = _problem(
+        np.random.default_rng(1), route, e=257, n=33, f=5
+    )
+    total, mean, std, count = agg.fused_segment_stats(
+        data, ids, n, mask=mask, row_ptr=row_ptr
+    )
+    for got, ref in (
+        (total, seg.segment_sum), (mean, seg.segment_mean), (std, seg.segment_std)
+    ):
+        np.testing.assert_allclose(
+            got[real], ref(data, ids, n, mask=mask)[real], rtol=_RTOL, atol=_ATOL
+        )
+    np.testing.assert_array_equal(
+        count[real], seg.segment_count(ids, n, mask=mask)[real]
+    )
+    # The contract's other half: the masked rows ARE counted, in the padding
+    # segment, and nowhere else.
+    assert float(count[n - 1]) == 40.0
+
+
+@pytest.mark.parametrize("route", ROUTES, indirect=True)
+def pytest_fused_stats_gradient_matches_xla(route):
+    data, ids, mask, n, row_ptr, real = _problem(
+        np.random.default_rng(2), route, e=64, n=10, f=4, padding=9
+    )
+    w = jnp.asarray(real, jnp.float32)[:, None]
+
+    def fused_loss(d):
+        _, mean, std, _ = agg.fused_segment_stats(
+            d, ids, n, mask=mask, row_ptr=row_ptr
+        )
+        return jnp.sum(w * mean * 1.3) + jnp.sum(w * std * 0.7)
+
+    def xla_loss(d):
+        mean = seg.segment_mean(d, ids, n, mask=mask)
+        std = seg.segment_std(d, ids, n, mask=mask)
+        return jnp.sum(w * mean * 1.3) + jnp.sum(w * std * 0.7)
+
+    g_fused = jax.grad(fused_loss)(data)
+    np.testing.assert_allclose(
+        g_fused, jax.grad(xla_loss)(data), rtol=1e-4, atol=1e-5
+    )
+    assert not np.asarray(g_fused)[~np.asarray(mask)].any()
+
+
+@pytest.mark.parametrize("route", ROUTES, indirect=True)
+def pytest_pna_aggregate_matches_the_xla_composition(route, monkeypatch):
+    """``pna_aggregate`` on the sorted arm against itself off it (the masked
+    XLA ops), on every segment somebody reads."""
+    data, ids, mask, n, row_ptr, real = _problem(
+        np.random.default_rng(3), route, e=120, n=16, f=8, padding=14
+    )
+    aggregators = ("mean", "min", "max", "std")
+    got, cnt = agg.pna_aggregate(
+        data, ids, n, aggregators, mask=mask, row_ptr=row_ptr
+    )
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "0")
+    want, cnt_xla = agg.pna_aggregate(
+        data, ids, n, aggregators, mask=mask, row_ptr=row_ptr
+    )
+    np.testing.assert_allclose(got[real], want[real], rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(cnt[real], cnt_xla[real])
+
+
+@pytest.mark.parametrize("route", ROUTES, indirect=True)
+def pytest_centered_std_beats_uncentered_on_degenerate_segments(route):
+    """``std`` from centered values against XLA's
+    ``sqrt(relu(E[x^2]-E[x]^2)+eps)``, which cancels catastrophically in f32
+    when a segment's values cluster round a large offset; both against a
+    float64 reference of the centered form."""
+    rng = np.random.default_rng(7)
+    e, n, f = 512, 64, 4
+    base = rng.normal(size=(n,)) * 50
+    ids_np = np.sort(rng.integers(0, n, size=e))
+    data64 = base[ids_np][:, None] + rng.normal(size=(e, f)) * 1e-3
+    ids = jnp.asarray(ids_np.astype(np.int32))
+    data = jnp.asarray(data64.astype(np.float32))
+    row_ptr = jnp.asarray(build_row_ptr(ids_np, n)) if route == "csr" else None
+
+    ref = np.full((n, f), np.sqrt(1e-5))
+    for s in range(n):
+        rows = data64[ids_np == s]
+        if len(rows):
+            ref[s] = np.sqrt(rows.var(axis=0) + 1e-5)
+
+    _, _, std_fused, _ = agg.fused_segment_stats(data, ids, n, row_ptr=row_ptr)
+    std_xla = seg.segment_std(data, ids, n)
+    err_fused = float(np.abs(np.asarray(std_fused, np.float64) - ref).max())
+    err_xla = float(np.abs(np.asarray(std_xla, np.float64) - ref).max())
+    assert err_fused < 1e-4, err_fused
+    assert err_fused < err_xla  # strictly better than the uncentered form
+
+
+@pytest.mark.parametrize("route", ROUTES, indirect=True)
+def pytest_fused_dropin_wrappers_match_xla(route):
+    """``fused_segment_sum`` / ``_mean`` / ``_sum_count`` (what every conv
+    family calls) against the masked XLA ops, with 3-D data and a bf16 input
+    whose dtype the output must keep."""
+    rng = np.random.default_rng(1)
+    data, ids, mask, n, row_ptr, real = _problem(rng, route)
+
+    np.testing.assert_allclose(
+        agg.fused_segment_sum(data, ids, n, mask=mask, row_ptr=row_ptr)[real],
+        seg.segment_sum(data, ids, n, mask=mask)[real], rtol=_RTOL, atol=_ATOL,
+    )
+    np.testing.assert_allclose(
+        agg.fused_segment_mean(data, ids, n, mask=mask, row_ptr=row_ptr)[real],
+        seg.segment_mean(data, ids, n, mask=mask)[real], rtol=_RTOL, atol=_ATOL,
+    )
+    total, count = agg.fused_segment_sum_count(
+        data, ids, n, mask=mask, row_ptr=row_ptr
+    )
+    np.testing.assert_array_equal(
+        count[real], seg.segment_count(ids, n, mask=mask)[real]
+    )
+
+    # 3-D ([E, h, f], the trailing dims flattened inside); no mask.
+    d3 = jnp.asarray(rng.normal(size=(300, 3, 5)).astype(np.float32))
+    np.testing.assert_allclose(
+        agg.fused_segment_sum(d3, ids, n, row_ptr=row_ptr),
+        seg.segment_sum(d3, ids, n), rtol=_RTOL, atol=_ATOL,
+    )
+
+    # bf16 in → bf16 out (mixed-precision dtype flow preserved).
+    out = agg.fused_segment_sum(
+        data.astype(jnp.bfloat16), ids, n, mask=mask, row_ptr=row_ptr
+    )
+    assert out.dtype == jnp.bfloat16
+
+    # Gradients flow (gather backward), masked rows get zero cotangent.
+    g = jax.grad(
+        lambda d: agg.fused_segment_sum(d, ids, n, mask=mask, row_ptr=row_ptr).sum()
+    )(data)
+    g_ref = jax.grad(lambda d: seg.segment_sum(d, ids, n, mask=mask).sum())(data)
+    np.testing.assert_allclose(g, g_ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("route", ROUTES, indirect=True)
+def pytest_fused_segment_softmax_matches_xla(route):
+    """``fused_segment_softmax`` == ``seg.segment_softmax``, values and
+    gradients, with masking; the sorted arm's sum is the denominator's."""
+    rng = np.random.default_rng(2)
+    _, ids, mask, n, row_ptr, _ = _problem(rng, route, e=200, n=30, f=1, padding=25)
+    logits = jnp.asarray(rng.normal(size=(200, 6)).astype(np.float32) * 3)
+
+    a = agg.fused_segment_softmax(logits, ids, n, mask=mask, row_ptr=row_ptr)
+    b = seg.segment_softmax(logits, ids, n, mask=mask)
+    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    assert float(jnp.where(mask[:, None], a, 0.0).sum()) > 0
+    assert not bool(jnp.any(jnp.where(~mask[:, None], a, 0.0) != 0))
+
+    ga = jax.grad(lambda l: (
+        agg.fused_segment_softmax(l, ids, n, mask=mask, row_ptr=row_ptr) ** 2
+    ).sum())(logits)
+    gb = jax.grad(lambda l: (seg.segment_softmax(l, ids, n, mask=mask) ** 2).sum())(logits)
+    np.testing.assert_allclose(ga, gb, rtol=1e-4, atol=1e-6)
+    text = jax.jit(
+        lambda l: agg.fused_segment_softmax(l, ids, n, mask=mask, row_ptr=row_ptr)
+    ).lower(logits).as_text(debug_info=True)
+    assert f"hydragnn.agg.softmax.{route}" in text
+
+
+@pytest.mark.parametrize("route", ROUTES, indirect=True)
+def pytest_fused_ops_differentiable_under_shard_map(route):
+    """Graph-parallel backward through the sorted arm, the boundaries searched
+    in each shard's own rows (``sorted``) or the batch's ``row_ptr`` localized
+    a shard (``csr``): the gradient flows through ``shard_map`` over a 'graph'
+    axis and equals the one-device gradient. Jitted, as every step of the
+    program is (outside ``jit`` the zero-size dtype carrier in
+    ``segment_sorted``'s residuals is refused a sharding: ROADMAP D20)."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("graph",))
+    e, n, h = 64, 10, 3
+    rng = np.random.default_rng(0)
+    logits = jnp.asarray(rng.normal(size=(e, h)).astype(np.float32))
+    ids_np = np.sort(rng.integers(0, n, size=e)).astype(np.int32)
+    ids, row_ptr = jnp.asarray(ids_np), jnp.asarray(build_row_ptr(ids_np, n))
+    use = (lambda ptr: ptr) if route == "csr" else (lambda ptr: None)
+
+    def terms(l_, ids_, ptr, axis_name):
+        """(what is whole on every shard after its psum, what is this shard's)."""
+        s, c = agg.fused_segment_sum_count(
+            l_, ids_, n, axis_name=axis_name, row_ptr=ptr
+        )
+        a = agg.fused_segment_softmax(l_, ids_, n, axis_name=axis_name, row_ptr=ptr)
+        m = agg.fused_segment_mean(l_, ids_, n, axis_name=axis_name, row_ptr=ptr)
+        return (s ** 2).sum() + (m ** 2).sum(), (a ** 2).sum()
+
+    def local(l_, ids_, ptr):
+        whole, mine = terms(l_, ids_, use(ptr), "graph")
+        return whole + jax.lax.psum(mine, "graph")
+
+    f = jax.shard_map(
+        local, mesh=mesh, in_specs=(P("graph"), P("graph"), P()), out_specs=P(),
+        check_vma=False,
+    )
+    g = jax.jit(jax.grad(lambda l: f(l, ids, row_ptr)))(logits)
+    g_one = jax.grad(lambda l: sum(terms(l, ids, use(row_ptr), None)))(logits)
+    np.testing.assert_allclose(g, g_one, rtol=1e-4, atol=1e-5)
+
+
+def pytest_no_flag_selects_an_aggregation_kernel():
+    """The arm is decided from what the code can observe. The only environment
+    variables anything under ``hydragnn_tpu/ops/`` reads are the sorted arm's
+    override (the tests' seam, ROADMAP D17) and the layout check's switch."""
+    ops = os.path.dirname(agg.__file__)
+    modules = sorted(name for name in os.listdir(ops) if name.endswith(".py"))
+    assert modules == [
+        "__init__.py", "aggregate.py", "certify.py", "extrema_scan.py",
+        "segment.py", "segment_sorted.py",
+    ]
+    found = set()
+    for name in modules:
+        with open(os.path.join(ops, name)) as f:
+            text = f.read()
+        found |= set(re.findall(r"\bHYDRAGNN_[A-Z0-9_]+", text))
+        reads = re.findall(r"os\.environ|getenv", text)
+        assert not reads or name == "segment_sorted.py", (name, reads)
+    assert found == {"HYDRAGNN_SEGMENT_SORTED", "HYDRAGNN_DEBUG_LAYOUT"}, found
+
+
+def pytest_pna_aggregate_off_the_sorted_arm_is_the_masked_xla_ops_bit_for_bit(
+    monkeypatch,
+):
+    """Off the sorted arm (a CPU's default) nothing stands between PNA and
+    ``ops/segment.py``: values and gradient equal to the bit, ``row_ptr`` or
+    not, and no other arm's name in the program."""
+    monkeypatch.delenv("HYDRAGNN_SEGMENT_SORTED", raising=False)
+    rng = np.random.default_rng(5)
+    data, ids, mask, n, row_ptr, _ = _problem(rng, "csr", e=120, n=16, f=8)
+    aggregators = ("mean", "min", "max", "std", "sum")
+    weights = jnp.asarray(rng.normal(size=(n, len(aggregators), 8)), jnp.float32)
+
+    def composed(d):
+        ops = {"mean": seg.segment_mean, "min": seg.segment_min,
+               "max": seg.segment_max, "std": seg.segment_std,
+               "sum": seg.segment_sum}
+        return jnp.stack(
+            [ops[a](d, ids, n, mask=mask) for a in aggregators], axis=1
+        ), seg.segment_count(ids, n, mask=mask)
+
+    def loss(fn):
+        return lambda d: jnp.sum(fn(d)[0] * weights)
+
+    for ptr in (row_ptr, None):
+        def program(d):
+            return agg.pna_aggregate(d, ids, n, aggregators, mask=mask, row_ptr=ptr)
+
+        got, cnt = jax.jit(program)(data)
+        want, want_cnt = jax.jit(composed)(data)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        assert np.array_equal(np.asarray(cnt), np.asarray(want_cnt))
+        assert np.array_equal(
+            np.asarray(jax.jit(jax.grad(loss(program)))(data)),
+            np.asarray(jax.jit(jax.grad(loss(composed)))(data)),
+        )
+        text = jax.jit(program).lower(data).as_text(debug_info=True)
+        arms = set(re.findall(r"hydragnn\.agg\.\w+\.(\w+)", text))
+        assert arms == {"xla"}, arms

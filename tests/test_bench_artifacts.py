@@ -1,6 +1,6 @@
 """bench.py behaviour that must hold WITHOUT a device: the default invocation
 refuses anything but a TPU and fails on any failing phase; the side rigs'
-stale-artifact readers; the PALLAS_MATRIX schema-continuity helpers."""
+stale-artifact readers."""
 
 import json
 import os
@@ -44,7 +44,7 @@ def pytest_bench_default_invocation_refuses_a_cpu(capsys):
 
 
 def pytest_bench_phase_failure_fails_the_run(monkeypatch, capsys):
-    """The cached-epoch, wide-model and certification phases used to be
+    """The cached-epoch and wide-model phases used to be
     caught as non-fatal; any of them failing now fails the run."""
     import pytest
 
@@ -69,20 +69,6 @@ def pytest_bench_phase_failure_fails_the_run(monkeypatch, capsys):
     doc = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert "cached epoch broke" in doc["error"]
     assert "bucketed_cached_error" not in doc
-
-
-def pytest_pallas_matrix_schema_readable_both_ways():
-    from benchmarks.pallas_matrix import SCHEMA_VERSION, scatter_row_is_pallas
-
-    assert SCHEMA_VERSION >= 2
-    # v1 rows (r04 and earlier): {"pallas": bool}
-    assert scatter_row_is_pallas({"pallas": True, "seed": 0})
-    assert not scatter_row_is_pallas({"pallas": False, "seed": 0})
-    assert not scatter_row_is_pallas({"seed": 0})
-    # v2 rows (r05+): {"arm": str} (+ compat "pallas" bool)
-    assert scatter_row_is_pallas({"arm": "pallas", "pallas": True})
-    assert not scatter_row_is_pallas({"arm": "xla", "pallas": False})
-    assert not scatter_row_is_pallas({"arm": "sorted"})
 
 
 def pytest_last_known_serving_picks_latest_real_measurement(tmp_path):
@@ -212,46 +198,6 @@ def pytest_committed_swap_artifact_readable():
     assert blk["drills_passed"] == blk["drills_total"]
     assert blk["recompiles_after_swap"] == 0
     assert blk["zero_version_torn"] is True
-
-
-def pytest_last_known_kernels_picks_latest_real_round(tmp_path):
-    from bench import _last_known_kernels
-
-    real = {
-        "metric": "kernel_fight",
-        "value": 1.2,
-        "backend": "tpu",
-        "arms": {
-            "xla": {"ms": 0.08, "ok": True, "speedup_vs_xla": 1.0},
-            "pallas_csr": {"ms": 0.066, "ok": True, "speedup_vs_xla": 1.2},
-        },
-    }
-    (tmp_path / "KERNELS_r07.json").write_text(json.dumps(real))
-    # A failed --kernels round carries no arms — never "last known".
-    (tmp_path / "KERNELS_r08.json").write_text(
-        json.dumps({"metric": "kernel_fight", "error": "TimeoutError"})
-    )
-    now = time.time()
-    os.utime(tmp_path / "KERNELS_r07.json", (now - 50, now - 50))
-    os.utime(tmp_path / "KERNELS_r08.json", (now - 10, now - 10))
-
-    blk = _last_known_kernels(str(tmp_path))
-    assert blk is not None
-    assert blk["value"] == 1.2
-    assert blk["arms"]["pallas_csr"]["speedup_vs_xla"] == 1.2
-    assert blk["provenance"] == "stale"
-    assert blk["source_artifact"] == "KERNELS_r07.json"
-
-
-def pytest_committed_kernels_artifact_readable():
-    """The committed KERNELS_r* round is a valid last-known block (the
-    stale-fallback convention every bench arm follows)."""
-    from bench import _last_known_kernels
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    blk = _last_known_kernels(repo)
-    assert blk is not None
-    assert set(blk["arms"]) >= {"xla", "pallas_onehot", "pallas_csr", "sorted"}
 
 
 def pytest_last_known_compile_cache_picks_latest_real_round(tmp_path):

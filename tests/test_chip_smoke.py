@@ -70,9 +70,6 @@ def pytest_chip_smoke_rehearsal_passes_end_to_end(tmp_path):
         summary = json.load(f)
     assert {"device", "train", "serve", "kernels", "warm"} <= set(summary)
     assert summary["train"]["xla_compiles_per_epoch"][1:] == [0, 0]
-    assert set(summary["kernels"]["arms"]) == {
-        "onehot_split", "sorted_prefix", "csr_run_walk", "onehot_packed",
-        "onehot_skip",
-    }
+    assert set(summary["kernels"]["arms"]) == {"sorted", "csr", "extrema_scan"}
     assert summary["warm"]["warm"]["persistent_cache_hits"] > 0
     assert summary["warm"]["warm"]["cache_dir"] == str(tmp_path / "jax_cache")

@@ -5,7 +5,7 @@ precomputed-boundary vs searchsorted segment ops, the packed+shuffled+
 quarantined loader property (receivers always non-decreasing, row_ptr always
 consistent), zero in-step searchsorted via the trace spy, GAT's
 self-loop-as-self-term parity against the reference concat formulation, the
-CSR Pallas kernel certification gates, the debug-mode layout assertion hook,
+debug-mode layout assertion hook,
 and the check_config sorted-family / CSR-shape rejections."""
 
 import os
@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from hydragnn_tpu.graphs.collate import GraphArena, collate_graphs
 from hydragnn_tpu.graphs.csr import build_row_ptr, validate_csr
 from hydragnn_tpu.graphs.sample import GraphSample
-from hydragnn_tpu.ops import pallas_segment as ps
+from hydragnn_tpu.ops import aggregate as agg
 from hydragnn_tpu.ops import segment as seg
 from hydragnn_tpu.ops import segment_sorted as srt
 
@@ -361,8 +361,8 @@ class _Rank3GATv2Conv(nn.Module):
         m = jax.lax.stop_gradient(jnp.maximum(edge_max, logit_self))
         exp_e = jnp.where(edge_mask[:, None], jnp.exp(logits - m[receivers]), 0.0)
         exp_self = jnp.where(node_mask[:, None], jnp.exp(logit_self - m), 0.0)
-        denom = ps.fused_segment_sum(
-            exp_e, receivers, n, mask=edge_mask, sorted_ids=True, row_ptr=row_ptr,
+        denom = agg.fused_segment_sum(
+            exp_e, receivers, n, mask=edge_mask, row_ptr=row_ptr,
         ) + exp_self
         alpha = exp_e / jnp.maximum(denom[receivers], 1e-16)
         alpha_self = exp_self / jnp.maximum(denom, 1e-16)
@@ -375,7 +375,7 @@ class _Rank3GATv2Conv(nn.Module):
             alpha_self = jnp.where(keep[:n], alpha_self / (1.0 - self.dropout), 0.0)
         x_j = x_src[senders]
         msgs = jnp.where(edge_mask[:, None, None], x_j * alpha[..., None], 0.0)
-        out = ps.fused_segment_sum(msgs, receivers, n, sorted_ids=True, row_ptr=row_ptr)
+        out = agg.fused_segment_sum(msgs, receivers, n, row_ptr=row_ptr)
         out = out + x_src * alpha_self[..., None]
         if self.concat:
             out = out.reshape(n, h * f)
@@ -490,102 +490,9 @@ def pytest_gat_rides_sorted_path_with_zero_searchsorted(monkeypatch):
         )
 
 
-# ---------------------------------------------------------- CSR Pallas kernel
-def pytest_csr_kernel_matches_xla_and_certifies():
-    """The CSR run-walk kernel (interpreter = the program that compiles on
-    TPU) matches the masked XLA ops across the f-packing boundary, and the
-    full certification harness passes its f64 gates for the csr arm."""
-    rng = np.random.default_rng(7)
-    n = 170
-    # f values straddle the f-packing boundary (2f <= 128 packs hi/lo into
-    # one tile); the wide two-matmul side gets one representative.
-    for f in (1, 64, 65):
-        e = 700
-        ids = np.sort(rng.integers(0, n - 1, e)).astype(np.int32)
-        ids[-50:] = n - 1
-        data = (rng.normal(size=(e, f)) * 2 + 1).astype(np.float32)
-        data[-50:] = 0.0
-        row_ptr = jnp.asarray(build_row_ptr(ids, n))
-        s, c = ps.csr_segment_sum_count(
-            jnp.asarray(data), row_ptr, jnp.asarray(ids), n, interpret=True
-        )
-        want = seg.segment_sum(jnp.asarray(data), jnp.asarray(ids), n)
-        np.testing.assert_allclose(
-            np.asarray(s), np.asarray(want), rtol=1e-4, atol=3e-4
-        )
-        np.testing.assert_array_equal(
-            np.asarray(c), np.bincount(ids, minlength=n)
-        )
-
-
-def pytest_csr_kernel_certifies_f64_gates():
-    report = ps.certify_pallas(
-        e=1024, f=24, n=256, reps=1, contiguous=True, sorted_arm=False
-    )
-    if report["backend"] == "tpu":
-        pytest.skip("interpreter semantics under test; TPU covered by "
-                    "tests/test_pallas_tpu.py")
-    assert report["csr_ok"], report
-    assert report["csr_err_fwd"] < report["tol"]
-    assert report["csr_err_grad"] < report["tol_grad"]
-
-
-def pytest_fused_wrappers_route_row_ptr_to_csr_kernel(monkeypatch):
-    """Under HYDRAGNN_PALLAS=1 (sorted prefix pinned off) a sorted_ids call
-    WITH row_ptr runs the CSR kernel — parity with the XLA ops and with the
-    legacy one-hot kernel (HYDRAGNN_PALLAS_CSR=0)."""
-    monkeypatch.setenv("HYDRAGNN_PALLAS", "1")
-    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "0")
-    rng = np.random.default_rng(8)
-    e, n, f = 600, 120, 10
-    ids = np.sort(rng.integers(0, n - 1, e)).astype(np.int32)
-    ids[-40:] = n - 1
-    mask = np.ones(e, bool)
-    mask[-40:] = False
-    data = jnp.asarray(rng.normal(size=(e, f)).astype(np.float32))
-    row_ptr = jnp.asarray(build_row_ptr(ids, n))
-
-    got = ps.fused_segment_sum(
-        data, jnp.asarray(ids), n, mask=jnp.asarray(mask), sorted_ids=True,
-        row_ptr=row_ptr,
-    )
-    want = seg.segment_sum(data, jnp.asarray(ids), n, mask=jnp.asarray(mask))
-    np.testing.assert_allclose(
-        np.asarray(got)[: n - 1], np.asarray(want)[: n - 1],
-        rtol=1e-4, atol=3e-4,
-    )
-    monkeypatch.setenv("HYDRAGNN_PALLAS_CSR", "0")
-    legacy = ps.fused_segment_sum(
-        data, jnp.asarray(ids), n, mask=jnp.asarray(mask), sorted_ids=True,
-        row_ptr=row_ptr,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got)[: n - 1], np.asarray(legacy)[: n - 1],
-        rtol=1e-4, atol=3e-4,
-    )
-    # PNA stats bundle through the CSR kernel (both fused passes).
-    monkeypatch.setenv("HYDRAGNN_PALLAS_CSR", "1")
-    total, mean, std, count = ps.fused_segment_stats(
-        data, jnp.asarray(ids), n, mask=jnp.asarray(mask), sorted_ids=True,
-        row_ptr=row_ptr,
-    )
-    std_ref = seg.segment_std(data, jnp.asarray(ids), n, mask=jnp.asarray(mask))
-    np.testing.assert_allclose(
-        np.asarray(std)[: n - 1], np.asarray(std_ref)[: n - 1],
-        rtol=1e-3, atol=3e-4,
-    )
-    g = jax.grad(
-        lambda d: ps.fused_segment_stats(
-            d, jnp.asarray(ids), n, mask=jnp.asarray(mask), sorted_ids=True,
-            row_ptr=row_ptr,
-        )[2].sum()
-    )(data)
-    assert bool(jnp.all(jnp.isfinite(g)))
-
-
 # ------------------------------------------------------------ layout assertion
 def pytest_debug_layout_hook_fails_loudly_on_unsorted_ids(monkeypatch):
-    """The bugfix satellite: sorted_ids=True on an actually-unsorted layout
+    """An actually-unsorted layout handed to ops/aggregate.py's sorted arm
     must fail loudly under HYDRAGNN_DEBUG_LAYOUT=1 instead of silently
     corrupting aggregation (and must stay silent on a valid layout)."""
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
@@ -595,12 +502,12 @@ def pytest_debug_layout_hook_fails_loudly_on_unsorted_ids(monkeypatch):
     good = jnp.asarray(np.sort(rng.integers(0, 10, 64)).astype(np.int32))
     bad = jnp.asarray(rng.permutation(np.asarray(good)).astype(np.int32))
 
-    out = ps.fused_segment_sum(data, good, 10, sorted_ids=True)
+    out = agg.fused_segment_sum(data, good, 10)
     jax.block_until_ready(out)  # valid layout: no error
 
     with pytest.raises(Exception, match="sorted-layout contract"):
         jax.block_until_ready(
-            ps.fused_segment_sum(data, bad, 10, sorted_ids=True)
+            agg.fused_segment_sum(data, bad, 10)
         )
 
 
@@ -611,7 +518,7 @@ def pytest_debug_layout_hook_off_by_default(monkeypatch):
     data = jnp.asarray(rng.normal(size=(32, 3)).astype(np.float32))
     bad = jnp.asarray(rng.integers(0, 8, 32).astype(np.int32))
     # Off by default: garbage in, garbage out, but NO runtime callback cost.
-    out = ps.fused_segment_sum(data, bad, 8, sorted_ids=True)
+    out = agg.fused_segment_sum(data, bad, 8)
     jax.block_until_ready(out)
 
 

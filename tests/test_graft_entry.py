@@ -15,7 +15,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def pytest_dryrun_multichip_clean_process():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.pop("HYDRAGNN_PALLAS", None)
     out = subprocess.run(
         [
             sys.executable, "-c",

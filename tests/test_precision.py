@@ -15,7 +15,7 @@ Locks the tentpole contracts:
   * precision is a CacheKey component: a bf16/int8 entry never hydrates an
     f32 lookup (and vice versa) in a shared graftcache store;
   * the certification tolerances are THE shared gate (precision/tolerance),
-    consumed by certify_pallas.
+    consumed by ops/certify.py.
 """
 
 import os
@@ -411,20 +411,30 @@ def pytest_cache_key_precision_component_blocks_cross_hits(tmp_path):
 
 # ------------------------------------------------------- shared tolerance gate
 @pytest.mark.mpi_skip
-def pytest_certify_pallas_consumes_the_shared_gate():
-    """Kernel certification and quantized serving share ONE tolerance
-    implementation: certify_pallas's reported pins ARE the gate constants."""
-    from hydragnn_tpu.ops import pallas_segment as ps
+def pytest_certify_aggregation_consumes_the_shared_gate(monkeypatch):
+    """The aggregation's certification and quantized serving share ONE
+    tolerance implementation: ops/certify.py's reported forward pin IS the
+    gate constant, its gradient pin the incumbent's error and never under the
+    forward gate, and its verdicts follow from the errors it reports."""
+    from hydragnn_tpu.ops.certify import certify_aggregation
 
     assert KERNEL_CERT_GATE.fwd == 5e-4
-    assert KERNEL_CERT_GATE.grad == 5e-3
-    report = ps.certify_pallas(e=2048, f=24, n=256, reps=1, sorted_arm=False)
+    monkeypatch.delenv("HYDRAGNN_SEGMENT_SORTED", raising=False)
+    with pytest.raises(RuntimeError, match="sorted arm"):
+        certify_aggregation(e=64, f=4, n=8)  # a CPU's default is the XLA ops
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    report = certify_aggregation(e=2048, f=24, n=256)
     assert report["tol"] == KERNEL_CERT_GATE.fwd
-    assert report["tol_grad"] == KERNEL_CERT_GATE.grad
-    assert report["ok"] == KERNEL_CERT_GATE.check(
-        max(report["max_err_fwd"], report["wide_err_fwd"]),
-        max(report["max_err_grad"], report["wide_err_grad"]),
-    )["ok"]
+    assert report["tol_grad"] == max(
+        KERNEL_CERT_GATE.fwd, report["xla"]["err_grad"]
+    )
+    assert set(report["arms"]) == {"sorted", "csr"}
+    for arm in report["arms"].values():
+        assert arm["ok"] == (
+            arm["err_fwd"] < report["tol"] and arm["err_grad"] <= report["tol_grad"]
+        )
+    assert report["extrema_scan"]["bit_equal"]
+    assert report["ok"], report
 
 
 @pytest.mark.mpi_skip

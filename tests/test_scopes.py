@@ -22,7 +22,7 @@ import pytest
 
 from hydragnn_tpu.graphs import GraphSample, collate_graphs
 from hydragnn_tpu.models import create_model, init_model_variables
-from hydragnn_tpu.ops import pallas_segment as ps
+from hydragnn_tpu.ops import aggregate as agg
 from hydragnn_tpu.ops import segment_sorted as srt
 from hydragnn_tpu.telemetry import scopes
 from hydragnn_tpu.train.trainer import (
@@ -149,7 +149,6 @@ def pytest_train_step_movers_scoped_and_arm_named(conv, route, monkeypatch):
     env, csr, arm = ROUTES[route]
     for k, v in env.items():
         monkeypatch.setenv(k, v)
-    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
     names = _op_names(_compiled_text(conv, _batch(csr)))
     _check_movers_scoped(names, scopes.TRAIN_STEP)
     used = _used(names)
@@ -158,7 +157,6 @@ def pytest_train_step_movers_scoped_and_arm_named(conv, route, monkeypatch):
             scopes.OPTIMIZER} <= used
     # (b): what the routing chose is what the names say.
     assert srt.sorted_enabled() is (route != "xla")
-    assert not ps.pallas_enabled()
     arms = _arms(names)
     # PNA's extrema: the scan kernel over receiver runs where the sorted arm
     # has the batch's row pointers, XLA's segment_max/min on the other routes.
@@ -174,28 +172,6 @@ def pytest_train_step_movers_scoped_and_arm_named(conv, route, monkeypatch):
     assert set(arms) == expected, (set(arms), expected)
     if conv == "PNA":
         assert scopes.AGG_PNA in used
-
-
-@pytest.mark.parametrize("csr_kernel", ["0", "1"])
-def pytest_pallas_arms_named_and_backward_scoped(csr_kernel, monkeypatch):
-    """The two kernel arms (interpreted here): their ``custom_vjp`` backward
-    gathers sit under the same ``hydragnn.agg.*`` scope, inside
-    ``transpose(``."""
-    monkeypatch.setenv("HYDRAGNN_PALLAS", "1")
-    monkeypatch.setenv("HYDRAGNN_PALLAS_CSR", csr_kernel)
-    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "0")
-    names = _op_names(_compiled_text("PNA", _batch()))
-    arm = "pallas_csr" if csr_kernel == "1" else "pallas"
-    assert ps.csr_kernel_enabled() is (csr_kernel == "1")
-    arms = _arms(names)
-    assert arms["stats"] == {arm} and arms["mean"] == {arm}, arms
-    # HYDRAGNN_PALLAS is the one-hot and run-walk SUM kernels' opt-in: without
-    # the sorted arm the extrema stay XLA's, row pointers or not.
-    assert arms["extrema"] == {"xla"}
-    assert _used(names) <= scopes.VOCABULARY
-    stats = scopes.agg("stats", arm)
-    assert any(stats in n and "transpose(" in n for _, n in names)
-    assert any(stats in n and "transpose(" not in n for _, n in names)
 
 
 def pytest_custom_vjp_backward_carries_the_scope(monkeypatch):
@@ -361,7 +337,6 @@ def pytest_painn_step_geometry_scoped_and_edge_rows_flat(route, monkeypatch):
     env, csr, arm = ROUTES[route]
     for k, v in env.items():
         monkeypatch.setenv(k, v)
-    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
     batch = _painn_batch(csr)
     n_pad, e_pad, f = batch.node_features.shape[0], batch.senders.shape[0], 8
     text = _compiled_text("PAINN", batch, edge_dim=None)
@@ -505,10 +480,10 @@ def pytest_outermost_entry_point_names_the_operation():
     assert names_of(lambda d: seg.segment_std(d, ids, 4)) == {
         scopes.agg("stats", "xla")
     }
-    assert names_of(lambda d: ps.fused_segment_sum(d, ids, 4)) == {
+    assert names_of(lambda d: agg.fused_segment_sum(d, ids, 4)) == {
         scopes.agg("sum", "xla")
     }
-    assert names_of(lambda d: ps.fused_segment_softmax(d[:, 0], ids, 4)) == {
+    assert names_of(lambda d: agg.fused_segment_softmax(d[:, 0], ids, 4)) == {
         scopes.agg("softmax", "xla")
     }
 

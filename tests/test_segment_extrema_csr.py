@@ -1,5 +1,5 @@
 """PNA's min and max over sorted receiver runs in one streamed pass
-(``ops/pallas_segment.py``: ``_extrema_scan_kernel``, interpreted here): the
+(``ops/extrema_scan.py``: ``_extrema_scan_kernel``, interpreted here): the
 outputs are held bit-equal to ``jax.ops.segment_min`` / ``segment_max``, and
 the gradient through ``pna_aggregate`` to the XLA route's, whose backward it
 shares.
@@ -11,30 +11,31 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hydragnn_tpu.ops import pallas_segment as ps
+from hydragnn_tpu.ops import aggregate
+from hydragnn_tpu.ops import extrema_scan as scan
 
 # Rows a run of each segment holds, in segment order. The kernel's block is
-# ps._XB rows and its in-register chunk ps._XC; the names say what each
+# scan._XB rows and its in-register chunk scan._XC; the names say what each
 # layout puts across those.
 LAYOUTS = {
     # E = 14: one partial block; empty segments first, in the middle, last.
     "empties_and_single_rows": [0, 0, 3, 1, 0, 1, 1, 6, 0, 2, 0, 0],
     # E = 2 * _XB exactly; a run of 40 rows over the boundary between blocks.
     "crosses_one_block_boundary": (
-        [7] * 70 + [ps._XB - 490 + 18] + [9] * 54 + [ps._XB - 18 - 486]
+        [7] * 70 + [scan._XB - 490 + 18] + [9] * 54 + [scan._XB - 18 - 486]
     ),
     # The padding node's: real runs, empty nodes, then one run over the rest
     # of block 0, all of blocks 1 and 2 and part of 3. E not a multiple.
     "padding_run_spans_three_blocks": (
-        [5, 1, 12, 1, 1, 30] * 6 + [0] * 9 + [3 * ps._XB + 77 - 300]
+        [5, 1, 12, 1, 1, 30] * 6 + [0] * 9 + [3 * scan._XB + 77 - 300]
     ),
     # Every run one chunk long and aligned to it, then single rows.
-    "chunk_aligned_runs": [ps._XC] * 20 + [1] * 37 + [0, 2 * ps._XC + 1],
+    "chunk_aligned_runs": [scan._XC] * 20 + [1] * 37 + [0, 2 * scan._XC + 1],
     # An empty edge set: every segment comes back 0, as from segment_min/max.
     "no_edges": [0, 0, 0],
 }
-assert sum(LAYOUTS["crosses_one_block_boundary"]) == 2 * ps._XB
-assert sum(LAYOUTS["crosses_one_block_boundary"][:70]) < ps._XB < sum(
+assert sum(LAYOUTS["crosses_one_block_boundary"]) == 2 * scan._XB
+assert sum(LAYOUTS["crosses_one_block_boundary"][:70]) < scan._XB < sum(
     LAYOUTS["crosses_one_block_boundary"][:71]
 )
 
@@ -63,9 +64,9 @@ CASES = [(layout, f, "normal") for layout in LAYOUTS for f in (1, 6, 256)] + [
 def pytest_csr_extrema_bit_equal_to_segment_min_max(layout, f, values):
     data, ids, row_ptr, counts = _problem(layout, f, values)
     n = len(counts)
-    assert data.shape[0] % ps._XB or layout in ("crosses_one_block_boundary", "no_edges")
+    assert data.shape[0] % scan._XB or layout in ("crosses_one_block_boundary", "no_edges")
     mn, mx = jax.jit(
-        lambda d: ps.segment_extrema(d, ids, n, None, row_ptr)
+        lambda d: aggregate.segment_extrema(d, ids, n, None, row_ptr)
     )(data)
     assert mn.dtype == data.dtype and mx.dtype == data.dtype
     filled = (counts > 0)[:, None]
@@ -77,7 +78,7 @@ def pytest_csr_extrema_bit_equal_to_segment_min_max(layout, f, values):
     if values == "negative":
         assert (np.asarray(mx)[counts > 0] < 0).all()  # no 0 fill leaked in
     # The XLA arm on the same rows (masked ids are its own convention).
-    xla_mn, xla_mx = ps.segment_extrema(data, ids, n)
+    xla_mn, xla_mx = aggregate.segment_extrema(data, ids, n)
     assert np.array_equal(np.asarray(mn), np.asarray(xla_mn))
     assert np.array_equal(np.asarray(mx), np.asarray(xla_mx))
 
@@ -95,7 +96,6 @@ def pytest_gradient_on_the_kernel_route_equals_the_xla_routes(
     segment is the padding node: its run holds the masked edges and nothing
     reads its output, as in a collated batch."""
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
     data, ids, row_ptr, counts = _problem("padding_run_spans_three_blocks", f, "normal")
     n = len(counts)
     mask = ids < n - 1
@@ -105,8 +105,8 @@ def pytest_gradient_on_the_kernel_route_equals_the_xla_routes(
     )
 
     def loss(d, ptr):
-        agg, _ = ps.pna_aggregate(
-            d, ids, n, aggregators, mask=mask, sorted_ids=True, row_ptr=ptr
+        agg, _ = aggregate.pna_aggregate(
+            d, ids, n, aggregators, mask=mask, row_ptr=ptr
         )
         return jnp.sum(jnp.where(real[:, None, None], agg * weights, 0.0))
 

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from hydragnn_tpu.graphs.collate import collate_graphs
-from hydragnn_tpu.ops import pallas_segment as ps
+from hydragnn_tpu.ops import aggregate as agg
 from hydragnn_tpu.ops import segment as seg
 from hydragnn_tpu.ops.segment_sorted import (
     segment_sum_count_sorted,
@@ -141,9 +141,9 @@ def pytest_sorted_routing_and_conv_equivalence(monkeypatch):
     # Wrapper-level: the node->graph pooling contract (node_graph is sorted
     # by construction) agrees with the masked XLA op.
     x = np.asarray(batch.node_features)
-    m_sorted = ps.fused_segment_mean(
+    m_sorted = agg.fused_segment_mean(
         jnp.asarray(x), batch.node_graph, batch.num_graphs_pad,
-        mask=batch.node_mask, sorted_ids=True,
+        mask=batch.node_mask,
     )
     m_ref = seg.segment_mean(
         jnp.asarray(x), batch.node_graph, batch.num_graphs_pad,
@@ -206,11 +206,11 @@ def pytest_sorted_training_step_converges(monkeypatch):
 
 
 def pytest_sorted_default_follows_execution_platform(monkeypatch):
-    """The sorted path defaults ON exactly for TPU execution (r05 hardware
-    race winner) and OFF elsewhere; HYDRAGNN_SEGMENT_SORTED overrides both
-    ways. The platform comes from ops.segment.execution_platform — the same
-    trace-time pin (trainer's pallas_platform) the Pallas gate uses, so a
-    TPU-attached host tracing a CPU mesh keeps the CPU default."""
+    """The sorted path defaults ON exactly for TPU execution and OFF
+    elsewhere; HYDRAGNN_SEGMENT_SORTED overrides both ways. The platform comes
+    from ops.segment.execution_platform, which the trainer's mesh steps pin
+    (platform_override), so a TPU-attached host tracing a CPU mesh keeps the
+    CPU default."""
     from hydragnn_tpu.ops import segment as seg
     from hydragnn_tpu.ops import segment_sorted as srt
 
@@ -237,22 +237,21 @@ def pytest_sorted_path_under_graph_shard_map(monkeypatch):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from hydragnn_tpu.ops import pallas_segment as ps
+    from hydragnn_tpu.ops import aggregate as agg
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    monkeypatch.setenv("HYDRAGNN_PALLAS", "0")
     rng = np.random.default_rng(11)
     e, n, f = 64, 10, 5
     data = jnp.asarray(rng.normal(size=(e, f)).astype(np.float32))
     ids = jnp.asarray(np.sort(rng.integers(0, n, size=e)).astype(np.int32))
 
-    ref = ps.fused_segment_stats(data, ids, n, sorted_ids=True)
+    ref = agg.fused_segment_stats(data, ids, n)
 
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("graph",))
 
     def local(d_, ids_):
-        total, mean, std, count = ps.fused_segment_stats(
-            d_, ids_, n, axis_name="graph", sorted_ids=True
+        total, mean, std, count = agg.fused_segment_stats(
+            d_, ids_, n, axis_name="graph"
         )
         return total, mean, std, count
 

@@ -39,7 +39,6 @@ def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch
     from hydragnn_tpu.models.convs import GATv2Conv
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
     n, e, h, f = 16384, 262144, 6, 64
     conv = GATv2Conv(out_dim=f, heads=h)
 
@@ -99,7 +98,6 @@ def pytest_painn_block_at_cell_size_keeps_the_vector_state_flat(one_chip, monkey
     from hydragnn_tpu.models.painn import EdgeGeometry, PaiNNBlock
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
     n, e, f, radial = 16384, 262144, 128, 20
     block = PaiNNBlock(f)
 
@@ -163,11 +161,10 @@ def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monke
     (the one scatter into ``f32[32768,256]`` that stays is the centered
     sum of squares of ``std``)."""
     from hydragnn_tpu.models.convs import PNAConv
-    from hydragnn_tpu.ops import pallas_segment as ps
+    from hydragnn_tpu.ops import segment as seg
     from hydragnn_tpu.telemetry import scopes
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    monkeypatch.delenv("HYDRAGNN_PALLAS", raising=False)
     n, e, f = 32768, 524288, 256
     conv = PNAConv(out_dim=f, deg_avg_log=2.5, deg_avg_lin=14.0, edge_dim=1)
 
@@ -193,7 +190,7 @@ def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monke
 
     # The kernel is interpreted wherever the step's platform is not the TPU:
     # this program is for the described chip.
-    with ps.pallas_platform("tpu"):
+    with seg.platform_override("tpu"):
         text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
             params, shaped((n, f)), shaped((e,), jnp.int32),
             shaped((e,), jnp.int32), shaped((e, 1)), shaped((e,), jnp.bool_),
