@@ -668,7 +668,7 @@ def _child_warm(args) -> dict:
 
 def _child_kernels(args) -> dict:
     """Every aggregation arm the sorted route can take, on this platform (the
-    extrema scan kernel is Mosaic's on the TPU, never the interpreter there),
+    extrema scan kernels are Mosaic's on the TPU, never the interpreter there),
     held to ops/certify.py's gates against an f64 ground truth, forward and
     gradient, next to ops/segment.py's own error on the same data; then the
     bf16 training policy against the f32 run."""
@@ -720,11 +720,16 @@ def _child_kernels(args) -> dict:
                 lambda d, i, p: agg.fused_segment_stats(d, i, n, row_ptr=p)
             ), 0,
         ),
+        # Value and gradient: the forward's scan and the backward's.
         "extrema_scan": (
             "the sorted arm, row_ptr, no edge-sharded axis",
             rep["extrema_scan"],
-            mosaic_calls(lambda d, i, p: agg.segment_extrema(d, i, n, None, p)),
-            1,
+            mosaic_calls(jax.value_and_grad(
+                lambda d, i, p: sum(
+                    jnp.sum(o) for o in agg.segment_extrema(d, i, n, None, p)
+                )
+            )),
+            2,
         ),
     }
     for name, (selected_by, verdict, mosaic, want_mosaic) in arms.items():

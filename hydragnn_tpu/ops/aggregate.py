@@ -33,8 +33,10 @@ Ids in any other order go to ``ops/segment.py``.
 ``std`` is computed from CENTERED values in a second pass,
 ``var = mean((x - mean[ids])^2)``: the uncentered ``E[x^2] - E[x]^2`` cancels
 catastrophically in float32 on near-degenerate segments, in value and in
-gradient (``tests/test_aggregate.py`` holds both against float64). Every
-backward here is gathers and no scatter.
+gradient (``tests/test_aggregate.py`` holds both against float64). No
+backward here scatters: the sums' and the stats' are gathers through the ids;
+the extrema's is gathers on the ``xla`` arm and, on ``pallas_csr``, a second
+streamed pass down the sorted rows that gathers nothing either.
 """
 
 from __future__ import annotations
@@ -48,7 +50,7 @@ import jax.numpy as jnp
 from ..telemetry import scopes
 from . import segment as seg
 from . import segment_sorted as srt
-from .extrema_scan import _extrema_csr
+from .extrema_scan import _extrema_csr, _extrema_csr_bwd
 
 
 def localize_row_ptr(row_ptr, axis_name, num_local_edges: int):
@@ -350,16 +352,18 @@ def _extrema_arm(row_ptr) -> str:
 def segment_extrema(
     data, ids, num_segments: int, axis_name: Optional[str] = None, row_ptr=None
 ):
-    """(min, max) per segment with a gather-based backward: the cotangent flows
-    to every row equal to its segment's extremum (the standard subgradient),
-    avoiding XLA's scatter-heavy segment_min/max VJP on TPU. Empty segments
-    yield 0.
+    """(min, max) per segment with a scatter-free backward: the cotangent flows
+    to every row equal to its segment's extremum (the standard subgradient;
+    a row equal to both gets both), avoiding XLA's scatter-heavy
+    segment_min/max VJP on TPU. Empty segments yield 0.
 
     With ``row_ptr`` (the CSR batch contract: ``ids`` RAW and non-decreasing,
     masked rows in the padding segments' runs, whose outputs nobody reads, and
-    no ``axis_name``) the forward is :func:`extrema_scan._extrema_csr`, one
-    streamed pass and no scatter. Without it ``ids`` < 0 marks masked rows and
-    the forward is XLA's two scatters. The backward is the same either way."""
+    no ``axis_name``) the forward is :func:`extrema_scan._extrema_csr` and the
+    backward :func:`extrema_scan._extrema_csr_bwd`: one streamed pass over the
+    rows each, no scatter and no ``[E, F]`` gather. Without it ``ids`` < 0
+    marks masked rows, the forward is XLA's two scatters and the backward four
+    row gathers through the ids. The two backwards are equal to the bit."""
     # This IS the custom_vjp, so the scope is opened inside it and again in
     # its backward: JAX traces both when it pleases, under the caller's name
     # stack, and a scope round the call alone would be written twice wherever
@@ -390,11 +394,19 @@ def _extrema_bwd(num_segments, axis_name, res, cots):
         if axis_name is not None:
             d_mn = jax.lax.psum(d_mn, axis_name)
             d_mx = jax.lax.psum(d_mx, axis_name)
-        valid = (ids >= 0)[:, None]
-        idx = jnp.clip(ids, 0, num_segments - 1)
-        d_data = jnp.where(valid & (data == mn[idx]), d_mn[idx], 0.0) + jnp.where(
-            valid & (data == mx[idx]), d_mx[idx], 0.0
-        )
+        if row_ptr is not None:
+            # The forward's route: the four node rows are constant along a
+            # run, so one streamed pass carries them and no row is gathered.
+            d_data = _extrema_csr_bwd(
+                data, ids, row_ptr, mn, mx, d_mn, d_mx,
+                seg.execution_platform() != "tpu",
+            )
+        else:
+            valid = (ids >= 0)[:, None]
+            idx = jnp.clip(ids, 0, num_segments - 1)
+            d_data = jnp.where(valid & (data == mn[idx]), d_mn[idx], 0.0) + jnp.where(
+                valid & (data == mx[idx]), d_mx[idx], 0.0
+            )
         return (
             d_data.astype(data.dtype),
             jnp.zeros(ids.shape, jax.dtypes.float0),

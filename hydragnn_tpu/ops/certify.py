@@ -11,9 +11,10 @@ benchmark's to state: graftbench, PERF_LEDGER.jsonl).
   gate. The arms' ``std`` gradient inherits a ``1/std^2`` amplification of the
   sums' ~1e-5 noise at near-degenerate segments (~5e-3), where XLA's
   uncentered ``E[x^2] - E[x]^2`` carries ~1e-1.
-* the extrema scan kernel (``segment_extrema`` with ``row_ptr``): bit-equal to
-  ``jax.ops.segment_min`` / ``segment_max`` on every non-empty run, 0 on the
-  empty ones.
+* the extrema scan kernels (``segment_extrema`` with ``row_ptr``): the
+  forward bit-equal to ``jax.ops.segment_min`` / ``segment_max`` on every
+  non-empty run, 0 on the empty ones; the gradient bit-equal to the XLA
+  route's (``segment_extrema`` without ``row_ptr``: four row gathers).
 
 Run by ``chip_smoke.py``'s kernels stage on the chip (where the scan kernel is
 Mosaic's, not the interpreter's) and by the tier-1 tests under a CPU.
@@ -65,7 +66,7 @@ def _truth(data, ids, n):
 def certify_aggregation(
     e: int = 16384, f: int = 64, n: int = 4096, seed: int = 0
 ) -> dict:
-    """Hold the ``sorted`` and ``csr`` arms and the extrema scan kernel to
+    """Hold the ``sorted`` and ``csr`` arms and the extrema scan kernels to
     their gates at ``[e, f]`` messages over ``n`` segments (sorted ids, no
     mask: the batch contract puts masked rows in padding segments nobody
     reads). Returns the errors, the gates and ``ok`` for each and overall."""
@@ -116,6 +117,17 @@ def certify_aggregation(
         np.array_equal(np.asarray(mn), want_mn)
         and np.array_equal(np.asarray(mx), want_mx)
     )
+
+    weights = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, n, f))
+
+    def extrema_grad(ptr):
+        def fn(d):
+            lo, hi = agg.segment_extrema(d, ids, n, None, ptr)
+            return jnp.sum(lo * weights[0] + hi * weights[1])
+
+        return np.asarray(jax.jit(jax.grad(fn))(data))
+
+    grad_bit_equal = bool(np.array_equal(extrema_grad(row_ptr), extrema_grad(None)))
     return {
         "backend": seg.execution_platform(),
         "shape": {"e": e, "f": f, "n": n},
@@ -123,6 +135,9 @@ def certify_aggregation(
         "tol_grad": tol_grad,
         "xla": {"err_fwd": xla_fwd, "err_grad": xla_grad},
         "arms": arms,
-        "extrema_scan": {"bit_equal": bit_equal, "ok": bit_equal},
-        "ok": bit_equal and all(a["ok"] for a in arms.values()),
+        "extrema_scan": {
+            "bit_equal": bit_equal, "grad_bit_equal": grad_bit_equal,
+            "ok": bit_equal and grad_bit_equal,
+        },
+        "ok": bit_equal and grad_bit_equal and all(a["ok"] for a in arms.values()),
     }

@@ -71,5 +71,7 @@ def pytest_chip_smoke_rehearsal_passes_end_to_end(tmp_path):
     assert {"device", "train", "serve", "kernels", "warm"} <= set(summary)
     assert summary["train"]["xla_compiles_per_epoch"][1:] == [0, 0]
     assert set(summary["kernels"]["arms"]) == {"sorted", "csr", "extrema_scan"}
+    scan = summary["kernels"]["arms"]["extrema_scan"]
+    assert scan["bit_equal"] and scan["grad_bit_equal"] and scan["ok"]
     assert summary["warm"]["warm"]["persistent_cache_hits"] > 0
     assert summary["warm"]["warm"]["cache_dir"] == str(tmp_path / "jax_cache")

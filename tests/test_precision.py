@@ -434,6 +434,7 @@ def pytest_certify_aggregation_consumes_the_shared_gate(monkeypatch):
             arm["err_fwd"] < report["tol"] and arm["err_grad"] <= report["tol_grad"]
         )
     assert report["extrema_scan"]["bit_equal"]
+    assert report["extrema_scan"]["grad_bit_equal"]
     assert report["ok"], report
 
 

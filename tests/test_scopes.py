@@ -177,23 +177,24 @@ def pytest_train_step_movers_scoped_and_arm_named(conv, route, monkeypatch):
 def pytest_custom_vjp_backward_carries_the_scope(monkeypatch):
     """``segment_sum_count_csr``, ``_stats`` and ``segment_extrema`` trace
     their ``_bwd`` apart from the call site: the backward gathers carry the
-    forward's scope all the same."""
+    forward's scope all the same, and so does the extrema's backward kernel,
+    which gathers nothing (interpreted here: a loop over its grid)."""
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
 
-    def check(names, *held):
+    def check(names, *held, op="gather"):
         for scope in held:
-            bwd = [n for op, n in names if op == "gather" and scope in n
+            bwd = [n for o, n in names if (op is None or o == op) and scope in n
                    and "transpose(" in n]
-            assert bwd, f"no backward gather under {scope}"
+            assert bwd, f"no backward {op or 'operation'} under {scope}"
             # Written once, not once by the call site and again by the function.
             assert all(n.count(scope) == 1 for _, n in names if scope in n)
 
     names = _op_names(_compiled_text("PNA", _batch()))
-    check(names, scopes.agg("stats", "csr"), scopes.agg("extrema", "pallas_csr"),
-          scopes.agg("mean", "csr"))
-    # The kernel's forward (its row fetches here, interpreted) and the
-    # unchanged backward both carry the new arm's name, and the old one is gone.
+    check(names, scopes.agg("stats", "csr"), scopes.agg("mean", "csr"))
+    # The kernels' forward (its row fetches here) and backward both carry the
+    # arm's name, and the other arm is gone.
     kernel = scopes.agg("extrema", "pallas_csr")
+    check(names, kernel, op=None)
     assert any(kernel in n and "transpose(" not in n for _, n in names)
     assert scopes.agg("extrema", "xla") not in _used(names)
     names = _op_names(_compiled_text("PNA", _batch(csr=False)))

@@ -152,21 +152,24 @@ def _combiners(text):
     }
 
 
-def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monkeypatch):
+@pytest.mark.parametrize("f", [256, 1], ids=["hidden_256", "input_layer_1"])
+def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monkeypatch, f):
     """One ``PNAConv``, forward and backward, at the large bucket of the cell
-    ``pna_multihead_h256.train_b512`` (32768 × 524288, hidden 256) on the CSR
-    route the chip takes: min and max come from ONE Mosaic kernel in the
-    forward, under ``hydragnn.agg.extrema.pallas_csr``; the backward holds
-    none; and no scatter that combines by minimum or maximum is left anywhere
-    (the one scatter into ``f32[32768,256]`` that stays is the centered
-    sum of squares of ``std``)."""
+    ``pna_multihead_h256.train_b512`` (32768 × 524288; a hidden layer's 256
+    columns, the input layer's one) on the CSR route the chip takes: min and
+    max come from ONE Mosaic kernel in the forward and their cotangents go
+    down the rows in ONE in the backward, both under
+    ``hydragnn.agg.extrema.pallas_csr``; no ``[E, f]`` row gather is left
+    under that scope; and no scatter that combines by minimum or maximum is
+    left anywhere (the one scatter into ``f32[32768,f]`` that stays is the
+    centered sum of squares of ``std``)."""
     from hydragnn_tpu.models.convs import PNAConv
     from hydragnn_tpu.ops import segment as seg
     from hydragnn_tpu.telemetry import scopes
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    n, e, f = 32768, 524288, 256
-    conv = PNAConv(out_dim=f, deg_avg_log=2.5, deg_avg_lin=14.0, edge_dim=1)
+    n, e = 32768, 524288
+    conv = PNAConv(out_dim=256, deg_avg_log=2.5, deg_avg_lin=14.0, edge_dim=1)
 
     def shaped(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -201,10 +204,15 @@ def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monke
         re.search(r'op_name="([^"]*)"', line).group(1)
         for line in text.splitlines() if "tpu_custom_call" in line
     ]
-    assert len(kernels) == 1, kernels
-    assert scopes.agg("extrema", "pallas_csr") in kernels[0]
-    assert "transpose(" not in kernels[0]
+    scope = scopes.agg("extrema", "pallas_csr")
+    assert len(kernels) == 2 and all(scope in k for k in kernels), kernels
+    assert sorted("transpose(" in k for k in kernels) == [False, True], kernels
     assert scopes.agg("extrema", "xla") not in text
+    gathered = [
+        line for line in text.splitlines()
+        if re.search(rf"= f32\[{e}(,{f})?\]\S* gather\(", line) and scope in line
+    ]
+    assert not gathered, gathered[:2]
     bodies = _combiners(text)
     scatters = [
         (m.group(1), bodies[m.group(2)])
@@ -214,4 +222,5 @@ def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monke
     ]
     assert scatters, "no scatter compiled: nothing was checked"
     assert not [s for s, body in scatters if re.search(r"(min|max)imum\(", body)], scatters
-    assert [s for s, body in scatters if s == f"f32[{n},{f}]" and " add(" in body]
+    kept = f"f32[{n},{f}]" if f > 1 else f"f32[{n}]"  # one column comes out rank 1
+    assert [s for s, body in scatters if s == kept and " add(" in body], scatters
