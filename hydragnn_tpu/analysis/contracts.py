@@ -1341,6 +1341,18 @@ def _check_position_family(arch, errors):
     mt = arch.get("model_type")
     if mt not in POSITION_FAMILIES:
         return
+    if mt == "LFM2":
+        # Positions are the nodes' places in their sequences: no cutoff, no
+        # basis, no cell. What the stack needs instead (models/lfm2.py):
+        from ..models.lfm2 import LFM2Config
+
+        # token_minmax is completion's to add (the dataset's table).
+        missing = [k for k in LFM2Config.missing(arch) if k != "token_minmax"]
+        if missing:
+            errors.append(
+                ("bad-arch", f"model_type=LFM2 needs Architecture.{'/'.join(missing)}")
+            )
+        return
     radius, num_radial = arch.get("radius"), arch.get("num_radial")
     if not isinstance(radius, (int, float)) or radius <= 0:
         errors.append(
@@ -1438,6 +1450,28 @@ def _check_shapes(config, arch, voi, training, mode, completed, errors, skipped)
         num_nodes=num_nodes,
     )
     arch2.setdefault("freeze_conv_layers", False)
+    # What completion reads from the dataset's table (utils/config_utils.py
+    # _stage_classification_heads); shapes do not depend on the values.
+    target_dim = list(arch2.get("target_dim") or output_dim)
+    kinds = list(voi.get("loss") or [])
+    if not completed and "cross_entropy" in kinds:
+        classes = voi.get("num_classes") or []
+        if len(kinds) != len(output_dim) or len(classes) != len(output_dim):
+            errors.append(
+                ("bad-arch", "Variables_of_interest.loss and .num_classes "
+                 "name one entry a head")
+            )
+            return None
+        output_dim = [
+            int(c) if k == "cross_entropy" else d
+            for k, c, d in zip(kinds, classes, output_dim)
+        ]
+        arch2.update(
+            output_dim=output_dim, head_loss=kinds,
+            class_minmax=[[0.0, 1.0] if k == "cross_entropy" else None for k in kinds],
+        )
+    if arch2.get("model_type") == "LFM2":
+        arch2.setdefault("token_minmax", [0.0, 1.0])
     if arch2.get("model_type") == "PNA" and not arch2.get("pna_deg"):
         mn = arch2.get("max_neighbours")
         if mn is None:
@@ -1462,7 +1496,7 @@ def _check_shapes(config, arch, voi, training, mode, completed, errors, skipped)
         return None
 
     example = make_example_batch(
-        input_dim, output_dim, output_type, edge_dim=edge_dim,
+        input_dim, target_dim, output_type, edge_dim=edge_dim,
         num_nodes=num_nodes, with_positions=model.needs_positions,
     )
     # CSR batch contract (graphs/csr.py): the example batch carries the same

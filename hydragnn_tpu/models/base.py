@@ -1,6 +1,6 @@
 """HydraGNN multi-headed GNN — the flax re-design of the reference architecture
 core (/root/reference/hydragnn/models/Base.py:20-372 plus the per-conv Stack
-subclasses). One module covers all seven families; the conv flavor is a static
+subclasses). One module covers all eight families; the conv flavor is a static
 field, so each (conv_type, dims) combination compiles to one XLA program.
 
 Architecture (mirrors reference semantics under padding):
@@ -9,7 +9,10 @@ Architecture (mirrors reference semantics under padding):
              array. PaiNN: num_conv_layers × [message → update] over a scalar
              and a vector state, the edge geometry computed once from
              ``batch.positions``; no norm, no ReLU (models/painn.py). The
-             read-out and the heads read the scalar state.
+             read-out and the heads read the scalar state. LFM2: a token
+             embedding, num_conv_layers × [operator → feed-forward] with
+             RMSNorm and a residual round each, a final RMSNorm
+             (models/lfm2.py): no edge list is read.
   readout:   masked segment-mean over nodes per graph (global_mean_pool analog)
   heads:     graph heads = shared MLP ("graph_shared") + per-head MLP;
              node heads = shared MLPNode ('mlp' / 'mlp_per_node') or a conv chain
@@ -30,12 +33,12 @@ from ..ops import aggregate
 from ..ops import segment as seg
 from ..telemetry import scopes
 from .layers import MLP, MaskedBatchNorm
-from . import painn
+from . import lfm2 as lfm2_model, painn
 from .convs import (
     POSITION_FAMILIES, CGConv, GATv2Conv, GINConv, MFCConv, PNAConv, SAGEConv,
 )
 
-CONV_TYPES = ("PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE", "PAINN")
+CONV_TYPES = ("PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE", "PAINN", "LFM2")
 
 
 class MLPNode(nn.Module):
@@ -120,6 +123,21 @@ class HydraGNN(nn.Module):
     # functions (Architecture.num_radial).
     radius: Optional[float] = None
     num_radial: Optional[int] = None
+    # LFM2: the stack's sizes, keyed as the source names them (models/lfm2.py).
+    lfm2: Optional[lfm2_model.LFM2Config] = None
+    # Loss kind a head ("rmse" | "cross_entropy"; () = rmse throughout) and,
+    # for a cross-entropy head, the dataset's (min, max) of its target column,
+    # from which the class ids are un-scaled (models/loss.py).
+    head_loss: Tuple[str, ...] = ()
+    class_minmax: Tuple[Any, ...] = ()
+
+    @property
+    def counts_routing(self) -> bool:
+        """Whether a step sows counters (the routed experts' loads): the train
+        step then asks for the collection (train/trainer.py)."""
+        return self.conv_type == "LFM2" and any(
+            self.lfm2.routed(i) for i in range(self.num_conv_layers)
+        )
 
     @property
     def use_edge_attr(self) -> bool:
@@ -218,6 +236,18 @@ class HydraGNN(nn.Module):
         self.batch_norms = bns
 
     @nn.nowrap
+    def _setup_lfm2_encoder(self):
+        """LFM2: the token embedding, one block a layer (``conv_<i>``, so that
+        ``freeze_conv_layers`` freezes them as the others), the final norm."""
+        block = nn.remat(lfm2_model.LFM2Block) if self.remat else lfm2_model.LFM2Block
+        self.conv_embed = nn.Embed(self.lfm2.vocab_size, self.hidden_dim)
+        self.convs = [
+            block(self.hidden_dim, self.lfm2, i, name=f"conv_{i}")
+            for i in range(self.num_conv_layers)
+        ]
+        self.conv_norm = lfm2_model.RMSNorm(self.lfm2.norm_eps)
+
+    @nn.nowrap
     def _setup_painn_encoder(self):
         """PaiNN: a Dense of the input features for s⁰ and one block a layer.
         The names keep the ``conv_`` prefix so that ``freeze_conv_layers``
@@ -236,6 +266,8 @@ class HydraGNN(nn.Module):
         h = self.gat_heads
         if self.conv_type == "PAINN":
             self._setup_painn_encoder()
+        elif self.conv_type == "LFM2":
+            self._setup_lfm2_encoder()
         else:
             self._setup_conv_encoder()
 
@@ -248,9 +280,10 @@ class HydraGNN(nn.Module):
         # override GATStack.py:48-86; CGCNN forbids 'conv' CGCNNStack.py:53-75) ---
         nch, ncb, nco, ncob = [], [], [], []
         if node_head_idx and self.node_nn_type == "conv":
-            if self.conv_type in ("CGCNN", "PAINN"):
+            if self.conv_type in ("CGCNN", "PAINN", "LFM2"):
                 # CGCNN preserves channels; a PaiNN block has two states and
-                # no width to narrow to a head's output.
+                # an LFM2 block a residual stream: no width to narrow to a
+                # head's output.
                 raise ValueError(
                     f'"conv" node decoder is not supported for {self.conv_type}; '
                     'use "mlp" or "mlp_per_node"'
@@ -389,9 +422,29 @@ class HydraGNN(nn.Module):
         # Padding rows at zero, as the batch norms leave them for the others.
         return jnp.where(batch.node_mask[:, None], s, 0.0)
 
+    @nn.nowrap
+    def _encode_lfm2(self, batch: GraphBatch):
+        if batch.positions is None:
+            raise ValueError(
+                "LFM2 reads each node's place in its sequence from "
+                "GraphBatch.positions[:, 0]: collate with with_positions=True "
+                "(config completion and the serving engine do, from the "
+                "model family)"
+            )
+        # senders, receivers, row_ptr and the edge mask are not read: both
+        # token mixers work from node_graph and the node order (models/lfm2.py).
+        h = self.conv_embed(lfm2_model.token_ids(batch.node_features[:, 0], self.lfm2))
+        place = batch.positions[:, 0]
+        for block in self.convs:
+            h = block(h, batch.node_graph, place, batch.node_mask)
+        # Padding rows at zero, as the batch norms leave them for the others.
+        return jnp.where(batch.node_mask[:, None], self.conv_norm(h), 0.0)
+
     def __call__(self, batch: GraphBatch, train: bool = False):
         if self.conv_type == "PAINN":
             x = self._encode_painn(batch)
+        elif self.conv_type == "LFM2":
+            x = self._encode_lfm2(batch)
         else:
             x = self._encode_convs(batch, train)
 

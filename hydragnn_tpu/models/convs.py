@@ -42,12 +42,13 @@ from ..telemetry import scopes
 # the unsorted scatter path on TPU, which the contract checker now rejects
 # instead (analysis/contracts.py).
 SORTED_PATH_FAMILIES = frozenset(
-    {"SAGE", "GIN", "MFC", "GAT", "CGCNN", "PNA", "PAINN"}
+    # LFM2 reads no edge list (models/lfm2.py): no aggregation to fall back.
+    {"SAGE", "GIN", "MFC", "GAT", "CGCNN", "PNA", "PAINN", "LFM2"}
 )
-# Families that compute their edge geometry inside the step, from
-# ``GraphBatch.positions``; the loaders carry positions for these alone
-# (utils/config_utils.py, serve/engine.py).
-POSITION_FAMILIES = frozenset({"PAINN"})
+# Families that read ``GraphBatch.positions`` inside the step (PaiNN its edge
+# geometry, LFM2 each node's place in its sequence); the loaders carry
+# positions for these alone (utils/config_utils.py, serve/engine.py).
+POSITION_FAMILIES = frozenset({"PAINN", "LFM2"})
 
 
 class SAGEConv(nn.Module):

@@ -39,6 +39,9 @@ def create_model_config(
         pna_deg=config.get("pna_deg"),
         radius=config.get("radius"),
         num_radial=config.get("num_radial"),
+        lfm2=config if config["model_type"] == "LFM2" else None,
+        head_loss=config.get("head_loss") or (),
+        class_minmax=config.get("class_minmax") or (),
         compute_dtype=config.get("compute_dtype"),
         remat=config.get("remat", False),
         verbosity=verbosity,
@@ -62,10 +65,18 @@ def create_model(
     pna_deg: Optional[Sequence[float]] = None,
     radius: Optional[float] = None,
     num_radial: Optional[int] = None,
+    lfm2: Optional[Dict[str, Any]] = None,
+    head_loss: Sequence[str] = (),
+    class_minmax: Sequence[Any] = (),
     compute_dtype: Optional[str] = None,
     remat: bool = False,
     verbosity: int = 0,
 ) -> HydraGNN:
+    """``lfm2``: for ``model_type`` "LFM2", the ``Architecture`` block's keys
+    that size the stack, named as the source names them (models/lfm2.py
+    ``LFM2Config``). ``head_loss``: "rmse" or "cross_entropy" a head (empty:
+    rmse throughout); ``class_minmax``: for a cross-entropy head the (min,
+    max) of its target column in the dataset's table, None for the others."""
     if len(task_weights) != len(output_dim):
         raise ValueError(
             f"Inconsistent number of loss weights and tasks: {len(task_weights)} "
@@ -92,6 +103,38 @@ def create_model(
                 "number of radial basis functions) in Architecture."
             )
         kwargs.update(radius=float(radius), num_radial=int(num_radial))
+    elif model_type == "LFM2":
+        from .lfm2 import LFM2Config
+
+        if lfm2 is None:
+            raise ValueError(
+                "LFM2 requires the stack's sizes (create_model(lfm2=the "
+                "Architecture block))"
+            )
+        if compute_dtype:
+            raise ValueError(
+                "LFM2 reads token ids from a float32 node column; "
+                "compute_dtype would round it"
+            )
+        kwargs.update(lfm2=LFM2Config.from_arch(lfm2, int(num_conv_layers)))
+    loss_kinds = tuple(head_loss) or ("rmse",) * len(output_dim)
+    unknown = set(loss_kinds) - {"rmse", "cross_entropy"}
+    if unknown or len(loss_kinds) != len(output_dim):
+        raise ValueError(f"head_loss {tuple(head_loss)!r}: one of 'rmse', "
+                         "'cross_entropy' a head")
+    if "cross_entropy" in loss_kinds:
+        ranges = tuple(
+            None if r is None else tuple(float(v) for v in r) for r in class_minmax
+        )
+        if len(ranges) != len(loss_kinds) or any(
+            (k == "cross_entropy") != (r is not None)
+            for k, r in zip(loss_kinds, ranges)
+        ):
+            raise ValueError(
+                "a cross_entropy head needs class_minmax: the (min, max) of "
+                "its target column (config completion reads the dataset's)"
+            )
+        kwargs.update(head_loss=loss_kinds, class_minmax=ranges)
     return HydraGNN(
         conv_type=model_type,
         input_dim=input_dim,

@@ -1,0 +1,490 @@
+"""LFM2-8B-A1B's block (LiquidAI, ``model_type`` ``lfm2_moe``;
+https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json) on this
+system's batch: a token is a node, a sequence is a graph with its nodes in
+order, ``positions[:, 0]`` is the node's place in its graph. Equations and
+this system's departures: PAPERS.md.
+
+Two token mixers, both aggregations over graphs the batch IMPLIES and never
+holds as an edge list:
+
+* the gated short convolution sums over the banded causal graph (node ``i``
+  receives from ``i``, ``i-1``, ``i-2`` of its own graph): two shifted reads
+  of the flat node array masked by "same graph", not a gather;
+* attention is the softmax aggregation over the complete causal graph of
+  each sequence (524,800 edges at 1024 nodes), computed blockwise from
+  ``node_graph`` and the flat node order; no ``[N, N]`` array exists.
+
+The batch's own edges (the loaders' radius graph of a line at radius 2.5 is
+that band: 4 edges a node) are carried by the unchanged loaders and LEFT
+UNREAD here, as are ``row_ptr`` and the edge mask.
+
+The routed feed-forward is a per-node update that is told which experts it
+holds (``num_experts_held`` from ``experts_offset``): it routes over all
+``num_experts``, computes its own experts' part of the result and leaves out
+the rest -- one rank's share of an expert-parallel layer, without the
+exchange (there is no code here that stands in for the absent ranks).
+
+Precision, as the configuration states it: float32 parameters, residual
+stream, norms, softmax, sigmoid; matrix multiplications at the backend's
+default for float32 operands (on the TPU one bf16 pass with float32
+accumulation), the router's ``W_g x`` at ``Precision.HIGHEST``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+import flax.linen as nn
+
+from ..ops.segment import execution_platform
+from ..telemetry import scopes
+from .layers import scaled_ids
+
+# Rows of a query block, and of a key block of the TPU kernel. The flat node
+# array is padded up to a multiple of it inside ``segment_causal_attention``
+# (the loaders' buckets are multiples of 64, not of 512).
+ATTN_BLOCK = 512
+# The collection the routed layers sow into: the experts each node chose and
+# the router's input (read by the benchmark's check, which asks for the
+# collection; a no-op in every program that does not), and the step's
+# counters (asked for by the train step, train/trainer.py).
+INTERMEDIATES = "intermediates"
+COUNTERS = ("moe_rows_held", "moe_load_max", "moe_load_min")
+
+
+@dataclasses.dataclass(frozen=True)
+class LFM2Config:
+    """The stack's static sizes, keyed as the source's ``config.json`` names
+    them, plus this rank's share (``num_experts_held``, ``experts_offset``)
+    and the dataset's table for the token column (``token_minmax``)."""
+
+    layer_types: Tuple[str, ...]
+    num_dense_layers: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_experts: int
+    num_experts_per_tok: int
+    num_experts_held: int
+    experts_offset: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    vocab_size: int
+    token_minmax: Tuple[float, float]
+    conv_L_cache: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+
+    @classmethod
+    def from_arch(cls, arch: dict, num_layers: int) -> "LFM2Config":
+        """From a completed ``Architecture`` block. ``layer_types`` may be the
+        published list whole: the first ``num_layers`` of it are built."""
+        missing = cls.missing(arch)
+        if missing:
+            raise ValueError(
+                f"LFM2 requires Architecture.{'/'.join(missing)} (token_minmax "
+                "comes from config completion: the dataset's table)"
+            )
+        types = tuple(arch["layer_types"][:num_layers])
+        if len(types) != num_layers or set(types) - {"conv", "full_attention"}:
+            raise ValueError(
+                f"LFM2 needs {num_layers} layer_types of 'conv' / "
+                f"'full_attention', got {arch['layer_types']!r}"
+            )
+        held = int(arch.get("num_experts_held", arch["num_experts"]))
+        offset = int(arch.get("experts_offset", 0))
+        if not 0 < held <= held + offset <= int(arch["num_experts"]):
+            raise ValueError(
+                f"experts {offset}..{offset + held} are not among "
+                f"{arch['num_experts']}"
+            )
+        kw = {
+            f.name: arch[f.name] for f in dataclasses.fields(cls) if f.name in arch
+        }
+        kw.update(
+            layer_types=types, num_experts_held=held, experts_offset=offset,
+            token_minmax=tuple(float(v) for v in arch["token_minmax"]),
+        )
+        return cls(**kw)
+
+    @classmethod
+    def missing(cls, arch: dict) -> list:
+        """The keys ``arch`` must have and lacks (the share defaults to all)."""
+        return [
+            f.name for f in dataclasses.fields(cls)
+            if f.default is dataclasses.MISSING and f.name not in arch
+            and f.name not in ("num_experts_held", "experts_offset")
+        ]
+
+    def routed(self, layer: int) -> bool:
+        return layer >= self.num_dense_layers
+
+
+def token_ids(column: jnp.ndarray, cfg: LFM2Config) -> jnp.ndarray:
+    """The token id of each node from its min-max-scaled column, exactly."""
+    return scaled_ids(column, cfg.token_minmax, cfg.vocab_size)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("weight", nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.eps) * w
+
+
+def _same_graph_shift(z, node_graph, by: int):
+    """``z`` moved down ``by`` rows, zero where the row ``by`` above belongs
+    to another graph (or to none: before the first node)."""
+    n = z.shape[0]
+    moved = jnp.concatenate([jnp.zeros((by,) + z.shape[1:], z.dtype), z[: n - by]])
+    above = jnp.concatenate([jnp.full((by,), -1, node_graph.dtype), node_graph[: n - by]])
+    return jnp.where((above == node_graph)[:, None], moved, 0.0)
+
+
+class ShortConv(nn.Module):
+    """``(B, C, u) = split(W_in x)``; ``z = B * u``; the depthwise causal
+    convolution ``c_i = sum_j k_j * z_{i-(L-1-j)}`` inside the node's own
+    graph; ``W_out (C * c)``. No bias anywhere (``conv_bias`` false)."""
+
+    features: int
+    taps: int = 3
+
+    @nn.compact
+    def __call__(self, x, node_graph):
+        d = self.features
+        bcu = nn.Dense(3 * d, use_bias=False, name="in_proj")(x)
+        with jax.named_scope(scopes.LFM2_CONV):
+            k = self.param(
+                "kernel", nn.initializers.variance_scaling(1.0, "fan_in", "uniform"),
+                (self.taps, d),
+            )
+            b, c, u = bcu[:, :d], bcu[:, d : 2 * d], bcu[:, 2 * d :]
+            z = b * u
+            conv = k[self.taps - 1] * z
+            for back in range(1, self.taps):
+                conv = conv + k[self.taps - 1 - back] * _same_graph_shift(z, node_graph, back)
+            y = c * conv
+        return nn.Dense(d, use_bias=False, name="out_proj")(y)
+
+
+def rope(x, place, theta: float):
+    """Rotary embedding over the last axis of ``x`` [N, heads, head_dim] at
+    ``place`` [N] (float), the halves convention of the source's
+    ``rotate_half``."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = place.astype(jnp.float32)[:, None] * inv  # [N, half]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _attention_rows(q, k, v, seg_q, seg_k, first_row: int, scale: float):
+    """One block of query rows against the keys up to its last row: masked
+    softmax in float32. ``q`` [bq, KV, rep, hd]; ``k``, ``v`` [nk, KV, hd]."""
+    s = jnp.einsum("qgrd,kgd->grqk", q, k) * scale
+    rows = first_row + jnp.arange(q.shape[0])
+    keep = (seg_q[:, None] == seg_k[None, :]) & (
+        jnp.arange(k.shape[0])[None, :] <= rows[:, None]
+    )
+    s = jnp.where(keep[None, None], s.astype(jnp.float32), -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("grqk,kgd->qgrd", p.astype(v.dtype), v)
+
+
+def segment_causal_attention(q, k, v, node_graph):
+    """Softmax aggregation over the complete causal graph of each sequence:
+    node ``i`` receives from every node ``j <= i`` of its own graph.
+    ``q`` [N, H, hd]; ``k``, ``v`` [N, KV, hd], each key-value head shared by
+    ``H / KV`` query heads. Nodes of one graph are contiguous and in order
+    (collation), so "earlier in the graph" is "earlier in the flat array":
+    the mask is ``same graph and j <= i`` and nothing is gathered.
+
+    On the TPU the Pallas flash kernel of JAX's own library, which skips the
+    blocks the causal order masks whole; elsewhere a loop over blocks of
+    query rows, each against the keys up to its end, rematerialized in the
+    backward. Either way the largest score array is a block's, never
+    ``[N, N]``. Padding nodes share the padding graph's id and attend among
+    themselves (every row keeps its diagonal, so no softmax is empty)."""
+    n, heads, hd = q.shape
+    kv = k.shape[1]
+    scale = hd ** -0.5
+    pad = -n % ATTN_BLOCK
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+        node_graph = jnp.pad(node_graph, (0, pad), constant_values=-1)
+    total = n + pad
+    if execution_platform() == "tpu":
+        from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+        b = ATTN_BLOCK
+        sizes = fa.BlockSizes(
+            block_q=b, block_k_major=b, block_k=b, block_b=1,
+            block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b, block_q_dkv=b,
+            block_k_major_dq=b, block_k_dq=b, block_q_dq=b,
+        )
+        rep = heads // kv
+        qh = q.transpose(1, 0, 2)[None]
+        kh = jnp.repeat(k.transpose(1, 0, 2), rep, axis=0)[None]
+        vh = jnp.repeat(v.transpose(1, 0, 2), rep, axis=0)[None]
+        seg = node_graph.astype(jnp.int32)[None]
+        out = fa.flash_attention(
+            qh, kh, vh, segment_ids=fa.SegmentIds(q=seg, kv=seg), causal=True,
+            sm_scale=scale, block_sizes=sizes,
+        )
+        return out[0].transpose(1, 0, 2)[:n].reshape(n, heads * hd)
+    q = q.reshape(total, kv, heads // kv, hd)
+    block = jax.checkpoint(_attention_rows, static_argnums=(5, 6))
+    out = []
+    for start in range(0, total, ATTN_BLOCK):
+        end = start + ATTN_BLOCK
+        out.append(block(
+            q[start:end], k[:end], v[:end], node_graph[start:end], node_graph[:end],
+            start, scale,
+        ))
+    return jnp.concatenate(out)[:n].reshape(n, heads * hd)
+
+
+class Attention(nn.Module):
+    """Grouped-query attention with RMSNorm over each head of ``q`` and
+    ``k`` and RoPE on the node's place in its graph."""
+
+    features: int
+    cfg: LFM2Config
+
+    @nn.compact
+    def __call__(self, x, node_graph, place):
+        c = self.cfg
+        n, h, kv, hd = x.shape[0], c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        q = nn.Dense(h * hd, use_bias=False, name="q_proj")(x).reshape(n, h, hd)
+        k = nn.Dense(kv * hd, use_bias=False, name="k_proj")(x).reshape(n, kv, hd)
+        v = nn.Dense(kv * hd, use_bias=False, name="v_proj")(x).reshape(n, kv, hd)
+        with jax.named_scope(scopes.LFM2_ATTN):
+            q = rope(RMSNorm(c.norm_eps, name="q_layernorm")(q), place, c.rope_theta)
+            k = rope(RMSNorm(c.norm_eps, name="k_layernorm")(k), place, c.rope_theta)
+            y = segment_causal_attention(q, k, v, node_graph)
+        return nn.Dense(self.features, use_bias=False, name="out_proj")(y)
+
+
+class DenseFFN(nn.Module):
+    """SwiGLU: ``W2(silu(W1 x) * W3 x)``."""
+
+    features: int
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        a = nn.silu(nn.Dense(self.width, use_bias=False, name="w1")(x))
+        b = nn.Dense(self.width, use_bias=False, name="w3")(x)
+        return nn.Dense(self.features, use_bias=False, name="w2")(a * b)
+
+
+_expert_init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+# (rows, contraction, columns) tiles of the TPU's grouped-matmul kernel; the
+# row tile has to divide the K N rows of a bucket (multiples of 256).
+GMM_TILING = (256, 1024, 1024)
+
+
+@jax.custom_vjp
+def _gmm_tpu(lhs, rhs, sizes):
+    """``lhs[rows of group g] @ rhs[g]`` on the TPU: the grouped-matmul Pallas
+    kernel of JAX's own library (megablox), operands rounded to bf16,
+    float32 accumulation and results -- the stated precision, and what
+    ``ragged_dot`` does there by default. Chosen over ``ragged_dot`` on the
+    chip (PERF.md section 6, PR 31): XLA's own grouped kernel drops the
+    operation's name, so its time could be booked to no scope."""
+    return _gmm_tpu_fwd(lhs, rhs, sizes)[0]
+
+
+def _gmm_tpu_fwd(lhs, rhs, sizes):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    lhs16, rhs16 = lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16)
+    out = gmm(lhs16, rhs16, sizes, jnp.float32, GMM_TILING)
+    return out, (lhs16, rhs16, sizes)
+
+
+def _gmm_tpu_bwd(residuals, ct):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    lhs16, rhs16, sizes = residuals
+    ct16 = ct.astype(jnp.bfloat16)
+    d_lhs = gmm(ct16, rhs16, sizes, jnp.float32, GMM_TILING, transpose_rhs=True)
+    d_rhs = tgmm(lhs16.swapaxes(0, 1), ct16, sizes, jnp.float32, GMM_TILING)
+    return d_lhs, d_rhs, None
+
+
+_gmm_tpu.defvjp(_gmm_tpu_fwd, _gmm_tpu_bwd)
+
+
+def grouped_matmul(lhs, rhs, sizes):
+    """``[rows, k] x [groups, k, n] -> [rows, n]``: rows ``sizes[0]`` first
+    by group 0, the next ``sizes[1]`` by group 1, ...; rows past the last
+    group are NOT multiplied and hold whatever the kernel left there."""
+    if execution_platform() == "tpu":
+        return _gmm_tpu(lhs, rhs, sizes)
+    return jax.lax.ragged_dot(lhs, rhs, sizes)
+
+
+@jax.custom_vjp
+def _permute(rows, forth, back):
+    """``rows[forth]`` for a PERMUTATION ``forth`` whose inverse is ``back``:
+    the backward is the gather ``ct[back]``, not the scatter-add autodiff
+    would write (a scatter pays by the row on the TPU; PERF.md section 6,
+    PR 24)."""
+    return rows[forth]
+
+
+def _permute_fwd(rows, forth, back):
+    return rows[forth], (forth, back)
+
+
+def _permute_bwd(residuals, ct):
+    _, back = residuals
+    return ct[back], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _spread(x, forth, back, k: int):
+    """``repeat(x, k)[forth]`` without the repeat: node ``i``'s row at each of
+    its ``k`` places of the permuted order. Backward: ``ct[back]`` is
+    node-major again, and a node's ``k`` rows are summed."""
+    return x[forth // k]
+
+
+def _spread_fwd(x, forth, back, k):
+    return x[forth // k], back
+
+
+def _spread_bwd(k, back, ct):
+    return ct[back].reshape(-1, k, ct.shape[-1]).sum(axis=1), None, None
+
+
+_spread.defvjp(_spread_fwd, _spread_bwd)
+
+
+class RoutedFFN(nn.Module):
+    """``s = sigmoid(W_g x)`` over all ``num_experts``; the
+    ``num_experts_per_tok`` largest of ``s + b`` are chosen (``b`` the expert
+    bias: a buffer, no gradient); ``w_e = s_e / (sum over the chosen + 1e-6)``
+    times ``routed_scaling_factor``, the sum over ALL chosen, held or not;
+    ``y = sum over the chosen AND held of w_e SwiGLU_e(x)``. Dropless.
+
+    The ``K N`` assignments (node-major: node ``i``'s are rows ``K i ..``)
+    are sorted by expert (stable), the held experts' rows first and, in ONE
+    trailing group that is never multiplied, the assignments to absent
+    experts and those of padding nodes. One grouped matmul a projection over
+    the held experts' rows, then the rows go back to node-major order and
+    each node sums its ``K`` rows by their weights. Both moves are row
+    permutations: gathers forward AND backward. Static shapes: the row
+    arrays are ``[K N, ·]`` whatever the routing."""
+
+    features: int
+    cfg: LFM2Config
+
+    @nn.compact
+    def __call__(self, x, node_mask):
+        c = self.cfg
+        n, d = x.shape
+        experts, k, held, f = (
+            c.num_experts, c.num_experts_per_tok, c.num_experts_held,
+            c.moe_intermediate_size,
+        )
+        gate = self.param("gate", nn.initializers.lecun_normal(), (d, experts))
+        bias = self.param("expert_bias", nn.initializers.zeros, (experts,))
+        w1 = self.param("w1", _expert_init, (held, d, f))
+        w3 = self.param("w3", _expert_init, (held, d, f))
+        w2 = self.param("w2", _expert_init, (held, f, d))
+        self.sow(INTERMEDIATES, "moe_router_in", x)
+        with jax.named_scope(scopes.MOE_ROUTE):
+            s = jax.nn.sigmoid(
+                jnp.dot(x, gate, precision=jax.lax.Precision.HIGHEST)
+            )
+            biased = s + jax.lax.stop_gradient(bias) if c.use_expert_bias else s
+            _, chosen = jax.lax.top_k(biased, k)  # [N, K]
+            # The chosen experts' own scores by a compare against an iota: a
+            # gather of K N scalars costs a row each, forward and backward.
+            picked = chosen[:, :, None] == jnp.arange(experts)[None, None, :]
+            weight = jnp.sum(jnp.where(picked, s[:, None, :], 0.0), axis=-1)
+            if c.norm_topk_prob:
+                weight = weight / (weight.sum(axis=-1, keepdims=True) + 1e-6)
+            weight = weight * c.routed_scaling_factor
+            local = chosen - c.experts_offset
+            here = (local >= 0) & (local < held) & node_mask[:, None]
+            group = jnp.where(here, local, held).reshape(-1)  # [K N]
+            order = jnp.argsort(group, stable=True)  # expert order <- node-major
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(n * k, dtype=order.dtype), unique_indices=True
+            )
+            sizes = (group[:, None] == jnp.arange(held)[None, :]).sum(
+                axis=0, dtype=jnp.int32
+            )
+            held_row = (jnp.arange(n * k) < sizes.sum())[:, None]
+            # Zero outside the held groups, on the way in and (through the
+            # select's transpose) on the way back: what a grouped matmul
+            # leaves in rows of no group is its own business.
+            rows = jnp.where(held_row, _spread(x, order, back, k), 0.0)
+        self.sow(INTERMEDIATES, "moe_chosen", chosen)
+        for name, value in zip(COUNTERS, (sizes.sum(), sizes.max(), sizes.min())):
+            self.sow(INTERMEDIATES, name, value.astype(jnp.float32))
+
+        def grouped(lhs, rhs):
+            return jnp.where(held_row, grouped_matmul(lhs, rhs, sizes), 0.0)
+
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            hidden = nn.silu(grouped(rows, w1)) * grouped(rows, w3)
+            out = grouped(hidden, w2)
+        with jax.named_scope(scopes.MOE_ROUTE):
+            out = _permute(out, back, order).reshape(n, k, d)
+            return jnp.sum(out * weight[:, :, None], axis=1)
+
+
+class LFM2Block(nn.Module):
+    """``h += op(RMSNorm(h))``; ``h += ffn(RMSNorm(h))``: the operator a short
+    convolution or attention by ``layer_types``, the feed-forward dense for
+    the ``num_dense_layers`` leading layers and routed after them."""
+
+    features: int
+    cfg: LFM2Config
+    layer: int
+
+    @nn.compact
+    def __call__(self, h, node_graph, place, node_mask):
+        c = self.cfg
+        x = RMSNorm(c.norm_eps, name="operator_norm")(h)
+        if c.layer_types[self.layer] == "conv":
+            h = h + ShortConv(self.features, c.conv_L_cache, name="conv")(x, node_graph)
+        else:
+            h = h + Attention(self.features, c, name="self_attn")(x, node_graph, place)
+        x = RMSNorm(c.norm_eps, name="ffn_norm")(h)
+        if c.routed(self.layer):
+            return h + RoutedFFN(self.features, c, name="feed_forward")(x, node_mask)
+        return h + DenseFFN(self.features, c.intermediate_size, name="feed_forward")(x)
+
+
+def split_intermediates(tree) -> Tuple[dict, dict]:
+    """What the routed layers sowed, as (per-layer dict of the check's
+    arrays keyed ``conv_<i>``, the step's counters summed over the layers)."""
+    per_layer, counters = {}, dict.fromkeys(COUNTERS, 0.0)
+    for module, sub in (tree or {}).items():
+        sown = sub.get("feed_forward", {})
+        if "moe_chosen" in sown:
+            per_layer[module] = {
+                "chosen": sown["moe_chosen"][-1], "router_in": sown["moe_router_in"][-1],
+            }
+            for name in COUNTERS:
+                counters[name] = counters[name] + sown[name][-1]
+    return per_layer, counters

@@ -41,6 +41,15 @@ POOL = "hydragnn.pool"  # graph read-out
 # basis, the cutoff, and each block's filter Dense over them. Its two
 # position gathers stay under GATHER (the innermost name wins).
 GEOM = "hydragnn.geom"
+# LFM2's two token mixers (models/lfm2.py): the gated short convolution's
+# shifted reads and products, and attention's head norms, RoPE and blockwise
+# softmax. Their projections stay with the module (Dense).
+LFM2_CONV = "hydragnn.lfm2.conv"
+LFM2_ATTN = "hydragnn.lfm2.attn"
+# The routed experts: router, top-k, the sort by expert, both row
+# permutations and the weighting; and the grouped matmuls alone.
+MOE_ROUTE = "hydragnn.moe.route"
+MOE_EXPERTS = "hydragnn.moe.experts"
 LOSS = "hydragnn.loss"
 OPTIMIZER = "hydragnn.optimizer"  # update, apply, loss-scale and guard selects
 GRAD_SYNC = "hydragnn.grad_sync"  # the mesh step's psums of gradients/counts
@@ -62,7 +71,8 @@ def agg(what: str, arm: str) -> str:
 
 VOCABULARY = frozenset(
     ROOTS
-    + (GATHER, POOL, GEOM, LOSS, OPTIMIZER, GRAD_SYNC, AGG_PNA)
+    + (GATHER, POOL, GEOM, LFM2_CONV, LFM2_ATTN, MOE_ROUTE, MOE_EXPERTS)
+    + (LOSS, OPTIMIZER, GRAD_SYNC, AGG_PNA)
     + tuple(agg(w, a) for w in AGG_WHATS for a in AGG_ARMS)
 )
 

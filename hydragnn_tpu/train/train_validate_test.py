@@ -21,6 +21,7 @@ import numpy as np
 from ..analysis.sentinel import compile_count
 from ..graphs.batch import GraphBatch
 from ..models.base import HydraGNN
+from ..models.lfm2 import COUNTERS
 from ..utils.optimizer import ReduceLROnPlateau, get_learning_rate, set_learning_rate
 from ..telemetry import graftel as telemetry
 from ..utils.print_utils import iterate_tqdm, print_distributed
@@ -115,12 +116,20 @@ class EpochMetrics:
         self.loss = 0.0
         self.rmses = None
         self.count = 0.0
+        # What the routed layers of a step counted (models/lfm2.py
+        # ``COUNTERS``), summed over the epoch; empty for every other model.
+        self.counters = {}
 
     def update(self, metrics):
         self.loss += float(metrics["loss"])
         r = np.asarray(metrics["rmses"])
         self.rmses = r if self.rmses is None else self.rmses + r
         self.count += float(metrics["count"])
+        for name in COUNTERS:
+            if name in metrics:
+                self.counters[name] = self.counters.get(name, 0.0) + float(
+                    metrics[name]
+                )
 
     def averages(self):
         c = max(self.count, 1.0)
@@ -641,7 +650,14 @@ class TrainingDriver:
                         profiler.step()
             finally:
                 self._drain_feed(batches, "train")
-            return metrics.averages()
+            return self._train_epoch_done(metrics)
+
+    def _train_epoch_done(self, metrics: "EpochMetrics"):
+        """The epoch's averages; its counters are published as gauges,
+        ``train/<counter>_per_epoch`` (none where the model counts none)."""
+        for name, value in metrics.counters.items():
+            telemetry.gauge(f"train/{name}_per_epoch", value)
+        return metrics.averages()
 
     def _train_epoch_scan(self, loader, ctx=None):
         """Whole-epoch lax.scan in fixed-size chunks, buffered per batch shape
@@ -716,7 +732,7 @@ class TrainingDriver:
                     self._after_update(m)
             cached["warm"] = True
             self._credit_timers("train")
-            return metrics.averages()
+            return self._train_epoch_done(metrics)
 
         cacheable = (
             getattr(loader, "reshuffle", None) == "batch"
@@ -758,7 +774,7 @@ class TrainingDriver:
                 "generation": gen,
                 "chunks": sink["items"] if sink is not None else None,
             }
-        return metrics.averages()
+        return self._train_epoch_done(metrics)
 
     def _host_chunks(self, loader):
         """Stage-1 producer for the scan path: collate (loader.__iter__) and

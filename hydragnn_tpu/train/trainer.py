@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..graphs.batch import GraphBatch
 from ..models.base import HydraGNN
+from ..models.lfm2 import INTERMEDIATES, split_intermediates
 from ..models.loss import multihead_rmse_loss
 from ..ops.segment import platform_override
 from ..telemetry import scopes
@@ -96,22 +97,35 @@ def _apply_model(model: HydraGNN, params, batch_stats, batch, **kwargs):
     return out
 
 
-def _loss_and_metrics(model: HydraGNN, params, batch_stats, batch, dropout_key):
+def _model_loss(model: HydraGNN, outputs, batch):
+    with jax.named_scope(scopes.LOSS):
+        return multihead_rmse_loss(
+            outputs, batch, model.output_type, model.task_weights,
+            head_loss=model.head_loss, class_minmax=model.class_minmax,
+        )
+
+
+def _loss_and_metrics(
+    model: HydraGNN, params, batch_stats, batch, dropout_key, counters=False
+):
+    """``counters``: also ask the model for what its routed layers count a
+    step (``HydraGNN.counts_routing``); the aux then has a third entry, the
+    dict of them (models/lfm2.py ``COUNTERS``)."""
     outputs, mut = _apply_model(
         model,
         params,
         batch_stats,
         batch,
         train=True,
-        mutable=["batch_stats"],
+        mutable=["batch_stats"] + ([INTERMEDIATES] if counters else []),
         rngs={"dropout": dropout_key},
     )
-    with jax.named_scope(scopes.LOSS):
-        loss, rmses = multihead_rmse_loss(
-            outputs, batch, model.output_type, model.task_weights
-        )
+    loss, rmses = _model_loss(model, outputs, batch)
     # A family without batch norm (PaiNN) has no such collection.
-    return loss, (mut.get("batch_stats", batch_stats), rmses)
+    aux = (mut.get("batch_stats", batch_stats), rmses)
+    if counters:
+        aux += (split_intermediates(mut.get(INTERMEDIATES))[1],)
+    return loss, aux
 
 
 def state_donation_safe(state: TrainState) -> bool:
@@ -187,10 +201,14 @@ def _step_body(
     def body(state: TrainState, batch: GraphBatch, rng):
         dropout_key = jax.random.fold_in(rng, state.step)
         grad_fn = jax.value_and_grad(
-            lambda p: _loss_and_metrics(model, p, state.batch_stats, batch, dropout_key),
+            lambda p: _loss_and_metrics(
+                model, p, state.batch_stats, batch, dropout_key,
+                counters=model.counts_routing,
+            ),
             has_aux=True,
         )
-        (loss, (new_bstats, rmses)), grads = grad_fn(state.params)
+        # ``counted``: () or (the routed layers' counters of this step,).
+        (loss, (new_bstats, rmses, *counted)), grads = grad_fn(state.params)
         with jax.named_scope(scopes.OPTIMIZER):
             if needs_value_fn:
                 # LBFGS zoom linesearch: update() re-evaluates the loss along the
@@ -232,6 +250,8 @@ def _step_body(
                 }
             else:
                 metrics = {"loss": loss * count, "rmses": rmses * count, "count": count}
+            for extra in counted:  # summed over a scanned epoch like the rest
+                metrics.update(extra)
         new_state = TrainState(
             params=new_params,
             batch_stats=new_bstats,
@@ -345,10 +365,7 @@ def make_eval_step(model: HydraGNN) -> Callable:
             outputs = _apply_model(
                 model, state.params, state.batch_stats, batch, train=False
             )
-            with jax.named_scope(scopes.LOSS):
-                loss, rmses = multihead_rmse_loss(
-                    outputs, batch, model.output_type, model.task_weights
-                )
+            loss, rmses = _model_loss(model, outputs, batch)
             count = batch.count_real_graphs().astype(jnp.float32)
         return (
             {"loss": loss * count, "rmses": rmses * count, "count": count},
@@ -708,10 +725,7 @@ def make_eval_step_dp(model: HydraGNN, mesh) -> Callable:
         outputs = _apply_model(
             model, state.params, state.batch_stats, batch, train=False
         )
-        with jax.named_scope(scopes.LOSS):
-            loss, rmses = multihead_rmse_loss(
-                outputs, batch, model.output_type, model.task_weights
-            )
+        loss, rmses = _model_loss(model, outputs, batch)
         count = batch.count_real_graphs().astype(jnp.float32)
         metrics = {
             "loss": jax.lax.psum(loss * count, "data"),

@@ -21,6 +21,8 @@ import pickle
 from dataclasses import dataclass
 from typing import Any, Dict, List
 
+import numpy as np
+
 from ..preprocess.graph_build import check_if_graph_size_variable
 from .model import calculate_PNA_degree
 
@@ -129,6 +131,47 @@ def _stage_infer_heads(config, ctx):
     arch["num_nodes"] = ctx.sample.num_nodes
 
 
+def _stage_classification_heads(config, ctx):
+    """``Variables_of_interest.loss`` names a loss kind a head ("rmse" where
+    it says nothing). A "cross_entropy" head is as wide as its number of
+    classes (``Variables_of_interest.num_classes``), not as its target, which
+    is ONE column holding the class id: the loaders keep the target's
+    dimension (``target_dim``), the model takes ``output_dim``, and the id is
+    un-scaled in the loss from the dataset's own table (``class_minmax``).
+    ``model_type`` "LFM2" reads its input column the same way
+    (``token_minmax``). Nothing is written for a config without either."""
+    arch = _at(config, ("NeuralNetwork", "Architecture"))
+    voi = _at(config, ("NeuralNetwork", "Variables_of_interest"))
+    kinds = list(voi.get("loss") or [])
+    classify = "cross_entropy" in kinds
+    if not classify and arch["model_type"] != "LFM2":
+        return
+    tables = _minmax_tables(_serialized_dataset_path(config))
+    if arch["model_type"] == "LFM2":
+        arch["token_minmax"] = tables["node"][
+            :, voi["input_node_features"][0]
+        ].tolist()
+    if not classify:
+        return
+    if len(kinds) != len(voi["type"]):
+        raise ValueError("Variables_of_interest.loss names one kind a head")
+    arch["target_dim"] = list(arch["output_dim"])
+    arch["head_loss"] = kinds
+    arch["class_minmax"] = [None] * len(kinds)
+    for i, (kind, head, index) in enumerate(
+        zip(kinds, voi["type"], voi["output_index"])
+    ):
+        if kind != "cross_entropy":
+            continue
+        if arch["output_dim"][i] != 1:
+            raise ValueError(
+                f"head {i}: a cross_entropy head's target is one column "
+                f"holding the class id, not {arch['output_dim'][i]}"
+            )
+        arch["output_dim"][i] = int(voi["num_classes"][i])
+        arch["class_minmax"][i] = tables[head][:, index].tolist()
+
+
 def _stage_denormalize(config, ctx):
     voi = _at(config, ("NeuralNetwork", "Variables_of_interest"))
     if voi.get("denormalize_output"):
@@ -182,7 +225,9 @@ def _stage_push_head_spec(config, ctx):
 
     arch = _at(config, ("NeuralNetwork", "Architecture"))
     for loader in ctx.loaders:
-        loader.set_head_spec(arch["output_type"], arch["output_dim"])
+        loader.set_head_spec(
+            arch["output_type"], arch.get("target_dim", arch["output_dim"])
+        )
         loader.edge_dim = arch["edge_dim"]
         loader.with_positions = arch["model_type"] in POSITION_FAMILIES
 
@@ -190,6 +235,7 @@ def _stage_push_head_spec(config, ctx):
 _PIPELINE = (
     _stage_check_declared_dims,
     _stage_infer_heads,
+    _stage_classification_heads,
     _stage_denormalize,
     _stage_input_dim,
     _stage_pna_degree,
@@ -237,15 +283,13 @@ def _serialized_dataset_path(config) -> str:
     )
 
 
-def update_config_minmax(dataset_path: str, config: Dict[str, Any]):
-    """Fill x_minmax/y_minmax from the per-feature min/max tables pickled
-    ahead of the serialized dataset samples — or, for a GSHD dataset, from
-    the tables the conversion preserved in the manifest."""
+def _minmax_tables(dataset_path: str) -> Dict[str, Any]:
+    """The dataset's min/max tables, ``{"node", "graph"}`` -> [2, columns]:
+    pickled ahead of the serialized samples — or, for a GSHD dataset, what
+    the conversion preserved in the manifest."""
     from ..datasets.shards import is_gshd_path, read_manifest
 
     if is_gshd_path(dataset_path):
-        import numpy as np
-
         manifest = read_manifest(dataset_path)
         node = manifest.get("minmax_node_feature")
         graph = manifest.get("minmax_graph_feature")
@@ -260,6 +304,12 @@ def update_config_minmax(dataset_path: str, config: Dict[str, Any]):
         with open(dataset_path, "rb") as f:
             # graftlint: disable=pickle-load-outside-compat(legacy minmax-table shim for pre-GSHD corpora — the shard manifest branch above is the supported path)
             tables = {"node": pickle.load(f), "graph": pickle.load(f)}
+    return {k: np.asarray(v) for k, v in tables.items()}
+
+
+def update_config_minmax(dataset_path: str, config: Dict[str, Any]):
+    """Fill x_minmax/y_minmax from the dataset's min/max tables."""
+    tables = _minmax_tables(dataset_path)
     config["x_minmax"] = [
         tables["node"][:, i].tolist() for i in config["input_node_features"]
     ]

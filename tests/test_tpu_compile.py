@@ -224,3 +224,87 @@ def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monke
     assert not [s for s, body in scatters if re.search(r"(min|max)imum\(", body)], scatters
     kept = f"f32[{n},{f}]" if f > 1 else f"f32[{n}]"  # one column comes out rank 1
     assert [s for s, body in scatters if s == kept and " add(" in body], scatters
+
+
+def pytest_lfm2_attention_at_cell_size_has_no_n_by_n_array(one_chip):
+    """LFM2's attention core, forward and backward, at the shapes of the cell
+    ``lfm2_8b_a1b_ep4.train_seq1k_b4`` (4 sequences of 1024 tokens in a bucket
+    of 4160 nodes, 32 query and 8 key-value heads of 64) on the route the chip
+    takes: the complete causal graph of a sequence is 524,800 edges and is
+    never materialised -- no array of the lowered program has two axes of the
+    node count (4160, or the 4608 it is padded to for the kernel's blocks),
+    and the scores live inside the flash kernel's calls."""
+    from hydragnn_tpu.models.lfm2 import ATTN_BLOCK, segment_causal_attention
+    from hydragnn_tpu.ops.segment import platform_override
+
+    n, h, kv, hd = 4160, 32, 8, 64
+    padded = n + -n % ATTN_BLOCK
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, node_graph):
+        out = segment_causal_attention(q, k, v, node_graph)
+        return (out * out).sum()
+
+    with platform_override("tpu"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shaped((n, h, hd)), shaped((n, kv, hd)), shaped((n, kv, hd)),
+            shaped((n,), jnp.int32),
+        ).compile().as_text()
+    shapes = set(re.findall(r"\w+\[([\d,]+)\]", text))
+    square = [
+        s for s in shapes
+        if sum(int(d) in (n, padded) for d in s.split(",")) >= 2
+    ]
+    assert not square, f"an array with two node axes: {square}"
+    assert text.count("tpu_custom_call") >= 3  # forward, dq, dkv
+    assert f"[1,{h},{padded},{hd}]" in text  # the kernel's rows, per query head
+
+
+def pytest_lfm2_routed_experts_at_cell_size_are_grouped_matmuls(one_chip):
+    """One ``RoutedFFN`` (8 of 32 experts held, 4 a token, SwiGLU 1792),
+    forward and backward, at the cell's 4160 nodes: the 16640 assignments are
+    multiplied as ragged groups by the grouped-matmul kernel (three
+    projections forward, six calls backward), never as a dense
+    ``[experts, rows, ·]`` array; the row arrays are ``[16640, ·]`` whatever
+    the routing (static shapes); and both row moves are gathers forward and
+    backward: no scatter of 2048-wide rows is compiled."""
+    from hydragnn_tpu.models.lfm2 import LFM2Config, RoutedFFN
+    from hydragnn_tpu.ops.segment import platform_override
+
+    n, d, f, held = 4160, 2048, 1792, 8
+    cfg = LFM2Config(
+        layer_types=("conv",), num_dense_layers=0, intermediate_size=7168,
+        moe_intermediate_size=f, num_experts=32, num_experts_per_tok=4,
+        num_experts_held=held, experts_offset=0, num_attention_heads=32,
+        num_key_value_heads=8, head_dim=64, vocab_size=16384,
+        token_minmax=(0.0, 16383.0),
+    )
+    layer = RoutedFFN(d, cfg)
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((8, d)), jnp.ones((8,), bool))
+        ),
+    )
+
+    def loss(params, x, mask):
+        out = layer.apply(params, x, mask)
+        return (out * out).sum()
+
+    with platform_override("tpu"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, shaped((n, d)), shaped((n,), jnp.bool_)
+        ).compile().as_text()
+    rows = 4 * n
+    assert f"f32[{rows},{d}]" in text and f"f32[{rows},{f}]" in text
+    dense = re.search(rf"f32\[(?:{held}|32),{rows},\d+\]", text)
+    assert not dense, f"the experts' rows as a dense batch: {dense.group(0)}"
+    assert text.count("tpu_custom_call") >= 9
+    wide_scatter = re.search(rf"f32\[\d+,(?:{d}|{f})\]\S* scatter\(", text)
+    assert not wide_scatter, wide_scatter.group(0)
