@@ -711,11 +711,22 @@ def _child_kernels(args) -> dict:
     arms = {
         # name: (selected by, the certifier's verdict, Mosaic kernels expected)
         "sorted": (
-            "the sorted arm, no row_ptr", rep["arms"]["sorted"],
+            "the sorted arm, no row_ptr, rows under WIDE_ROW columns",
+            rep["arms"]["sorted"],
             mosaic_calls(lambda d, i, p: agg.fused_segment_stats(d, i, n)), 0,
         ),
         "csr": (
-            "the sorted arm, the batch's row_ptr", rep["arms"]["csr"],
+            "the sorted arm, the batch's row_ptr, rows under WIDE_ROW columns",
+            rep["arms"]["csr"],
+            mosaic_calls(
+                lambda d, i, p: agg.fused_segment_stats(d, i, n, row_ptr=p)
+            ), 0,
+        ),
+        # One XLA scatter-add told the ids are sorted, over ops/certify.py's
+        # WIDE_CASES (an edge-sharded axis over this chip alone among them).
+        "scatter_sorted": (
+            "the sorted arm, rows of WIDE_ROW columns or more",
+            {k: v for k, v in rep["arms"]["scatter_sorted"].items() if k != "cases"},
             mosaic_calls(
                 lambda d, i, p: agg.fused_segment_stats(d, i, n, row_ptr=p)
             ), 0,

@@ -1,7 +1,7 @@
 """The aggregation every conv family calls: one entry point a reduction, one
 arm a condition the code can observe.
 
-The arm is decided at trace time, here and nowhere else, from three things:
+The arm is decided at trace time, here and nowhere else, from four things:
 
 * the execution platform (``ops/segment.py`` ``execution_platform``): the
   sorted arm is the TPU's, the masked XLA segment ops the CPU's
@@ -11,14 +11,21 @@ The arm is decided at trace time, here and nowhere else, from three things:
   ``graphs/csr.py``);
 * whether an edge-sharded ``axis_name`` is set.
 
-| sorted arm | ``row_ptr`` | sums, means, PNA's stats                  | min / max          |
-|------------|-------------|-------------------------------------------|--------------------|
-| on         | yes         | ``csr``: prefix sums, no search           | ``pallas_csr`` [1] |
-| on         | no          | ``sorted``: prefix sums, two searchsorted | ``xla``            |
-| off        | either      | ``xla``: ``ops/segment.py``               | ``xla``            |
+* a row's width, read off the shape (``segment_sorted.WIDE_ROW``, one lane
+  tile): on the sorted arm a scatter-add costs the same a row at any width and
+  the prefix sums cost by the lane tile, so wide rows take the first and
+  narrow rows the second (PERF.md §6, PR 32).
+
+| sorted arm | ``row_ptr`` | sums, means, PNA's stats: rows under ``WIDE_ROW`` columns | the same, wider rows      | min / max          |
+|------------|-------------|------------------------------------------------------------|---------------------------|--------------------|
+| on         | yes         | ``csr``: prefix sums, no search                            | ``scatter_sorted`` [2]    | ``pallas_csr`` [1] |
+| on         | no          | ``sorted``: prefix sums, two searchsorted                  | ``scatter_sorted`` [2]    | ``xla``            |
+| off        | either      | ``xla``: ``ops/segment.py``                                | ``xla``                   | ``xla``            |
 
 [1] ``ops/extrema_scan.py``; under an ``axis_name`` a run is cut across shards
 and the extrema are ``xla`` too.
+[2] ONE XLA scatter-add over the raw ids with ``indices_are_sorted=True``; the
+count still comes from the boundaries (``row_ptr``, or the two searches).
 
 The names in quotes are the arms of ``telemetry/scopes.py``: every entry point
 opens ``hydragnn.agg.<what>.<arm>``, so a trace says which arm ran.
@@ -33,7 +40,9 @@ Ids in any other order go to ``ops/segment.py``.
 ``std`` is computed from CENTERED values in a second pass,
 ``var = mean((x - mean[ids])^2)``: the uncentered ``E[x^2] - E[x]^2`` cancels
 catastrophically in float32 on near-degenerate segments, in value and in
-gradient (``tests/test_aggregate.py`` holds both against float64). No
+gradient (``tests/test_aggregate.py`` holds both against float64). That
+second pass is a centered scatter-add at every width, told like the wide sums'
+that the ids are sorted. No
 backward here scatters: the sums' and the stats' are gathers through the ids;
 the extrema's is gathers on the ``xla`` arm and, on ``pallas_csr``, a second
 streamed pass down the sorted rows that gathers nothing either.
@@ -42,6 +51,7 @@ streamed pass down the sorted rows that gathers nothing either.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -77,12 +87,19 @@ def localize_row_ptr(row_ptr, axis_name, num_local_edges: int):
     )
 
 
-def _arm(row_ptr) -> str:
+def _arm(row_ptr, width: int) -> str:
     """The ONE resolution of the route, as the scope names it
-    (telemetry/scopes.py AGG_ARMS)."""
+    (telemetry/scopes.py AGG_ARMS). ``width`` is a row's trailing size."""
     if not srt.sorted_enabled():
         return "xla"
+    if srt.wide(width):
+        return "scatter_sorted"
     return "csr" if row_ptr is not None else "sorted"
+
+
+def _width(data) -> int:
+    """Columns of ``data`` as :func:`_flatten_trailing` lays it out."""
+    return math.prod(data.shape[1:])
 
 
 def _shard_row_ptr(row_ptr, axis_name, segment_ids):
@@ -133,7 +150,7 @@ def _fused_sum_count(
 ):
     """:func:`fused_segment_sum_count` under the scope of the entry point that
     was called (``what``: sum, sum_count)."""
-    arm = _arm(row_ptr)
+    arm = _arm(row_ptr, _width(data))
     with scopes.agg_scope(what, arm):
         if arm == "xla":
             return (
@@ -145,7 +162,8 @@ def _fused_sum_count(
                 ),
             )
         # Zero the masked rows and keep the RAW ids: a -1 marker would break
-        # the order the prefix sums need.
+        # the order both routes need (the prefix sums' runs, the scatter-add's
+        # ``indices_are_sorted``).
         srt.attach_layout_check(segment_ids)
         row_ptr = _shard_row_ptr(row_ptr, axis_name, segment_ids)
         flat, unflatten = _flatten_trailing(data)
@@ -167,7 +185,7 @@ def fused_segment_mean(
 ):
     """Masked ``segment_mean`` (SAGE's neighbour mean, the global mean-pool
     read-out). Returns ``data.dtype`` on every arm."""
-    arm = _arm(row_ptr)
+    arm = _arm(row_ptr, _width(data))
     with scopes.agg_scope("mean", arm):
         if arm == "xla":
             return seg.segment_mean(
@@ -195,7 +213,7 @@ def fused_segment_softmax(
     {incoming edges} ∪ {self} and is built inline so that the dense self term
     joins the denominator (models/convs.py). This is the entry point for a
     plain edge-only segment softmax."""
-    arm = _arm(row_ptr)
+    arm = _arm(row_ptr, _width(logits))
     sum_fn = None
     if arm != "xla":
         def sum_fn(d, i, n, mask=None, axis_name=None):
@@ -226,13 +244,15 @@ def _stats_forward(data, ids, num_segments, eps, axis_name, want_std, row_ptr):
     if not want_std:
         return total, mean, jnp.zeros_like(mean), count
     idx = jnp.clip(ids, 0, num_segments - 1)
-    # sumsq via a CENTERED XLA scatter, not the prefix sums: squares are tiny
-    # exactly where 1/std^2 amplifies error (near-degenerate segments), and
-    # prefix-difference noise (~1e-5 abs) there costs ~5e-3 in the std
-    # GRADIENT. The centered scatter has no cancellation (~1e-6 fwd, ~1e-5
-    # grad). It is the one scatter PNA's bundle keeps.
+    # sumsq via a CENTERED XLA scatter-add at every width, never the prefix
+    # sums: squares are tiny exactly where 1/std^2 amplifies error
+    # (near-degenerate segments), and prefix-difference noise (~1e-5 abs)
+    # there costs ~5e-3 in the std GRADIENT. The centered scatter-add has no
+    # cancellation (~1e-6 fwd, ~1e-5 grad). Told, like the wide sums', that
+    # the ids are sorted (they are the same receivers).
     sumsq = jax.ops.segment_sum(
-        jnp.square(data - mean[idx]), ids, num_segments=num_segments
+        jnp.square(data - mean[idx]), ids, num_segments=num_segments,
+        indices_are_sorted=True,
     )
     if axis_name is not None:
         sumsq = jax.lax.psum(sumsq, axis_name)
@@ -322,7 +342,7 @@ def fused_segment_stats(
     sorted arm it is ``ops/segment.py``'s four ops (whose ``std`` is the
     uncentered one)."""
     ids = segment_ids.astype(jnp.int32)
-    arm = _arm(row_ptr)
+    arm = _arm(row_ptr, _width(data))
     with scopes.agg_scope("stats", arm):
         if arm == "xla":
             total = seg.segment_sum(data, ids, num_segments, mask, axis_name)

@@ -1,11 +1,22 @@
-"""Scatter-free segment aggregation for SORTED segment ids.
+"""Segment sums for SORTED segment ids: two routes, chosen by a row's width.
 
 Collation owns edge order (message passing is permutation-invariant over
 edges), so GraphArena sorts each graph's edges by receiver once at arena
 build; batch receivers are then globally non-decreasing (per-graph sorted
-runs + ascending node offsets + padding edges at the top index). That turns
-segment_sum — TPU's worst op as a scatter — into pure prefix sums and
-gathers:
+runs + ascending node offsets + padding edges at the top index).
+
+**Wide rows** (``WIDE_ROW`` columns or more; PR 32): ONE XLA scatter-add told
+``indices_are_sorted=True`` (``_sum_count_scatter``). It adds a run's rows one
+after another onto a zero row, so a segment's error is that of a plain
+sequential float32 sum of its own rows (~1e-6 at the cells' runs of <= 20
+rows), with no cancellation against a prefix. On one TPU v5 lite chip it
+costs 9-12 ns a row whatever the width where the prefix sums below cost 14 ns
+at 128 columns and 55 at 512 (``benchmarks/sorted_sum_routes.py``; PERF.md §6,
+PR 32). ``count`` still comes from the boundaries, exact.
+
+**Narrow rows**: no scatter, pure prefix sums and gathers (a scatter pays by
+the row, and below one lane tile the cumsum is cheaper: 3-7 ns a row at 6 to
+64 columns against the scatter-add's 9.5):
 
     P[k]   = sum(data[:k])                       (compensated prefix, below)
     out[s] = P[right_s] - P[left_s]
@@ -32,13 +43,14 @@ is exactly rounded and its accumulated rounding error lives in err.
 Certified against an f64 ground truth (ops/certify.py).
 
 The TPU's arm (``sorted_enabled``): ``ops/aggregate.py`` routes every conv
-family's sums, means and PNA's stats here when it is on, with the batch's
-``row_ptr`` where the batch carries one (scope arm ``csr``) and the two
-searches where it does not (``sorted``). ``ops/certify.py`` holds both to an
-f64 ground truth, forward and gradient. What the arms cost on the chip is in
-PERF.md §5 and PERF_LEDGER.jsonl (``agg.sum.csr``, ``agg.stats.csr``,
-``agg.mean.csr``); against XLA's scatters inside a train step: not measured
-since the benchmark of PR 22 replaced the harness that had compared them.
+family's sums, means and PNA's stats here when it is on: wide rows under the
+scope arm ``scatter_sorted``, narrow ones under ``csr`` with the batch's
+``row_ptr`` where the batch carries one and under ``sorted`` with the two
+searches where it does not. ``ops/certify.py`` holds all three to an f64
+ground truth, forward and gradient. What the arms cost on the chip, alone and
+inside a train step, is in PERF.md §5-6 and PERF_LEDGER.jsonl
+(``agg.sum.scatter_sorted``, ``agg.stats.scatter_sorted``, ``agg.sum.csr``,
+``agg.mean.csr``).
 """
 
 from __future__ import annotations
@@ -49,6 +61,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 # Trace-time spy: number of searchsorted boundary derivations traced by this
 # module. The CSR batch contract (graphs/csr.py) exists to drive this to ZERO
@@ -102,8 +115,11 @@ def sorted_enabled() -> bool:
     CPU's cheap one, and the exact-gate reference-parity tests pin the XLA
     ops on the CPU. ``HYDRAGNN_SEGMENT_SORTED=1/0`` overrides either way: it
     is how the tests put the chip's arm under a CPU, and the only variable
-    that selects an aggregation arm. Speed of one arm against the other on
-    the chip: not measured (PERF.md §7)."""
+    that selects an aggregation arm. On the chip the sorted arm's wide sums
+    ARE XLA's scatter-add, with the flag that the ids are sorted (3.3 against
+    5.9 ms unflagged and 14.5 for the prefix sums at ``[262144, 512]``), and
+    its narrow sums beat it (0.8 against 2.5 ms at 6 columns): PERF.md §6,
+    PR 32."""
     env = os.environ.get("HYDRAGNN_SEGMENT_SORTED")
     if env is not None:
         return env not in ("0", "false", "False")
@@ -153,15 +169,42 @@ def _prefix_open(data32: jnp.ndarray):
     return local.reshape(e_pad, f), hi, err, chunk
 
 
-def _sum_count_sorted(data, ids, num_segments: int, row_ptr=None):
-    data32 = data.astype(jnp.float32)
-    if data32.shape[0] == 0:
-        # Drop-in parity with segment_sum on an empty edge set: exact zeros
-        # (jnp.mean over the empty axis would otherwise inject NaN via mu).
-        return (
-            jnp.zeros((num_segments, data32.shape[1]), jnp.float32),
-            jnp.zeros((num_segments,), jnp.float32),
-        )
+# W: rows at least this wide are summed by ONE XLA scatter-add told the ids
+# are sorted, narrower ones by the prefix sums. One lane tile. Read off the
+# shape, nothing else. Set from the kernel-alone table of
+# ``benchmarks/sorted_sum_routes.py`` on one TPU v5 lite chip (PERF.md §6,
+# PR 32): a scatter-add pays by the row whatever its width, the chunked cumsum
+# by the row AND its width in lane tiles, and they cross below one tile.
+WIDE_ROW = 128
+
+
+def wide(width: int) -> bool:
+    """Whether a sum of ``[E, width]`` rows takes the scatter-add
+    (``ops/aggregate.py`` names the arm ``scatter_sorted`` from this)."""
+    return width >= WIDE_ROW
+
+
+def _boundaries(ids, num_segments: int, row_ptr):
+    """(left, right) of every segment's run of rows."""
+    if row_ptr is not None:
+        # CSR batch contract: collation precomputed the boundaries once per
+        # batch (graphs/csr.py). Identical values to the searchsorted
+        # derivation below (validated at collation), so the two paths are
+        # bit-exact — tests/test_csr_contract.py pins that.
+        row_ptr = row_ptr.astype(jnp.int32)
+        return row_ptr[:-1], row_ptr[1:]
+    ids = ids.astype(jnp.int32)
+    seg = jnp.arange(num_segments, dtype=jnp.int32)
+    global SEARCHSORTED_CALLS
+    SEARCHSORTED_CALLS += 1
+    left = jnp.searchsorted(ids, seg, side="left").astype(jnp.int32)
+    right = jnp.searchsorted(ids, seg, side="right").astype(jnp.int32)
+    return left, right
+
+
+def _sum_count_prefix(data32, ids, num_segments: int, row_ptr=None):
+    """The narrow route: segment totals as differences of a compensated
+    prefix sum (module docstring), the count from the boundaries."""
     # Mean-center before the prefix: a mean-shifted stream grows the prefix
     # linearly and the within-chunk f32 cumsum rounds at ulp(prefix) — ~5e-4
     # absolute at E=16k, 100x the scatter path. Centered, the prefix is a
@@ -169,20 +212,7 @@ def _sum_count_sorted(data, ids, num_segments: int, row_ptr=None):
     # the difference (masked rows contribute -mu then get +mu back: net 0).
     mu = jnp.mean(data32, axis=0)
     local, hi, err, chunk = _prefix_open(data32 - mu)
-    if row_ptr is not None:
-        # CSR batch contract: collation precomputed the boundaries once per
-        # batch (graphs/csr.py). Identical values to the searchsorted
-        # derivation below (validated at collation), so the two paths are
-        # bit-exact — tests/test_csr_contract.py pins that.
-        row_ptr = row_ptr.astype(jnp.int32)
-        left, right = row_ptr[:-1], row_ptr[1:]
-    else:
-        ids = ids.astype(jnp.int32)
-        seg = jnp.arange(num_segments, dtype=jnp.int32)
-        global SEARCHSORTED_CALLS
-        SEARCHSORTED_CALLS += 1
-        left = jnp.searchsorted(ids, seg, side="left").astype(jnp.int32)
-        right = jnp.searchsorted(ids, seg, side="right").astype(jnp.int32)
+    left, right = _boundaries(ids, num_segments, row_ptr)
 
     def parts(k):
         """(hi, err, local) components of P[k] = sum(data[:k]); k in [0, E]."""
@@ -205,6 +235,42 @@ def _sum_count_sorted(data, ids, num_segments: int, row_ptr=None):
         + count[:, None] * mu
     )
     return total, count
+
+
+def _sum_count_scatter(data32, ids, num_segments: int, row_ptr=None):
+    """The wide route: ONE XLA scatter-add over the RAW ids, told they are
+    non-decreasing. A run's rows are added one after another onto a zero row,
+    with no cancellation against a prefix: no centering, no carries. The
+    count still comes from the boundaries: exact, and no second scatter.
+
+    The rows are PINNED row-major on their way in. A scatter (like the row
+    gather that fed the messages) moves whole rows, and the prefix route's
+    chunked reshape used to hold the whole elementwise chain between gather
+    and sum to that layout. Without it the chip's layout assignment follows
+    whichever producer states a preference (PaiNN's 20-deep filter Dense wants
+    its ``[E, 3F]`` output column-major) and pays a transposing copy of every
+    ``[E, F]`` array at the gather and again here: 22 copies a PaiNN train
+    step, 120.4 ms where the pinned step takes 91.2 (PERF.md §6, PR 32)."""
+    rows = with_layout_constraint(data32, Layout(major_to_minor=(0, 1)))
+    total = jax.ops.segment_sum(
+        rows, ids.astype(jnp.int32), num_segments=num_segments,
+        indices_are_sorted=True,
+    )
+    left, right = _boundaries(ids, num_segments, row_ptr)
+    return total, (right - left).astype(jnp.float32)
+
+
+def _sum_count_sorted(data, ids, num_segments: int, row_ptr=None):
+    data32 = data.astype(jnp.float32)
+    if data32.shape[0] == 0:
+        # Drop-in parity with segment_sum on an empty edge set: exact zeros
+        # (jnp.mean over the empty axis would otherwise inject NaN via mu).
+        return (
+            jnp.zeros((num_segments, data32.shape[1]), jnp.float32),
+            jnp.zeros((num_segments,), jnp.float32),
+        )
+    route = _sum_count_scatter if wide(data32.shape[1]) else _sum_count_prefix
+    return route(data32, ids, num_segments, row_ptr)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -237,8 +303,9 @@ segment_sum_count_sorted.defvjp(_fwd, _bwd)
 def segment_sum_count_csr(data, row_ptr, ids, num_segments: int):
     """(segment_sum, segment_count) from PRECOMPUTED CSR boundaries — the
     zero-searchsorted twin of :func:`segment_sum_count_sorted`. ``row_ptr``
-    [num_segments + 1] comes from collation (graphs/csr.py); ``ids`` is kept
-    only for the gather backward (it never enters the forward)."""
+    [num_segments + 1] comes from collation (graphs/csr.py); ``ids`` feeds
+    the gather backward and, for wide rows, the forward's scatter-add (narrow
+    rows' forward never reads it)."""
     return _sum_count_sorted(data, ids, num_segments, row_ptr=row_ptr)
 
 

@@ -59,8 +59,9 @@ AGG_WHATS = ("sum", "count", "sum_count", "mean", "stats", "extrema", "softmax")
 # The route ops/aggregate.py took at trace time: masked XLA segment ops, the
 # sorted prefix path with searched or with precomputed (CSR) boundaries, the
 # Pallas kernel over the CSR boundaries (the extrema's scan over receiver
-# runs, ops/extrema_scan.py).
-AGG_ARMS = ("xla", "sorted", "csr", "pallas_csr")
+# runs, ops/extrema_scan.py), the sorted arm's wide rows as one XLA
+# scatter-add told the ids are sorted (PR 32; a name added, none changed).
+AGG_ARMS = ("xla", "sorted", "csr", "pallas_csr", "scatter_sorted")
 
 
 def agg(what: str, arm: str) -> str:

@@ -18,6 +18,7 @@ import pytest
 
 from hydragnn_tpu.graphs.csr import build_row_ptr
 from hydragnn_tpu.ops import aggregate as agg
+from hydragnn_tpu.ops import certify
 from hydragnn_tpu.ops import segment as seg
 
 ROUTES = ("sorted", "csr")
@@ -249,6 +250,63 @@ def pytest_fused_ops_differentiable_under_shard_map(route):
     g = jax.jit(jax.grad(lambda l: f(l, ids, row_ptr)))(logits)
     g_one = jax.grad(lambda l: sum(terms(l, ids, use(row_ptr), None)))(logits)
     np.testing.assert_allclose(g, g_one, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", certify.WIDE_CASES)
+def pytest_wide_route_holds_the_f64_truth(case, monkeypatch):
+    """The sorted arm's WIDE sums (``segment_sorted.WIDE_ROW`` columns or
+    more: one XLA scatter-add told the ids are sorted, arm ``scatter_sorted``)
+    against numpy in float64 at ``[16384, 512]`` rows over 4096 segments,
+    through PNA's stats bundle, forward and gradient, in every layout a batch
+    can bring (``certify.WIDE_CASES``: short runs, boundaries searched, empty
+    runs, a long zeroed padding run, 16347 rows, bfloat16 messages, an
+    edge-sharded axis over two devices).
+
+    The order it adds in: a run's rows one after another, in row order, onto
+    a zero row; nothing is subtracted from a prefix. So the error of a segment
+    is a sequential float32 sum's over ITS rows: at most (rows - 1) half-ulps
+    of the partial sums. Messages ~N(1, 2) in runs of 4-12 rows: read 3.9e-6
+    to 9.8e-6 here, held to 2e-5 (on the chip 1.6e-5 to 4.3e-5 in runs of 16
+    and 48 at ``[262144, 512]``, and 4.5e-6 on the cells' unit-variance rows,
+    where the prefix route reads 3.7e-4: PERF.md §6 PR 32); the certifier's
+    pin is ``WIDE_FWD_PIN`` = 1e-4, a fifth of its gate. The gradient
+    is gathers through the ids (3.4e-5, the ``std`` term's; bfloat16: its own
+    rounding, 2^-8 of the largest entry)."""
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    report = certify.certify_wide_sum(case, e=16384, f=512, n=4096)
+    assert report["shape"]["e"] == (16347 if case == "ragged_rows" else 16384)
+    assert report["err_fwd"] < 2e-5 < certify.WIDE_FWD_PIN, report
+    assert report["err_grad"] <= report["tol_grad"], report
+    if case != "bf16":
+        assert report["err_grad"] < 5e-4, report
+    assert report["ok"], report
+
+
+def pytest_wide_route_engages_by_width_alone(monkeypatch):
+    """``WIDE_ROW`` is one lane tile, read off the shape: the arm of a sum is
+    ``scatter_sorted`` from that width up with or without ``row_ptr``, the
+    prefix arms below it, ``xla`` off the sorted arm at any width; the wide
+    route refuses a narrow call and the certification a CPU's default arm."""
+    from hydragnn_tpu.ops import segment_sorted as srt
+
+    assert srt.WIDE_ROW == 128
+    ptr = jnp.zeros((5,), jnp.int32)
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    for width, with_ptr, without in (
+        (1, "csr", "sorted"), (6, "csr", "sorted"), (127, "csr", "sorted"),
+        (128, "scatter_sorted", "scatter_sorted"),
+        (512, "scatter_sorted", "scatter_sorted"),
+    ):
+        assert agg._arm(ptr, width) == with_ptr, width
+        assert agg._arm(None, width) == without, width
+    assert agg._width(jnp.zeros((8, 6, 64))) == 384
+    assert agg._width(jnp.zeros((8,))) == 1
+    with pytest.raises(ValueError, match="not a wide case"):
+        certify.certify_wide_sum("short_runs", e=64, f=64, n=8)
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "0")
+    assert agg._arm(ptr, 512) == "xla"
+    with pytest.raises(RuntimeError, match="sorted arm"):
+        certify.certify_wide_sum("short_runs", e=64, f=128, n=8)
 
 
 def pytest_no_flag_selects_an_aggregation_kernel():

@@ -70,7 +70,10 @@ def pytest_chip_smoke_rehearsal_passes_end_to_end(tmp_path):
         summary = json.load(f)
     assert {"device", "train", "serve", "kernels", "warm"} <= set(summary)
     assert summary["train"]["xla_compiles_per_epoch"][1:] == [0, 0]
-    assert set(summary["kernels"]["arms"]) == {"sorted", "csr", "extrema_scan"}
+    assert set(summary["kernels"]["arms"]) == {
+        "sorted", "csr", "scatter_sorted", "extrema_scan",
+    }
+    assert summary["kernels"]["arms"]["scatter_sorted"]["ok"]
     scan = summary["kernels"]["arms"]["extrema_scan"]
     assert scan["bit_equal"] and scan["grad_bit_equal"] and scan["ok"]
     assert summary["warm"]["warm"]["persistent_cache_hits"] > 0

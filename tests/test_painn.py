@@ -55,12 +55,12 @@ def _graphs(seed=0, sizes=(5, 7, 9, 12)):
     return out
 
 
-def _model(kind="PAINN", **kw):
+def _model(kind="PAINN", hidden=F, **kw):
     if kind == "PNA":
         kw["pna_deg"] = [0, 1, 2, 4, 4, 3, 2, 1]
     if kind == "PAINN":
         kw.update(radius=RADIUS, num_radial=6)
-    return create_model(kind, 1, F, DIMS, TYPES, HEADS, [1.0, 1.0], 2, **kw)
+    return create_model(kind, 1, hidden, DIMS, TYPES, HEADS, [1.0, 1.0], 2, **kw)
 
 
 def _collate(model, graphs, **pads):
@@ -84,22 +84,62 @@ def _per_graph(outputs, graphs):
 
 
 # ------------------------------------------------- (i) forward vs the yardstick
-@pytest.mark.parametrize("kind", ["PNA", "GAT", "PAINN"])
-def pytest_program_forward_matches_the_plain_reference(kind):
+# (family, hidden width): the tiny size, and each benchmark configuration's own
+# width, so that the sums run on both sides of ``segment_sorted.WIDE_ROW``
+# (128): PaiNN F 128 sums [E, 512] rows; GAT 64 a head x 6 sums [E, 384] rows
+# and [E, 6] denominators; PNA 256 sums [E, 256] rows and its input layer's
+# one column.
+# (family, hidden) -> (widths summed by the scatter-add, by the prefix sums) on
+# the chip's arm; the pool's mean is hidden wide (GAT: its shared layer's).
+_ROUTES = {
+    ("PNA", F): (set(), {1, F}), ("GAT", F): (set(), {6, F, 6 * F}),
+    ("PAINN", F): (set(), {F, 4 * F}), ("PAINN", 128): ({128, 512}, set()),
+    ("GAT", 64): ({384}, {6, 64}), ("PNA", 256): ({256}, {1}),
+}
+
+
+@pytest.mark.parametrize("arm", ["xla", "chip"])
+@pytest.mark.parametrize("kind,hidden", list(_ROUTES))
+def pytest_program_forward_matches_the_plain_reference(kind, hidden, arm, monkeypatch):
     """What ``correct`` holds a chip run to (graftbench/drivers/
-    train_epochs.py), here at a tiny size on every tier-1 run: the program's
-    forward on seeded, shaken weights against ``graftbench.reference``. Both
-    are float32 on the CPU and differ by summation order alone."""
+    train_epochs.py), here on every tier-1 run: the program's forward on
+    seeded, shaken weights against ``graftbench.reference``, on the CPU's arm
+    (``xla``: the masked XLA segment ops) and on the chip's (``chip``: the
+    sorted arm through ``HYDRAGNN_SEGMENT_SORTED=1`` with the batch's
+    ``row_ptr``: wide sums one scatter-add told the ids are sorted, narrow
+    ones prefix sums). Both sides are float32 on the CPU and differ by
+    summation order alone; the limit is PaiNN's own on the chip (1e-4,
+    ``graftbench/families/painn.py``). This is the check PR 29's streamed sum
+    failed in ``painn_f128.train_b512`` (ledger, PR 29)."""
+    from hydragnn_tpu.ops import segment_sorted as srt
+
+    if arm == "chip":
+        monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    else:
+        monkeypatch.delenv("HYDRAGNN_SEGMENT_SORTED", raising=False)
+    scattered, prefixed = [], []
+    scatter, prefix = srt._sum_count_scatter, srt._sum_count_prefix
+    monkeypatch.setattr(srt, "_sum_count_scatter", lambda d, *a: (
+        scattered.append(d.shape[1]), scatter(d, *a))[1])
+    monkeypatch.setattr(srt, "_sum_count_prefix", lambda d, *a: (
+        prefixed.append(d.shape[1]), prefix(d, *a))[1])
     graphs = _graphs()
-    model = _model(kind)
+    model = _model(kind, hidden=hidden)
     variables = _shaken_variables(model, graphs)
-    got = _per_graph(
-        model.apply(variables, _collate(model, graphs), train=False), graphs
-    )
+    batch = _collate(model, graphs)
+    assert batch.row_ptr is not None
+    got = _per_graph(model.apply(variables, batch, train=False), graphs)
     want = reference.forward(model, variables, graphs)
     for g, w in zip(got, want):
         for a, b in zip(g, w):
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    # The arm that ran is the arm the case names: nothing of the sorted arm on
+    # a CPU's default; on the chip's, every edge sum at least a lane tile wide
+    # took the scatter-add and every narrower one the prefix sums.
+    want_routes = (set(), set()) if arm == "xla" else _ROUTES[(kind, hidden)]
+    assert (set(scattered), set(prefixed)) == want_routes
+    assert all(w >= srt.WIDE_ROW for w in scattered)
+    assert all(w < srt.WIDE_ROW for w in prefixed)
 
 
 # ------------------------------------------ (ii) loss and gradients vs reference

@@ -428,11 +428,17 @@ def pytest_certify_aggregation_consumes_the_shared_gate(monkeypatch):
     assert report["tol_grad"] == max(
         KERNEL_CERT_GATE.fwd, report["xla"]["err_grad"]
     )
-    assert set(report["arms"]) == {"sorted", "csr"}
-    for arm in report["arms"].values():
+    assert set(report["arms"]) == {"sorted", "csr", "scatter_sorted"}
+    # The prefix arms at the width asked for, the wide arm at four lane tiles.
+    assert report["shape"]["f_narrow"] == 24 and report["shape"]["f_wide"] == 512
+    for name in ("sorted", "csr"):
+        arm = report["arms"][name]
         assert arm["ok"] == (
             arm["err_fwd"] < report["tol"] and arm["err_grad"] <= report["tol_grad"]
         )
+    wide = report["arms"]["scatter_sorted"]
+    assert wide["ok"] and wide["err_fwd"] < report["tol"]
+    assert all(c["ok"] for c in wide["cases"].values())
     assert report["extrema_scan"]["bit_equal"]
     assert report["extrema_scan"]["grad_bit_equal"]
     assert report["ok"], report

@@ -76,7 +76,7 @@ def _batch(csr=True):
     return batch if csr else batch.replace(row_ptr=None, graph_ptr=None)
 
 
-def _model(conv, node_type="mlp", **kw):
+def _model(conv, node_type="mlp", hidden=8, **kw):
     kw.setdefault("edge_dim", 1)
     if conv == "PNA":
         kw["pna_deg"] = [0, 1, 2, 4, 2, 1]
@@ -87,7 +87,7 @@ def _model(conv, node_type="mlp", **kw):
     if conv == "PAINN":
         kw.update(radius=1.5, num_radial=4)
     return create_model(
-        conv, 1, 8, (1, 1), ("graph", "node"), _heads(node_type), [1.0, 1.0],
+        conv, 1, hidden, (1, 1), ("graph", "node"), _heads(node_type), [1.0, 1.0],
         2, **kw,
     )
 
@@ -172,6 +172,83 @@ def pytest_train_step_movers_scoped_and_arm_named(conv, route, monkeypatch):
     assert set(arms) == expected, (set(arms), expected)
     if conv == "PNA":
         assert scopes.AGG_PNA in used
+
+
+# family -> (hidden width, {what: widths it reduces at that hidden width}):
+# sums on both sides of ``segment_sorted.WIDE_ROW`` (128) in one program.
+_WIDE = {
+    # 6 heads of 32: the weighted sum 192 wide, the denominators 6, the pool 32
+    "GAT": (32, {"sum": (192, 6), "mean": (32,)}),
+    # F 32: the block's one sum 4F = 128 wide, the pool 32
+    "PAINN": (32, {"sum": (128,), "mean": (32,)}),
+    # hidden 128: conv_0's messages one column, conv_1's 128, the pool 128
+    "PNA": (128, {"stats": (1, 128), "mean": (128,)}),
+}
+
+
+@pytest.mark.parametrize("route", list(ROUTES))
+@pytest.mark.parametrize("conv", list(_WIDE))
+def pytest_wide_sums_are_named_scatter_sorted(conv, route, monkeypatch):
+    """The engagement counter of PR 32's mechanism, held to the compiled train
+    step. On the sorted arm a reduction of rows ``WIDE_ROW`` columns wide or
+    more is named ``scatter_sorted`` with or without ``row_ptr`` and a
+    narrower one keeps ``csr`` / ``sorted``, side by side in one program
+    (GATv2's 192-wide sum and its 6-wide denominators; PNA's 128-wide hidden
+    layer and its one-column input layer); a CPU's default arm names every
+    width ``xla``. Under ``scatter_sorted`` the forward holds a scatter and
+    none of the prefix route's row fetches (a sum's or a mean's forward
+    gathers nothing; the chip's ``reduce-window`` is held in
+    tests/test_tpu_compile.py, this CPU lowers a cumsum otherwise); under the
+    narrow arms the forward fetches rows and scatters nothing but PNA's
+    centered squares; and every scatter under an aggregation scope of the
+    sorted arm is told its indices are sorted."""
+    env, csr, arm = ROUTES[route]
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    hidden, reduced = _WIDE[conv]
+    batch = _painn_batch(csr) if conv == "PAINN" else _batch(csr)
+    kw = {"edge_dim": None} if conv == "PAINN" else {}
+    text = _compiled_text(conv, batch, hidden=hidden, **kw)
+    names = _op_names(text)
+    _check_movers_scoped(names, scopes.TRAIN_STEP)
+    assert _used(names) <= scopes.VOCABULARY
+    want = {
+        what: {
+            arm if route == "xla" or w < srt.WIDE_ROW else "scatter_sorted"
+            for w in widths
+        }
+        for what, widths in reduced.items()
+    }
+    got = {what: arms for what, arms in _arms(names).items() if what != "extrema"}
+    assert got == want, (got, want)
+    if route == "xla":
+        return
+    forward = lambda scope, op: [  # noqa: E731
+        n for o, n in names if o == op and scope in n and "transpose(" not in n
+    ]
+    for what, arms in want.items():
+        if "scatter_sorted" in arms:
+            wide = scopes.agg(what, "scatter_sorted")
+            assert forward(wide, "scatter"), f"no scatter under {wide}"
+            # stats gathers the mean back for the centered squares
+            # (without ``row_ptr`` the count's two searches gather ids)
+            fetched = [n for n in forward(wide, "gather") if "searchsorted" not in n]
+            assert what == "stats" or not fetched, fetched
+            # The backward is the gathers it was: no scatter there.
+            assert not [n for o, n in names
+                        if o == "scatter" and wide in n and "transpose(" in n]
+        if arm in arms:
+            narrow = scopes.agg(what, arm)
+            assert forward(narrow, "gather"), f"no prefix fetch under {narrow}"
+            # PNA's std keeps its centered scatter-add at every width.
+            assert bool(forward(narrow, "scatter")) == (what == "stats")
+    scatters = [
+        line for line in text.splitlines()
+        if re.search(r"\sscatter\(", line.split("metadata=")[0])
+        and re.search(r"hydragnn\.agg\.(sum|stats|mean)\.", line)
+        and "transpose(" not in _OP_NAME.search(line).group(1)
+    ]
+    assert scatters and all("indices_are_sorted=true" in s for s in scatters), scatters
 
 
 def pytest_custom_vjp_backward_carries_the_scope(monkeypatch):
@@ -452,6 +529,11 @@ def pytest_scopes_are_metadata_only(conv, monkeypatch):
 # ------------------------------------------------------------- the vocabulary
 def pytest_vocabulary_table():
     assert scopes.agg("stats", "csr") == "hydragnn.agg.stats.csr"
+    # PR 32: a name added, none changed, so the version stands.
+    assert scopes.AGG_ARMS == ("xla", "sorted", "csr", "pallas_csr", "scatter_sorted")
+    assert scopes.agg("sum", "scatter_sorted") == "hydragnn.agg.sum.scatter_sorted"
+    assert scopes.agg("stats", "scatter_sorted") in scopes.VOCABULARY
+    assert scopes.VERSION == 1
     assert all(n.startswith("hydragnn.") for n in scopes.VOCABULARY)
     assert set(scopes.ROOTS) < scopes.VOCABULARY
     with pytest.raises(ValueError):

@@ -28,6 +28,14 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _sorts(text, scope):
+    """The ``sort`` instructions whose ``op_name`` holds ``scope``."""
+    return [
+        line for line in text.splitlines()
+        if re.search(r"\ssort\(", line.split("metadata=")[0]) and scope in line
+    ]
+
+
 def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch):
     """One ``GATv2Conv``, forward and backward, at the shapes of the cell
     ``gatv2_h64x6_md17like.train_b512`` (16384 × 262144, six heads of 64) on
@@ -84,6 +92,20 @@ def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch
     assert sorted(wide) == sorted(
         [(f"{e},{h * f}", "gather")] * 2 + [(f"{n},{h * f}", "scatter-add")] * 2
     ), moved
+    # PR 32, the in-cell bypass: the 384-wide weighted sum is one scatter-add
+    # told the ids are sorted; the 6-wide denominators keep the prefix sums
+    # (a reduce-window over [., ., 6]-wide chunks is still in the program).
+    summed = [
+        line for line in text.splitlines()
+        if re.search(rf"= f32\[{n},{h * f}\]\S* scatter\(", line)
+        and "hydragnn.agg.sum.scatter_sorted" in line
+    ]
+    assert len(summed) == 1, summed
+    assert _sorts(text, "hydragnn.gather")
+    assert not _sorts(text, "hydragnn.agg.sum.scatter_sorted")
+    assert "hydragnn.agg.sum.csr" in text
+    assert not re.search(rf"f32\[\d+,\d+,{h * f}\]\S* reduce-window\(", text)
+    assert re.search(r"\sreduce-window\(", text), "the narrow sum's cumsum is gone"
 
 
 def pytest_painn_block_at_cell_size_keeps_the_vector_state_flat(one_chip, monkeypatch):
@@ -141,7 +163,30 @@ def pytest_painn_block_at_cell_size_keeps_the_vector_state_flat(one_chip, monkey
     assert sorted(moved) == sorted(
         [(f"{e},{3 * f}", "gather")] * 2 + [(f"{n},{3 * f}", "scatter-add")] * 2
     ), moved
-    assert re.search(rf"f32\[{e},{4 * f}\]\S* fusion\(.*hydragnn\.agg\.sum\.csr", entry)
+    # PR 32: that 512-wide sum is ONE scatter-add told the ids are sorted,
+    # under the arm's own name, and the prefix route is gone from the block:
+    # no reduce-window (the chunked cumsum's lowering) over [E, 512] rows in
+    # any form, no [512, 512, 4, 128] chunks, nothing under ``agg.sum.csr``.
+    wide = "hydragnn.agg.sum.scatter_sorted"
+    sums = [
+        line for line in text.splitlines()
+        if re.search(rf"= f32\[{n},{4 * f}\]\S* scatter\(", line)
+    ]
+    assert len(sums) == 1 and wide in sums[0], sums
+    assert "unique_indices=true" not in sums[0]  # a run is many rows of one id
+    # Told the ids are sorted: the chip's compiler sorts the indices of every
+    # OTHER scatter itself (and writes ``indices_are_sorted=true`` on all of
+    # them afterwards), so what the flag shows as is NO sort under the sum's
+    # scope, where the senders' scatter-adds have theirs.
+    assert _sorts(text, "hydragnn.gather") and not _sorts(text, wide)
+    assert re.search(rf"f32\[{e},{4 * f}\]\S* fusion\(.*{re.escape(wide)}", entry)
+    assert "hydragnn.agg.sum.csr" not in text
+    windows = [
+        line for line in text.splitlines()
+        if re.search(r"\sreduce-window\(", line.split("metadata=")[0])
+    ]
+    assert not windows, windows[:2]
+    assert f"f32[{e // 512},512,4,128]" not in text
 
 
 def _combiners(text):
@@ -224,6 +269,26 @@ def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monke
     assert not [s for s, body in scatters if re.search(r"(min|max)imum\(", body)], scatters
     kept = f"f32[{n},{f}]" if f > 1 else f"f32[{n}]"  # one column comes out rank 1
     assert [s for s, body in scatters if s == kept and " add(" in body], scatters
+    adds = [  # the bundle's own (the gathers' backward scatter-adds beside them)
+        line for line in text.splitlines()
+        if re.search(r"\sscatter\(", line.split("metadata=")[0])
+        and "hydragnn.agg.stats" in line
+    ]
+    # PR 32: 256 columns are wide, so the sums are a scatter-add too, beside
+    # the squares', under ``stats.scatter_sorted`` and with no prefix sums
+    # left; the input layer's one column keeps ``stats.csr`` and its cumsum.
+    # Neither scatter-add has its indices sorted for it (both are told).
+    wide, narrow = scopes.agg("stats", "scatter_sorted"), scopes.agg("stats", "csr")
+    # (float32: the extrema's backward has an int32 cumsum of its own)
+    windows = re.search(r"= f32\[[\d,]+\]\S* reduce-window\(", text)
+    if f > 1:
+        assert len(adds) == 2 and wide in text and narrow not in text, adds
+        assert not windows, windows.group(0)
+    else:
+        assert len(adds) == 1 and narrow in text and wide not in text, adds
+        assert windows, "the one-column sum's cumsum is gone"
+    assert not _sorts(text, "hydragnn.agg.stats")
+    assert f == 1 or _sorts(text, scopes.GATHER)  # what a sort looks like here
 
 
 def pytest_lfm2_attention_at_cell_size_has_no_n_by_n_array(one_chip):
