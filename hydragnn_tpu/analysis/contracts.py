@@ -1336,21 +1336,21 @@ def _check_position_family(arch, errors):
     the cutoff and the basis size must be there, and the edge vector must be
     the difference of the two positions, which it is not across a periodic
     cell boundary."""
-    from ..models.convs import POSITION_FAMILIES
+    from ..models.convs import POSITION_FAMILIES, TOKEN_STACKS
 
     mt = arch.get("model_type")
     if mt not in POSITION_FAMILIES:
         return
-    if mt == "LFM2":
+    if mt in TOKEN_STACKS:
         # Positions are the nodes' places in their sequences: no cutoff, no
-        # basis, no cell. What the stack needs instead (models/lfm2.py):
-        from ..models.lfm2 import LFM2Config
-
-        # token_minmax is completion's to add (the dataset's table).
-        missing = [k for k in LFM2Config.missing(arch) if k != "token_minmax"]
+        # basis, no cell. What the stack needs instead (models/lfm2.py,
+        # models/laguna.py); token_minmax is completion's to add (the
+        # dataset's table).
+        sizes = TOKEN_STACKS[mt][0]
+        missing = [k for k in sizes.missing(arch) if k != "token_minmax"]
         if missing:
             errors.append(
-                ("bad-arch", f"model_type=LFM2 needs Architecture.{'/'.join(missing)}")
+                ("bad-arch", f"model_type={mt} needs Architecture.{'/'.join(missing)}")
             )
         return
     radius, num_radial = arch.get("radius"), arch.get("num_radial")
@@ -1470,7 +1470,9 @@ def _check_shapes(config, arch, voi, training, mode, completed, errors, skipped)
             output_dim=output_dim, head_loss=kinds,
             class_minmax=[[0.0, 1.0] if k == "cross_entropy" else None for k in kinds],
         )
-    if arch2.get("model_type") == "LFM2":
+    from ..models.convs import TOKEN_FAMILIES
+
+    if arch2.get("model_type") in TOKEN_FAMILIES:
         arch2.setdefault("token_minmax", [0.0, 1.0])
     if arch2.get("model_type") == "PNA" and not arch2.get("pna_deg"):
         mn = arch2.get("max_neighbours")

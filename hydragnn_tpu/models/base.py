@@ -12,7 +12,8 @@ Architecture (mirrors reference semantics under padding):
              read-out and the heads read the scalar state. LFM2: a token
              embedding, num_conv_layers × [operator → feed-forward] with
              RMSNorm and a residual round each, a final RMSNorm
-             (models/lfm2.py): no edge list is read.
+             (models/lfm2.py): no edge list is read. LAGUNA: the same
+             loop over Laguna-XS.2's block (models/laguna.py).
   readout:   masked segment-mean over nodes per graph (global_mean_pool analog)
   heads:     graph heads = shared MLP ("graph_shared") + per-head MLP;
              node heads = shared MLPNode ('mlp' / 'mlp_per_node') or a conv chain
@@ -33,12 +34,15 @@ from ..ops import aggregate
 from ..ops import segment as seg
 from ..telemetry import scopes
 from .layers import MLP, MaskedBatchNorm
-from . import lfm2 as lfm2_model, painn
+from . import laguna as laguna_model, lfm2 as lfm2_model, painn
 from .convs import (
-    POSITION_FAMILIES, CGConv, GATv2Conv, GINConv, MFCConv, PNAConv, SAGEConv,
+    POSITION_FAMILIES, TOKEN_STACKS, CGConv, GATv2Conv, GINConv, MFCConv,
+    PNAConv, SAGEConv,
 )
 
-CONV_TYPES = ("PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE", "PAINN", "LFM2")
+CONV_TYPES = (
+    "PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE", "PAINN", "LFM2", "LAGUNA"
+)
 
 
 class MLPNode(nn.Module):
@@ -125,6 +129,8 @@ class HydraGNN(nn.Module):
     num_radial: Optional[int] = None
     # LFM2: the stack's sizes, keyed as the source names them (models/lfm2.py).
     lfm2: Optional[lfm2_model.LFM2Config] = None
+    # LAGUNA: the same (models/laguna.py).
+    laguna: Optional[laguna_model.LagunaConfig] = None
     # Loss kind a head ("rmse" | "cross_entropy"; () = rmse throughout) and,
     # for a cross-entropy head, the dataset's (min, max) of its target column,
     # from which the class ids are un-scaled (models/loss.py).
@@ -135,9 +141,17 @@ class HydraGNN(nn.Module):
     def counts_routing(self) -> bool:
         """Whether a step sows counters (the routed experts' loads): the train
         step then asks for the collection (train/trainer.py)."""
-        return self.conv_type == "LFM2" and any(
-            self.lfm2.routed(i) for i in range(self.num_conv_layers)
+        cfg = self.token_cfg
+        return cfg is not None and any(
+            cfg.routed(i) for i in range(self.num_conv_layers)
         )
+
+    @property
+    def token_cfg(self):
+        """The sizes of a token stack (LFM2, LAGUNA); None for the others."""
+        if self.conv_type in TOKEN_STACKS:
+            return getattr(self, self.conv_type.lower())
+        return None
 
     @property
     def use_edge_attr(self) -> bool:
@@ -236,16 +250,19 @@ class HydraGNN(nn.Module):
         self.batch_norms = bns
 
     @nn.nowrap
-    def _setup_lfm2_encoder(self):
-        """LFM2: the token embedding, one block a layer (``conv_<i>``, so that
-        ``freeze_conv_layers`` freezes them as the others), the final norm."""
-        block = nn.remat(lfm2_model.LFM2Block) if self.remat else lfm2_model.LFM2Block
-        self.conv_embed = nn.Embed(self.lfm2.vocab_size, self.hidden_dim)
+    def _setup_token_encoder(self):
+        """A token stack: the token embedding, one block a layer (``conv_<i>``,
+        so that ``freeze_conv_layers`` freezes them as the others), the final
+        norm."""
+        block, cfg = TOKEN_STACKS[self.conv_type][1], self.token_cfg
+        if self.remat:
+            block = nn.remat(block)
+        self.conv_embed = nn.Embed(cfg.vocab_size, self.hidden_dim)
         self.convs = [
-            block(self.hidden_dim, self.lfm2, i, name=f"conv_{i}")
+            block(self.hidden_dim, cfg, i, name=f"conv_{i}")
             for i in range(self.num_conv_layers)
         ]
-        self.conv_norm = lfm2_model.RMSNorm(self.lfm2.norm_eps)
+        self.conv_norm = lfm2_model.RMSNorm(cfg.norm_eps)
 
     @nn.nowrap
     def _setup_painn_encoder(self):
@@ -266,8 +283,8 @@ class HydraGNN(nn.Module):
         h = self.gat_heads
         if self.conv_type == "PAINN":
             self._setup_painn_encoder()
-        elif self.conv_type == "LFM2":
-            self._setup_lfm2_encoder()
+        elif self.conv_type in TOKEN_STACKS:
+            self._setup_token_encoder()
         else:
             self._setup_conv_encoder()
 
@@ -280,10 +297,10 @@ class HydraGNN(nn.Module):
         # override GATStack.py:48-86; CGCNN forbids 'conv' CGCNNStack.py:53-75) ---
         nch, ncb, nco, ncob = [], [], [], []
         if node_head_idx and self.node_nn_type == "conv":
-            if self.conv_type in ("CGCNN", "PAINN", "LFM2"):
+            if self.conv_type in ("CGCNN", "PAINN", *TOKEN_STACKS):
                 # CGCNN preserves channels; a PaiNN block has two states and
-                # an LFM2 block a residual stream: no width to narrow to a
-                # head's output.
+                # a token stack's block a residual stream: no width to narrow
+                # to a head's output.
                 raise ValueError(
                     f'"conv" node decoder is not supported for {self.conv_type}; '
                     'use "mlp" or "mlp_per_node"'
@@ -423,17 +440,19 @@ class HydraGNN(nn.Module):
         return jnp.where(batch.node_mask[:, None], s, 0.0)
 
     @nn.nowrap
-    def _encode_lfm2(self, batch: GraphBatch):
+    def _encode_tokens(self, batch: GraphBatch):
         if batch.positions is None:
             raise ValueError(
-                "LFM2 reads each node's place in its sequence from "
+                f"{self.conv_type} reads each node's place in its sequence from "
                 "GraphBatch.positions[:, 0]: collate with with_positions=True "
                 "(config completion and the serving engine do, from the "
                 "model family)"
             )
         # senders, receivers, row_ptr and the edge mask are not read: both
         # token mixers work from node_graph and the node order (models/lfm2.py).
-        h = self.conv_embed(lfm2_model.token_ids(batch.node_features[:, 0], self.lfm2))
+        h = self.conv_embed(
+            lfm2_model.token_ids(batch.node_features[:, 0], self.token_cfg)
+        )
         place = batch.positions[:, 0]
         for block in self.convs:
             h = block(h, batch.node_graph, place, batch.node_mask)
@@ -443,8 +462,8 @@ class HydraGNN(nn.Module):
     def __call__(self, batch: GraphBatch, train: bool = False):
         if self.conv_type == "PAINN":
             x = self._encode_painn(batch)
-        elif self.conv_type == "LFM2":
-            x = self._encode_lfm2(batch)
+        elif self.conv_type in TOKEN_STACKS:
+            x = self._encode_tokens(batch)
         else:
             x = self._encode_convs(batch, train)
 

@@ -40,6 +40,7 @@ def create_model_config(
         radius=config.get("radius"),
         num_radial=config.get("num_radial"),
         lfm2=config if config["model_type"] == "LFM2" else None,
+        laguna=config if config["model_type"] == "LAGUNA" else None,
         head_loss=config.get("head_loss") or (),
         class_minmax=config.get("class_minmax") or (),
         compute_dtype=config.get("compute_dtype"),
@@ -66,6 +67,7 @@ def create_model(
     radius: Optional[float] = None,
     num_radial: Optional[int] = None,
     lfm2: Optional[Dict[str, Any]] = None,
+    laguna: Optional[Dict[str, Any]] = None,
     head_loss: Sequence[str] = (),
     class_minmax: Sequence[Any] = (),
     compute_dtype: Optional[str] = None,
@@ -74,7 +76,8 @@ def create_model(
 ) -> HydraGNN:
     """``lfm2``: for ``model_type`` "LFM2", the ``Architecture`` block's keys
     that size the stack, named as the source names them (models/lfm2.py
-    ``LFM2Config``). ``head_loss``: "rmse" or "cross_entropy" a head (empty:
+    ``LFM2Config``); ``laguna``: the same for "LAGUNA" (models/laguna.py
+    ``LagunaConfig``). ``head_loss``: "rmse" or "cross_entropy" a head (empty:
     rmse throughout); ``class_minmax``: for a cross-entropy head the (min,
     max) of its target column in the dataset's table, None for the others."""
     if len(task_weights) != len(output_dim):
@@ -83,6 +86,7 @@ def create_model(
             f"VS {len(output_dim)}"
         )
     from .base import CONV_TYPES
+    from .convs import TOKEN_STACKS
 
     if model_type not in CONV_TYPES:
         raise ValueError("Unknown model_type: {0}".format(model_type))
@@ -103,20 +107,22 @@ def create_model(
                 "number of radial basis functions) in Architecture."
             )
         kwargs.update(radius=float(radius), num_radial=int(num_radial))
-    elif model_type == "LFM2":
-        from .lfm2 import LFM2Config
-
-        if lfm2 is None:
+    elif model_type in TOKEN_STACKS:
+        field = model_type.lower()
+        sizes = {"lfm2": lfm2, "laguna": laguna}[field]
+        if sizes is None:
             raise ValueError(
-                "LFM2 requires the stack's sizes (create_model(lfm2=the "
-                "Architecture block))"
+                f"{model_type} requires the stack's sizes (create_model("
+                f"{field}=the Architecture block))"
             )
         if compute_dtype:
             raise ValueError(
-                "LFM2 reads token ids from a float32 node column; "
+                f"{model_type} reads token ids from a float32 node column; "
                 "compute_dtype would round it"
             )
-        kwargs.update(lfm2=LFM2Config.from_arch(lfm2, int(num_conv_layers)))
+        kwargs[field] = TOKEN_STACKS[model_type][0].from_arch(
+            sizes, int(num_conv_layers)
+        )
     loss_kinds = tuple(head_loss) or ("rmse",) * len(output_dim)
     unknown = set(loss_kinds) - {"rmse", "cross_entropy"}
     if unknown or len(loss_kinds) != len(output_dim):

@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,9 +44,12 @@ from ..ops.segment import execution_platform
 from ..telemetry import scopes
 from .layers import scaled_ids
 
-# Rows of a query block, and of a key block of the TPU kernel. The flat node
+# Rows of a query block, and of a key block of the TPU kernels. The flat node
 # array is padded up to a multiple of it inside ``segment_causal_attention``
-# (the loaders' buckets are multiples of 64, not of 512).
+# (the loaders' buckets are multiples of 64, not of 512). The band's kernel
+# was timed at 128, 256 and 512 (benchmarks/token_kernel_routes.py; PERF.md
+# section 6, PR 33): the largest wins though a window of 512 then spans 2 key
+# blocks a query block, twice the band's pairs.
 ATTN_BLOCK = 512
 # The collection the routed layers sow into: the experts each node chose and
 # the router's input (read by the benchmark's check, which asks for the
@@ -54,6 +57,29 @@ ATTN_BLOCK = 512
 # counters (asked for by the train step, train/trainer.py).
 INTERMEDIATES = "intermediates"
 COUNTERS = ("moe_rows_held", "moe_load_max", "moe_load_min")
+
+
+def missing_fields(cls, arch: dict) -> list:
+    """The fields of a stack's config dataclass ``cls`` that ``arch`` must
+    have and lacks (the rank's share defaults to all the experts)."""
+    return [
+        f.name for f in dataclasses.fields(cls)
+        if f.default is dataclasses.MISSING and f.name not in arch
+        and f.name not in ("num_experts_held", "experts_offset")
+    ]
+
+
+def experts_share(arch: dict) -> Tuple[int, int]:
+    """(``num_experts_held``, ``experts_offset``) of ``arch``: this rank's
+    share of the routed experts, all of them unless told."""
+    held = int(arch.get("num_experts_held", arch["num_experts"]))
+    offset = int(arch.get("experts_offset", 0))
+    if not 0 < held <= held + offset <= int(arch["num_experts"]):
+        raise ValueError(
+            f"experts {offset}..{offset + held} are not among "
+            f"{arch['num_experts']}"
+        )
+    return held, offset
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,13 +124,7 @@ class LFM2Config:
                 f"LFM2 needs {num_layers} layer_types of 'conv' / "
                 f"'full_attention', got {arch['layer_types']!r}"
             )
-        held = int(arch.get("num_experts_held", arch["num_experts"]))
-        offset = int(arch.get("experts_offset", 0))
-        if not 0 < held <= held + offset <= int(arch["num_experts"]):
-            raise ValueError(
-                f"experts {offset}..{offset + held} are not among "
-                f"{arch['num_experts']}"
-            )
+        held, offset = experts_share(arch)
         kw = {
             f.name: arch[f.name] for f in dataclasses.fields(cls) if f.name in arch
         }
@@ -114,14 +134,7 @@ class LFM2Config:
         )
         return cls(**kw)
 
-    @classmethod
-    def missing(cls, arch: dict) -> list:
-        """The keys ``arch`` must have and lacks (the share defaults to all)."""
-        return [
-            f.name for f in dataclasses.fields(cls)
-            if f.default is dataclasses.MISSING and f.name not in arch
-            and f.name not in ("num_experts_held", "experts_offset")
-        ]
+    missing = classmethod(missing_fields)
 
     def routed(self, layer: int) -> bool:
         return layer >= self.num_dense_layers
@@ -177,54 +190,111 @@ class ShortConv(nn.Module):
         return nn.Dense(d, use_bias=False, name="out_proj")(y)
 
 
-def rope(x, place, theta: float):
-    """Rotary embedding over the last axis of ``x`` [N, heads, head_dim] at
-    ``place`` [N] (float), the halves convention of the source's
-    ``rotate_half``."""
+def rotate(x, place, inv, factor: float = 1.0):
+    """Rotary embedding over the last axis of ``x`` [N, heads, dim] at
+    ``place`` [N] (float) with the frequencies ``inv`` [dim / 2], the halves
+    convention of the source's ``rotate_half``; cos and sin times ``factor``
+    (1 but for a scaled-context variant's attention factor)."""
     half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = place.astype(jnp.float32)[:, None] * inv  # [N, half]
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _attention_rows(q, k, v, seg_q, seg_k, first_row: int, scale: float):
-    """One block of query rows against the keys up to its last row: masked
-    softmax in float32. ``q`` [bq, KV, rep, hd]; ``k``, ``v`` [nk, KV, hd]."""
+def rope(x, place, theta: float):
+    """``rotate`` at the plain frequencies ``theta^(-2i/dim)``."""
+    half = x.shape[-1] // 2
+    return rotate(x, place, theta ** (-jnp.arange(half, dtype=jnp.float32) / half))
+
+
+def _attention_rows(q, k, v, seg_q, seg_k, first_row: int, scale: float,
+                    first_key: int = 0, window=None):
+    """One block of query rows (the flat rows from ``first_row``) against the
+    keys from ``first_key`` up to its last row: masked softmax in float32,
+    over ``same graph and j <= i`` and, with a ``window``, ``i - j < window``.
+    ``q`` [bq, KV, rep, hd]; ``k``, ``v`` [nk, KV, hd]."""
     s = jnp.einsum("qgrd,kgd->grqk", q, k) * scale
-    rows = first_row + jnp.arange(q.shape[0])
-    keep = (seg_q[:, None] == seg_k[None, :]) & (
-        jnp.arange(k.shape[0])[None, :] <= rows[:, None]
-    )
+    rows = first_row + jnp.arange(q.shape[0])[:, None]
+    keys = first_key + jnp.arange(k.shape[0])[None, :]
+    keep = (seg_q[:, None] == seg_k[None, :]) & (keys <= rows)
+    if window is not None:
+        keep &= rows - keys < window
     s = jnp.where(keep[None, None], s.astype(jnp.float32), -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("grqk,kgd->qgrd", p.astype(v.dtype), v)
 
 
-def segment_causal_attention(q, k, v, node_graph):
-    """Softmax aggregation over the complete causal graph of each sequence:
-    node ``i`` receives from every node ``j <= i`` of its own graph.
-    ``q`` [N, H, hd]; ``k``, ``v`` [N, KV, hd], each key-value head shared by
-    ``H / KV`` query heads. Nodes of one graph are contiguous and in order
-    (collation), so "earlier in the graph" is "earlier in the flat array":
-    the mask is ``same graph and j <= i`` and nothing is gathered.
+def _band_attention_tpu(q, k, v, node_graph, window: int, scale: float):
+    """The band on the TPU: the splash-attention Pallas kernel of JAX's own
+    library under a ``LocalMask``, one call a key-value head (``vmap``) over
+    its ``H / KV`` query heads. The mask is static, so the kernel's grid
+    holds only the key blocks the band touches, forward, dq and dkv alike;
+    the graph boundary is the kernel's segment ids. ``q`` [H, N, hd]
+    (scaled here: the kernel takes no scale); ``k``, ``v`` [KV, N, hd]."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash,
+        splash_attention_mask as masks,
+    )
 
-    On the TPU the Pallas flash kernel of JAX's own library, which skips the
-    blocks the causal order masks whole; elsewhere a loop over blocks of
-    query rows, each against the keys up to its end, rematerialized in the
-    backward. Either way the largest score array is a block's, never
-    ``[N, N]``. Padding nodes share the padding graph's id and attend among
-    themselves (every row keeps its diagonal, so no softmax is empty)."""
+    heads, n, hd = q.shape
+    kv = k.shape[0]
+    rep = heads // kv
+    b = ATTN_BLOCK
+    # Node i sees j with 0 <= i - j < window: window - 1 to the left, none to
+    # the right.
+    band = masks.LocalMask((n, n), (window - 1, 0), 0)
+    kernel = splash.make_splash_mqa_single_device(
+        masks.MultiHeadMask([band] * rep),
+        block_sizes=splash.BlockSizes(
+            block_q=b, block_kv=b, block_kv_compute=b,
+            block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+            block_q_dq=b, block_kv_dq=b,
+        ),
+    )
+    seg = node_graph.astype(jnp.int32)
+    out = jax.vmap(kernel, in_axes=(0, 0, 0, None))(
+        (q * scale).reshape(kv, rep, n, hd), k, v, splash.SegmentIds(q=seg, kv=seg)
+    )
+    return out.reshape(heads, n, hd)
+
+
+def segment_causal_attention(q, k, v, node_graph, window=None):
+    """Softmax aggregation over the complete causal graph of each sequence:
+    node ``i`` receives from every node ``j <= i`` of its own graph; with a
+    ``window``, over the causal BAND: also ``i - j < window`` (the node
+    itself counts). ``q`` [N, H, hd]; ``k``, ``v`` [N, KV, hd], each
+    key-value head shared by ``H / KV`` query heads. Nodes of one graph are
+    contiguous and in order (collation), so "earlier in the graph" is
+    "earlier in the flat array": the mask is ``same graph and j <= i`` and
+    nothing is gathered.
+
+    On the TPU a Pallas kernel of JAX's own library that skips the blocks the
+    mask leaves empty: the flash kernel for the complete causal graph, the
+    splash kernel for the band (the flash kernel has no window and would do
+    the triangle's work). Elsewhere a loop over blocks of query rows, each
+    against the keys it can see (up to its end; from ``window - 1`` rows
+    before its start), rematerialized in the backward. Either way the
+    largest score array is a block's, never ``[N, N]``. Padding nodes share
+    the padding graph's id and attend among themselves (every row keeps its
+    diagonal, so no softmax is empty)."""
     n, heads, hd = q.shape
     kv = k.shape[1]
     scale = hd ** -0.5
+    on_tpu = execution_platform() == "tpu"
     pad = -n % ATTN_BLOCK
     if pad:
         q, k, v = (jnp.pad(a, ((0, pad), (0, 0), (0, 0))) for a in (q, k, v))
         node_graph = jnp.pad(node_graph, (0, pad), constant_values=-1)
     total = n + pad
-    if execution_platform() == "tpu":
+    if on_tpu and window is not None:
+        out = _band_attention_tpu(
+            *(a.transpose(1, 0, 2) for a in (q, k, v)), node_graph, window, scale
+        )
+        return out.transpose(1, 0, 2)[:n].reshape(n, heads * hd)
+    if on_tpu:
         from jax.experimental.pallas.ops.tpu import flash_attention as fa
 
         b = ATTN_BLOCK
@@ -244,13 +314,14 @@ def segment_causal_attention(q, k, v, node_graph):
         )
         return out[0].transpose(1, 0, 2)[:n].reshape(n, heads * hd)
     q = q.reshape(total, kv, heads // kv, hd)
-    block = jax.checkpoint(_attention_rows, static_argnums=(5, 6))
+    block = jax.checkpoint(_attention_rows, static_argnums=(5, 6, 7, 8))
     out = []
     for start in range(0, total, ATTN_BLOCK):
         end = start + ATTN_BLOCK
+        lo = 0 if window is None else max(0, start - window + 1)
         out.append(block(
-            q[start:end], k[:end], v[:end], node_graph[start:end], node_graph[:end],
-            start, scale,
+            q[start:end], k[lo:end], v[lo:end], node_graph[start:end],
+            node_graph[lo:end], start, scale, lo, window,
         ))
     return jnp.concatenate(out)[:n].reshape(n, heads * hd)
 
@@ -295,6 +366,13 @@ _expert_init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=
 GMM_TILING = (256, 1024, 1024)
 
 
+def _gmm_tiles(m: int, k: int, n: int):
+    """``GMM_TILING`` no wider than the matrices: a fine-grained expert (512
+    wide) is narrower than a tile, and the kernel would multiply the tile."""
+    tm, tk, tn = GMM_TILING
+    return tm, min(tk, k), min(tn, n)
+
+
 @jax.custom_vjp
 def _gmm_tpu(lhs, rhs, sizes):
     """``lhs[rows of group g] @ rhs[g]`` on the TPU: the grouped-matmul Pallas
@@ -310,7 +388,7 @@ def _gmm_tpu_fwd(lhs, rhs, sizes):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     lhs16, rhs16 = lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16)
-    out = gmm(lhs16, rhs16, sizes, jnp.float32, GMM_TILING)
+    out = gmm(lhs16, rhs16, sizes, jnp.float32, _gmm_tiles)
     return out, (lhs16, rhs16, sizes)
 
 
@@ -319,8 +397,8 @@ def _gmm_tpu_bwd(residuals, ct):
 
     lhs16, rhs16, sizes = residuals
     ct16 = ct.astype(jnp.bfloat16)
-    d_lhs = gmm(ct16, rhs16, sizes, jnp.float32, GMM_TILING, transpose_rhs=True)
-    d_rhs = tgmm(lhs16.swapaxes(0, 1), ct16, sizes, jnp.float32, GMM_TILING)
+    d_lhs = gmm(ct16, rhs16, sizes, jnp.float32, _gmm_tiles, transpose_rhs=True)
+    d_rhs = tgmm(lhs16.swapaxes(0, 1), ct16, sizes, jnp.float32, _gmm_tiles)
     return d_lhs, d_rhs, None
 
 
@@ -393,7 +471,7 @@ class RoutedFFN(nn.Module):
     arrays are ``[K N, ·]`` whatever the routing."""
 
     features: int
-    cfg: LFM2Config
+    cfg: Any  # LFM2Config, or another stack's with the same routing fields
 
     @nn.compact
     def __call__(self, x, node_mask):
@@ -404,7 +482,10 @@ class RoutedFFN(nn.Module):
             c.moe_intermediate_size,
         )
         gate = self.param("gate", nn.initializers.lecun_normal(), (d, experts))
-        bias = self.param("expert_bias", nn.initializers.zeros, (experts,))
+        bias = (
+            self.param("expert_bias", nn.initializers.zeros, (experts,))
+            if c.use_expert_bias else None
+        )
         w1 = self.param("w1", _expert_init, (held, d, f))
         w3 = self.param("w3", _expert_init, (held, d, f))
         w2 = self.param("w2", _expert_init, (held, f, d))

@@ -46,6 +46,11 @@ GEOM = "hydragnn.geom"
 # softmax. Their projections stay with the module (Dense).
 LFM2_CONV = "hydragnn.lfm2.conv"
 LFM2_ATTN = "hydragnn.lfm2.attn"
+# Laguna's two kinds of attention (models/laguna.py): the query scaling,
+# rotary, the attention kernel's calls and the gate's product, under the
+# complete causal graph and under the causal band of the sliding window.
+ATTN_FULL = "hydragnn.attn.full"
+ATTN_WINDOW = "hydragnn.attn.window"
 # The routed experts: router, top-k, the sort by expert, both row
 # permutations and the weighting; and the grouped matmuls alone.
 MOE_ROUTE = "hydragnn.moe.route"
@@ -72,7 +77,8 @@ def agg(what: str, arm: str) -> str:
 
 VOCABULARY = frozenset(
     ROOTS
-    + (GATHER, POOL, GEOM, LFM2_CONV, LFM2_ATTN, MOE_ROUTE, MOE_EXPERTS)
+    + (GATHER, POOL, GEOM, LFM2_CONV, LFM2_ATTN, ATTN_FULL, ATTN_WINDOW)
+    + (MOE_ROUTE, MOE_EXPERTS)
     + (LOSS, OPTIMIZER, GRAD_SYNC, AGG_PNA)
     + tuple(agg(w, a) for w in AGG_WHATS for a in AGG_ARMS)
 )

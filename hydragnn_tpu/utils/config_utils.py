@@ -138,16 +138,19 @@ def _stage_classification_heads(config, ctx):
     is ONE column holding the class id: the loaders keep the target's
     dimension (``target_dim``), the model takes ``output_dim``, and the id is
     un-scaled in the loss from the dataset's own table (``class_minmax``).
-    ``model_type`` "LFM2" reads its input column the same way
+    A token stack (``convs.TOKEN_FAMILIES``) reads its input column the same way
     (``token_minmax``). Nothing is written for a config without either."""
     arch = _at(config, ("NeuralNetwork", "Architecture"))
     voi = _at(config, ("NeuralNetwork", "Variables_of_interest"))
     kinds = list(voi.get("loss") or [])
     classify = "cross_entropy" in kinds
-    if not classify and arch["model_type"] != "LFM2":
+    from ..models.convs import TOKEN_FAMILIES
+
+    tokens = arch["model_type"] in TOKEN_FAMILIES
+    if not classify and not tokens:
         return
     tables = _minmax_tables(_serialized_dataset_path(config))
-    if arch["model_type"] == "LFM2":
+    if tokens:
         arch["token_minmax"] = tables["node"][
             :, voi["input_node_features"][0]
         ].tolist()

@@ -373,3 +373,116 @@ def pytest_lfm2_routed_experts_at_cell_size_are_grouped_matmuls(one_chip):
     assert text.count("tpu_custom_call") >= 9
     wide_scatter = re.search(rf"f32\[\d+,(?:{d}|{f})\]\S* scatter\(", text)
     assert not wide_scatter, wide_scatter.group(0)
+
+
+def pytest_laguna_window_layers_at_cell_size_call_the_band_kernel(one_chip):
+    """A sliding layer's attention core (64 query and 8 key-value heads of
+    128, window 512) and a full layer's (48 heads), forward and backward, at
+    the shapes of the cell ``laguna_xs2_ep8.train_seq4k_b1`` (one sequence of
+    4096 tokens in a bucket of 4160 nodes) on the routes the chip takes: no
+    array has two axes of the node count (4160, or what the kernels pad it
+    to), so neither ``[N, N]`` nor ``[H, N, N]`` scores exist; the band runs
+    the splash kernel (one call a key-value head, batched: its rows are
+    ``[8, 8, padded, 128]``) and the triangle the flash kernel, and
+    the band's kernels visit FEWER key blocks than the triangle's would: the
+    grid of the band's forward is the 3 key blocks of 256 a query block that
+    the window touches, not the 17 the sequence has."""
+    from hydragnn_tpu.models.lfm2 import ATTN_BLOCK, segment_causal_attention
+    from hydragnn_tpu.ops.segment import platform_override
+
+    n, kv, hd, window = 4160, 8, 128, 512
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def lowered(heads, w):
+        def loss(q, k, v, node_graph):
+            out = segment_causal_attention(q, k, v, node_graph, window=w)
+            return (out * out).sum()
+
+        with platform_override("tpu"):
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+                shaped((n, heads, hd)), shaped((n, kv, hd)), shaped((n, kv, hd)),
+                shaped((n,), jnp.int32),
+            ).compile().as_text()
+
+    block = ATTN_BLOCK
+    padded = n + -n % block
+    for heads, w in ((64, window), (48, None)):
+        text = lowered(heads, w)
+        shapes = set(re.findall(r"\w+\[([\d,]+)\]", text))
+        square = [
+            s for s in shapes
+            if sum(int(d) in (n, padded) for d in s.split(",")) >= 2
+        ]
+        assert not square, f"an array with two node axes: {square}"
+        assert text.count("tpu_custom_call") >= 3  # forward, dq, dkv
+        if w is None:
+            assert f"f32[1,{heads},{padded},{hd}]" in text  # the flash kernel's rows
+            assert "splash" not in text
+        else:
+            assert f"f32[{kv},{heads // kv},{padded},{hd}]" in text
+            assert "splash" in text and "flash_attention" not in text.replace(
+                "splash_attention", ""
+            )
+            # The kernels' schedules (the mask is static, so they are
+            # constants of the program): a query block visits the 512 / 512 +
+            # 1 = 2 key blocks the band touches of the 9 the sequence has,
+            # forward and dq; a key block as many query blocks, dkv.
+            blocks, touched = padded // block, window // block + 1
+            assert f"s8[1,{blocks},{touched}]" in text and f"s8[1,{touched},{blocks}]" in text
+            assert f"s8[1,{blocks},{blocks}]" not in text and touched < blocks
+
+
+def pytest_grouped_matmul_tiles_follow_the_weights(one_chip):
+    """``RoutedFFN`` at the two cells' shapes after ``GMM_TILING`` follows the
+    weights: Laguna's fine-grained layer (32 of 256 experts held, 8 a token,
+    SwiGLU 512 wide: ``[33280, .]`` row arrays) compiles, forward and
+    backward, to the grouped-matmul kernel's nine calls with no tile wider
+    than a matrix; LFM2's (``[16640, .]``, 1792 wide) still calls the same
+    kernel with the tiles it had."""
+    from hydragnn_tpu.models import laguna, lfm2
+    from hydragnn_tpu.ops.segment import platform_override
+
+    assert lfm2._gmm_tiles(16640, 2048, 1792) == lfm2.GMM_TILING == (256, 1024, 1024)
+    assert lfm2._gmm_tiles(16640, 1792, 2048) == lfm2.GMM_TILING
+    assert lfm2._gmm_tiles(33280, 2048, 512) == (256, 1024, 512)
+    assert lfm2._gmm_tiles(33280, 512, 2048) == (256, 512, 1024)
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "graftbench", "configs", "laguna_xs2_ep8.json",
+    )) as f:
+        import json
+
+        arch = json.load(f)["NeuralNetwork"]["Architecture"]
+    cfg = laguna.LagunaConfig.from_arch(dict(arch, token_minmax=[0.0, 12543.0]), 5)
+    n, d, f_, held, k = 4160, 2048, 512, 32, 8
+    layer = lfm2.RoutedFFN(d, cfg)
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((8, d)), jnp.ones((8,), bool))
+        ),
+    )
+    assert "expert_bias" not in params["params"]
+
+    def loss(params, x, mask):
+        out = layer.apply(params, x, mask)
+        return (out * out).sum()
+
+    with platform_override("tpu"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, shaped((n, d)), shaped((n,), jnp.bool_)
+        ).compile().as_text()
+    rows = k * n
+    assert f"f32[{rows},{d}]" in text and f"f32[{rows},{f_}]" in text
+    dense = re.search(rf"f32\[(?:{held}|256),{rows},\d+\]", text)
+    assert not dense, f"the experts' rows as a dense batch: {dense.group(0)}"
+    assert text.count("tpu_custom_call") >= 9
+    assert f"f32[{held},{d},{f_}]" in text and f"f32[{held},{f_},{d}]" in text
+    wide_scatter = re.search(rf"f32\[\d+,(?:{d}|{f_})\]\S* scatter\(", text)
+    assert not wide_scatter, wide_scatter.group(0)
