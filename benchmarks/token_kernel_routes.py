@@ -14,13 +14,29 @@ sequence of 4096 tokens in a bucket of 4160 nodes; 8 key-value heads of 128):
                 of which 4096 are live in 32 groups of 128 (uniform routing),
                 for the up (2048 -> 512) and the down (512 -> 2048)
                 projection, at row tiles 128 / 256 / 512
+    routed      ``RoutedFFN`` alone (router, top-k, sort, row moves, grouped
+                matmuls), forward and forward + backward, at BOTH token
+                cells' shapes (4160 nodes of 2048; K 8, 32 of 256 held, 512
+                wide; K 4, 8 of 32 held, 1792 wide): one pass over ``[K N, .]``
+                row arrays (``capacity`` = K N) against the layer as it sizes
+                them itself (``[C, .]``), with the router steered so that
+                exactly ``live`` assignments reach held experts: half the
+                uniform share, the share, ``C``, and ``C + 1`` (a second
+                pass)
+    way back    the compact rows' way back to node-major order and the
+                weighted sum over a node's K rows, alone: a gather of K N
+                rows out of ``[C, 2048]`` (one gather, or K of N rows) against
+                the scatter-add of the C rows that the layer does. Forward
+                only: either way the backward is one gather of C rows, and
+                the backward of the way IN is this same operation
 
 A time is the wall clock round ``REPEATS`` calls ended by
 ``block_until_ready``, the least of ``ROUNDS``. Refuses to run anywhere but on
 a TPU. Prints one JSON line a route and writes the table to ``chiprun_out/``:
 
-    python3 benchmarks/token_kernel_routes.py
+    python3 benchmarks/token_kernel_routes.py [attention_rows | expert_rows | routed_layer | way_back ...]
 
+(every table unless some are named).
 ``--rehearse-on-cpu`` walks the same code at a small size through the arms a
 CPU takes and writes nothing: it finds wrong arguments, and its times mean
 nothing.
@@ -110,6 +126,97 @@ def expert_rows(rows, live, groups, d, f):
             }
 
 
+def steered_layer(n, d, k, held, experts, f, live):
+    """(layer, params, x, mask): a ``RoutedFFN`` whose router sends exactly
+    ``live`` assignments to held experts, evenly over them: the gate reads
+    expert ``e``'s score off column ``e`` of ``x``, where a node's chosen
+    experts stand out."""
+    cfg = lfm2.LFM2Config(
+        layer_types=("conv",), num_dense_layers=0, intermediate_size=4 * f,
+        moe_intermediate_size=f, num_experts=experts, num_experts_per_tok=k,
+        num_experts_held=held, experts_offset=0, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, vocab_size=64, token_minmax=(0.0, 63.0),
+    )
+    layer = lfm2.RoutedFFN(d, cfg)
+    real = n - 64  # the bucket's padding nodes
+    mask = np.arange(n) < real
+    rng = np.random.default_rng(2)
+    x = 0.01 * rng.normal(size=(n, d)).astype(np.float32)
+    to_held = np.full(real, live // real) + (np.arange(real) < live % real)
+    assert to_held.max() <= min(k, held) and k - to_held.min() <= experts - held
+    for i in range(real):
+        m = to_held[i]
+        chosen = [(i * m + j) % held for j in range(m)]
+        chosen += [held + (i * k + j) % (experts - held) for j in range(k - m)]
+        x[i, chosen] = 4.0
+    # 64 nodes: on the TPU the K N rows of even this call are whole row tiles.
+    params = layer.init(jax.random.PRNGKey(0), jnp.zeros((64, d)), jnp.ones((64,), bool))
+    gate = np.zeros((d, experts), np.float32)
+    gate[np.arange(experts), np.arange(experts)] = 1.0
+    params = {"params": dict(params["params"], gate=jnp.asarray(gate))}
+    return layer, params, jnp.asarray(x), jnp.asarray(mask)
+
+
+def routed_layer(n, d, shapes):
+    for cell, k, held, experts, f in shapes:
+        cap = lfm2._capacity(n * k, held, experts)
+        share = n * k * held // experts
+        for live in (share // 2, share, cap, cap + 1):
+            layer, params, x, mask = steered_layer(n, d, k, held, experts, f, live)
+            for path, capacity in (("every row", (n * k,)), ("as the layer sizes them", ())):
+
+                def fwd(params, x):
+                    y, sown = layer.apply(
+                        params, x, mask, *capacity, mutable=[lfm2.INTERMEDIATES]
+                    )
+                    return y, sown[lfm2.INTERMEDIATES]
+
+                def loss(params, x):
+                    return (fwd(params, x)[0] ** 2).sum()
+
+                counted = jax.jit(fwd)(params, x)[1]
+                assert int(counted["moe_rows_held"][0]) == live
+                yield {
+                    "what": f"routed layer {cell}, {path}", "rows": n * k, "cap": cap,
+                    "live": live, "one_compact_pass": int(counted["moe_layers_compact"][0]),
+                    "fwd_ms": time_ms(jax.jit(lambda p, x: fwd(p, x)[0]), params, x),
+                    "fwd_bwd_ms": time_ms(jax.jit(jax.grad(loss, argnums=(0, 1))), params, x),
+                }
+
+
+def way_back(n, d, k, cap):
+    rng = np.random.default_rng(3)
+    out = jnp.asarray(rng.normal(size=(cap, d)), jnp.float32)
+    weight = jnp.asarray(rng.uniform(size=(n, k)), jnp.float32)
+    order = rng.permutation(n * k).astype(np.int32)
+    back = np.empty_like(order)
+    back[order] = np.arange(n * k, dtype=np.int32)
+    forth, back = jnp.asarray(order[:cap]), jnp.asarray(back)
+
+    def gather(out, weight):
+        rows = jnp.take(out, back, axis=0, mode="fill", fill_value=0).reshape(n, k, d)
+        return jnp.sum(rows * weight[:, :, None], axis=1)
+
+    def gather_by_slot(out, weight):
+        rows = jnp.concatenate([out, jnp.zeros((1, d), out.dtype)])
+        place = jnp.minimum(back, cap).reshape(n, k)
+        return sum(rows[place[:, j]] * weight[:, j, None] for j in range(k))
+
+    def scatter_add(out, weight):
+        rows = out * weight.reshape(-1)[forth][:, None]
+        return jnp.zeros((n, d), out.dtype).at[forth // k].add(rows)
+
+    for name, fn in (
+        (f"one gather of {n * k} rows", gather),
+        (f"{k} gathers of {n} rows", gather_by_slot),
+        (f"scatter-add of {cap} rows", scatter_add),
+    ):
+        yield {
+            "what": f"way back, K {k}: {name}", "rows": n * k, "cap": cap,
+            "fwd_ms": time_ms(jax.jit(fn), out, weight),
+        }
+
+
 def main() -> int:
     rehearsal = "--rehearse-on-cpu" in sys.argv[1:]
     device = jax.devices()[0]
@@ -120,8 +227,17 @@ def main() -> int:
     shapes = (
         (attention_rows, (640, 2, 16, 128, True) if rehearsal else (4160, 8, 128, 512, False)),
         (expert_rows, (1024, 256, 4, 64, 32) if rehearsal else (33280, 4096, 32, 2048, 512)),
+        (routed_layer, (
+            (320, 64, (("laguna", 8, 4, 32, 16), ("lfm2", 4, 4, 16, 24))) if rehearsal
+            else (4160, 2048, (("laguna", 8, 32, 256, 512), ("lfm2", 4, 8, 32, 1792)))
+        )),
+        (way_back, (320, 64, 8, 512) if rehearsal else (4160, 2048, 8, 6400)),
+        (way_back, (320, 64, 4, 512) if rehearsal else (4160, 2048, 4, 6400)),
     )
+    named = [a for a in sys.argv[1:] if not a.startswith("--")]
     for rows, args in shapes:
+        if named and rows.__name__ not in named:
+            continue
         for row in rows(*args):
             row["device"] = device.device_kind
             print(json.dumps(row), flush=True)

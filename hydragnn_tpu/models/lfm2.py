@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Tuple
 
 import jax
@@ -56,7 +57,7 @@ ATTN_BLOCK = 512
 # collection; a no-op in every program that does not), and the step's
 # counters (asked for by the train step, train/trainer.py).
 INTERMEDIATES = "intermediates"
-COUNTERS = ("moe_rows_held", "moe_load_max", "moe_load_min")
+COUNTERS = ("moe_rows_held", "moe_load_max", "moe_load_min", "moe_layers_compact")
 
 
 def missing_fields(cls, arch: dict) -> list:
@@ -362,8 +363,22 @@ class DenseFFN(nn.Module):
 
 _expert_init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
 # (rows, contraction, columns) tiles of the TPU's grouped-matmul kernel; the
-# row tile has to divide the K N rows of a bucket (multiples of 256).
+# row tile has to divide a row array's rows: ``_capacity`` is a multiple of
+# it, and so are the K N rows of a bucket (multiples of 256) where a layer
+# holds every expert.
 GMM_TILING = (256, 1024, 1024)
+# Rows of the routed layer's compact path over the rank's uniform share
+# ``K N held / experts`` (``_capacity``).
+CAPACITY_FACTOR = 1.5
+
+
+def _capacity(assignments: int, held: int, experts: int) -> int:
+    """Rows of the compact path for a layer that holds ``held`` of
+    ``experts``: its share of the ``assignments`` under uniform routing times
+    ``CAPACITY_FACTOR``, up to a whole row tile of the grouped matmul."""
+    tile = GMM_TILING[0]
+    share = assignments * held * CAPACITY_FACTOR / experts
+    return -(-math.ceil(share) // tile) * tile
 
 
 def _gmm_tiles(m: int, k: int, n: int):
@@ -414,44 +429,103 @@ def grouped_matmul(lhs, rhs, sizes):
     return jax.lax.ragged_dot(lhs, rhs, sizes)
 
 
-@jax.custom_vjp
-def _permute(rows, forth, back):
-    """``rows[forth]`` for a PERMUTATION ``forth`` whose inverse is ``back``:
-    the backward is the gather ``ct[back]``, not the scatter-add autodiff
-    would write (a scatter pays by the row on the TPU; PERF.md section 6,
-    PR 24)."""
-    return rows[forth]
+def _held_experts(x, w1, w3, w2, weight, order, sizes, start=0, *, cap: int):
+    """``sum over a node's K assignments of weight * SwiGLU_e(x)`` for the
+    assignments to held experts that stand at ``start .. start + cap`` of the
+    sorted order, over row arrays of ``cap`` rows. With ``cap = K N`` that is
+    every assignment. A pure function of arrays."""
+    n, d = x.shape
+    k = weight.shape[1]
+    if cap < n * k:
+        order = jax.lax.dynamic_slice(
+            jnp.pad(order, (0, -(n * k) % cap)), (start,), (cap,)
+        )
+        ends = jnp.cumsum(sizes) - start
+        sizes = jnp.clip(ends, 0, cap) - jnp.clip(ends - sizes, 0, cap)
+    # Zero outside the held groups, on the way in and (through the select's
+    # transpose) on the way back: what a grouped matmul leaves in rows of no
+    # group is its own business.
+    live = (jnp.arange(cap) < sizes.sum())[:, None]
+    node = order // k
+
+    def grouped(lhs, rhs):
+        return jnp.where(live, grouped_matmul(lhs, rhs, sizes), 0.0)
+
+    with jax.named_scope(scopes.MOE_ROUTE):
+        rows = jnp.where(live, x[node], 0.0)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        hidden = nn.silu(grouped(rows, w1)) * grouped(rows, w3)
+        out = grouped(hidden, w2)
+    with jax.named_scope(scopes.MOE_ROUTE):
+        out = out * weight.reshape(-1)[order][:, None]
+        return jnp.zeros_like(x).at[node].add(out)
 
 
-def _permute_fwd(rows, forth, back):
-    return rows[forth], (forth, back)
+_FLOATS = 5  # x, w1, w3, w2, weight lead the operands; order and sizes end them
 
 
-def _permute_bwd(residuals, ct):
-    _, back = residuals
-    return ct[back], None, None
+def _further_passes(cap: int, operands, first, one_pass):
+    """``first`` plus ``one_pass(start)`` for every further ``cap`` sorted
+    rows the live rows reach into: none on a step whose live rows fit in
+    ``cap``, and no loop at all where ``cap`` is every row."""
+    weight, sizes = operands[_FLOATS - 1], operands[-1]
+    if cap >= weight.size:
+        return first
+
+    def one_more(carry):
+        start, total = carry
+        return start + cap, jax.tree_util.tree_map(jnp.add, total, one_pass(start))
+
+    if not isinstance(sizes, jax.core.Tracer):
+        # Run eagerly (the initializer): the live rows are known, and a
+        # ``while`` would be compiled a layer for passes that are never made
+        # (a second each on the TPU, too short for the persistent cache).
+        carry, live = (cap, first), sizes.sum()
+        while carry[0] < live:
+            carry = one_more(carry)
+        return carry[1]
+    return jax.lax.while_loop(
+        lambda carry: carry[0] < sizes.sum(), one_more, (jnp.int32(cap), first)
+    )[1]
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+def _one_pass(cap: int, operands):
+    return lambda start: _held_experts(*operands, start, cap=cap)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _spread(x, forth, back, k: int):
-    """``repeat(x, k)[forth]`` without the repeat: node ``i``'s row at each of
-    its ``k`` places of the permuted order. Backward: ``ct[back]`` is
-    node-major again, and a node's ``k`` rows are summed."""
-    return x[forth // k]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _in_passes(cap: int, *operands):
+    """``_held_experts`` over ``cap`` sorted rows at a time until the live
+    rows are through: ONE pass on a step whose live rows fit in ``cap``, as
+    many more as a step that overflows needs, each adding its part of the
+    nodes' sums. Differentiated by hand, because a loop of unknown length has
+    no reverse mode: the first pass keeps what its backward needs, as any
+    straight-line code; a further pass keeps nothing, and its backward runs
+    its forward again."""
+    one_pass = _one_pass(cap, operands)
+    return _further_passes(cap, operands, one_pass(0), one_pass)
 
 
-def _spread_fwd(x, forth, back, k):
-    return x[forth // k], back
+def _in_passes_fwd(cap, *operands):
+    y, pullback = jax.vjp(functools.partial(_held_experts, cap=cap), *operands)
+    return _further_passes(cap, operands, y, _one_pass(cap, operands)), (operands, pullback)
 
 
-def _spread_bwd(k, back, ct):
-    return ct[back].reshape(-1, k, ct.shape[-1]).sum(axis=1), None, None
+def _in_passes_bwd(cap, residuals, ct):
+    operands, pullback = residuals
+
+    def again(start):
+        _, pullback = jax.vjp(
+            lambda *floats: _held_experts(*floats, *operands[_FLOATS:], start, cap=cap),
+            *operands[:_FLOATS],
+        )
+        return pullback(ct)
+
+    grads = _further_passes(cap, operands, pullback(ct)[:_FLOATS], again)
+    return (*grads, None, None)
 
 
-_spread.defvjp(_spread_fwd, _spread_bwd)
+_in_passes.defvjp(_in_passes_fwd, _in_passes_bwd)
 
 
 class RoutedFFN(nn.Module):
@@ -459,22 +533,32 @@ class RoutedFFN(nn.Module):
     ``num_experts_per_tok`` largest of ``s + b`` are chosen (``b`` the expert
     bias: a buffer, no gradient); ``w_e = s_e / (sum over the chosen + 1e-6)``
     times ``routed_scaling_factor``, the sum over ALL chosen, held or not;
-    ``y = sum over the chosen AND held of w_e SwiGLU_e(x)``. Dropless.
+    ``y = sum over the chosen AND held of w_e SwiGLU_e(x)``. Dropless,
+    compact with a fall-back.
 
     The ``K N`` assignments (node-major: node ``i``'s are rows ``K i ..``)
     are sorted by expert (stable), the held experts' rows first and, in ONE
     trailing group that is never multiplied, the assignments to absent
-    experts and those of padding nodes. One grouped matmul a projection over
-    the held experts' rows, then the rows go back to node-major order and
-    each node sums its ``K`` rows by their weights. Both moves are row
-    permutations: gathers forward AND backward. Static shapes: the row
-    arrays are ``[K N, ·]`` whatever the routing."""
+    experts and those of padding nodes. The held rows are gathered from
+    their nodes, multiplied by one grouped matmul a projection, weighted, and
+    added into their nodes' rows again (``_held_experts``).
+
+    Static shapes, sized by what this rank can be sent and not by every
+    assignment: the row arrays are ``[C, ·]``, ``C`` the rank's share of the
+    ``K N`` assignments under uniform routing times ``CAPACITY_FACTOR``
+    (``_capacity``; ``capacity`` overrides it: the tests' handle). A step
+    whose routing sends the layer more than ``C`` rows falls back on further
+    passes over the next ``C`` sorted rows until every live row has met its
+    expert (``_in_passes``): no assignment is dropped, clipped or re-routed,
+    and no ``[K N, ·]`` array exists on either path. A layer with
+    ``C >= K N`` (one that holds every expert; tiny inputs) makes its one
+    pass over all ``K N`` rows and compiles no loop."""
 
     features: int
     cfg: Any  # LFM2Config, or another stack's with the same routing fields
 
     @nn.compact
-    def __call__(self, x, node_mask):
+    def __call__(self, x, node_mask, capacity=None):
         c = self.cfg
         n, d = x.shape
         experts, k, held, f = (
@@ -507,30 +591,18 @@ class RoutedFFN(nn.Module):
             here = (local >= 0) & (local < held) & node_mask[:, None]
             group = jnp.where(here, local, held).reshape(-1)  # [K N]
             order = jnp.argsort(group, stable=True)  # expert order <- node-major
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(n * k, dtype=order.dtype), unique_indices=True
-            )
             sizes = (group[:, None] == jnp.arange(held)[None, :]).sum(
                 axis=0, dtype=jnp.int32
             )
-            held_row = (jnp.arange(n * k) < sizes.sum())[:, None]
-            # Zero outside the held groups, on the way in and (through the
-            # select's transpose) on the way back: what a grouped matmul
-            # leaves in rows of no group is its own business.
-            rows = jnp.where(held_row, _spread(x, order, back, k), 0.0)
+            cap = _capacity(n * k, held, experts) if capacity is None else capacity
+            cap = min(cap, n * k)
+            y = _in_passes(cap, x, w1, w3, w2, weight, order, sizes)
+            compact = (sizes.sum() <= cap) & (cap < n * k)
         self.sow(INTERMEDIATES, "moe_chosen", chosen)
-        for name, value in zip(COUNTERS, (sizes.sum(), sizes.max(), sizes.min())):
+        counted = (sizes.sum(), sizes.max(), sizes.min(), compact)
+        for name, value in zip(COUNTERS, counted):
             self.sow(INTERMEDIATES, name, value.astype(jnp.float32))
-
-        def grouped(lhs, rhs):
-            return jnp.where(held_row, grouped_matmul(lhs, rhs, sizes), 0.0)
-
-        with jax.named_scope(scopes.MOE_EXPERTS):
-            hidden = nn.silu(grouped(rows, w1)) * grouped(rows, w3)
-            out = grouped(hidden, w2)
-        with jax.named_scope(scopes.MOE_ROUTE):
-            out = _permute(out, back, order).reshape(n, k, d)
-            return jnp.sum(out * weight[:, :, None], axis=1)
+        return y
 
 
 class LFM2Block(nn.Module):

@@ -31,7 +31,9 @@ from hydragnn_tpu.models import laguna, lfm2  # noqa: E402
 from hydragnn_tpu.models.loss import multihead_rmse_loss  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
-from tests.test_lfm2 import _collate, _forward, _rows, _sequences  # noqa: E402
+from tests.test_lfm2 import (  # noqa: E402
+    _collate, _forward, _rows, _sequences, assert_bit_equal,
+)
 
 V, D, LAYERS = sibling.V, 32, 5  # the sibling's sequences: ids under its V
 CONFIG = os.path.join(REPO, "graftbench", "configs", "laguna_xs2_ep8.json")
@@ -398,10 +400,60 @@ def pytest_padding_changes_nothing_and_every_gradient_is_finite(setup):
         assert np.isfinite(np.asarray(g)).all(), path
 
 
+@pytest.mark.parametrize("where", ["rematerialized_blocks", "scanned_epoch"])
+def pytest_compact_row_arrays_inside_the_model(where, monkeypatch):
+    """At 170 tokens the four routed layers size their row arrays at 256 of
+    352 rows and one pass takes every live row: under ``nn.remat`` (the
+    cell's blocks) loss and every gradient, and inside a scanned epoch of two
+    steps the parameters AdamW leaves, are bit-equal at another capacity
+    (320), and the loss equals to rounding what one pass over all ``K N`` rows
+    gives (a capacity out of reach: no loop is compiled); the counter reads
+    one a routed layer and step, and none there."""
+    from hydragnn_tpu.train.trainer import (
+        _loss_and_metrics, create_train_state, make_train_epoch_scan,
+    )
+    from hydragnn_tpu.utils.optimizer import select_optimizer
+
+    model = _model(remat=True)
+    batch = _collate(_sequences((60, 70, 40)))
+    rows = batch.node_features.shape[0] * ARCH["num_experts_per_tok"]
+    assert lfm2._capacity(rows, 4, 16) == 256 < 320 < rows
+    variables = shaken(init_model_variables(model, batch), 35)
+    opt = select_optimizer("AdamW", 1e-3)
+
+    def run():
+        if where == "rematerialized_blocks":
+            (loss, aux), grads = jax.jit(jax.value_and_grad(
+                lambda p: _loss_and_metrics(
+                    model, p, {}, batch, jax.random.PRNGKey(0), counters=True
+                ), has_aux=True,
+            ))(variables["params"])
+            return (loss, grads), aux[2], 1
+        state = create_train_state(model, variables, opt)
+        stacked = jax.tree_util.tree_map(lambda a: jnp.stack([a, a]), batch)
+        state, metrics = make_train_epoch_scan(model, opt, donate=False)(
+            state, stacked, jax.random.PRNGKey(0)
+        )
+        return (metrics["loss"], state.params), metrics, 2
+
+    got, counted, steps = run()
+    monkeypatch.setattr(lfm2, "_capacity", lambda *_: 320)
+    wider, counted_wider, _ = run()
+    monkeypatch.setattr(lfm2, "_capacity", lambda *_: rows)
+    every_row, counted_every_row, _ = run()
+    passes = len(ROUTED) * steps
+    assert float(counted["moe_layers_compact"]) == passes
+    assert float(counted_wider["moe_layers_compact"]) == passes
+    assert float(counted_every_row["moe_layers_compact"]) == 0
+    assert float(counted["moe_rows_held"]) == float(counted_every_row["moe_rows_held"]) > 0
+    assert_bit_equal(got, wider)
+    assert abs(float(got[0]) - float(every_row[0])) <= 1e-6 * abs(float(every_row[0]))
+
+
 def pytest_train_step_scopes_counters_and_other_families_untouched():
     """The compiled train step (rematerialized, as the cell's) carries both
     attention scopes and the routed layers' under the root, forward and
-    backward, and nothing outside the vocabulary; its metrics hold the three
+    backward, and nothing outside the vocabulary; its metrics hold the four
     counters. LFM2's step opens neither new scope; a classic family's has no
     counters."""
     import re

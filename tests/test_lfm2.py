@@ -6,8 +6,9 @@ gradients with the routing taken from the program and held to the margins;
 the four shares of a routed layer adding up to the uncut layer; no mixing
 across a graph boundary in a packed, padded batch of unequal lengths; padding
 nodes routed nowhere and every gradient finite; token ids exact over the
-whole slice; the scopes and counters; the family through ``run_training``.
-Values and counts, never a time."""
+whole slice; the scopes and counters; the routed layer's compact ``[C, .]``
+path against its ``[K N, .]`` fall-back, bit for bit; the family through
+``run_training``. Values and counts, never a time."""
 
 import copy
 import json
@@ -303,9 +304,134 @@ def pytest_cross_entropy_by_hand_and_rmse_untouched():
     assert float(plain_rmse[0]) == float(named[0])
 
 
+def steered_layer(k, held, experts, offset, to_held, one_expert=None, d=None, f=16):
+    """(layer, params, x): a ``RoutedFFN`` (``k`` a token, ``held`` of
+    ``experts`` from ``offset``) whose router sends node ``i`` to
+    ``to_held[i]`` held experts (to ``one_expert`` if given) and to absent
+    ones for the rest: the gate reads expert ``e``'s score off column ``e`` of
+    ``x``, where a node's chosen experts stand out of the noise."""
+    d = d or experts + 32
+    cfg = lfm2.LFM2Config.from_arch(dict(
+        ARCH, layer_types=["conv"], num_dense_layers=0, moe_intermediate_size=f,
+        num_experts=experts, num_experts_per_tok=k, num_experts_held=held,
+        experts_offset=offset,
+    ), 1)
+    layer = lfm2.RoutedFFN(d, cfg)
+    rng = np.random.default_rng(5)
+    n = len(to_held)
+    x = 0.1 * rng.normal(size=(n, d)).astype(np.float32)
+    absent = [e for e in range(experts) if not offset <= e < offset + held]
+    for i, m in enumerate(to_held):
+        here = [offset + (i * m + j) % held for j in range(m)]
+        if one_expert is not None and m:
+            here = [one_expert]
+        away = [absent[(i * k + j) % len(absent)] for j in range(k - m)]
+        x[i, here + away] = 4.0
+    params = layer.init(jax.random.PRNGKey(0), jnp.zeros((8, d)), jnp.ones((8,), bool))
+    gate = 0.01 * rng.normal(size=(d, experts)).astype(np.float32)
+    gate[np.arange(experts), np.arange(experts)] = 1.0
+    params = {"params": dict(params["params"], gate=jnp.asarray(gate))}
+    return layer, params, jnp.asarray(x)
+
+
+def at_capacity(layer, params, x, mask, capacity):
+    """(output, gradients by parameter and by the input, counters) of the
+    layer with row arrays of ``capacity`` rows."""
+    def loss(params, x):
+        y, sown = layer.apply(params, x, mask, capacity, mutable=[lfm2.INTERMEDIATES])
+        return (y * jnp.cos(y)).sum(), (y, sown[lfm2.INTERMEDIATES])
+
+    (_, (y, sown)), grads = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+    )(params, x)
+    return y, grads, {name: float(sown[name][0]) for name in lfm2.COUNTERS}
+
+
+def assert_equal_to_rounding(got, want, rel=4e-7):
+    """Float32 sums of the same terms in another order."""
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)
+    ):
+        a, b = np.asarray(a), np.asarray(b)
+        scale = float(np.abs(b).max()) if b.size else 0.0
+        assert a.shape == b.shape, path
+        assert float(np.abs(a - b).max(initial=0.0)) <= rel * (scale or 1.0), path
+
+
+def assert_bit_equal(got, want):
+    assert_equal_to_rounding(got, want, rel=0.0)
+
+
+# (k, held, experts, offset, nodes, padding nodes where a case has them): the
+# two token cells' routing at a fortieth of their nodes; the rank's uniform
+# share is 96 / 128 rows of 384 / 1024.
+ROUTINGS = {
+    "k4_8_of_32": (4, 8, 32, 8, 96, 24), "k8_32_of_256": (8, 32, 256, 0, 128, 64),
+}
+# name: (held experts a node, the capacity less the live rows, passes).
+# Every capacity is a multiple of 16 as the layer's own is of its row tile,
+# and every expert's rows are 12, 18 or 24 (K 4) or a power of two (K 8): the
+# CPU's ``ragged_dot`` sums a weight gradient's rows in an order that follows
+# the row count of the WHOLE array otherwise (1 ulp; the TPU's kernel sums by
+# row tile, and the tiles are the same at every capacity).
+COMPACT = {
+    "live_well_under_capacity": (1, 96, 1),
+    "live_equals_capacity": (2, 0, 1),
+    "one_row_over_takes_a_second_pass": (2, -1, 2),
+    "no_live_row_at_all": (0, 64, 1),
+    "every_assignment_to_one_held_expert": (1, 32, 1),
+    "padding_nodes_present": (2, 64, 1),
+}
+
+
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+@pytest.mark.parametrize("case", sorted(COMPACT))
+def pytest_compact_row_arrays_give_what_every_row_gives(routing, case):
+    """``RoutedFFN`` over ``[C, .]`` row arrays against the same layer over
+    ``[K N, .]`` (``capacity`` = K N: every assignment in one pass, no loop
+    compiled). Where the live rows fit in ``C`` the output and every
+    parameter's gradient are bit-equal, the input's gradient equal to the
+    last bit but one (XLA adds its two parts, the router's and the rows', in
+    another order round the loop), and all of them bit-equal between two
+    capacities; the counter reads 1. One row over, a second pass takes the
+    rest: nothing is dropped, sums of the same terms in another order, and
+    the counter reads 0."""
+    k, held, experts, offset, n, padding = ROUTINGS[routing]
+    a_node, room, passes = COMPACT[case]
+    real = n - padding if case == "padding_nodes_present" else n
+    mask = jnp.arange(n) < real
+    layer, params, x = steered_layer(
+        k, held, experts, offset, [a_node] * n,
+        one_expert=offset + 3 if "one_held_expert" in case else None,
+    )
+    live = a_node * real
+    capacity = live + room
+    assert capacity < n * k and (capacity % 16 == 0 or passes > 1)
+    y, grads, counted = at_capacity(layer, params, x, mask, capacity)
+    y_all, grads_all, counted_all = at_capacity(layer, params, x, mask, n * k)
+    assert counted["moe_rows_held"] == counted_all["moe_rows_held"] == live
+    assert counted["moe_layers_compact"] == int(passes == 1) == int(live <= capacity)
+    assert counted_all["moe_layers_compact"] == 0  # no compact path compiled
+    if "one_held_expert" in case:
+        assert counted["moe_load_max"] == live and counted["moe_load_min"] == 0
+    if passes == 1:
+        assert_bit_equal((y, grads[0]), (y_all, grads_all[0]))
+        wider = at_capacity(layer, params, x, mask, capacity + 48)
+        assert_bit_equal((y, grads), wider[:2])
+    assert_equal_to_rounding((y, grads), (y_all, grads_all))
+    # Run eagerly, as the initializer runs the layer, the passes are a Python
+    # loop over the live rows it can read: no ``while`` is compiled.
+    assert_equal_to_rounding(layer.apply(params, x, mask, capacity), y)
+    assert float(jnp.abs(y).max()) > 0 or live == 0
+    assert not np.asarray(y[real:]).any()  # padding nodes receive nothing
+    # The layer's own capacity: the rank's share times the factor, in tiles.
+    assert lfm2._capacity(n * k, held, experts) == 256
+    assert lfm2._capacity(33280, 32, 256) == lfm2._capacity(16640, 8, 32) == 6400
+
+
 def pytest_train_step_scopes_counters_and_other_families_untouched():
     """The compiled train step carries the four new scopes under the root and
-    nothing outside the vocabulary; its metrics hold the three counters. A
+    nothing outside the vocabulary; its metrics hold the four counters. A
     classic family's step has neither the collection nor the counters."""
     import re
 

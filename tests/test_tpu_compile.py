@@ -327,21 +327,29 @@ def pytest_lfm2_attention_at_cell_size_has_no_n_by_n_array(one_chip):
     assert f"[1,{h},{padded},{hd}]" in text  # the kernel's rows, per query head
 
 
-def pytest_lfm2_routed_experts_at_cell_size_are_grouped_matmuls(one_chip):
-    """One ``RoutedFFN`` (8 of 32 experts held, 4 a token, SwiGLU 1792),
-    forward and backward, at the cell's 4160 nodes: the 16640 assignments are
-    multiplied as ragged groups by the grouped-matmul kernel (three
-    projections forward, six calls backward), never as a dense
-    ``[experts, rows, ·]`` array; the row arrays are ``[16640, ·]`` whatever
-    the routing (static shapes); and both row moves are gathers forward and
-    backward: no scatter of 2048-wide rows is compiled."""
-    from hydragnn_tpu.models.lfm2 import LFM2Config, RoutedFFN
+@pytest.mark.parametrize("cell", ["lfm2", "laguna"])
+def pytest_lfm2_routed_experts_at_cell_size_are_grouped_matmuls(one_chip, cell):
+    """One ``RoutedFFN``, forward and backward, at the 4160 nodes of the two
+    token cells: LFM2's (8 of 32 experts held, 4 a token, SwiGLU 1792) and
+    Laguna's (32 of 256, 8 a token, 512). Every row array is ``[6400, .]``
+    (the rank's uniform share of 4160 rows times 1.5, in row tiles): no array
+    of a row's width has the ``K N`` = 16640 / 33280 rows, in the straight
+    line or in the loops of a step that overflows, and no ``conditional``
+    holds a second program. The rows are multiplied as ragged groups by the
+    grouped-matmul kernel (three projections forward and six calls backward
+    in the straight line; three, and those nine, in the two loops' bodies),
+    never as a dense ``[experts, rows, .]`` array. The way in is a gather of
+    6400 rows, the way back (and the way in's backward) a scatter-add of
+    those 6400 rows into the ``[4160, 2048]`` nodes: no scatter of wider
+    updates is compiled (the decision: PERF.md section 6, PR 34)."""
+    from hydragnn_tpu.models.lfm2 import LFM2Config, RoutedFFN, _capacity
     from hydragnn_tpu.ops.segment import platform_override
 
-    n, d, f, held = 4160, 2048, 1792, 8
+    n, d = 4160, 2048
+    f, held, experts, k = (1792, 8, 32, 4) if cell == "lfm2" else (512, 32, 256, 8)
     cfg = LFM2Config(
         layer_types=("conv",), num_dense_layers=0, intermediate_size=7168,
-        moe_intermediate_size=f, num_experts=32, num_experts_per_tok=4,
+        moe_intermediate_size=f, num_experts=experts, num_experts_per_tok=k,
         num_experts_held=held, experts_offset=0, num_attention_heads=32,
         num_key_value_heads=8, head_dim=64, vocab_size=16384,
         token_minmax=(0.0, 16383.0),
@@ -366,13 +374,27 @@ def pytest_lfm2_routed_experts_at_cell_size_are_grouped_matmuls(one_chip):
         text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
             params, shaped((n, d)), shaped((n,), jnp.bool_)
         ).compile().as_text()
-    rows = 4 * n
-    assert f"f32[{rows},{d}]" in text and f"f32[{rows},{f}]" in text
-    dense = re.search(rf"f32\[(?:{held}|32),{rows},\d+\]", text)
+    rows, cap = k * n, _capacity(k * n, held, experts)
+    assert cap == 6400 and "conditional(" not in text
+    for width in (d, f):
+        assert re.search(rf"(?:f32|bf16)\[{cap},{width}\]", text)
+        wide = re.search(rf"(?:f32|bf16|pred)\[{rows},{width}\]", text)
+        assert not wide, f"a row array of every assignment: {wide.group(0)}"
+    assert f"f32[{n},{k},{d}]" not in text
+    dense = re.search(rf"f32\[(?:{held}|{experts}),(?:{rows}|{cap}),\d+\]", text)
     assert not dense, f"the experts' rows as a dense batch: {dense.group(0)}"
-    assert text.count("tpu_custom_call") >= 9
-    wide_scatter = re.search(rf"f32\[\d+,(?:{d}|{f})\]\S* scatter\(", text)
-    assert not wide_scatter, wide_scatter.group(0)
+    calls = sorted(
+        body.group(0).count("tpu_custom_call")
+        for body in re.finditer(r"(?ms)^(?:ENTRY )?%[\w.\-]+ \(.*?^}", text)
+        if "tpu_custom_call" in body.group(0)
+    )
+    assert calls == [3, 9, 9], calls  # a further pass forward; one backward; the entry
+    shapes = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\])", text))
+    updates = [
+        shapes[m.group(2)]
+        for m in re.finditer(r"= f32\[(\d+),\d+\]\S* scatter\(%\S+, %\S+, (%[\w.\-]+)\)", text)
+    ]
+    assert updates and set(updates) == {f"f32[{cap},{d}]"}, updates
 
 
 def pytest_laguna_window_layers_at_cell_size_call_the_band_kernel(one_chip):
@@ -437,17 +459,17 @@ def pytest_laguna_window_layers_at_cell_size_call_the_band_kernel(one_chip):
 def pytest_grouped_matmul_tiles_follow_the_weights(one_chip):
     """``RoutedFFN`` at the two cells' shapes after ``GMM_TILING`` follows the
     weights: Laguna's fine-grained layer (32 of 256 experts held, 8 a token,
-    SwiGLU 512 wide: ``[33280, .]`` row arrays) compiles, forward and
-    backward, to the grouped-matmul kernel's nine calls with no tile wider
-    than a matrix; LFM2's (``[16640, .]``, 1792 wide) still calls the same
-    kernel with the tiles it had."""
+    SwiGLU 512 wide: ``[6400, .]`` row arrays of 33280 assignments) compiles,
+    forward and backward, to the grouped-matmul kernel's calls with no tile
+    wider than a matrix; LFM2's (1792 wide) still calls the same kernel with
+    the tiles it had."""
     from hydragnn_tpu.models import laguna, lfm2
     from hydragnn_tpu.ops.segment import platform_override
 
-    assert lfm2._gmm_tiles(16640, 2048, 1792) == lfm2.GMM_TILING == (256, 1024, 1024)
-    assert lfm2._gmm_tiles(16640, 1792, 2048) == lfm2.GMM_TILING
-    assert lfm2._gmm_tiles(33280, 2048, 512) == (256, 1024, 512)
-    assert lfm2._gmm_tiles(33280, 512, 2048) == (256, 512, 1024)
+    assert lfm2._gmm_tiles(6400, 2048, 1792) == lfm2.GMM_TILING == (256, 1024, 1024)
+    assert lfm2._gmm_tiles(6400, 1792, 2048) == lfm2.GMM_TILING
+    assert lfm2._gmm_tiles(6400, 2048, 512) == (256, 1024, 512)
+    assert lfm2._gmm_tiles(6400, 512, 2048) == (256, 512, 1024)
     with open(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "graftbench", "configs", "laguna_xs2_ep8.json",
@@ -478,11 +500,9 @@ def pytest_grouped_matmul_tiles_follow_the_weights(one_chip):
         text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
             params, shaped((n, d)), shaped((n,), jnp.bool_)
         ).compile().as_text()
-    rows = k * n
+    rows = lfm2._capacity(k * n, held, 256)  # [6400, .], not every assignment's 33280
     assert f"f32[{rows},{d}]" in text and f"f32[{rows},{f_}]" in text
     dense = re.search(rf"f32\[(?:{held}|256),{rows},\d+\]", text)
     assert not dense, f"the experts' rows as a dense batch: {dense.group(0)}"
     assert text.count("tpu_custom_call") >= 9
     assert f"f32[{held},{d},{f_}]" in text and f"f32[{held},{f_},{d}]" in text
-    wide_scatter = re.search(rf"f32\[\d+,(?:{d}|{f_})\]\S* scatter\(", text)
-    assert not wide_scatter, wide_scatter.group(0)
