@@ -227,8 +227,7 @@ def build_production_pipeline(
     """ci_multihead.json (the north-star multi-task config) through the real
     pipeline: serialized dataset -> bucketed loader (2 shape buckets) ->
     config completion -> model -> TrainingDriver. ONE implementation shared
-    by the production workload below and benchmarks/profile_epoch.py, so the
-    profiler measures exactly the plumbing the benchmark times."""
+    by the production workloads below."""
     from hydragnn_tpu.models.create import create_model_config, init_model_variables
     from hydragnn_tpu.preprocess.load_data import dataset_loading_and_splitting
     from hydragnn_tpu.train.train_validate_test import TrainingDriver

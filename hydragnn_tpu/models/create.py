@@ -14,11 +14,13 @@ import numpy as np
 
 from ..graphs.batch import GraphBatch
 from ..graphs.collate import collate_graphs
+from ..telemetry import graftel as telemetry
 from .base import HydraGNN
 from .convs import pna_degree_averages
 from .loss import normalize_task_weights
 
 
+@telemetry.setup_phase("create_model")
 def create_model_config(
     config: Dict[str, Any], verbosity: int = 0, use_gpu: bool = True
 ) -> HydraGNN:
@@ -160,6 +162,7 @@ def create_model(
     )
 
 
+@telemetry.setup_phase("init_variables", until_ready=True)
 def init_model_variables(
     model: HydraGNN, example_batch: GraphBatch, seed: int = 0
 ) -> Dict[str, Any]:

@@ -13,6 +13,7 @@ import pickle
 from typing import Dict, Tuple
 
 from ..parallel.distributed import barrier, get_comm_size_and_rank
+from ..telemetry import graftel as telemetry
 from ..utils.time_utils import Timer
 from .dataloader import GraphDataLoader
 from .raw_loader import RawDataLoader
@@ -20,6 +21,7 @@ from .serialized_loader import SerializedDataLoader
 from .splitting import split_dataset
 
 
+@telemetry.setup_phase("load_data")
 def dataset_loading_and_splitting(config: Dict):
     # Streaming data plane (docs/DATA_PLANE.md): when every split path is a
     # GSHD dataset, nothing is materialized in host RAM — the loaders stream

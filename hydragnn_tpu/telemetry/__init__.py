@@ -46,15 +46,19 @@ from .graftel import (
     flight_dump,
     gauge,
     gauges_snapshot,
+    install_gc_hook,
     install_jax_hooks,
     jax_annotations,
+    jax_seconds,
     new_context,
     new_request_id,
     record_span,
     render_prometheus,
     reset,
+    setup_phase,
     snapshot_records,
     span,
+    span_totals,
     timer_credit,
     timer_totals,
 )
@@ -80,17 +84,21 @@ __all__ = [
     "flight_dump",
     "gauge",
     "gauges_snapshot",
+    "install_gc_hook",
     "install_jax_hooks",
     "jax_annotations",
+    "jax_seconds",
     "new_context",
     "new_request_id",
     "record_span",
     "render_prometheus",
     "reset",
     "scopes",
+    "setup_phase",
     "snapshot_records",
     "span",
     "span_counts",
+    "span_totals",
     "timer_credit",
     "timer_totals",
     "validate_chrome_trace",

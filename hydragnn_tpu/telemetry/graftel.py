@@ -10,7 +10,13 @@ is the hub they all emit into:
 * **Spans and events.** ``span(name, **attrs)`` is a context manager timing a
   wall-clock region; ``event(name, **attrs)`` records an instant. Both carry
   a :class:`Context` (trace id, span id, optional request correlation id) and
-  the emitting thread's name. Same-thread nesting rides a thread-local
+  the emitting thread's name. A span is also a RUNNING TOTAL: on exit its
+  seconds and one count go to ``span_s/<name>`` / ``span_n/<name>`` in the
+  registry, under the lock acquisition the record already makes, so where a
+  run's host seconds went is readable with collection off and after the ring
+  has turned over (``span_totals()``). A span keeps its seconds as
+  ``.dur_s``: the one clock pair of its region, which ``FeedStats`` and
+  ``Timer`` are credited from. Same-thread nesting rides a thread-local
   context stack; CROSS-thread propagation is explicit — a producer captures
   ``current()`` (or a span's ``.ctx``) and the consumer thread calls
   ``attach(ctx)`` (the DeviceFeed pipeline and the serve dispatcher do this),
@@ -22,9 +28,11 @@ is the hub they all emit into:
   ``<run_dir>/flightrec_<pid>_<seq>_<trigger>.json``. Wired triggers:
   non-finite step-guard trips (faults/guard.py), engine poisoning
   (serve/engine.py), checkpoint-fallback loads (checkpoint/io.py),
-  supervisor restarts (faults/supervisor.py), and elastic dirty-shrink
+  supervisor restarts (faults/supervisor.py), elastic dirty-shrink
   transitions (parallel/elastic.py — the timeline that led into a worker
-  death, next to the checkpoint the shrunk world resumed from).
+  death, next to the checkpoint the shrunk world resumed from), and a
+  stalled epoch (``epoch_stall``, train/train_validate_test.py: an epoch 1.5
+  times the median of those before it, with each span name's seconds in it).
 
 * **Metric registry.** ``counter``/``gauge``/``timer_credit`` feed one locked
   registry; ``Timer`` and ``FaultCounters`` delegate their storage here, so
@@ -34,17 +42,26 @@ is the hub they all emit into:
   (``hydragnn_train_*``) the epoch loop publishes.
 
 * **jax bridges.** ``install_jax_hooks()`` registers a monitoring listener
-  that folds every XLA backend compile into the registry
-  (``jax/compiles`` + ``jax/compile_s``) and the ring;
+  that folds JAX's own durations into the registry: every XLA backend
+  compile (``jax/compiles`` + ``jax/compile_s``, also a ring event; JAX
+  takes that duration round ``compile_or_get_cached``, so it HOLDS the
+  persistent cache's loads), tracing (``jax/trace_s``), lowering
+  (``jax/lower_s``) and the persistent cache's retrievals
+  (``jax/cache_loads`` + ``jax/cache_load_s``);
   ``configure(jax_annotations=True)`` makes every span also open a
   ``jax.profiler.TraceAnnotation`` so host spans line up with device ops in
   a captured Perfetto trace.
 
+* **The collector's pauses.** ``install_gc_hook()`` puts a ``gc.callbacks``
+  entry in: every collection's seconds go to ``host/gc_pause_s``, and one of
+  ``GC_RECORD_S`` (1 ms) or longer is a retroactive ``gc`` span
+  (``record_span``: generation and collected count as attributes, no
+  ``TraceAnnotation``, marked ``retro`` so that no reader takes it for a
+  phase its thread opened).
+
 Zero-surprise defaults: the ring and registry are always live (host-side,
-one uncontended lock acquisition per record — measured < 2% of a steady CPU
-train epoch by ``bench.py --trace``, a CPU count in ``TRACE_r06.json``; on the
-chip the traced-against-untraced distance of every benchmark cell is in
-PERF.md §6); full span COLLECTION for the JSONL /
+one uncontended lock acquisition per record; what it costs on the chip, on
+against off, is in PERF.md §6, PR 35); full span COLLECTION for the JSONL /
 Chrome-trace exporters is opt-in (``configure(collect=True)``, the
 ``Telemetry`` config block, or ``HYDRAGNN_TRACE=1``). ``enabled=False``
 silences span/event recording entirely while keeping the counter registry
@@ -53,6 +70,8 @@ silences span/event recording entirely while keeping the counter registry
 
 from __future__ import annotations
 
+import functools
+import gc
 import itertools
 import os
 import threading
@@ -88,6 +107,26 @@ _enabled = True  # guarded-by: _lock, dirty-reads(bool flag flipped only by conf
 _run_dir: Optional[str] = None  # guarded-by: _lock, dirty-reads(rebound only by configure(); a dump racing a reconfigure writes to the old run dir, which is correct for the events it holds)
 _jax_annotations = False  # guarded-by: _lock, dirty-reads(bool flag flipped only by configure(); a stale read annotates or skips one span)
 _jax_hooks_installed = False  # guarded-by: _lock
+
+# Registry keys of a span name's running totals (seconds, count).
+SPAN_SECONDS = "span_s/"
+SPAN_COUNT = "span_n/"
+# A collection this long or longer is a ``gc`` record, not only a count.
+GC_RECORD_S = 1e-3
+# JAX's own monitoring durations folded into counters beside jax/compile_s.
+_JAX_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JAX_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax/trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax/lower_s",
+    _JAX_BACKEND_COMPILE: "jax/compile_s",
+    _JAX_CACHE_LOAD: "jax/cache_load_s",
+}
+# Collections the gc callback saw, waiting to be folded in (``_fold_gc``). The
+# callback may run between any two bytecodes of a thread that HOLDS ``_lock``,
+# so it takes no lock: deque.append/popleft are single C calls.
+_gc_pending: "deque" = deque(maxlen=_RING_CAPACITY)  # guarded-by: none(deque.append/popleft are GIL-atomic; the callback must never take _lock: it can fire inside a critical section of its own thread)
+_gc_t0: Optional[float] = None  # guarded-by: none(the interpreter runs one collection at a time and both callback phases inside it)
 
 # Per-process trace id — every record of this process shares it, so merged
 # event logs from a supervised run's incarnations stay separable.
@@ -157,13 +196,24 @@ def _record(rec: dict) -> None:
         _ring.append(rec)
         if _collected is not None:
             _collected.append(rec)
+        if rec["kind"] == "span":
+            seconds, count = SPAN_SECONDS + rec["name"], SPAN_COUNT + rec["name"]
+            _counters[seconds] = _counters.get(seconds, 0.0) + rec["dur_s"]
+            _counters[count] = _counters.get(count, 0.0) + 1.0
+            tsan.shared_access("graftel.registry")
 
 
 class span:
-    """Timed region. Plain class (not contextlib) — it sits in per-batch hot
-    loops, so one small allocation per use, like pipeline.timed_consume."""
+    """Timed region. Plain class (not contextlib): it sits in per-batch hot
+    loops, so one small allocation per use. ``dur_s`` holds the region's
+    seconds once it has closed (None before), recording on or off: the
+    consumer loops credit ``FeedStats`` from it, so a region has ONE clock
+    pair."""
 
-    __slots__ = ("name", "attrs", "ctx", "_parent", "_t0", "_wall0", "_jax", "_off")
+    __slots__ = (
+        "name", "attrs", "ctx", "dur_s", "_parent", "_t0", "_wall0", "_jax",
+        "_off",
+    )
 
     def __init__(
         self,
@@ -182,15 +232,17 @@ class span:
             if request_id is not None
             else (parent.request_id if parent is not None else None),
         )
+        self.dur_s = None
         self._jax = None
         self._off = False
 
     def __enter__(self):
-        # Disabled fast path: no stack/clock/annotation work — the .ctx is
-        # still real (callers hand it to DeviceFeed regardless), but nothing
-        # records, so the bench A/B's disabled arm is a near-zero baseline.
+        # Disabled fast path: no stack/annotation work, only the clock (the
+        # .ctx is still real: callers hand it to DeviceFeed regardless), and
+        # nothing records.
         if not _enabled:
             self._off = True
+            self._t0 = time.perf_counter()
             return self
         parent = self._parent if self._parent is not None else current()
         if parent is not None and self.ctx.request_id is None and parent.request_id:
@@ -210,9 +262,9 @@ class span:
         return self
 
     def __exit__(self, *exc):
+        dur = self.dur_s = time.perf_counter() - self._t0
         if self._off:
             return
-        dur = time.perf_counter() - self._t0
         if self._jax is not None:
             self._jax.__exit__(*exc)
         st = _stack()
@@ -220,6 +272,7 @@ class span:
             st.pop()
         if not _enabled:
             return
+        _fold_gc()
         rec = {
             "kind": "span",
             "name": self.name,
@@ -242,22 +295,28 @@ def record_span(
     dur_s: float,
     parent: Optional[Context] = None,
     request_id: Optional[str] = None,
+    end_ts: Optional[float] = None,
+    thread: Optional[str] = None,
     **attrs: Any,
 ) -> None:
-    """Retroactive span for a region timed elsewhere (FeedStats' H2D wire
-    time is measured by its own perf_counter pair on the transfer thread)."""
+    """Retroactive span for a region timed elsewhere (a garbage collection,
+    timed by the interpreter's callback): it ended at ``end_ts`` (now) on
+    ``thread`` (this one). The record is marked ``retro``: it was never open
+    on its thread's stack and is no ``TraceAnnotation``, so it is nobody's
+    child phase; its seconds go to the running totals like any span's."""
     if not _enabled:
         return
     ctx = parent if parent is not None else current()
     rec = {
         "kind": "span",
         "name": name,
-        "ts": time.time() - dur_s,
+        "ts": (time.time() if end_ts is None else end_ts) - dur_s,
         "dur_s": float(dur_s),
-        "thread": threading.current_thread().name,
+        "thread": thread or threading.current_thread().name,
         "trace_id": _TRACE_ID,
         "span_id": _new_span_id(),
         "parent_id": ctx.span_id if ctx else None,
+        "retro": True,
     }
     rid = request_id or (ctx.request_id if ctx else None)
     if rid:
@@ -308,14 +367,36 @@ def gauge(name: str, value: float) -> None:
 
 
 def counter_value(name: str) -> float:
+    _fold_gc()
     with _lock:
         return _counters.get(name, 0.0)
 
 
 def counters_snapshot(prefix: str = "") -> Dict[str, float]:
+    _fold_gc()
     with _lock:
         return {
             k: v for k, v in _counters.items() if k.startswith(prefix)
+        }
+
+
+def span_totals() -> Dict[str, float]:
+    """{span name: seconds of its closed spans so far, all threads}: the
+    always-live account of where the host's wall went (a nested span's
+    seconds are in its parents' too). The difference of two copies is one
+    epoch's account (the stall detector of train_validate_test.py)."""
+    pre = len(SPAN_SECONDS)
+    return {k[pre:]: v for k, v in counters_snapshot(SPAN_SECONDS).items()}
+
+
+def jax_seconds() -> Dict[str, float]:
+    """The four cumulative ``jax/*_s`` counters under one lock acquisition,
+    keyed ``jax_trace_s`` ... : what each ``epoch`` span carries as
+    attributes, so a reader knows how they stood when the epoch opened."""
+    with _lock:
+        return {
+            key.replace("/", "_"): _counters.get(key, 0.0)
+            for key in _JAX_DURATIONS.values()
         }
 
 
@@ -342,12 +423,14 @@ def clear_counters(prefix: str) -> None:
 
 def snapshot_records() -> List[dict]:
     """Locked copy of the flight-recorder ring (newest last)."""
+    _fold_gc()
     with _lock:
         return list(_ring)
 
 
 def collected_records() -> List[dict]:
     """Locked copy of the export buffer ([] when collect mode is off)."""
+    _fold_gc()
     with _lock:
         return list(_collected) if _collected is not None else []
 
@@ -400,6 +483,7 @@ def reset(keep_config: bool = False) -> None:
     """Clear records + registry (tests). ``keep_config`` keeps run_dir /
     collect / enabled; the default restores module defaults."""
     global _collected, _run_dir, _enabled, _jax_annotations
+    _gc_pending.clear()
     with _lock:
         _ring.clear()
         _counters.clear()
@@ -426,6 +510,7 @@ def flight_dump(
     target = run_dir if run_dir is not None else _run_dir
     if not target:
         return None
+    _fold_gc()
     with _lock:
         _dump_seq += 1
         seq = _dump_seq
@@ -464,11 +549,16 @@ def flight_dump(
 
 # ------------------------------------------------------------------ jax hooks
 def install_jax_hooks() -> None:
-    """Fold XLA backend compiles into the registry + ring: one monitoring
-    event fires per real compile (the recompile sentinel's mechanism,
+    """Fold JAX's own monitoring durations into the registry: one event fires
+    per real XLA backend compile (the recompile sentinel's mechanism,
     analysis/sentinel.py), so ``jax/compiles`` / ``jax/compile_s`` track
-    compile count and seconds for ANY path — the training Prometheus compile
-    gauge reads the per-epoch delta. Idempotent."""
+    compile count and seconds for ANY path (the training Prometheus compile
+    gauge reads the per-epoch delta), and beside them the seconds of tracing
+    (``jax/trace_s``; a jit traced inside another's trace is in both), of
+    lowering to MLIR (``jax/lower_s``) and of retrieving executables from the
+    persistent cache (``jax/cache_loads`` / ``jax/cache_load_s``: JAX takes
+    the backend compile's duration round ``compile_or_get_cached``, so
+    ``jax/compile_s`` holds these seconds too). Idempotent."""
     global _jax_hooks_installed
     with _lock:
         if _jax_hooks_installed:
@@ -476,14 +566,91 @@ def install_jax_hooks() -> None:
         _jax_hooks_installed = True
     import jax
 
-    def _on_compile(name: str, duration: float, **kwargs) -> None:
-        if name != "/jax/core/compile/backend_compile_duration":
+    def _on_duration(name: str, duration: float, **kwargs) -> None:
+        key = _JAX_DURATIONS.get(name)
+        if key is None:
             return
-        counter("jax/compiles", 1.0)
-        counter("jax/compile_s", float(duration))
-        event("jax/compile", duration_s=round(float(duration), 4))
+        counter(key, float(duration))
+        if name == _JAX_BACKEND_COMPILE:
+            counter("jax/compiles", 1.0)
+            event("jax/compile", duration_s=round(float(duration), 4))
+        elif name == _JAX_CACHE_LOAD:
+            counter("jax/cache_loads", 1.0)
 
-    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def setup_phase(name: str, until_ready: bool = False):
+    """Decorator: every call of the function runs under the span
+    ``setup.<name>``; with ``until_ready`` the span closes when the result is
+    on the device, as a clock round ``jax.block_until_ready(fn(...))`` would
+    (the eager initializers: their seconds are the work, not its dispatch).
+    The set-up account is opened INSIDE the functions every
+    entry point calls before its first step (``run_training``, the
+    benchmark's drivers, a library user), so no caller needs a clock of its
+    own; whichever phase comes first installs the hooks, so the account is
+    whole for a caller that installs them late or never."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def in_phase(*args, **kwargs):
+            install_jax_hooks()
+            install_gc_hook()
+            with span("setup." + name):
+                result = fn(*args, **kwargs)
+                if until_ready:
+                    import jax
+
+                    result = jax.block_until_ready(result)
+                return result
+
+        return in_phase
+
+    return wrap
+
+
+# -------------------------------------------------------------------- gc hook
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    t0, _gc_t0 = _gc_t0, None
+    if t0 is not None:
+        _gc_pending.append((
+            time.time(), time.perf_counter() - t0, info.get("generation"),
+            info.get("collected"), threading.current_thread().name, current(),
+        ))
+
+
+def install_gc_hook() -> None:
+    """Time every garbage collection from ``gc.callbacks``: all of them add
+    to ``host/gc_pause_s`` (and ``host/gc_collections``), one of
+    ``GC_RECORD_S`` or longer is a ``gc`` record too. The callback only
+    queues; the registry's writers and readers fold the queue in.
+    Idempotent (two threads racing here could leave two entries: the second
+    finds no start time and queues nothing)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def _fold_gc() -> None:
+    """Book the queued collections. Called with ``_lock`` NOT held."""
+    pause, n = 0.0, 0
+    while _gc_pending:
+        try:
+            end_ts, dur, generation, collected, thread, ctx = _gc_pending.popleft()
+        except IndexError:  # another thread took the last one
+            break
+        pause, n = pause + dur, n + 1
+        if dur >= GC_RECORD_S:
+            record_span(
+                "gc", dur, parent=ctx, end_ts=end_ts, thread=thread,
+                generation=generation, collected=collected,
+            )
+    if n:
+        counter("host/gc_pause_s", pause)
+        counter("host/gc_collections", float(n))
 
 
 # ------------------------------------------------------------------ prom text
@@ -499,6 +666,7 @@ def render_prometheus(prefix: str = "hydragnn") -> str:
     where the TRAINING path's per-epoch step/h2d/compile gauges surface
     (docs/OBSERVABILITY.md catalogue). The serve front end appends this to
     its engine-scoped /metrics payload."""
+    _fold_gc()
     with _lock:
         counters = dict(_counters)
         gauges = dict(_gauges)

@@ -187,7 +187,9 @@ class FeedStats:
       transfer thread — overlapped with compute, so this is NOT a share of
       epoch wall time unless the pipeline is transfer-bound).
     - ``feed_wait_s``: consumer seconds blocked on the device queue — where
-      an input-bound pipeline actually stalls.
+      an input-bound pipeline actually stalls. Every consumer loop that pulls
+      from a ``DeviceFeed`` credits it: the per-step loop, ``evaluate`` and
+      the scan path's pull of its next chunk.
     - ``step_s``: consumer seconds in step dispatch + metrics readback (the
       readback blocks on the device computation, so this is compute-bound
       wall time).
@@ -218,8 +220,9 @@ class FeedStats:
         return idx
 
     def credit(self, field: str, seconds: float) -> None:
-        """Add consumer-side seconds to ``feed_wait_s``/``step_s`` (the
-        ``timed_consume`` sink — one locked add per region exit)."""
+        """Add consumer-side seconds to ``feed_wait_s``/``step_s``: the
+        ``dur_s`` of the region's graftel span (``feed_wait``, ``device_step``,
+        ``eval_step``), which is the region's one clock pair."""
         with self._lock:
             setattr(self, field, getattr(self, field) + seconds)
             tsan.shared_access("FeedStats.fields")
@@ -315,22 +318,3 @@ def traced_batches(iterable: Iterable, name: str = "collate"):
                 return
         yield b
         i += 1
-
-
-class timed_consume:
-    """Context manager crediting a wall-time region to a FeedStats field.
-    Plain class (not contextlib.contextmanager): it sits twice in the
-    per-batch consumer hot loop, so one small allocation per use."""
-
-    __slots__ = ("_stats", "_field", "_t0")
-
-    def __init__(self, stats: FeedStats, field: str):
-        self._stats = stats
-        self._field = field
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self._stats.credit(self._field, time.perf_counter() - self._t0)

@@ -10,8 +10,11 @@ and the TensorBoard writer actually works (model.py:50-54 quirk)."""
 from __future__ import annotations
 
 import contextlib
+import logging
 import os
+import statistics
 import time
+from collections import deque
 from typing import List, Optional
 
 import jax
@@ -31,7 +34,6 @@ from .pipeline import (  # noqa: F401  (_Prefetcher re-exported for compat)
     DeviceFeed,
     FeedStats,
     _Prefetcher,
-    timed_consume,
     traced_batches,
 )
 from .trainer import (
@@ -106,6 +108,103 @@ def _post_supervisor_heartbeat(epoch: Optional[int] = None) -> None:
         pass  # missed beat == the supervisor's deadline does its job
 
 
+# An epoch this many times the median of the epochs before it (at least
+# EPOCH_STALL_HISTORY of them), and at least EPOCH_STALL_MIN_S longer, is a
+# stall: it says so itself, profiler or none (``EpochAccount``).
+EPOCH_STALL_RATIO = 1.5
+EPOCH_STALL_HISTORY = 3
+EPOCH_STALL_MIN_S = 0.1
+# The leaf phases that partition an ``epoch`` span on the dispatching thread;
+# what they leave of its wall lay under no leaf. ``train_epoch`` and
+# ``evaluate`` contain them.
+EPOCH_LEAVES = (
+    "epoch_head", "feed_wait", "device_step", "feed_drain", "eval_step",
+    "epoch_tail",
+)
+_EPOCH_CONTAINERS = ("epoch", "train_epoch", "evaluate")
+NO_LEAF = "(no leaf)"
+EPOCHS_KEPT = 32
+_FEED_END = object()  # what a pull returns from an exhausted feed
+
+_log = logging.getLogger(__name__)
+
+
+class EpochAccount:
+    """The last epochs' ``epoch`` walls, and each span name's seconds in
+    them: the difference of graftel's running totals across the epoch, all
+    threads. Kept by the driver, so it lives across ``train_validate_test``
+    calls (a caller may run one epoch a call). An epoch far over the median
+    of those before it emits ``train/epoch_stall`` with where its seconds
+    went, dumps the flight recorder and logs one warning line."""
+
+    def __init__(self):
+        self.walls: deque = deque(maxlen=EPOCHS_KEPT)
+        self.seconds: deque = deque(maxlen=EPOCHS_KEPT)
+
+    def close(self, epoch: int, wall_s: float, before: dict) -> None:
+        """Book one epoch (``before``: ``telemetry.span_totals()`` as the
+        epoch opened), and report it if it stalled."""
+        after = telemetry.span_totals()
+        seconds = {
+            name: total - before.get(name, 0.0)
+            for name, total in after.items()
+            if total > before.get(name, 0.0)
+        }
+        seconds[NO_LEAF] = max(
+            wall_s - sum(seconds.get(name, 0.0) for name in EPOCH_LEAVES), 0.0
+        )
+        if len(self.walls) >= EPOCH_STALL_HISTORY:
+            median = statistics.median(self.walls)
+            if wall_s > EPOCH_STALL_RATIO * median and (
+                wall_s - median >= EPOCH_STALL_MIN_S
+            ):
+                self._report(epoch, wall_s, median, seconds)
+        self.walls.append(wall_s)
+        self.seconds.append(seconds)
+
+    def _report(self, epoch, wall_s, median, seconds) -> None:
+        usual = {
+            name: statistics.median(s.get(name, 0.0) for s in self.seconds)
+            for name in seconds
+        }
+        excess = sorted(
+            (
+                (seconds[name] - usual[name], name)
+                for name in seconds if name not in _EPOCH_CONTAINERS
+            ),
+            reverse=True,
+        )
+        # The leaves partition the dispatching thread's wall: the one that
+        # grew most is where that thread was; the other names say why.
+        phase = max(
+            EPOCH_LEAVES + (NO_LEAF,),
+            key=lambda name: seconds.get(name, 0.0) - usual.get(name, 0.0),
+        )
+        stall = dict(
+            epoch=epoch, wall_s=round(wall_s, 4), median_s=round(median, 4),
+            phase=phase, no_leaf_s=round(seconds[NO_LEAF], 4),
+            seconds={k: round(v, 4) for k, v in sorted(seconds.items())},
+            excess=[
+                [name, round(over, 4), round(usual[name], 4)]
+                for over, name in excess[:3]
+            ],
+        )
+        telemetry.counter("train/epoch_stalls")
+        telemetry.event("train/epoch_stall", **stall)
+        telemetry.flight_dump("epoch_stall", extra=stall)
+        _log.warning(
+            "epoch %d took %.3f s against a median of %.3f s; on the "
+            "dispatching thread the excess is in %s; largest excesses over "
+            "their own medians: %s; %.3f s under no leaf phase",
+            epoch, wall_s, median, phase,
+            ", ".join(
+                f"{name} +{over:.3f} s ({seconds[name]:.3f} against {usual[name]:.3f})"
+                for over, name in excess[:3]
+            ),
+            seconds[NO_LEAF],
+        )
+
+
 class EpochMetrics:
     """Graph-count-weighted averages accumulated over an epoch. The guarded
     step's extra ``bad`` metric is consumed by StepGuard (per step/chunk) and
@@ -141,6 +240,7 @@ class EpochMetrics:
 class TrainingDriver:
     """Owns the compiled steps + scheduler/profiler state for one model run."""
 
+    @telemetry.setup_phase("driver")
     def __init__(
         self,
         model: HydraGNN,
@@ -387,6 +487,9 @@ class TrainingDriver:
         # (train_epoch / evaluate): filled by the device-feed pipeline,
         # credited into the Timer registry, read by bench.py.
         self.feed_stats = FeedStats()
+        # The last epochs' walls and where their seconds went: says when an
+        # epoch stalled and in what (``train_validate_test`` books each one).
+        self.epoch_account = EpochAccount()
         # Batch structure -> NamedSharding tree. Written from the
         # transfer thread AND the main-thread eval path; safe without a
         # lock because it is an idempotent memo (the value for a key is
@@ -526,9 +629,25 @@ class TrainingDriver:
         in-flight transfer completing later must not record H2D into the
         next epoch's split (the join is bounded so a transfer wedged on a
         dead device link cannot hang the caller)."""
-        feed.close()
-        feed.join(2.0)
+        with telemetry.span("feed_drain"):
+            feed.close()
+            feed.join(2.0)
         self._credit_timers(label)
+
+    def _pulls(self, feed):
+        """Iterate a device feed with every blocking pull a ``feed_wait``
+        span, credited to ``FeedStats.feed_wait_s``: batch ACQUISITION (the
+        device-queue wait, where an input-bound pipeline actually stalls;
+        collation, the multi-host lift and the H2D transfer already happened
+        on the pipeline threads). The last one finds the feed exhausted."""
+        it = iter(feed)
+        while True:
+            with telemetry.span("feed_wait") as wait:
+                item = next(it, _FEED_END)
+            self.feed_stats.credit("feed_wait_s", wait.dur_s)
+            if item is _FEED_END:
+                return
+            yield item
 
     def _credit_timers(self, label: str):
         """Fold the epoch's split into the Timer registry (print_timers)."""
@@ -621,30 +740,18 @@ class TrainingDriver:
                 transfer=self._put_timed,
                 ctx=ep.ctx,
             )
-            batch_iter = iter(iterate_tqdm(batches, self.verbosity))
-            bi = 0
             try:
-                while True:
-                    # "feed_wait" covers batch ACQUISITION (the device-queue
-                    # wait — where an input-bound pipeline actually stalls);
-                    # collation, the multi-host lift, and the H2D transfer
-                    # all already happened on the pipeline threads.
-                    with telemetry.span("feed_wait"), timed_consume(
-                        self.feed_stats, "feed_wait_s"
-                    ):
-                        batch = next(batch_iter, None)
-                    if batch is None:
-                        break
-                    with telemetry.span(
-                        "device_step", index=bi
-                    ), timed_consume(self.feed_stats, "step_s"):
+                for bi, batch in enumerate(
+                    self._pulls(iterate_tqdm(batches, self.verbosity))
+                ):
+                    with telemetry.span("device_step", index=bi) as step:
                         self.state, m = self._dispatch(
                             "train_step", self.train_step,
                             self._dispatch_shape_key(batch),
                             self.state, batch, self.rng,
                         )
                         metrics.update(m)
-                    bi += 1
+                    self.feed_stats.credit("step_s", step.dur_s)
                     self._after_update(m)
                     if profiler:
                         profiler.step()
@@ -708,7 +815,7 @@ class TrainingDriver:
                     single, payload = cached["chunks"][ci]
                     with telemetry.span(
                         "device_step", index=int(ci), cached=True
-                    ), timed_consume(self.feed_stats, "step_s"):
+                    ) as step:
                         if single:
                             self.state, m = self._dispatch(
                                 "train_step", self.train_step,
@@ -729,6 +836,7 @@ class TrainingDriver:
                                 self.state, payload, perm, self.rng,
                             )
                         metrics.update(m)
+                    self.feed_stats.credit("step_s", step.dur_s)
                     self._after_update(m)
             cached["warm"] = True
             self._credit_timers("train")
@@ -758,7 +866,9 @@ class TrainingDriver:
             ctx=ctx,
         )
         try:
-            for ci, (single, payload) in enumerate(feed):
+            # The pull waits for a whole CHUNK: with scan_chunk batches or
+            # fewer of a shape in the epoch, for the loader to be exhausted.
+            for ci, (single, payload) in enumerate(self._pulls(feed)):
                 sink = self._run_scan_chunk(
                     single, payload, metrics, sink, index=ci
                 )
@@ -811,7 +921,7 @@ class TrainingDriver:
         first (timed) epoch's bookkeeping stays O(1) per chunk."""
         with telemetry.span(
             "device_step", index=index, chunk=not single
-        ), timed_consume(self.feed_stats, "step_s"):
+        ) as step:
             if single:
                 self.state, m = self._dispatch(
                     "train_step", self.train_step,
@@ -825,6 +935,7 @@ class TrainingDriver:
                     self.state, payload, self.rng,
                 )
             metrics.update(m)
+        self.feed_stats.credit("step_s", step.dur_s)
         self._after_update(m)
         if sink is not None:
             nbytes = self._tree_nbytes(payload)
@@ -836,11 +947,14 @@ class TrainingDriver:
         return sink
 
     # ------------------------------------------------------------------- eval
-    def evaluate(self, loader, return_values: bool = False):
+    def evaluate(
+        self, loader, return_values: bool = False, split: Optional[str] = None
+    ):
         """validate()/test() analog. With return_values, also gathers per-head
         (true, predicted) arrays over real rows (test(), reference
-        train_validate_test.py:267-304)."""
-        with telemetry.span("evaluate") as ep:
+        train_validate_test.py:267-304). ``split`` ("val", "test") only names
+        the pass on its ``evaluate`` span."""
+        with telemetry.span("evaluate", split=split) as ep:
             return self._evaluate(loader, return_values, ep.ctx)
 
     def _evaluate(self, loader, return_values, ctx=None):
@@ -889,13 +1003,14 @@ class TrainingDriver:
             for ei, (host_b, dev_b) in enumerate(cached["batches"]):
                 with telemetry.span(
                     "eval_step", index=ei, cached=True
-                ), timed_consume(self.feed_stats, "step_s"):
+                ) as step:
                     m, outputs = self._dispatch(
                         "eval_step", self.eval_step,
                         self._dispatch_shape_key(dev_b),
                         self.state, dev_b,
                     )
                     metrics.update(m)
+                self.feed_stats.credit("step_s", step.dur_s)
                 if return_values:
                     consume(host_b, outputs)
             self._credit_timers("eval")
@@ -917,26 +1032,16 @@ class TrainingDriver:
                 transfer=lambda b: (b, self._put_timed(b)),
                 ctx=ctx,
             )
-            batch_iter = iter(batches)
-            ei = 0
             try:
-                while True:
-                    with telemetry.span("feed_wait"), timed_consume(
-                        self.feed_stats, "feed_wait_s"
-                    ):
-                        item = next(batch_iter, None)
-                    if item is None:
-                        break
-                    batch, dev_b = item
-                    with telemetry.span(
-                        "eval_step", index=ei
-                    ), timed_consume(self.feed_stats, "step_s"):
+                for ei, (batch, dev_b) in enumerate(self._pulls(batches)):
+                    with telemetry.span("eval_step", index=ei) as step:
                         m, outputs = self._dispatch(
                             "eval_step", self.eval_step,
                             self._dispatch_shape_key(dev_b),
                             self.state, dev_b,
                         )
                         metrics.update(m)
+                    self.feed_stats.credit("step_s", step.dur_s)
                     if return_values:
                         consume(batch, outputs)
                     if sink is not None:
@@ -946,7 +1051,6 @@ class TrainingDriver:
                             sink["bytes"] += nbytes
                         else:
                             sink = None
-                    ei += 1
             finally:
                 self._drain_feed(batches, "eval")
             if cacheable:
@@ -995,7 +1099,9 @@ def train_validate_test(
     if visualizer is not None:
         visualizer.num_nodes_plot()
         if plot_init_solution:
-            _, _, tv, pv = driver.evaluate(test_loader, return_values=True)
+            _, _, tv, pv = driver.evaluate(
+                test_loader, return_values=True, split="test"
+            )
             visualizer.create_scatter_plots(
                 tv, pv, output_names=output_names, iepoch=-1
             )
@@ -1014,6 +1120,7 @@ def train_validate_test(
     # publishes its step/h2d/feed-wait/compile split as hydragnn_train_*
     # Prometheus gauges — the training analog of the serve /metrics surface.
     telemetry.install_jax_hooks()
+    telemetry.install_gc_hook()
     # Async checkpointing (docs/CHECKPOINTING.md): periodic saves snapshot
     # device→host on this thread and hand serialize/fsync/rename to a single
     # background writer — the epoch loop stalls for the snapshot only. The
@@ -1027,135 +1134,170 @@ def train_validate_test(
         checkpointer = AsyncCheckpointer()
     try:
         for epoch in range(start_epoch, num_epoch):
-            _start_supervisor_heartbeat_pump()
-            _post_supervisor_heartbeat(epoch)
-            for loader in (train_loader, val_loader, test_loader):
-                if hasattr(loader, "set_epoch"):
-                    loader.set_epoch(epoch)
-            if profiler:
-                profiler.set_current_epoch(epoch)
+            totals0 = telemetry.span_totals()
+            # The dispatching thread's epoch, partitioned into leaf phases
+            # (EPOCH_LEAVES; docs/OBSERVABILITY.md "The host's timeline").
+            # The span carries JAX's cumulative trace / lower / compile /
+            # cache-load seconds as they stood when it opened.
+            with telemetry.span(
+                "epoch", epoch=epoch, **telemetry.jax_seconds()
+            ) as epoch_span:
+                with telemetry.span("epoch_head"):
+                    _start_supervisor_heartbeat_pump()
+                    _post_supervisor_heartbeat(epoch)
+                    for loader in (train_loader, val_loader, test_loader):
+                        if hasattr(loader, "set_epoch"):
+                            loader.set_epoch(epoch)
+                    if profiler:
+                        profiler.set_current_epoch(epoch)
+                    compile_s0 = telemetry.counter_value("jax/compile_s")
+                    compiles0 = compile_count()
 
-            compile_s0 = telemetry.counter_value("jax/compile_s")
-            compiles0 = compile_count()
-            t_epoch0 = time.perf_counter()
-            train_loss, train_rmses = driver.train_epoch(train_loader, profiler)
-            train_wall_s = time.perf_counter() - t_epoch0
-            train_split = driver.feed_stats.as_dict()
-            val_loss, val_rmses = driver.evaluate(val_loader)
-            test_loss, test_rmses = driver.evaluate(test_loader)
-
-            # Per-epoch training gauges (rendered by telemetry.
-            # render_prometheus; served by /metrics in a co-resident serve
-            # process, dumped to logs/<name>/train_metrics.prom at run end).
-            telemetry.gauge("train/epoch", epoch)
-            telemetry.gauge("train/epoch_wall_s", round(train_wall_s, 4))
-            telemetry.gauge("train/step_s_per_epoch", train_split["step_s"])
-            telemetry.gauge("train/h2d_s_per_epoch", train_split["h2d_s"])
-            telemetry.gauge(
-                "train/h2d_mb_per_epoch",
-                round(train_split["h2d_bytes"] / (1 << 20), 4),
-            )
-            telemetry.gauge(
-                "train/feed_wait_s_per_epoch", train_split["feed_wait_s"]
-            )
-            telemetry.gauge(
-                "train/compile_s_epoch",
-                round(telemetry.counter_value("jax/compile_s") - compile_s0, 4),
-            )
-
-            if scheduler is not None:
-                current_lr = get_learning_rate(driver.state.opt_state)
-                # None = no injected LR knob (LBFGS: linesearch owns the step
-                # size) — the plateau scheduler has nothing to act on.
-                new_lr = (
-                    scheduler.step(val_loss, current_lr)
-                    if current_lr is not None
-                    else None
+                t_epoch0 = time.perf_counter()
+                train_loss, train_rmses = driver.train_epoch(
+                    train_loader, profiler
                 )
-                if new_lr is not None and new_lr != current_lr:
-                    driver.state = driver.state.replace(
-                        opt_state=set_learning_rate(driver.state.opt_state, new_lr)
+                train_wall_s = time.perf_counter() - t_epoch0
+                train_split = driver.feed_stats.as_dict()
+                val_loss, val_rmses = driver.evaluate(val_loader, split="val")
+                test_loss, test_rmses = driver.evaluate(
+                    test_loader, split="test"
+                )
+                if visualizer is not None and plot_hist_solution:
+                    _, _, tv, pv = driver.evaluate(
+                        test_loader, return_values=True, split="test"
                     )
+                    visualizer.create_scatter_plots(
+                        tv, pv, output_names=output_names, iepoch=epoch
+                    )
+
+                with telemetry.span("epoch_tail"):
+                    # Per-epoch training gauges (rendered by telemetry.
+                    # render_prometheus; served by /metrics in a co-resident
+                    # serve process, dumped to logs/<name>/train_metrics.prom
+                    # at run end).
+                    telemetry.gauge("train/epoch", epoch)
+                    telemetry.gauge(
+                        "train/epoch_wall_s", round(train_wall_s, 4)
+                    )
+                    telemetry.gauge(
+                        "train/step_s_per_epoch", train_split["step_s"]
+                    )
+                    telemetry.gauge(
+                        "train/h2d_s_per_epoch", train_split["h2d_s"]
+                    )
+                    telemetry.gauge(
+                        "train/h2d_mb_per_epoch",
+                        round(train_split["h2d_bytes"] / (1 << 20), 4),
+                    )
+                    telemetry.gauge(
+                        "train/feed_wait_s_per_epoch", train_split["feed_wait_s"]
+                    )
+                    telemetry.gauge(
+                        "train/compile_s_epoch",
+                        round(
+                            telemetry.counter_value("jax/compile_s")
+                            - compile_s0,
+                            4,
+                        ),
+                    )
+
+                    if scheduler is not None:
+                        current_lr = get_learning_rate(driver.state.opt_state)
+                        # None = no injected LR knob (LBFGS: linesearch owns
+                        # the step size) — the plateau scheduler has nothing
+                        # to act on.
+                        new_lr = (
+                            scheduler.step(val_loss, current_lr)
+                            if current_lr is not None
+                            else None
+                        )
+                        if new_lr is not None and new_lr != current_lr:
+                            driver.state = driver.state.replace(
+                                opt_state=set_learning_rate(
+                                    driver.state.opt_state, new_lr
+                                )
+                            )
+                            print_distributed(
+                                verbosity,
+                                f"Epoch {epoch}: learning rate reduced to {new_lr}",
+                            )
+
+                    if writer is not None:
+                        writer.add_scalar("train error", train_loss, epoch)
+                        writer.add_scalar("validate error", val_loss, epoch)
+                        writer.add_scalar("test error", test_loss, epoch)
+                        for ivar, rmse in enumerate(train_rmses):
+                            writer.add_scalar(
+                                f"train error of task {ivar}", rmse, epoch
+                            )
+
                     print_distributed(
                         verbosity,
-                        f"Epoch {epoch}: learning rate reduced to {new_lr}",
+                        f"Epoch: {epoch:4d}  Train: {train_loss:.8f}  "
+                        f"Val: {val_loss:.8f}  Test: {test_loss:.8f}",
+                    )
+                    history["total_loss_train"].append(train_loss)
+                    history["total_loss_val"].append(val_loss)
+                    history["total_loss_test"].append(test_loss)
+                    history["task_loss_train"].append(train_rmses)
+                    history["task_loss_val"].append(val_rmses)
+                    history["task_loss_test"].append(test_rmses)
+                    # XLA compiles this epoch (train + both evaluations),
+                    # from the recompile sentinel: after the first epoch a run
+                    # on static bucket shapes should record zeros.
+                    history.setdefault("xla_compiles", []).append(
+                        compile_count() - compiles0
                     )
 
-            if writer is not None:
-                writer.add_scalar("train error", train_loss, epoch)
-                writer.add_scalar("validate error", val_loss, epoch)
-                writer.add_scalar("test error", test_loss, epoch)
-                for ivar, rmse in enumerate(train_rmses):
-                    writer.add_scalar(f"train error of task {ivar}", rmse, epoch)
+                    # Mid-training periodic checkpoint — an improvement over
+                    # the reference, which saves only once at the very end
+                    # (SURVEY.md §5.4); a preempted multi-hour run warm-starts
+                    # from the last save. Non-blocking by default
+                    # (checkpoint_async).
+                    if (
+                        checkpoint_name
+                        and checkpoint_every > 0
+                        and (epoch + 1) % checkpoint_every == 0
+                    ):
+                        ckpt_vars = {
+                            "params": driver.state.params,
+                            "batch_stats": driver.state.batch_stats,
+                        }
+                        ckpt_meta = {
+                            "epoch": epoch + 1,
+                            "scheduler": (
+                                scheduler.state_dict() if scheduler else None
+                            ),
+                            "history": history,
+                        }
+                        if checkpointer is not None:
+                            stall = checkpointer.save(
+                                ckpt_vars,
+                                driver.state.opt_state,
+                                checkpoint_name,
+                                meta=ckpt_meta,
+                                keep_last_k=checkpoint_keep_last_k,
+                            )
+                        else:
+                            from ..utils.model import save_model
 
-            print_distributed(
-                verbosity,
-                f"Epoch: {epoch:4d}  Train: {train_loss:.8f}  "
-                f"Val: {val_loss:.8f}  Test: {test_loss:.8f}",
-            )
-            history["total_loss_train"].append(train_loss)
-            history["total_loss_val"].append(val_loss)
-            history["total_loss_test"].append(test_loss)
-            history["task_loss_train"].append(train_rmses)
-            history["task_loss_val"].append(val_rmses)
-            history["task_loss_test"].append(test_rmses)
-            # XLA compiles this epoch (train + both evaluations), from the
-            # recompile sentinel: after the first epoch a run on static
-            # bucket shapes should record zeros.
-            history.setdefault("xla_compiles", []).append(
-                compile_count() - compiles0
-            )
-
-            if visualizer is not None and plot_hist_solution:
-                _, _, tv, pv = driver.evaluate(test_loader, return_values=True)
-                visualizer.create_scatter_plots(
-                    tv, pv, output_names=output_names, iepoch=epoch
-                )
-
-            # Mid-training periodic checkpoint — an improvement over the
-            # reference, which saves only once at the very end (SURVEY.md
-            # §5.4); a preempted multi-hour run warm-starts from the last
-            # save. Non-blocking by default (checkpoint_async).
-            if (
-                checkpoint_name
-                and checkpoint_every > 0
-                and (epoch + 1) % checkpoint_every == 0
-            ):
-                ckpt_vars = {
-                    "params": driver.state.params,
-                    "batch_stats": driver.state.batch_stats,
-                }
-                ckpt_meta = {
-                    "epoch": epoch + 1,
-                    "scheduler": scheduler.state_dict() if scheduler else None,
-                    "history": history,
-                }
-                if checkpointer is not None:
-                    stall = checkpointer.save(
-                        ckpt_vars,
-                        driver.state.opt_state,
-                        checkpoint_name,
-                        meta=ckpt_meta,
-                        keep_last_k=checkpoint_keep_last_k,
-                    )
-                else:
-                    from ..utils.model import save_model
-
-                    t0 = time.perf_counter()
-                    save_model(
-                        ckpt_vars,
-                        driver.state.opt_state,
-                        checkpoint_name,
-                        meta=ckpt_meta,
-                        keep_last_k=checkpoint_keep_last_k,
-                    )
-                    stall = time.perf_counter() - t0
-                Timer.credit("ckpt_save_stall", stall)
-                telemetry.event(
-                    "train/checkpoint_saved",
-                    epoch=epoch + 1,
-                    stall_s=round(stall, 4),
-                )
+                            t0 = time.perf_counter()
+                            save_model(
+                                ckpt_vars,
+                                driver.state.opt_state,
+                                checkpoint_name,
+                                meta=ckpt_meta,
+                                keep_last_k=checkpoint_keep_last_k,
+                            )
+                            stall = time.perf_counter() - t0
+                        Timer.credit("ckpt_save_stall", stall)
+                        telemetry.event(
+                            "train/checkpoint_saved",
+                            epoch=epoch + 1,
+                            stall_s=round(stall, 4),
+                        )
+            driver.epoch_account.close(epoch, epoch_span.dur_s, totals0)
     finally:
         if checkpointer is not None:
             # Run-exit wait barrier: every queued write lands before the run

@@ -31,6 +31,7 @@ from ..models.base import HydraGNN
 from ..models.lfm2 import INTERMEDIATES, split_intermediates
 from ..models.loss import multihead_rmse_loss
 from ..ops.segment import platform_override
+from ..telemetry import graftel as telemetry
 from ..telemetry import scopes
 
 
@@ -53,6 +54,7 @@ class TrainState:
     loss_scale: Any = None
 
 
+@telemetry.setup_phase("create_state", until_ready=True)
 def create_train_state(model, variables, optimizer) -> TrainState:
     # init() on a COPY of params: optimizers that store the params pytree in
     # their state (optax.lbfgs memory) would otherwise alias params buffers,

@@ -24,6 +24,7 @@ from typing import Any, Dict, List
 import numpy as np
 
 from ..preprocess.graph_build import check_if_graph_size_variable
+from ..telemetry import graftel as telemetry
 from .model import calculate_PNA_degree
 
 # Conv stacks that consume per-edge feature vectors.
@@ -248,6 +249,7 @@ _PIPELINE = (
 )
 
 
+@telemetry.setup_phase("complete_config")
 def update_config(config, train_loader, val_loader, test_loader):
     """Complete a user config from the training data (the reference's
     data-driven completion contract; output pinned by golden tests)."""

@@ -58,7 +58,7 @@ def _driver_for(loader):
 
 class _ActiveProf:
     """Minimal active profiler stub: routes train_epoch onto the per-step
-    (non-scan) path, like benchmarks/profile_epoch.py's span profiler."""
+    (non-scan) path."""
 
     active = True
 
@@ -368,3 +368,172 @@ def pytest_eval_cache_build_single_transfer(monkeypatch):
     count["n"] = 0
     driver.evaluate(ev)  # cached replay: zero transfers
     assert count["n"] == 0
+
+
+# ------------------------------------------------- the host's epoch timeline
+class _PerStepProfiler(_ActiveProf):
+    """Active for the whole run: every train epoch takes the per-step path."""
+
+    def set_current_epoch(self, epoch):
+        pass
+
+    def stop(self):
+        pass
+
+
+def _timeline_run(path):
+    """Four epochs of ``train_validate_test`` on ``path`` with collection on
+    and a slow collation (50 ms a batch; a 512-graph batch costs the chip's
+    host 8-11), so that an epoch is some 0.6 s here as it is seconds there:
+    starting a feed's two threads, which no leaf covers, costs 1-2 ms a feed
+    on this CPU. Returns (driver, collected spans)."""
+    from hydragnn_tpu import telemetry
+    from hydragnn_tpu.faults import FaultPlan
+    from hydragnn_tpu.train.train_validate_test import train_validate_test
+
+    telemetry.reset()
+    telemetry.configure(collect=True)
+    ds = _dataset(np.random.default_rng(5), count=64)
+
+    def loader(graphs, shuffle):
+        ld = GraphDataLoader(graphs, batch_size=4, shuffle=shuffle)
+        ld.set_head_spec(("graph",), (1,))
+        return ld
+
+    train = loader(ds[:48], True)
+    mesh = None
+    if path == "mesh4":
+        from hydragnn_tpu.parallel.distributed import make_mesh
+
+        mesh = make_mesh(data_axis=4, graph_axis=1, devices=jax.devices()[:4])
+    model = create_model("SAGE", 1, 8, (1,), ("graph",), HEADS, [1.0], 2)
+    variables = init_model_variables(model, next(iter(train)))
+    opt = select_optimizer("AdamW", 5e-3)
+    driver = TrainingDriver(
+        model, opt, create_train_state(model, variables, opt), mesh=mesh,
+        fault_plan=FaultPlan("slow_collate:ms=50"),
+    )
+    try:
+        train_validate_test(
+            driver, train, loader(ds[48:56], False), loader(ds[56:], False), 4,
+            profiler=_PerStepProfiler() if path == "per_step" else None,
+        )
+        spans = [r for r in telemetry.collected_records() if r["kind"] == "span"]
+    finally:
+        telemetry.reset()
+    return driver, spans
+
+
+@pytest.fixture(scope="module", params=["scan", "per_step", "mesh4"])
+def timeline(request):
+    return request.param, *_timeline_run(request.param)
+
+
+def pytest_leaf_phases_cover_each_epoch(timeline):
+    """The dispatching thread's epoch is PARTITIONED: the leaf phases
+    (``EPOCH_LEAVES``) cover 95% of every ``epoch`` span's wall on the scan
+    path, the per-step path and a mesh of four virtual devices, and each of
+    them is a child of the epoch, its train epoch or an evaluation."""
+    from hydragnn_tpu.train.train_validate_test import EPOCH_LEAVES
+
+    path, _, spans = timeline
+    epochs = [r for r in spans if r["name"] == "epoch"]
+    assert [r["attrs"]["epoch"] for r in epochs] == [0, 1, 2, 3]
+    by_id = {r["span_id"]: r for r in spans}
+    for ep in epochs:
+        lo, hi = ep["ts"], ep["ts"] + ep["dur_s"]
+        leaves = [
+            r for r in spans
+            if r["name"] in EPOCH_LEAVES and r["thread"] == ep["thread"]
+            and lo <= r["ts"] < hi
+        ]
+        assert {r["name"] for r in leaves} == set(EPOCH_LEAVES), path
+        covered = sum(r["dur_s"] for r in leaves)
+        assert covered >= 0.95 * ep["dur_s"], (path, ep["attrs"], covered, ep["dur_s"])
+        for r in leaves:  # each hangs off this epoch, directly or by one container
+            parent = by_id[r["parent_id"]]
+            assert parent is ep or by_id[parent["parent_id"]] is ep, (path, r["name"])
+        # The four cumulative jax/*_s counters as they stood at the opening.
+        assert {"jax_trace_s", "jax_lower_s", "jax_compile_s", "jax_cache_load_s"} <= set(ep["attrs"])
+    splits = [r["attrs"]["split"] for r in spans if r["name"] == "evaluate"]
+    assert splits == ["val", "test"] * 4
+
+
+def pytest_no_span_opens_inside_a_device_or_eval_step(timeline):
+    """``trace_reduce`` books a program to the SHORTEST span open at its
+    midpoint on the dispatching thread, so nothing may open inside
+    ``device_step`` or ``eval_step`` there (a ``gc`` record is retroactive:
+    no annotation, marked ``retro``)."""
+    path, _, spans = timeline
+    steps = {
+        r["span_id"]: r for r in spans if r["name"] in ("device_step", "eval_step")
+    }
+    assert steps
+    inside = [
+        (r["name"], steps[r["parent_id"]]["name"]) for r in spans
+        if r.get("parent_id") in steps and not r.get("retro")
+    ]
+    assert inside == [], path
+    for r in spans:  # and none overlaps one in time on its thread either
+        if r["name"] in ("device_step", "eval_step") or r.get("retro"):
+            continue
+        for s in steps.values():
+            if s["thread"] == r["thread"]:
+                assert not (s["ts"] < r["ts"] < s["ts"] + s["dur_s"]), (path, r["name"])
+
+
+def pytest_feed_wait_is_credited_on_every_path(timeline):
+    """``FeedStats.feed_wait_s`` is what the consumer really waited: non-zero
+    under a slow collation on the scan path too (it read 0.0 there by
+    construction), and equal to the ``feed_wait`` spans' seconds, which are
+    its one clock. The scan path's first pull waits for the whole epoch's
+    collation: no chunk is handed over before the loader is exhausted."""
+    path, driver, spans = timeline
+    last = [r for r in spans if r["name"] == "train_epoch"][-1]
+    waits = [
+        r for r in spans
+        if r["name"] == "feed_wait" and r["parent_id"] == last["span_id"]
+    ]
+    # The driver's FeedStats were reset by the evaluations since: read the
+    # train epoch's figure as the loop published it.
+    assert waits and sum(r["dur_s"] for r in waits) > 0.05, path
+    if path == "scan":
+        collates = [
+            r for r in spans
+            if r["name"] == "collate" and r["parent_id"] == last["span_id"]
+        ]
+        assert len(collates) == 13  # 12 batches and the pull that ends them
+        assert waits[0]["dur_s"] >= 0.9 * sum(r["dur_s"] for r in collates[:12])
+        drains = [
+            r for r in spans
+            if r["name"] == "feed_drain" and r["parent_id"] == last["span_id"]
+        ]
+        assert len(drains) == 1
+
+
+def pytest_scan_path_feed_wait_equals_its_spans():
+    from hydragnn_tpu import telemetry
+    from hydragnn_tpu.faults import FaultPlan
+
+    telemetry.reset()
+    telemetry.configure(collect=True)
+    try:
+        ds = _dataset(np.random.default_rng(2))
+        loader = GraphDataLoader(ds, batch_size=4, shuffle=True)
+        loader.set_head_spec(("graph",), (1,))
+        driver = _driver_for(loader)
+        driver.fault_plan = FaultPlan("slow_collate@3:ms=60")
+        driver.train_epoch(loader)
+        stats = driver.feed_stats.as_dict()
+        spans = [r for r in telemetry.collected_records() if r["kind"] == "span"]
+        waits = [r["dur_s"] for r in spans if r["name"] == "feed_wait"]
+        steps = [r["dur_s"] for r in spans if r["name"] == "device_step"]
+        assert stats["feed_wait_s"] >= 0.06
+        assert stats["feed_wait_s"] == pytest.approx(sum(waits), abs=1e-3)
+        assert stats["step_s"] == pytest.approx(sum(steps), abs=1e-3)
+        # The totals are the same seconds again, with collection on or off.
+        totals = telemetry.span_totals()
+        assert totals["feed_wait"] == pytest.approx(sum(waits), abs=1e-9)
+        assert totals["device_step"] == pytest.approx(sum(steps), abs=1e-9)
+    finally:
+        telemetry.reset()

@@ -556,3 +556,220 @@ def pytest_traced_train_exports_valid_jsonl_and_perfetto(tmp_path):
     counts = telemetry.span_counts()
     assert counts["train_epoch"] == 2
     assert counts["device_step"] >= 2
+
+
+# ------------------------------------------------- running totals, the account
+def pytest_span_totals_equal_collected_durations_from_two_threads():
+    """A span is also a running total: ``span_s/<name>`` / ``span_n/<name>``
+    equal the collected spans' ``dur_s`` summed by name, from two threads at
+    once, live with collection OFF, beyond the ring's 4,096 records, and
+    rendered in Prometheus. ``dur_s`` is the span's one clock reading."""
+    telemetry.configure(collect=True)
+
+    def work(name, n):
+        for _ in range(n):
+            with telemetry.span(name) as outer:
+                with telemetry.span("shared"):
+                    pass
+            assert outer.dur_s is not None and outer.dur_s >= 0.0
+
+    threads = [
+        threading.Thread(target=work, args=(name, 200)) for name in ("left", "right")
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(30)
+        assert not t.is_alive()
+    by_name = {}
+    for r in telemetry.collected_records():
+        if r["kind"] == "span":
+            by_name.setdefault(r["name"], []).append(r["dur_s"])
+    assert {k: len(v) for k, v in by_name.items()} == {
+        "left": 200, "right": 200, "shared": 400,
+    }
+    totals = telemetry.span_totals()
+    counts = telemetry.counters_snapshot("span_n/")
+    for name, durs in by_name.items():
+        assert totals[name] == pytest.approx(sum(durs), rel=1e-9)
+        assert counts["span_n/" + name] == len(durs)
+    assert "hydragnn_span_s_shared_total" in telemetry.render_prometheus()
+    # Collection off, and more spans than the ring holds: the totals go on.
+    telemetry.configure(collect=False)
+    before = telemetry.counter_value("span_n/flood")
+    for _ in range(5000):
+        with telemetry.span("flood"):
+            pass
+    assert telemetry.collected_records() == []
+    assert len(telemetry.snapshot_records()) <= 4096
+    assert telemetry.counter_value("span_n/flood") - before == 5000
+
+
+def pytest_disabled_spans_keep_their_clock_for_feedstats():
+    """``enabled=False`` drops records and totals, but a span still reads
+    its clock pair: ``FeedStats`` is credited from ``dur_s`` either way."""
+    telemetry.configure(enabled=False)
+    loader = _loader(_dataset(np.random.default_rng(0)))
+    d = _driver_for(loader)
+    d.train_epoch(loader)
+    stats = d.feed_stats.as_dict()
+    assert stats["step_s"] > 0.0 and stats["feed_wait_s"] > 0.0
+    assert telemetry.snapshot_records() == []
+    assert telemetry.span_totals() == {}
+
+
+def pytest_record_span_is_retroactive_and_totalled():
+    telemetry.configure(collect=True)
+    with telemetry.span("holder") as holder:
+        telemetry.record_span(
+            "late", 0.25, end_ts=1000.0, thread="elsewhere", generation=2
+        )
+    (late,) = [r for r in telemetry.collected_records() if r["name"] == "late"]
+    assert late["retro"] is True and late["thread"] == "elsewhere"
+    assert late["ts"] == pytest.approx(999.75) and late["dur_s"] == 0.25
+    assert late["parent_id"] == holder.ctx.span_id
+    assert late["attrs"] == {"generation": 2}
+    assert telemetry.span_totals()["late"] == 0.25
+    assert telemetry.validate_flight(
+        {"schema": telemetry.SCHEMA_FLIGHT, "trigger": "t", "ts_utc": "x",
+         "pid": 1, "seq": 1, "records": [late], "counters": {}, "gauges": {}}
+    ) == []
+
+
+def pytest_gc_pause_is_a_record_only_from_a_millisecond():
+    """Every collection adds to ``host/gc_pause_s``; one of a millisecond or
+    more is a retroactive ``gc`` record (generation and collected count as
+    attributes), a generation-0 collection of a few objects is not."""
+    import gc
+
+    from hydragnn_tpu.telemetry import graftel
+
+    telemetry.configure(collect=True)
+    telemetry.install_gc_hook()
+    telemetry.install_gc_hook()  # idempotent
+    assert gc.callbacks.count(graftel._on_gc) == 1
+
+    class Node:
+        pass
+
+    ring = []
+    for _ in range(200_000):
+        a, b = Node(), Node()
+        a.other, b.other = b, a
+        ring.append(a)
+    del ring, a, b
+    with telemetry.span("around"):
+        gc.collect()
+    records = [r for r in telemetry.collected_records() if r["name"] == "gc"]
+    full = [r for r in records if r["attrs"]["generation"] == 2]
+    assert full and all(r["retro"] for r in records)
+    assert max(r["attrs"]["collected"] for r in full) >= 400_000
+    assert all(r["dur_s"] >= graftel.GC_RECORD_S for r in records)
+    assert telemetry.counter_value("host/gc_pause_s") >= sum(
+        r["dur_s"] for r in records
+    ) - 1e-9
+    # Young collections of next to nothing: counted, never recorded.
+    n_records = len(records)
+    n_counted = telemetry.counter_value("host/gc_collections")
+    gc.disable()
+    try:
+        for _ in range(5):
+            gc.collect(0)
+    finally:
+        gc.enable()
+    assert telemetry.counter_value("host/gc_collections") >= n_counted + 5
+    records = [r for r in telemetry.collected_records() if r["name"] == "gc"]
+    assert len(records) < n_records + 5
+    assert all(r["dur_s"] >= graftel.GC_RECORD_S for r in records)
+
+
+def pytest_setup_account_and_jax_durations():
+    """The functions every entry point calls before its first step open
+    ``setup.*`` spans themselves, and the first one installs the hooks: with
+    no caller configuring anything, the totals hold the eager initializer and
+    JAX's own trace / lower / compile seconds."""
+    loader = _loader(_dataset(np.random.default_rng(0)))
+    before = telemetry.jax_seconds()
+    d = _driver_for(loader)
+    totals = telemetry.span_totals()
+    for name in ("setup.init_variables", "setup.create_state", "setup.driver"):
+        assert totals[name] > 0.0, name
+    assert totals["setup.init_variables"] > totals["setup.driver"]
+    # A program this process has not compiled yet (the models above may be
+    # in its caches from an earlier test).
+    import jax
+
+    jax.block_until_ready(
+        jax.jit(lambda x: (x * 35.0 + 0.35).sum())(np.arange(35, dtype=np.float32))
+    )
+    after = telemetry.jax_seconds()
+    assert set(after) == {
+        "jax_trace_s", "jax_lower_s", "jax_compile_s", "jax_cache_load_s",
+    }
+    for key in ("jax_trace_s", "jax_lower_s", "jax_compile_s"):
+        assert after[key] > before[key], key
+    # The persistent cache is off in the tests: nothing was loaded.
+    assert after["jax_cache_load_s"] == before["jax_cache_load_s"]
+    assert telemetry.counter_value("jax/compiles") >= 1
+    assert isinstance(d, TrainingDriver)
+    # Config completion and the loaders, through their own entry points.
+    from hydragnn_tpu.utils.config_utils import update_config
+
+    assert update_config.__wrapped__.__name__ == "update_config"
+
+
+def _epochs_one_call_each(driver, loaders, epochs):
+    """``train_validate_test`` one epoch a call, as the benchmark's drivers
+    run it: the account of the last epochs lives on the driver."""
+    from hydragnn_tpu.train.train_validate_test import train_validate_test
+
+    history = None
+    for epoch in range(epochs):
+        history = train_validate_test(
+            driver, *loaders, epoch + 1, start_epoch=epoch, history=history
+        )
+    return history
+
+
+@pytest.mark.parametrize("stalled", [True, False])
+def pytest_injected_stall_in_sixth_epoch_names_its_phase(tmp_path, caplog, stalled):
+    """A collation stalled in the sixth epoch yields exactly ONE
+    ``train/epoch_stall`` whose phase on the dispatching thread is
+    ``feed_wait`` (the feed thread's ``collate`` beside it), a flight dump
+    with trigger ``epoch_stall`` and one warning line, with no profiler and
+    no collection; the same run without the stall yields none of them."""
+    telemetry.configure(run_dir=str(tmp_path))
+    graphs = _dataset(np.random.default_rng(0), count=24)
+    loaders = (
+        _loader(graphs[:16], shuffle=True), _loader(graphs[16:20]),
+        _loader(graphs[20:]),
+    )
+    # 4 train batches an epoch: fed batch 21 is the sixth epoch's second.
+    plan = FaultPlan("slow_collate@21:ms=400") if stalled else None
+    d = _driver_for(loaders[0], plan=plan)
+    with caplog.at_level("WARNING", logger="hydragnn_tpu.train.train_validate_test"):
+        _epochs_one_call_each(d, loaders, 8)
+    stalls = [
+        r for r in telemetry.snapshot_records() if r["name"] == "train/epoch_stall"
+    ]
+    dumps = glob.glob(str(tmp_path / "flightrec_*_epoch_stall.json"))
+    warnings = [r for r in caplog.records if "against a median" in r.getMessage()]
+    assert telemetry.counter_value("train/epoch_stalls") == len(stalls)
+    if not stalled:
+        assert stalls == [] and dumps == [] and warnings == []
+        return
+    (stall,) = stalls
+    attrs = stall["attrs"]
+    assert attrs["epoch"] == 5 and attrs["phase"] == "feed_wait"
+    assert attrs["wall_s"] > 0.4 > 1.5 * attrs["median_s"]
+    assert attrs["seconds"]["feed_wait"] >= 0.39
+    assert attrs["seconds"]["collate"] >= 0.39  # all threads: the feed's too
+    assert {name for name, _, _ in attrs["excess"][:2]} == {"feed_wait", "collate"}
+    assert attrs["no_leaf_s"] < 0.05
+    (dump,) = dumps
+    assert telemetry.validate_flight_file(dump) == []
+    with open(dump) as f:
+        doc = json.load(f)
+    assert doc["trigger"] == "epoch_stall" and doc["extra"]["phase"] == "feed_wait"
+    (warning,) = warnings
+    assert "feed_wait" in warning.getMessage() and "epoch 5" in warning.getMessage()
