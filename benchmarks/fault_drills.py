@@ -146,6 +146,7 @@ def _guard_overhead_pct(windows=6, batch=64, steps=8):
     from hydragnn_tpu.utils.optimizer import select_optimizer
 
     runs = {}
+    count = np.asarray(steps, np.int32)  # every stacked batch is real
     for key, guard in (("off", False), ("on", True)):
         rng = np.random.default_rng(0)
         graphs = _make_graphs(batch, rng, n_lo=12, n_hi=26)
@@ -157,10 +158,10 @@ def _guard_overhead_pct(windows=6, batch=64, steps=8):
         state = create_train_state(model, variables, opt)
         compiled = (
             make_train_epoch_scan(model, opt, guard=guard)
-            .lower(state, stacked, jax.random.PRNGKey(0))
+            .lower(state, stacked, count, jax.random.PRNGKey(0))
             .compile()
         )
-        state, m = compiled(state, stacked, jax.random.PRNGKey(0))  # warmup
+        state, m = compiled(state, stacked, count, jax.random.PRNGKey(0))  # warmup
         jax.block_until_ready(m["loss"])
         runs[key] = (compiled, state, stacked)
     times = {"off": [], "on": []}
@@ -168,7 +169,7 @@ def _guard_overhead_pct(windows=6, batch=64, steps=8):
         for key in ("off", "on"):
             compiled, state, stacked = runs[key]
             t0 = time.perf_counter()
-            state, m = compiled(state, stacked, jax.random.PRNGKey(0))
+            state, m = compiled(state, stacked, count, jax.random.PRNGKey(0))
             jax.block_until_ready(m["loss"])
             times[key].append(time.perf_counter() - t0)
             runs[key] = (compiled, state, stacked)

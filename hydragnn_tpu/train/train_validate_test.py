@@ -125,6 +125,17 @@ _EPOCH_CONTAINERS = ("epoch", "train_epoch", "evaluate")
 NO_LEAF = "(no leaf)"
 EPOCHS_KEPT = 32
 _FEED_END = object()  # what a pull returns from an exhausted feed
+# Batches a dispatch on the scan path: ONE constant for every model (no
+# config key, no environment variable). A chunk is handed to the chip once
+# this many batches of its shape are collated, so an epoch's first wait is
+# SCAN_CHUNK collations and one chunk's transfer (at the 64 it was, with
+# 12-45 batches an epoch, it was the whole epoch's collation with the chip
+# idle), and each dispatch pays one dispatch-and-readback gap: 1.5-3 ms in
+# the graph cells, 6-10 where the state is gigabytes (the token cells).
+# 4 is where the sum over the benchmark's graph cells is largest with the
+# token cells inside their spread (PERF.md section 6, PR 36:
+# benchmarks/scan_chunk_lengths.py over 1, 2, 4, 8 and 64 on the chip).
+SCAN_CHUNK = 4
 
 _log = logging.getLogger(__name__)
 
@@ -376,10 +387,9 @@ class TrainingDriver:
                 model, optimizer, donate, guard=guard,
                 loss_scaling=loss_scaling,
             )
-        # Chunked lax.scan over the epoch: one device dispatch per chunk
-        # instead of per batch (dispatch overhead dominates at HydraGNN's
-        # model sizes). Chunk bounds the stacked batches' HBM footprint.
-        self.scan_chunk = 64
+        # The scan path steps ``scan_chunk`` batches a dispatch (SCAN_CHUNK,
+        # above, has the reason for its value). Tests set the attribute.
+        self.scan_chunk = SCAN_CHUNK
         self.rng = jax.random.PRNGKey(0)
         # Device-resident batch caches (reshuffle="batch" train loaders and
         # static eval loaders): id(loader) -> {"loader": strong ref (keeps
@@ -395,8 +405,8 @@ class TrainingDriver:
         self._perm_scan = None
         if mesh is None:
             self._perm_scan = jax.jit(
-                lambda s, p, perm, rng: self.epoch_scan(
-                    s, jax.tree_util.tree_map(lambda x: x[perm], p), rng
+                lambda s, p, perm, count, rng: self.epoch_scan(
+                    s, jax.tree_util.tree_map(lambda x: x[perm], p), count, rng
                 ),
                 donate_argnums=(0,),
             )
@@ -620,8 +630,10 @@ class TrainingDriver:
         return dev
 
     def _put_chunk(self, item):
-        single, payload = item
-        return single, self._put_timed(payload)
+        """One transfer a chunk: the stack and its count of real batches go
+        together, so the dispatch finds both on the device."""
+        steps, stacked = item
+        return steps, self._put_timed((stacked, np.asarray(steps, np.int32)))
 
     def _drain_feed(self, feed, label: str):
         """End-of-epoch teardown: cancel the pipeline and give its threads a
@@ -759,25 +771,32 @@ class TrainingDriver:
                 self._drain_feed(batches, "train")
             return self._train_epoch_done(metrics)
 
-    def _train_epoch_done(self, metrics: "EpochMetrics"):
+    def _train_epoch_done(self, metrics: "EpochMetrics", scan_chunks: int = 0):
         """The epoch's averages; its counters are published as gauges,
-        ``train/<counter>_per_epoch`` (none where the model counts none)."""
+        ``train/<counter>_per_epoch`` (none where the model counts none),
+        and the scan path's dispatches as ``train/scan_chunks_per_epoch`` (0
+        on the per-batch paths)."""
+        telemetry.gauge("train/scan_chunks_per_epoch", scan_chunks)
         for name, value in metrics.counters.items():
             telemetry.gauge(f"train/{name}_per_epoch", value)
         return metrics.averages()
 
     def _train_epoch_scan(self, loader, ctx=None):
-        """Whole-epoch lax.scan in fixed-size chunks, buffered per batch shape
-        (bucketed loaders emit a handful of static shapes). Chunk sizes repeat
-        across epochs (loader length is constant), so compiles stay bounded:
-        per shape, the full chunk plus remainders. The tqdm bar (verbosity
-        2/4) ticks per batch as batches are consumed into chunks.
+        """The epoch as dispatches of ``scan_chunk`` steps each, buffered per
+        batch shape (bucketed loaders emit a handful of static shapes). A
+        chunk goes to the chip as soon as ``scan_chunk`` batches of its shape
+        are collated; a shape's tail of fewer is the same stack with a smaller
+        ``count`` (``make_train_epoch_scan``), so a batch shape has exactly
+        ONE step program whatever the epoch's length, and a loader whose
+        per-shape count moves between epochs compiles nothing. Several shapes
+        interleave a chunk at a time, in the loader's own order. The tqdm bar
+        (verbosity 2/4) ticks per batch as batches are consumed into chunks.
 
         reshuffle="batch" loaders (frozen membership) additionally get their
         stacked chunks cached ON DEVICE after the first epoch: steady-state
         epochs then do zero host collation and zero host->device transfer.
         Batch visit order still reshuffles per epoch (chunk dispatch order on
-        host, plus a device-side permutation of each chunk's stacked axis).
+        host, plus a device-side permutation of each chunk's real slots).
         Capped by
         HYDRAGNN_DEVICE_CACHE_MB (default 512). Cache entries carry the
         loader's head-spec generation; a set_head_spec after the build makes
@@ -812,35 +831,32 @@ class TrainingDriver:
             )
             with sentinel:
                 for ci in rng.permutation(len(cached["chunks"])):
-                    single, payload = cached["chunks"][ci]
+                    steps, (stacked, count) = cached["chunks"][ci]
                     with telemetry.span(
-                        "device_step", index=int(ci), cached=True
+                        "device_step", index=int(ci), cached=True, steps=steps
                     ) as step:
-                        if single:
-                            self.state, m = self._dispatch(
-                                "train_step", self.train_step,
-                                self._dispatch_shape_key(payload),
-                                self.state, payload, self.rng,
-                            )
-                        else:
-                            # Batch-level order reshuffle WITHIN the chunk too —
-                            # compiled into the scan dispatch (see _perm_scan), so
-                            # the mode's "order reshuffles per epoch" promise holds
-                            # even when the whole epoch fits one chunk. Membership
-                            # and batch->chunk assignment stay frozen (the cache).
-                            steps = jax.tree_util.tree_leaves(payload)[0].shape[0]
-                            perm = jnp.asarray(rng.permutation(steps))
-                            self.state, m = self._dispatch(
-                                "perm_scan", self._perm_scan,
-                                self._dispatch_shape_key(payload),
-                                self.state, payload, perm, self.rng,
-                            )
+                        # Batch-level order reshuffle WITHIN the chunk too —
+                        # compiled into the scan dispatch (see _perm_scan), so
+                        # the mode's "order reshuffles per epoch" promise holds
+                        # even when the whole epoch fits one chunk. Membership
+                        # and batch->chunk assignment stay frozen (the cache).
+                        # Only the real slots move: a padded tail keeps its
+                        # real batches in the first ``count`` slots.
+                        slots = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+                        perm = jnp.asarray(np.concatenate(
+                            [rng.permutation(steps), np.arange(steps, slots)]
+                        ))
+                        self.state, m = self._dispatch(
+                            "perm_scan", self._perm_scan,
+                            self._dispatch_shape_key(stacked),
+                            self.state, stacked, perm, count, self.rng,
+                        )
                         metrics.update(m)
                     self.feed_stats.credit("step_s", step.dur_s)
                     self._after_update(m)
             cached["warm"] = True
             self._credit_timers("train")
-            return self._train_epoch_done(metrics)
+            return self._train_epoch_done(metrics, len(cached["chunks"]))
 
         cacheable = (
             getattr(loader, "reshuffle", None) == "batch"
@@ -856,8 +872,8 @@ class TrainingDriver:
         # Two-stage device feed over stacked chunks: collation + stacking on
         # the host thread, device_put on the transfer thread, so chunk k+1
         # is committed while chunk k's scan executes. device_depth=1 (not
-        # the per-batch default): payloads here are WHOLE scan chunks, and
-        # one queued + one transferring + one computing already bounds the
+        # the per-batch default): payloads here are scan chunks, and one
+        # queued + one transferring + one computing already bounds the
         # transient HBM at ~3 chunks while keeping the overlap.
         feed = DeviceFeed(
             self._host_chunks(loader),
@@ -865,13 +881,15 @@ class TrainingDriver:
             device_depth=1,
             ctx=ctx,
         )
+        chunks = 0
         try:
-            # The pull waits for a whole CHUNK: with scan_chunk batches or
-            # fewer of a shape in the epoch, for the loader to be exhausted.
-            for ci, (single, payload) in enumerate(self._pulls(feed)):
+            # The pull waits for a CHUNK: ``scan_chunk`` collations of one
+            # shape, or the loader's end where a shape has fewer left.
+            for ci, (steps, payload) in enumerate(self._pulls(feed)):
                 sink = self._run_scan_chunk(
-                    single, payload, metrics, sink, index=ci
+                    steps, payload, metrics, sink, index=ci
                 )
+                chunks += 1
         finally:
             self._drain_feed(feed, "train")
         if cacheable:
@@ -884,13 +902,14 @@ class TrainingDriver:
                 "generation": gen,
                 "chunks": sink["items"] if sink is not None else None,
             }
-        return self._train_epoch_done(metrics)
+        return self._train_epoch_done(metrics, chunks)
 
     def _host_chunks(self, loader):
         """Stage-1 producer for the scan path: collate (loader.__iter__) and
-        group batches by shape into scan-chunk stacks, yielding
-        ``(single, host payload)``. Runs on the pipeline's host thread, so
-        numpy stacking also overlaps device compute."""
+        group batches by shape into stacks of ``scan_chunk``, yielding
+        ``(real batches, host stack)`` as soon as a shape has a full chunk and
+        each shape's tail when the loader ends. Runs on the pipeline's host
+        thread, so numpy stacking also overlaps device compute."""
         bufs: dict = {}
         for b in traced_batches(
             self._wrap_faults(iterate_tqdm(loader, self.verbosity))
@@ -904,43 +923,36 @@ class TrainingDriver:
             if buf:
                 yield self._stack_chunk(buf)
 
-    @staticmethod
-    def _stack_chunk(batches):
-        if len(batches) == 1:
-            return True, batches[0]
-        return False, stack_batches(batches, len(batches))
+    def _stack_chunk(self, batches):
+        """``(len(batches), a stack of scan_chunk)``: a tail's padding slots
+        repeat its last batch. The counted program never reads them."""
+        slots = batches + batches[-1:] * (self.scan_chunk - len(batches))
+        return len(batches), stack_batches(slots, len(slots))
 
     def _run_scan_chunk(
-        self, single, payload, metrics, sink: Optional[dict], index: int = 0
+        self, steps, payload, metrics, sink: Optional[dict], index: int = 0
     ):
-        """Dispatch one device-resident chunk; when ``sink`` is given, retain
-        THE SAME device copy for the reshuffle="batch" cache — the pipeline
-        already transferred it, so the cache-building epoch performs exactly
-        one host->device transfer per chunk. Returns None instead once the
-        byte budget is exceeded; ``sink`` carries a running byte total so the
-        first (timed) epoch's bookkeeping stays O(1) per chunk."""
-        with telemetry.span(
-            "device_step", index=index, chunk=not single
-        ) as step:
-            if single:
-                self.state, m = self._dispatch(
-                    "train_step", self.train_step,
-                    self._dispatch_shape_key(payload),
-                    self.state, payload, self.rng,
-                )
-            else:
-                self.state, m = self._dispatch(
-                    "epoch_scan", self.epoch_scan,
-                    self._dispatch_shape_key(payload),
-                    self.state, payload, self.rng,
-                )
+        """Dispatch one device-resident chunk (``payload``: the stack and its
+        count of real batches, ``steps`` of them); when ``sink`` is given,
+        retain THE SAME device copy for the reshuffle="batch" cache — the
+        pipeline already transferred it, so the cache-building epoch performs
+        exactly one host->device transfer per chunk. Returns None instead once
+        the byte budget is exceeded; ``sink`` carries a running byte total so
+        the first (timed) epoch's bookkeeping stays O(1) per chunk."""
+        stacked, count = payload
+        with telemetry.span("device_step", index=index, steps=steps) as step:
+            self.state, m = self._dispatch(
+                "epoch_scan", self.epoch_scan,
+                self._dispatch_shape_key(stacked),
+                self.state, stacked, count, self.rng,
+            )
             metrics.update(m)
         self.feed_stats.credit("step_s", step.dur_s)
         self._after_update(m)
         if sink is not None:
             nbytes = self._tree_nbytes(payload)
             if sink["bytes"] + nbytes <= self._cache_budget_bytes():
-                sink["items"].append((single, payload))
+                sink["items"].append((steps, payload))
                 sink["bytes"] += nbytes
             else:
                 sink = None

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import struct
+from jax.extend.core import jaxpr_as_fun
 from jax.sharding import PartitionSpec as P
 
 from ..graphs.batch import GraphBatch
@@ -384,30 +385,54 @@ def make_train_epoch_scan(
     guard: bool = False,
     loss_scaling=None,
 ) -> Callable:
-    """Whole-epoch driver: one compiled call scans the train step over a
-    stacked batch array [S, ...] (single dispatch per epoch instead of per
-    step — the python-loop dispatch overhead dominates at HydraGNN's model
-    sizes, hidden_dim 5-50 in every shipped config). Metrics come back summed
-    over steps, matching EpochMetrics' weighted accumulation. With ``guard``,
-    the per-step skip rides INSIDE the scan (a NaN step never poisons later
-    steps of the same chunk) and the summed ``bad`` metric reports how many
-    steps were skipped. With ``loss_scaling`` the dynamic-scale state rides
-    the scan carry (TrainState.loss_scale), so backoff/growth stay exact per
-    step even inside a single-dispatch epoch."""
+    """The scan path's program: ``epoch(state, batches[L, ...], count, rng)``
+    runs the train step over the first ``count <= L`` of a stack of ``L``
+    batches in ONE dispatch. The trip count is an ARGUMENT (an int32 array,
+    never a Python int or a static argument), so a batch shape has one
+    compiled program whatever an epoch's length: a full chunk is ``count =
+    L``, a shape's tail of ``r`` batches is the same stack with ``count = r``,
+    and the slots past ``count`` are never read. The loop itself is never
+    differentiated (each step differentiates inside itself); the metrics are
+    summed in the carry, matching EpochMetrics' weighted accumulation. With
+    ``guard``, the per-step skip rides INSIDE the loop (a NaN step never
+    poisons later steps of the same chunk) and the summed ``bad`` metric
+    reports how many steps were skipped. With ``loss_scaling`` the
+    dynamic-scale state rides the carry (TrainState.loss_scale), so
+    backoff/growth stay exact per step inside a dispatch of several."""
 
     body = _step_body(model, optimizer, guard, loss_scaling)
 
     @functools.partial(jax.jit, donate_argnums=(0,) if donate else ())
-    def epoch(state: TrainState, batches: GraphBatch, rng):
+    def epoch(state: TrainState, batches: GraphBatch, count, rng):
+        # The step is traced ONCE, to a jaxpr that the loop replays: the
+        # carry needs the metrics' shapes before the loop is built, and a
+        # second Python trace of the model for them costs a second or more
+        # of set-up a batch shape.
+        one = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), batches
+        )
+        traced, shapes = jax.make_jaxpr(body, return_shape=True)(state, one, rng)
+        replay = jaxpr_as_fun(traced)
+        out_tree = jax.tree_util.tree_structure(shapes)
+
+        def step(i, carry):
+            state, summed = carry
+            batch = jax.tree_util.tree_map(
+                lambda x: jax.lax.dynamic_index_in_dim(x, i, keepdims=False),
+                batches,
+            )
+            state, metrics = jax.tree_util.tree_unflatten(
+                out_tree, replay(*jax.tree_util.tree_leaves((state, batch, rng)))
+            )
+            return state, jax.tree_util.tree_map(jnp.add, summed, metrics)
+
         # Trace-annotation bridge: same metadata-only scope as
         # make_train_step, so scanned epochs attribute identically.
         with jax.named_scope(scopes.TRAIN_EPOCH_SCAN):
-            state, metrics = jax.lax.scan(
-                lambda s, b: body(s, b, rng), state, batches
+            zeros = jax.tree_util.tree_map(
+                lambda m: jnp.zeros(m.shape, m.dtype), shapes[1]
             )
-        return state, jax.tree_util.tree_map(
-            lambda m: jnp.sum(m, axis=0), metrics
-        )
+            return jax.lax.fori_loop(0, count, step, (state, zeros))
 
     return epoch
 

@@ -236,7 +236,11 @@ def pytest_crash_resume_after_kill(tmp_path, monkeypatch):
         config = json.load(f)
     config["Visualization"] = {"create_plots": False}
     tr = config["NeuralNetwork"]["Training"]
-    tr["num_epoch"] = 6
+    # Long enough that the run cannot finish inside the kill poll below: an
+    # epoch of these 48 graphs is milliseconds once its one scan program a
+    # batch shape is compiled (nothing compiles after the first epoch).
+    epochs = 60
+    tr["num_epoch"] = epochs
     tr["periodic_checkpoint_every"] = 2
     tr["resume"] = 1
     for split, cnt in {"train": 48, "test": 16, "validate": 16}.items():
@@ -260,7 +264,7 @@ def pytest_crash_resume_after_kill(tmp_path, monkeypatch):
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         env=dict(os.environ, SERIALIZED_DATA_PATH=str(tmp_path)),
     )
-    # Kill the instant the first periodic checkpoint lands (epoch 2 of 6).
+    # Kill the instant the first periodic checkpoint lands (epoch 2 of 60).
     deadline = _time.time() + 600
     ckpt = None
     while _time.time() < deadline and proc.poll() is None:
@@ -278,17 +282,17 @@ def pytest_crash_resume_after_kill(tmp_path, monkeypatch):
     proc.wait()
 
     meta = load_checkpoint_meta(ckpt)
-    if meta["epoch"] >= 6:  # machine outran the 50 ms kill poll — no signal
+    if meta["epoch"] >= epochs:  # machine outran the 50 ms kill poll — no signal
         pytest.skip("training finished before SIGKILL landed")
-    assert 0 < meta["epoch"] < 6  # genuinely mid-run
+    assert 0 < meta["epoch"] < epochs  # genuinely mid-run
     assert meta["scheduler"] is not None
     assert len(meta["history"]["total_loss_train"]) == meta["epoch"]
 
     # Same config, same log name: resume completes the remaining epochs.
     history = run_training(dict(config))
-    assert len(history["total_loss_train"]) == 6
-    assert load_checkpoint_meta(ckpt)["epoch"] == 6
+    assert len(history["total_loss_train"]) == epochs
+    assert load_checkpoint_meta(ckpt)["epoch"] == epochs
 
     # Resuming a finished run trains zero further epochs.
     history2 = run_training(dict(config))
-    assert len(history2["total_loss_train"]) == 6
+    assert len(history2["total_loss_train"]) == epochs

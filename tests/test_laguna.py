@@ -432,7 +432,7 @@ def pytest_compact_row_arrays_inside_the_model(where, monkeypatch):
         state = create_train_state(model, variables, opt)
         stacked = jax.tree_util.tree_map(lambda a: jnp.stack([a, a]), batch)
         state, metrics = make_train_epoch_scan(model, opt, donate=False)(
-            state, stacked, jax.random.PRNGKey(0)
+            state, stacked, np.asarray(2, np.int32), jax.random.PRNGKey(0)
         )
         return (metrics["loss"], state.params), metrics, 2
 

@@ -113,43 +113,248 @@ def pytest_piped_per_batch_train_matches_unpiped():
     _assert_params_close(piped_params, state.params)
 
 
-def pytest_piped_scan_train_matches_unpiped():
+def _counted_chunks(driver, batches):
+    """``_host_chunks``' contract by hand: per shape, a stack of
+    ``scan_chunk`` as soon as that many batches are there, each shape's tail
+    padded to the same length with its last batch; ``(real batches, stack)``."""
+    length = driver.scan_chunk
+
+    def stacked(buf):
+        slots = buf + buf[-1:] * (length - len(buf))
+        return len(buf), stack_batches(slots, length)
+
+    bufs, chunks = {}, []
+    for b in batches:
+        buf = bufs.setdefault(driver._shape_key(b), [])
+        buf.append(b)
+        if len(buf) == length:
+            chunks.append(stacked(buf))
+            buf.clear()
+    return chunks + [stacked(buf) for buf in bufs.values() if buf]
+
+
+@pytest.mark.parametrize("scan_chunk", [1, 3, 64])
+def pytest_piped_scan_train_matches_unpiped(scan_chunk):
     """Scan path: pipeline chunking + transfer-thread device_put reproduces
-    the unpiped chunked epoch_scan dispatch batch for batch."""
+    the unpiped dispatch of the counted epoch_scan chunk for chunk: one batch
+    a chunk, full chunks and a padded tail (7 batches by 3), one padded chunk
+    for the whole epoch (64)."""
     ds = _dataset(np.random.default_rng(1))
     loader = GraphDataLoader(ds, batch_size=4, shuffle=False)
     loader.set_head_spec(("graph",), (1,))
 
     driver = _driver_for(loader)
-    driver.scan_chunk = 3  # multiple chunks + a remainder single-batch chunk
+    driver.scan_chunk = scan_chunk
     state0 = _state_copy(driver.state)
     loss_piped, _ = driver.train_epoch(loader)
     piped_params = driver.state.params
 
-    bufs, chunks = {}, []
-    for b in loader:
-        key = driver._shape_key(b)
-        buf = bufs.setdefault(key, [])
-        buf.append(b)
-        if len(buf) == driver.scan_chunk:
-            chunks.append(list(buf))
-            buf.clear()
-    for buf in bufs.values():
-        if buf:
-            chunks.append(list(buf))
+    chunks = _counted_chunks(driver, loader)
+    assert [n for n, _ in chunks] == {1: [1] * 7, 3: [3, 3, 1], 64: [7]}[scan_chunk]
     state, ms = state0, []
-    for chunk in chunks:
-        if len(chunk) == 1:
-            state, m = driver.train_step(state, chunk[0], driver.rng)
-        else:
-            state, m = driver.epoch_scan(
-                state, stack_batches(chunk, len(chunk)), driver.rng
-            )
+    for n, stack in chunks:
+        state, m = driver.epoch_scan(
+            state, stack, np.asarray(n, np.int32), driver.rng
+        )
         ms.append(m)
     np.testing.assert_allclose(
         loss_piped, _epoch_metrics_like(ms), rtol=1e-6
     )
     _assert_params_close(piped_params, state.params)
+    assert driver.train_step._cache_size() == 0  # no lone-batch route
+
+
+# ------------------------------------------- the counted chunk (scan path)
+def _one_shape_loader(seed, count, **kw):
+    """Graphs of one size, so every batch of every loader has one shape."""
+    loader = GraphDataLoader(
+        _dataset(np.random.default_rng(seed), count=count, lo=6, hi=7),
+        batch_size=4, **kw,
+    )
+    loader.set_head_spec(("graph",), (1,))
+    return loader
+
+
+def _poisoned(batch):
+    """``batch`` with NaN in every float: a slot that must never be read."""
+    return jax.tree_util.tree_map(
+        lambda a: np.full_like(a, np.nan) if a.dtype.kind == "f" else a, batch
+    )
+
+
+def _assert_trees_bit_equal(a, b):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _sequential_steps(driver, state, batches):
+    """``train_step`` a batch at a time; (state, metrics summed in float32
+    in step order, as the counted program's carry sums them)."""
+    summed = None
+    for b in batches:
+        state, m = driver.train_step(state, b, driver.rng)
+        summed = m if summed is None else jax.tree_util.tree_map(
+            lambda x, y: x + y, summed, m
+        )
+    return state, summed
+
+
+@pytest.mark.parametrize("real", [1, 3, 4], ids=["one", "all_but_one", "all"])
+def pytest_counted_scan_runs_only_its_real_batches(real):
+    """The counted program over a stack of 4 with ``count = real``, the
+    padding slots filled with NaN: state and summed metrics bit-equal to
+    ``real`` sequential ``train_step``s. The trip count is the argument."""
+    loader = _one_shape_loader(11, 16, shuffle=False)
+    driver = _driver_for(loader)
+    batches = list(loader)
+    stack = stack_batches(
+        batches[:real] + [_poisoned(batches[-1])] * (4 - real), 4
+    )
+    got_state, got = driver.epoch_scan(
+        _state_copy(driver.state), stack, np.asarray(real, np.int32), driver.rng
+    )
+    want_state, want = _sequential_steps(
+        driver, _state_copy(driver.state), batches[:real]
+    )
+    _assert_trees_bit_equal(got_state, want_state)
+    _assert_trees_bit_equal(got, want)
+    assert int(got_state.step) == real and float(got["count"]) == 4 * real
+
+
+@pytest.mark.parametrize("scan_chunk", [1, 2, 4, 7, 64])
+def pytest_scan_epoch_bit_equal_at_any_chunk_length(scan_chunk):
+    """An epoch of a one-shape loader (7 batches) leaves the SAME parameters,
+    to the bit, whatever the chunk length: a batch a dispatch, padded tails
+    (2, 4), one exact chunk (7), one padded chunk (64); all equal to seven
+    sequential ``train_step``s. No padding slot is executed or counted."""
+    loader = _one_shape_loader(12, 28, shuffle=False)
+    driver = _driver_for(loader)
+    driver.scan_chunk = scan_chunk
+    want_state, want = _sequential_steps(
+        driver, _state_copy(driver.state), list(loader)
+    )
+    seen = []
+    after = driver._after_update
+    driver._after_update = lambda m: (seen.append(float(m["count"])), after(m))
+    loss, _ = driver.train_epoch(loader)
+    _assert_trees_bit_equal(driver.state, want_state)
+    assert int(driver.state.step) == 7
+    assert len(seen) == -(-7 // scan_chunk) and sum(seen) == 28.0
+    assert loss == pytest.approx(float(want["loss"]) / 28.0, rel=1e-6)
+    from hydragnn_tpu import telemetry
+
+    assert telemetry.gauges_snapshot()["train/scan_chunks_per_epoch"] == len(seen)
+
+
+def pytest_one_scan_program_a_shape_whatever_the_epoch_length():
+    """Epochs of 5, 6 and 7 batches of one shape compile ``epoch_scan`` ONCE
+    (tails of 1, 2 and 3 are the same stack of 4 with another count) and
+    ``train_step`` never: the scan path has no lone-batch route."""
+    from hydragnn_tpu.analysis import no_recompile
+
+    loaders = [_one_shape_loader(13, 4 * n, shuffle=True) for n in (5, 6, 7)]
+    driver = _driver_for(loaders[0])
+    driver.train_epoch(loaders[0])
+    with no_recompile(label="epochs of 6 and 7 batches"):
+        for loader in loaders[1:]:
+            driver.train_epoch(loader)
+    assert driver.epoch_scan._cache_size() == 1
+    assert driver.train_step._cache_size() == 0
+    assert int(driver.state.step) == 18
+
+
+class _GatedBatches:
+    """A loader of ready batches whose ``__next__`` number ``free + 1`` waits
+    until the consumer has been handed its first chunk."""
+
+    def __init__(self, batches, free):
+        import threading
+
+        self.batches, self.free = batches, free
+        self.first_chunk = threading.Event()
+        self.released = None
+
+    def __iter__(self):
+        for i, b in enumerate(self.batches):
+            if i == self.free:
+                self.released = self.first_chunk.wait(20.0)
+            yield b
+
+
+@pytest.mark.parametrize("shapes", [1, 2])
+def pytest_first_chunk_needs_no_more_than_a_chunk_of_batches(shapes):
+    """The epoch's first pull returns after at most ``L`` ``__next__`` calls
+    of a one-shape loader and ``2L - 1`` of a loader that alternates two
+    shapes, not after the loader has run dry (13 and 14 batches here): the
+    next call waits for the first dispatch, and is released by it."""
+    a = list(_one_shape_loader(14, 56, shuffle=False))
+    batches = a[:13]
+    if shapes == 2:
+        wide = GraphDataLoader(
+            _dataset(np.random.default_rng(15), count=28, lo=10, hi=11),
+            batch_size=4, shuffle=False,
+        )
+        wide.set_head_spec(("graph",), (1,))
+        batches = [b for pair in zip(a[:7], wide) for b in pair]
+    driver = _driver_for(batches[:1])
+    length = driver.scan_chunk
+    assert len({driver._shape_key(b) for b in batches}) == shapes
+    gated = _GatedBatches(batches, length if shapes == 1 else 2 * length - 1)
+    run = driver._run_scan_chunk
+
+    def first_seen(*args, **kw):
+        gated.first_chunk.set()
+        return run(*args, **kw)
+
+    driver._run_scan_chunk = first_seen
+    driver.train_epoch(gated)
+    assert gated.released is True
+    assert int(driver.state.step) == len(batches)
+    assert driver.epoch_scan._cache_size() == shapes
+
+
+def pytest_cached_replay_over_a_padded_tail_visits_real_batches_only():
+    """``reshuffle="batch"`` replay at 7 batches by 3: the cached tail holds
+    one real batch and two padding slots. With the padding slots of every
+    cached chunk overwritten by NaN, two replay epochs visit each real batch
+    once (28 graphs counted an epoch, in a shuffled order) and stay finite."""
+    loader = _one_shape_loader(16, 28, shuffle=True, reshuffle="batch")
+    driver = _driver_for(loader)
+    driver.scan_chunk = 3
+    loader.set_epoch(0)
+    driver.train_epoch(loader)
+    entry = driver._scan_cache[id(loader)]
+    assert [steps for steps, _ in entry["chunks"]] == [3, 3, 1]
+    entry["chunks"] = [
+        (steps, (jax.tree_util.tree_map(
+            lambda a: a.at[steps:].set(np.nan) if a.dtype.kind == "f" else a,
+            stacked,
+        ), real))
+        for steps, (stacked, real) in entry["chunks"]
+    ]
+    perms, counted = [], []
+    replay, after = driver._perm_scan, driver._after_update
+
+    def spy(state, stacked, perm, real, rng):
+        perms.append((np.asarray(perm), int(real)))
+        return replay(state, stacked, perm, real, rng)
+
+    driver._perm_scan = spy
+    driver._after_update = lambda m: (counted.append(float(m["count"])), after(m))
+    for epoch in (1, 2):
+        loader.set_epoch(epoch)
+        loss, _ = driver.train_epoch(loader)
+        assert np.isfinite(loss)
+    assert sum(counted) == 2 * 28.0
+    for perm, real in perms:
+        assert sorted(perm[:real]) == list(range(real))
+        assert list(perm[real:]) == list(range(real, 3))
+    assert sorted(real for _, real in perms) == [1, 1, 3, 3, 3, 3]
+    assert int(driver.state.step) == 21
+    for leaf in jax.tree_util.tree_leaves(driver.state.params):
+        assert np.isfinite(np.asarray(leaf)).all()
 
 
 def pytest_piped_evaluate_matches_unpiped():
@@ -305,15 +510,18 @@ def pytest_driver_cache_skips_fixed_order_batch_loader():
 
 
 # ------------------------------------------------ single-transfer cache build
-def pytest_cache_build_single_transfer_per_chunk(monkeypatch):
+@pytest.mark.parametrize("scan_chunk", [3, 7, 64])
+def pytest_cache_build_single_transfer_per_chunk(monkeypatch, scan_chunk):
     """The cache-building epoch must perform exactly ONE host->device
-    transfer per chunk — the pipeline's device copy is fed to both the step
-    and the cache sink (previously each chunk transferred twice)."""
+    transfer per chunk — the pipeline's device copy, the stack with its count
+    of real batches, is fed to both the step and the cache sink (previously
+    each chunk transferred twice) — with a padded tail (7 batches by 3), none
+    (by 7) and one padded chunk for the epoch (by 64)."""
     ds = _dataset(np.random.default_rng(7))
     loader = GraphDataLoader(ds, batch_size=4, shuffle=True, reshuffle="batch")
     loader.set_head_spec(("graph",), (1,))
     driver = _driver_for(loader)
-    driver.scan_chunk = 3
+    driver.scan_chunk = scan_chunk
     n_batches = len(loader)
     n_chunks = -(-n_batches // driver.scan_chunk)  # one shape bucket
 
@@ -333,7 +541,11 @@ def pytest_cache_build_single_transfer_per_chunk(monkeypatch):
     assert count["n"] == n_chunks, (
         f"cache build did {count['n']} transfers for {n_chunks} chunks"
     )
-    assert driver._scan_cache[id(loader)]["chunks"] is not None
+    cached = driver._scan_cache[id(loader)]["chunks"]
+    assert sum(steps for steps, _ in cached) == n_batches  # real batches only
+    for steps, (stacked, real) in cached:
+        assert int(real) == steps
+        assert {a.shape[0] for a in jax.tree_util.tree_leaves(stacked)} == {scan_chunk}
     # The pipeline's split instrumentation saw those same transfers.
     assert driver.feed_stats.h2d_transfers == n_chunks
     assert driver.feed_stats.h2d_bytes > 0
@@ -486,8 +698,10 @@ def pytest_feed_wait_is_credited_on_every_path(timeline):
     """``FeedStats.feed_wait_s`` is what the consumer really waited: non-zero
     under a slow collation on the scan path too (it read 0.0 there by
     construction), and equal to the ``feed_wait`` spans' seconds, which are
-    its one clock. The scan path's first pull waits for the whole epoch's
-    collation: no chunk is handed over before the loader is exhausted."""
+    its one clock. The scan path's first pull waits for ONE chunk's
+    collations (``SCAN_CHUNK`` of the epoch's 12), not for the loader's end."""
+    from hydragnn_tpu.train.train_validate_test import SCAN_CHUNK
+
     path, driver, spans = timeline
     last = [r for r in spans if r["name"] == "train_epoch"][-1]
     waits = [
@@ -503,7 +717,9 @@ def pytest_feed_wait_is_credited_on_every_path(timeline):
             if r["name"] == "collate" and r["parent_id"] == last["span_id"]
         ]
         assert len(collates) == 13  # 12 batches and the pull that ends them
-        assert waits[0]["dur_s"] >= 0.9 * sum(r["dur_s"] for r in collates[:12])
+        first = sum(r["dur_s"] for r in collates[:SCAN_CHUNK])
+        assert 0.9 * first <= waits[0]["dur_s"] < first + collates[SCAN_CHUNK]["dur_s"]
+        assert len(waits) == 12 // SCAN_CHUNK + 1  # a pull a chunk, and the end
         drains = [
             r for r in spans
             if r["name"] == "feed_drain" and r["parent_id"] == last["span_id"]
@@ -522,7 +738,10 @@ def pytest_scan_path_feed_wait_equals_its_spans():
         loader = GraphDataLoader(ds, batch_size=4, shuffle=True)
         loader.set_head_spec(("graph",), (1,))
         driver = _driver_for(loader)
-        driver.fault_plan = FaultPlan("slow_collate@3:ms=60")
+        # The slow batch is in the epoch's first chunk, so the first pull
+        # waits out its sleep less the feed thread's head start on the
+        # consumer (milliseconds: 100 ms slept leave well over 60 waited).
+        driver.fault_plan = FaultPlan("slow_collate@3:ms=100")
         driver.train_epoch(loader)
         stats = driver.feed_stats.as_dict()
         spans = [r for r in telemetry.collected_records() if r["kind"] == "span"]

@@ -99,9 +99,12 @@ def _compiled_text(conv, batch, build=make_train_step, stacked=None, **model_kw)
     opt = select_optimizer("AdamW", 1e-3)
     state = create_train_state(model, init_model_variables(model, batch), opt)
     step = build(model, opt, donate=False)
-    return step.lower(
-        state, batch if stacked is None else stacked, jax.random.PRNGKey(0)
-    ).compile().as_text()
+    if stacked is None:
+        args = (state, batch, jax.random.PRNGKey(0))
+    else:  # the scanned epoch: every stacked batch real
+        slots = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+        args = (state, stacked, np.asarray(slots, np.int32), jax.random.PRNGKey(0))
+    return step.lower(*args).compile().as_text()
 
 
 def _op_names(text):

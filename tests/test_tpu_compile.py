@@ -506,3 +506,84 @@ def pytest_grouped_matmul_tiles_follow_the_weights(one_chip):
     assert not dense, f"the experts' rows as a dense batch: {dense.group(0)}"
     assert text.count("tpu_custom_call") >= 9
     assert f"f32[{held},{d},{f_}]" in text and f"f32[{held},{f_},{d}]" in text
+
+
+def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monkeypatch):
+    """The scan path's program (``make_train_epoch_scan``) for the whole GATv2
+    model of ``gatv2_h64x6_md17like.train_b512`` over a stack of
+    ``SCAN_CHUNK`` batches of the cell's one shape (16384 × 262144, 513 graph
+    slots): ONE ``while`` whose condition compares the induction variable
+    with the ``count`` PARAMETER, no constant (so no trip count is known to
+    the compiler and a tail needs no program of its own), whose body holds
+    the step (the two backward scatter-adds a layer into ``f32[16384,384]``),
+    within the chip's memory."""
+    import json
+
+    import numpy as np
+
+    from hydragnn_tpu.graphs.batch import GraphBatch
+    from hydragnn_tpu.models.create import create_model_config, init_model_variables
+    from hydragnn_tpu.ops.segment import platform_override
+    from hydragnn_tpu.train.train_validate_test import SCAN_CHUNK
+    from hydragnn_tpu.train.trainer import create_train_state, make_train_epoch_scan
+    from hydragnn_tpu.utils.optimizer import select_optimizer
+
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "graftbench/configs/gatv2_h64x6_md17like.json")) as f:
+        arch = json.load(f)["NeuralNetwork"]["Architecture"]
+    # What config completion reads off the cell's data (one node feature, one
+    # graph target, 21 atoms a molecule).
+    arch.update(input_dim=1, output_dim=[1], output_type=["graph"], num_nodes=21)
+    model = create_model_config(config=arch)
+    opt = select_optimizer("AdamW", 1e-3)
+    n, e, g = 16384, 262144, 513
+
+    def batch(lead, make):
+        def arr(shape, dtype=np.float32):
+            return make(lead + shape, dtype)
+
+        return GraphBatch(
+            node_features=arr((n, 1)), edge_features=arr((e, 1)),
+            senders=arr((e,), np.int32), receivers=arr((e,), np.int32),
+            node_graph=arr((n,), np.int32), node_mask=arr((n,), np.bool_),
+            edge_mask=arr((e,), np.bool_), graph_mask=arr((g,), np.bool_),
+            targets=(arr((g, 1)),), row_ptr=arr((n + 1,), np.int32),
+            graph_ptr=arr((g + 1,), np.int32), num_graphs_pad=g,
+        )
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: create_train_state(
+            model, init_model_variables(model, batch((), jnp.zeros)), opt
+        )),
+    )
+    with platform_override("tpu"):
+        lowered = make_train_epoch_scan(model, opt).lower(
+            state, batch((SCAN_CHUNK,), shaped), shaped((), jnp.int32),
+            shaped((2,), jnp.uint32),
+        )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+
+    entry = text[text.index("ENTRY "):]
+    assert re.search(r"%count\S* = s32\[\]\S* parameter\(", entry), (
+        "no s32[] parameter named count: the trip count is not an argument"
+    )
+    loops = [
+        line.split(", metadata=")[0] for line in entry.splitlines()
+        if re.search(r"\swhile\(", line.split(", metadata=")[0])
+    ]
+    assert len(loops) == 1, len(loops)  # the steps; sorts and sums loop inside
+    assert "known_trip_count" not in loops[0]
+    name = re.search(r"condition=(%[\w.\-]+)", loops[0]).group(1)
+    cond = text[text.index("\n" + name + " ("):]
+    cond = cond[:cond.index("\n}")]
+    assert "compare(" in cond and "direction=LT" in cond
+    assert "constant(" not in cond, cond
+    scatters = re.findall(r"f32\[16384,384\]\S* scatter\(", text)
+    assert len(scatters) >= 6, len(scatters)
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
