@@ -1,10 +1,12 @@
 """Milliseconds the consumer waited for the FIRST payload of a train epoch
 (the first ``feed_wait`` span under each ``train_epoch`` of the window),
-mean over the epochs: on the scan path the whole epoch's collation, since no
-chunk is handed over before the loader is exhausted; on the mesh path one
-group of batches. What ROADMAP S7 would shorten. None where a train epoch
-waits on no feed (a program without the span there, a device-resident
-replay)."""
+mean over the epochs. Since PR 36 on the scan path that is ONE chunk's wait:
+``TrainingDriver._host_chunks`` hands a shape's chunk on once ``SCAN_CHUNK``
+= 4 of its batches are collated (a tail as the same stack with a smaller
+count), where before it the loader had to run dry first and the wait was the
+whole epoch's collation; on the mesh path it is one group of batches. What
+ROADMAP S7 would shorten. None where a train epoch waits on no feed (a
+program without the span there, a device-resident replay)."""
 
 from graftbench import host_phases
 

@@ -5,8 +5,9 @@
 A new process that loads, warms up every shape the cell's traffic uses,
 measures for ``--seconds``, and prints ONE last line of standard output: a
 JSON object with ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, traced, ``breakdown``. Everything else worth reading goes on
-earlier lines. A run that finds no TPU, or fewer chips than the cell asks
+``device``, traced ``breakdown`` and, last, where the driver compares numbers
+against limits, ``compared``. Everything else worth reading goes on earlier
+lines. A run that finds no TPU, or fewer chips than the cell asks
 for, exits non-zero and prints no result; so does one started away from the
 program it measures.
 
@@ -346,6 +347,14 @@ def main(argv=None, allow_cpu: bool = False) -> int:
     }
     if breakdown is not None:
         line["breakdown"] = breakdown
+    # Each number a driver compared beside its limit: the line's last key,
+    # and the last lines of standard error.
+    compared = result.get("compared")
+    if compared:
+        line["compared"] = compared
+        for name, row in compared.items():
+            print(f"[graftbench] compared {name}: {json.dumps(row)}", file=sys.stderr)
+        sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -11,6 +11,13 @@ REPO = os.path.dirname(BENCH_DIR)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# Keys a ``reduced`` list may never hold, beside any that ends in ``_dim`` or
+# ``_rank``: the widths of the configurations' sources.
+WIDTHS = {
+    "hidden_size", "hidden_dim", "intermediate_size", "moe_intermediate_size",
+    "shared_expert_intermediate_size", "head_dim", "num_experts_per_tok",
+    "conv_L_cache", "sliding_window",
+}
 
 
 def _bench():
@@ -37,7 +44,7 @@ def pytest_keys_names_units_and_lengths():
         assert NAME.match(c["name"]) and _line(c["source"]) and _line(c["why"])
         assert len(c["reduced"]) <= 16 and all(NAME.match(k) for k in c["reduced"])
         assert not any(
-            k.endswith(("_dim", "_rank")) or "hidden" in k for k in c["reduced"]
+            k.endswith(("_dim", "_rank")) or k in WIDTHS for k in c["reduced"]
         ), "reduced may never name a width"
     for w in b["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}

@@ -55,6 +55,11 @@ def make_copy(dst: str) -> str:
                 cell_x=[1, 3], cell_y=[1, 2], cell_z=[1, 2], number_types=2
             )
         traffic["batch_size"] = 16
+        if "bucket_ladder" in traffic:  # a serving mix: 4 clients of 2 or 4
+            # atoms, a rung for most flushes and the guard for 4 of the largest
+            traffic["clients"] = traffic["engine"]["max_batch_graphs"] = 4
+            traffic["engine"]["queue_limit"] = 8
+            traffic["bucket_ladder"] = [[16, 40], [32, 64]]
         with open(os.path.join(root, "traffic", "tiny_" + entry["traffic"] + ".json"), "w") as f:
             json.dump(traffic, f)
         cells.append(dict(entry, name="tiny." + entry["traffic"],
@@ -96,12 +101,15 @@ def cell(root: str, driver: str, chips: int = 1, model: str = "PNA") -> str:
 
 
 def run_cell(root: str, workload: str, seconds: float = 1.0, trace: int = 0,
-             seed: int = 0, devices: int = 1, timeout: float = 600.0):
+             seed: int = 0, devices: int = 1, timeout: float = 600.0,
+             prelude: str = ""):
     """One run of ``workload`` from the copy at ``root``, in a process of its
     own on the CPU backend (``allow_cpu`` is an argument of ``main`` that no
-    command line reaches). Returns (exit code, parsed last line, stdout)."""
-    code = (
-        "import sys; from graftbench.run import main; "
+    command line reaches). ``prelude`` is code run in that process first: a
+    test breaks the timed path with it. Returns (exit code, parsed last line,
+    stdout)."""
+    code = prelude + (
+        "\nimport sys; from graftbench.run import main; "
         f"sys.exit(main(['--workload', {workload!r}, '--seed', '{seed}', "
         f"'--seconds', '{seconds}', '--trace', '{trace}'], allow_cpu=True))"
     )
