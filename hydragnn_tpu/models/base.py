@@ -13,7 +13,8 @@ Architecture (mirrors reference semantics under padding):
              embedding, num_conv_layers × [operator → feed-forward] with
              RMSNorm and a residual round each, a final RMSNorm
              (models/lfm2.py): no edge list is read. LAGUNA: the same
-             loop over Laguna-XS.2's block (models/laguna.py).
+             loop over Laguna-XS.2's block (models/laguna.py). MISTRAL4:
+             over Mistral-Small-4's (models/mistral4.py).
   readout:   masked segment-mean over nodes per graph (global_mean_pool analog)
   heads:     graph heads = shared MLP ("graph_shared") + per-head MLP;
              node heads = shared MLPNode ('mlp' / 'mlp_per_node') or a conv chain
@@ -35,14 +36,19 @@ from ..ops import segment as seg
 from ..telemetry import scopes
 from .layers import MLP, MaskedBatchNorm
 from . import laguna as laguna_model, lfm2 as lfm2_model, painn
+from . import mistral4 as mistral4_model
 from .convs import (
     POSITION_FAMILIES, TOKEN_STACKS, CGConv, GATv2Conv, GINConv, MFCConv,
     PNAConv, SAGEConv,
 )
 
 CONV_TYPES = (
-    "PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE", "PAINN", "LFM2", "LAGUNA"
+    "PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE", "PAINN", "LFM2", "LAGUNA",
+    "MISTRAL4",
 )
+# Rows of a block of ``HydraGNN.score_tokens``: a class head's logits exist a
+# block at a time ([512, classes]), never as [N, classes].
+LOGPROB_BLOCK = 512
 
 
 class MLPNode(nn.Module):
@@ -131,6 +137,8 @@ class HydraGNN(nn.Module):
     lfm2: Optional[lfm2_model.LFM2Config] = None
     # LAGUNA: the same (models/laguna.py).
     laguna: Optional[laguna_model.LagunaConfig] = None
+    # MISTRAL4: the same (models/mistral4.py).
+    mistral4: Optional[mistral4_model.Mistral4Config] = None
     # Loss kind a head ("rmse" | "cross_entropy"; () = rmse throughout) and,
     # for a cross-entropy head, the dataset's (min, max) of its target column,
     # from which the class ids are un-scaled (models/loss.py).
@@ -148,7 +156,7 @@ class HydraGNN(nn.Module):
 
     @property
     def token_cfg(self):
-        """The sizes of a token stack (LFM2, LAGUNA); None for the others."""
+        """The sizes of a token stack (``TOKEN_STACKS``); None for the others."""
         if self.conv_type in TOKEN_STACKS:
             return getattr(self, self.conv_type.lower())
         return None
@@ -466,7 +474,66 @@ class HydraGNN(nn.Module):
             x = self._encode_tokens(batch)
         else:
             x = self._encode_convs(batch, train)
+        return self._heads(x, batch, train)
 
+    @property
+    def scored_heads(self) -> Tuple[int, ...]:
+        """The heads ``score_tokens`` answers with log-probabilities: a token
+        family's node heads under a cross-entropy loss, one shared MLP."""
+        if self.conv_type not in TOKEN_STACKS:
+            return ()
+        node_type = self.config_heads.get("node", {}).get("type")
+        return tuple(
+            i for i, (kind, loss) in enumerate(zip(self.output_type, self.head_loss))
+            if kind == "node" and loss == "cross_entropy" and node_type == "mlp"
+        )
+
+    @nn.nowrap
+    def score_tokens(self, batch: GraphBatch):
+        """What the serving engine's executable returns for a token family
+        (``model.apply(..., method=HydraGNN.score_tokens)``): each of
+        ``scored_heads`` as ``[N, 1]``, ``log softmax(logits)[i, token_{i+1}]``
+        with the next token read from the node column inside the same
+        sequence, 0 for a sequence's last token and for padding; every other
+        head as ``__call__`` gives it. The ``[N, classes]`` logits are taken
+        ``LOGPROB_BLOCK`` rows at a time and never held whole."""
+        x = self._encode_tokens(batch)
+        ids = lfm2_model.token_ids(batch.node_features[:, 0], self.token_cfg)
+        follows = jnp.concatenate([
+            (batch.node_graph[1:] == batch.node_graph[:-1])
+            & batch.node_mask[1:] & batch.node_mask[:-1],
+            jnp.zeros((1,), bool),
+        ])
+        nxt = jnp.concatenate([ids[1:], ids[:1]])
+        return self._heads(x, batch, False, score=(nxt, follows))
+
+    @nn.nowrap
+    def _next_token_logprob(self, ihead: int, x, nxt, follows):
+        head, variables = self.heads_nn[ihead].unbind()
+        n, classes = x.shape[0], self.output_dim[ihead]
+        block = min(LOGPROB_BLOCK, n)
+        pad = -n % block
+
+        def rows(args):
+            xb, tb = args
+            logits = head.apply(variables, xb, None).astype(jnp.float32)
+            top = jnp.max(logits, axis=-1, keepdims=True)
+            lse = top[:, 0] + jnp.log(jnp.sum(jnp.exp(logits - top), axis=-1))
+            # The next token's logit by a compare against an iota: a gather
+            # of one scalar a row would cost a row each.
+            hit = tb[:, None] == jnp.arange(classes)[None, :]
+            return jnp.sum(jnp.where(hit, logits, 0.0), axis=-1) - lse
+
+        with jax.named_scope(scopes.HEAD_LOGPROB):
+            xs = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, block, x.shape[1])
+            ts = jnp.pad(jnp.clip(nxt, 0, classes - 1), (0, pad)).reshape(-1, block)
+            logp = jax.lax.map(rows, (xs, ts)).reshape(-1)[:n]
+            return jnp.where(follows, logp, 0.0)[:, None]
+
+    @nn.nowrap
+    def _heads(self, x, batch: GraphBatch, train: bool, score=None):
+        """Read-out and heads over the encoder's output ``x``; ``score``
+        (``score_tokens``): the next token and where one follows."""
         # Masked global mean pool (Base.py:247-250); graph_ptr is the CSR
         # boundary array over node_graph (nodes are contiguous per graph).
         with jax.named_scope(scopes.POOL):
@@ -508,6 +575,8 @@ class HydraGNN(nn.Module):
                         xn = nn.relu(bn(xn, batch.node_mask, train))
                     inode += 1
                     outputs.append(xn)
+                elif score is not None and ihead in self.scored_heads:
+                    outputs.append(self._next_token_logprob(ihead, x, *score))
                 else:
                     outputs.append(self.heads_nn[ihead](x, batch))
         return outputs

@@ -34,7 +34,7 @@ import flax.linen as nn
 
 from ..ops import aggregate
 from ..telemetry import scopes
-from . import laguna, lfm2
+from . import laguna, lfm2, mistral4
 
 # Conv families whose aggregation rides the sorted/CSR edge layout end to end
 # (every family since PR 7 — GAT's sort-breaking [edges; self-loops] concat
@@ -43,9 +43,11 @@ from . import laguna, lfm2
 # the unsorted scatter path on TPU, which the contract checker now rejects
 # instead (analysis/contracts.py).
 SORTED_PATH_FAMILIES = frozenset(
-    # The token stacks read no edge list (models/lfm2.py, models/laguna.py):
+    # The token stacks read no edge list (models/lfm2.py, models/laguna.py,
+    # models/mistral4.py):
     # no aggregation to fall back.
-    {"SAGE", "GIN", "MFC", "GAT", "CGCNN", "PNA", "PAINN", "LFM2", "LAGUNA"}
+    {"SAGE", "GIN", "MFC", "GAT", "CGCNN", "PNA", "PAINN", "LFM2", "LAGUNA",
+     "MISTRAL4"}
 )
 # The token stacks: a sequence as a graph, a token a node, the node column a
 # min-max-scaled token id (``Architecture.token_minmax`` from completion).
@@ -54,6 +56,7 @@ SORTED_PATH_FAMILIES = frozenset(
 TOKEN_STACKS = {
     "LFM2": (lfm2.LFM2Config, lfm2.LFM2Block),
     "LAGUNA": (laguna.LagunaConfig, laguna.LagunaBlock),
+    "MISTRAL4": (mistral4.Mistral4Config, mistral4.Mistral4Block),
 }
 TOKEN_FAMILIES = frozenset(TOKEN_STACKS)
 # Families that read ``GraphBatch.positions`` inside the step (PaiNN its edge

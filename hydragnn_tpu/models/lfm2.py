@@ -262,7 +262,7 @@ def _band_attention_tpu(q, k, v, node_graph, window: int, scale: float):
     return out.reshape(heads, n, hd)
 
 
-def segment_causal_attention(q, k, v, node_graph, window=None):
+def segment_causal_attention(q, k, v, node_graph, window=None, scale=None):
     """Softmax aggregation over the complete causal graph of each sequence:
     node ``i`` receives from every node ``j <= i`` of its own graph; with a
     ``window``, over the causal BAND: also ``i - j < window`` (the node
@@ -280,10 +280,13 @@ def segment_causal_attention(q, k, v, node_graph, window=None):
     before its start), rematerialized in the backward. Either way the
     largest score array is a block's, never ``[N, N]``. Padding nodes share
     the padding graph's id and attend among themselves (every row keeps its
-    diagonal, so no softmax is empty)."""
+    diagonal, so no softmax is empty). ``scale`` multiplies the scores:
+    ``hd ** -0.5`` unless a stack states its own (models/mistral4.py: YaRN's
+    ``mscale`` squared rides on it)."""
     n, heads, hd = q.shape
     kv = k.shape[1]
-    scale = hd ** -0.5
+    if scale is None:
+        scale = hd ** -0.5
     on_tpu = execution_platform() == "tpu"
     pad = -n % ATTN_BLOCK
     if pad:
@@ -423,8 +426,11 @@ _gmm_tpu.defvjp(_gmm_tpu_fwd, _gmm_tpu_bwd)
 def grouped_matmul(lhs, rhs, sizes):
     """``[rows, k] x [groups, k, n] -> [rows, n]``: rows ``sizes[0]`` first
     by group 0, the next ``sizes[1]`` by group 1, ...; rows past the last
-    group are NOT multiplied and hold whatever the kernel left there."""
-    if execution_platform() == "tpu":
+    group are NOT multiplied and hold whatever the kernel left there. The
+    TPU's kernel takes whole row tiles: fewer rows than that (an initializer's
+    example batch of 4 nodes, as ``InferenceEngine.from_config`` builds one)
+    go through ``ragged_dot`` there too."""
+    if execution_platform() == "tpu" and lhs.shape[0] % GMM_TILING[0] == 0:
         return _gmm_tpu(lhs, rhs, sizes)
     return jax.lax.ragged_dot(lhs, rhs, sizes)
 
@@ -529,7 +535,8 @@ _in_passes.defvjp(_in_passes_fwd, _in_passes_bwd)
 
 
 class RoutedFFN(nn.Module):
-    """``s = sigmoid(W_g x)`` over all ``num_experts``; the
+    """``s = sigmoid(W_g x)`` over all ``num_experts`` (``softmax(W_g x)``
+    for a stack whose sizes say ``scoring_func = "softmax"``); the
     ``num_experts_per_tok`` largest of ``s + b`` are chosen (``b`` the expert
     bias: a buffer, no gradient); ``w_e = s_e / (sum over the chosen + 1e-6)``
     times ``routed_scaling_factor``, the sum over ALL chosen, held or not;
@@ -575,9 +582,11 @@ class RoutedFFN(nn.Module):
         w2 = self.param("w2", _expert_init, (held, f, d))
         self.sow(INTERMEDIATES, "moe_router_in", x)
         with jax.named_scope(scopes.MOE_ROUTE):
-            s = jax.nn.sigmoid(
-                jnp.dot(x, gate, precision=jax.lax.Precision.HIGHEST)
-            )
+            s = jnp.dot(x, gate, precision=jax.lax.Precision.HIGHEST)
+            if getattr(c, "scoring_func", "sigmoid") == "softmax":
+                s = jax.nn.softmax(s, axis=-1)
+            else:
+                s = jax.nn.sigmoid(s)
             biased = s + jax.lax.stop_gradient(bias) if c.use_expert_bias else s
             _, chosen = jax.lax.top_k(biased, k)  # [N, K]
             # The chosen experts' own scores by a compare against an iota: a

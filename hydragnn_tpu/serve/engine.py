@@ -37,6 +37,13 @@ construction (masked BN/pool/heads, padding edges connect padding nodes), so
 engine outputs are bit-identical to ``run_prediction`` on CPU for the same
 checkpoint and graphs regardless of how requests are grouped into buckets
 (locked by tests/test_serve_engine.py).
+
+A token family (``models/convs.py`` ``TOKEN_STACKS``: a document as a graph,
+a token a node, no edges) is served on the same path: a request is the token
+column and each token's place, the ladder's rungs are counted in tokens, a
+class head answers with the log-probability of each next token instead of its
+logits, and the routed layers' choices come out of the same executable
+(docs/SERVING.md "Token families").
 """
 
 from __future__ import annotations
@@ -114,6 +121,49 @@ class SwapFingerprintError(RuntimeError):
     the tier down (docs/SERVING.md "Live model lifecycle")."""
 
 
+# The padded edge count of a token family's batches: such a request has no
+# edges, and this is the smallest count the round-up ladder ever emits
+# (graphs/collate.py ``round_up_pow2``'s minimum).
+TOKEN_EDGE_PAD = 8
+
+
+def _token_forward(model):
+    """The executable of a token family: ``HydraGNN.score_tokens`` (a class
+    head as ``[N, 1]`` log-probabilities of the next token) and, where the
+    stack routes, the experts each node chose in every routed layer as ONE
+    ``[N, routed layers x K]`` int32 array, in layer order."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..models.base import HydraGNN
+    from ..models.lfm2 import INTERMEDIATES, split_intermediates
+
+    routes = model.counts_routing
+
+    def forward(params, bstats, batch):
+        out = model.apply(
+            {"params": params, "batch_stats": bstats}, batch,
+            method=HydraGNN.score_tokens,
+            mutable=[INTERMEDIATES] if routes else False,
+        )
+        if not routes:
+            return out, None
+        outputs, sown = out
+        per_layer, _ = split_intermediates(sown[INTERMEDIATES])
+        layers = sorted(per_layer, key=lambda name: int(name.rsplit("_", 1)[1]))
+        chosen = [per_layer[name]["chosen"].astype(jnp.int32) for name in layers]
+        return outputs, jnp.concatenate(chosen, axis=1)
+
+    return jax.jit(forward)
+
+
+class _Outputs(list):
+    """A flush's per-head host arrays; for a routed token family also the
+    experts every node chose (``routing``), which ride along to the demux."""
+
+    routing: Optional[np.ndarray] = None
+
+
 class _Future:
     """Minimal thread-safe future.
 
@@ -126,7 +176,9 @@ class _Future:
     ``concurrent.futures.TimeoutError`` is not the builtin ``TimeoutError``
     callers naturally catch.)"""
 
-    __slots__ = ("_event", "_result", "_error", "request_id", "model_version")
+    __slots__ = (
+        "_event", "_result", "_error", "request_id", "model_version", "routing",
+    )
 
     def __init__(self, request_id: Optional[str] = None):
         self._event = threading.Event()
@@ -139,6 +191,10 @@ class _Future:
         # set_result; the lifecycle layer's per-response version tag —
         # docs/SERVING.md "Live model lifecycle").
         self.model_version: Optional[str] = None
+        # A routed token family: the experts each of the request's tokens
+        # chose, [tokens, routed layers x K] int32 (set before set_result);
+        # None for every other family.
+        self.routing: Optional[np.ndarray] = None
 
     def set_result(self, value) -> None:
         self._result = value
@@ -302,6 +358,13 @@ class InferenceEngine:
             )
         self.precision = precision
         self.tolerance = None if tolerance is None else float(tolerance)
+        # A token family (models/convs.py TOKEN_STACKS): its sizes, else None.
+        self._token_cfg = model.token_cfg
+        if self._token_cfg is not None and precision != "f32":
+            raise ValueError(
+                f"{model.conv_type} reads token ids from a float32 node "
+                f"column; the {precision!r} arm's cast would round them"
+            )
         # Quantized-arm reference state: rebound only under _swap_lock
         # (created below; __init__ is pre-publication) — a swap and a
         # concurrent tolerance check must agree on which f32 reference
@@ -363,9 +426,7 @@ class InferenceEngine:
         # _pack_groups/_collate/_bucket_shape, so every batch — and
         # therefore every request — is planned against exactly one ladder
         # even while a swap lands mid-flush.
-        self._ladder = sorted(  # guarded-by: self._lock, dirty-reads(status surfaces read the immutable list reference for display; consistency-bearing readers snapshot under the lock via _current_ladder)
-            (int(n), int(e)) for n, e in (bucket_ladder or ())
-        )
+        self._ladder = self._rungs(bucket_ladder or ())  # guarded-by: self._lock, dirty-reads(status surfaces read the immutable list reference for display; consistency-bearing readers snapshot under the lock via _current_ladder)
         self._packing = bool(packing)
         self._ladder_step = ladder_step
 
@@ -380,11 +441,14 @@ class InferenceEngine:
             "id": dev.id,
             "visible": jax.device_count(),
         }
-        self._jit = jax.jit(
-            lambda params, bstats, batch: _apply_model(
-                model, params, bstats, batch, train=False
+        if self._token_cfg is None:
+            self._jit = jax.jit(
+                lambda params, bstats, batch: _apply_model(
+                    model, params, bstats, batch, train=False
+                )
             )
-        )
+        else:
+            self._jit = _token_forward(model)
         self._lock = tsan.instrument_lock(
             threading.Lock(), "InferenceEngine._lock"
         )
@@ -526,6 +590,17 @@ class InferenceEngine:
         this cross-thread (the registry's len() holds its own lock;
         callers must not reach through the registry's internals directly)."""
         return len(self._registry)
+
+    def _rungs(self, ladder) -> List[Tuple[int, int]]:
+        """``ladder`` as the sorted ``(N_pad, E_pad)`` rungs the batcher
+        selects from. A token family's rungs are counted in TOKENS (a bare
+        int, or a pair whose edge count is not read): every one takes
+        ``TOKEN_EDGE_PAD`` edges."""
+        if self._token_cfg is None:
+            return sorted({(int(n), int(e)) for n, e in ladder})
+        return sorted({
+            (int(r if np.ndim(r) == 0 else r[0]), TOKEN_EDGE_PAD) for r in ladder
+        })
 
     def _current_weights(self) -> Tuple[Any, Any, str]:
         """One locked read of the atomic (params, batch_stats, version)
@@ -750,6 +825,8 @@ class InferenceEngine:
                 f"sample.x feature width {x.shape[1]} != model input_dim "
                 f"{self.model.input_dim}"
             )
+        if self._token_cfg is not None:
+            self._validate_tokens(sample)
         if sample.edge_index is not None:
             ei = np.asarray(sample.edge_index)
             if ei.ndim != 2 or ei.shape[0] != 2:
@@ -791,6 +868,34 @@ class InferenceEngine:
         # No size ceiling: a graph too large for every ladder rung is still
         # serveable through _bucket_shape's pow2 fallback (one compile,
         # counted as ladder_fallback_total).
+
+    def _validate_tokens(self, sample: GraphSample) -> None:
+        """A token family's request: the min-max-scaled token column with
+        every id inside the rank's vocabulary slice, each token's place in
+        ``pos[:, 0]`` counting 0 .. T-1, and no edges (the stack reads none,
+        and a rung holds ``TOKEN_EDGE_PAD`` of them)."""
+        cfg, n = self._token_cfg, sample.num_nodes
+        lo, hi = cfg.token_minmax
+        ids = np.asarray(sample.x, np.float64)[:, 0] * (hi - lo) + lo
+        if n and not (ids.min() > -0.5 and ids.max() < cfg.vocab_size - 0.5):
+            raise ValueError(
+                f"sample.x holds a token id outside 0..{cfg.vocab_size - 1} "
+                f"(scaled by token_minmax {list(cfg.token_minmax)})"
+            )
+        if sample.pos is None or np.shape(sample.pos) != (n, 3):
+            raise ValueError(
+                f"a token request carries each token's place: sample.pos "
+                f"must be [{n}, 3] with the place in column 0"
+            )
+        if not np.array_equal(np.asarray(sample.pos)[:, 0], np.arange(n)):
+            raise ValueError(
+                "sample.pos[:, 0] must count the document's places 0..T-1 "
+                "in order"
+            )
+        if sample.num_edges:
+            raise ValueError(
+                f"{self.model.conv_type} reads no edges; send edge_index=None"
+            )
 
     def _retry_after_hint(self) -> float:
         """Seconds until the queue has likely drained one batch's worth:
@@ -1028,8 +1133,10 @@ class InferenceEngine:
         )
 
     def _execute(self, dev_batch) -> Tuple[List[np.ndarray], str]:
-        """Run the (cached) compiled executable; host numpy outputs plus the
-        model version the batch executed against. The weight reference is
+        """Run the (cached) compiled executable; host numpy outputs (for a
+        routed token family with the experts every node chose beside them,
+        ``_Outputs.routing``) plus the model version the batch executed
+        against. The weight reference is
         read ONCE here, so the whole batch — and every response demuxed from
         it — belongs to exactly one version even while a swap publishes a
         new one concurrently."""
@@ -1041,7 +1148,14 @@ class InferenceEngine:
         outputs = exe(params, bstats, dev_batch)
         outputs = jax.block_until_ready(outputs)
         self.metrics.observe("device", time.perf_counter() - t0)
-        return [np.asarray(o) for o in outputs], version
+        routing = None
+        if self._token_cfg is not None:
+            outputs, routing = outputs
+        with telemetry.span("serve/d2h"):
+            host = _Outputs(np.asarray(o) for o in outputs)
+            if routing is not None:
+                host.routing = np.asarray(routing)
+        return host, version
 
     def _dispatch_loop(self) -> None:
         # Explicit context handoff: the dispatcher's device spans parent to
@@ -1075,11 +1189,42 @@ class InferenceEngine:
         except BaseException as e:  # noqa: BLE001 — re-raised at callers
             self._fail(e)
 
+    def _count_routing(self, routing: np.ndarray, real: int) -> None:
+        """A flush's routing in the engine's counters (serve/metrics.py) and
+        as graftel gauges: over the flush's ``real`` tokens and each routed
+        layer, the rows sent to held experts, the fullest held expert's rows
+        and the layers whose rows passed the compact path's ``C`` and took a
+        further pass (models/lfm2.py ``RoutedFFN``)."""
+        from ..models.lfm2 import _capacity
+
+        cfg, n_pad = self._token_cfg, routing.shape[0]
+        k, held = cfg.num_experts_per_tok, cfg.num_experts_held
+        local = routing[:real].reshape(real, -1, k) - cfg.experts_offset
+        loads = np.stack([
+            np.bincount(layer[(layer >= 0) & (layer < held)], minlength=held)
+            for layer in np.moveaxis(local, 1, 0)
+        ])  # [routed layers, held]
+        cap = min(_capacity(n_pad * k, held, cfg.num_experts), n_pad * k)
+        counted = {
+            "moe_rows_held_total": int(loads.sum()),
+            "moe_load_max_total": int(loads.max(axis=1).sum()),
+            "moe_fallback_layers_total": int((loads.sum(axis=1) > cap).sum()),
+        }
+        for name, value in counted.items():
+            self.metrics.count(name, value)
+            telemetry.gauge("serve/" + name[: -len("_total")], value)
+
     def _resolve(
         self, work: _BatchWork, outputs: List[np.ndarray], version: str
     ) -> None:
         now = time.perf_counter()
         batch_had_nonfinite = False
+        routing = getattr(outputs, "routing", None)
+        if routing is not None:
+            last = work.requests[-1]
+            self._count_routing(
+                routing, int(work.node_start[-1]) + last.sample.num_nodes
+            )
         for i, req in enumerate(work.requests):
             per_head: List[np.ndarray] = []
             for ihead, htype in enumerate(self.model.output_type):
@@ -1112,6 +1257,9 @@ class InferenceEngine:
             # Version tag BEFORE set_result: a waiter woken by the event
             # must never observe a result without its version.
             req.future.model_version = version
+            if routing is not None:
+                start = int(work.node_start[i])
+                req.future.routing = routing[start : start + req.sample.num_nodes]
             req.future.set_result(per_head)
             self.metrics.observe("e2e", now - req.t_submit)
             # Demux complete: the end of the correlation trail
@@ -1253,9 +1401,7 @@ class InferenceEngine:
         Returns the number of executables compiled."""
         if ladder:
             with self._lock:
-                self._ladder = sorted(
-                    set(self._ladder) | {(int(n), int(e)) for n, e in ladder}
-                )
+                self._ladder = sorted(set(self._ladder) | set(self._rungs(ladder)))
         compiled = 0
         params, bstats, _version = self._current_weights()
         # Iterate the MERGED ladder: constructor-declared buckets still cold
@@ -1286,7 +1432,9 @@ class InferenceEngine:
         s = GraphSample(
             x=np.zeros((1, self.model.input_dim), np.float32),
             pos=np.zeros((1, 3), np.float32),
-            edge_index=np.zeros((2, 1), np.int32),
+            # A token family's batches hold no edge.
+            edge_index=None if self._token_cfg is not None
+            else np.zeros((2, 1), np.int32),
             edge_attr=np.zeros((1, max(self._edge_dim, 1)), np.float32)
             if self._edge_dim
             else None,
@@ -1324,7 +1472,7 @@ class InferenceEngine:
 
         Returns {ladder, previous, compiled, hydrated, wall_s}.
         """
-        new = sorted({(int(n), int(e)) for n, e in ladder})
+        new = self._rungs(ladder)
         if not new:
             raise ValueError(
                 "swap_ladder needs at least one (N_pad, E_pad) rung"

@@ -200,6 +200,13 @@ class ServeMetrics:
         self.exec_cache_hydrated_total = 0  # guarded-by: self._lock
         self.exec_cache_hydrate_seconds_total = 0.0  # guarded-by: self._lock
         self.h2d_bytes_total = 0  # guarded-by: self._lock
+        # A routed token family (serve/engine.py _count_routing), summed over
+        # the flushes and their routed layers: rows sent to held experts, the
+        # fullest held expert's rows, and the layers whose rows passed the
+        # compact path's capacity and took a further pass.
+        self.moe_rows_held_total = 0  # guarded-by: self._lock
+        self.moe_load_max_total = 0  # guarded-by: self._lock
+        self.moe_fallback_layers_total = 0  # guarded-by: self._lock
         # Occupancy / padding accumulators (averages derived in snapshot()).
         self._occupancy_sum = 0.0  # guarded-by: self._lock
         self._node_fill_sum = 0.0  # guarded-by: self._lock
@@ -334,6 +341,9 @@ class ServeMetrics:
                     ),
                 },
                 "h2d_bytes_total": self.h2d_bytes_total,
+                "moe_rows_held_total": self.moe_rows_held_total,
+                "moe_load_max_total": self.moe_load_max_total,
+                "moe_fallback_layers_total": self.moe_fallback_layers_total,
                 # Precision arm + tolerance-gate record (docs/PRECISION.md).
                 "precision": {
                     "arm": self.precision_arm,
@@ -417,6 +427,9 @@ class ServeMetrics:
         ("exec_cache_hydrated_total", "exec_cache_hydrated_total"),
         ("exec_cache_hydrate_seconds_total", "exec_cache_hydrate_seconds_total"),
         ("h2d_bytes_total", "h2d_bytes_total"),
+        ("moe_rows_held_total", "moe_rows_held_total"),
+        ("moe_load_max_total", "moe_load_max_total"),
+        ("moe_fallback_layers_total", "moe_fallback_layers_total"),
     )
 
     def render_prometheus(self) -> str:

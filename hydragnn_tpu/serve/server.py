@@ -376,13 +376,18 @@ class _Handler(RequestPlumbing, BaseHTTPRequestHandler):
                 "request_id": rid,
                 "model_version": call_versions[-1] if call_versions else None,
                 "model_versions": versions,
+                # A token family's class head replies one log-probability a
+                # token, not its `dim` logits (docs/SERVING.md).
                 "heads": [
                     {"name": name, "type": htype, "dim": int(dim)}
-                    for name, htype, dim in zip(
+                    if ihead not in engine.model.scored_heads
+                    else {"name": name, "type": htype, "dim": 1,
+                          "classes": int(dim), "reply": "next_token_logprob"}
+                    for ihead, (name, htype, dim) in enumerate(zip(
                         engine.head_names,
                         engine.model.output_type,
                         engine.model.output_dim,
-                    )
+                    ))
                 ],
                 "predictions": [
                     [np.asarray(h).tolist() for h in per_graph]

@@ -51,6 +51,18 @@ LFM2_ATTN = "hydragnn.lfm2.attn"
 # complete causal graph and under the causal band of the sliding window.
 ATTN_FULL = "hydragnn.attn.full"
 ATTN_WINDOW = "hydragnn.attn.window"
+# Mistral-Small-4's latent attention (models/mistral4.py): the two low-rank
+# chains with their norms, the rotation of the rotary parts and the
+# concatenation into whole heads; the causal kernel's calls stay under
+# ATTN_FULL (PR 39; names added, none changed).
+ATTN_LATENT = "hydragnn.attn.latent"
+# The shared expert beside the routed ones, in that block alone (Laguna's
+# stays with its module, where its readers book it).
+MOE_SHARED = "hydragnn.moe.shared"
+# The serving engine's reply of a class head on a token family
+# (HydraGNN.score_tokens): the head's matmul in row blocks, the log-softmax
+# and the pick of the next token's log-probability.
+HEAD_LOGPROB = "hydragnn.head.logprob"
 # The routed experts: router, top-k, the sort by expert, both row
 # permutations and the weighting; and the grouped matmuls alone.
 MOE_ROUTE = "hydragnn.moe.route"
@@ -78,7 +90,7 @@ def agg(what: str, arm: str) -> str:
 VOCABULARY = frozenset(
     ROOTS
     + (GATHER, POOL, GEOM, LFM2_CONV, LFM2_ATTN, ATTN_FULL, ATTN_WINDOW)
-    + (MOE_ROUTE, MOE_EXPERTS)
+    + (MOE_ROUTE, MOE_EXPERTS, ATTN_LATENT, MOE_SHARED, HEAD_LOGPROB)
     + (LOSS, OPTIMIZER, GRAD_SYNC, AGG_PNA)
     + tuple(agg(w, a) for w in AGG_WHATS for a in AGG_ARMS)
 )

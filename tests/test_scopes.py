@@ -545,6 +545,37 @@ def pytest_vocabulary_table():
         scopes.agg("variance", "xla")
 
 
+def pytest_pr39_adds_three_names_and_changes_none():
+    """PR 39's leaves (latent attention's chains, the shared expert of that
+    block, the class head's reply in the engine's executable): added to the
+    vocabulary, every earlier name still there, so the version stands and no
+    cached executable is served under a changed meaning."""
+    added = {"hydragnn.attn.latent", "hydragnn.moe.shared", "hydragnn.head.logprob"}
+    assert {scopes.ATTN_LATENT, scopes.MOE_SHARED, scopes.HEAD_LOGPROB} == added
+    assert added <= scopes.VOCABULARY and scopes.VERSION == 1
+    before = {
+        "hydragnn.train_step", "hydragnn.train_epoch_scan", "hydragnn.eval_step",
+        "hydragnn.gather", "hydragnn.pool", "hydragnn.geom", "hydragnn.lfm2.conv",
+        "hydragnn.lfm2.attn", "hydragnn.attn.full", "hydragnn.attn.window",
+        "hydragnn.moe.route", "hydragnn.moe.experts", "hydragnn.loss",
+        "hydragnn.optimizer", "hydragnn.grad_sync", "hydragnn.agg.pna",
+    } | {scopes.agg(w, a) for w in scopes.AGG_WHATS for a in scopes.AGG_ARMS}
+    assert scopes.VOCABULARY == before | added
+    # A regression family's served program opens none of them.
+    from hydragnn_tpu.serve import InferenceEngine
+
+    model = create_model("PNA", 1, 8, (1, 1), ("graph", "node"), _heads(), [1.0, 1.0], 2,
+                         pna_deg=[0, 2, 4, 2, 1], max_neighbours=4)
+    batch = _batch()
+    variables = init_model_variables(model, batch)
+    engine = InferenceEngine(model, variables, autostart=False)
+    text = engine._jit.lower(
+        variables["params"], variables.get("batch_stats", {}), batch
+    ).as_text(debug_info=True)
+    engine.close()
+    assert not any(name in text for name in added) and scopes.POOL in text
+
+
 def pytest_outermost_entry_point_names_the_operation():
     """``fused_segment_sum`` is ``..._sum_count``'s first output and
     ``segment_mean`` a sum over a count: one name an operation, the entry

@@ -587,3 +587,153 @@ def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monke
     scatters = re.findall(r"f32\[16384,384\]\S* scatter\(", text)
     assert len(scatters) >= 6, len(scatters)
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
+# What PR 39 left as it was: the programs of the configurations that share
+# code with the new token family (``RoutedFFN``'s new score function, the
+# attention core's new ``scale`` argument, the serving engine's token path).
+# Each is compiled here for the described chip and its optimized HLO compared
+# with the PARENT's (commit 2ffcd66) as a digest (``_hlo_digest``), computed
+# by running ``_unchanged_programs`` against a checkout of the parent.
+PARENT_HLO = {
+    "lfm2_train": "11d9fe82695b0b73",
+    "laguna_train": "f5a701e3addc01d1",
+    "pna_serve": "6878f6b8702ee02c",
+}
+
+
+def _hlo_digest(text):
+    """A digest of an optimized HLO text with what depends on WHERE the
+    source stands taken out: the tables of file names and stack frames, each
+    instruction's ``metadata={...}`` and a Pallas call's serialized body
+    (its MLIR carries source locations)."""
+    import hashlib
+
+    kept, table = [], False
+    for line in text.splitlines():
+        if re.match(r"^(FileNames|FunctionNames|FileLocations|StackFrames)", line):
+            table = True
+        elif table and not line.strip():
+            table = False
+        elif not table:
+            if '"custom_call_config"' in line:
+                line = line[: line.index("backend_config=")]
+            kept.append(re.sub(r", metadata=\{[^}]*\}", "", line))
+    return hashlib.sha256("\n".join(kept).encode()).hexdigest()[:16]
+
+
+def _unchanged_programs(one_chip):
+    """{name: digest} of: a whole LFM2 and a whole Laguna model's loss and
+    gradient (attention kernels, routed experts, the sigmoid router), and the
+    serving engine's forward executable for a PNA model, each at small sizes
+    the chip's kernels take (hidden 256, heads of 64 / 128, 1024 nodes)."""
+    import numpy as np
+
+    from hydragnn_tpu.graphs.collate import GraphArena
+    from hydragnn_tpu.graphs.sample import GraphSample
+    from hydragnn_tpu.models import create_model
+    from hydragnn_tpu.models.create import init_model_variables
+    from hydragnn_tpu.models.loss import multihead_rmse_loss
+    from hydragnn_tpu.ops.segment import platform_override
+    from hydragnn_tpu.serve import InferenceEngine
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    v, d, n = 512, 256, 1024
+    heads = {"node": {"num_headlayers": 0, "dim_headlayers": [], "type": "mlp"}}
+    token = dict(head_loss=("cross_entropy",), class_minmax=([0.0, v - 1.0],))
+    models = {
+        "lfm2_train": create_model(
+            "LFM2", 1, d, (v,), ("node",), heads, [1.0], 2, lfm2=dict(
+                layer_types=["conv", "full_attention"], num_dense_layers=0,
+                intermediate_size=512, moe_intermediate_size=256, num_experts=8,
+                num_experts_per_tok=2, num_experts_held=4, experts_offset=2,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=64,
+                vocab_size=v, token_minmax=[0.0, v - 1.0],
+            ), **token,
+        ),
+        "laguna_train": create_model(
+            "LAGUNA", 1, d, (v,), ("node",), heads, [1.0], 2, laguna=dict(
+                layer_types=["full_attention", "sliding_attention"],
+                mlp_layer_types=["dense", "sparse"],
+                num_attention_heads_per_layer=[2, 4], num_key_value_heads=2, head_dim=128,
+                intermediate_size=512, moe_intermediate_size=256,
+                shared_expert_intermediate_size=256, num_experts=8,
+                num_experts_per_tok=2, num_experts_held=4, experts_offset=0,
+                sliding_window=512, moe_routed_scaling_factor=2.5, vocab_size=v,
+                token_minmax=[0.0, v - 1.0], rope_parameters={
+                    "full_attention": {
+                        "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+                        "original_max_position_embeddings": 4096, "beta_slow": 1,
+                        "beta_fast": 64, "partial_rotary_factor": 0.5,
+                    },
+                    "sliding_attention": {"rope_type": "default", "rope_theta": 10000},
+                },
+            ), **token,
+        ),
+    }
+    pos = np.zeros((n - 1, 3), np.float32)
+    pos[:, 0] = np.arange(n - 1)
+    sequence = GraphSample(
+        x=np.zeros((n - 1, 1), np.float32), pos=pos, y=np.zeros(n - 1, np.float32),
+        y_loc=np.array([[0, n - 1]], np.int64),
+    )
+    batch = GraphArena([sequence]).collate(
+        np.array([0]), ("node",), (1,), num_nodes_pad=n, num_edges_pad=8,
+        num_graphs_pad=2, with_positions=True,
+    )
+    out = {}
+    for name, model in models.items():
+        params = jax.eval_shape(lambda m=model: init_model_variables(m, batch))["params"]
+
+        def loss(p, b, m=model):
+            got = m.apply({"params": p}, b, train=True)
+            return multihead_rmse_loss(
+                got, b, m.output_type, m.task_weights,
+                head_loss=m.head_loss, class_minmax=m.class_minmax,
+            )[0]
+
+        with platform_override("tpu"):
+            out[name] = _hlo_digest(
+                jax.jit(jax.grad(loss)).lower(shaped(params), shaped(batch)).compile().as_text()
+            )
+    pna = create_model(
+        "PNA", 1, 64, (1, 3), ("graph", "node"), {
+            "graph": {"num_sharedlayers": 1, "dim_sharedlayers": 32, "num_headlayers": 1,
+                      "dim_headlayers": [32]},
+            "node": {"num_headlayers": 1, "dim_headlayers": [32], "type": "mlp"},
+        }, [1.0, 1.0], 2, max_neighbours=8, pna_deg=[0, 2, 4, 8, 4, 2, 1, 1, 1],
+    )
+    lattice = GraphSample(
+        x=np.zeros((4, 1), np.float32), pos=np.zeros((4, 3), np.float32),
+        edge_index=np.array([[0, 1, 2, 3], [1, 2, 3, 0]], np.int32),
+    )
+    small = GraphArena([lattice]).collate(
+        np.array([0]), num_nodes_pad=8, num_edges_pad=16, num_graphs_pad=2
+    )
+    variables = init_model_variables(pna, small)
+    engine = InferenceEngine(pna, variables, max_batch_graphs=8, autostart=False)
+    served = engine._dummy_batch(512, 8192)
+    os.environ["HYDRAGNN_SEGMENT_SORTED"] = "1"
+    try:
+        with platform_override("tpu"):
+            out["pna_serve"] = _hlo_digest(
+                engine._jit.lower(
+                    shaped(variables["params"]), shaped(variables.get("batch_stats", {})),
+                    shaped(served),
+                ).compile().as_text()
+            )
+    finally:
+        del os.environ["HYDRAGNN_SEGMENT_SORTED"]
+        engine.close()
+    return out
+
+
+def pytest_programs_pr39_shares_code_with_are_text_identical_to_the_parents(one_chip):
+    """LFM2's and Laguna's train programs and the PNA serving engine's
+    executable, optimized for the described chip: the same HLO text as the
+    parent commit's (``PARENT_HLO``), metadata apart."""
+    assert _unchanged_programs(one_chip) == PARENT_HLO
