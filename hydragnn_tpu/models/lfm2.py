@@ -40,7 +40,14 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+import numpy as np
 
+from ..ops.block_attention import (
+    block_pairs,
+    block_range,
+    block_range_attention,
+    whole_blocks,
+)
 from ..ops.segment import execution_platform
 from ..telemetry import scopes
 from .layers import scaled_ids
@@ -262,6 +269,74 @@ def _band_attention_tpu(q, k, v, node_graph, window: int, scale: float):
     return out.reshape(heads, n, hd)
 
 
+def _flash_attention_tpu(q, k, v, node_graph, scale: float):
+    """The complete causal graph on the TPU by JAX's own flash kernel, forward,
+    dq and dkv: the whole array as ONE sequence under ``causal`` and segment
+    ids, one call a query head (``k`` and ``v`` repeated). It skips a key
+    block above the diagonal and no other: a block below it that belongs to
+    another graph is multiplied and then masked. ``q`` [N, H, hd]; ``k``,
+    ``v`` [N, KV, hd], ``N`` a whole number of blocks; returns [N, H, hd]."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    b = ATTN_BLOCK
+    sizes = fa.BlockSizes(
+        block_q=b, block_k_major=b, block_k=b, block_b=1,
+        block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b, block_q_dkv=b,
+        block_k_major_dq=b, block_k_dq=b, block_q_dq=b,
+    )
+    rep = q.shape[1] // k.shape[1]
+    qh = q.transpose(1, 0, 2)[None]
+    kh = jnp.repeat(k.transpose(1, 0, 2), rep, axis=0)[None]
+    vh = jnp.repeat(v.transpose(1, 0, 2), rep, axis=0)[None]
+    seg = node_graph.astype(jnp.int32)[None]
+    out = fa.flash_attention(
+        qh, kh, vh, segment_ids=fa.SegmentIds(q=seg, kv=seg), causal=True,
+        sm_scale=scale, block_sizes=sizes,
+    )
+    return out[0].transpose(1, 0, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _full_attention_tpu(q, k, v, node_graph, scale: float):
+    """The complete causal graph on the TPU. A call that is not
+    differentiated (the engine's ``score_tokens``, an evaluation step) visits
+    only the key blocks of a query block's own graphs
+    (``ops/block_attention.py``); under a gradient the library kernel's
+    forward, dq and dkv run as ``_flash_attention_tpu`` makes them (the
+    backward kernels with the same block range are ROADMAP S10's)."""
+    return block_range_attention(q, k, v, node_graph, scale, ATTN_BLOCK)
+
+
+def _full_attention_tpu_fwd(q, k, v, node_graph, scale):
+    def library(q, k, v):
+        # XLA names a kernel's instruction after the first name that the
+        # nested ``jvp`` wraps: under this scope it stays ``flash_attention``,
+        # as in a trace and in the program before PR 40, not
+        # ``jvp_jit_flash_attention__``.
+        with jax.named_scope("library"):
+            return _flash_attention_tpu(q, k, v, node_graph, scale)
+
+    return jax.vjp(library, q, k, v)
+
+
+def _full_attention_tpu_bwd(scale, vjp, g):
+    return (*vjp(g), None)
+
+
+_full_attention_tpu.defvjp(_full_attention_tpu_fwd, _full_attention_tpu_bwd)
+
+
+def attention_key_blocks(node_graph, ranged: bool = True):
+    """(visited, causal): the (query block, key block) pairs ONE call of the
+    complete causal core visits on the host array ``node_graph`` [N], a head,
+    and the pairs of the padded rows' whole triangle; by the function that
+    hands the TPU's kernel its range. Not ``ranged`` (every path but the
+    TPU's undifferentiated one) the triangle is walked."""
+    padded = whole_blocks(np.asarray(node_graph), ATTN_BLOCK)
+    visited, causal = block_pairs(block_range(padded, ATTN_BLOCK))
+    return visited if ranged else causal, causal
+
+
 def segment_causal_attention(q, k, v, node_graph, window=None, scale=None):
     """Softmax aggregation over the complete causal graph of each sequence:
     node ``i`` receives from every node ``j <= i`` of its own graph; with a
@@ -272,13 +347,21 @@ def segment_causal_attention(q, k, v, node_graph, window=None, scale=None):
     "earlier in the flat array": the mask is ``same graph and j <= i`` and
     nothing is gathered.
 
-    On the TPU a Pallas kernel of JAX's own library that skips the blocks the
-    mask leaves empty: the flash kernel for the complete causal graph, the
-    splash kernel for the band (the flash kernel has no window and would do
-    the triangle's work). Elsewhere a loop over blocks of query rows, each
-    against the keys it can see (up to its end; from ``window - 1`` rows
-    before its start), rematerialized in the backward. Either way the
-    largest score array is a block's, never ``[N, N]``. Padding nodes share
+    On the TPU a Pallas kernel, and what each skips differs. The complete
+    causal graph, not differentiated (the engine's ``score_tokens``, an
+    evaluation step): ``ops/block_attention.py``, which visits for a block
+    of query rows only the key blocks from its earliest graph's first row up
+    to the diagonal, so neither the blocks above the diagonal nor those of
+    other graphs. Under a gradient: the flash kernel of JAX's own library,
+    forward, dq and dkv, which skips the blocks ABOVE the diagonal only (a
+    block of another graph is multiplied, then masked). The band: the splash
+    kernel of the same library, whose grid holds only the blocks the band
+    touches (the flash kernel has no window and would do the triangle's
+    work); a graph's end inside the band is masked, not skipped. Elsewhere a
+    loop over blocks of query rows, each against ALL the keys up to its end
+    (from ``window - 1`` rows before its start), other graphs' masked,
+    rematerialized in the backward. Every way the largest score array is a
+    block's, never ``[N, N]``. Padding nodes share
     the padding graph's id and attend among themselves (every row keeps its
     diagonal, so no softmax is empty). ``scale`` multiplies the scores:
     ``hd ** -0.5`` unless a stack states its own (models/mistral4.py: YaRN's
@@ -299,24 +382,7 @@ def segment_causal_attention(q, k, v, node_graph, window=None, scale=None):
         )
         return out.transpose(1, 0, 2)[:n].reshape(n, heads * hd)
     if on_tpu:
-        from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-        b = ATTN_BLOCK
-        sizes = fa.BlockSizes(
-            block_q=b, block_k_major=b, block_k=b, block_b=1,
-            block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b, block_q_dkv=b,
-            block_k_major_dq=b, block_k_dq=b, block_q_dq=b,
-        )
-        rep = heads // kv
-        qh = q.transpose(1, 0, 2)[None]
-        kh = jnp.repeat(k.transpose(1, 0, 2), rep, axis=0)[None]
-        vh = jnp.repeat(v.transpose(1, 0, 2), rep, axis=0)[None]
-        seg = node_graph.astype(jnp.int32)[None]
-        out = fa.flash_attention(
-            qh, kh, vh, segment_ids=fa.SegmentIds(q=seg, kv=seg), causal=True,
-            sm_scale=scale, block_sizes=sizes,
-        )
-        return out[0].transpose(1, 0, 2)[:n].reshape(n, heads * hd)
+        return _full_attention_tpu(q, k, v, node_graph, scale)[:n].reshape(n, heads * hd)
     q = q.reshape(total, kv, heads // kv, hd)
     block = jax.checkpoint(_attention_rows, static_argnums=(5, 6, 7, 8))
     out = []

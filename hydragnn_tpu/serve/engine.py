@@ -1205,11 +1205,31 @@ class InferenceEngine:
             for layer in np.moveaxis(local, 1, 0)
         ])  # [routed layers, held]
         cap = min(_capacity(n_pad * k, held, cfg.num_experts), n_pad * k)
-        counted = {
+        self._count_flush({
             "moe_rows_held_total": int(loads.sum()),
             "moe_load_max_total": int(loads.max(axis=1).sum()),
             "moe_fallback_layers_total": int((loads.sum(axis=1) > cap).sum()),
-        }
+        })
+
+    def _count_key_blocks(self, node_graph: np.ndarray) -> None:
+        """A flush's attention core in the engine's counters and as graftel
+        gauges: the (query block, key block) pairs ONE call of it visits, a
+        head and a layer, beside the pairs of the padded rung's whole causal
+        triangle (models/lfm2.py ``attention_key_blocks``: the function that
+        hands the TPU's kernel its block range, on the flush's own
+        ``node_graph``). Elsewhere than on a TPU the core walks the
+        triangle, and the two are equal."""
+        from ..models.lfm2 import attention_key_blocks
+
+        visited, causal = attention_key_blocks(
+            node_graph, ranged=self.device["platform"] == "tpu"
+        )
+        self._count_flush({
+            "attn_key_blocks_visited_total": visited,
+            "attn_key_blocks_causal_total": causal,
+        })
+
+    def _count_flush(self, counted: Dict[str, int]) -> None:
         for name, value in counted.items():
             self.metrics.count(name, value)
             telemetry.gauge("serve/" + name[: -len("_total")], value)
@@ -1220,6 +1240,8 @@ class InferenceEngine:
         now = time.perf_counter()
         batch_had_nonfinite = False
         routing = getattr(outputs, "routing", None)
+        if self._token_cfg is not None:
+            self._count_key_blocks(work.batch.node_graph)
         if routing is not None:
             last = work.requests[-1]
             self._count_routing(

@@ -316,8 +316,8 @@ def pytest_no_flag_selects_an_aggregation_kernel():
     ops = os.path.dirname(agg.__file__)
     modules = sorted(name for name in os.listdir(ops) if name.endswith(".py"))
     assert modules == [
-        "__init__.py", "aggregate.py", "certify.py", "extrema_scan.py",
-        "segment.py", "segment_sorted.py",
+        "__init__.py", "aggregate.py", "block_attention.py", "certify.py",
+        "extrema_scan.py", "segment.py", "segment_sorted.py",
     ]
     found = set()
     for name in modules:

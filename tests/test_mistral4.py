@@ -524,6 +524,42 @@ def pytest_engine_spans_the_copy_to_the_host_and_gauges_the_counters(setup):
     assert gauges["serve/moe_fallback_layers"] == 0
 
 
+def pytest_engine_counts_the_key_blocks_a_flush_visits(setup, engine, monkeypatch):
+    """``attn_key_blocks_visited_total`` / ``_causal_total`` a flush, by the
+    function that hands the TPU's kernel its block range, on the flush's own
+    ``node_graph``: 5 + 9 + 30 tokens and 20 padding rows in the 64-token
+    rung, in blocks of 8 rows here. An engine on a CPU walks the triangle."""
+    from hydragnn_tpu import telemetry
+
+    model, graphs, batch, variables = setup
+    monkeypatch.setattr(lfm2, "ATTN_BLOCK", 8)
+    names = ("attn_key_blocks_visited_total", "attn_key_blocks_causal_total")
+
+    def flush():
+        before = engine.metrics.read_counters(*names)
+        for future in [engine.submit(r) for r in _requests(graphs)]:
+            future.result(60)
+        after = engine.metrics.read_counters(*names)
+        return tuple(after[n] - before[n] for n in names)
+
+    assert flush() == (36, 36)
+    # As an engine on a TPU counts it: block 0 and 1 open in the first two
+    # documents (from block 0), blocks 2-5 in the third (rows 14-43: from
+    # block 1), blocks 6 and 7 in the padding (rows 44-63: from block 5).
+    monkeypatch.setitem(engine.device, "platform", "tpu")
+    telemetry.configure(collect=True)
+    try:
+        assert flush() == (1 + 2 + 2 + 3 + 4 + 5 + 2 + 3, 36)
+        gauges = telemetry.gauges_snapshot()
+    finally:
+        telemetry.configure(collect=False)
+    assert gauges["serve/attn_key_blocks_visited"] == 22
+    assert gauges["serve/attn_key_blocks_causal"] == 36
+    text = engine.metrics.render_prometheus()
+    assert all(f"hydragnn_serve_{name} " in text for name in names)
+    assert set(names) <= set(engine.metrics.snapshot())
+
+
 def pytest_engine_counts_a_layer_past_its_capacity():
     """A rung whose row arrays are compact ([C, .], C under K N) and a
     document that sends one layer more rows than C: counted as a fall-back

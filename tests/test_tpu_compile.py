@@ -327,6 +327,43 @@ def pytest_lfm2_attention_at_cell_size_has_no_n_by_n_array(one_chip):
     assert f"[1,{h},{padded},{hd}]" in text  # the kernel's rows, per query head
 
 
+def pytest_served_attention_core_at_cell_size_is_the_block_range_kernel(one_chip):
+    """The attention core at the shape of the cell
+    ``mistral_small4_ep8.serve_score_docs_c4``'s commonest rung (15,872 rows,
+    32 heads of 128, float32 rows): a call that is not differentiated is ONE
+    Mosaic call, ``block_range_attention``, fed the rows a head at a time
+    with nothing repeated, and no array with two row axes; under
+    ``jax.grad`` the program holds the library's three kernels and not the
+    new one."""
+    from hydragnn_tpu.models.lfm2 import segment_causal_attention
+    from hydragnn_tpu.ops.segment import platform_override
+
+    n, h, hd = 15872, 32, 128
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = (shaped((n, h, hd)),) * 3 + (shaped((n,), jnp.int32),)
+
+    def loss(q, k, v, node_graph):
+        out = segment_causal_attention(q, k, v, node_graph)
+        return (out * out).sum()
+
+    with platform_override("tpu"):
+        forward = jax.jit(segment_causal_attention).lower(*rows).compile().as_text()
+        backward = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*rows).compile().as_text()
+    calls = [line for line in forward.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "%block_range_attention" in calls[0]
+    assert f"f32[{h},{n},{hd}]" in calls[0] and f"[1,{h},{n},{hd}]" not in forward
+    for text in (forward, backward):
+        shapes = set(re.findall(r"\w+\[([\d,]+)\]", text))
+        assert not [s for s in shapes if s.split(",").count(str(n)) >= 2]
+    kernels = re.findall(r"(%[\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", backward)
+    assert len(kernels) == 3 and "block_range_attention" not in backward
+    assert sorted(k.split("_")[0].split(".")[0] for k in kernels) == ["%flash"] * 3
+    assert f"[1,{h},{n},{hd}]" in backward  # the library kernel's rows, per head
+
+
 @pytest.mark.parametrize("cell", ["lfm2", "laguna"])
 def pytest_lfm2_routed_experts_at_cell_size_are_grouped_matmuls(one_chip, cell):
     """One ``RoutedFFN``, forward and backward, at the 4160 nodes of the two
@@ -594,7 +631,9 @@ def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monke
 # attention core's new ``scale`` argument, the serving engine's token path).
 # Each is compiled here for the described chip and its optimized HLO compared
 # with the PARENT's (commit 2ffcd66) as a digest (``_hlo_digest``), computed
-# by running ``_unchanged_programs`` against a checkout of the parent.
+# by running ``_unchanged_programs`` against a checkout of the parent. PR 40
+# holds the same digests: a differentiated call of the attention core is the
+# library's three kernels as they were, kernel names included.
 PARENT_HLO = {
     "lfm2_train": "11d9fe82695b0b73",
     "laguna_train": "f5a701e3addc01d1",
