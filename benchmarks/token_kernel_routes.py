@@ -30,6 +30,18 @@ sequence of 4096 tokens in a bucket of 4160 nodes; 8 key-value heads of 128):
                 only: either way the backward is one gather of C rows, and
                 the backward of the way IN is this same operation
 
+    expert tiles  ``grouped_matmul`` FORWARD over the rows of a layer that
+                holds EVERY expert at a deployment's rows an expert (the cell
+                ``mellum2_12b_l4.serve_score_docs_c4_v98k``'s commonest rung:
+                ``[126976, .]`` rows, 64 groups of 1,658, 106,112 live), up
+                (2304 -> 896) and down (896 -> 2304), whose widths are no
+                whole number of ``GMM_TILING``'s 1024: the tile clipped to the
+                matrix alone (1024 over 2304: 2.25 tiles, the kernel's
+                remainder path) against tiles that divide it (1152, which
+                ``_gmm_tiles`` takes, 768, 384) and a row tile of 512; beside
+                it LFM2's 1792 under 1024 (1.75 tiles, what its cell runs)
+                and 896
+
 A time is the wall clock round ``REPEATS`` calls ended by
 ``block_until_ready``, the least of ``ROUNDS``. Refuses to run anywhere but on
 a TPU. Prints one JSON line a route and writes the table to ``chiprun_out/``:
@@ -124,6 +136,34 @@ def expert_rows(rows, live, groups, d, f):
                 "what": f"experts {name}, row tile {tile}", "rows": rows, "live": live,
                 "fwd_bwd_ms": time_ms(jax.jit(jax.grad(loss, argnums=(0, 1))), lhs, rhs),
             }
+
+
+def expert_tiles(rows, groups, per_group, d, f, tilings):
+    """Forward alone (a served layer asks for no gradient). A tiling is
+    (name, row tile, the tile along the ``d``-wide side, the tile along the
+    ``f``-wide side); None takes ``lfm2._gmm_tiles``'s own."""
+    rng = np.random.default_rng(2)
+    live = groups * per_group
+    sizes = jnp.full((groups,), per_group, jnp.int32)
+    fitted = lfm2._gmm_tiles
+    try:
+        for name, k, n in (("up", d, f), ("down", f, d)):
+            lhs = jnp.asarray(rng.normal(size=(rows, k)), jnp.float32)
+            rhs = jnp.asarray(rng.normal(size=(groups, k, n)) / np.sqrt(k), jnp.float32)
+            for what, tm, tile_d, tile_f in tilings:
+                tiles = (tm, *fitted(rows, k, n)[1:]) if tile_d is None else (
+                    (tm, tile_d, tile_f) if k == d else (tm, tile_f, tile_d)
+                )
+                lfm2._gmm_tiles = lambda m, k_, n_, t=tiles: t
+                jax.clear_caches()  # the kernel's own jit is keyed by the tiling FUNCTION
+                ms = time_ms(jax.jit(lambda a, b: lfm2.grouped_matmul(a, b, sizes)), lhs, rhs)
+                yield {
+                    "what": f"expert tiles {name} {k} -> {n}, {what}", "tiles": list(tiles),
+                    "rows": rows, "live": live, "fwd_ms": ms,
+                    "tflops": 2 * live * k * n / ms / 1e9,
+                }
+    finally:
+        lfm2._gmm_tiles = fitted
 
 
 def steered_layer(n, d, k, held, experts, f, live):
@@ -233,6 +273,17 @@ def main() -> int:
         )),
         (way_back, (320, 64, 8, 512) if rehearsal else (4160, 2048, 8, 6400)),
         (way_back, (320, 64, 4, 512) if rehearsal else (4160, 2048, 4, 6400)),
+        (expert_tiles, (1024, 4, 200, 288, 112, (("fitted", 256, None, None),)) if rehearsal else (
+            126976, 64, 1658, 2304, 896, (
+                ("clipped", 256, 1024, 896), ("fitted", 256, None, None),
+                ("divides", 256, 768, 896), ("divides", 256, 384, 896),
+                ("row tile 512, fitted", 512, None, None),
+            ),
+        )),
+        (expert_tiles, (1024, 4, 200, 256, 224, (("divides", 256, 256, 112),)) if rehearsal else (
+            6400, 8, 800, 2048, 1792,  # LFM2's expert, as its cell runs it, and a dividing tile
+            (("clipped (the cell's)", 256, 1024, 1024), ("divides", 256, 1024, 896)),
+        )),
     )
     named = [a for a in sys.argv[1:] if not a.startswith("--")]
     for rows, args in shapes:

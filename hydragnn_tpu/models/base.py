@@ -14,7 +14,8 @@ Architecture (mirrors reference semantics under padding):
              RMSNorm and a residual round each, a final RMSNorm
              (models/lfm2.py): no edge list is read. LAGUNA: the same
              loop over Laguna-XS.2's block (models/laguna.py). MISTRAL4:
-             over Mistral-Small-4's (models/mistral4.py).
+             over Mistral-Small-4's (models/mistral4.py). MELLUM: over
+             Mellum2-12B-A2.5B's (models/mellum.py).
   readout:   masked segment-mean over nodes per graph (global_mean_pool analog)
   heads:     graph heads = shared MLP ("graph_shared") + per-head MLP;
              node heads = shared MLPNode ('mlp' / 'mlp_per_node') or a conv chain
@@ -36,7 +37,7 @@ from ..ops import segment as seg
 from ..telemetry import scopes
 from .layers import MLP, MaskedBatchNorm
 from . import laguna as laguna_model, lfm2 as lfm2_model, painn
-from . import mistral4 as mistral4_model
+from . import mellum as mellum_model, mistral4 as mistral4_model
 from .convs import (
     POSITION_FAMILIES, TOKEN_STACKS, CGConv, GATv2Conv, GINConv, MFCConv,
     PNAConv, SAGEConv,
@@ -44,7 +45,7 @@ from .convs import (
 
 CONV_TYPES = (
     "PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE", "PAINN", "LFM2", "LAGUNA",
-    "MISTRAL4",
+    "MISTRAL4", "MELLUM",
 )
 # Rows of a block of ``HydraGNN.score_tokens``: a class head's logits exist a
 # block at a time ([512, classes]), never as [N, classes].
@@ -139,6 +140,8 @@ class HydraGNN(nn.Module):
     laguna: Optional[laguna_model.LagunaConfig] = None
     # MISTRAL4: the same (models/mistral4.py).
     mistral4: Optional[mistral4_model.Mistral4Config] = None
+    # MELLUM: the same (models/mellum.py).
+    mellum: Optional[mellum_model.MellumConfig] = None
     # Loss kind a head ("rmse" | "cross_entropy"; () = rmse throughout) and,
     # for a cross-entropy head, the dataset's (min, max) of its target column,
     # from which the class ids are un-scaled (models/loss.py).

@@ -34,7 +34,7 @@ import flax.linen as nn
 
 from ..ops import aggregate
 from ..telemetry import scopes
-from . import laguna, lfm2, mistral4
+from . import laguna, lfm2, mellum, mistral4
 
 # Conv families whose aggregation rides the sorted/CSR edge layout end to end
 # (every family since PR 7 — GAT's sort-breaking [edges; self-loops] concat
@@ -44,10 +44,10 @@ from . import laguna, lfm2, mistral4
 # instead (analysis/contracts.py).
 SORTED_PATH_FAMILIES = frozenset(
     # The token stacks read no edge list (models/lfm2.py, models/laguna.py,
-    # models/mistral4.py):
+    # models/mistral4.py, models/mellum.py):
     # no aggregation to fall back.
     {"SAGE", "GIN", "MFC", "GAT", "CGCNN", "PNA", "PAINN", "LFM2", "LAGUNA",
-     "MISTRAL4"}
+     "MISTRAL4", "MELLUM"}
 )
 # The token stacks: a sequence as a graph, a token a node, the node column a
 # min-max-scaled token id (``Architecture.token_minmax`` from completion).
@@ -57,6 +57,7 @@ TOKEN_STACKS = {
     "LFM2": (lfm2.LFM2Config, lfm2.LFM2Block),
     "LAGUNA": (laguna.LagunaConfig, laguna.LagunaBlock),
     "MISTRAL4": (mistral4.Mistral4Config, mistral4.Mistral4Block),
+    "MELLUM": (mellum.MellumConfig, mellum.MellumBlock),
 }
 TOKEN_FAMILIES = frozenset(TOKEN_STACKS)
 # Families that read ``GraphBatch.positions`` inside the step (PaiNN its edge

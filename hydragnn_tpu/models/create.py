@@ -44,6 +44,7 @@ def create_model_config(
         lfm2=config if config["model_type"] == "LFM2" else None,
         laguna=config if config["model_type"] == "LAGUNA" else None,
         mistral4=config if config["model_type"] == "MISTRAL4" else None,
+        mellum=config if config["model_type"] == "MELLUM" else None,
         head_loss=config.get("head_loss") or (),
         class_minmax=config.get("class_minmax") or (),
         compute_dtype=config.get("compute_dtype"),
@@ -72,6 +73,7 @@ def create_model(
     lfm2: Optional[Dict[str, Any]] = None,
     laguna: Optional[Dict[str, Any]] = None,
     mistral4: Optional[Dict[str, Any]] = None,
+    mellum: Optional[Dict[str, Any]] = None,
     head_loss: Sequence[str] = (),
     class_minmax: Sequence[Any] = (),
     compute_dtype: Optional[str] = None,
@@ -81,8 +83,9 @@ def create_model(
     """``lfm2``: for ``model_type`` "LFM2", the ``Architecture`` block's keys
     that size the stack, named as the source names them (models/lfm2.py
     ``LFM2Config``); ``laguna``: the same for "LAGUNA" (models/laguna.py
-    ``LagunaConfig``) and ``mistral4`` for "MISTRAL4" (models/mistral4.py
-    ``Mistral4Config``). ``head_loss``: "rmse" or "cross_entropy" a head (empty:
+    ``LagunaConfig``), ``mistral4`` for "MISTRAL4" (models/mistral4.py
+    ``Mistral4Config``) and ``mellum`` for "MELLUM" (models/mellum.py
+    ``MellumConfig``). ``head_loss``: "rmse" or "cross_entropy" a head (empty:
     rmse throughout); ``class_minmax``: for a cross-entropy head the (min,
     max) of its target column in the dataset's table, None for the others."""
     if len(task_weights) != len(output_dim):
@@ -114,7 +117,9 @@ def create_model(
         kwargs.update(radius=float(radius), num_radial=int(num_radial))
     elif model_type in TOKEN_STACKS:
         field = model_type.lower()
-        sizes = {"lfm2": lfm2, "laguna": laguna, "mistral4": mistral4}[field]
+        sizes = {
+            "lfm2": lfm2, "laguna": laguna, "mistral4": mistral4, "mellum": mellum,
+        }[field]
         if sizes is None:
             raise ValueError(
                 f"{model_type} requires the stack's sizes (create_model("

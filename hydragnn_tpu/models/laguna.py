@@ -105,6 +105,16 @@ class Rope:
         return inv.astype(np.float32), float(scale), r
 
 
+def ropes_by_kind(rope_parameters: dict) -> Tuple[Rope, Rope]:
+    """The source's ``rope_parameters``, one section a kind, as records by
+    ``KINDS`` (keys a ``Rope`` does not hold are left out)."""
+    names = {f.name for f in dataclasses.fields(Rope)}
+    return tuple(
+        Rope(**{k: v for k, v in rope_parameters[kind].items() if k in names})
+        for kind in KINDS
+    )
+
+
 @dataclasses.dataclass(frozen=True)
 class LagunaConfig:
     """The stack's static sizes, keyed as the source's ``config.json`` names
@@ -180,13 +190,7 @@ class LagunaConfig:
         if not arch.get("gating", True):
             raise ValueError("LAGUNA without the gate on the attention output is not built")
         held, offset = experts_share(arch)
-        ropes = tuple(
-            Rope(**{
-                k: v for k, v in arch["rope_parameters"][kind].items()
-                if k in {f.name for f in dataclasses.fields(Rope)}
-            })
-            for kind in KINDS
-        )
+        ropes = ropes_by_kind(arch["rope_parameters"])
         kw = {
             f.name: arch[f.name] for f in dataclasses.fields(cls) if f.name in arch
         }

@@ -235,6 +235,29 @@ def _attention_rows(q, k, v, seg_q, seg_k, first_row: int, scale: float,
     return jnp.einsum("grqk,kgd->qgrd", p.astype(v.dtype), v)
 
 
+def _band_reach(window: int) -> Tuple[int, int]:
+    """Node i sees j with 0 <= i - j < window: ``window - 1`` to the left,
+    none to the right (the node itself counts)."""
+    return window - 1, 0
+
+
+def band_key_blocks(rows: int, window: int) -> int:
+    """The (query block, key block) pairs ONE call of the band's core visits
+    over ``rows`` rows (padded up to whole blocks), a head: the blocks of
+    ``ATTN_BLOCK`` that hold a pair of ``_band_reach``, which are the splash
+    kernel's grid under its static mask (a window of 1024 reaches into 3 key
+    blocks of 512 a query block, where the triangle has up to all before
+    it). Graph boundaries are not in it: a graph's end inside the band is
+    masked, not skipped."""
+    left, right = _band_reach(window)
+    b = ATTN_BLOCK
+    blocks = -(-rows // b)
+    return sum(
+        min((i * b + b - 1 + right) // b, blocks - 1) - max((i * b - left) // b, 0) + 1
+        for i in range(blocks)
+    )
+
+
 def _band_attention_tpu(q, k, v, node_graph, window: int, scale: float):
     """The band on the TPU: the splash-attention Pallas kernel of JAX's own
     library under a ``LocalMask``, one call a key-value head (``vmap``) over
@@ -251,9 +274,7 @@ def _band_attention_tpu(q, k, v, node_graph, window: int, scale: float):
     kv = k.shape[0]
     rep = heads // kv
     b = ATTN_BLOCK
-    # Node i sees j with 0 <= i - j < window: window - 1 to the left, none to
-    # the right.
-    band = masks.LocalMask((n, n), (window - 1, 0), 0)
+    band = masks.LocalMask((n, n), _band_reach(window), 0)
     kernel = splash.make_splash_mqa_single_device(
         masks.MultiHeadMask([band] * rep),
         block_sizes=splash.BlockSizes(
@@ -433,8 +454,11 @@ class DenseFFN(nn.Module):
 _expert_init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
 # (rows, contraction, columns) tiles of the TPU's grouped-matmul kernel; the
 # row tile has to divide a row array's rows: ``_capacity`` is a multiple of
-# it, and so are the K N rows of a bucket (multiples of 256) where a layer
-# holds every expert.
+# it, and where a layer holds every expert the K N rows of a bucket
+# (multiples of 64 nodes) are whole row tiles from K = 4 (the serving
+# ladder's rungs of Mellum2's layer, K = 8: 98k-201k rows in ONE pass, 4.0 GB
+# of temporaries at the largest, PERF.md section 6, PR 41); row arrays of
+# another length go through ``ragged_dot`` (``grouped_matmul``).
 GMM_TILING = (256, 1024, 1024)
 # Rows of the routed layer's compact path over the rank's uniform share
 # ``K N held / experts`` (``_capacity``).
@@ -450,11 +474,27 @@ def _capacity(assignments: int, held: int, experts: int) -> int:
     return -(-math.ceil(share) // tile) * tile
 
 
+def _gmm_tile(tile: int, width: int) -> int:
+    """A contraction or column tile for a matrix ``width`` wide: no wider
+    than the matrix (a fine-grained expert, 512 wide, is narrower than a
+    tile, and the kernel would multiply the tile); and where the last tile
+    would be under half full, that remainder spread over the whole tiles
+    before it (2304 is 2.25 tiles of 1024: 2 tiles of 1152) if that leaves
+    whole lanes: the kernel multiplies a whole tile for a remainder. On the
+    chip at 1,658 rows an expert 1152 beat 1024 by 12-15% and 768 by 1-5%
+    (PERF.md section 6, PR 41). LFM2's 1792 keeps 1024 (its last tile is three
+    quarters full)."""
+    tile = min(tile, width)
+    whole, rest = divmod(width, tile)
+    if 0 < rest < tile // 2 and width % (128 * whole) == 0:
+        tile = width // whole
+    return tile
+
+
 def _gmm_tiles(m: int, k: int, n: int):
-    """``GMM_TILING`` no wider than the matrices: a fine-grained expert (512
-    wide) is narrower than a tile, and the kernel would multiply the tile."""
+    """``GMM_TILING`` fitted to the matrices (``_gmm_tile``)."""
     tm, tk, tn = GMM_TILING
-    return tm, min(tk, k), min(tn, n)
+    return tm, _gmm_tile(tk, k), _gmm_tile(tn, n)
 
 
 @jax.custom_vjp

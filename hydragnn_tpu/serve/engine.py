@@ -43,7 +43,10 @@ a token a node, no edges) is served on the same path: a request is the token
 column and each token's place, the ladder's rungs are counted in tokens, a
 class head answers with the log-probability of each next token instead of its
 logits, and the routed layers' choices come out of the same executable
-(docs/SERVING.md "Token families").
+(docs/SERVING.md "Token families"). Its attention cores are of up to two
+KINDS by layer, the complete causal graph of a document and the causal band
+of ``sliding_window`` (``models/mellum.py``, ``models/laguna.py``), and a
+flush's key blocks are counted a kind (``_count_key_blocks``).
 """
 
 from __future__ import annotations
@@ -360,6 +363,13 @@ class InferenceEngine:
         self.tolerance = None if tolerance is None else float(tolerance)
         # A token family (models/convs.py TOKEN_STACKS): its sizes, else None.
         self._token_cfg = model.token_cfg
+        # The window of its band layers, None for a stack with none.
+        sliding = getattr(self._token_cfg, "sliding", None)
+        self._band_window = (
+            self._token_cfg.sliding_window
+            if sliding and any(sliding(i) for i in range(model.num_conv_layers))
+            else None
+        )
         if self._token_cfg is not None and precision != "f32":
             raise ValueError(
                 f"{model.conv_type} reads token ids from a float32 node "
@@ -1212,14 +1222,19 @@ class InferenceEngine:
         })
 
     def _count_key_blocks(self, node_graph: np.ndarray) -> None:
-        """A flush's attention core in the engine's counters and as graftel
-        gauges: the (query block, key block) pairs ONE call of it visits, a
-        head and a layer, beside the pairs of the padded rung's whole causal
+        """A flush's attention cores in the engine's counters and as graftel
+        gauges, by the KIND of layer. A full layer (the complete causal
+        graph): the (query block, key block) pairs ONE call of its core
+        visits, a head, beside the pairs of the padded rung's whole causal
         triangle (models/lfm2.py ``attention_key_blocks``: the function that
         hands the TPU's kernel its block range, on the flush's own
-        ``node_graph``). Elsewhere than on a TPU the core walks the
-        triangle, and the two are equal."""
-        from ..models.lfm2 import attention_key_blocks
+        ``node_graph``); elsewhere than on a TPU the core walks the triangle,
+        and the two are equal. A window layer (the causal band, where the
+        stack has one): the pairs ONE call of the band's core visits at the
+        flush's rung (``band_key_blocks``: the blocks the band's static mask
+        holds, whatever the documents); 0 for a stack whose every layer is
+        full."""
+        from ..models.lfm2 import attention_key_blocks, band_key_blocks
 
         visited, causal = attention_key_blocks(
             node_graph, ranged=self.device["platform"] == "tpu"
@@ -1227,6 +1242,10 @@ class InferenceEngine:
         self._count_flush({
             "attn_key_blocks_visited_total": visited,
             "attn_key_blocks_causal_total": causal,
+            "attn_window_key_blocks_total": (
+                band_key_blocks(len(node_graph), self._band_window)
+                if self._band_window else 0
+            ),
         })
 
     def _count_flush(self, counted: Dict[str, int]) -> None:

@@ -207,11 +207,14 @@ class ServeMetrics:
         self.moe_rows_held_total = 0  # guarded-by: self._lock
         self.moe_load_max_total = 0  # guarded-by: self._lock
         self.moe_fallback_layers_total = 0  # guarded-by: self._lock
-        # A token family's attention core (serve/engine.py _count_key_blocks),
-        # summed over the flushes: the key blocks one call visits, a head and
-        # a layer, and those of the rung's whole causal triangle.
+        # A token family's attention cores (serve/engine.py _count_key_blocks),
+        # summed over the flushes: the key blocks one call of a FULL layer's
+        # core visits, a head, and those of the rung's whole causal triangle;
+        # the key blocks one call of a WINDOW layer's core visits (0 for a
+        # stack with no window layer).
         self.attn_key_blocks_visited_total = 0  # guarded-by: self._lock
         self.attn_key_blocks_causal_total = 0  # guarded-by: self._lock
+        self.attn_window_key_blocks_total = 0  # guarded-by: self._lock
         # Occupancy / padding accumulators (averages derived in snapshot()).
         self._occupancy_sum = 0.0  # guarded-by: self._lock
         self._node_fill_sum = 0.0  # guarded-by: self._lock
@@ -351,6 +354,7 @@ class ServeMetrics:
                 "moe_fallback_layers_total": self.moe_fallback_layers_total,
                 "attn_key_blocks_visited_total": self.attn_key_blocks_visited_total,
                 "attn_key_blocks_causal_total": self.attn_key_blocks_causal_total,
+                "attn_window_key_blocks_total": self.attn_window_key_blocks_total,
                 # Precision arm + tolerance-gate record (docs/PRECISION.md).
                 "precision": {
                     "arm": self.precision_arm,
@@ -439,6 +443,7 @@ class ServeMetrics:
         ("moe_fallback_layers_total", "moe_fallback_layers_total"),
         ("attn_key_blocks_visited_total", "attn_key_blocks_visited_total"),
         ("attn_key_blocks_causal_total", "attn_key_blocks_causal_total"),
+        ("attn_window_key_blocks_total", "attn_window_key_blocks_total"),
     )
 
     def render_prometheus(self) -> str:

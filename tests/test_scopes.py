@@ -576,6 +576,49 @@ def pytest_pr39_adds_three_names_and_changes_none():
     assert not any(name in text for name in added) and scopes.POOL in text
 
 
+def pytest_pr41_serves_a_band_under_the_names_the_table_has():
+    """PR 41 adds no name: Mellum's engine executable, the first served
+    program with ``hydragnn.attn.window`` in it, carries the band's core, the
+    triangle's, the router, the experts and the reply under names the table
+    already held, each kind of core under its own layers, and no
+    ``hydragnn.`` name outside the vocabulary."""
+    from hydragnn_tpu.serve import InferenceEngine
+
+    assert len(scopes.VOCABULARY) == 19 + len(scopes.AGG_WHATS) * len(scopes.AGG_ARMS)
+    v = 16
+    rope = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                               "original_max_position_embeddings": 8},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
+    model = create_model(
+        "MELLUM", 1, 8, (v,), ("node",),
+        {"node": {"num_headlayers": 0, "dim_headlayers": [], "type": "mlp"}}, [1.0], 2,
+        mellum=dict(
+            layer_types=["sliding_attention", "full_attention"], mlp_layer_types=["sparse"] * 2,
+            num_attention_heads=2, num_key_value_heads=1, head_dim=4, sliding_window=3,
+            rope_parameters=rope, moe_intermediate_size=8, num_experts=4,
+            num_experts_per_tok=2, vocab_size=v, token_minmax=[0.0, v - 1.0],
+        ), head_loss=("cross_entropy",), class_minmax=([0.0, v - 1.0],),
+    )
+    from hydragnn_tpu.models.create import make_example_batch
+
+    variables = init_model_variables(
+        model, make_example_batch(1, [1], ["node"], edge_dim=None, num_nodes=4, with_positions=True)
+    )
+    engine = InferenceEngine(model, variables, autostart=False)
+    text = engine._jit.lower(
+        variables["params"], variables.get("batch_stats", {}), engine._dummy_batch(16, 8)
+    ).as_text(debug_info=True)
+    engine.close()
+    used = set(_HYDRAGNN.findall(text))
+    assert used <= scopes.VOCABULARY, used - scopes.VOCABULARY
+    assert {scopes.ATTN_WINDOW, scopes.ATTN_FULL, scopes.MOE_ROUTE, scopes.MOE_EXPERTS,
+            scopes.HEAD_LOGPROB} <= used
+    assert f"conv_0/self_attn/{scopes.ATTN_WINDOW}" in text
+    assert f"conv_1/self_attn/{scopes.ATTN_FULL}" in text
+    assert f"conv_0/self_attn/{scopes.ATTN_FULL}" not in text
+    assert scopes.VERSION == 1
+
+
 def pytest_outermost_entry_point_names_the_operation():
     """``fused_segment_sum`` is ``..._sum_count``'s first output and
     ``segment_mean`` a sum over a count: one name an operation, the entry

@@ -545,6 +545,67 @@ def pytest_grouped_matmul_tiles_follow_the_weights(one_chip):
     assert f"f32[{held},{d},{f_}]" in text and f"f32[{held},{f_},{d}]" in text
 
 
+def pytest_mellum_engine_at_the_guard_rung_fits_beside_every_expert(one_chip):
+    """The serving engine's executable for Mellum2's block at the published
+    widths and the cell ``mellum2_12b_l4.serve_score_docs_c4_v98k``'s GUARD
+    rung (25,088 tokens: ``K N`` = 200,704 rows through a layer that holds
+    all 64 experts, in ONE pass), two layers, one of each kind (the
+    temporaries are a layer's, not the stack's: four layers read 4.02 GB):
+    the compiler's buffer assignment stays under 4.5 GB beside the weights,
+    the band is the splash kernel under its static mask (3 key blocks a query
+    block), the triangle the block-range kernel, the experts the grouped
+    matmul at tiles that divide 2304 (1152) and fit 896, and no loop over row
+    passes is compiled."""
+    import json
+
+    import numpy as np
+
+    from graftbench.drivers import serve_tokens
+    from hydragnn_tpu.graphs.collate import GraphArena
+    from hydragnn_tpu.graphs.sample import GraphSample
+    from hydragnn_tpu.models import lfm2
+    from hydragnn_tpu.ops.segment import platform_override
+    from hydragnn_tpu.serve.engine import _token_forward
+
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "graftbench", "configs", "mellum2_12b_l4.json",
+    )) as f:
+        config = json.load(f)
+    arch = serve_tokens.completed_arch(config)
+    arch.update(num_conv_layers=2, layer_types=["sliding_attention", "full_attention"])
+    model, template, _ = serve_tokens.init_model(arch)
+    n, tokens = 25088, 6144
+    pos = np.zeros((tokens, 3), np.float32)
+    pos[:, 0] = np.arange(tokens)
+    document = GraphSample(x=np.zeros((tokens, 1), np.float32), pos=pos)
+    batch = GraphArena([document] * 4).collate(
+        np.arange(4), num_nodes_pad=n, num_edges_pad=8, num_graphs_pad=5, with_positions=True,
+    )
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    with platform_override("tpu"):
+        compiled = _token_forward(model).lower(
+            shaped(template["params"]), shaped(template.get("batch_stats", {})), shaped(batch)
+        ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 4.5e9, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    rows, blocks = 8 * n, n // lfm2.ATTN_BLOCK
+    assert text.count("tpu_custom_call") == 2 * 4  # a core and three grouped matmuls a layer
+    assert f"f32[{rows},2304]" in text and f"f32[{rows},896]" in text
+    # One pass: no loop carries a row array (the head's row blocks and the
+    # top-k's are the loops there are).
+    assert not [l for l in text.splitlines() if " while(" in l and f"[{rows}," in l]
+    assert f"s8[1,{blocks},3]" in text and f"s8[1,{blocks},{blocks}]" not in text  # the band's schedule
+    assert lfm2._gmm_tiles(rows, 2304, 896) == (256, 1152, 896)
+    assert lfm2.band_key_blocks(n, 1024) == 1 + 2 + 3 * (blocks - 2)
+
+
 def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monkeypatch):
     """The scan path's program (``make_train_epoch_scan``) for the whole GATv2
     model of ``gatv2_h64x6_md17like.train_b512`` over a stack of
