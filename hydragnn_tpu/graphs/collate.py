@@ -11,7 +11,7 @@ on the host, once per batch.
 from __future__ import annotations
 
 import functools
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -346,6 +346,154 @@ class GraphArena:
             positions=positions,
             num_graphs_pad=g_pad,
         )
+
+
+class PreparedGraph(NamedTuple):
+    """One graph in the form ``collate_prepared`` concatenates: what the
+    arena makes of a sample, made per graph (``prepare_graph``)."""
+
+    x: np.ndarray  # [n, F] float32, contiguous
+    edge_index: np.ndarray  # [2, e] int32, stable-sorted by receiver
+    edge_attr: Optional[np.ndarray]  # [e, edge_dim] float32, the same order
+    pos: Optional[np.ndarray]  # [n, 3] float32 where positions were asked for
+    presorted: bool  # the edge list arrived in that order: nothing was sorted
+
+    @property
+    def num_nodes(self) -> int:
+        return self.x.shape[0]
+
+    @property
+    def num_edges(self) -> int:
+        return self.edge_index.shape[1]
+
+
+_NO_EDGES = np.zeros((2, 0), np.int32)
+_NO_EDGES.setflags(write=False)
+
+
+def _stable_order_by_receiver(receivers: np.ndarray, num_nodes: int) -> np.ndarray:
+    """The permutation of a stable sort by receiver. A graph under 65,536
+    nodes sorts 16-bit keys, which numpy's stable ``argsort`` takes as a radix
+    sort: on the serving host a quarter of the int32 stable sort's time on a
+    graph's ~21k receivers, and under a value sort of packed
+    ``(receiver << 32) | position`` keys both in turn and on 64 callers'
+    threads at once (``benchmarks/prepare_sort_routes.py``; PERF.md §6,
+    PR 42). A larger graph takes the int32 stable sort."""
+    keys = receivers.astype(np.uint16) if num_nodes <= 1 << 16 else receivers
+    return np.argsort(keys, kind="stable")
+
+
+def prepare_graph(
+    sample: GraphSample, edge_dim: int = 0, with_positions: bool = False
+) -> PreparedGraph:
+    """What ``GraphArena`` makes of ONE sample, for a batch that sees each
+    graph once (the serving engine: a request is prepared where it is
+    submitted, a flush is ``collate_prepared``). float32 features, int32
+    edges in the arena's order (a stable sort by receiver; an edge list that
+    arrives sorted, or empty, is taken as it is), ``edge_attr`` rows through
+    the same permutation where ``edge_dim`` asks for them. Reads the sample,
+    never writes it; an array already in its form is shared, not copied."""
+    x = np.ascontiguousarray(sample.x, dtype=np.float32)
+    pos = None
+    if with_positions:
+        if sample.pos is None:
+            raise ValueError("positions requested but the sample has no pos")
+        pos = np.ascontiguousarray(sample.pos, dtype=np.float32).reshape(-1, 3)
+    if not sample.num_edges:
+        return PreparedGraph(x, _NO_EDGES, None, pos, True)
+    ei = np.ascontiguousarray(sample.edge_index, dtype=np.int32)
+    ea = None
+    if edge_dim and sample.edge_attr is not None:
+        ea = np.asarray(sample.edge_attr, dtype=np.float32)[:, :edge_dim]
+    receivers = ei[1]
+    presorted = bool((receivers[1:] >= receivers[:-1]).all())
+    if not presorted:
+        order = _stable_order_by_receiver(receivers, x.shape[0])
+        # np.take along an axis: a quarter of ``ei[:, order]``'s time.
+        ei = np.take(ei, order, axis=1)
+        if ea is not None:
+            ea = np.take(ea, order, axis=0)
+    return PreparedGraph(x, ei, ea, pos, presorted)
+
+
+def collate_prepared(
+    graphs: Sequence[PreparedGraph],
+    num_nodes_pad: int,
+    num_edges_pad: int,
+    num_graphs_pad: int,
+    edge_dim: int = 0,
+    with_positions: bool = False,
+) -> GraphBatch:
+    """The batch ``GraphArena(samples).collate(arange(g), ...)`` gives at the
+    same pads, bit for bit (tests/test_collate.py), from graphs prepared with
+    the same ``edge_dim`` / ``with_positions``: one pass of slice writes with
+    the running node offset added, no sort and no gather. Unlabeled: no
+    targets. Graphs that lack ``edge_attr`` leave zero rows, as in the arena."""
+    g = len(graphs)
+    tot_nodes = sum(p.num_nodes for p in graphs)
+    tot_edges = sum(p.num_edges for p in graphs)
+    n_pad, e_pad, g_pad = num_nodes_pad, num_edges_pad, num_graphs_pad
+    if n_pad <= tot_nodes:
+        raise ValueError(f"num_nodes_pad={n_pad} must exceed total nodes {tot_nodes}")
+    if e_pad < tot_edges:
+        raise ValueError(f"num_edges_pad={e_pad} must fit total edges {tot_edges}")
+    if g_pad <= g:
+        raise ValueError(f"num_graphs_pad={g_pad} must exceed num graphs {g}")
+
+    node_features = np.zeros((n_pad, graphs[0].x.shape[1]), dtype=np.float32)
+    positions = np.zeros((n_pad, 3), dtype=np.float32) if with_positions else None
+    edge_features = (
+        np.zeros((e_pad, edge_dim), dtype=np.float32) if edge_dim else None
+    )
+    # Padding edges join the top padding node, padding nodes the top padding
+    # graph; only the tails need the sentinel, the heads are written below.
+    senders = np.empty((e_pad,), dtype=np.int32)
+    receivers = np.empty((e_pad,), dtype=np.int32)
+    senders[tot_edges:] = n_pad - 1
+    receivers[tot_edges:] = n_pad - 1
+    node_graph = np.empty((n_pad,), dtype=np.int32)
+    node_graph[tot_nodes:] = g_pad - 1
+    node_mask = np.zeros((n_pad,), dtype=bool)
+    node_mask[:tot_nodes] = True
+    edge_mask = np.zeros((e_pad,), dtype=bool)
+    edge_mask[:tot_edges] = True
+    graph_mask = np.zeros((g_pad,), dtype=bool)
+    graph_mask[:g] = True
+
+    n0 = e0 = 0
+    for i, p in enumerate(graphs):
+        n1, e1 = n0 + p.num_nodes, e0 + p.num_edges
+        node_features[n0:n1] = p.x
+        node_graph[n0:n1] = i
+        if positions is not None:
+            positions[n0:n1] = p.pos
+        if e1 > e0:
+            np.add(p.edge_index[0], n0, out=senders[e0:e1])
+            np.add(p.edge_index[1], n0, out=receivers[e0:e1])
+            if edge_features is not None and p.edge_attr is not None:
+                edge_features[e0:e1] = p.edge_attr
+        n0, e0 = n1, e1
+
+    row_ptr = build_row_ptr(receivers, n_pad)
+    graph_ptr = build_graph_ptr(node_graph, g_pad)
+    if csr_debug_enabled():
+        validate_csr(receivers, row_ptr, n_pad, what="receivers")
+        validate_csr(node_graph, graph_ptr, g_pad, what="node_graph")
+    return GraphBatch(
+        node_features=node_features,
+        edge_features=edge_features,
+        senders=senders,
+        receivers=receivers,
+        node_graph=node_graph,
+        node_mask=node_mask,
+        edge_mask=edge_mask,
+        graph_mask=graph_mask,
+        targets=(),
+        row_ptr=row_ptr,
+        graph_ptr=graph_ptr,
+        positions=positions,
+        num_graphs_pad=g_pad,
+    )
 
 
 def compute_pad_sizes(

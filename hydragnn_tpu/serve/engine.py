@@ -17,7 +17,8 @@ queue:
 
 * **Overlap.** Batches flow through the PR-1 two-stage ``DeviceFeed``
   pipeline (train/pipeline.py): the micro-batcher generator runs on the
-  feed's host thread (queue pop + deadline flush + arena collation), the
+  feed's host thread (queue pop + deadline flush + the concatenation of
+  requests that ``submit`` already made ready on their callers' threads), the
   transfer stage commits each batch with a blocking ``device_put`` on its
   own thread, and the dispatch thread only ever executes on
   already-committed device arrays — batch *k+1* transfers while batch *k*
@@ -66,7 +67,13 @@ import numpy as np
 
 from ..analysis import tsan
 from ..cache import CacheKey, ExecutableRegistry, ExecutableStore, tree_signature
-from ..graphs.collate import GraphArena, round_up_pow2
+from ..graphs.collate import (
+    GraphArena,
+    PreparedGraph,
+    collate_prepared,
+    prepare_graph,
+    round_up_pow2,
+)
 from ..graphs.packing import PackCaps, first_fit_decreasing
 from ..graphs.sample import GraphSample
 from ..telemetry import graftel as telemetry
@@ -220,9 +227,18 @@ class _Future:
 
 @dataclass
 class _Request:
-    sample: GraphSample
+    """One admitted request. ``graph`` is the caller's sample made ready on
+    the caller's thread (``prepare_graph``); the caller's own ``GraphSample``
+    is read once, never written and not kept. ``t_submit`` is taken after
+    ``_validate`` and BEFORE the preparation, ``t_queued`` after it, so
+    ``prepare`` (``t_queued - t_submit``) + ``queue_wait`` (the flush -
+    ``t_queued``) + ``collate`` + ``h2d`` + ``device`` add to ``e2e`` (the
+    resolution - ``t_submit``) with no second counted twice."""
+
+    graph: PreparedGraph
     future: _Future
     t_submit: float
+    t_queued: float
     request_id: str = ""
 
 
@@ -705,17 +721,28 @@ class InferenceEngine:
             raise EngineClosedError("engine is shut down")
         self._validate(sample)
         rid = request_id or telemetry.new_request_id()
-        req = _Request(
-            sample=sample,
-            future=_Future(request_id=rid),
-            t_submit=time.perf_counter(),
-            request_id=rid,
-        )
         telemetry.event(
             "serve/submit",
             request_id=rid,
             nodes=int(sample.num_nodes),
             edges=int(sample.num_edges),
+        )
+        # Made ready HERE, once, on the caller's thread, for every request
+        # (keyed on nothing: a caller sends a new graph each time), so that
+        # a flush is a concatenation (_collate).
+        t_submit = time.perf_counter()
+        with telemetry.span("serve/prepare", request_id=rid):
+            graph = prepare_graph(
+                sample,
+                edge_dim=self._edge_dim,
+                with_positions=self._with_positions,
+            )
+        req = _Request(
+            graph=graph,
+            future=_Future(request_id=rid),
+            t_submit=t_submit,
+            t_queued=time.perf_counter(),
+            request_id=rid,
         )
         with self._lock:
             self._pending.add(req.future)
@@ -750,7 +777,10 @@ class InferenceEngine:
             )
             return req.future
         self.metrics.count("requests_total")
-        self.metrics.record_request(sample.num_nodes, sample.num_edges)
+        self.metrics.observe("prepare", req.t_queued - t_submit)
+        if graph.presorted:
+            self.metrics.count("presorted_total")
+        self.metrics.record_request(graph.num_nodes, graph.num_edges)
         return req.future
 
     def predict(
@@ -842,7 +872,7 @@ class InferenceEngine:
             if ei.ndim != 2 or ei.shape[0] != 2:
                 raise ValueError("sample.edge_index must be [2, num_edges]")
             # Bounds matter for batch ISOLATION, not just this request: after
-            # the arena's per-graph offset shift an out-of-range index would
+            # the flush's per-graph offset shift an out-of-range index would
             # alias this graph's edges onto a co-batched graph's nodes.
             if ei.size and (ei.min() < 0 or ei.max() >= sample.num_nodes):
                 raise ValueError(
@@ -865,7 +895,7 @@ class InferenceEngine:
                     f"model expects edge_attr of width {self._edge_dim}; "
                     "request carries none"
                 )
-            # Row count too: the arena reads attr rows by edge_index counts,
+            # Row count too: the flush writes attr rows by edge_index counts,
             # so a mismatch corrupts (or crashes) co-batched requests.
             if np.ndim(ea) != 2 or np.shape(ea) != (
                 sample.num_edges,
@@ -921,7 +951,7 @@ class InferenceEngine:
     # ----------------------------------------------------------- the worker
     def _batch_source(self, stop: threading.Event):
         """Micro-batcher generator (runs on the DeviceFeed host thread):
-        pop → deadline/size flush → arena collation → host batch. ``stop`` is
+        pop → deadline/size flush → concatenation → host batch. ``stop`` is
         this incarnation's kill switch — set by a worker restart so a stale
         batcher cannot keep consuming the shared queue."""
         q = self._queue
@@ -990,8 +1020,8 @@ class InferenceEngine:
             nodes=top_n - 1, edges=top_e, graphs=self.max_batch_graphs
         )
         bins = first_fit_decreasing(
-            [r.sample.num_nodes for r in entries],
-            [r.sample.num_edges for r in entries],
+            [r.graph.num_nodes for r in entries],
+            [r.graph.num_edges for r in entries],
             caps,
         )
         return [[entries[i] for i in members] for members in bins]
@@ -1026,21 +1056,22 @@ class InferenceEngine:
         # Queue wait ends at the FLUSH (now), before collation starts — the
         # stage decomposition must not double-count collate seconds.
         for r in entries:
-            self.metrics.observe("queue_wait", t0 - r.t_submit)
+            self.metrics.observe("queue_wait", t0 - r.t_queued)
         # "pack bin" stage of the correlation trail: this span names every
         # request collated into the bin (docs/OBSERVABILITY.md).
         with telemetry.span(
             "serve/collate", request_ids=[r.request_id for r in entries]
         ):
-            samples = [r.sample for r in entries]
-            arena = GraphArena(samples)
-            tot_nodes = int(arena.ns.sum())
-            tot_edges = int(arena.es.sum())
+            graphs = [r.graph for r in entries]
+            node_start = np.zeros(len(graphs) + 1, np.int64)
+            np.cumsum([p.num_nodes for p in graphs], out=node_start[1:])
+            tot_nodes = int(node_start[-1])
+            tot_edges = sum(p.num_edges for p in graphs)
             n_pad, e_pad, fallback = self._bucket_shape(
                 tot_nodes, tot_edges, ladder
             )
-            batch = arena.collate(
-                np.arange(len(samples)),
+            batch = collate_prepared(
+                graphs,
                 num_nodes_pad=n_pad,
                 num_edges_pad=e_pad,
                 num_graphs_pad=self._g_pad,
@@ -1056,7 +1087,7 @@ class InferenceEngine:
             self.metrics.count("ladder_fallback_total")
         return _BatchWork(
             requests=entries,
-            node_start=np.asarray(arena.node_start[:-1], dtype=np.int64),
+            node_start=node_start[:-1],
             batch=batch,
             fallback=fallback,
         )
@@ -1264,7 +1295,7 @@ class InferenceEngine:
         if routing is not None:
             last = work.requests[-1]
             self._count_routing(
-                routing, int(work.node_start[-1]) + last.sample.num_nodes
+                routing, int(work.node_start[-1]) + last.graph.num_nodes
             )
         for i, req in enumerate(work.requests):
             per_head: List[np.ndarray] = []
@@ -1274,7 +1305,7 @@ class InferenceEngine:
                     val = out[i]
                 else:
                     start = int(work.node_start[i])
-                    val = out[start : start + req.sample.num_nodes]
+                    val = out[start : start + req.graph.num_nodes]
                 per_head.append(self._denormalize(ihead, val))
             if self._guard_outputs and any(
                 not np.isfinite(v).all() for v in per_head
@@ -1300,7 +1331,7 @@ class InferenceEngine:
             req.future.model_version = version
             if routing is not None:
                 start = int(work.node_start[i])
-                req.future.routing = routing[start : start + req.sample.num_nodes]
+                req.future.routing = routing[start : start + req.graph.num_nodes]
             req.future.set_result(per_head)
             self.metrics.observe("e2e", now - req.t_submit)
             # Demux complete: the end of the correlation trail

@@ -147,14 +147,24 @@ class ServeMetrics:
     """All counters/histograms of one ``InferenceEngine``.
 
     Latency stages (per docs/SERVING.md):
-      queue_wait — submit() to the request joining a flushed micro-batch;
-      collate    — host packing of the micro-batch into its padded arena;
+      prepare    — submit(), on the caller's thread: the request made ready
+                   for concatenation (float32 / int32 arrays, the edges
+                   stable-sorted by receiver); one observation an ADMITTED
+                   request, so its count is ``requests_total``;
+      queue_wait — the prepared request entering the queue to its joining a
+                   flushed micro-batch;
+      collate    — host assembly of the micro-batch into its padded arrays;
       h2d        — blocking device_put wire time (pipeline transfer thread);
       device     — compiled executable dispatch + readback;
       e2e        — submit() to future resolution.
+
+    ``t_submit`` sits in ``submit()`` after validation and BEFORE the
+    preparation (serve/engine.py ``_Request``): ``e2e`` holds ``prepare``,
+    ``queue_wait`` starts where ``prepare`` ends, and the five stages above
+    ``e2e`` add to it (less the demux) with no second counted twice.
     """
 
-    _STAGES = ("queue_wait", "collate", "h2d", "device", "e2e")
+    _STAGES = ("prepare", "queue_wait", "collate", "h2d", "device", "e2e")
 
     def __init__(self):
         self._lock = tsan.instrument_lock(
@@ -168,6 +178,9 @@ class ServeMetrics:
         }
         # Counters (monotonic).
         self.requests_total = 0  # guarded-by: self._lock
+        # Admitted requests whose edge list needed no sort in ``prepare``: it
+        # arrived non-decreasing by receiver (an edgeless request does).
+        self.presorted_total = 0  # guarded-by: self._lock
         self.rejected_total = 0  # guarded-by: self._lock
         self.errors_total = 0  # guarded-by: self._lock
         # Fault-tolerance split of errors (docs/FAULT_TOLERANCE.md):
@@ -327,6 +340,7 @@ class ServeMetrics:
             batches = self.batches_total
             out = {
                 "requests_total": self.requests_total,
+                "presorted_total": self.presorted_total,
                 "rejected_total": self.rejected_total,
                 "errors_total": self.errors_total,
                 "bad_batches_total": self.bad_batches_total,
@@ -413,6 +427,7 @@ class ServeMetrics:
     # e.g. batches_total incremented but graphs_total not yet).
     _PROM_COUNTERS = (
         ("requests_total", "requests_total"),
+        ("presorted_total", "presorted_total"),
         ("rejected_total", "rejected_total"),
         ("errors_total", "errors_total"),
         ("bad_batches_total", "bad_batches_total"),

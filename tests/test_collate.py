@@ -2,6 +2,7 @@
 reference serialized_dataset_loader.py:220-261) and the padding contract."""
 
 import numpy as np
+import pytest
 
 from hydragnn_tpu.graphs import GraphSample, collate_graphs, compute_pad_sizes
 
@@ -216,3 +217,168 @@ def pytest_arena_edge_cases():
         arena_l.collate([0], ("graph", "node"), (2, 1))
     with _pytest.raises(ValueError, match="spans"):
         arena_l.collate([0], ("graph", "node"), (1, 2))
+
+
+# ---------------------------------------------- prepared graphs (serving flush)
+def _random_graph(rng, n, e, attr_dim=0, pos=True, ei_dtype=np.int32):
+    return GraphSample(
+        x=rng.normal(size=(n, 2)).astype(np.float32),
+        pos=rng.normal(size=(n, 3)).astype(np.float32) if pos else None,
+        edge_index=rng.integers(0, n, size=(2, e)).astype(ei_dtype) if e else None,
+        edge_attr=rng.normal(size=(e, attr_dim)).astype(np.float32)
+        if attr_dim and e else None,
+    )
+
+
+def _lattices(rng):
+    """A slice of the serving cell's pool: radius graphs of BCC supercells,
+    both directions, in the neighbour search's order (unsorted)."""
+    from scipy.spatial import cKDTree
+
+    from graftbench.datagen import bcc_lattice
+
+    params = dict(graphs=5, cell_x=[3, 6], cell_y=[3, 5], cell_z=[2, 4], number_types=3)
+    out = []
+    for x, pos, _ in bcc_lattice.generate(params, 42):
+        pairs = cKDTree(pos).query_pairs(2.0, output_type="ndarray")
+        both = np.concatenate([pairs, pairs[:, ::-1]]).T
+        out.append(GraphSample(
+            x=x[:, :2], pos=pos, edge_index=np.ascontiguousarray(both, np.int32)
+        ))
+    return out, {}
+
+
+def _one_alone(rng):
+    return [_random_graph(rng, 7, 19)], {}
+
+
+def _zero_edge_among_others(rng):
+    return [_random_graph(rng, 5, 11), _random_graph(rng, 3, 0), _random_graph(rng, 6, 14)], {}
+
+
+def _all_edgeless(rng):
+    # The token shape: a document a graph, no edge, the rung's 8 padding edges.
+    return [_random_graph(rng, n, 0) for n in (9, 4, 12)], dict(
+        with_positions=True, num_edges_pad=8
+    )
+
+
+def _attr_on_all(rng):
+    return [_random_graph(rng, n, 3 * n, attr_dim=2) for n in (4, 6, 5)], dict(edge_dim=2)
+
+
+def _attr_on_some(rng):
+    return [
+        _random_graph(rng, 4, 9, attr_dim=2), _random_graph(rng, 5, 12),
+        _random_graph(rng, 3, 0), _random_graph(rng, 6, 10, attr_dim=2),
+    ], dict(edge_dim=2)
+
+
+def _attr_on_none(rng):
+    return [_random_graph(rng, n, 2 * n) for n in (4, 6)], dict(edge_dim=3)
+
+
+def _attr_unread(rng):
+    # The model reads no edge features: a request's own are left out.
+    return [_random_graph(rng, n, 2 * n, attr_dim=2) for n in (4, 6)], {}
+
+
+def _with_positions(rng):
+    return [_random_graph(rng, n, 2 * n) for n in (4, 6, 3)], dict(with_positions=True)
+
+
+def _already_sorted(rng):
+    graphs = [_random_graph(rng, n, 4 * n, attr_dim=1) for n in (5, 8)]
+    for s in graphs:
+        order = np.argsort(s.edge_index[1], kind="stable")
+        s.edge_index, s.edge_attr = s.edge_index[:, order], s.edge_attr[order]
+    return graphs, dict(edge_dim=1)
+
+
+def _duplicate_edges(rng):
+    # Many equal receivers, and whole edges repeated with DIFFERENT attrs:
+    # only a stable sort keeps the attr rows where the arena puts them.
+    graphs = []
+    for n in (3, 4):
+        ei = rng.integers(0, n, size=(2, 40)).astype(np.int32)
+        ei = np.concatenate([ei, ei[:, :15]], axis=1)
+        graphs.append(GraphSample(
+            x=rng.normal(size=(n, 2)).astype(np.float32), edge_index=ei,
+            edge_attr=np.arange(ei.shape[1], dtype=np.float32).reshape(-1, 1),
+        ))
+    return graphs, dict(edge_dim=1)
+
+
+def _over_65535_nodes(rng):
+    # Past the 16-bit keys' reach (the int32 fall-back) and exactly at its
+    # edge (receiver 65,535 of 65,536 nodes); int64 / non-contiguous inputs
+    # on the way.
+    big = _random_graph(rng, 70_000, 90_000, ei_dtype=np.int64)
+    big.edge_index[1, :4] = (69_999, 65_536, 65_535, 0)
+    big.x = np.asfortranarray(big.x.astype(np.float64))
+    edge = _random_graph(rng, 65_536, 80_000)
+    edge.edge_index[1, :3] = (65_535, 0, 65_535)
+    return [_random_graph(rng, 5, 9), big, edge], {}
+
+
+def _edges_fill_the_rung(rng):
+    graphs = [_random_graph(rng, n, 3 * n) for n in (4, 6)]
+    return graphs, dict(num_edges_pad=sum(s.num_edges for s in graphs))
+
+
+PREPARED_CASES = (
+    _lattices, _one_alone, _zero_edge_among_others, _all_edgeless, _attr_on_all,
+    _attr_on_some, _attr_on_none, _attr_unread, _with_positions, _already_sorted,
+    _duplicate_edges, _over_65535_nodes, _edges_fill_the_rung,
+)
+
+
+@pytest.mark.parametrize("case", PREPARED_CASES, ids=lambda f: f.__name__.strip("_"))
+def pytest_collate_prepared_equals_the_arena_bit_for_bit(case):
+    """The serving flush (``prepare_graph`` a request, ``collate_prepared`` a
+    flush) gives the arena's batch in every field, values and dtypes, and
+    leaves the samples as they were."""
+    import dataclasses
+
+    from hydragnn_tpu.graphs.collate import (
+        GraphArena, collate_prepared, prepare_graph,
+    )
+
+    samples, options = case(np.random.default_rng(11))
+    edge_dim = options.get("edge_dim", 0)
+    with_positions = options.get("with_positions", False)
+    pads = dict(
+        num_nodes_pad=sum(s.num_nodes for s in samples) + 3,
+        num_edges_pad=options.get(
+            "num_edges_pad", sum(s.num_edges for s in samples) + 5
+        ),
+        num_graphs_pad=len(samples) + 1,
+        edge_dim=edge_dim, with_positions=with_positions,
+    )
+    def arrays(s):  # astuple would copy them
+        return [getattr(s, f.name) for f in dataclasses.fields(s)]
+
+    before = [s.clone() for s in samples]
+    held = [arrays(s) for s in samples]
+    want = GraphArena(samples).collate(np.arange(len(samples)), **pads)
+    prepared = [prepare_graph(s, edge_dim, with_positions) for s in samples]
+    got = collate_prepared(prepared, **pads)
+
+    for field in dataclasses.fields(want):
+        a, b = getattr(want, field.name), getattr(got, field.name)
+        if isinstance(a, np.ndarray):
+            assert isinstance(b, np.ndarray) and a.dtype == b.dtype, field.name
+            assert np.array_equal(a, b), field.name
+        else:
+            assert a == b, field.name
+    for p, s in zip(prepared, samples):
+        r = p.edge_index[1]
+        assert (r[1:] >= r[:-1]).all() and p.x.flags.c_contiguous
+        if s.num_edges:
+            was = np.asarray(s.edge_index)[1]
+            assert p.presorted == bool((was[1:] >= was[:-1]).all())
+    # The caller's samples: the same objects holding the same values.
+    for s, fields, clone in zip(samples, held, before):
+        for now, then, kept in zip(arrays(s), fields, arrays(clone)):
+            assert now is then
+            assert now is None or np.array_equal(now, kept)

@@ -10,9 +10,11 @@ Covers the serving subsystem's contracts:
     "zero recompiles after warmup" steady-state property.
 """
 
+import dataclasses
 import json
 import os
 import sys
+import threading
 import time
 
 import numpy as np
@@ -252,6 +254,120 @@ def pytest_collation_failure_fails_batch_not_engine():
         # Engine still alive and serving.
         assert engine.predict(graphs[:2])[0] is not None
         assert engine._error is None
+    finally:
+        engine.close()
+
+
+# --------------------------------------------------- prepared once, at submit
+def _shuffled_edges(sample, seed=1):
+    """The sample with its edge list in no order (attrs along)."""
+    order = np.random.default_rng(seed).permutation(sample.num_edges)
+    out = sample.clone()
+    out.edge_index = np.ascontiguousarray(out.edge_index[:, order])
+    out.edge_attr = np.ascontiguousarray(out.edge_attr[order])
+    return out
+
+
+@pytest.mark.mpi_skip
+def pytest_submit_prepares_every_request_and_leaves_the_sample_alone(monkeypatch):
+    """A request is made ready in ``submit()``, every time (the same object
+    sent twice is prepared twice: no memo), on a copy: the caller's sample
+    keeps its very arrays. The ``prepare`` clock counts each admitted request
+    and the stages still add up to ``e2e``."""
+    from hydragnn_tpu.serve import engine as engine_module
+
+    calls = []
+    real_prepare = engine_module.prepare_graph
+
+    def counted(sample, **kw):
+        calls.append(id(sample))
+        return real_prepare(sample, **kw)
+
+    monkeypatch.setattr(engine_module, "prepare_graph", counted)
+    engine, graphs = _tiny_engine(max_batch_graphs=4, max_delay_ms=10.0)
+    try:
+        g = _shuffled_edges(graphs[0])
+        receivers = g.edge_index[1]
+        assert not (receivers[1:] >= receivers[:-1]).all()
+        held = {f.name: getattr(g, f.name) for f in dataclasses.fields(g)}
+        kept = g.clone()
+        first = engine.submit(g).result(timeout=30.0)
+        second = engine.submit(g).result(timeout=30.0)
+        for name, then in held.items():
+            now = getattr(g, name)
+            assert now is then, name
+            assert now is None or np.array_equal(now, getattr(kept, name)), name
+        assert calls == [id(g), id(g)]
+        for a, b in zip(first, second):
+            assert np.array_equal(a, b)
+        # The order the edges arrive in is no part of the answer.
+        for a, b in zip(first, engine.predict([graphs[0]])[0]):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+        order = np.argsort(receivers, kind="stable")
+        tidy = g.clone()
+        tidy.edge_index, tidy.edge_attr = g.edge_index[:, order], g.edge_attr[order]
+        engine.submit(tidy).result(timeout=30.0)
+
+        snap = engine.metrics.snapshot()
+        stages = snap["latency_ms"]
+        assert snap["requests_total"] == stages["prepare"]["count"] == len(calls)
+        assert snap["presorted_total"] >= 1  # ``tidy``; graphs[0] if built sorted
+        assert snap["presorted_total"] <= 2
+        # One request a flush here, so a request's stages are its flush's:
+        # they add to no more than submit -> resolution.
+        parts = ("prepare", "queue_wait", "collate", "h2d", "device")
+        assert all(stages[p]["count"] == 4 for p in parts + ("e2e",))
+        assert sum(stages[p]["sum_s"] for p in parts) <= stages["e2e"]["sum_s"] + 1e-3
+        text = engine.metrics.render_prometheus()
+        assert 'hydragnn_serve_latency_seconds_count{stage="prepare"} 4' in text
+        assert f"hydragnn_serve_presorted_total {snap['presorted_total']}" in text
+    finally:
+        engine.close()
+
+
+@pytest.mark.mpi_skip
+def pytest_concurrent_submits_get_the_serial_replies():
+    """64 callers preparing and submitting at once (more threads than cores,
+    a shortened switch interval) each get the reply a serial ``predict`` of
+    their graph gives, and every request was prepared exactly once."""
+    engine, graphs = _tiny_engine(
+        max_batch_graphs=16, max_delay_ms=20.0, queue_limit=128,
+        bucket_ladder=[(512, 8192)],
+    )
+    try:
+        graphs = [_shuffled_edges(g, seed=i) for i, g in enumerate(graphs)]
+        serial = [engine.predict([g])[0] for g in graphs]
+        picks = [slot % len(graphs) for slot in range(64)]
+        replies, errors, gate = [None] * len(picks), [], threading.Event()
+
+        def call(slot):
+            gate.wait(10.0)
+            try:
+                fut = engine.submit(graphs[picks[slot]])
+                replies[slot] = fut.result(timeout=60.0)
+            except Exception as e:  # noqa: BLE001 -- reported below
+                errors.append(repr(e))
+
+        threads = [threading.Thread(target=call, args=(s,)) for s in range(len(picks))]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            gate.set()
+            for t in threads:
+                t.join(timeout=120.0)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads) and not errors, errors
+        for slot, reply in enumerate(replies):
+            for a, b in zip(reply, serial[picks[slot]]):
+                assert np.array_equal(a, b), slot
+        snap = engine.metrics.snapshot()
+        assert snap["requests_total"] == len(graphs) + len(picks)
+        assert snap["latency_ms"]["prepare"]["count"] == snap["requests_total"]
+        assert snap["bucket_cache"]["ladder_fallbacks"] == 0
     finally:
         engine.close()
 
