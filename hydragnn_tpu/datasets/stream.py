@@ -282,7 +282,7 @@ class StreamingGraphLoader(GraphDataLoader):
         reshuffle: str = "sample",
         skip_budget: int = 0,
         packing: bool = False,
-        ladder_step: str = "pow2",
+        ladder_step: Optional[str] = None,
         ring_depth: int = 2,
         resident_shards: int = 8,
         with_positions: bool = False,

@@ -11,6 +11,7 @@ on the host, once per batch.
 from __future__ import annotations
 
 import functools
+import math
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -496,13 +497,24 @@ def collate_prepared(
     )
 
 
+def loader_pad_tile() -> int:
+    """The step a loader's static pad is rounded up to when its ``Dataset``
+    block names no ``ladder_step``: the least common multiple of the extrema
+    kernels' edge block and node block (``ops/extrema_scan.py`` ``_XB``,
+    ``_NB``), so their padding of ``[E, F]`` and ``[N, F]`` to whole blocks
+    stays a no-op on a loader's shapes."""
+    from ..ops.extrema_scan import _NB, _XB
+
+    return math.lcm(_XB, _NB)
+
+
 def compute_pad_sizes(
-    graphs: Sequence[GraphSample], batch_size: int, ladder_step: str = "pow2"
+    graphs: Sequence[GraphSample], batch_size: int, ladder_step: Optional[str] = None
 ) -> Tuple[int, int, int]:
     """Dataset-level static pad sizes so every batch of ``batch_size`` graphs from
     this dataset fits one compiled shape: a worst-case batch is the ``batch_size``
-    largest graphs. ``ladder_step`` picks the round-up ladder (see
-    ``round_up_pow2``)."""
+    largest graphs. ``ladder_step`` picks the round-up (see
+    ``compute_pad_sizes_from_counts``)."""
     return compute_pad_sizes_from_counts(
         [s.num_nodes for s in graphs],
         [s.num_edges for s in graphs],
@@ -512,15 +524,31 @@ def compute_pad_sizes(
 
 
 def compute_pad_sizes_from_counts(
-    ns, es, batch_size: int, ladder_step: str = "pow2"
+    ns, es, batch_size: int, ladder_step: Optional[str] = None
 ) -> Tuple[int, int, int]:
     """``compute_pad_sizes`` from per-sample (num_nodes, num_edges) count
     arrays alone — the form the loaders use (their ``_ns``/``_es`` arrays are
     the single source of truth) and the only form the out-of-core streaming
     loader CAN use: its pad shapes come from the GSHD index without decoding
-    a single shard (docs/DATA_PLANE.md)."""
+    a single shard (docs/DATA_PLANE.md).
+
+    This shape is chosen ONCE a bucket, so it is one compiled program whatever
+    it is rounded to, and the power of two that bounds the programs where a
+    shape is chosen per batch (``round_up_pow2``'s own default) buys nothing
+    here: ``ladder_step=None`` (a ``Dataset`` block that names none; the one
+    place this default lives) rounds up to the next multiple of
+    ``loader_pad_tile()`` and keeps the power of two at or under four tiles,
+    as ``"mult64"`` keeps it under 256. ``"pow2"`` and ``"mult64"`` named
+    mean what they always did."""
     nodes = sorted((int(n) for n in ns), reverse=True)[:batch_size]
     edges = sorted((int(e) for e in es), reverse=True)[:batch_size]
-    n_pad = round_up_pow2(sum(nodes) + 1, mode=ladder_step)
-    e_pad = round_up_pow2(max(sum(edges), 1) + 1, mode=ladder_step)
+    from .packing import round_up_step
+
+    ladder = (
+        dict(mode="mult64", step=loader_pad_tile())
+        if ladder_step is None
+        else dict(mode=ladder_step)
+    )
+    n_pad = round_up_step(sum(nodes) + 1, **ladder)
+    e_pad = round_up_step(max(sum(edges), 1) + 1, **ladder)
     return n_pad, e_pad, batch_size + 1

@@ -74,6 +74,19 @@ def invalid_sample_reason(s: GraphSample) -> Optional[str]:
     return None
 
 
+def share_eval_pads(val_loader, test_loader) -> None:
+    """Give the two evaluation loaders ONE static shape, the larger of theirs
+    in each dimension, so that validation and test run one compiled program.
+    The power of two did that by itself; the round-up to the kernels' tile
+    (``compute_pad_sizes_from_counts``) does not: two samples of one dataset
+    give 283,648 and 288,256 edge rows. Called where the three loaders are
+    made, when no ladder is named."""
+    pads = val_loader._bucket_pads + test_loader._bucket_pads
+    if len(pads) == 2:  # an evaluation loader has one bucket, an empty split none
+        shared = tuple(map(max, *pads))
+        val_loader._bucket_pads, test_loader._bucket_pads = [shared], [shared]
+
+
 class GraphDataLoader:
     def __init__(
         self,
@@ -91,7 +104,7 @@ class GraphDataLoader:
         skip_budget: int = 0,
         fault_plan=None,
         packing: bool = False,
-        ladder_step: str = "pow2",
+        ladder_step: Optional[str] = None,
         with_positions: Optional[bool] = None,
     ):
         """``reshuffle`` picks the per-epoch shuffling granularity:
@@ -124,8 +137,11 @@ class GraphDataLoader:
         still reshuffle per epoch) — a mild SGD semantics change like
         ``reshuffle="batch"``, which is why it is opt-in; same-seed
         convergence parity is locked by tests/test_packing.py.
-        ``ladder_step`` picks the pad round-up ladder (``"pow2"`` historical,
-        ``"mult64"``: multiples of 64 above 256 — docs/INPUT_PIPELINE.md).
+        ``ladder_step`` names the pad round-up (``"pow2"``: the next power of
+        two; ``"mult64"``: multiples of 64 above 256); None, as when the
+        ``Dataset`` block names none, rounds a bucket's one static shape up to
+        the kernels' tile (``compute_pad_sizes_from_counts``;
+        docs/INPUT_PIPELINE.md).
 
         ``with_positions`` puts the node coordinates into every batch
         (``GraphBatch.positions``) for the families that compute their edge

@@ -15,7 +15,7 @@ from typing import Dict, Tuple
 from ..parallel.distributed import barrier, get_comm_size_and_rank
 from ..telemetry import graftel as telemetry
 from ..utils.time_utils import Timer
-from .dataloader import GraphDataLoader
+from .dataloader import GraphDataLoader, share_eval_pads
 from .raw_loader import RawDataLoader
 from .serialized_loader import SerializedDataLoader
 from .splitting import split_dataset
@@ -62,15 +62,17 @@ def dataset_loading_and_splitting(config: Dict):
         fault_plan=fault_plan,
         # Graph packing + pad round-up ladder (docs/INPUT_PIPELINE.md
         # "Graph packing"): packing densifies train batches by FFD
-        # bin-packing; ladder_step picks pow2 vs multiples-of-64 pads.
+        # bin-packing; ladder_step names the pad round-up ("pow2",
+        # "mult64"); absent, the static pad rounds up to the kernels' tile
+        # (graphs/collate.py compute_pad_sizes_from_counts).
         packing=bool(config["Dataset"].get("packing", False)),
-        ladder_step=config["Dataset"].get("ladder_step", "pow2"),
+        ladder_step=config["Dataset"].get("ladder_step"),
     )
 
 
 def create_dataloaders(trainset, valset, testset, batch_size, num_buckets=1,
                        reshuffle="sample", skip_budget=0, fault_plan=None,
-                       packing=False, ladder_step="pow2"):
+                       packing=False, ladder_step=None):
     """Three GraphDataLoaders; multi-process runs shard every split by process
     (the DistributedSampler analog). Returns (train, val, test, sampler_list) for
     reference API parity — the loaders are their own samplers here.
@@ -122,6 +124,8 @@ def create_dataloaders(trainset, valset, testset, batch_size, num_buckets=1,
             )
         )
     train_loader, val_loader, test_loader = loaders
+    if ladder_step is None:
+        share_eval_pads(val_loader, test_loader)
     sampler_list = loaders if world_size > 1 else []
     return train_loader, val_loader, test_loader, sampler_list
 
@@ -161,12 +165,14 @@ def create_streaming_dataloaders(config: Dict):
                 reshuffle=reshuffle if shuffle else "sample",
                 skip_budget=ds.get("skip_budget", 0),
                 packing=bool(ds.get("packing", False)) if shuffle else False,
-                ladder_step=ds.get("ladder_step", "pow2"),
+                ladder_step=ds.get("ladder_step"),
                 ring_depth=ds.get("ring_depth", 2),
                 resident_shards=ds.get("resident_shards", 8),
             )
         )
     train_loader, val_loader, test_loader = loaders
+    if ds.get("ladder_step") is None:
+        share_eval_pads(val_loader, test_loader)
     sampler_list = loaders if world_size > 1 else []
     return train_loader, val_loader, test_loader, sampler_list
 

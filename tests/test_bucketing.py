@@ -6,6 +6,7 @@ pads nothing — PyG batches are ragged)."""
 
 import numpy as np
 import jax
+import pytest
 
 from hydragnn_tpu.graphs import GraphSample, collate_graphs
 from hydragnn_tpu.models import create_model, init_model_variables
@@ -100,6 +101,42 @@ def pytest_pad_sizes_covers_all_buckets():
         assert b.node_features.shape[0] <= n_pad
         assert b.senders.shape[0] <= e_pad
         assert b.num_graphs_pad <= g_pad
+
+
+@pytest.mark.parametrize("ladder_step", [None, "pow2"])
+def pytest_evaluation_loaders_share_one_shape(ladder_step):
+    """Validation and test run the same evaluation program, so with no ladder
+    named (pads rounded up to the kernels' tile, which two samples of one
+    dataset rarely share) ``create_dataloaders`` gives both the larger pad in
+    each dimension: one shape, one compile. A named ladder is left as it was,
+    and so are the train loader's buckets."""
+    from hydragnn_tpu.preprocess.load_data import create_dataloaders
+
+    train, val, test = (
+        _mixed_dataset(np.random.default_rng(seed), count=160, small=(30, 50), large=large)
+        for seed, large in ((0, (40, 64)), (1, (40, 52)), (2, (40, 64)))
+    )
+    made = create_dataloaders(
+        train, val, test, batch_size=64, num_buckets=2, ladder_step=ladder_step
+    )[:3]
+    alone = [
+        GraphDataLoader(
+            ds, batch_size=64, shuffle=i == 0, num_buckets=2 if i == 0 else 1,
+            ladder_step=ladder_step,
+        )
+        for i, ds in enumerate((train, val, test))
+    ]
+    assert made[0]._bucket_pads == alone[0]._bucket_pads and made[0].num_buckets == 2
+    if ladder_step is None:
+        assert alone[1].pad_sizes != alone[2].pad_sizes  # the case at hand
+        shared = tuple(max(d) for d in zip(alone[1].pad_sizes, alone[2].pad_sizes))
+        assert made[1].pad_sizes == made[2].pad_sizes == shared
+        shapes = {
+            (b.node_features.shape[0], b.senders.shape[0]) for ld in made[1:] for b in ld
+        }
+        assert shapes == {shared[:2]}
+    else:
+        assert [ld.pad_sizes for ld in made[1:]] == [ld.pad_sizes for ld in alone[1:]]
 
 
 def pytest_uniform_dataset_collapses_buckets():

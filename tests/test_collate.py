@@ -68,6 +68,64 @@ def pytest_pad_sizes_fit_worst_batch():
     assert g_pad == 3
 
 
+# (ladder_step, worst batch + 1 as (nodes, edges)) -> the loader's static pad.
+# The first three are the benchmark's graph cells (PNA's small bucket, PNA's
+# large bucket and its one-bucket evaluation loaders, the md17-shaped loader of
+# GATv2 and PaiNN): an absent ``Dataset.ladder_step`` rounds up to the extrema
+# kernels' tile. A named ladder means what it always did, and at or under four
+# tiles the default is still the power of two.
+_ROUND_UPS = [
+    (None, (8193, 112641), (8704, 113152)),
+    (None, (18433, 401409), (18944, 401920)),
+    (None, (10753, 215041), (11264, 215552)),
+    (None, (2049, 2050), (2560, 2560)),
+    (None, (2048, 1025), (2048, 2048)),
+    (None, (520, 130), (1024, 256)),
+    (None, (3, 2), (8, 8)),
+    ("pow2", (8193, 112641), (16384, 131072)),
+    ("pow2", (10753, 215041), (16384, 262144)),
+    ("mult64", (4097, 16321), (4160, 16384)),
+    ("mult64", (520, 130), (576, 256)),
+]
+
+
+@pytest.mark.parametrize(
+    "ladder_step,worst,want", _ROUND_UPS,
+    ids=[f"{step}-{worst[0]}x{worst[1]}" for step, worst, _ in _ROUND_UPS],
+)
+def pytest_loader_pad_round_up(ladder_step, worst, want):
+    from hydragnn_tpu.graphs.collate import (
+        GraphArena, compute_pad_sizes_from_counts, loader_pad_tile, round_up_pow2,
+    )
+    from hydragnn_tpu.ops.extrema_scan import _NB, _XB
+
+    tile = loader_pad_tile()
+    assert tile % _XB == 0 and tile % _NB == 0 and tile == 512
+    # One graph a batch: the worst batch + 1 is the graph's own counts + 1.
+    n_pad, e_pad, g_pad = compute_pad_sizes_from_counts(
+        [worst[0] - 1, 1], [worst[1] - 1, 1], 1, ladder_step=ladder_step
+    )
+    assert (n_pad, e_pad, g_pad) == (*want, 2)
+    for real, pad in zip(worst, (n_pad, e_pad)):
+        assert pad >= real
+        if ladder_step is None and pad > 4 * tile:
+            assert pad % _XB == 0 and pad % _NB == 0 and pad - real < tile
+        elif ladder_step != "mult64":
+            assert pad & (pad - 1) == 0
+    # Where a shape is chosen per batch the power of two bounds the programs:
+    # the round-up's own default and an arena given no pads keep it.
+    assert round_up_pow2(worst[0]) == 1 << (max(worst[0], 8) - 1).bit_length()
+    if worst[0] <= 1024:
+        graph = GraphSample(
+            x=np.zeros((worst[0] - 1, 1), np.float32), pos=None,
+            y=np.zeros(1, np.float32), y_loc=np.array([[0, 1]], np.int64),
+            edge_index=np.zeros((2, worst[1] - 1), np.int32),
+        )
+        batch = GraphArena([graph]).collate([0], ("graph",), (1,))
+        assert batch.node_features.shape[0] == round_up_pow2(worst[0])
+        assert batch.senders.shape[0] == round_up_pow2(worst[1])
+
+
 def pytest_vectorized_collate_matches_per_sample_unpack():
     """The vectorized packer must equal a per-sample reference built directly
     from unpack_targets over random ragged graphs (incl. vector node heads and

@@ -180,6 +180,57 @@ def pytest_streamed_collation_bit_exact_vs_in_memory(tmp_path, knobs):
                     assert np.array_equal(np.asarray(x), np.asarray(y))
 
 
+@pytest.mark.parametrize("ladder_step", [None, "pow2", "mult64"])
+def pytest_streamed_and_in_memory_loaders_round_their_pads_alike(tmp_path, ladder_step):
+    """Both loaders take a bucket's static pad from the same count arrays
+    through the same function, so an absent ``ladder_step`` gives both the
+    same multiples of the kernels' tile (two buckets, each over 2,048 rows:
+    under that the default is a power of two like ``"pow2"``), a named one
+    what it always gave, and the first batches are bit-identical."""
+    from hydragnn_tpu.datasets import shards
+    from hydragnn_tpu.datasets.stream import StreamingGraphLoader
+    from hydragnn_tpu.graphs.collate import loader_pad_tile
+    from hydragnn_tpu.graphs.sample import GraphSample
+    from hydragnn_tpu.preprocess.dataloader import GraphDataLoader
+
+    rng = np.random.default_rng(11)
+    samples = []
+    for i in range(192):
+        n = int(rng.integers(20, 30)) * (1 if i % 2 else 3)
+        e = 4 * n
+        samples.append(GraphSample(
+            x=rng.standard_normal((n, 4)).astype(np.float32),
+            pos=rng.standard_normal((n, 3)).astype(np.float32),
+            edge_index=rng.integers(0, n, size=(2, e)).astype(np.int64),
+            y=rng.standard_normal((1,)).astype(np.float32),
+            y_loc=np.asarray([[0, 1]], np.int64),
+        ))
+    corpus = str(tmp_path / "corpus")
+    shards.write_gshd(corpus, samples, shard_size=32, name="t")
+    common = dict(
+        batch_size=96, shuffle=True, seed=2, num_buckets=2, head_types=("graph",),
+        head_dims=(1,), with_positions=False, ladder_step=ladder_step,
+    )
+    mem = GraphDataLoader(samples, **common)
+    st = StreamingGraphLoader(corpus, **common)
+    assert mem.num_buckets == st.num_buckets == 2
+    assert mem._bucket_pads == st._bucket_pads
+    tile = loader_pad_tile()
+    for n_pad, e_pad, _ in mem._bucket_pads:
+        assert n_pad > 4 * tile and e_pad > 4 * tile
+        power_of_two = n_pad & (n_pad - 1) == 0 and e_pad & (e_pad - 1) == 0
+        if ladder_step is None:
+            assert n_pad % tile == 0 and e_pad % tile == 0 and not power_of_two
+        elif ladder_step == "pow2":
+            assert power_of_two
+        else:
+            assert n_pad % 64 == 0 and e_pad % 64 == 0 and e_pad % tile != 0
+    bm, bs = next(iter(mem)), next(iter(st))
+    assert bm.node_features.shape[0] in {p[0] for p in mem._bucket_pads}
+    assert np.array_equal(bm.senders, bs.senders)
+    assert np.array_equal(bm.node_features, bs.node_features)
+
+
 # -------------------------------------------------------------- prefetch ring
 def pytest_plan_shard_ring_bounds_and_coverage():
     """The Belady schedule never holds more than ``capacity`` shards and

@@ -38,16 +38,16 @@ def _sorts(text, scope):
 
 def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch):
     """One ``GATv2Conv``, forward and backward, at the shapes of the cell
-    ``gatv2_h64x6_md17like.train_b512`` (16384 × 262144, six heads of 64) on
+    ``gatv2_h64x6_md17like.train_b512`` (11264 × 215552, six heads of 64) on
     the sorted/CSR route the chip takes: the row gathers come out as
-    ``f32[262144,384]``, the backward holds exactly two scatter-adds into
-    ``f32[16384,384]``, and no array with a ``[6,64]`` row or a transposed
-    ``[262144,384]{0,1}`` is written anywhere (PERF.md §6, PR 24: a reshape
+    ``f32[215552,384]``, the backward holds exactly two scatter-adds into
+    ``f32[11264,384]``, and no array with a ``[6,64]`` row or a transposed
+    ``[215552,384]{0,1}`` is written anywhere (PERF.md §6, PR 24: a reshape
     inside the per-head reduce makes the chip's compiler write both)."""
     from hydragnn_tpu.models.convs import GATv2Conv
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    n, e, h, f = 16384, 262144, 6, 64
+    n, e, h, f = 11264, 215552, 6, 64
     conv = GATv2Conv(out_dim=f, heads=h)
 
     def shaped(shape, dtype=jnp.float32):
@@ -110,17 +110,17 @@ def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch
 
 def pytest_painn_block_at_cell_size_keeps_the_vector_state_flat(one_chip, monkeypatch):
     """One ``PaiNNBlock`` (message + update), forward and backward, at the
-    shapes of the cell ``painn_f128.train_b512`` (16384 × 262144, F 128, 20
+    shapes of the cell ``painn_f128.train_b512`` (11264 × 215552, F 128, 20
     basis functions) on the sorted/CSR route the chip takes: the two sources
-    come out as ``f32[262144,384]`` row gathers with two scatter-adds into
-    ``f32[16384,384]`` behind them, the two states are summed as ONE
-    ``[262144,512]`` array, and no array with a ``[3,128]`` row or a
-    transposed ``[262144,384]{0,1}`` is written anywhere: ``v`` stays flat
+    come out as ``f32[215552,384]`` row gathers with two scatter-adds into
+    ``f32[11264,384]`` behind them, the two states are summed as ONE
+    ``[215552,512]`` array, and no array with a ``[3,128]`` row or a
+    transposed ``[215552,384]{0,1}`` is written anywhere: ``v`` stays flat
     ``[·, 3F]``, xyz-major, from the gather to what the backward saves."""
     from hydragnn_tpu.models.painn import EdgeGeometry, PaiNNBlock
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    n, e, f, radial = 16384, 262144, 128, 20
+    n, e, f, radial = 11264, 215552, 128, 20
     block = PaiNNBlock(f)
 
     def shaped(shape, dtype=jnp.float32):
@@ -200,20 +200,20 @@ def _combiners(text):
 @pytest.mark.parametrize("f", [256, 1], ids=["hidden_256", "input_layer_1"])
 def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monkeypatch, f):
     """One ``PNAConv``, forward and backward, at the large bucket of the cell
-    ``pna_multihead_h256.train_b512`` (32768 × 524288; a hidden layer's 256
+    ``pna_multihead_h256.train_b512`` (18944 × 401920; a hidden layer's 256
     columns, the input layer's one) on the CSR route the chip takes: min and
     max come from ONE Mosaic kernel in the forward and their cotangents go
     down the rows in ONE in the backward, both under
     ``hydragnn.agg.extrema.pallas_csr``; no ``[E, f]`` row gather is left
     under that scope; and no scatter that combines by minimum or maximum is
-    left anywhere (the one scatter into ``f32[32768,f]`` that stays is the
+    left anywhere (the one scatter into ``f32[18944,f]`` that stays is the
     centered sum of squares of ``std``)."""
     from hydragnn_tpu.models.convs import PNAConv
     from hydragnn_tpu.ops import segment as seg
     from hydragnn_tpu.telemetry import scopes
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    n, e = 32768, 524288
+    n, e = 18944, 401920
     conv = PNAConv(out_dim=256, deg_avg_log=2.5, deg_avg_lin=14.0, edge_dim=1)
 
     def shaped(shape, dtype=jnp.float32):
@@ -609,11 +609,11 @@ def pytest_mellum_engine_at_the_guard_rung_fits_beside_every_expert(one_chip):
 def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monkeypatch):
     """The scan path's program (``make_train_epoch_scan``) for the whole GATv2
     model of ``gatv2_h64x6_md17like.train_b512`` over a stack of
-    ``SCAN_CHUNK`` batches of the cell's one shape (16384 × 262144, 513 graph
+    ``SCAN_CHUNK`` batches of the cell's one shape (11264 × 215552, 513 graph
     slots): ONE ``while`` whose condition compares the induction variable
     with the ``count`` PARAMETER, no constant (so no trip count is known to
     the compiler and a tail needs no program of its own), whose body holds
-    the step (the two backward scatter-adds a layer into ``f32[16384,384]``),
+    the step (the two backward scatter-adds a layer into ``f32[11264,384]``),
     within the chip's memory."""
     import json
 
@@ -635,7 +635,7 @@ def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monke
     arch.update(input_dim=1, output_dim=[1], output_type=["graph"], num_nodes=21)
     model = create_model_config(config=arch)
     opt = select_optimizer("AdamW", 1e-3)
-    n, e, g = 16384, 262144, 513
+    n, e, g = 11264, 215552, 513
 
     def batch(lead, make):
         def arr(shape, dtype=np.float32):
@@ -682,7 +682,7 @@ def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monke
     cond = cond[:cond.index("\n}")]
     assert "compare(" in cond and "direction=LT" in cond
     assert "constant(" not in cond, cond
-    scatters = re.findall(r"f32\[16384,384\]\S* scatter\(", text)
+    scatters = re.findall(rf"f32\[{n},384\]\S* scatter\(", text)
     assert len(scatters) >= 6, len(scatters)
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
 
