@@ -13,8 +13,8 @@ forward, 32 heads of 128 in float32 rows, at the cell's four rungs (12,288 /
                 and the schedule has no surplus step
 
 and, on each, JAX's flash kernel as a differentiated call still takes it
-(``models/lfm2.py`` ``_flash_attention_tpu``: the whole triangle whatever the
-mix) beside the block-range kernel with 1, 2, 4 and 8 query heads a grid
+(``models/token_attention.py`` ``_flash_attention_tpu``: the whole triangle
+whatever the mix) beside the block-range kernel with 1, 2, 4 and 8 query heads a grid
 step. A row holds the milliseconds a call, the (query block, key block)
 pairs visited and the triangle's, and whether the result equals the flash
 kernel's bit for bit.
@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hydragnn_tpu.models import lfm2
+from hydragnn_tpu.models import token_attention
 from hydragnn_tpu.ops import block_attention
 
 REPEATS, ROUNDS = 10, 3
@@ -89,10 +89,10 @@ def table(rungs, heads, hd, block, interpret):
         }
         if interpret:
             def flash(q, k, v, ids):
-                return lfm2.segment_causal_attention(q, k, v, ids).reshape(q.shape)
+                return token_attention.segment_causal_attention(q, k, v, ids).reshape(q.shape)
         else:
             def flash(q, k, v, ids):
-                return lfm2._flash_attention_tpu(q, k, v, ids, scale)
+                return token_attention._flash_attention_tpu(q, k, v, ids, scale)
         routes = {"flash": jax.jit(flash)}
         for g in HEADS_A_STEP:
             routes[f"block range, {g} heads a step"] = functools.partial(
@@ -127,10 +127,10 @@ def main(argv):
     if rehearsal:
         global REPEATS, ROUNDS
         REPEATS, ROUNDS = 1, 1
-        lfm2.ATTN_BLOCK = 128
+        token_attention.ATTN_BLOCK = 128
         made = table({1024: (128, 256, 256, 384)}, 8, 128, 128, True)
     else:
-        made = table(RUNGS, HEADS, HEAD_DIM, lfm2.ATTN_BLOCK, False)
+        made = table(RUNGS, HEADS, HEAD_DIM, token_attention.ATTN_BLOCK, False)
     device = jax.devices()[0]
     rows = []
     for row in made:

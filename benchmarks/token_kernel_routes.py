@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
-"""Kernel-alone table behind ``models/lfm2.py`` ``ATTN_BLOCK`` (the band) and
-``GMM_TILING`` at the shapes of the cell ``laguna_xs2_ep8.train_seq4k_b1`` (one
-sequence of 4096 tokens in a bucket of 4160 nodes; 8 key-value heads of 128):
+"""Kernel-alone table behind ``models/token_attention.py`` ``ATTN_BLOCK`` (the
+band) and ``models/token_routed.py`` ``GMM_TILING`` at the shapes of the cell
+``laguna_xs2_ep8.train_seq4k_b1`` (one sequence of 4096 tokens in a bucket of
+4160 nodes; 8 key-value heads of 128):
 
     attention   ``segment_causal_attention`` as the layers call it, forward
                 and forward + backward: the complete causal graph (the flash
@@ -67,7 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hydragnn_tpu.models import lfm2
+from hydragnn_tpu.models import lfm2, token_attention, token_routed
 
 REPEATS, ROUNDS = 10, 3
 
@@ -103,10 +104,10 @@ def attention_rows(n, kv, hd, window, rehearsal):
             jnp.asarray(rng.normal(size=(n, h, hd)), dtype) for h in (heads, kv, kv)
         )
         if block:
-            lfm2.ATTN_BLOCK = block
+            token_attention.ATTN_BLOCK = block
 
         def fwd(q, k, v):
-            return lfm2.segment_causal_attention(q, k, v, seg, window=w)
+            return token_attention.segment_causal_attention(q, k, v, seg, window=w)
 
         def loss(q, k, v):
             return (fwd(q, k, v).astype(jnp.float32) ** 2).sum()
@@ -125,11 +126,11 @@ def expert_rows(rows, live, groups, d, f):
         lhs = jnp.asarray(rng.normal(size=(rows, k)), jnp.float32)
         rhs = jnp.asarray(rng.normal(size=(groups, k, n)) / np.sqrt(k), jnp.float32)
         for tile in (128, 256, 512):
-            lfm2.GMM_TILING = (tile, 1024, 1024)
+            token_routed.GMM_TILING = (tile, 1024, 1024)
             jax.clear_caches()  # the kernel's own jit is keyed by the tiling FUNCTION
 
             def loss(lhs, rhs):
-                out = lfm2.grouped_matmul(lhs, rhs, sizes)
+                out = token_routed.grouped_matmul(lhs, rhs, sizes)
                 return (jnp.where(jnp.arange(rows)[:, None] < live, out, 0.0) ** 2).sum()
 
             yield {
@@ -141,11 +142,11 @@ def expert_rows(rows, live, groups, d, f):
 def expert_tiles(rows, groups, per_group, d, f, tilings):
     """Forward alone (a served layer asks for no gradient). A tiling is
     (name, row tile, the tile along the ``d``-wide side, the tile along the
-    ``f``-wide side); None takes ``lfm2._gmm_tiles``'s own."""
+    ``f``-wide side); None takes ``token_routed._gmm_tiles``'s own."""
     rng = np.random.default_rng(2)
     live = groups * per_group
     sizes = jnp.full((groups,), per_group, jnp.int32)
-    fitted = lfm2._gmm_tiles
+    fitted = token_routed._gmm_tiles
     try:
         for name, k, n in (("up", d, f), ("down", f, d)):
             lhs = jnp.asarray(rng.normal(size=(rows, k)), jnp.float32)
@@ -154,16 +155,16 @@ def expert_tiles(rows, groups, per_group, d, f, tilings):
                 tiles = (tm, *fitted(rows, k, n)[1:]) if tile_d is None else (
                     (tm, tile_d, tile_f) if k == d else (tm, tile_f, tile_d)
                 )
-                lfm2._gmm_tiles = lambda m, k_, n_, t=tiles: t
+                token_routed._gmm_tiles = lambda m, k_, n_, t=tiles: t
                 jax.clear_caches()  # the kernel's own jit is keyed by the tiling FUNCTION
-                ms = time_ms(jax.jit(lambda a, b: lfm2.grouped_matmul(a, b, sizes)), lhs, rhs)
+                ms = time_ms(jax.jit(lambda a, b: token_routed.grouped_matmul(a, b, sizes)), lhs, rhs)
                 yield {
                     "what": f"expert tiles {name} {k} -> {n}, {what}", "tiles": list(tiles),
                     "rows": rows, "live": live, "fwd_ms": ms,
                     "tflops": 2 * live * k * n / ms / 1e9,
                 }
     finally:
-        lfm2._gmm_tiles = fitted
+        token_routed._gmm_tiles = fitted
 
 
 def steered_layer(n, d, k, held, experts, f, live):
@@ -177,7 +178,7 @@ def steered_layer(n, d, k, held, experts, f, live):
         num_experts_held=held, experts_offset=0, num_attention_heads=4,
         num_key_value_heads=2, head_dim=8, vocab_size=64, token_minmax=(0.0, 63.0),
     )
-    layer = lfm2.RoutedFFN(d, cfg)
+    layer = token_routed.RoutedFFN(d, cfg)
     real = n - 64  # the bucket's padding nodes
     mask = np.arange(n) < real
     rng = np.random.default_rng(2)
@@ -199,7 +200,7 @@ def steered_layer(n, d, k, held, experts, f, live):
 
 def routed_layer(n, d, shapes):
     for cell, k, held, experts, f in shapes:
-        cap = lfm2._capacity(n * k, held, experts)
+        cap = token_routed._capacity(n * k, held, experts)
         share = n * k * held // experts
         for live in (share // 2, share, cap, cap + 1):
             layer, params, x, mask = steered_layer(n, d, k, held, experts, f, live)
@@ -207,9 +208,9 @@ def routed_layer(n, d, shapes):
 
                 def fwd(params, x):
                     y, sown = layer.apply(
-                        params, x, mask, *capacity, mutable=[lfm2.INTERMEDIATES]
+                        params, x, mask, *capacity, mutable=[token_routed.INTERMEDIATES]
                     )
-                    return y, sown[lfm2.INTERMEDIATES]
+                    return y, sown[token_routed.INTERMEDIATES]
 
                 def loss(params, x):
                     return (fwd(params, x)[0] ** 2).sum()

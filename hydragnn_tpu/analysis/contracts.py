@@ -1297,7 +1297,7 @@ def _check_mesh(training, deep, errors):
 # ---------------------------------------------------------- aggregation path
 def _check_aggregation_path(arch, errors):
     """Reject configs whose resolved conv family cannot ride the sorted/CSR
-    edge layout (models/convs.py:SORTED_PATH_FAMILIES). On TPU the sorted
+    edge layout (models/families.py:SORTED_PATH_FAMILIES). On TPU the sorted
     path is the DEFAULT (ops/segment_sorted.sorted_enabled) — a family
     outside the registry would silently fall back to the unsorted XLA
     scatter path, the exact regression class BENCH_r05 measured at 0.47x.
@@ -1309,8 +1309,7 @@ def _check_aggregation_path(arch, errors):
     mt = arch.get("model_type")
     if mt is None:
         return  # missing-field already reported
-    from ..models.base import CONV_TYPES
-    from ..models.convs import SORTED_PATH_FAMILIES
+    from ..models.families import CONV_TYPES, SORTED_PATH_FAMILIES
 
     if mt not in CONV_TYPES:
         return  # bad-arch surfaces at model build; don't double-report
@@ -1322,7 +1321,7 @@ def _check_aggregation_path(arch, errors):
         (
             "bad-arch",
             f"model_type {mt!r} is not registered in SORTED_PATH_FAMILIES "
-            "(models/convs.py): on TPU its aggregation would silently fall "
+            "(models/families.py): on TPU its aggregation would silently fall "
             "back to the unsorted scatter path — register the family's "
             "sorted/CSR aggregation or pin HYDRAGNN_SEGMENT_SORTED=0",
         )
@@ -1332,20 +1331,20 @@ def _check_aggregation_path(arch, errors):
 # ----------------------------------------------------------- position families
 def _check_position_family(arch, errors):
     """The families that compute their edge geometry in the step from
-    ``GraphBatch.positions`` (models/convs.py:POSITION_FAMILIES — PaiNN):
+    ``GraphBatch.positions`` (models/families.py:POSITION_FAMILIES — PaiNN):
     the cutoff and the basis size must be there, and the edge vector must be
     the difference of the two positions, which it is not across a periodic
     cell boundary."""
-    from ..models.convs import POSITION_FAMILIES, TOKEN_STACKS
+    from ..models.families import POSITION_FAMILIES, TOKEN_STACKS
 
     mt = arch.get("model_type")
     if mt not in POSITION_FAMILIES:
         return
     if mt in TOKEN_STACKS:
         # Positions are the nodes' places in their sequences: no cutoff, no
-        # basis, no cell. What the stack needs instead (models/lfm2.py,
-        # models/laguna.py); token_minmax is completion's to add (the
-        # dataset's table).
+        # basis, no cell. What the stack needs instead (its sizes class,
+        # the registry's); token_minmax is completion's to add (the dataset's
+        # table).
         sizes = TOKEN_STACKS[mt][0]
         missing = [k for k in sizes.missing(arch) if k != "token_minmax"]
         if missing:
@@ -1470,7 +1469,7 @@ def _check_shapes(config, arch, voi, training, mode, completed, errors, skipped)
             output_dim=output_dim, head_loss=kinds,
             class_minmax=[[0.0, 1.0] if k == "cross_entropy" else None for k in kinds],
         )
-    from ..models.convs import TOKEN_FAMILIES
+    from ..models.families import TOKEN_FAMILIES
 
     if arch2.get("model_type") in TOKEN_FAMILIES:
         arch2.setdefault("token_minmax", [0.0, 1.0])

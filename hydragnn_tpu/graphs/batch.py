@@ -54,7 +54,7 @@ class GraphBatch:
       positions:      [N_pad, 3] float32 or None — node coordinates (padding
                       rows zero), carried only for the families that compute
                       their edge geometry inside the step
-                      (``models/convs.py:POSITION_FAMILIES``). None is an
+                      (``models/families.py:POSITION_FAMILIES``). None is an
                       empty subtree: every other family's batch, program and
                       host-to-device bytes are what they were without it.
       num_graphs_pad: static python int (G_pad). Needed as a static segment count.

@@ -9,13 +9,11 @@ Architecture (mirrors reference semantics under padding):
              array. PaiNN: num_conv_layers × [message → update] over a scalar
              and a vector state, the edge geometry computed once from
              ``batch.positions``; no norm, no ReLU (models/painn.py). The
-             read-out and the heads read the scalar state. LFM2: a token
-             embedding, num_conv_layers × [operator → feed-forward] with
-             RMSNorm and a residual round each, a final RMSNorm
-             (models/lfm2.py): no edge list is read. LAGUNA: the same
-             loop over Laguna-XS.2's block (models/laguna.py). MISTRAL4:
-             over Mistral-Small-4's (models/mistral4.py). MELLUM: over
-             Mellum2-12B-A2.5B's (models/mellum.py).
+             read-out and the heads read the scalar state. A token stack
+             (models/families.py ``TOKEN_STACKS``): a token embedding,
+             num_conv_layers × the family's block [operator → feed-forward]
+             with RMSNorm and a residual round each, a final RMSNorm: no
+             edge list is read.
   readout:   masked segment-mean over nodes per graph (global_mean_pool analog)
   heads:     graph heads = shared MLP ("graph_shared") + per-head MLP;
              node heads = shared MLPNode ('mlp' / 'mlp_per_node') or a conv chain
@@ -36,17 +34,11 @@ from ..ops import aggregate
 from ..ops import segment as seg
 from ..telemetry import scopes
 from .layers import MLP, MaskedBatchNorm
-from . import laguna as laguna_model, lfm2 as lfm2_model, painn
-from . import mellum as mellum_model, mistral4 as mistral4_model
-from .convs import (
-    POSITION_FAMILIES, TOKEN_STACKS, CGConv, GATv2Conv, GINConv, MFCConv,
-    PNAConv, SAGEConv,
-)
+from . import painn
+from .convs import CGConv, GATv2Conv, GINConv, MFCConv, PNAConv, SAGEConv
+from .families import CONV_TYPES, POSITION_FAMILIES, TOKEN_STACKS
+from .token_common import RMSNorm, token_ids
 
-CONV_TYPES = (
-    "PNA", "MFC", "GIN", "GAT", "CGCNN", "SAGE", "PAINN", "LFM2", "LAGUNA",
-    "MISTRAL4", "MELLUM",
-)
 # Rows of a block of ``HydraGNN.score_tokens``: a class head's logits exist a
 # block at a time ([512, classes]), never as [N, classes].
 LOGPROB_BLOCK = 512
@@ -134,14 +126,10 @@ class HydraGNN(nn.Module):
     # functions (Architecture.num_radial).
     radius: Optional[float] = None
     num_radial: Optional[int] = None
-    # LFM2: the stack's sizes, keyed as the source names them (models/lfm2.py).
-    lfm2: Optional[lfm2_model.LFM2Config] = None
-    # LAGUNA: the same (models/laguna.py).
-    laguna: Optional[laguna_model.LagunaConfig] = None
-    # MISTRAL4: the same (models/mistral4.py).
-    mistral4: Optional[mistral4_model.Mistral4Config] = None
-    # MELLUM: the same (models/mellum.py).
-    mellum: Optional[mellum_model.MellumConfig] = None
+    # A token stack (``TOKEN_STACKS``): its sizes, keyed as the source names
+    # them (the family's own dataclass, built ``from_arch``; what the shared
+    # code reads off it: models/token_common.py). None for the others.
+    token_cfg: Optional[Any] = None
     # Loss kind a head ("rmse" | "cross_entropy"; () = rmse throughout) and,
     # for a cross-entropy head, the dataset's (min, max) of its target column,
     # from which the class ids are un-scaled (models/loss.py).
@@ -157,12 +145,24 @@ class HydraGNN(nn.Module):
             cfg.routed(i) for i in range(self.num_conv_layers)
         )
 
+    # The four names graftbench/families/*.py read the sizes under (the
+    # benchmark's files are not a program PR's to edit). They go when the
+    # families read ``model.token_cfg``: ROADMAP D25.
     @property
-    def token_cfg(self):
-        """The sizes of a token stack (``TOKEN_STACKS``); None for the others."""
-        if self.conv_type in TOKEN_STACKS:
-            return getattr(self, self.conv_type.lower())
-        return None
+    def lfm2(self):
+        return self.token_cfg if self.conv_type == "LFM2" else None
+
+    @property
+    def laguna(self):
+        return self.token_cfg if self.conv_type == "LAGUNA" else None
+
+    @property
+    def mistral4(self):
+        return self.token_cfg if self.conv_type == "MISTRAL4" else None
+
+    @property
+    def mellum(self):
+        return self.token_cfg if self.conv_type == "MELLUM" else None
 
     @property
     def use_edge_attr(self) -> bool:
@@ -273,7 +273,7 @@ class HydraGNN(nn.Module):
             block(self.hidden_dim, cfg, i, name=f"conv_{i}")
             for i in range(self.num_conv_layers)
         ]
-        self.conv_norm = lfm2_model.RMSNorm(cfg.norm_eps)
+        self.conv_norm = RMSNorm(cfg.norm_eps)
 
     @nn.nowrap
     def _setup_painn_encoder(self):
@@ -459,11 +459,10 @@ class HydraGNN(nn.Module):
                 "(config completion and the serving engine do, from the "
                 "model family)"
             )
-        # senders, receivers, row_ptr and the edge mask are not read: both
-        # token mixers work from node_graph and the node order (models/lfm2.py).
-        h = self.conv_embed(
-            lfm2_model.token_ids(batch.node_features[:, 0], self.token_cfg)
-        )
+        # senders, receivers, row_ptr and the edge mask are not read: the
+        # token mixers work from node_graph and the node order
+        # (models/token_attention.py).
+        h = self.conv_embed(token_ids(batch.node_features[:, 0], self.token_cfg))
         place = batch.positions[:, 0]
         for block in self.convs:
             h = block(h, batch.node_graph, place, batch.node_mask)
@@ -501,7 +500,7 @@ class HydraGNN(nn.Module):
         head as ``__call__`` gives it. The ``[N, classes]`` logits are taken
         ``LOGPROB_BLOCK`` rows at a time and never held whole."""
         x = self._encode_tokens(batch)
-        ids = lfm2_model.token_ids(batch.node_features[:, 0], self.token_cfg)
+        ids = token_ids(batch.node_features[:, 0], self.token_cfg)
         follows = jnp.concatenate([
             (batch.node_graph[1:] == batch.node_graph[:-1])
             & batch.node_mask[1:] & batch.node_mask[:-1],

@@ -34,37 +34,6 @@ import flax.linen as nn
 
 from ..ops import aggregate
 from ..telemetry import scopes
-from . import laguna, lfm2, mellum, mistral4
-
-# Conv families whose aggregation rides the sorted/CSR edge layout end to end
-# (every family since PR 7 — GAT's sort-breaking [edges; self-loops] concat
-# was replaced by an explicit self-attention term). check_config consults
-# this registry: a future family missing here would silently fall back to
-# the unsorted scatter path on TPU, which the contract checker now rejects
-# instead (analysis/contracts.py).
-SORTED_PATH_FAMILIES = frozenset(
-    # The token stacks read no edge list (models/lfm2.py, models/laguna.py,
-    # models/mistral4.py, models/mellum.py):
-    # no aggregation to fall back.
-    {"SAGE", "GIN", "MFC", "GAT", "CGCNN", "PNA", "PAINN", "LFM2", "LAGUNA",
-     "MISTRAL4", "MELLUM"}
-)
-# The token stacks: a sequence as a graph, a token a node, the node column a
-# min-max-scaled token id (``Architecture.token_minmax`` from completion).
-# Each is (the dataclass of its sizes, built ``from_arch``; its block); the
-# model holds the sizes in the field of the family's name in lower case.
-TOKEN_STACKS = {
-    "LFM2": (lfm2.LFM2Config, lfm2.LFM2Block),
-    "LAGUNA": (laguna.LagunaConfig, laguna.LagunaBlock),
-    "MISTRAL4": (mistral4.Mistral4Config, mistral4.Mistral4Block),
-    "MELLUM": (mellum.MellumConfig, mellum.MellumBlock),
-}
-TOKEN_FAMILIES = frozenset(TOKEN_STACKS)
-# Families that read ``GraphBatch.positions`` inside the step (PaiNN its edge
-# geometry, a token stack each node's place in its sequence); the loaders carry
-# positions for these alone (utils/config_utils.py, serve/engine.py).
-POSITION_FAMILIES = frozenset({"PAINN"}) | TOKEN_FAMILIES
-
 
 class SAGEConv(nn.Module):
     """GraphSAGE (mean aggregation): W_self·x_i + W_nbr·mean_j x_j.

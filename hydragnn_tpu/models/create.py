@@ -17,6 +17,7 @@ from ..graphs.collate import collate_graphs
 from ..telemetry import graftel as telemetry
 from .base import HydraGNN
 from .convs import pna_degree_averages
+from .families import CONV_TYPES, TOKEN_STACKS
 from .loss import normalize_task_weights
 
 
@@ -41,10 +42,7 @@ def create_model_config(
         pna_deg=config.get("pna_deg"),
         radius=config.get("radius"),
         num_radial=config.get("num_radial"),
-        lfm2=config if config["model_type"] == "LFM2" else None,
-        laguna=config if config["model_type"] == "LAGUNA" else None,
-        mistral4=config if config["model_type"] == "MISTRAL4" else None,
-        mellum=config if config["model_type"] == "MELLUM" else None,
+        token_arch=config if config["model_type"] in TOKEN_STACKS else None,
         head_loss=config.get("head_loss") or (),
         class_minmax=config.get("class_minmax") or (),
         compute_dtype=config.get("compute_dtype"),
@@ -70,32 +68,25 @@ def create_model(
     pna_deg: Optional[Sequence[float]] = None,
     radius: Optional[float] = None,
     num_radial: Optional[int] = None,
-    lfm2: Optional[Dict[str, Any]] = None,
-    laguna: Optional[Dict[str, Any]] = None,
-    mistral4: Optional[Dict[str, Any]] = None,
-    mellum: Optional[Dict[str, Any]] = None,
+    token_arch: Optional[Dict[str, Any]] = None,
     head_loss: Sequence[str] = (),
     class_minmax: Sequence[Any] = (),
     compute_dtype: Optional[str] = None,
     remat: bool = False,
     verbosity: int = 0,
 ) -> HydraGNN:
-    """``lfm2``: for ``model_type`` "LFM2", the ``Architecture`` block's keys
-    that size the stack, named as the source names them (models/lfm2.py
-    ``LFM2Config``); ``laguna``: the same for "LAGUNA" (models/laguna.py
-    ``LagunaConfig``), ``mistral4`` for "MISTRAL4" (models/mistral4.py
-    ``Mistral4Config``) and ``mellum`` for "MELLUM" (models/mellum.py
-    ``MellumConfig``). ``head_loss``: "rmse" or "cross_entropy" a head (empty:
-    rmse throughout); ``class_minmax``: for a cross-entropy head the (min,
-    max) of its target column in the dataset's table, None for the others."""
+    """``token_arch``: for a token family (``model_type`` one of
+    models/families.py ``TOKEN_STACKS``), the ``Architecture`` block's keys
+    that size the stack, named as the family's source names them (the
+    family's sizes class is built ``from_arch`` of it). ``head_loss``: "rmse"
+    or "cross_entropy" a head (empty: rmse throughout); ``class_minmax``: for
+    a cross-entropy head the (min, max) of its target column in the dataset's
+    table, None for the others."""
     if len(task_weights) != len(output_dim):
         raise ValueError(
             f"Inconsistent number of loss weights and tasks: {len(task_weights)} "
             f"VS {len(output_dim)}"
         )
-    from .base import CONV_TYPES
-    from .convs import TOKEN_STACKS
-
     if model_type not in CONV_TYPES:
         raise ValueError("Unknown model_type: {0}".format(model_type))
     kwargs: Dict[str, Any] = {}
@@ -116,23 +107,19 @@ def create_model(
             )
         kwargs.update(radius=float(radius), num_radial=int(num_radial))
     elif model_type in TOKEN_STACKS:
-        field = model_type.lower()
-        sizes = {
-            "lfm2": lfm2, "laguna": laguna, "mistral4": mistral4, "mellum": mellum,
-        }[field]
-        if sizes is None:
+        if token_arch is None:
             raise ValueError(
                 f"{model_type} requires the stack's sizes (create_model("
-                f"{field}=the Architecture block))"
+                "token_arch=the Architecture block))"
             )
         if compute_dtype:
             raise ValueError(
                 f"{model_type} reads token ids from a float32 node column; "
                 "compute_dtype would round it"
             )
-        kwargs[field] = TOKEN_STACKS[model_type][0].from_arch(
-            sizes, int(num_conv_layers)
-        )
+        kwargs.update(token_cfg=TOKEN_STACKS[model_type][0].from_arch(
+            token_arch, int(num_conv_layers)
+        ))
     loss_kinds = tuple(head_loss) or ("rmse",) * len(output_dim)
     unknown = set(loss_kinds) - {"rmse", "cross_entropy"}
     if unknown or len(loss_kinds) != len(output_dim):
@@ -187,7 +174,7 @@ def make_example_batch(
     with_positions: bool = False,
 ) -> GraphBatch:
     """A tiny structurally-valid batch for shape inference / init
-    (``with_positions`` for the families of ``convs.POSITION_FAMILIES``)."""
+    (``with_positions`` for the families of ``families.POSITION_FAMILIES``)."""
     from ..graphs.sample import GraphSample
 
     n = num_nodes

@@ -1,8 +1,8 @@
 """Laguna-XS.2's block (poolside, ``model_type`` ``laguna``;
 https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json) on this
-system's batch, as ``models/lfm2.py`` puts LFM2's there: a token is a node, a
-sequence a graph with its nodes in order, ``positions[:, 0]`` the node's
-place. Equations, assumptions and departures: PAPERS.md.
+system's batch: a token is a node, a sequence a graph with its nodes in
+order, ``positions[:, 0]`` the node's place. Equations, assumptions and
+departures: PAPERS.md.
 
 What this stack adds to the token path, all of it per LAYER:
 
@@ -10,8 +10,8 @@ What this stack adds to the token path, all of it per LAYER:
   softmax aggregation over the complete causal graph of a sequence,
   ``sliding_attention`` the same over the causal BAND (node ``i`` receives
   from the ``j`` of its own graph with ``0 <= i - j < sliding_window``): a
-  third implied graph beside LFM2's 3-wide band and causal triangle, and as
-  they are never held as an edge list (``lfm2.segment_causal_attention``);
+  second implied graph beside the causal triangle, and like it never held as
+  an edge list (``token_attention.segment_causal_attention``);
 * the number of query heads by layer (``num_attention_heads_per_layer``; the
   key-value heads stay), so the projections' widths change with the layer;
 * rotary embedding by kind (``rope_parameters``): full layers rotate the
@@ -19,12 +19,15 @@ What this stack adds to the token path, all of it per LAYER:
   frequencies and its attention factor and leave the rest as it is, sliding
   layers rotate the whole head at the plain frequencies;
 * a sigmoid gate a head on the attention output, from the layer's input;
-* a shared expert beside the routed ones (``lfm2.RoutedFFN``, told which
-  experts it holds): every rank computes the shared expert whole.
+* a shared expert beside the routed ones (``token_routed.RoutedFFN``, told
+  which experts it holds): every rank computes the shared expert whole.
 
-Nothing of LFM2's is copied: norm, rotary, the attention core, the dense and
-the routed feed-forward, the sown intermediates and counters are imported.
-Precision as there: float32 parameters, residual stream, norms, softmax,
+This file holds what is Laguna's alone: its sizes, the gated attention layer
+and the block. Norm and rotary (``Rope``, ``rotary``) come from
+``token_common.py``, the attention core from ``token_attention.py``, the dense
+and the routed feed-forward with the sown intermediates and counters from
+``token_routed.py``; no other family's file is imported here and none imports
+this one. Precision: float32 parameters, residual stream, norms, softmax,
 sigmoids; matmul operands rounded to bf16 on the TPU; the router at
 ``Precision.HIGHEST``.
 """
@@ -32,87 +35,15 @@ sigmoids; matmul operands rounded to bf16 on the TPU; the router at
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 import flax.linen as nn
 
 from ..telemetry import scopes
-from .lfm2 import (
-    DenseFFN, RMSNorm, RoutedFFN, experts_share, missing_fields, rotate,
-    segment_causal_attention,
-)
-
-KINDS = ("full_attention", "sliding_attention")
-
-
-@dataclasses.dataclass(frozen=True)
-class Rope:
-    """One entry of the source's ``rope_parameters``."""
-
-    rope_theta: float
-    rope_type: str = "default"
-    partial_rotary_factor: float = 1.0
-    factor: float = 1.0
-    original_max_position_embeddings: Optional[int] = None
-    beta_fast: float = 32.0
-    beta_slow: float = 1.0
-    attention_factor: Optional[float] = None
-
-    def __post_init__(self):
-        if self.rope_type not in ("default", "yarn"):
-            raise ValueError(f"rope_type {self.rope_type!r}: 'default' or 'yarn'")
-        if self.rope_type == "yarn" and not self.original_max_position_embeddings:
-            raise ValueError("yarn needs original_max_position_embeddings")
-
-    def frequencies(self, head_dim: int):
-        """(``inv`` [rotated / 2] float32, the factor on cos and sin, the
-        number of leading dimensions of a head that are rotated).
-
-        ``default``: ``theta^(-2i/r)``. ``yarn`` (Peng et al. 2023, as
-        ``transformers`` ``_compute_yarn_parameters`` has it, ``truncate``
-        true): per pair ``i`` the blend ``(1 - g_i) theta^(-2i/r) / factor +
-        g_i theta^(-2i/r)`` with ``g_i = 1 - clip((i - low) / (high - low),
-        0, 1)`` between the correction dimensions ``low = floor(c(beta_fast))``
-        and ``high = ceil(c(beta_slow))``, ``c(b) = r ln(L / (2 pi b)) /
-        (2 ln theta)``, ``L`` the original context; cos and sin times
-        ``attention_factor`` (``0.1 ln(factor) + 1`` where the source gives
-        none)."""
-        r = int(head_dim * self.partial_rotary_factor)
-        i = np.arange(r // 2, dtype=np.float64)
-        plain = float(self.rope_theta) ** (-2.0 * i / r)
-        if self.rope_type == "default":
-            return plain.astype(np.float32), 1.0, r
-
-        def correction(rotations):
-            return (
-                r * math.log(self.original_max_position_embeddings
-                             / (rotations * 2 * math.pi))
-                / (2 * math.log(self.rope_theta))
-            )
-
-        low = max(math.floor(correction(self.beta_fast)), 0)
-        high = min(math.ceil(correction(self.beta_slow)), r - 1)
-        ramp = np.clip((i - low) / ((high if high != low else high + 1e-3) - low), 0, 1)
-        keep = 1.0 - ramp  # 1: the frequency as it is; 0: divided by factor
-        inv = plain / self.factor * (1 - keep) + plain * keep
-        scale = self.attention_factor
-        if scale is None:
-            scale = 0.1 * math.log(self.factor) + 1.0 if self.factor > 1 else 1.0
-        return inv.astype(np.float32), float(scale), r
-
-
-def ropes_by_kind(rope_parameters: dict) -> Tuple[Rope, Rope]:
-    """The source's ``rope_parameters``, one section a kind, as records by
-    ``KINDS`` (keys a ``Rope`` does not hold are left out)."""
-    names = {f.name for f in dataclasses.fields(Rope)}
-    return tuple(
-        Rope(**{k: v for k, v in rope_parameters[kind].items() if k in names})
-        for kind in KINDS
-    )
+from .token_attention import segment_causal_attention
+from .token_common import KINDS, RMSNorm, Rope, missing_fields, ropes_by_kind, rotary
+from .token_routed import DenseFFN, RoutedFFN, experts_share
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,9 +73,11 @@ class LagunaConfig:
     rms_norm_eps: float = 1e-6
     moe_routed_scaling_factor: float = 1.0
 
-    # What ``RoutedFFN`` and the encoder read under LFM2's names. The router
-    # the config implies (sigmoid scores, the chosen ones normalised, then
-    # the scaling; no expert bias) is assumed: PAPERS.md.
+    # What ``RoutedFFN`` and the encoder read under the shared names
+    # (token_common.py lists them). The router the config implies (sigmoid
+    # scores, the chosen ones normalised, then the scaling; no expert bias) is
+    # assumed: PAPERS.md.
+    scoring_func = "sigmoid"
     norm_topk_prob = True
     use_expert_bias = False
 
@@ -211,14 +144,6 @@ class LagunaConfig:
         return self.rope_parameters[KINDS.index(self.layer_types[layer])]
 
 
-def rotary(x, place, rope: Rope):
-    """``x`` [N, heads, hd] with the first ``partial_rotary_factor`` of each
-    head rotated at ``place`` and the rest as it is."""
-    inv, factor, r = rope.frequencies(x.shape[-1])
-    turned = rotate(x[..., :r], place, jnp.asarray(inv), factor)
-    return turned if r == x.shape[-1] else jnp.concatenate([turned, x[..., r:]], axis=-1)
-
-
 class GatedAttention(nn.Module):
     """Grouped-query attention over the layer's graph (complete causal, or
     the causal band of ``sliding_window``), its own number of query heads,
@@ -252,7 +177,8 @@ class LagunaBlock(nn.Module):
     """``h += attn(RMSNorm(h))``; ``h += ffn(RMSNorm(h))``: the feed-forward a
     dense SwiGLU on the ``dense`` layers and, on the ``sparse`` ones, the
     shared expert plus this rank's part of the routed sum. The routed layer
-    is ``feed_forward``, as LFM2's: ``split_intermediates`` finds it there."""
+    is ``feed_forward``, as every family's: ``split_intermediates`` finds it
+    there."""
 
     features: int
     cfg: LagunaConfig

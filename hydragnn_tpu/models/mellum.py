@@ -1,23 +1,27 @@
 """Mellum2-12B-A2.5B's block (JetBrains, ``model_type`` ``mellum``;
 https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/blob/main/config.json)
-on this system's batch, as ``models/lfm2.py`` puts LFM2's there: a token is a
-node, a sequence a graph with its nodes in order, ``positions[:, 0]`` the
-node's place. Equations, assumptions and departures: PAPERS.md.
+on this system's batch: a token is a node, a sequence a graph with its nodes
+in order, ``positions[:, 0]`` the node's place. Equations, assumptions and
+departures: PAPERS.md.
 
-The block is plain where the siblings' are not, and every part of it is one
-of theirs: grouped-query attention with ONE head count, no gate, no norm on
-``q`` / ``k``, over the causal BAND on the ``sliding_attention`` layers and
-the complete causal graph on the ``full_attention`` ones (three to one as
-published); rotary over the whole head by the layer's kind (plain on the
+The block is plain where the other families' are not, and every part of it
+is a shared one: grouped-query attention with ONE head count, no gate, no
+norm on ``q`` / ``k``, over the causal BAND on the ``sliding_attention``
+layers and the complete causal graph on the ``full_attention`` ones (three to
+one as published); rotary over the whole head by the layer's kind (plain on the
 band, YaRN on the triangle); then a routed feed-forward on EVERY layer, with
 no shared expert beside it and no leading dense layer, its router a softmax
 over all experts whose chosen scores are normalised again.
 
-Nothing of the siblings' is copied: norm, ``laguna.rotary`` and its ``Rope``
-record, the attention core, the routed feed-forward, the sown intermediates
-and counters are imported. Precision as there: float32 parameters, residual
-stream, norms, softmax; matmul operands rounded to bf16 on the TPU; the
-router's ``W_r x`` at ``Precision.HIGHEST``.
+This file holds what is Mellum2's alone: its sizes, the attention layer and
+the block. Norm, ``rotary`` and its ``Rope`` record come from
+``token_common.py``, the attention core from ``token_attention.py`` (imported
+by NAME and called through this module's own global: the benchmark's control
+replaces it here), the routed feed-forward with the sown intermediates and
+counters from ``token_routed.py``; no other family's file is imported here and
+none imports this one. Precision: float32 parameters, residual stream, norms,
+softmax; matmul operands rounded to bf16 on the TPU; the router's ``W_r x`` at
+``Precision.HIGHEST``.
 """
 
 from __future__ import annotations
@@ -29,10 +33,11 @@ import jax
 import flax.linen as nn
 
 from ..telemetry import scopes
-from .laguna import KINDS, Rope, ropes_by_kind, rotary
-from .lfm2 import (
-    RMSNorm, RoutedFFN, experts_share, missing_fields, segment_causal_attention,
-)
+# graftbench/tests/test_mellum_cell.py replaces this module's
+# ``segment_causal_attention``: keep it imported by name (ROADMAP D25).
+from .token_attention import segment_causal_attention
+from .token_common import KINDS, RMSNorm, Rope, missing_fields, ropes_by_kind, rotary
+from .token_routed import RoutedFFN, experts_share
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,16 +59,16 @@ class MellumConfig:
     num_experts_held: int
     experts_offset: int
     sliding_window: int
-    rope_parameters: Tuple[Rope, Rope]  # by laguna.KINDS
+    rope_parameters: Tuple[Rope, Rope]  # by KINDS
     vocab_size: int
     token_minmax: Tuple[float, float]
     rms_norm_eps: float = 1e-6
     norm_topk_prob: bool = True
 
-    # What ``RoutedFFN`` and the encoder read under LFM2's names. The router
-    # the config implies (softmax over all experts, the chosen ones
-    # normalised, no scaling, no bias: the keys are Qwen3-MoE's, and so is the
-    # convention) is assumed: PAPERS.md.
+    # What ``RoutedFFN`` and the encoder read under the shared names
+    # (token_common.py lists them). The router the config implies (softmax
+    # over all experts, the chosen ones normalised, no scaling, no bias: the
+    # keys are Qwen3-MoE's, and so is the convention) is assumed: PAPERS.md.
     scoring_func = "softmax"
     routed_scaling_factor = 1.0
     use_expert_bias = False
@@ -147,7 +152,7 @@ class Attention(nn.Module):
 
 class MellumBlock(nn.Module):
     """``h += attn(RMSNorm(h))``; ``h += routed(RMSNorm(h))`` on every layer.
-    The routed layer is ``feed_forward``, as the siblings':
+    The routed layer is ``feed_forward``, as every family's:
     ``split_intermediates`` finds it there."""
 
     features: int

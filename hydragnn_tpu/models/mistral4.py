@@ -1,8 +1,8 @@
 """Mistral-Small-4's block (mistralai, ``model_type`` ``mistral4``;
 https://huggingface.co/mistralai/Mistral-Small-4-119B-2603/blob/main/config.json)
-on this system's batch, as ``models/lfm2.py`` puts LFM2's there: a token is a
-node, a sequence a graph with its nodes in order, ``positions[:, 0]`` the
-node's place. Equations, assumptions and departures: PAPERS.md.
+on this system's batch: a token is a node, a sequence a graph with its nodes
+in order, ``positions[:, 0]`` the node's place. Equations, assumptions and
+departures: PAPERS.md.
 
 What this stack adds to the token path:
 
@@ -23,11 +23,14 @@ What this stack adds to the token path:
   ``scoring_func`` by name), beside a shared expert that every rank computes
   whole.
 
-Nothing of the siblings' is copied: norm, the rotation, the attention core,
-the dense and the routed feed-forward, YaRN's frequencies (``laguna.Rope``),
-the sown intermediates and counters are imported. Precision as there: float32
-parameters, residual stream, norms, softmax; matmul operands rounded to bf16
-on the TPU; the router's ``W_r x`` at ``Precision.HIGHEST``.
+This file holds what is Mistral-Small-4's alone: its sizes, the latent
+attention layer and the block. Norm, the rotation and YaRN's frequencies
+(``Rope``) come from ``token_common.py``, the attention core from
+``token_attention.py``, the dense and the routed feed-forward with the sown
+intermediates and counters from ``token_routed.py``; no other family's file is
+imported here and none imports this one. Precision: float32 parameters,
+residual stream, norms, softmax; matmul operands rounded to bf16 on the TPU;
+the router's ``W_r x`` at ``Precision.HIGHEST``.
 """
 
 from __future__ import annotations
@@ -41,11 +44,9 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from ..telemetry import scopes
-from .laguna import Rope
-from .lfm2 import (
-    DenseFFN, RMSNorm, RoutedFFN, experts_share, missing_fields, rotate,
-    segment_causal_attention,
-)
+from .token_attention import segment_causal_attention
+from .token_common import RMSNorm, Rope, missing_fields, rotate
+from .token_routed import DenseFFN, RoutedFFN, experts_share
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -88,9 +89,10 @@ class Mistral4Config:
     routed_scaling_factor: float = 1.0
     rope_interleave: bool = True
 
-    # What ``RoutedFFN`` and the encoder read under LFM2's names. The score
-    # function and the absence of a correction bias are assumed (the config
-    # has no key for either): PAPERS.md.
+    # What ``RoutedFFN`` and the encoder read under the shared names
+    # (token_common.py lists them). The score function and the absence of a
+    # correction bias are assumed (the config has no key for either):
+    # PAPERS.md.
     scoring_func = "softmax"
     use_expert_bias = False
 
@@ -225,7 +227,7 @@ class LatentAttention(nn.Module):
 class Mistral4Block(nn.Module):
     """``h += attn(RMSNorm(h))``; ``h += shared(RMSNorm(h)) + routed(...)``
     on every layer from ``first_k_dense_replace`` (0 as published), a dense
-    SwiGLU before it. The routed layer is ``feed_forward``, as the siblings':
+    SwiGLU before it. The routed layer is ``feed_forward``, as every family's:
     ``split_intermediates`` finds it there."""
 
     features: int

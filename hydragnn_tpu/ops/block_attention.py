@@ -1,8 +1,9 @@
 """Causal attention inside each run of a flat row array that visits, for a
 block of query rows, only the key blocks from the one that holds the first
 row of the block's EARLIEST run up to the diagonal: the forward Pallas kernel
-``models/lfm2.py`` ``segment_causal_attention`` takes on the TPU where no
-gradient is asked for (the engine's ``score_tokens``, an evaluation step).
+``models/token_attention.py`` ``segment_causal_attention`` (every token
+stack's attention entry point) takes on the TPU where no gradient is asked
+for (the engine's ``score_tokens``, an evaluation step).
 
 JAX's own flash kernel skips a key block only above the diagonal; a block
 below it that belongs to another run (another document of the flush) is

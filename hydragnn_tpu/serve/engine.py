@@ -39,15 +39,19 @@ engine outputs are bit-identical to ``run_prediction`` on CPU for the same
 checkpoint and graphs regardless of how requests are grouped into buckets
 (locked by tests/test_serve_engine.py).
 
-A token family (``models/convs.py`` ``TOKEN_STACKS``: a document as a graph,
+A token family (``models/families.py`` ``TOKEN_STACKS``: a document as a graph,
 a token a node, no edges) is served on the same path: a request is the token
 column and each token's place, the ladder's rungs are counted in tokens, a
 class head answers with the log-probability of each next token instead of its
 logits, and the routed layers' choices come out of the same executable
 (docs/SERVING.md "Token families"). Its attention cores are of up to two
 KINDS by layer, the complete causal graph of a document and the causal band
-of ``sliding_window`` (``models/mellum.py``, ``models/laguna.py``), and a
-flush's key blocks are counted a kind (``_count_key_blocks``).
+of ``sliding_window`` (which is which is the stack's sizes' to say:
+``sliding(layer)``), and a flush's key blocks are counted a kind
+(``_count_key_blocks``). Of the token layers the engine imports the shared
+modules alone (``models/token_routed.py``: the sown collection and
+``pass_rows``; ``models/token_attention.py``: the key-block counts), never a
+family's file.
 """
 
 from __future__ import annotations
@@ -146,7 +150,7 @@ def _token_forward(model):
     import jax.numpy as jnp
 
     from ..models.base import HydraGNN
-    from ..models.lfm2 import INTERMEDIATES, split_intermediates
+    from ..models.token_routed import INTERMEDIATES, split_intermediates
 
     routes = model.counts_routing
 
@@ -377,7 +381,7 @@ class InferenceEngine:
             )
         self.precision = precision
         self.tolerance = None if tolerance is None else float(tolerance)
-        # A token family (models/convs.py TOKEN_STACKS): its sizes, else None.
+        # A token family (models/families.py TOKEN_STACKS): its sizes, else None.
         self._token_cfg = model.token_cfg
         # The window of its band layers, None for a stack with none.
         sliding = getattr(self._token_cfg, "sliding", None)
@@ -1235,8 +1239,8 @@ class InferenceEngine:
         as graftel gauges: over the flush's ``real`` tokens and each routed
         layer, the rows sent to held experts, the fullest held expert's rows
         and the layers whose rows passed the compact path's ``C`` and took a
-        further pass (models/lfm2.py ``RoutedFFN``)."""
-        from ..models.lfm2 import _capacity
+        further pass (models/token_routed.py ``RoutedFFN``)."""
+        from ..models.token_routed import pass_rows
 
         cfg, n_pad = self._token_cfg, routing.shape[0]
         k, held = cfg.num_experts_per_tok, cfg.num_experts_held
@@ -1245,7 +1249,7 @@ class InferenceEngine:
             np.bincount(layer[(layer >= 0) & (layer < held)], minlength=held)
             for layer in np.moveaxis(local, 1, 0)
         ])  # [routed layers, held]
-        cap = min(_capacity(n_pad * k, held, cfg.num_experts), n_pad * k)
+        cap = pass_rows(cfg, n_pad)
         self._count_flush({
             "moe_rows_held_total": int(loads.sum()),
             "moe_load_max_total": int(loads.max(axis=1).sum()),
@@ -1257,15 +1261,15 @@ class InferenceEngine:
         gauges, by the KIND of layer. A full layer (the complete causal
         graph): the (query block, key block) pairs ONE call of its core
         visits, a head, beside the pairs of the padded rung's whole causal
-        triangle (models/lfm2.py ``attention_key_blocks``: the function that
-        hands the TPU's kernel its block range, on the flush's own
-        ``node_graph``); elsewhere than on a TPU the core walks the triangle,
-        and the two are equal. A window layer (the causal band, where the
+        triangle (models/token_attention.py ``attention_key_blocks``: the
+        function that hands the TPU's kernel its block range, on the flush's
+        own ``node_graph``); elsewhere than on a TPU the core walks the
+        triangle, and the two are equal. A window layer (the causal band, where the
         stack has one): the pairs ONE call of the band's core visits at the
         flush's rung (``band_key_blocks``: the blocks the band's static mask
         holds, whatever the documents); 0 for a stack whose every layer is
         full."""
-        from ..models.lfm2 import attention_key_blocks, band_key_blocks
+        from ..models.token_attention import attention_key_blocks, band_key_blocks
 
         visited, causal = attention_key_blocks(
             node_graph, ranged=self.device["platform"] == "tpu"

@@ -24,7 +24,7 @@ import numpy as np
 from ..analysis.sentinel import compile_count
 from ..graphs.batch import GraphBatch
 from ..models.base import HydraGNN
-from ..models.lfm2 import COUNTERS
+from ..models.token_routed import COUNTERS
 from ..utils.optimizer import ReduceLROnPlateau, get_learning_rate, set_learning_rate
 from ..telemetry import graftel as telemetry
 from ..utils.print_utils import iterate_tqdm, print_distributed
@@ -226,7 +226,7 @@ class EpochMetrics:
         self.loss = 0.0
         self.rmses = None
         self.count = 0.0
-        # What the routed layers of a step counted (models/lfm2.py
+        # What the routed layers of a step counted (models/token_routed.py
         # ``COUNTERS``), summed over the epoch; empty for every other model.
         self.counters = {}
 

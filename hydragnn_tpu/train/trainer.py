@@ -29,8 +29,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..graphs.batch import GraphBatch
 from ..models.base import HydraGNN
-from ..models.lfm2 import INTERMEDIATES, split_intermediates
 from ..models.loss import multihead_rmse_loss
+from ..models.token_routed import INTERMEDIATES, split_intermediates
 from ..ops.segment import platform_override
 from ..telemetry import graftel as telemetry
 from ..telemetry import scopes
@@ -113,7 +113,7 @@ def _loss_and_metrics(
 ):
     """``counters``: also ask the model for what its routed layers count a
     step (``HydraGNN.counts_routing``); the aux then has a third entry, the
-    dict of them (models/lfm2.py ``COUNTERS``)."""
+    dict of them (models/token_routed.py ``COUNTERS``)."""
     outputs, mut = _apply_model(
         model,
         params,

@@ -139,13 +139,13 @@ def _stage_classification_heads(config, ctx):
     is ONE column holding the class id: the loaders keep the target's
     dimension (``target_dim``), the model takes ``output_dim``, and the id is
     un-scaled in the loss from the dataset's own table (``class_minmax``).
-    A token stack (``convs.TOKEN_FAMILIES``) reads its input column the same way
+    A token stack (``families.TOKEN_FAMILIES``) reads its input column the same way
     (``token_minmax``). Nothing is written for a config without either."""
     arch = _at(config, ("NeuralNetwork", "Architecture"))
     voi = _at(config, ("NeuralNetwork", "Variables_of_interest"))
     kinds = list(voi.get("loss") or [])
     classify = "cross_entropy" in kinds
-    from ..models.convs import TOKEN_FAMILIES
+    from ..models.families import TOKEN_FAMILIES
 
     tokens = arch["model_type"] in TOKEN_FAMILIES
     if not classify and not tokens:
@@ -225,7 +225,7 @@ def _stage_defaults(config, ctx):
 def _stage_push_head_spec(config, ctx):
     """Loaders need the inferred head spec to emit per-head dense targets,
     and the model family to know what else a batch carries."""
-    from ..models.convs import POSITION_FAMILIES
+    from ..models.families import POSITION_FAMILIES
 
     arch = _at(config, ("NeuralNetwork", "Architecture"))
     for loader in ctx.loaders:

@@ -11,11 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hydragnn_tpu.models import lfm2
+from hydragnn_tpu.models import token_attention
 from hydragnn_tpu.ops import block_attention
 from hydragnn_tpu.ops.segment import platform_override
 
-BLOCK = lfm2.ATTN_BLOCK
+BLOCK = token_attention.ATTN_BLOCK
 
 
 def _ids(rows, documents):
@@ -59,18 +59,18 @@ def pytest_block_range_kernel_is_the_blockwise_path_and_the_dense_softmax(case, 
     )
     scale = hd ** -0.5
     args = (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(ids))
-    blockwise = np.asarray(lfm2.segment_causal_attention(*args))
+    blockwise = np.asarray(token_attention.segment_causal_attention(*args))
     monkeypatch.setattr(
-        lfm2, "block_range_attention",
+        token_attention, "block_range_attention",
         functools.partial(block_attention.block_range_attention, interpret=True),
     )
     with platform_override("tpu"):
-        got = np.asarray(lfm2.segment_causal_attention(*args))
+        got = np.asarray(token_attention.segment_causal_attention(*args))
     assert got.shape == (rows, heads * hd)
     assert np.abs(got - blockwise).max() < 5e-6
     assert np.abs(got - _dense(q, k, v, ids, scale)).max() < 5e-6
     # The range is not the triangle but where one run fills the rows.
-    visited, causal = lfm2.attention_key_blocks(ids)
+    visited, causal = token_attention.attention_key_blocks(ids)
     assert (visited == causal) == (len(documents) == 1 and rows % BLOCK == 0)
 
 
@@ -110,6 +110,6 @@ def pytest_rows_past_the_end_are_one_more_run():
         block_attention.whole_blocks(padded, BLOCK), padded
     )
     # Block 1 opens inside the padding graph's run, which began in block 0.
-    assert lfm2.attention_key_blocks(ids) == (3, 3)
-    assert lfm2.attention_key_blocks(_ids(2 * BLOCK, (BLOCK,))) == (2, 3)
-    assert lfm2.attention_key_blocks(_ids(2 * BLOCK, (BLOCK,)), ranged=False) == (3, 3)
+    assert token_attention.attention_key_blocks(ids) == (3, 3)
+    assert token_attention.attention_key_blocks(_ids(2 * BLOCK, (BLOCK,))) == (2, 3)
+    assert token_attention.attention_key_blocks(_ids(2 * BLOCK, (BLOCK,)), ranged=False) == (3, 3)

@@ -533,7 +533,7 @@ def pytest_check_config_rejects_unregistered_sorted_family(monkeypatch):
         ConfigContractError,
         check_config,
     )
-    from hydragnn_tpu.models import convs
+    from hydragnn_tpu.models import families
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(repo, "tests/inputs/ci.json")) as fh:
@@ -542,7 +542,7 @@ def pytest_check_config_rejects_unregistered_sorted_family(monkeypatch):
     check_config(config, deep=False)  # registered family: fine
 
     monkeypatch.setattr(
-        convs, "SORTED_PATH_FAMILIES", frozenset({"GIN"}), raising=True
+        families, "SORTED_PATH_FAMILIES", frozenset({"GIN"}), raising=True
     )
     monkeypatch.delenv("HYDRAGNN_SEGMENT_SORTED", raising=False)
     with pytest.raises(ConfigContractError, match="SORTED_PATH_FAMILIES"):

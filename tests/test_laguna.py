@@ -27,7 +27,9 @@ from graftbench.drivers.train_epochs import shaken  # noqa: E402
 from graftbench.families import laguna as plain  # noqa: E402
 from hydragnn_tpu.graphs import collate_graphs  # noqa: E402
 from hydragnn_tpu.models import create_model, init_model_variables  # noqa: E402
-from hydragnn_tpu.models import laguna, lfm2  # noqa: E402
+from hydragnn_tpu.models import (  # noqa: E402
+    laguna, token_attention, token_common, token_routed,
+)
 from hydragnn_tpu.models.loss import multihead_rmse_loss  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
@@ -55,7 +57,7 @@ ROUTED = tuple(f"conv_{i}" for i in range(1, LAYERS))
 def _model(remat=False, **arch):
     return create_model(
         "LAGUNA", 1, D, (V,), ("node",), HEADS, [1.0], LAYERS,
-        laguna=dict(ARCH, **arch), head_loss=("cross_entropy",),
+        token_arch=dict(ARCH, **arch), head_loss=("cross_entropy",),
         class_minmax=([0.0, V - 1.0],), remat=remat,
     )
 
@@ -195,8 +197,8 @@ def pytest_eight_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
     )
     x = jnp.asarray(rng.normal(size=(n, D)).astype(np.float32))
     mask = jnp.ones((n,), bool)
-    full = lfm2.RoutedFFN(D, whole).init(jax.random.PRNGKey(0), x, mask)["params"]
-    shared = lfm2.DenseFFN(D, whole.shared_expert_intermediate_size)
+    full = token_routed.RoutedFFN(D, whole).init(jax.random.PRNGKey(0), x, mask)["params"]
+    shared = token_routed.DenseFFN(D, whole.shared_expert_intermediate_size)
     shared_p = shared.init(jax.random.PRNGKey(1), x)["params"]
     report = dict(route_margin=0.0, router_margin=0.0, rows_held=0)
     want = plain._dense(shared_p, x, plain.Exact) + plain._routed(
@@ -210,10 +212,10 @@ def pytest_eight_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
         )
         held = slice(2 * rank, 2 * rank + 2)
         part = dict(full, w1=full["w1"][held], w3=full["w3"][held], w2=full["w2"][held])
-        out, sown = lfm2.RoutedFFN(D, share).apply(
-            {"params": part}, x, mask, mutable=[lfm2.INTERMEDIATES]
+        out, sown = token_routed.RoutedFFN(D, share).apply(
+            {"params": part}, x, mask, mutable=[token_routed.INTERMEDIATES]
         )
-        seen += int(sown[lfm2.INTERMEDIATES]["moe_rows_held"][-1])
+        seen += int(sown[token_routed.INTERMEDIATES]["moe_rows_held"][-1])
         total = total + out
     assert seen == n * k  # every assignment is computed on exactly one rank
     assert np.abs(np.asarray(total - want)).max() < 1e-5 * np.abs(np.asarray(want)).max()
@@ -254,8 +256,8 @@ def pytest_the_band_at_the_published_window():
             jnp.asarray(rng.normal(size=(n, heads, hd)).astype(np.float32))
             for heads in (h, kv, kv)
         )
-        band = np.asarray(lfm2.segment_causal_attention(q, k, v, jnp.asarray(seg), window=w))
-        full = np.asarray(lfm2.segment_causal_attention(q, k, v, jnp.asarray(seg)))
+        band = np.asarray(token_attention.segment_causal_attention(q, k, v, jnp.asarray(seg), window=w))
+        full = np.asarray(token_attention.segment_causal_attention(q, k, v, jnp.asarray(seg)))
         assert np.abs(band - _band_by_hand(q, k, v, seg, w)).max() < 2e-5
         assert np.abs(full - _band_by_hand(q, k, v, seg, None)).max() < 2e-5
         if max(lengths) <= w:
@@ -267,7 +269,7 @@ def pytest_the_band_at_the_published_window():
         at = first + 100  # a key of the second sequence
         k2 = k.at[at].add(1.0)
         v2 = v.at[at].add(1.0)
-        moved = np.asarray(lfm2.segment_causal_attention(q, k2, v2, jnp.asarray(seg), window=w))
+        moved = np.asarray(token_attention.segment_causal_attention(q, k2, v2, jnp.asarray(seg), window=w))
         assert np.array_equal(moved[:at], band[:at])  # earlier rows, the other sequence
         assert np.abs(moved[at : at + w] - band[at : at + w]).min(axis=0).max() > 0
         assert np.abs(moved[at + w - 1] - band[at + w - 1]).max() > 1e-6  # 511 back: seen
@@ -301,7 +303,7 @@ def pytest_heads_by_layer_and_the_published_parameter_count():
     configuration file's 691.6M."""
     arch = dict(PUBLISHED["Architecture"], token_minmax=[0.0, 12543.0])
     model = create_model(
-        "LAGUNA", 1, 2048, (12544,), ("node",), HEADS, [1.0], 5, laguna=arch,
+        "LAGUNA", 1, 2048, (12544,), ("node",), HEADS, [1.0], 5, token_arch=arch,
         head_loss=("cross_entropy",), class_minmax=([0.0, 12543.0],), remat=True,
     )
     batch = _collate(_sequences((6,)))
@@ -351,13 +353,13 @@ def pytest_yarn_frequencies_and_the_unrotated_half_by_hand():
     # 0 is the input times the attention factor; at place 3 pair 0 turns by
     # 3 rad.
     x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 3, 128)).astype(np.float32))
-    out = np.asarray(laguna.rotary(x, jnp.asarray([0.0, 3.0]), full))
+    out = np.asarray(token_common.rotary(x, jnp.asarray([0.0, 3.0]), full))
     assert np.array_equal(out[..., 64:], np.asarray(x)[..., 64:])
     assert np.allclose(out[0, :, :64], np.asarray(x)[0, :, :64] * factor, rtol=1e-6)
     a, b = np.asarray(x)[1, 0, 0], np.asarray(x)[1, 0, 32]
     assert abs(out[1, 0, 0] - factor * (a * np.cos(3.0) - b * np.sin(3.0))) < 1e-5
     assert abs(out[1, 0, 32] - factor * (b * np.cos(3.0) + a * np.sin(3.0))) < 1e-5
-    whole = np.asarray(laguna.rotary(x, jnp.asarray([0.0, 3.0]), sliding))
+    whole = np.asarray(token_common.rotary(x, jnp.asarray([0.0, 3.0]), sliding))
     assert np.abs(whole[1, :, 64:] - np.asarray(x)[1, :, 64:]).max() > 1e-3
 
 
@@ -417,7 +419,7 @@ def pytest_compact_row_arrays_inside_the_model(where, monkeypatch):
     model = _model(remat=True)
     batch = _collate(_sequences((60, 70, 40)))
     rows = batch.node_features.shape[0] * ARCH["num_experts_per_tok"]
-    assert lfm2._capacity(rows, 4, 16) == 256 < 320 < rows
+    assert token_routed._capacity(rows, 4, 16) == 256 < 320 < rows
     variables = shaken(init_model_variables(model, batch), 35)
     opt = select_optimizer("AdamW", 1e-3)
 
@@ -437,9 +439,9 @@ def pytest_compact_row_arrays_inside_the_model(where, monkeypatch):
         return (metrics["loss"], state.params), metrics, 2
 
     got, counted, steps = run()
-    monkeypatch.setattr(lfm2, "_capacity", lambda *_: 320)
+    monkeypatch.setattr(token_routed, "_capacity", lambda *_: 320)
     wider, counted_wider, _ = run()
-    monkeypatch.setattr(lfm2, "_capacity", lambda *_: rows)
+    monkeypatch.setattr(token_routed, "_capacity", lambda *_: rows)
     every_row, counted_every_row, _ = run()
     passes = len(ROUTED) * steps
     assert float(counted["moe_layers_compact"]) == passes
@@ -479,7 +481,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     for scope in (scopes.ATTN_FULL, scopes.ATTN_WINDOW, scopes.MOE_EXPERTS):
         assert any(scope in n for n in backward), scope
     _, metrics = step(state, batch, jax.random.PRNGKey(0))
-    assert set(metrics) == {"loss", "rmses", "count", *lfm2.COUNTERS}
+    assert set(metrics) == {"loss", "rmses", "count", *token_routed.COUNTERS}
     assert 0 <= float(metrics["moe_load_min"]) <= float(metrics["moe_load_max"])
     assert 0 < float(metrics["moe_rows_held"]) <= 4 * 26 * 2
 
@@ -511,9 +513,9 @@ def pytest_entry_points_refuse_what_the_family_cannot_run():
         "LAGUNA", 1, D, (V,), ("node",), HEADS, [1.0], LAYERS, **kw
     )
     with pytest.raises(ValueError, match="compute_dtype"):
-        make(laguna=ARCH, compute_dtype="bfloat16")
+        make(token_arch=ARCH, compute_dtype="bfloat16")
     with pytest.raises(ValueError, match="token_minmax"):
-        make(laguna={k: v for k, v in ARCH.items() if k != "token_minmax"})
+        make(token_arch={k: v for k, v in ARCH.items() if k != "token_minmax"})
     with pytest.raises(ValueError, match="stack's sizes"):
         make()
     with pytest.raises(ValueError, match="not among"):
@@ -528,7 +530,7 @@ def pytest_entry_points_refuse_what_the_family_cannot_run():
             "sliding_attention": {"rope_theta": 1e4},
         })
     with pytest.raises(ValueError, match="class_minmax"):
-        make(laguna=ARCH, head_loss=("cross_entropy",))
+        make(token_arch=ARCH, head_loss=("cross_entropy",))
     model = _model()
     with pytest.raises(ValueError, match="positions"):
         batch = collate_graphs(_sequences((5,)), ("node",), (1,))

@@ -27,7 +27,7 @@ from graftbench.drivers.train_epochs import shaken  # noqa: E402
 from graftbench.families import lfm2 as plain  # noqa: E402
 from hydragnn_tpu.graphs import GraphSample, collate_graphs  # noqa: E402
 from hydragnn_tpu.models import create_model, init_model_variables  # noqa: E402
-from hydragnn_tpu.models import lfm2  # noqa: E402
+from hydragnn_tpu.models import lfm2, token_common, token_routed  # noqa: E402
 from hydragnn_tpu.models.loss import class_ids, multihead_rmse_loss  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 
@@ -64,7 +64,7 @@ def _sequences(sizes, seed=0):
 
 def _model(**arch):
     return create_model(
-        "LFM2", 1, D, (V,), ("node",), HEADS, [1.0], 4, lfm2=dict(ARCH, **arch),
+        "LFM2", 1, D, (V,), ("node",), HEADS, [1.0], 4, token_arch=dict(ARCH, **arch),
         head_loss=("cross_entropy",), class_minmax=([0.0, V - 1.0],),
     )
 
@@ -76,9 +76,9 @@ def _collate(graphs, **pads):
 def _forward(model, variables, batch):
     out, sown = model.apply(
         {"params": variables["params"]}, batch, train=False,
-        mutable=[lfm2.INTERMEDIATES],
+        mutable=[token_routed.INTERMEDIATES],
     )
-    routing, counters = lfm2.split_intermediates(sown[lfm2.INTERMEDIATES])
+    routing, counters = token_routed.split_intermediates(sown[token_routed.INTERMEDIATES])
     return np.asarray(out[0]), jax.tree_util.tree_map(np.asarray, routing), counters
 
 
@@ -193,7 +193,7 @@ def pytest_four_shares_add_up_to_the_uncut_layer():
     )
     x = jnp.asarray(rng.normal(size=(n, D)).astype(np.float32))
     mask = jnp.ones((n,), bool)
-    full = lfm2.RoutedFFN(D, whole).init(jax.random.PRNGKey(0), x, mask)["params"]
+    full = token_routed.RoutedFFN(D, whole).init(jax.random.PRNGKey(0), x, mask)["params"]
     full = dict(full, expert_bias=jnp.asarray(rng.normal(0, 0.05, experts), jnp.float32))
     report = dict(route_margin=0.0, router_margin=0.0, rows_held=0)
     want = plain._routed(full, x, whole, plain.Exact, None, report)
@@ -205,10 +205,10 @@ def pytest_four_shares_add_up_to_the_uncut_layer():
         )
         held = slice(2 * rank, 2 * rank + 2)
         part = dict(full, w1=full["w1"][held], w3=full["w3"][held], w2=full["w2"][held])
-        out, sown = lfm2.RoutedFFN(D, share).apply(
-            {"params": part}, x, mask, mutable=[lfm2.INTERMEDIATES]
+        out, sown = token_routed.RoutedFFN(D, share).apply(
+            {"params": part}, x, mask, mutable=[token_routed.INTERMEDIATES]
         )
-        seen += int(sown[lfm2.INTERMEDIATES]["moe_rows_held"][-1])
+        seen += int(sown[token_routed.INTERMEDIATES]["moe_rows_held"][-1])
         total = total + out
     assert seen == n * k  # every assignment is computed on exactly one rank
     assert np.abs(np.asarray(total - want)).max() < 1e-5 * np.abs(np.asarray(want)).max()
@@ -275,7 +275,7 @@ def pytest_token_ids_exact_over_the_whole_slice(lo, hi):
         edge_index=np.zeros((2, 0), np.int32),
     )
     batch = collate_graphs([sample], ("node",), (1,), with_positions=True)
-    assert np.array_equal(np.asarray(lfm2.token_ids(batch.node_features[:n, 0], cfg)), ids)
+    assert np.array_equal(np.asarray(token_common.token_ids(batch.node_features[:n, 0], cfg)), ids)
     assert np.array_equal(
         np.asarray(class_ids(batch.targets[0][:n], (float(lo), float(hi)), 16384)), ids
     )
@@ -316,7 +316,7 @@ def steered_layer(k, held, experts, offset, to_held, one_expert=None, d=None, f=
         num_experts=experts, num_experts_per_tok=k, num_experts_held=held,
         experts_offset=offset,
     ), 1)
-    layer = lfm2.RoutedFFN(d, cfg)
+    layer = token_routed.RoutedFFN(d, cfg)
     rng = np.random.default_rng(5)
     n = len(to_held)
     x = 0.1 * rng.normal(size=(n, d)).astype(np.float32)
@@ -338,13 +338,13 @@ def at_capacity(layer, params, x, mask, capacity):
     """(output, gradients by parameter and by the input, counters) of the
     layer with row arrays of ``capacity`` rows."""
     def loss(params, x):
-        y, sown = layer.apply(params, x, mask, capacity, mutable=[lfm2.INTERMEDIATES])
-        return (y * jnp.cos(y)).sum(), (y, sown[lfm2.INTERMEDIATES])
+        y, sown = layer.apply(params, x, mask, capacity, mutable=[token_routed.INTERMEDIATES])
+        return (y * jnp.cos(y)).sum(), (y, sown[token_routed.INTERMEDIATES])
 
     (_, (y, sown)), grads = jax.jit(
         jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
     )(params, x)
-    return y, grads, {name: float(sown[name][0]) for name in lfm2.COUNTERS}
+    return y, grads, {name: float(sown[name][0]) for name in token_routed.COUNTERS}
 
 
 def assert_equal_to_rounding(got, want, rel=4e-7):
@@ -425,8 +425,8 @@ def pytest_compact_row_arrays_give_what_every_row_gives(routing, case):
     assert float(jnp.abs(y).max()) > 0 or live == 0
     assert not np.asarray(y[real:]).any()  # padding nodes receive nothing
     # The layer's own capacity: the rank's share times the factor, in tiles.
-    assert lfm2._capacity(n * k, held, experts) == 256
-    assert lfm2._capacity(33280, 32, 256) == lfm2._capacity(16640, 8, 32) == 6400
+    assert token_routed._capacity(n * k, held, experts) == 256
+    assert token_routed._capacity(33280, 32, 256) == token_routed._capacity(16640, 8, 32) == 6400
 
 
 def pytest_train_step_scopes_counters_and_other_families_untouched():
@@ -453,7 +453,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     assert any(scopes.MOE_EXPERTS in n for n in backward)
     assert any(scopes.LFM2_ATTN in n for n in backward)
     new_state, metrics = step(state, batch, jax.random.PRNGKey(0))
-    assert set(metrics) == {"loss", "rmses", "count", *lfm2.COUNTERS}
+    assert set(metrics) == {"loss", "rmses", "count", *token_routed.COUNTERS}
     assert 0 < float(metrics["moe_load_min"]) <= float(metrics["moe_load_max"])
     assert float(metrics["moe_rows_held"]) <= 3 * 26 * 2
     # The same path for a family that routes nothing: no counters.
@@ -472,15 +472,15 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
 
 def pytest_entry_points_refuse_what_the_family_cannot_run():
     with pytest.raises(ValueError, match="compute_dtype"):
-        create_model("LFM2", 1, D, (V,), ("node",), HEADS, [1.0], 4, lfm2=ARCH,
+        create_model("LFM2", 1, D, (V,), ("node",), HEADS, [1.0], 4, token_arch=ARCH,
                      compute_dtype="bfloat16")
     with pytest.raises(ValueError, match="token_minmax"):
         create_model("LFM2", 1, D, (V,), ("node",), HEADS, [1.0], 4,
-                     lfm2={k: v for k, v in ARCH.items() if k != "token_minmax"})
+                     token_arch={k: v for k, v in ARCH.items() if k != "token_minmax"})
     with pytest.raises(ValueError, match="not among"):
         _model(num_experts_held=4, experts_offset=6)
     with pytest.raises(ValueError, match="class_minmax"):
-        create_model("LFM2", 1, D, (V,), ("node",), HEADS, [1.0], 4, lfm2=ARCH,
+        create_model("LFM2", 1, D, (V,), ("node",), HEADS, [1.0], 4, token_arch=ARCH,
                      head_loss=("cross_entropy",))
     model = _model()
     with pytest.raises(ValueError, match="positions"):

@@ -33,7 +33,9 @@ from graftbench.drivers.train_epochs import shaken  # noqa: E402
 from graftbench.families import mellum as plain  # noqa: E402
 from hydragnn_tpu.graphs import GraphSample, collate_graphs  # noqa: E402
 from hydragnn_tpu.models import create_model, init_model_variables  # noqa: E402
-from hydragnn_tpu.models import laguna, lfm2, mellum  # noqa: E402
+from hydragnn_tpu.models import (  # noqa: E402
+    mellum, token_attention, token_common, token_routed,
+)
 from hydragnn_tpu.models.base import HydraGNN  # noqa: E402
 from hydragnn_tpu.models.layers import scaled_ids  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
@@ -61,7 +63,7 @@ LENGTHS = (5, 13, 30)  # under the window, over it, and over it after a boundary
 def _model(layers=LAYERS, **arch):
     return create_model(
         "MELLUM", 1, D, (V,), ("node",), HEADS, [1.0], layers,
-        mellum=dict(ARCH, **arch), head_loss=("cross_entropy",),
+        token_arch=dict(ARCH, **arch), head_loss=("cross_entropy",),
         class_minmax=([0.0, V - 1.0],),
     )
 
@@ -69,9 +71,9 @@ def _model(layers=LAYERS, **arch):
 def _forward(model, variables, batch):
     """(logits, the routing as the engine returns it [N, layers x K], counters)."""
     out, sown = model.apply(
-        {"params": variables["params"]}, batch, train=False, mutable=[lfm2.INTERMEDIATES],
+        {"params": variables["params"]}, batch, train=False, mutable=[token_routed.INTERMEDIATES],
     )
-    routing, counters = lfm2.split_intermediates(sown[lfm2.INTERMEDIATES])
+    routing, counters = token_routed.split_intermediates(sown[token_routed.INTERMEDIATES])
     chosen = np.concatenate(
         [np.asarray(routing[f"conv_{i}"]["chosen"]) for i in range(model.num_conv_layers)], axis=1
     )
@@ -172,16 +174,16 @@ def pytest_the_all_held_layer_is_the_dense_sum_over_experts(capacity):
     assert (cfg.num_experts_held, cfg.experts_offset, cfg.routed_scaling_factor) == (8, 0, 1.0)
     x = jnp.asarray(rng.normal(size=(n, D)).astype(np.float32))
     mask = jnp.ones((n,), bool)
-    layer = lfm2.RoutedFFN(D, cfg)
+    layer = token_routed.RoutedFFN(D, cfg)
     params = layer.init(jax.random.PRNGKey(0), x, mask)["params"]
     assert "expert_bias" not in params and params["w1"].shape == (8, D, 24)
     apply = jax.jit(
-        lambda p: layer.apply({"params": p}, x, mask, capacity, mutable=[lfm2.INTERMEDIATES])
+        lambda p: layer.apply({"params": p}, x, mask, capacity, mutable=[token_routed.INTERMEDIATES])
     )
     out, sown = apply(params)
     loop = "while" in apply.lower(params).as_text()
     assert loop == (capacity is not None)
-    sown = sown[lfm2.INTERMEDIATES]
+    sown = sown[token_routed.INTERMEDIATES]
     chosen = np.asarray(sown["moe_chosen"][-1])
     assert float(sown["moe_rows_held"][-1]) == n * K
     p = np.asarray(jax.nn.softmax(np.asarray(x, np.float64) @ np.asarray(params["gate"], np.float64)))
@@ -230,7 +232,7 @@ def pytest_yarn_at_factor_16_and_the_plain_band_by_hand():
     # The rotation itself at a place past the trained context, one pair.
     x = np.zeros((1, 1, 128), np.float32)
     x[0, 0, 40], x[0, 0, 104] = 1.0, 2.0  # pair 40 = (40, 40 + 64)
-    turned = np.asarray(laguna.rotary(jnp.asarray(x), jnp.asarray([9000.0]), cfg.rope(3)))[0, 0]
+    turned = np.asarray(token_common.rotary(jnp.asarray(x), jnp.asarray([9000.0]), cfg.rope(3)))[0, 0]
     angle = 9000.0 * float(inv[40])
     assert turned[40] == pytest.approx(factor * (math.cos(angle) - 2 * math.sin(angle)), abs=2e-4)
     assert turned[104] == pytest.approx(factor * (2 * math.cos(angle) + math.sin(angle)), abs=2e-4)
@@ -256,19 +258,19 @@ def pytest_grouped_matmul_tiles_fit_2304_and_896():
     over the whole tiles before it (2304 = 2 x 1152); a matrix narrower than
     the tile takes its width (896); the siblings' tiles stay what they were
     (LFM2's 1792 keeps 1024: its last tile is three quarters full)."""
-    assert lfm2._gmm_tiles(126976, 2304, 896) == (256, 1152, 896)  # w1, w3
-    assert lfm2._gmm_tiles(126976, 896, 2304) == (256, 896, 1152)  # w2
-    assert lfm2._gmm_tiles(6400, 2048, 1792) == lfm2.GMM_TILING == (256, 1024, 1024)
-    assert lfm2._gmm_tiles(6400, 1792, 2048) == lfm2.GMM_TILING
-    assert lfm2._gmm_tiles(6400, 2048, 512) == (256, 1024, 512)
-    assert lfm2._gmm_tiles(6400, 512, 2048) == (256, 512, 1024)
-    assert lfm2._gmm_tiles(18944, 4096, 2048) == lfm2._gmm_tiles(18944, 2048, 4096) == lfm2.GMM_TILING
+    assert token_routed._gmm_tiles(126976, 2304, 896) == (256, 1152, 896)  # w1, w3
+    assert token_routed._gmm_tiles(126976, 896, 2304) == (256, 896, 1152)  # w2
+    assert token_routed._gmm_tiles(6400, 2048, 1792) == token_routed.GMM_TILING == (256, 1024, 1024)
+    assert token_routed._gmm_tiles(6400, 1792, 2048) == token_routed.GMM_TILING
+    assert token_routed._gmm_tiles(6400, 2048, 512) == (256, 1024, 512)
+    assert token_routed._gmm_tiles(6400, 512, 2048) == (256, 512, 1024)
+    assert token_routed._gmm_tiles(18944, 4096, 2048) == token_routed._gmm_tiles(18944, 2048, 4096) == token_routed.GMM_TILING
     for width in (2304, 896, 1792, 512, 2048, 4096, 24, 100, 1100, 2560, 3328):
-        tile = lfm2._gmm_tile(1024, width)
+        tile = token_routed._gmm_tile(1024, width)
         assert tile <= min(width, 1280) and (tile % 128 == 0 or tile == width)
         assert tile >= min(width, 1024)
     # Every rung of the cell's ladder is whole row tiles of K N = 8 N rows.
-    assert all(8 * n % lfm2.GMM_TILING[0] == 0 for n in (12288, 15872, 19968, 25088))
+    assert all(8 * n % token_routed.GMM_TILING[0] == 0 for n in (12288, 15872, 19968, 25088))
 
 
 @pytest.mark.parametrize("rows,window,block", [
@@ -280,19 +282,19 @@ def pytest_band_key_blocks_are_the_splash_masks_blocks(rows, window, block, monk
     published window a query block of 512 reaches into 3 key blocks."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
 
-    monkeypatch.setattr(lfm2, "ATTN_BLOCK", block)
+    monkeypatch.setattr(token_attention, "ATTN_BLOCK", block)
     padded = -(-rows // block) * block
     if padded <= 512:
-        band = masks.LocalMask((padded, padded), lfm2._band_reach(window), 0)
+        band = masks.LocalMask((padded, padded), token_attention._band_reach(window), 0)
         blocks = padded // block
         occupied = sum(
             bool(band[i * block : (i + 1) * block, j * block : (j + 1) * block].any())
             for i in range(blocks) for j in range(blocks)
         )
-        assert lfm2.band_key_blocks(rows, window) == occupied
+        assert token_attention.band_key_blocks(rows, window) == occupied
     else:
-        assert lfm2.band_key_blocks(rows, window) == 1 + 2 + 3 * (padded // block - 2) == 144
-    assert lfm2._band_reach(window) == (window - 1, 0)
+        assert token_attention.band_key_blocks(rows, window) == 1 + 2 + 3 * (padded // block - 2) == 144
+    assert token_attention._band_reach(window) == (window - 1, 0)
 
 
 def pytest_no_mixing_across_a_boundary_and_none_from_beyond_the_window(setup):
@@ -328,11 +330,11 @@ def pytest_entry_points_refuse_what_the_family_cannot_run():
         "MELLUM", 1, D, (V,), ("node",), HEADS, [1.0], LAYERS, **kw
     )
     with pytest.raises(ValueError, match="compute_dtype"):
-        make(mellum=ARCH, compute_dtype="bfloat16")
+        make(token_arch=ARCH, compute_dtype="bfloat16")
     with pytest.raises(ValueError, match="token_minmax"):
-        make(mellum={k: v for k, v in ARCH.items() if k != "token_minmax"})
+        make(token_arch={k: v for k, v in ARCH.items() if k != "token_minmax"})
     with pytest.raises(ValueError, match="sliding_window"):
-        make(mellum={k: v for k, v in ARCH.items() if k != "sliding_window"})
+        make(token_arch={k: v for k, v in ARCH.items() if k != "sliding_window"})
     with pytest.raises(ValueError, match="stack's sizes"):
         make()
     with pytest.raises(ValueError, match="not among"):
@@ -492,7 +494,7 @@ def pytest_engine_counts_the_key_blocks_of_both_kinds(setup, engine, monkeypatch
     from hydragnn_tpu.serve import InferenceEngine
 
     model, graphs, batch, variables = setup
-    monkeypatch.setattr(lfm2, "ATTN_BLOCK", 8)
+    monkeypatch.setattr(token_attention, "ATTN_BLOCK", 8)
     names = ("attn_key_blocks_visited_total", "attn_key_blocks_causal_total",
              "attn_window_key_blocks_total")
 

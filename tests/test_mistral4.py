@@ -32,7 +32,9 @@ from graftbench.drivers.train_epochs import shaken  # noqa: E402
 from graftbench.families import mistral4 as plain  # noqa: E402
 from hydragnn_tpu.graphs import GraphSample, collate_graphs  # noqa: E402
 from hydragnn_tpu.models import create_model, init_model_variables  # noqa: E402
-from hydragnn_tpu.models import laguna, lfm2, mistral4  # noqa: E402
+from hydragnn_tpu.models import (  # noqa: E402
+    laguna, lfm2, mistral4, token_attention, token_common, token_routed,
+)
 from hydragnn_tpu.models.base import HydraGNN  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
@@ -56,7 +58,7 @@ K = ARCH["num_experts_per_tok"]
 def _model(layers=LAYERS, **arch):
     return create_model(
         "MISTRAL4", 1, D, (V,), ("node",), HEADS, [1.0], layers,
-        mistral4=dict(ARCH, **arch), head_loss=("cross_entropy",),
+        token_arch=dict(ARCH, **arch), head_loss=("cross_entropy",),
         class_minmax=([0.0, V - 1.0],),
     )
 
@@ -64,9 +66,9 @@ def _model(layers=LAYERS, **arch):
 def _forward(model, variables, batch):
     """(logits, the routing as the engine returns it [N, layers x K], counters)."""
     out, sown = model.apply(
-        {"params": variables["params"]}, batch, train=False, mutable=[lfm2.INTERMEDIATES],
+        {"params": variables["params"]}, batch, train=False, mutable=[token_routed.INTERMEDIATES],
     )
-    routing, counters = lfm2.split_intermediates(sown[lfm2.INTERMEDIATES])
+    routing, counters = token_routed.split_intermediates(sown[token_routed.INTERMEDIATES])
     chosen = np.concatenate(
         [np.asarray(routing[f"conv_{i}"]["chosen"]) for i in range(model.num_conv_layers)], axis=1
     )
@@ -130,13 +132,13 @@ def pytest_the_router_is_a_softmax_over_all_experts_top_k_normalised():
     cfg = mistral4.Mistral4Config.from_arch(dict(ARCH, num_experts_held=8, experts_offset=0), LAYERS)
     assert cfg.scoring_func == "softmax" and not cfg.use_expert_bias and cfg.norm_topk_prob
     x = jnp.asarray(rng.normal(size=(12, D)).astype(np.float32))
-    layer = lfm2.RoutedFFN(D, cfg)
+    layer = token_routed.RoutedFFN(D, cfg)
     params = layer.init(jax.random.PRNGKey(0), x, jnp.ones((12,), bool))["params"]
     assert "expert_bias" not in params
     out, sown = layer.apply(
-        {"params": params}, x, jnp.ones((12,), bool), mutable=[lfm2.INTERMEDIATES]
+        {"params": params}, x, jnp.ones((12,), bool), mutable=[token_routed.INTERMEDIATES]
     )
-    chosen = np.asarray(sown[lfm2.INTERMEDIATES]["moe_chosen"][-1])
+    chosen = np.asarray(sown[token_routed.INTERMEDIATES]["moe_chosen"][-1])
     p = np.asarray(jax.nn.softmax(np.asarray(x, np.float64) @ np.asarray(params["gate"], np.float64)))
     assert np.allclose(p.sum(-1), 1.0)
     assert np.array_equal(np.sort(chosen), np.sort(np.argsort(-p, axis=1)[:, :K]))
@@ -148,9 +150,9 @@ def pytest_the_router_is_a_softmax_over_all_experts_top_k_normalised():
             b = np.asarray(x[i], np.float64) @ np.asarray(params["w3"][e], np.float64)
             want[i] += w_e * ((a / (1 + np.exp(-a))) * b) @ np.asarray(params["w2"][e], np.float64)
     assert np.abs(np.asarray(out) - want).max() < 1e-5 * np.abs(want).max()
-    # The siblings' sizes name no score function and keep the sigmoid.
-    assert not hasattr(lfm2.LFM2Config, "scoring_func")
-    assert not hasattr(laguna.LagunaConfig, "scoring_func")
+    # The siblings' sizes keep the sigmoid, and say so themselves: the routed
+    # layer defaults nothing.
+    assert lfm2.LFM2Config.scoring_func == laguna.LagunaConfig.scoring_func == "sigmoid"
 
 
 def pytest_four_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
@@ -165,8 +167,8 @@ def pytest_four_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
     )
     x = jnp.asarray(rng.normal(size=(n, D)).astype(np.float32))
     mask = jnp.ones((n,), bool)
-    full = lfm2.RoutedFFN(D, whole).init(jax.random.PRNGKey(0), x, mask)["params"]
-    shared = lfm2.DenseFFN(D, whole.moe_intermediate_size)
+    full = token_routed.RoutedFFN(D, whole).init(jax.random.PRNGKey(0), x, mask)["params"]
+    shared = token_routed.DenseFFN(D, whole.moe_intermediate_size)
     shared_p = shared.init(jax.random.PRNGKey(1), x)["params"]
     report = dict(route_margin=0.0, loads=[], chosen=[])
     want = plain._dense(shared_p, x, plain.Exact) + plain._routed(
@@ -180,10 +182,10 @@ def pytest_four_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
         )
         held = slice(2 * rank, 2 * rank + 2)
         part = dict(full, w1=full["w1"][held], w3=full["w3"][held], w2=full["w2"][held])
-        out, sown = lfm2.RoutedFFN(D, share).apply(
-            {"params": part}, x, mask, mutable=[lfm2.INTERMEDIATES]
+        out, sown = token_routed.RoutedFFN(D, share).apply(
+            {"params": part}, x, mask, mutable=[token_routed.INTERMEDIATES]
         )
-        seen += int(sown[lfm2.INTERMEDIATES]["moe_rows_held"][-1])
+        seen += int(sown[token_routed.INTERMEDIATES]["moe_rows_held"][-1])
         total = total + out
     assert seen == n * K  # every assignment is computed on exactly one rank
     assert np.abs(np.asarray(total - want)).max() < 1e-5 * np.abs(np.asarray(want)).max()
@@ -201,7 +203,7 @@ def pytest_interleaved_rotation_in_place_against_the_permuted_halves():
     inv, factor = plain.frequencies(ROPE, rot)
     q_ref, k_ref = (plain.turn_pairs(a, place, inv, factor) for a in (q, k))
     q_got, k_got = (
-        lfm2.rotate(mistral4.pairs_to_halves(a), place, inv, factor) for a in (q, k)
+        token_common.rotate(mistral4.pairs_to_halves(a), place, inv, factor) for a in (q, k)
     )
     want = np.einsum("qhd,kd->hqk", np.asarray(q_ref), np.asarray(k_ref)[:, 0])
     got = np.einsum("qhd,kd->hqk", np.asarray(q_got), np.asarray(k_got)[:, 0])
@@ -308,9 +310,9 @@ def pytest_entry_points_refuse_what_the_family_cannot_run():
         "MISTRAL4", 1, D, (V,), ("node",), HEADS, [1.0], LAYERS, **kw
     )
     with pytest.raises(ValueError, match="compute_dtype"):
-        make(mistral4=ARCH, compute_dtype="bfloat16")
+        make(token_arch=ARCH, compute_dtype="bfloat16")
     with pytest.raises(ValueError, match="token_minmax"):
-        make(mistral4={k: v for k, v in ARCH.items() if k != "token_minmax"})
+        make(token_arch={k: v for k, v in ARCH.items() if k != "token_minmax"})
     with pytest.raises(ValueError, match="stack's sizes"):
         make()
     with pytest.raises(ValueError, match="not among"):
@@ -489,7 +491,7 @@ def pytest_engine_reply_is_the_direct_forwards_log_probabilities(setup, engine):
         np.bincount(chosen[:, layer][(chosen[:, layer] >= 0) & (chosen[:, layer] < 4)], minlength=4)
         for layer in range(LAYERS)
     ])
-    cap = lfm2._capacity(64 * K, 4, 8)
+    cap = token_routed._capacity(64 * K, 4, 8)
     assert cap == 256  # every row array of a 64-token rung is one pass
     assert together["moe_rows_held_total"] == loads.sum() > 0
     assert together["moe_load_max_total"] == loads.max(axis=1).sum()
@@ -532,7 +534,7 @@ def pytest_engine_counts_the_key_blocks_a_flush_visits(setup, engine, monkeypatc
     from hydragnn_tpu import telemetry
 
     model, graphs, batch, variables = setup
-    monkeypatch.setattr(lfm2, "ATTN_BLOCK", 8)
+    monkeypatch.setattr(token_attention, "ATTN_BLOCK", 8)
     names = ("attn_key_blocks_visited_total", "attn_key_blocks_causal_total")
 
     def flush():
@@ -578,7 +580,7 @@ def pytest_engine_counts_a_layer_past_its_capacity():
         future = eng.submit(_requests([g])[0])
         reply = future.result(120)
         snap = eng.metrics.snapshot()
-    cap = lfm2._capacity(300 * K, 2, 8)
+    cap = token_routed._capacity(300 * K, 2, 8)
     assert cap == 256 < 300 * K
     held = int(((future.routing >= 0) & (future.routing < 2)).sum())
     assert snap["moe_rows_held_total"] == held > cap

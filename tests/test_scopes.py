@@ -592,7 +592,7 @@ def pytest_pr41_serves_a_band_under_the_names_the_table_has():
     model = create_model(
         "MELLUM", 1, 8, (v,), ("node",),
         {"node": {"num_headlayers": 0, "dim_headlayers": [], "type": "mlp"}}, [1.0], 2,
-        mellum=dict(
+        token_arch=dict(
             layer_types=["sliding_attention", "full_attention"], mlp_layer_types=["sparse"] * 2,
             num_attention_heads=2, num_key_value_heads=1, head_dim=4, sliding_window=3,
             rope_parameters=rope, moe_intermediate_size=8, num_experts=4,

@@ -299,7 +299,7 @@ def pytest_lfm2_attention_at_cell_size_has_no_n_by_n_array(one_chip):
     never materialised -- no array of the lowered program has two axes of the
     node count (4160, or the 4608 it is padded to for the kernel's blocks),
     and the scores live inside the flash kernel's calls."""
-    from hydragnn_tpu.models.lfm2 import ATTN_BLOCK, segment_causal_attention
+    from hydragnn_tpu.models.token_attention import ATTN_BLOCK, segment_causal_attention
     from hydragnn_tpu.ops.segment import platform_override
 
     n, h, kv, hd = 4160, 32, 8, 64
@@ -335,7 +335,7 @@ def pytest_served_attention_core_at_cell_size_is_the_block_range_kernel(one_chip
     with nothing repeated, and no array with two row axes; under
     ``jax.grad`` the program holds the library's three kernels and not the
     new one."""
-    from hydragnn_tpu.models.lfm2 import segment_causal_attention
+    from hydragnn_tpu.models.token_attention import segment_causal_attention
     from hydragnn_tpu.ops.segment import platform_override
 
     n, h, hd = 15872, 32, 128
@@ -379,7 +379,8 @@ def pytest_lfm2_routed_experts_at_cell_size_are_grouped_matmuls(one_chip, cell):
     6400 rows, the way back (and the way in's backward) a scatter-add of
     those 6400 rows into the ``[4160, 2048]`` nodes: no scatter of wider
     updates is compiled (the decision: PERF.md section 6, PR 34)."""
-    from hydragnn_tpu.models.lfm2 import LFM2Config, RoutedFFN, _capacity
+    from hydragnn_tpu.models.lfm2 import LFM2Config
+    from hydragnn_tpu.models.token_routed import RoutedFFN, _capacity
     from hydragnn_tpu.ops.segment import platform_override
 
     n, d = 4160, 2048
@@ -446,7 +447,7 @@ def pytest_laguna_window_layers_at_cell_size_call_the_band_kernel(one_chip):
     the band's kernels visit FEWER key blocks than the triangle's would: the
     grid of the band's forward is the 3 key blocks of 256 a query block that
     the window touches, not the 17 the sequence has."""
-    from hydragnn_tpu.models.lfm2 import ATTN_BLOCK, segment_causal_attention
+    from hydragnn_tpu.models.token_attention import ATTN_BLOCK, segment_causal_attention
     from hydragnn_tpu.ops.segment import platform_override
 
     n, kv, hd, window = 4160, 8, 128, 512
@@ -500,13 +501,13 @@ def pytest_grouped_matmul_tiles_follow_the_weights(one_chip):
     forward and backward, to the grouped-matmul kernel's calls with no tile
     wider than a matrix; LFM2's (1792 wide) still calls the same kernel with
     the tiles it had."""
-    from hydragnn_tpu.models import laguna, lfm2
+    from hydragnn_tpu.models import laguna, token_routed
     from hydragnn_tpu.ops.segment import platform_override
 
-    assert lfm2._gmm_tiles(6400, 2048, 1792) == lfm2.GMM_TILING == (256, 1024, 1024)
-    assert lfm2._gmm_tiles(6400, 1792, 2048) == lfm2.GMM_TILING
-    assert lfm2._gmm_tiles(6400, 2048, 512) == (256, 1024, 512)
-    assert lfm2._gmm_tiles(6400, 512, 2048) == (256, 512, 1024)
+    assert token_routed._gmm_tiles(6400, 2048, 1792) == token_routed.GMM_TILING == (256, 1024, 1024)
+    assert token_routed._gmm_tiles(6400, 1792, 2048) == token_routed.GMM_TILING
+    assert token_routed._gmm_tiles(6400, 2048, 512) == (256, 1024, 512)
+    assert token_routed._gmm_tiles(6400, 512, 2048) == (256, 512, 1024)
     with open(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "graftbench", "configs", "laguna_xs2_ep8.json",
@@ -516,7 +517,7 @@ def pytest_grouped_matmul_tiles_follow_the_weights(one_chip):
         arch = json.load(f)["NeuralNetwork"]["Architecture"]
     cfg = laguna.LagunaConfig.from_arch(dict(arch, token_minmax=[0.0, 12543.0]), 5)
     n, d, f_, held, k = 4160, 2048, 512, 32, 8
-    layer = lfm2.RoutedFFN(d, cfg)
+    layer = token_routed.RoutedFFN(d, cfg)
 
     def shaped(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -537,7 +538,7 @@ def pytest_grouped_matmul_tiles_follow_the_weights(one_chip):
         text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
             params, shaped((n, d)), shaped((n,), jnp.bool_)
         ).compile().as_text()
-    rows = lfm2._capacity(k * n, held, 256)  # [6400, .], not every assignment's 33280
+    rows = token_routed._capacity(k * n, held, 256)  # [6400, .], not every assignment's 33280
     assert f"f32[{rows},{d}]" in text and f"f32[{rows},{f_}]" in text
     dense = re.search(rf"f32\[(?:{held}|256),{rows},\d+\]", text)
     assert not dense, f"the experts' rows as a dense batch: {dense.group(0)}"
@@ -563,7 +564,7 @@ def pytest_mellum_engine_at_the_guard_rung_fits_beside_every_expert(one_chip):
     from graftbench.drivers import serve_tokens
     from hydragnn_tpu.graphs.collate import GraphArena
     from hydragnn_tpu.graphs.sample import GraphSample
-    from hydragnn_tpu.models import lfm2
+    from hydragnn_tpu.models import token_attention, token_routed
     from hydragnn_tpu.ops.segment import platform_override
     from hydragnn_tpu.serve.engine import _token_forward
 
@@ -595,15 +596,15 @@ def pytest_mellum_engine_at_the_guard_rung_fits_beside_every_expert(one_chip):
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 4.5e9, memory.temp_size_in_bytes
     text = compiled.as_text()
-    rows, blocks = 8 * n, n // lfm2.ATTN_BLOCK
+    rows, blocks = 8 * n, n // token_attention.ATTN_BLOCK
     assert text.count("tpu_custom_call") == 2 * 4  # a core and three grouped matmuls a layer
     assert f"f32[{rows},2304]" in text and f"f32[{rows},896]" in text
     # One pass: no loop carries a row array (the head's row blocks and the
     # top-k's are the loops there are).
     assert not [l for l in text.splitlines() if " while(" in l and f"[{rows}," in l]
     assert f"s8[1,{blocks},3]" in text and f"s8[1,{blocks},{blocks}]" not in text  # the band's schedule
-    assert lfm2._gmm_tiles(rows, 2304, 896) == (256, 1152, 896)
-    assert lfm2.band_key_blocks(n, 1024) == 1 + 2 + 3 * (blocks - 2)
+    assert token_routed._gmm_tiles(rows, 2304, 896) == (256, 1152, 896)
+    assert token_attention.band_key_blocks(n, 1024) == 1 + 2 + 3 * (blocks - 2)
 
 
 def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monkeypatch):
@@ -747,7 +748,7 @@ def _unchanged_programs(one_chip):
     token = dict(head_loss=("cross_entropy",), class_minmax=([0.0, v - 1.0],))
     models = {
         "lfm2_train": create_model(
-            "LFM2", 1, d, (v,), ("node",), heads, [1.0], 2, lfm2=dict(
+            "LFM2", 1, d, (v,), ("node",), heads, [1.0], 2, token_arch=dict(
                 layer_types=["conv", "full_attention"], num_dense_layers=0,
                 intermediate_size=512, moe_intermediate_size=256, num_experts=8,
                 num_experts_per_tok=2, num_experts_held=4, experts_offset=2,
@@ -756,7 +757,7 @@ def _unchanged_programs(one_chip):
             ), **token,
         ),
         "laguna_train": create_model(
-            "LAGUNA", 1, d, (v,), ("node",), heads, [1.0], 2, laguna=dict(
+            "LAGUNA", 1, d, (v,), ("node",), heads, [1.0], 2, token_arch=dict(
                 layer_types=["full_attention", "sliding_attention"],
                 mlp_layer_types=["dense", "sparse"],
                 num_attention_heads_per_layer=[2, 4], num_key_value_heads=2, head_dim=128,
