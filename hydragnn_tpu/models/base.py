@@ -165,6 +165,14 @@ class HydraGNN(nn.Module):
         return self.token_cfg if self.conv_type == "MELLUM" else None
 
     @property
+    def tied_head(self) -> bool:
+        """Whether the class head is the token embedding transposed (a token
+        stack whose sizes say ``tie_word_embeddings``): the one node head then
+        has no matrix and no bias of its own, its logits are ``h E^T``."""
+        cfg = self.token_cfg
+        return cfg is not None and getattr(cfg, "tie_word_embeddings", False)
+
+    @property
     def use_edge_attr(self) -> bool:
         return self.edge_dim is not None and self.edge_dim > 0
 
@@ -385,7 +393,20 @@ class HydraGNN(nn.Module):
                     )
                 )
             elif htype == "node":
-                if self.node_nn_type in ("mlp", "mlp_per_node"):
+                if self.tied_head:
+                    ncfg = self.config_heads["node"]
+                    if (
+                        self.node_nn_type != "mlp" or ncfg["num_headlayers"]
+                        or hdim != self.token_cfg.vocab_size
+                        or len(self.output_type) != 1
+                    ):
+                        raise ValueError(
+                            f"{self.conv_type} ties its one class head to the "
+                            "embedding: one 'mlp' node head of no hidden layer, "
+                            f"as wide as the vocabulary ({self.token_cfg.vocab_size})"
+                        )
+                    heads.append(None)  # the embedding's own table: _node_head
+                elif self.node_nn_type in ("mlp", "mlp_per_node"):
                     ncfg = self.config_heads["node"]
                     heads.append(
                         MLPNode(
@@ -510,15 +531,26 @@ class HydraGNN(nn.Module):
         return self._heads(x, batch, False, score=(nxt, follows))
 
     @nn.nowrap
-    def _next_token_logprob(self, ihead: int, x, nxt, follows):
+    def _node_head(self, ihead: int):
+        """The node head ``ihead`` as a function of rows alone, unbound (so
+        that ``lax.map`` may call it): its MLP, or for a tied head the
+        embedding's table transposed (``nn.Embed.attend``: ``x E^T``)."""
+        if self.tied_head:
+            embed, variables = self.conv_embed.unbind()
+            return lambda rows: embed.apply(variables, rows, method="attend")
         head, variables = self.heads_nn[ihead].unbind()
+        return lambda rows: head.apply(variables, rows, None)
+
+    @nn.nowrap
+    def _next_token_logprob(self, ihead: int, x, nxt, follows):
+        head = self._node_head(ihead)
         n, classes = x.shape[0], self.output_dim[ihead]
         block = min(LOGPROB_BLOCK, n)
         pad = -n % block
 
         def rows(args):
             xb, tb = args
-            logits = head.apply(variables, xb, None).astype(jnp.float32)
+            logits = head(xb).astype(jnp.float32)
             top = jnp.max(logits, axis=-1, keepdims=True)
             lse = top[:, 0] + jnp.log(jnp.sum(jnp.exp(logits - top), axis=-1))
             # The next token's logit by a compare against an iota: a gather
@@ -579,6 +611,8 @@ class HydraGNN(nn.Module):
                     outputs.append(xn)
                 elif score is not None and ihead in self.scored_heads:
                     outputs.append(self._next_token_logprob(ihead, x, *score))
+                elif self.tied_head:
+                    outputs.append(self.conv_embed.attend(x))
                 else:
                     outputs.append(self.heads_nn[ihead](x, batch))
         return outputs

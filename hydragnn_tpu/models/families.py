@@ -9,6 +9,7 @@ apart: ROADMAP D25).
 
 from __future__ import annotations
 
+from .jamba import JambaBlock, JambaConfig
 from .laguna import LagunaBlock, LagunaConfig
 from .lfm2 import LFM2Block, LFM2Config
 from .mellum import MellumBlock, MellumConfig
@@ -23,6 +24,7 @@ TOKEN_STACKS = {
     "LAGUNA": (LagunaConfig, LagunaBlock),
     "MISTRAL4": (Mistral4Config, Mistral4Block),
     "MELLUM": (MellumConfig, MellumBlock),
+    "JAMBA": (JambaConfig, JambaBlock),
 }
 TOKEN_FAMILIES = frozenset(TOKEN_STACKS)
 # Every valid ``model_type``: the six convolutions of models/convs.py, PaiNN

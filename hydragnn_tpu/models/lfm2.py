@@ -22,7 +22,8 @@ UNREAD here, as are ``row_ptr`` and the edge mask.
 
 This file holds what is LFM2's alone: its sizes, the short convolution, its
 attention layer (RMSNorm on each head of ``q`` and ``k``, plain rotary) and
-the block that orders them. Norm and rotary come from ``token_common.py``, the
+the block that orders them. Norm, rotary and the shifted read inside a graph
+come from ``token_common.py``, the
 attention core from ``token_attention.py``, the dense and the routed
 feed-forward (one rank's share of an expert-parallel layer) from
 ``token_routed.py``; no other family's file is imported here and none imports
@@ -40,12 +41,11 @@ import dataclasses
 from typing import Tuple
 
 import jax
-import jax.numpy as jnp
 import flax.linen as nn
 
 from ..telemetry import scopes
 from .token_attention import segment_causal_attention
-from .token_common import RMSNorm, missing_fields, rope
+from .token_common import RMSNorm, missing_fields, rope, same_graph_shift
 from .token_routed import DenseFFN, RoutedFFN, experts_share
 # Read by graftbench/drivers/train_tokens.py under this module's name (the
 # benchmark's files are not this PR's to edit: ROADMAP D25).
@@ -114,15 +114,6 @@ class LFM2Config:
         return layer >= self.num_dense_layers
 
 
-def _same_graph_shift(z, node_graph, by: int):
-    """``z`` moved down ``by`` rows, zero where the row ``by`` above belongs
-    to another graph (or to none: before the first node)."""
-    n = z.shape[0]
-    moved = jnp.concatenate([jnp.zeros((by,) + z.shape[1:], z.dtype), z[: n - by]])
-    above = jnp.concatenate([jnp.full((by,), -1, node_graph.dtype), node_graph[: n - by]])
-    return jnp.where((above == node_graph)[:, None], moved, 0.0)
-
-
 class ShortConv(nn.Module):
     """``(B, C, u) = split(W_in x)``; ``z = B * u``; the depthwise causal
     convolution ``c_i = sum_j k_j * z_{i-(L-1-j)}`` inside the node's own
@@ -144,7 +135,7 @@ class ShortConv(nn.Module):
             z = b * u
             conv = k[self.taps - 1] * z
             for back in range(1, self.taps):
-                conv = conv + k[self.taps - 1 - back] * _same_graph_shift(z, node_graph, back)
+                conv = conv + k[self.taps - 1 - back] * same_graph_shift(z, node_graph, back)
             y = c * conv
         return nn.Dense(d, use_bias=False, name="out_proj")(y)
 

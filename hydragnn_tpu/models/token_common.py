@@ -1,6 +1,7 @@
 """What every token stack (``models/families.py`` ``TOKEN_STACKS``) does to a
 token before and between its mixers, and no family's own: the token id read
-exactly off the node column, RMSNorm, and the rotary embedding by the source's
+exactly off the node column, RMSNorm, the shifted read inside a node's own
+graph (a causal convolution's tap), and the rotary embedding by the source's
 ``rope_parameters`` (plain frequencies, or YaRN's blended ones over a part of
 the head). A family's file imports from here, from ``token_attention.py`` and
 from ``token_routed.py``; this module imports none of them.
@@ -13,7 +14,8 @@ them:
 
 * the encoder (``models/base.py``) and ``token_ids``: ``vocab_size``,
   ``token_minmax``, ``norm_eps`` (the final norm's), ``routed(layer)``
-  (whether the layer sows the routed layer's counters);
+  (whether the layer sows the routed layer's counters) and, of a stack whose
+  class head is the embedding transposed ALONE, ``tie_word_embeddings``;
 * ``token_routed.RoutedFFN`` and ``pass_rows``: ``num_experts``,
   ``num_experts_per_tok``, ``num_experts_held``, ``experts_offset``,
   ``moe_intermediate_size``, ``use_expert_bias``, ``norm_topk_prob``,
@@ -24,7 +26,8 @@ them:
   ``num_experts_held``, ``experts_offset`` (a flush's routing counters,
   against ``pass_rows``) and, of a stack with band layers ALONE,
   ``sliding(layer)`` and ``sliding_window`` (a stack whose every layer is the
-  complete causal graph has neither);
+  complete causal graph has neither); of a stack with selective-scan layers
+  ALONE, ``scans(layer)``;
 * config checking (``analysis/contracts.py``) and ``create_model``: the
   class's ``missing(arch)`` and ``from_arch(arch, num_layers)``.
 
@@ -73,6 +76,32 @@ class RMSNorm(nn.Module):
         w = self.param("weight", nn.initializers.ones, (x.shape[-1],))
         x = x.astype(jnp.float32)
         return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.eps) * w
+
+
+def same_graph_above(node_graph, by: int):
+    """[N] bool: whether the row ``by`` above belongs to the row's own graph
+    (False before the first node)."""
+    n = node_graph.shape[0]
+    above = jnp.concatenate([jnp.full((by,), -1, node_graph.dtype), node_graph[: n - by]])
+    return above == node_graph
+
+
+def same_graph_shift(z, node_graph, by: int):
+    """``z`` moved down ``by`` rows, zero where the row ``by`` above belongs
+    to another graph (or to none: before the first node): the read of a
+    causal convolution's tap inside a node's own graph.
+
+    Not for a ``z`` that nothing reads afterwards in a program with no
+    backward: the TPU compiler makes ``z[: n - by]`` a VIEW of ``z``, lets a
+    fusion that also reads ``z`` itself write its result into ``z``'s buffer,
+    and walks that fusion in windows of rows, so the first ``by`` rows of
+    every window read rows the window before has overwritten (my chip runs,
+    PR 45: rows 368 k .. 368 k + 2 of a 2560-row array off by 40-60%). A train
+    step keeps ``z`` for its backward and is safe; a served layer reads its
+    taps off an array of ANOTHER length than its result (models/jamba.py)."""
+    n = z.shape[0]
+    moved = jnp.concatenate([jnp.zeros((by,) + z.shape[1:], z.dtype), z[: n - by]])
+    return jnp.where(same_graph_above(node_graph, by)[:, None], moved, 0.0)
 
 
 def rotate(x, place, inv, factor: float = 1.0):

@@ -48,7 +48,10 @@ logits, and the routed layers' choices come out of the same executable
 KINDS by layer, the complete causal graph of a document and the causal band
 of ``sliding_window`` (which is which is the stack's sizes' to say:
 ``sliding(layer)``), and a flush's key blocks are counted a kind
-(``_count_key_blocks``). Of the token layers the engine imports the shared
+(``_count_key_blocks``); a stack may route in no layer (``counts_routing``
+false: the executable returns no choices and ``future.routing`` stays None)
+and may hold selective-scan layers (``scans(layer)``), whose walk a flush is
+counted by (``_count_scans``). Of the token layers the engine imports the shared
 modules alone (``models/token_routed.py``: the sown collection and
 ``pass_rows``; ``models/token_attention.py``: the key-block counts), never a
 family's file.
@@ -389,6 +392,12 @@ class InferenceEngine:
             self._token_cfg.sliding_window
             if sliding and any(sliding(i) for i in range(model.num_conv_layers))
             else None
+        )
+        # Whether any of its layers is a selective scan (a stack with none
+        # has no ``scans``).
+        scans = getattr(self._token_cfg, "scans", None)
+        self._scans = bool(
+            scans and any(scans(i) for i in range(model.num_conv_layers))
         )
         if self._token_cfg is not None and precision != "f32":
             raise ValueError(
@@ -1283,6 +1292,19 @@ class InferenceEngine:
             ),
         })
 
+    def _count_scans(self, node_graph: np.ndarray, documents: int) -> None:
+        """A flush's selective-scan layers in the engine's counters and as
+        graftel gauges: the time chunks ONE scan call walks over the flush's
+        rung, a layer and a channel block (ops/selective_scan.py
+        ``scan_chunks``: the kernel's sequential grid axis), and the
+        documents whose state the flush started (each a run of
+        ``node_graph``; the padding rows' run is not a document); 0 and 0 for
+        a stack with no such layer."""
+        from ..ops.selective_scan import scan_chunks
+
+        chunks, resets = (scan_chunks(len(node_graph)), documents) if self._scans else (0, 0)
+        self._count_flush({"ssm_scan_chunks_total": chunks, "ssm_state_resets_total": resets})
+
     def _count_flush(self, counted: Dict[str, int]) -> None:
         for name, value in counted.items():
             self.metrics.count(name, value)
@@ -1296,6 +1318,7 @@ class InferenceEngine:
         routing = getattr(outputs, "routing", None)
         if self._token_cfg is not None:
             self._count_key_blocks(work.batch.node_graph)
+            self._count_scans(work.batch.node_graph, len(work.requests))
         if routing is not None:
             last = work.requests[-1]
             self._count_routing(

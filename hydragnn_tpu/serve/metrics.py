@@ -228,6 +228,12 @@ class ServeMetrics:
         self.attn_key_blocks_visited_total = 0  # guarded-by: self._lock
         self.attn_key_blocks_causal_total = 0  # guarded-by: self._lock
         self.attn_window_key_blocks_total = 0  # guarded-by: self._lock
+        # A token family's selective-scan layers (serve/engine.py
+        # _count_scans), summed over the flushes: the time chunks ONE scan
+        # call walks at the flush's rung, a layer, and the documents whose
+        # state a flush started (0 for a stack with no such layer).
+        self.ssm_scan_chunks_total = 0  # guarded-by: self._lock
+        self.ssm_state_resets_total = 0  # guarded-by: self._lock
         # Occupancy / padding accumulators (averages derived in snapshot()).
         self._occupancy_sum = 0.0  # guarded-by: self._lock
         self._node_fill_sum = 0.0  # guarded-by: self._lock
@@ -369,6 +375,8 @@ class ServeMetrics:
                 "attn_key_blocks_visited_total": self.attn_key_blocks_visited_total,
                 "attn_key_blocks_causal_total": self.attn_key_blocks_causal_total,
                 "attn_window_key_blocks_total": self.attn_window_key_blocks_total,
+                "ssm_scan_chunks_total": self.ssm_scan_chunks_total,
+                "ssm_state_resets_total": self.ssm_state_resets_total,
                 # Precision arm + tolerance-gate record (docs/PRECISION.md).
                 "precision": {
                     "arm": self.precision_arm,
@@ -459,6 +467,8 @@ class ServeMetrics:
         ("attn_key_blocks_visited_total", "attn_key_blocks_visited_total"),
         ("attn_key_blocks_causal_total", "attn_key_blocks_causal_total"),
         ("attn_window_key_blocks_total", "attn_window_key_blocks_total"),
+        ("ssm_scan_chunks_total", "ssm_scan_chunks_total"),
+        ("ssm_state_resets_total", "ssm_state_resets_total"),
     )
 
     def render_prometheus(self) -> str:

@@ -63,6 +63,14 @@ MOE_SHARED = "hydragnn.moe.shared"
 # (HydraGNN.score_tokens): the head's matmul in row blocks, the log-softmax
 # and the pick of the next token's log-probability.
 HEAD_LOGPROB = "hydragnn.head.logprob"
+# The Mamba mixer of a state-space layer (models/jamba.py): the depthwise
+# causal convolution and its silu; the split of ``u W_x``, the three inner
+# norms, ``W_dt`` and the softplus; the selective scan's calls with the gate
+# (ops/selective_scan.py). The other projections stay with the module (PR 45;
+# names added, none changed).
+SSM_CONV = "hydragnn.ssm.conv"
+SSM_DT = "hydragnn.ssm.dt"
+SSM_SCAN = "hydragnn.ssm.scan"
 # The routed experts: router, top-k, the sort by expert, both row
 # permutations and the weighting; and the grouped matmuls alone.
 MOE_ROUTE = "hydragnn.moe.route"
@@ -91,7 +99,7 @@ VOCABULARY = frozenset(
     ROOTS
     + (GATHER, POOL, GEOM, LFM2_CONV, LFM2_ATTN, ATTN_FULL, ATTN_WINDOW)
     + (MOE_ROUTE, MOE_EXPERTS, ATTN_LATENT, MOE_SHARED, HEAD_LOGPROB)
-    + (LOSS, OPTIMIZER, GRAD_SYNC, AGG_PNA)
+    + (LOSS, OPTIMIZER, GRAD_SYNC, AGG_PNA, SSM_CONV, SSM_DT, SSM_SCAN)
     + tuple(agg(w, a) for w in AGG_WHATS for a in AGG_ARMS)
 )
 

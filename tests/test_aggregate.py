@@ -317,7 +317,7 @@ def pytest_no_flag_selects_an_aggregation_kernel():
     modules = sorted(name for name in os.listdir(ops) if name.endswith(".py"))
     assert modules == [
         "__init__.py", "aggregate.py", "block_attention.py", "certify.py",
-        "extrema_scan.py", "segment.py", "segment_sorted.py",
+        "extrema_scan.py", "segment.py", "segment_sorted.py", "selective_scan.py",
     ]
     found = set()
     for name in modules:

@@ -560,7 +560,9 @@ def pytest_pr39_adds_three_names_and_changes_none():
         "hydragnn.moe.route", "hydragnn.moe.experts", "hydragnn.loss",
         "hydragnn.optimizer", "hydragnn.grad_sync", "hydragnn.agg.pna",
     } | {scopes.agg(w, a) for w in scopes.AGG_WHATS for a in scopes.AGG_ARMS}
-    assert scopes.VOCABULARY == before | added
+    # PR 45 added the three leaves of a state-space layer the same way.
+    later = {"hydragnn.ssm.conv", "hydragnn.ssm.dt", "hydragnn.ssm.scan"}
+    assert scopes.VOCABULARY == before | added | later
     # A regression family's served program opens none of them.
     from hydragnn_tpu.serve import InferenceEngine
 
@@ -584,7 +586,8 @@ def pytest_pr41_serves_a_band_under_the_names_the_table_has():
     ``hydragnn.`` name outside the vocabulary."""
     from hydragnn_tpu.serve import InferenceEngine
 
-    assert len(scopes.VOCABULARY) == 19 + len(scopes.AGG_WHATS) * len(scopes.AGG_ARMS)
+    # 19 names at PR 41; PR 45's three leaves came after.
+    assert len(scopes.VOCABULARY) == 19 + 3 + len(scopes.AGG_WHATS) * len(scopes.AGG_ARMS)
     v = 16
     rope = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
                                "original_max_position_embeddings": 8},
@@ -617,6 +620,48 @@ def pytest_pr41_serves_a_band_under_the_names_the_table_has():
     assert f"conv_1/self_attn/{scopes.ATTN_FULL}" in text
     assert f"conv_0/self_attn/{scopes.ATTN_FULL}" not in text
     assert scopes.VERSION == 1
+
+
+def pytest_pr45_adds_three_names_for_a_state_space_layer_and_changes_none():
+    """PR 45's leaves (the Mamba mixer's convolution, its dt chain, its scan):
+    added to the vocabulary, every earlier name still there, so the version
+    stands. The JAMBA engine's executable carries them in its Mamba layers
+    alone and the causal core in its attention layer alone; a stack with no
+    such layer (Mellum's, above) opens none of them; the TRAIN step of the
+    family carries them under the train root, forward and backward."""
+    from hydragnn_tpu.models.create import make_example_batch
+    from hydragnn_tpu.serve import InferenceEngine
+
+    added = {"hydragnn.ssm.conv", "hydragnn.ssm.dt", "hydragnn.ssm.scan"}
+    assert {scopes.SSM_CONV, scopes.SSM_DT, scopes.SSM_SCAN} == added
+    assert added <= scopes.VOCABULARY and scopes.VERSION == 1
+    v = 16
+    model = create_model(
+        "JAMBA", 1, 8, (v,), ("node",),
+        {"node": {"num_headlayers": 0, "dim_headlayers": [], "type": "mlp"}}, [1.0], 2,
+        token_arch=dict(
+            attn_layer_period=2, attn_layer_offset=1, intermediate_size=16,
+            num_attention_heads=2, num_key_value_heads=1, mamba_d_state=4, mamba_d_conv=4,
+            mamba_dt_rank=2, mamba_expand=2, vocab_size=v, token_minmax=[0.0, v - 1.0],
+        ), head_loss=("cross_entropy",), class_minmax=([0.0, v - 1.0],),
+    )
+    variables = init_model_variables(
+        model, make_example_batch(1, [1], ["node"], edge_dim=None, num_nodes=4, with_positions=True)
+    )
+    assert not [k for k in variables["params"] if k.startswith("head")]  # the tied head
+    engine = InferenceEngine(model, variables, autostart=False)
+    text = engine._jit.lower(
+        variables["params"], variables.get("batch_stats", {}), engine._dummy_batch(16, 8)
+    ).as_text(debug_info=True)
+    engine.close()
+    used = set(_HYDRAGNN.findall(text))
+    assert used <= scopes.VOCABULARY, used - scopes.VOCABULARY
+    assert added | {scopes.ATTN_FULL, scopes.HEAD_LOGPROB} <= used
+    assert not used & {scopes.MOE_ROUTE, scopes.MOE_EXPERTS, scopes.ATTN_WINDOW}
+    for name in added:
+        assert f"conv_0/mamba/{name}" in text and f"conv_1/mamba/{name}" not in text
+    assert f"conv_1/self_attn/{scopes.ATTN_FULL}" in text
+    assert f"conv_0/self_attn" not in text
 
 
 def pytest_outermost_entry_point_names_the_operation():

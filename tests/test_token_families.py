@@ -117,6 +117,9 @@ def pytest_the_token_sides_imports_point_one_way(rule):
     rule()
 
 
+PINNED = ("lfm2", "laguna", "mistral4", "mellum")
+
+
 def _published_arch(family):
     """The family's ``Architecture`` block as the benchmark's configuration
     publishes it, with what config completion adds."""
@@ -156,15 +159,23 @@ def pytest_a_token_family_is_one_registry_line(family):
     model = create_model_config(arch)
     assert model.conv_type == family and type(model.token_cfg) is sizes
     assert model.token_cfg == sizes.from_arch(arch, arch["num_conv_layers"])
-    assert model.needs_positions and model.counts_routing
+    # It counts routing where some layer of it routes (PR 45's stack routes in none).
+    assert model.needs_positions and model.counts_routing == any(
+        model.token_cfg.routed(i) for i in range(model.num_conv_layers)
+    )
     assert "token_arch" in inspect.signature(create.create_model).parameters
     assert "token_cfg" in type(model).__dataclass_fields__
     lower = family.lower()
     for other in families.TOKEN_STACKS:
         assert other.lower() not in inspect.signature(create.create_model).parameters
         assert other.lower() not in type(model).__dataclass_fields__
-        # The benchmark's pinned names (ROADMAP D25): read-only, this family's alone.
-        assert getattr(model, other.lower()) is (model.token_cfg if other == family else None)
+        # The benchmark's pinned names (ROADMAP D25): read-only, this family's
+        # alone; the four that were there, and none for a family added since
+        # (its benchmark file reads ``model.token_cfg``).
+        if other.lower() in PINNED:
+            assert getattr(model, other.lower()) is (model.token_cfg if other == family else None)
+        else:
+            assert not hasattr(type(model), other.lower())
     with pytest.raises(ValueError, match="token_arch"):
         create.create_model(
             family, 1, 8, (4,), ("node",), arch["output_heads"], [1.0], 1
@@ -172,7 +183,9 @@ def pytest_a_token_family_is_one_registry_line(family):
     # The program's sources spell the family nowhere but in the registry and
     # in ``HydraGNN``'s four pinned properties.
     pinned = rf'    @property\n    def {lower}\(self\):\n        return self\.token_cfg if self\.conv_type == "{family}" else None\n'
-    for module, allowed in ((create, ()), (convs, ()), (contracts, ()), (base, (pinned,))):
+    for module, allowed in (
+        (create, ()), (convs, ()), (contracts, ()), (base, (pinned,) if lower in PINNED else ()),
+    ):
         source = _without(inspect.getsource(module), *allowed)
         assert not re.search(lower, source, flags=re.I), (
             f"{module.__name__} spells {family}"

@@ -607,6 +607,63 @@ def pytest_mellum_engine_at_the_guard_rung_fits_beside_every_expert(one_chip):
     assert token_attention.band_key_blocks(n, 1024) == 1 + 2 + 3 * (blocks - 2)
 
 
+def pytest_jamba_engine_at_the_guard_rung_holds_no_state_history(one_chip):
+    """The serving engine's executable for AI21-Jamba2-3B WHOLE (28 layers,
+    every width as published, the head tied) at the cell
+    ``jamba2_3b.serve_score_pages_c4``'s GUARD rung (16,896 tokens, four
+    pages of 4096 and the padding rows' block): no ``[N, 5120, 16]`` array of
+    any type anywhere in the optimized program (the state history: 5.5 GB an
+    array), one scan kernel a Mamba layer and one block-range kernel an
+    attention layer, the class head's logits a block of 512 rows, and the
+    compiler's buffer assignment under 2.0 GB beside the 12.12 GB of weights
+    (ISSUE 45's trigger is 15.0 GB for the two together)."""
+    import json
+
+    import numpy as np
+
+    from graftbench.drivers import serve_tokens
+    from hydragnn_tpu.graphs.collate import GraphArena
+    from hydragnn_tpu.graphs.sample import GraphSample
+    from hydragnn_tpu.ops import selective_scan
+    from hydragnn_tpu.ops.segment import platform_override
+    from hydragnn_tpu.serve.engine import _token_forward
+
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "graftbench", "configs", "jamba2_3b.json",
+    )) as f:
+        config = json.load(f)
+    arch = serve_tokens.completed_arch(config)
+    model, template, _ = serve_tokens.init_model(arch)
+    assert model.num_conv_layers == 28 and "head_0" not in template["params"]
+    n, tokens = 16896, 4096
+    pos = np.zeros((tokens, 3), np.float32)
+    pos[:, 0] = np.arange(tokens)
+    document = GraphSample(x=np.zeros((tokens, 1), np.float32), pos=pos)
+    batch = GraphArena([document] * 4).collate(
+        np.arange(4), num_nodes_pad=n, num_edges_pad=8, num_graphs_pad=5, with_positions=True,
+    )
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    with platform_override("tpu"):
+        compiled = _token_forward(model).lower(
+            shaped(template["params"]), shaped(template.get("batch_stats", {})), shaped(batch)
+        ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 12.1e9
+    assert memory.temp_size_in_bytes < 2.0e9, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    assert not re.search(rf"\[{n},5120,16\]|\[{n},16,5120\]|\[{n},81920\]", text)
+    assert text.count("tpu_custom_call") == 26 + 2
+    assert text.count('"selective_scan"') + text.count("selective_scan") >= 26
+    assert n % selective_scan.SCAN_CHUNK == 0 and 5120 % selective_scan.CHANNEL_BLOCK == 0
+    assert "f32[512,65536]" in text and f"f32[{n},65536]" not in text
+
+
 def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monkeypatch):
     """The scan path's program (``make_train_epoch_scan``) for the whole GATv2
     model of ``gatv2_h64x6_md17like.train_b512`` over a stack of
@@ -696,10 +753,16 @@ def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monke
 # by running ``_unchanged_programs`` against a checkout of the parent. PR 40
 # holds the same digests: a differentiated call of the attention core is the
 # library's three kernels as they were, kernel names included.
+# PR 45 (which moved ``same_graph_shift``, gave ``base.py`` a tied class head
+# and the engine two counters) holds them again and adds the two SERVED token
+# programs it shares ``score_tokens`` and the engine's forward with, digests
+# taken the same way from a checkout of ITS parent (a0116c7).
 PARENT_HLO = {
     "lfm2_train": "11d9fe82695b0b73",
     "laguna_train": "f5a701e3addc01d1",
     "pna_serve": "6878f6b8702ee02c",
+    "mistral_serve": "8efa33521d065d1a",
+    "mellum_serve": "4706551e93fa6f41",
 }
 
 
@@ -830,11 +893,60 @@ def _unchanged_programs(one_chip):
     finally:
         del os.environ["HYDRAGNN_SEGMENT_SORTED"]
         engine.close()
+    out.update(_served_token_programs(one_chip))
+    return out
+
+
+def _served_token_programs(one_chip):
+    """{name: digest} of the engine's ``score_tokens`` executable for the two
+    served token stacks that came before PR 45, at their published widths,
+    two layers (Mellum's one of each kind) and 2,048 rows of two documents."""
+    import json
+
+    import numpy as np
+
+    from graftbench.drivers import serve_tokens
+    from hydragnn_tpu.graphs.collate import GraphArena
+    from hydragnn_tpu.graphs.sample import GraphSample
+    from hydragnn_tpu.ops.segment import platform_override
+    from hydragnn_tpu.serve.engine import _token_forward
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    configs = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "graftbench", "configs"
+    )
+    n, tokens = 2048, 768
+    pos = np.zeros((tokens, 3), np.float32)
+    pos[:, 0] = np.arange(tokens)
+    document = GraphSample(x=np.zeros((tokens, 1), np.float32), pos=pos)
+    batch = GraphArena([document] * 2).collate(
+        np.arange(2), num_nodes_pad=n, num_edges_pad=8, num_graphs_pad=3, with_positions=True,
+    )
+    out = {}
+    for name, file, cut in (
+        ("mistral_serve", "mistral_small4_ep8.json", {}),
+        ("mellum_serve", "mellum2_12b_l4.json",
+         {"layer_types": ["sliding_attention", "full_attention"]}),
+    ):
+        with open(os.path.join(configs, file)) as f:
+            arch = serve_tokens.completed_arch(json.load(f))
+        arch.update(cut, num_conv_layers=2)
+        model, template, _ = serve_tokens.init_model(arch)
+        with platform_override("tpu"):
+            out[name] = _hlo_digest(_token_forward(model).lower(
+                shaped(template["params"]), shaped(template.get("batch_stats", {})),
+                shaped(batch),
+            ).compile().as_text())
     return out
 
 
 def pytest_programs_pr39_shares_code_with_are_text_identical_to_the_parents(one_chip):
-    """LFM2's and Laguna's train programs and the PNA serving engine's
-    executable, optimized for the described chip: the same HLO text as the
-    parent commit's (``PARENT_HLO``), metadata apart."""
+    """LFM2's and Laguna's train programs, the PNA serving engine's executable
+    and (PR 45) Mistral's and Mellum's served programs, optimized for the
+    described chip: the same HLO text as the parent commit's
+    (``PARENT_HLO``), metadata apart."""
     assert _unchanged_programs(one_chip) == PARENT_HLO
