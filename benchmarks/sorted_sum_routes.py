@@ -19,7 +19,9 @@ collation's contract), so a long run is part of every shape.
 Refuses to run anywhere but on a TPU (a CPU's time is no device time). Prints
 one JSON line a shape and writes the table to ``chiprun_out/``:
 
-    python3 benchmarks/sorted_sum_routes.py
+    python3 benchmarks/sorted_sum_routes.py [part of a row's name ...]
+
+(``"gather backward"`` runs PR 46's four rows alone, ~1 min.)
 
 ``--rehearse-on-cpu`` walks the same code at 1/64 of the rows and writes
 nothing: it finds wrong arguments, and its times mean nothing.
@@ -38,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hydragnn_tpu.ops import aggregate
 from hydragnn_tpu.ops import segment_sorted as srt
 
 REPEATS, ROUNDS = 20, 5
@@ -55,6 +58,14 @@ SHAPES = (
     ("width 32", 262144, 32, 16384, 205900, 10752),
     ("width 64", 262144, 64, 16384, 205900, 10752),
     ("width 128", 262144, 128, 16384, 205900, 10752),
+    # PR 46: a receiver-side gather's BACKWARD is this sum over the cotangent
+    # rows (``add`` is what autodiff writes for plain indexing), at the shapes
+    # the cells have since PR 43's pads. These rows also time the whole
+    # ``jax.grad`` through ``aggregate.gather_sorted`` and through ``table[ids]``.
+    ("gather backward gatv2 x_dst", 215552, 384, 11264, 215040, 10752),
+    ("gather backward gatv2 denom", 215552, 6, 11264, 215040, 10752),
+    ("gather backward pna large bucket", 401920, 256, 18944, 290000, 18900),
+    ("gather backward pna small bucket", 113152, 256, 8704, 100000, 8000),
 )
 
 
@@ -98,7 +109,10 @@ def main() -> int:
         print(f"needs a TPU, found {device.platform}: a CPU's time is no device time")
         return 3
     table = []
+    wanted = [a for a in sys.argv[1:] if not a.startswith("--")]
     for what, e, f, n, real_rows, real_segments in SHAPES:
+        if wanted and not any(w in what for w in wanted):
+            continue
         if rehearsal:
             e, n, real_rows, real_segments = (
                 e // 64, max(n // 64, 8), real_rows // 64, max(real_segments // 64, 7)
@@ -122,6 +136,21 @@ def main() -> int:
             row[f"{name}_max_abs_err"] = float(
                 np.abs(np.asarray(jitted(data, ids, row_ptr), np.float64) - truth).max()
             )
+        if what.startswith("gather backward"):
+            # What the train step runs: the gradient of a use of the gathered
+            # rows with respect to the table, ids and boundaries as arguments.
+            zeros = jnp.zeros((n, f), jnp.float32)
+            for name, gather in (
+                ("grad_plain", lambda t, i, p: t[i]),
+                ("grad_gather_sorted", aggregate.gather_sorted),
+            ):
+                grad = jax.jit(jax.grad(
+                    lambda t, w, i, p, gather=gather: jnp.sum(gather(t, i, p) * w)
+                ))
+                row[f"{name}_ms"] = time_ms(grad, zeros, data, ids, row_ptr)
+                row[f"{name}_max_abs_err"] = float(np.abs(
+                    np.asarray(grad(zeros, data, ids, row_ptr), np.float64) - truth
+                ).max())
         print(json.dumps(row), flush=True)
         table.append(row)
     if rehearsal:

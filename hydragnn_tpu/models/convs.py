@@ -169,9 +169,11 @@ class GATv2Conv(nn.Module):
         repeat = _head_blocks(jnp.ones_like(att)).T  # [h, h·f], 0/1
         # Each source is gathered ONCE: x_j feeds the logits and the
         # messages, so its two cotangents add over [E, h·f] before the one
-        # scatter-add of the backward.
+        # scatter-add of the backward. The receiver side's ids are sorted:
+        # its backward is a sorted sum (aggregate.gather_sorted).
         with jax.named_scope(scopes.GATHER):
-            x_j, x_i = x_src[senders], x_dst[receivers]
+            x_j = x_src[senders]
+        x_i = aggregate.gather_sorted(x_dst, receivers, row_ptr, self.axis_name)
         pre = nn.leaky_relu(x_j + x_i, self.negative_slope)  # [E, h·f]
         logits = jnp.dot(pre, att_blocks, precision=_HEAD_PRECISION)  # [E, h]
         # Self term: the diagonal of the attention matrix, computed densely
@@ -208,8 +210,7 @@ class GATv2Conv(nn.Module):
             exp_e, receivers, n, mask=edge_mask, axis_name=self.axis_name,
             row_ptr=row_ptr,
         ) + exp_self
-        with jax.named_scope(scopes.GATHER):
-            denom_e = denom[receivers]
+        denom_e = aggregate.gather_sorted(denom, receivers, row_ptr, self.axis_name)
         alpha = exp_e / jnp.maximum(denom_e, 1e-16)  # [E, h]
         alpha_self = exp_self / jnp.maximum(denom, 1e-16)  # [N, h]
         if train and self.dropout > 0.0:
@@ -250,8 +251,9 @@ class CGConv(nn.Module):
     @nn.compact
     def __call__(self, x, senders, receivers, edge_attr, edge_mask, node_mask, train=False, row_ptr=None):
         n, f = x.shape
+        x_i = aggregate.gather_sorted(x, receivers, row_ptr, self.axis_name)
         with jax.named_scope(scopes.GATHER):
-            z = [x[receivers], x[senders]]
+            z = [x_i, x[senders]]
         if self.edge_dim and edge_attr is not None:
             z.append(edge_attr)
         z = jnp.concatenate(z, axis=-1)
@@ -284,8 +286,9 @@ class PNAConv(nn.Module):
     @nn.compact
     def __call__(self, x, senders, receivers, edge_attr, edge_mask, node_mask, train=False, row_ptr=None):
         n, f = x.shape
+        x_i = aggregate.gather_sorted(x, receivers, row_ptr, self.axis_name)
         with jax.named_scope(scopes.GATHER):
-            z = [x[receivers], x[senders]]
+            z = [x_i, x[senders]]
         if self.edge_dim and edge_attr is not None:
             z.append(edge_attr)
         z = jnp.concatenate(z, axis=-1)

@@ -27,6 +27,17 @@ and the extrema are ``xla`` too.
 [2] ONE XLA scatter-add over the raw ids with ``indices_are_sorted=True``; the
 count still comes from the boundaries (``row_ptr``, or the two searches).
 
+A conv's receiver-side row gather (:func:`gather_sorted`, PR 46) is decided by
+the same rule, for its BACKWARD (its forward is ``table[ids]`` on every arm):
+
+| sorted arm | ``row_ptr`` | backward of ``table[ids]``: rows under ``WIDE_ROW`` columns | the same, wider rows          |
+|------------|-------------|--------------------------------------------------------------|-------------------------------|
+| on         | yes         | prefix sums of the cotangent rows, no search                 | the scatter-add of [2]        |
+| on         | no          | prefix sums, two searchsorted                                | the scatter-add of [2]        |
+| off        | either      | autodiff's scatter-add (plain indexing, no ``custom_vjp``)   | the same                      |
+
+It stays under ``hydragnn.gather``, forward and backward: no aggregation scope.
+
 The names in quotes are the arms of ``telemetry/scopes.py``: every entry point
 opens ``hydragnn.agg.<what>.<arm>``, so a trace says which arm ran.
 
@@ -43,9 +54,11 @@ catastrophically in float32 on near-degenerate segments, in value and in
 gradient (``tests/test_aggregate.py`` holds both against float64). That
 second pass is a centered scatter-add at every width, told like the wide sums'
 that the ids are sorted. No
-backward here scatters: the sums' and the stats' are gathers through the ids;
-the extrema's is gathers on the ``xla`` arm and, on ``pallas_csr``, a second
-streamed pass down the sorted rows that gathers nothing either.
+backward here scatters in an order the compiler has to find: the sums' and the
+stats' are gathers through the ids; the extrema's is gathers on the ``xla``
+arm and, on ``pallas_csr``, a second streamed pass down the sorted rows that
+gathers nothing either; the receiver-side gathers' is, on the sorted arm, the
+forward sums' own two routes (a scatter-add told its ids are sorted, or none).
 """
 
 from __future__ import annotations
@@ -225,6 +238,69 @@ def fused_segment_softmax(
             logits, segment_ids, num_segments, mask=mask, axis_name=axis_name,
             sum_fn=sum_fn,
         )
+
+
+# ------------------------------------------------- the receiver-side gathers
+def _gather(table, ids):
+    with jax.named_scope(scopes.GATHER):
+        return table[ids]
+
+
+@jax.custom_vjp
+def _gather_sorted(table, ids, row_ptr):
+    # The scope is opened inside and again in the backward, as in
+    # :func:`segment_extrema`: JAX traces each when it pleases.
+    return _gather(table, ids)
+
+
+def _gather_sorted_fwd(table, ids, row_ptr):
+    # A zero-size carrier keeps the table's row count and dtype in the
+    # residuals (neither is a JAX type).
+    carrier = jnp.zeros((table.shape[0], 0), table.dtype)
+    return _gather_sorted(table, ids, row_ptr), (ids, row_ptr, carrier)
+
+
+def _gather_sorted_bwd(res, cot):
+    """The sorted arm's segment sum of the cotangent rows, routed by their
+    width as the forward sums are, under the GATHER scope and no
+    ``hydragnn.agg.*`` one (``graftbench/flops.py`` counts these bytes as the
+    gather's). No mask: a padded row's cotangent lands in the padding node's
+    row, as autodiff's scatter-add lands it. No collective: see
+    :func:`gather_sorted`."""
+    ids, row_ptr, carrier = res
+    with jax.named_scope(scopes.GATHER):
+        flat, unflatten = _flatten_trailing(cot)
+        total, _ = srt._sum_count_sorted(flat, ids, carrier.shape[0], row_ptr)
+        return (
+            unflatten(total.astype(carrier.dtype)),
+            jnp.zeros(ids.shape, jax.dtypes.float0),
+            None if row_ptr is None
+            else jnp.zeros(row_ptr.shape, jax.dtypes.float0),
+        )
+
+
+_gather_sorted.defvjp(_gather_sorted_fwd, _gather_sorted_bwd)
+
+
+def gather_sorted(table, ids, row_ptr=None, axis_name=None):
+    """``table[ids]`` for ids under the batch contract (non-decreasing, the
+    masked rows in the padding row's run): a conv's receiver-side gather.
+
+    The forward is the gather on every arm. Off the sorted arm that is all
+    there is: plain indexing, whose backward autodiff writes as a scatter-add.
+    On it the backward is the segment sum of the cotangent rows over ``ids``
+    on the forward sums' own routes: an undeclared scatter-add has its indices
+    sorted and its ``[E, F]`` updates permuted by the chip's compiler first.
+
+    Under an edge-sharded ``axis_name`` the backward is the LOCAL sum over
+    this shard's rows, with this shard's boundaries: the transpose of the
+    replicated table's use reduces it across shards, as it does for plain
+    indexing, so a ``psum`` here would count it twice. ``axis_name`` is read
+    for those boundaries and nothing else."""
+    if not srt.sorted_enabled():
+        return _gather(table, ids)
+    srt.attach_layout_check(ids)
+    return _gather_sorted(table, ids, _shard_row_ptr(row_ptr, axis_name, ids))
 
 
 # ------------------------------------------------ sum, mean, std, count: stats

@@ -35,7 +35,8 @@ EVAL_STEP = "hydragnn.eval_step"
 ROOTS = (TRAIN_STEP, TRAIN_EPOCH_SCAN, EVAL_STEP)
 
 # Leaves.
-GATHER = "hydragnn.gather"  # node -> edge row gathers (backward: scatter-adds)
+GATHER = "hydragnn.gather"  # node -> edge row gathers (backward: scatter-adds;
+# the receiver side's sorted sums on the sorted arm, aggregate.gather_sorted)
 POOL = "hydragnn.pool"  # graph read-out
 # What depends on positions alone (PaiNN): edge vectors, lengths, the radial
 # basis, the cutoff, and each block's filter Dense over them. Its two

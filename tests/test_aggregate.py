@@ -8,6 +8,7 @@ where those are themselves inexact, against float64.
 non-decreasing, the masked rows in the last (padding) segment's run, whose
 outputs nobody reads. Values and routes, never a time."""
 
+import math
 import os
 import re
 
@@ -250,6 +251,119 @@ def pytest_fused_ops_differentiable_under_shard_map(route):
     g = jax.jit(jax.grad(lambda l: f(l, ids, row_ptr)))(logits)
     g_one = jax.grad(lambda l: sum(terms(l, ids, use(row_ptr), None)))(logits)
     np.testing.assert_allclose(g, g_one, rtol=1e-4, atol=1e-5)
+
+
+def _gather_problem(rng, shape, n=40, e=300, padding=60):
+    """(table [n, *shape], ids, weights [e, *shape]): ids non-decreasing over
+    every THIRD row of the table (so two in three runs are empty), the last
+    ``padding`` rows in the padding row's run (``n - 1``): a long one."""
+    ids = np.sort(rng.integers(0, (n - 1) // 3, size=e) * 3).astype(np.int32)
+    ids[e - padding:] = n - 1
+    table = rng.normal(size=(n,) + shape).astype(np.float32)
+    weights = rng.normal(size=(e,) + shape).astype(np.float32)
+    return jnp.asarray(table), ids, jnp.asarray(weights)
+
+
+def _segment_sum_f64(rows, ids, n):
+    truth = np.zeros((n,) + rows.shape[1:], np.float64)
+    np.add.at(truth, ids, np.asarray(rows, np.float64))
+    return truth
+
+
+@pytest.mark.parametrize(
+    "shape", [(1,), (6,), (128,), (6, 64)], ids=["w1", "w6", "w128", "w384"]
+)
+@pytest.mark.parametrize("arm", ("xla",) + ROUTES)
+def pytest_gather_sorted_is_the_gather_and_its_gradient_the_sorted_sum(
+    arm, shape, monkeypatch
+):
+    """``gather_sorted`` (a conv's receiver-side gather) against plain
+    indexing, at GATv2's and PNA's widths (PNA's input column, GATv2's six
+    denominators, one lane tile, GATv2's ``[6, 64]`` rows), over ids with
+    empty runs and a long padding run. The value is ``table[ids]`` to the bit
+    on every arm. Off the sorted arm the gradient is plain indexing's to the
+    bit; on it the gradient is the forward sums' route by the row's width,
+    held to float64: the prefix sums' bound under ``WIDE_ROW`` columns, a
+    sequential float32 sum's from there up (the error of adding a run's rows
+    in row order onto zero, relative to the largest entry)."""
+    if arm == "xla":
+        monkeypatch.delenv("HYDRAGNN_SEGMENT_SORTED", raising=False)
+    else:
+        monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    table, ids_np, weights = _gather_problem(np.random.default_rng(46), shape)
+    n, ids = table.shape[0], jnp.asarray(ids_np)
+    row_ptr = jnp.asarray(build_row_ptr(ids_np, n)) if arm == "csr" else None
+
+    def got(t):
+        return agg.gather_sorted(t, ids, row_ptr)
+
+    assert np.array_equal(np.asarray(jax.jit(got)(table)), np.asarray(table)[ids_np])
+    grad = jax.jit(jax.grad(lambda t: jnp.sum(got(t) * weights)))(table)
+    plain = jax.jit(jax.grad(lambda t: jnp.sum(t[ids] * weights)))(table)
+    assert grad.dtype == table.dtype and grad.shape == table.shape
+    if arm == "xla":
+        assert np.array_equal(np.asarray(grad), np.asarray(plain))
+        return
+    truth = _segment_sum_f64(weights, ids_np, n)
+    if math.prod(shape) >= 128:
+        err = np.abs(np.asarray(grad, np.float64) - truth).max()
+        assert err <= 1e-6 * np.abs(truth).max(), err
+    else:
+        np.testing.assert_allclose(grad, truth, rtol=_RTOL, atol=_ATOL)
+    # bf16 table in, bf16 gradient out (the sum itself runs in float32).
+    half = jax.grad(lambda t: jnp.sum(got(t).astype(jnp.float32) * weights))(
+        table.astype(jnp.bfloat16)
+    )
+    assert half.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(half, np.float64), truth, rtol=2e-2, atol=2e-2 * np.abs(truth).max()
+    )
+
+
+@pytest.mark.parametrize("route", ROUTES, indirect=True)
+def pytest_gather_sorted_reduces_the_table_gradient_once_under_shard_map(route):
+    """Under an edge-sharded axis the table is whole on every shard and its
+    rows are gathered by each shard's own ids. The backward is the LOCAL sum
+    (this shard's rows, this shard's boundaries) and holds no collective: the
+    transpose of the replicated table's use reduces it across the shards, so
+    the gradient equals plain indexing's in the same harness and the
+    one-device truth, narrow route and wide, and no ``all-reduce`` of the
+    compiled program sits under ``hydragnn.gather``."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("graph",))
+    use = (lambda ptr: ptr) if route == "csr" else (lambda ptr: None)
+    for shape in ((6,), (128,)):
+        table, ids_np, weights = _gather_problem(
+            np.random.default_rng(7), shape, e=256, padding=40
+        )
+        n, ids = table.shape[0], jnp.asarray(ids_np)
+        row_ptr = jnp.asarray(build_row_ptr(ids_np, n))
+
+        def sharded(gather):
+            def local(t, ids_, w_, ptr):
+                return jax.lax.psum(jnp.sum(gather(t, ids_, ptr) * w_), "graph")
+
+            f = jax.shard_map(
+                local, mesh=mesh, in_specs=(P(), P("graph"), P("graph"), P()),
+                out_specs=P(), check_vma=False,
+            )
+            return jax.jit(jax.grad(lambda t: f(t, ids, weights, row_ptr)))
+
+        program = sharded(lambda t, i, ptr: agg.gather_sorted(t, i, use(ptr), "graph"))
+        grad = program(table)
+        plain = sharded(lambda t, i, ptr: t[i])(table)
+        np.testing.assert_allclose(grad, plain, rtol=_RTOL, atol=_ATOL)
+        np.testing.assert_allclose(
+            grad, _segment_sum_f64(weights, ids_np, n), rtol=_RTOL, atol=_ATOL
+        )
+        text = program.lower(table).compile().as_text()
+        reduces = [
+            line.split("metadata=")[1] for line in text.splitlines()
+            if re.search(r"\sall-reduce\(", line.split("metadata=")[0])
+        ]
+        assert "hydragnn.gather" in text and reduces
+        assert not [r for r in reduces if "hydragnn.gather" in r], reduces
 
 
 @pytest.mark.parametrize("case", certify.WIDE_CASES)

@@ -255,10 +255,11 @@ def pytest_wide_sums_are_named_scatter_sorted(conv, route, monkeypatch):
 
 
 def pytest_custom_vjp_backward_carries_the_scope(monkeypatch):
-    """``segment_sum_count_csr``, ``_stats`` and ``segment_extrema`` trace
-    their ``_bwd`` apart from the call site: the backward gathers carry the
-    forward's scope all the same, and so does the extrema's backward kernel,
-    which gathers nothing (interpreted here: a loop over its grid)."""
+    """``segment_sum_count_csr``, ``_stats``, ``segment_extrema`` and
+    ``gather_sorted`` trace their ``_bwd`` apart from the call site: the
+    backward gathers carry the forward's scope all the same, and so does the
+    extrema's backward kernel, which gathers nothing (interpreted here: a
+    loop over its grid)."""
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
 
     def check(names, *held, op="gather"):
@@ -271,6 +272,13 @@ def pytest_custom_vjp_backward_carries_the_scope(monkeypatch):
 
     names = _op_names(_compiled_text("PNA", _batch()))
     check(names, scopes.agg("stats", "csr"), scopes.agg("mean", "csr"))
+    # PR 46: ``aggregate.gather_sorted``'s backward is a sorted sum (eight
+    # columns here: the prefix sums' row fetches, the only GATHERS a gather's
+    # backward can hold) booked to the gather it is the backward of, written
+    # once, under no aggregation scope.
+    check(names, scopes.GATHER)
+    back = [n for o, n in names if o and scopes.GATHER in n and "transpose(" in n]
+    assert not [n for n in back if "hydragnn.agg." in n], back
     # The kernels' forward (its row fetches here) and backward both carry the
     # arm's name, and the other arm is gone.
     kernel = scopes.agg("extrema", "pallas_csr")
@@ -346,7 +354,8 @@ def pytest_gat_gathers_flat_rows_once_a_layer(route, monkeypatch):
     every gather and scatter under ``hydragnn.gather`` moves rank-2 rows; a
     conv layer has exactly two row gathers of width h·f forward
     (``x_src[senders]``, ``x_dst[receivers]``) and two scatter-adds into
-    ``[N_pad, h·f]`` backward; and NO instruction anywhere, inside a fusion or
+    ``[N_pad, h·f]`` backward (one on the sorted arm since PR 46: the
+    senders'); and NO instruction anywhere, inside a fusion or
     out, has the shape ``[E_pad, h, f]``: a ``[h, f]`` row pads to a whole
     (8, 128) tile on the TPU, and a reshape that brings it back fails here
     (stricter than "no fusion outputs one": the CPU compiler this runs on
@@ -378,9 +387,13 @@ def pytest_gat_gathers_flat_rows_once_a_layer(route, monkeypatch):
             [(e_pad, heads * f)] * 2 + [(e_pad, heads)] * 2
         ), (layer, forward)  # x_j, x_i; the softmax's shift and denominator
         backward = rows[(layer, True, "scatter")]
+        # The shift carries no gradient. On the sorted arm the receiver side
+        # (``x_dst``, the denominators) goes back as sorted sums, narrow here
+        # (``aggregate.gather_sorted``): the senders' scatter-add is alone.
         assert sorted(backward) == sorted(
-            [(n_pad, heads * f)] * 2 + [(n_pad, heads)]
-        ), (layer, backward)  # the shift carries no gradient
+            [(n_pad, heads * f)] * 2 + [(n_pad, heads)] if route == "xla"
+            else [(n_pad, heads * f)]
+        ), (layer, backward)
         assert (layer, False, "scatter") not in rows
     rank3 = re.compile(rf"f32\[{e_pad},(1,)?{heads},(1,)?{f}\]")
     assert not rank3.search(text), rank3.search(text).group(0)

@@ -36,6 +36,15 @@ def _sorts(text, scope):
     ]
 
 
+def _backward_scatters(text, scope):
+    """The ``scatter`` instructions of the backward pass under ``scope``."""
+    return [
+        line for line in text.splitlines()
+        if re.search(r"\sscatter\(", line.split("metadata=")[0])
+        and re.search(rf"transpose\([^\"]*{re.escape(scope)}", line)
+    ]
+
+
 def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch):
     """One ``GATv2Conv``, forward and backward, at the shapes of the cell
     ``gatv2_h64x6_md17like.train_b512`` (11264 × 215552, six heads of 64) on
@@ -43,7 +52,14 @@ def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch
     ``f32[215552,384]``, the backward holds exactly two scatter-adds into
     ``f32[11264,384]``, and no array with a ``[6,64]`` row or a transposed
     ``[215552,384]{0,1}`` is written anywhere (PERF.md §6, PR 24: a reshape
-    inside the per-head reduce makes the chip's compiler write both)."""
+    inside the per-head reduce makes the chip's compiler write both).
+
+    PR 46, the receiver-side gathers' backward (``aggregate.gather_sorted``):
+    of those two scatter-adds only the senders' has its indices sorted for it
+    (ONE ``sort`` under ``hydragnn.gather``; ``x_dst[receivers]`` goes back as
+    a scatter-add told its ids are sorted), and no scatter into
+    ``f32[11264,6]`` is left under that scope: ``denom[receivers]`` goes back
+    down the prefix sums, as the denominators came."""
     from hydragnn_tpu.models.convs import GATv2Conv
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
@@ -101,7 +117,12 @@ def pytest_gatv2_conv_at_cell_size_has_no_rank3_edge_array(one_chip, monkeypatch
         and "hydragnn.agg.sum.scatter_sorted" in line
     ]
     assert len(summed) == 1, summed
-    assert _sorts(text, "hydragnn.gather")
+    assert len(_sorts(text, "hydragnn.gather")) == 1, _sorts(text, "hydragnn.gather")
+    back = [
+        re.search(r"= (f32\[[\d,]+\])", line).group(1)
+        for line in _backward_scatters(text, "hydragnn.gather")
+    ]
+    assert back == [f"f32[{n},{h * f}]"] * 2, back
     assert not _sorts(text, "hydragnn.agg.sum.scatter_sorted")
     assert "hydragnn.agg.sum.csr" in text
     assert not re.search(rf"f32\[\d+,\d+,{h * f}\]\S* reduce-window\(", text)
@@ -207,7 +228,12 @@ def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monke
     ``hydragnn.agg.extrema.pallas_csr``; no ``[E, f]`` row gather is left
     under that scope; and no scatter that combines by minimum or maximum is
     left anywhere (the one scatter into ``f32[18944,f]`` that stays is the
-    centered sum of squares of ``std``)."""
+    centered sum of squares of ``std``).
+
+    PR 46: of the two backward scatter-adds into ``f32[18944,256]`` under
+    ``hydragnn.gather`` only the senders' keeps a ``sort`` (``x[receivers]``
+    goes back told its ids are sorted); the input layer's one column goes
+    back down the prefix sums, so one scatter is left there, the senders'."""
     from hydragnn_tpu.models.convs import PNAConv
     from hydragnn_tpu.ops import segment as seg
     from hydragnn_tpu.telemetry import scopes
@@ -288,7 +314,12 @@ def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monke
         assert len(adds) == 1 and narrow in text and wide not in text, adds
         assert windows, "the one-column sum's cumsum is gone"
     assert not _sorts(text, "hydragnn.agg.stats")
-    assert f == 1 or _sorts(text, scopes.GATHER)  # what a sort looks like here
+    # what a sort looks like here: the senders' scatter-add has its own (one
+    # column is scattered as rank 1, and no index is sorted for that)
+    assert len(_sorts(text, scopes.GATHER)) == (f > 1), _sorts(text, scopes.GATHER)
+    back = _backward_scatters(text, scopes.GATHER)
+    assert len(back) == (2 if f > 1 else 1), back
+    assert all(re.search(rf"= f32\[{n}(,{f})?\]", line) for line in back), back
 
 
 def pytest_lfm2_attention_at_cell_size_has_no_n_by_n_array(one_chip):
