@@ -11,9 +11,18 @@ JAX's persistent compilation cache is OFF for the session, and for every
 child process a test starts: the entry points place it at a fixed path in the
 checkout (hydragnn_tpu/cache/jaxcache.py), which would turn the cold compiles
 that tests/test_compile_cache.py times into hits left by an earlier run.
+
+Every test has a time limit of its own (``TIME_LIMIT_S``, or what its
+``@pytest.mark.time_limit(seconds)`` says): a test that runs into it FAILS by
+name with every thread's stack on stderr and the run goes on, where a test
+that ran for ever used to cost the whole run its clock.
 """
 
+import faulthandler
 import os
+import signal
+import sys
+import threading
 
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
@@ -23,10 +32,52 @@ os.environ["XLA_FLAGS"] = (
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import jax
+import pytest
 
 # HYDRAGNN_TPU_TESTS=1 leaves the real accelerator as the default backend.
 if os.environ.get("HYDRAGNN_TPU_TESTS") != "1":
     jax.config.update("jax_platforms", "cpu")
+
+
+# One test's seconds, its function-scoped fixtures included. A subprocess a
+# test waits for gets a shorter wait, so that the child is killed and its
+# output shown before this fires.
+TIME_LIMIT_S = 300.0
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    """Arm ``ITIMER_REAL`` round the test. The handler runs on the main
+    thread, between two bytecodes of whatever the test is doing there (an
+    xdist worker runs its tests on its main thread; a wait on a lock, a queue
+    or a child process is interrupted): it dumps every thread's stack and
+    raises pytest's own failure, so the test fails by name and the next one
+    runs."""
+    marker = request.node.get_closest_marker("time_limit")
+    seconds = float(marker.args[0]) if marker else TIME_LIMIT_S
+    if threading.current_thread() is not threading.main_thread():
+        yield  # signals are the main thread's: nothing to arm here
+        return
+
+    def ran_out(signum, frame):
+        try:
+            faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+        except (AttributeError, OSError, ValueError):  # a stderr with no descriptor
+            faulthandler.dump_traceback(file=sys.__stderr__, all_threads=True)
+        pytest.fail(
+            f"{request.node.nodeid} ran into its time limit of {seconds:g} s "
+            "(tests/conftest.py TIME_LIMIT_S; @pytest.mark.time_limit(seconds) "
+            "for a test that needs more); every thread's stack is on stderr",
+            pytrace=False,
+        )
+
+    before = signal.signal(signal.SIGALRM, ran_out)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
 
 
 def pytest_collection_modifyitems(config, items):
@@ -34,8 +85,6 @@ def pytest_collection_modifyitems(config, items):
     analog of the reference's ``@pytest.mark.mpi_skip`` under ``mpirun -n 2``
     (.github/workflows/CI.yml:47-52): those tests race on shared ./logs and
     ./serialized_dataset paths when every rank runs them."""
-    import pytest
-
     world = int(
         os.environ.get("HYDRAGNN_WORLD_SIZE")
         or os.environ.get("OMPI_COMM_WORLD_SIZE")
@@ -62,7 +111,13 @@ def pytest_collection_modifyitems(config, items):
     # matrix AND checkpoint-reload/predict (train → save → fresh model →
     # load_existing_model → evaluate under 2 ranks).
     world_safe = {
-        "test_graphs.py",
+        "test_graphs_pna.py",
+        "test_graphs_pna_multihead.py",
+        "test_graphs_cgcnn.py",
+        "test_graphs_sage.py",
+        "test_graphs_gin.py",
+        "test_graphs_gat.py",
+        "test_graphs_mfc.py",
         "test_model_loadpred.py",
         "test_resume_2proc.py",
         "test_predict_2proc.py",

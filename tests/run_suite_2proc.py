@@ -5,15 +5,16 @@ reference CI's distributed pass ``mpirun -n 2 python -m pytest --with-mpi``
 Each rank runs pytest over tests/ with OMPI-style env; ``setup_ddp`` inside the
 high-level API rendezvouses the two processes via jax.distributed, and
 run_training/run_prediction auto-shard over the global 2-device mesh, so the
-full convergence matrix (tests/test_graphs.py — every conv family, unchanged
-single-process accuracy thresholds) trains data-parallel. Serial-only tests are
-skipped by tests/conftest.py, exactly like the reference's @pytest.mark.mpi_skip.
+full convergence matrix (tests/test_graphs_<family>.py, one file a conv family
+— every conv family, unchanged single-process accuracy thresholds) trains
+data-parallel. Serial-only tests are skipped by tests/conftest.py, exactly
+like the reference's @pytest.mark.mpi_skip.
 
     python tests/run_suite_2proc.py [extra pytest args...]
 
 A custom selection (anything other than the default ``tests/``) additionally
 gets the PNA single-head convergence cell appended
-(tests/test_graphs.py::pytest_train_model[ci.json-PNA], reference-CI
+(tests/test_graphs_pna.py::pytest_train_model[ci.json-PNA], reference-CI
 thresholds), so a narrowed 2-process run is never plumbing-only — it always
 trains at least one real model data-parallel to convergence, mirroring the
 reference CI's ``mpirun -n 2`` coverage. Opt out with --no-convergence-cell.
@@ -64,11 +65,11 @@ def main() -> int:
     # The real-convergence guarantee (docstring above): a narrowed selection
     # still trains PNA single-head to the reference thresholds under the
     # 2-process mesh. The full default selection already contains it.
-    convergence_cell = "tests/test_graphs.py::pytest_train_model[ci.json-PNA]"
+    convergence_cell = "tests/test_graphs_pna.py::pytest_train_model[ci.json-PNA]"
     if (
         argv
         and not args.no_convergence_cell
-        and not any(a.startswith("tests/test_graphs.py") for a in argv)
+        and not any(a.startswith("tests/test_graphs_") for a in argv)
         # A -k expression would also filter the appended node id; the caller
         # controls selection semantics then, so leave it untouched.
         and "-k" not in argv

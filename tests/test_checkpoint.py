@@ -1,7 +1,12 @@
 """Checkpoint subsystem units: atomic single-file save/restore round-trip and
 the periodic mid-training save (our documented improvement over the reference's
 end-of-run-only save, /root/reference/hydragnn/utils/model.py:35-47 +
-run_training.py:120)."""
+run_training.py:120).
+
+The two crash drills, each a training in a child process that is killed and
+started again, are files of their own (tests/test_checkpoint_supervisor.py,
+tests/test_checkpoint_crash_resume.py): ``--dist loadfile`` gives a file to
+ONE worker, and a file of few tests starts last."""
 
 import glob
 import pytest
@@ -157,142 +162,3 @@ def pytest_keep_last_k_retention_manifest_and_tmp_cleanup(tmp_path):
     (run_dir / "junk.tmp").write_bytes(b"x")
     removed = cleanup_stale_checkpoint_tmp(str(run_dir))
     assert len(removed) == 2 and not glob.glob(str(run_dir / "*.tmp"))
-
-
-def pytest_supervisor_restarts_killed_scan_run(tmp_path, monkeypatch):
-    """Crash-resume as a first-class API: run_training(supervise=True) with an
-    injected kill@K fault (HYDRAGNN_FAULTS) on the SCAN epoch path (mesh=None,
-    no profiler — the production single-device path). The child dies by
-    SIGKILL mid-run, the supervisor restarts it, Training.resume picks up the
-    periodic checkpoint, and the restart metadata (logs/<name>/supervisor.json)
-    records the death + completion."""
-    import json
-    import signal
-
-    from hydragnn_tpu.faults import read_supervisor_meta
-    from hydragnn_tpu.run_training import run_training
-    from hydragnn_tpu.utils.model import load_checkpoint_meta
-    from tests.deterministic_graph_data import deterministic_graph_data
-
-    monkeypatch.chdir(tmp_path)
-    monkeypatch.setenv("SERIALIZED_DATA_PATH", str(tmp_path))
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")  # children must stay on CPU
-    # kill@2: the scan path feeds one train batch per epoch here (24 samples,
-    # batch 32), so the third fed TRAIN batch = epoch 2 — after the epoch-1
-    # and epoch-2 periodic checkpoints landed. Fires only in incarnation 0
-    # (HYDRAGNN_RESTART_COUNT gating), so the restart completes.
-    monkeypatch.setenv("HYDRAGNN_FAULTS", "kill@2")
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(repo, "tests/inputs/ci.json")) as f:
-        config = json.load(f)
-    config["Visualization"] = {"create_plots": False}
-    tr = config["NeuralNetwork"]["Training"]
-    tr["num_epoch"] = 4
-    tr["periodic_checkpoint_every"] = 1
-    for split, cnt in {"train": 24, "test": 8, "validate": 8}.items():
-        p = f"dataset/unit_test_singlehead_{split}"
-        os.makedirs(p, exist_ok=True)
-        deterministic_graph_data(p, number_configurations=cnt)
-        config["Dataset"]["path"][split] = p
-
-    meta = run_training(dict(config), supervise=True, max_restarts=2)
-
-    assert meta["completed"] is True
-    assert meta["restarts"] == 1, meta
-    assert len(meta["attempts"]) == 2
-    # First incarnation died by SIGKILL; the restart exited clean.
-    assert meta["attempts"][0]["returncode"] == -signal.SIGKILL
-    assert meta["attempts"][1]["returncode"] == 0
-    # The persisted metadata matches what the API returned.
-    from hydragnn_tpu.utils.config_utils import get_log_name_config
-
-    log_name = get_log_name_config(config)
-    on_disk = read_supervisor_meta(log_name)
-    assert on_disk["restarts"] == 1 and on_disk["completed"] is True
-    # The run actually finished all epochs after resume.
-    assert load_checkpoint_meta(log_name)["epoch"] == 4
-
-
-def pytest_crash_resume_after_kill(tmp_path, monkeypatch):
-    """Training.resume (extension over the reference's weights-only warm
-    start, SURVEY.md §5.3/5.4): a run SIGKILLed after its first periodic
-    checkpoint resumes at the saved epoch — same config, same log name — with
-    scheduler decision state and loss history intact, and finishes with the
-    full history length."""
-    import json
-    import signal
-    import subprocess
-    import sys
-    import time as _time
-
-    from hydragnn_tpu.run_training import run_training
-    from hydragnn_tpu.utils.model import load_checkpoint_meta
-    from tests.deterministic_graph_data import deterministic_graph_data
-
-    monkeypatch.chdir(tmp_path)
-    monkeypatch.setenv("SERIALIZED_DATA_PATH", str(tmp_path))
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(repo, "tests/inputs/ci.json")) as f:
-        config = json.load(f)
-    config["Visualization"] = {"create_plots": False}
-    tr = config["NeuralNetwork"]["Training"]
-    # Long enough that the run cannot finish inside the kill poll below: an
-    # epoch of these 48 graphs is milliseconds once its one scan program a
-    # batch shape is compiled (nothing compiles after the first epoch).
-    epochs = 60
-    tr["num_epoch"] = epochs
-    tr["periodic_checkpoint_every"] = 2
-    tr["resume"] = 1
-    for split, cnt in {"train": 48, "test": 16, "validate": 16}.items():
-        p = f"dataset/unit_test_singlehead_{split}"
-        os.makedirs(p, exist_ok=True)
-        deterministic_graph_data(p, number_configurations=cnt)
-        config["Dataset"]["path"][split] = p
-    with open("config.json", "w") as f:
-        json.dump(config, f)
-
-    script = (
-        "import os, sys\n"
-        "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'\n"
-        "import jax; jax.config.update('jax_platforms', 'cpu')\n"
-        f"sys.path.insert(0, {repo!r})\n"
-        "import hydragnn_tpu\n"
-        "hydragnn_tpu.run_training('config.json')\n"
-    )
-    proc = subprocess.Popen(
-        [sys.executable, "-c", script], cwd=str(tmp_path),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        env=dict(os.environ, SERIALIZED_DATA_PATH=str(tmp_path)),
-    )
-    # Kill the instant the first periodic checkpoint lands (epoch 2 of 60).
-    deadline = _time.time() + 600
-    ckpt = None
-    while _time.time() < deadline and proc.poll() is None:
-        if os.path.isdir("logs"):
-            hits = [
-                d for d in os.listdir("logs")
-                if os.path.exists(f"logs/{d}/{d}.pk")
-            ]
-            if hits:
-                ckpt = hits[0]
-                break
-        _time.sleep(0.05)
-    assert ckpt is not None, "no periodic checkpoint appeared before timeout"
-    proc.send_signal(signal.SIGKILL)
-    proc.wait()
-
-    meta = load_checkpoint_meta(ckpt)
-    if meta["epoch"] >= epochs:  # machine outran the 50 ms kill poll — no signal
-        pytest.skip("training finished before SIGKILL landed")
-    assert 0 < meta["epoch"] < epochs  # genuinely mid-run
-    assert meta["scheduler"] is not None
-    assert len(meta["history"]["total_loss_train"]) == meta["epoch"]
-
-    # Same config, same log name: resume completes the remaining epochs.
-    history = run_training(dict(config))
-    assert len(history["total_loss_train"]) == epochs
-    assert load_checkpoint_meta(ckpt)["epoch"] == epochs
-
-    # Resuming a finished run trains zero further epochs.
-    history2 = run_training(dict(config))
-    assert len(history2["total_loss_train"]) == epochs

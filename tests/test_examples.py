@@ -1,13 +1,17 @@
 """Example smoke tests (reference tests/test_examples.py:18-26): run each
 example script in a subprocess and require exit 0. The wrapper forces JAX onto
 host CPU before the example imports jax (the env pins an external platform that
-can only be overridden in-process)."""
+can only be overridden in-process).
+
+The cases live in one file an example (``tests/test_examples_<name>.py``):
+``--dist loadfile`` gives a file to ONE worker and starts the files with the
+fewest tests last, so five examples in one file were one worker's 400-700 s
+at the end of the run with five workers idle. This module holds what they
+share and no test of its own."""
 
 import os
 import subprocess
 import sys
-
-import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _WRAPPER = """
@@ -18,14 +22,12 @@ jax.config.update('jax_platforms', 'cpu')
 import runpy
 runpy.run_path({script!r}, run_name='__main__')
 """
+# Under the test's own limit (tests/conftest.py TIME_LIMIT_S), so that a stuck
+# example is killed and its output shown before the limit fails the test.
+_WAIT_S = 270
 
 
-@pytest.mark.parametrize(
-    "example",
-    ["qm9", "md17", "lsms", "eam", os.path.join("ising_model", "ising_model")],
-)
-@pytest.mark.mpi_skip()
-def pytest_examples(example):
+def run_example(example):
     if os.sep not in example:
         example = os.path.join(example, example)
     script = os.path.join(_REPO, "examples", example + ".py")
@@ -35,7 +37,7 @@ def pytest_examples(example):
         cwd=_REPO,
         capture_output=True,
         text=True,
-        timeout=1200,
+        timeout=_WAIT_S,
     )
     assert result.returncode == 0, (
         f"{example} failed:\n{result.stdout[-2000:]}\n{result.stderr[-2000:]}"

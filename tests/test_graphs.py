@@ -2,14 +2,22 @@
 /root/reference/tests/test_graphs.py:21-196): train each conv family on the
 synthetic deterministic dataset through the full high-level API
 (run_training → run_prediction), then assert the SAME accuracy thresholds the
-reference CI enforces (BASELINE.md)."""
+reference CI enforces (BASELINE.md).
 
+This module holds what the matrix shares (the thresholds, the training cell,
+the dataset generator) and no test of its own: the 15 cases live in one file
+a conv family (``tests/test_graphs_<family>.py``; PNA's four in two), because
+``--dist loadfile`` gives a file to ONE worker and starts the files with the
+fewest tests last. Cases that share a log name (a family's ``ci.json`` with
+and without edge lengths) stay in one file: two workers must never train one
+``./logs/<name>`` at once."""
+
+import fcntl
 import json
 import os
 import sys
 
 import numpy as np
-import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -179,16 +187,23 @@ def ensure_raw_datasets(config, num_samples_tot=500):
                     "validate": int(num_samples_tot * (1 - perc_train) * 0.5),
                 }[dataset_name]
                 os.makedirs(data_path, exist_ok=True)
-                # One file per configuration: any other count means a crashed
-                # earlier generation left a partial directory — regenerate
-                # rather than fingerprinting incomplete data as "done".
-                existing = os.listdir(data_path)
-                if len(existing) != num_samples:
-                    for name in existing:
-                        os.remove(os.path.join(data_path, name))
-                    deterministic_graph_data(
-                        data_path, number_configurations=num_samples
-                    )
+                # Rank 0 of EVERY xdist worker comes through here, and in a
+                # fresh checkout several find the directory empty at once:
+                # the count and the generation are one worker's at a time,
+                # and the one that waited uses what the first made.
+                with open(f"{sentinel_base}.lock", "w") as lock:
+                    fcntl.flock(lock, fcntl.LOCK_EX)
+                    # One file per configuration: any other count means a
+                    # crashed earlier generation left a partial directory —
+                    # regenerate rather than fingerprinting incomplete data
+                    # as "done".
+                    existing = os.listdir(data_path)
+                    if len(existing) != num_samples:
+                        for name in existing:
+                            os.remove(os.path.join(data_path, name))
+                        deterministic_graph_data(
+                            data_path, number_configurations=num_samples
+                        )
                 with open(sentinel, "w") as f:
                     f.write(_dir_state(data_path) or "")
             else:
@@ -207,19 +222,3 @@ def ensure_raw_datasets(config, num_samples_tot=500):
                     if _time.time() > deadline:
                         raise TimeoutError(f"rank 0 never finished {data_path}")
                     _time.sleep(0.1)
-
-
-@pytest.mark.parametrize("model_type", ["SAGE", "GIN", "GAT", "MFC", "PNA", "CGCNN"])
-@pytest.mark.parametrize("ci_input", ["ci.json", "ci_multihead.json"])
-def pytest_train_model(model_type, ci_input, overwrite_data=False):
-    unittest_train_model(model_type, ci_input, False, overwrite_data)
-
-
-@pytest.mark.parametrize("model_type", ["PNA", "CGCNN"])
-def pytest_train_model_lengths(model_type, overwrite_data=False):
-    unittest_train_model(model_type, "ci.json", True, overwrite_data)
-
-
-@pytest.mark.parametrize("model_type", ["PNA"])
-def pytest_train_model_vectoroutput(model_type, overwrite_data=False):
-    unittest_train_model(model_type, "ci_vectoroutput.json", True, overwrite_data)

@@ -37,7 +37,9 @@ from hydragnn_tpu.models.layers import scaled_ids  # noqa: E402
 from hydragnn_tpu.ops import block_attention, selective_scan  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
-from tests.test_lfm2 import _collate, _sequences  # noqa: E402
+from tests.test_lfm2 import (  # noqa: E402, F401
+    _collate, _sequences, compiled, init_variables, programs,
+)
 
 V, D, LAYERS = sibling.V, 32, 4  # the sibling's sequences: ids under its V
 CONFIG = os.path.join(REPO, "graftbench", "configs", "jamba2_3b.json")
@@ -60,14 +62,16 @@ def _model(layers=LAYERS, **arch):
     )
 
 
-def _logits(model, variables, batch):
-    return np.asarray(model.apply({"params": variables["params"]}, batch, train=False)[0])
+def _logits(model, variables, batch, purpose="logits"):
+    return np.asarray(compiled(model, purpose, lambda params, batch: model.apply(
+        {"params": params}, batch, train=False
+    ))(variables["params"], batch)[0])
 
 
 def _scores(model, variables, batch):
-    return np.asarray(
-        model.apply({"params": variables["params"]}, batch, method=HydraGNN.score_tokens)[0]
-    )
+    return np.asarray(compiled(model, "scores", lambda params, batch: model.apply(
+        {"params": params}, batch, method=HydraGNN.score_tokens
+    ))(variables["params"], batch)[0])
 
 
 def _documents(graphs):
@@ -82,7 +86,7 @@ def setup():
     model = _model()
     graphs = _sequences(LENGTHS)
     batch = _collate(graphs)
-    variables = shaken(init_model_variables(model, batch), 45)
+    variables = shaken(init_variables(model, batch), 45)
     return model, graphs, batch, variables
 
 
@@ -107,7 +111,7 @@ def pytest_one_block_of_a_kind_against_the_reference(kind):
     model = _model(layers=1, attn_layer_offset=1 if kind == "mamba" else 0)
     graphs = _sequences(LENGTHS, seed=3)
     batch = _collate(graphs)
-    variables = shaken(init_model_variables(model, batch), 7)
+    variables = shaken(init_variables(model, batch), 7)
     block = variables["params"]["conv_0"]
     assert ("mamba" in block) == (kind == "mamba") and ("self_attn" in block) == (kind != "mamba")
     got = _logits(model, variables, batch)
@@ -193,7 +197,8 @@ def pytest_a_dropped_reset_shows(setup, monkeypatch):
         lambda u, dt, a, b, c, skip, node_graph: scan(
             u, dt, a, b, c, skip, jnp.zeros_like(node_graph)),
     )
-    wrong = _logits(model, variables, batch)
+    # Traced anew under the patch: the module's program was traced before it.
+    wrong = _logits(model, variables, batch, purpose="logits with no reset")
     assert np.array_equal(wrong[:5], right[:5])
     for _, rows in list(_documents(graphs))[1:]:
         assert np.abs(wrong[rows] - right[rows]).max() > 1e-2
@@ -207,7 +212,9 @@ def pytest_the_tied_head_is_the_embeddings_table(setup):
     params = variables["params"]
     assert not [k for k in params if k.startswith("head")]
     assert set(params) == {f"conv_{i}" for i in range(LAYERS)} | {"conv_embed", "conv_norm"}
-    h = np.asarray(model.apply({"params": params}, batch, method=HydraGNN._encode_tokens))
+    h = np.asarray(jax.jit(
+        lambda params: model.apply({"params": params}, batch, method=HydraGNN._encode_tokens)
+    )(params))
     table = np.asarray(params["conv_embed"]["embedding"])
     assert table.shape == (V, D)
     assert np.allclose(_logits(model, variables, batch), h @ table.T, atol=1e-5)
@@ -288,7 +295,7 @@ def pytest_entry_points_refuse_what_the_family_cannot_run():
     from hydragnn_tpu.serve import InferenceEngine
 
     model = _model()
-    variables = init_model_variables(model, _collate(_sequences((5,))))
+    variables = init_variables(model, _collate(_sequences((5,))))
     with pytest.raises(ValueError, match="float32 node"):
         InferenceEngine(model, variables, precision="bf16", autostart=False)
 
@@ -419,7 +426,7 @@ def pytest_a_stack_with_no_scan_counts_none():
 
     model = sibling._model()
     graphs = _sequences((5, 9))
-    variables = init_model_variables(model, _collate(graphs))
+    variables = init_variables(model, _collate(graphs))
     with InferenceEngine(model, variables, max_batch_graphs=2, max_delay_ms=1.0,
                          bucket_ladder=[32], warmup=True) as eng:
         assert eng._scans is False

@@ -8,7 +8,12 @@ routing taken from the program and held to the margins; the eight shares of a
 routed layer, the shared expert counted once, adding up to the uncut layer;
 the band against the triangle at the published window; heads by layer; YaRN's
 frequencies by hand; graph boundaries, padding, the entry points, the scopes,
-``run_training``. Values and counts, never a time."""
+``run_training``. Values and counts, never a time.
+
+Two parts of it are files of their own, because ``--dist loadfile`` gives a
+file to ONE worker: the compact row arrays inside the model
+(tests/test_laguna_compact.py) and ``run_training``
+(tests/test_laguna_train.py); the sizes above are theirs too."""
 
 import copy
 import json
@@ -26,15 +31,15 @@ sys.path.insert(0, REPO)
 from graftbench.drivers.train_epochs import shaken  # noqa: E402
 from graftbench.families import laguna as plain  # noqa: E402
 from hydragnn_tpu.graphs import collate_graphs  # noqa: E402
-from hydragnn_tpu.models import create_model, init_model_variables  # noqa: E402
+from hydragnn_tpu.models import create_model  # noqa: E402
 from hydragnn_tpu.models import (  # noqa: E402
     laguna, token_attention, token_common, token_routed,
 )
-from hydragnn_tpu.models.loss import multihead_rmse_loss  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
-from tests.test_lfm2 import (  # noqa: E402
-    _collate, _forward, _rows, _sequences, assert_bit_equal,
+from tests.test_lfm2 import (  # noqa: E402, F401
+    _collate, _forward, _rows, _sequences, init_variables, loss_and_grads, loss_of,
+    programs,
 )
 
 V, D, LAYERS = sibling.V, 32, 5  # the sibling's sequences: ids under its V
@@ -67,7 +72,7 @@ def setup():
     model = _model()
     graphs = _sequences((5, 9, 12))
     batch = _collate(graphs)
-    variables = shaken(init_model_variables(model, batch), 33)
+    variables = shaken(init_variables(model, batch), 33)
     return model, graphs, batch, variables
 
 
@@ -102,13 +107,7 @@ def pytest_rematerialized_blocks_compute_the_same(setup):
     assert np.array_equal(routing["conv_3"]["chosen"], routing2["conv_3"]["chosen"])
 
     def loss(m):
-        def f(p):
-            out = m.apply({"params": p}, batch, train=True)
-            return multihead_rmse_loss(
-                out, batch, m.output_type, m.task_weights,
-                head_loss=m.head_loss, class_minmax=m.class_minmax,
-            )[0]
-        return jax.grad(f)(jax.tree_util.tree_map(jnp.asarray, variables["params"]))
+        return loss_and_grads(m, variables["params"], batch, True)[1]
 
     for a, b in zip(*(jax.tree_util.tree_leaves(loss(m)) for m in (model, again))):
         assert np.abs(np.asarray(a) - np.asarray(b)).max() <= 1e-6 * max(
@@ -142,13 +141,6 @@ def pytest_loss_and_every_gradient_against_the_plain_reference(setup):
     _, routing, _ = _forward(model, variables, batch)
     params = jax.tree_util.tree_map(jnp.asarray, variables["params"])
 
-    def program(p):
-        out = model.apply({"params": p}, batch, train=False)
-        return multihead_rmse_loss(
-            out, batch, model.output_type, model.task_weights,
-            head_loss=model.head_loss, class_minmax=model.class_minmax,
-        )[0]
-
     def reference(p):
         total, start = 0.0, 0
         for g in graphs:
@@ -161,10 +153,11 @@ def pytest_loss_and_every_gradient_against_the_plain_reference(setup):
             total = total - logp[np.arange(g.num_nodes), label].sum()
         return total / start
 
+    # Both sides one program each, traced at the precision they are held to
+    # (the reference's own code, with the routing it is given as numbers).
     with jax.default_matmul_precision("highest"):
-        (got, g_got), (want, g_want) = (
-            jax.value_and_grad(f)(params) for f in (program, reference)
-        )
+        got, g_got = jax.jit(jax.value_and_grad(loss_of(model, False)))(params, batch)
+        want, g_want = jax.jit(jax.value_and_grad(reference))(params)
     assert abs(float(got) - float(want)) < 1e-5 * abs(float(want))
     flat_got, flat_want = (
         jax.tree_util.tree_leaves_with_path(t) for t in (g_got, g_want)
@@ -249,6 +242,7 @@ def pytest_the_band_at_the_published_window():
     array, the second starting mid-block."""
     rng = np.random.default_rng(2)
     h, kv, hd, w = 4, 2, 8, 512
+    attention = jax.jit(token_attention.segment_causal_attention, static_argnames="window")
     for lengths in ((512,), (300, 900)):
         n = sum(lengths)
         seg = np.repeat(np.arange(len(lengths)), lengths).astype(np.int32)
@@ -256,8 +250,8 @@ def pytest_the_band_at_the_published_window():
             jnp.asarray(rng.normal(size=(n, heads, hd)).astype(np.float32))
             for heads in (h, kv, kv)
         )
-        band = np.asarray(token_attention.segment_causal_attention(q, k, v, jnp.asarray(seg), window=w))
-        full = np.asarray(token_attention.segment_causal_attention(q, k, v, jnp.asarray(seg)))
+        band = np.asarray(attention(q, k, v, jnp.asarray(seg), window=w))
+        full = np.asarray(attention(q, k, v, jnp.asarray(seg)))
         assert np.abs(band - _band_by_hand(q, k, v, seg, w)).max() < 2e-5
         assert np.abs(full - _band_by_hand(q, k, v, seg, None)).max() < 2e-5
         if max(lengths) <= w:
@@ -269,7 +263,7 @@ def pytest_the_band_at_the_published_window():
         at = first + 100  # a key of the second sequence
         k2 = k.at[at].add(1.0)
         v2 = v.at[at].add(1.0)
-        moved = np.asarray(token_attention.segment_causal_attention(q, k2, v2, jnp.asarray(seg), window=w))
+        moved = np.asarray(attention(q, k2, v2, jnp.asarray(seg), window=w))
         assert np.array_equal(moved[:at], band[:at])  # earlier rows, the other sequence
         assert np.abs(moved[at : at + w] - band[at : at + w]).min(axis=0).max() > 0
         assert np.abs(moved[at + w - 1] - band[at + w - 1]).max() > 1e-6  # 511 back: seen
@@ -387,69 +381,10 @@ def pytest_padding_changes_nothing_and_every_gradient_is_finite(setup):
     assert np.array_equal(routing_wide["conv_2"]["chosen"][:26], routing["conv_2"]["chosen"][:26])
     assert float(counters_wide["moe_rows_held"]) == float(counters["moe_rows_held"])
 
-    def loss(p):
-        out = model.apply({"params": p}, wide, train=True)
-        return multihead_rmse_loss(
-            out, wide, model.output_type, model.task_weights,
-            head_loss=model.head_loss, class_minmax=model.class_minmax,
-        )[0]
-
-    value, grads = jax.value_and_grad(loss)(
-        jax.tree_util.tree_map(jnp.asarray, variables["params"])
-    )
+    value, grads = loss_and_grads(model, variables["params"], wide, True)
     assert np.isfinite(float(value))
     for path, g in jax.tree_util.tree_leaves_with_path(grads):
         assert np.isfinite(np.asarray(g)).all(), path
-
-
-@pytest.mark.parametrize("where", ["rematerialized_blocks", "scanned_epoch"])
-def pytest_compact_row_arrays_inside_the_model(where, monkeypatch):
-    """At 170 tokens the four routed layers size their row arrays at 256 of
-    352 rows and one pass takes every live row: under ``nn.remat`` (the
-    cell's blocks) loss and every gradient, and inside a scanned epoch of two
-    steps the parameters AdamW leaves, are bit-equal at another capacity
-    (320), and the loss equals to rounding what one pass over all ``K N`` rows
-    gives (a capacity out of reach: no loop is compiled); the counter reads
-    one a routed layer and step, and none there."""
-    from hydragnn_tpu.train.trainer import (
-        _loss_and_metrics, create_train_state, make_train_epoch_scan,
-    )
-    from hydragnn_tpu.utils.optimizer import select_optimizer
-
-    model = _model(remat=True)
-    batch = _collate(_sequences((60, 70, 40)))
-    rows = batch.node_features.shape[0] * ARCH["num_experts_per_tok"]
-    assert token_routed._capacity(rows, 4, 16) == 256 < 320 < rows
-    variables = shaken(init_model_variables(model, batch), 35)
-    opt = select_optimizer("AdamW", 1e-3)
-
-    def run():
-        if where == "rematerialized_blocks":
-            (loss, aux), grads = jax.jit(jax.value_and_grad(
-                lambda p: _loss_and_metrics(
-                    model, p, {}, batch, jax.random.PRNGKey(0), counters=True
-                ), has_aux=True,
-            ))(variables["params"])
-            return (loss, grads), aux[2], 1
-        state = create_train_state(model, variables, opt)
-        stacked = jax.tree_util.tree_map(lambda a: jnp.stack([a, a]), batch)
-        state, metrics = make_train_epoch_scan(model, opt, donate=False)(
-            state, stacked, np.asarray(2, np.int32), jax.random.PRNGKey(0)
-        )
-        return (metrics["loss"], state.params), metrics, 2
-
-    got, counted, steps = run()
-    monkeypatch.setattr(token_routed, "_capacity", lambda *_: 320)
-    wider, counted_wider, _ = run()
-    monkeypatch.setattr(token_routed, "_capacity", lambda *_: rows)
-    every_row, counted_every_row, _ = run()
-    passes = len(ROUTED) * steps
-    assert float(counted["moe_layers_compact"]) == passes
-    assert float(counted_wider["moe_layers_compact"]) == passes
-    assert float(counted_every_row["moe_layers_compact"]) == 0
-    assert float(counted["moe_rows_held"]) == float(counted_every_row["moe_rows_held"]) > 0
-    assert_bit_equal(got, wider)
-    assert abs(float(got[0]) - float(every_row[0])) <= 1e-6 * abs(float(every_row[0]))
 
 
 def pytest_train_step_scopes_counters_and_other_families_untouched():
@@ -470,7 +405,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     model = _model(remat=True)
     batch = _collate(_sequences((5, 9, 12)))
     opt = select_optimizer("AdamW", 1e-3)
-    state = create_train_state(model, init_model_variables(model, batch), opt)
+    state = create_train_state(model, init_variables(model, batch), opt)
     assert state.batch_stats == {} and model.counts_routing
     step = make_train_step(model, opt, donate=False)
     used, names = used_scopes(step.lower(state, batch, jax.random.PRNGKey(0)).compile().as_text())
@@ -487,7 +422,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
 
     lfm2_model = sibling._model()  # the sibling's small model
     sbatch = _collate(_sequences((5, 9)))
-    sstate = create_train_state(lfm2_model, init_model_variables(lfm2_model, sbatch), opt)
+    sstate = create_train_state(lfm2_model, init_variables(lfm2_model, sbatch), opt)
     used, _ = used_scopes(
         make_train_step(lfm2_model, opt, donate=False)
         .lower(sstate, sbatch, jax.random.PRNGKey(0)).compile().as_text()
@@ -501,7 +436,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     )
     assert not classic.counts_routing and classic.token_cfg is None
     cbatch = collate_graphs(_sequences((5, 9)), ("node",), (1,))
-    cstate = create_train_state(classic, init_model_variables(classic, cbatch), opt)
+    cstate = create_train_state(classic, init_variables(classic, cbatch), opt)
     _, cmetrics = make_train_step(classic, opt, donate=False)(
         cstate, cbatch, jax.random.PRNGKey(0)
     )
@@ -550,45 +485,3 @@ def pytest_entry_points_refuse_what_the_family_cannot_run():
         e["code"] == "bad-arch" and "LAGUNA" in e["message"] and "sliding_window" in e["message"]
         for e in report["errors"]
     ), report["errors"]
-
-
-def pytest_run_training_trains_the_family_through_the_loaders(tmp_path, monkeypatch):
-    """``run_training`` on a ``model_type: "LAGUNA"`` config: the benchmark's
-    generator and configuration file at small sizes, the loaders' split,
-    config completion (the head as wide as its classes, both tables read),
-    rematerialized blocks, ``TrainingDriver``'s scanned epoch. The loss falls
-    from ln(vocab) and the counters are published."""
-    import hydragnn_tpu
-    from graftbench import datasets
-    from hydragnn_tpu import telemetry
-
-    monkeypatch.chdir(tmp_path)
-    monkeypatch.setenv("SERIALIZED_DATA_PATH", str(tmp_path))
-    block, _ = datasets.materialize(
-        {"generator": "token_chain", "graphs": 40, "tokens": 24, "vocab": V,
-         "successors": 2}, 7, str(tmp_path / "cache"),
-    )
-    nn_block = copy.deepcopy(PUBLISHED)
-    nn_block["Architecture"].update(
-        {k: v for k, v in ARCH.items() if k != "token_minmax"}, hidden_dim=D,
-        num_conv_layers=LAYERS,
-    )
-    assert nn_block["Architecture"]["remat"] is True
-    nn_block["Variables_of_interest"]["num_classes"] = [V]
-    nn_block["Training"].update(batch_size=4, num_epoch=6, learning_rate=0.01)
-    config = {
-        "Verbosity": {"level": 0}, "Dataset": block, "NeuralNetwork": nn_block,
-        "Visualization": {"create_plots": 0},
-    }
-    history = hydragnn_tpu.run_training(config)
-    losses = history["total_loss_train"]
-    assert abs(losses[0] - np.log(V)) < 1.0 and losses[-1] < losses[0] - 1.0, losses
-    assert all(np.isfinite(history["total_loss_val"]))
-    arch = config["NeuralNetwork"]["Architecture"]
-    assert arch["output_dim"] == [V] and arch["target_dim"] == [1]
-    assert arch["head_loss"] == ["cross_entropy"]
-    lo, hi = arch["token_minmax"]
-    assert 0 <= lo < hi <= V - 1 and arch["class_minmax"][0][1] <= V - 1
-    gauges = telemetry.gauges_snapshot()
-    assert gauges["train/moe_rows_held_per_epoch"] > 0
-    assert gauges["train/moe_load_max_per_epoch"] >= gauges["train/moe_load_min_per_epoch"]

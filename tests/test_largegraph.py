@@ -87,9 +87,7 @@ def _predict(mesh):
     }
 
 
-@pytest.mark.mpi_skip
-@pytest.mark.parametrize("agg_arm", ["xla", "sorted"])
-def pytest_largegraph_graph_axis_equivalence(tmp_path, monkeypatch, agg_arm):
+def graph_axis_equivalence(tmp_path, monkeypatch, agg_arm):
     # "sorted" = the TPU production default since r05 (graph-sharded edges of
     # a sorted batch stay sorted per shard); exercised explicitly on the CPU
     # suite where the platform default is the XLA scatter bundle.
@@ -166,3 +164,12 @@ def pytest_largegraph_graph_axis_equivalence(tmp_path, monkeypatch, agg_arm):
     # Under tmp_path: a tier-1 run writes nothing into the tracked tree.
     with open(tmp_path / f"LARGEGRAPH_{agg_arm}.json", "w") as f:
         json.dump(artifact, f, indent=2)
+
+
+# One file an arm (the other is tests/test_largegraph_sorted.py): each case is
+# two trainings and three predictions, ``--dist loadfile`` gives a file to
+# ONE worker, and a file of two tests starts among the last.
+@pytest.mark.mpi_skip
+@pytest.mark.parametrize("agg_arm", ["xla"])
+def pytest_largegraph_graph_axis_equivalence(tmp_path, monkeypatch, agg_arm):
+    graph_axis_equivalence(tmp_path, monkeypatch, agg_arm)

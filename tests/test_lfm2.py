@@ -8,7 +8,13 @@ across a graph boundary in a packed, padded batch of unequal lengths; padding
 nodes routed nowhere and every gradient finite; token ids exact over the
 whole slice; the scopes and counters; the routed layer's compact ``[C, .]``
 path against its ``[K N, .]`` fall-back, bit for bit; the family through
-``run_training``. Values and counts, never a time."""
+``run_training``. Values and counts, never a time.
+
+A whole stack is initialised and run under ``jit``, once a model: op by op
+outside it every primitive of every shape compiles alone, and that was a
+third of the suite's seconds (``compiled``, ``init_variables`` below, which
+the four sibling families' files import). What is about the eager path
+itself (``RoutedFFN`` run as the initializer runs it) stays eager."""
 
 import copy
 import json
@@ -73,11 +79,62 @@ def _collate(graphs, **pads):
     return collate_graphs(graphs, ("node",), (1,), with_positions=True, **pads)
 
 
+_PROGRAMS = {}  # (id(model), purpose) -> (the model, its compiled callable)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def programs():
+    """What a module's tests compiled, kept for as long as the module runs:
+    a model's forward or loss is traced and compiled ONCE (once a batch
+    shape: ``jit``'s own cache), whichever test asks first. The sibling
+    families' files import this fixture with the helpers below."""
+    yield _PROGRAMS
+    _PROGRAMS.clear()
+
+
+def compiled(model, purpose, function):
+    """``jax.jit(function)``, made once for this ``model`` object and
+    ``purpose`` (later calls hand back the first one's). A test that patches
+    what the program calls names a purpose of its own, or it would be handed
+    the program traced before the patch."""
+    key = (id(model), purpose)
+    if key not in _PROGRAMS:
+        _PROGRAMS[key] = (model, jax.jit(function))  # the model kept alive: its id is the key
+    return _PROGRAMS[key][1]
+
+
+def init_variables(model, batch, seed=0):
+    """``init_model_variables`` as ONE compiled program."""
+    return jax.jit(lambda batch: init_model_variables(model, batch, seed))(batch)
+
+
+def apply_routed(model, params, batch):
+    """(outputs, what the routed layers sowed) of the compiled forward."""
+    return compiled(model, "forward", lambda params, batch: model.apply(
+        {"params": params}, batch, train=False, mutable=[token_routed.INTERMEDIATES],
+    ))(params, batch)
+
+
+def loss_of(model, train):
+    """``(params, batch) -> loss`` as the trainer computes it."""
+    def loss(params, batch):
+        out = model.apply({"params": params}, batch, train=train)
+        return multihead_rmse_loss(
+            out, batch, model.output_type, model.task_weights,
+            head_loss=model.head_loss, class_minmax=model.class_minmax,
+        )[0]
+    return loss
+
+
+def loss_and_grads(model, params, batch, train):
+    """(loss, its gradient by every parameter), one compiled program."""
+    return compiled(
+        model, ("loss and gradients", train), jax.value_and_grad(loss_of(model, train)),
+    )(jax.tree_util.tree_map(jnp.asarray, params), batch)
+
+
 def _forward(model, variables, batch):
-    out, sown = model.apply(
-        {"params": variables["params"]}, batch, train=False,
-        mutable=[token_routed.INTERMEDIATES],
-    )
+    out, sown = apply_routed(model, variables["params"], batch)
     routing, counters = token_routed.split_intermediates(sown[token_routed.INTERMEDIATES])
     return np.asarray(out[0]), jax.tree_util.tree_map(np.asarray, routing), counters
 
@@ -91,7 +148,7 @@ def setup():
     model = _model()
     graphs = _sequences((5, 9, 12))
     batch = _collate(graphs)
-    variables = shaken(init_model_variables(model, batch), 31)
+    variables = shaken(init_variables(model, batch), 31)
     return model, graphs, batch, variables
 
 
@@ -144,13 +201,6 @@ def pytest_loss_and_gradients_against_the_plain_reference(setup):
     _, routing, _ = _forward(model, variables, batch)
     params = jax.tree_util.tree_map(jnp.asarray, variables["params"])
 
-    def program(p):
-        out = model.apply({"params": p}, batch, train=False)
-        return multihead_rmse_loss(
-            out, batch, model.output_type, model.task_weights,
-            head_loss=model.head_loss, class_minmax=model.class_minmax,
-        )[0]
-
     def reference(p):
         total, start = 0.0, 0
         for g in graphs:
@@ -163,10 +213,11 @@ def pytest_loss_and_gradients_against_the_plain_reference(setup):
             total = total - logp[np.arange(g.num_nodes), label].sum()
         return total / start
 
+    # Both sides one program each, traced at the precision they are held to
+    # (the reference's own code, with the routing it is given as numbers).
     with jax.default_matmul_precision("highest"):
-        (got, g_got), (want, g_want) = (
-            jax.value_and_grad(f)(params) for f in (program, reference)
-        )
+        got, g_got = jax.jit(jax.value_and_grad(loss_of(model, False)))(params, batch)
+        want, g_want = jax.jit(jax.value_and_grad(reference))(params)
     assert abs(float(got) - float(want)) < 1e-5 * abs(float(want))
     flat_got, flat_want = (
         jax.tree_util.tree_leaves_with_path(t) for t in (g_got, g_want)
@@ -242,16 +293,7 @@ def pytest_padding_changes_nothing_and_every_gradient_is_finite(setup):
     # 38 padding nodes more, not one row more routed.
     assert float(counters_wide["moe_rows_held"]) == float(counters["moe_rows_held"])
 
-    def loss(p):
-        out = model.apply({"params": p}, wide, train=True)
-        return multihead_rmse_loss(
-            out, wide, model.output_type, model.task_weights,
-            head_loss=model.head_loss, class_minmax=model.class_minmax,
-        )[0]
-
-    value, grads = jax.value_and_grad(loss)(
-        jax.tree_util.tree_map(jnp.asarray, variables["params"])
-    )
+    value, grads = loss_and_grads(model, variables["params"], wide, True)
     assert np.isfinite(float(value))
     for path, g in jax.tree_util.tree_leaves_with_path(grads):
         assert np.isfinite(np.asarray(g)).all(), path
@@ -327,7 +369,7 @@ def steered_layer(k, held, experts, offset, to_held, one_expert=None, d=None, f=
             here = [one_expert]
         away = [absent[(i * k + j) % len(absent)] for j in range(k - m)]
         x[i, here + away] = 4.0
-    params = layer.init(jax.random.PRNGKey(0), jnp.zeros((8, d)), jnp.ones((8,), bool))
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), jnp.zeros((8, d)), jnp.ones((8,), bool))
     gate = 0.01 * rng.normal(size=(d, experts)).astype(np.float32)
     gate[np.arange(experts), np.arange(experts)] = 1.0
     params = {"params": dict(params["params"], gate=jnp.asarray(gate))}
@@ -441,7 +483,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     model = _model()
     batch = _collate(_sequences((5, 9, 12)))
     opt = select_optimizer("AdamW", 1e-3)
-    state = create_train_state(model, init_model_variables(model, batch), opt)
+    state = create_train_state(model, init_variables(model, batch), opt)
     assert state.batch_stats == {} and model.counts_routing
     step = make_train_step(model, opt, donate=False)
     text = step.lower(state, batch, jax.random.PRNGKey(0)).compile().as_text()
@@ -463,7 +505,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     )
     assert not classic.counts_routing and classic.head_loss == ()
     cbatch = collate_graphs(_sequences((5, 9)), ("node",), (1,))
-    cstate = create_train_state(classic, init_model_variables(classic, cbatch), opt)
+    cstate = create_train_state(classic, init_variables(classic, cbatch), opt)
     _, cmetrics = make_train_step(classic, opt, donate=False)(
         cstate, cbatch, jax.random.PRNGKey(0)
     )

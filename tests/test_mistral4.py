@@ -31,14 +31,16 @@ sys.path.insert(0, REPO)
 from graftbench.drivers.train_epochs import shaken  # noqa: E402
 from graftbench.families import mistral4 as plain  # noqa: E402
 from hydragnn_tpu.graphs import GraphSample, collate_graphs  # noqa: E402
-from hydragnn_tpu.models import create_model, init_model_variables  # noqa: E402
+from hydragnn_tpu.models import create_model  # noqa: E402
 from hydragnn_tpu.models import (  # noqa: E402
     laguna, lfm2, mistral4, token_attention, token_common, token_routed,
 )
 from hydragnn_tpu.models.base import HydraGNN  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
-from tests.test_lfm2 import _collate, _sequences  # noqa: E402
+from tests.test_lfm2 import (  # noqa: E402, F401
+    _collate, _sequences, apply_routed, compiled, init_variables, loss_and_grads, programs,
+)
 
 V, D, LAYERS = sibling.V, 32, 3  # the sibling's sequences: ids under its V
 CONFIG = os.path.join(REPO, "graftbench", "configs", "mistral_small4_ep8.json")
@@ -65,9 +67,7 @@ def _model(layers=LAYERS, **arch):
 
 def _forward(model, variables, batch):
     """(logits, the routing as the engine returns it [N, layers x K], counters)."""
-    out, sown = model.apply(
-        {"params": variables["params"]}, batch, train=False, mutable=[token_routed.INTERMEDIATES],
-    )
+    out, sown = apply_routed(model, variables["params"], batch)
     routing, counters = token_routed.split_intermediates(sown[token_routed.INTERMEDIATES])
     chosen = np.concatenate(
         [np.asarray(routing[f"conv_{i}"]["chosen"]) for i in range(model.num_conv_layers)], axis=1
@@ -80,7 +80,7 @@ def setup():
     model = _model()
     graphs = _sequences((5, 9, 30))  # the third runs past the 8 trained places
     batch = _collate(graphs)
-    variables = shaken(init_model_variables(model, batch), 39)
+    variables = shaken(init_variables(model, batch), 39)
     return model, graphs, batch, variables
 
 
@@ -133,7 +133,7 @@ def pytest_the_router_is_a_softmax_over_all_experts_top_k_normalised():
     assert cfg.scoring_func == "softmax" and not cfg.use_expert_bias and cfg.norm_topk_prob
     x = jnp.asarray(rng.normal(size=(12, D)).astype(np.float32))
     layer = token_routed.RoutedFFN(D, cfg)
-    params = layer.init(jax.random.PRNGKey(0), x, jnp.ones((12,), bool))["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x, jnp.ones((12,), bool))["params"]
     assert "expert_bias" not in params
     out, sown = layer.apply(
         {"params": params}, x, jnp.ones((12,), bool), mutable=[token_routed.INTERMEDIATES]
@@ -325,7 +325,7 @@ def pytest_entry_points_refuse_what_the_family_cannot_run():
         _model(first_k_dense_replace=1, intermediate_size=0)
     dense_first = _model(first_k_dense_replace=1)
     batch = _collate(_sequences((5,)))
-    params = init_model_variables(dense_first, batch)["params"]
+    params = init_variables(dense_first, batch)["params"]
     assert "shared_experts" not in params["conv_0"] and "shared_experts" in params["conv_1"]
     assert params["conv_0"]["feed_forward"]["w1"]["kernel"].shape == (D, 48)
     with pytest.raises(ValueError, match="positions"):
@@ -406,18 +406,8 @@ def pytest_run_training_trains_the_family_through_the_loaders(tmp_path, monkeypa
 
 
 def pytest_gradients_are_finite_through_the_block(setup):
-    from hydragnn_tpu.models.loss import multihead_rmse_loss
-
     model, graphs, batch, variables = setup
-
-    def loss(p):
-        out = model.apply({"params": p}, batch, train=True)
-        return multihead_rmse_loss(
-            out, batch, model.output_type, model.task_weights,
-            head_loss=model.head_loss, class_minmax=model.class_minmax,
-        )[0]
-
-    grads = jax.grad(loss)(jax.tree_util.tree_map(jnp.asarray, variables["params"]))
+    _, grads = loss_and_grads(model, variables["params"], batch, True)
     flat = jax.tree_util.tree_leaves_with_path(grads)
     assert all(np.isfinite(np.asarray(g)).all() for _, g in flat)
     moved = {jax.tree_util.keystr(p) for p, g in flat if np.abs(np.asarray(g)).max() > 0}
@@ -433,7 +423,9 @@ def _requests(graphs):
 
 def _direct_logprobs(model, variables, g):
     """The direct forward's log-probabilities of one document's next tokens."""
-    out = model.apply({"params": variables["params"]}, _collate([g]))[0]
+    out = compiled(model, "logits", lambda params, batch: model.apply(
+        {"params": params}, batch
+    ))(variables["params"], _collate([g]))[0]
     logp = np.asarray(jax.nn.log_softmax(out[: g.num_nodes], axis=-1))
     ids = np.round(g.x[:, 0] * (V - 1.0)).astype(int)
     want = np.zeros((g.num_nodes, 1), np.float32)
@@ -571,7 +563,7 @@ def pytest_engine_counts_a_layer_past_its_capacity():
     # 8 experts, 2 a token, 2 held: C = 1.5 x 2 x 300 x 2 / 8 -> 256 rows.
     model = _model(layers=1, num_experts_held=2, experts_offset=0)
     g = _sequences((280,), seed=3)[0]
-    variables = shaken(init_model_variables(model, _collate([g])), 5)
+    variables = shaken(init_variables(model, _collate([g])), 5)
     gate = np.array(variables["params"]["conv_0"]["feed_forward"]["gate"])
     gate[:, :2] *= 30.0  # nearly every token chooses the two held experts
     variables["params"]["conv_0"]["feed_forward"]["gate"] = jnp.asarray(gate)
@@ -660,7 +652,7 @@ def pytest_served_from_a_snapshot_through_from_config_and_check_config(tmp_path,
         reply = future.result(120)
         params, bstats, _ = eng._current_weights()
         batch = collate_graphs([GraphSample(x=x, pos=pos)], with_positions=True)
-        out = eng.model.apply({"params": params, "batch_stats": bstats}, batch)[0]
+        out = jax.jit(eng.model.apply)({"params": params, "batch_stats": bstats}, batch)[0]
     logp = np.asarray(jax.nn.log_softmax(out[:9], axis=-1))
     assert reply[0].shape == (9, 1) and future.routing.shape == (9, 2 * K)
     assert np.abs(reply[0][:-1, 0] - logp[np.arange(8), ids[1:]]).max() < 2e-5

@@ -68,7 +68,7 @@ def pytest_forward_and_grad(model_type):
         loss, _ = multihead_rmse_loss(out, batch, types, model.task_weights)
         return loss
 
-    loss, grads = jax.value_and_grad(loss_fn)(variables["params"])
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(variables["params"])
     assert np.isfinite(float(loss))
     flat = jax.tree_util.tree_leaves(grads)
     assert all(np.all(np.isfinite(np.asarray(g))) for g in flat)

@@ -13,7 +13,10 @@ workflows/CI.yml:47-52), in TWO arms since graftmesh (docs/DISTRIBUTED.md):
   backends without cross-process collectives (XLA:CPU raises "Multiprocess
   computations aren't implemented") this arm keeps its PRECISE skip — the
   capability is the backend's, not ours; the loopback arm carries the
-  distributed coverage there."""
+  distributed coverage there. Its tests are tests/test_multiprocess_spawn.py
+  (a file of their own: ``--dist loadfile`` gives a file to ONE worker, and
+  the arm's three epochs in two fresh processes are the longest test here);
+  what both arms share stays in this module."""
 
 import json
 import os
@@ -65,8 +68,10 @@ def _make_split_datasets(config, tmp_path, counts):
         deterministic_graph_data(p, number_configurations=counts[split])
 
 
-def _launch_two_process(config, tmp_path, extra_env=None, timeout=420):
-    """Write config, spawn 2 rendezvousing workers, return their outputs."""
+def _launch_two_process(config, tmp_path, extra_env=None, timeout=270):
+    """Write config, spawn 2 rendezvousing workers, return their outputs.
+    ``timeout`` stays under the test's own limit (tests/conftest.py), so
+    that the workers are killed and the test fails with a reason of its own."""
     config_path = str(tmp_path / "config.json")
     with open(config_path, "w") as f:
         json.dump(config, f)
@@ -176,68 +181,3 @@ def pytest_two_worker_loopback_overlap_arm_agrees(tmp_path, monkeypatch):
     assert single[0]["final_loss"] == pytest.approx(
         bucketed[0]["final_loss"], rel=1e-4
     )
-
-
-@pytest.mark.mpi_skip
-def pytest_two_process_rendezvous_arm(tmp_path):
-    """The genuinely-multiprocess arm: two OS processes rendezvous through
-    jax.distributed and train over the global mesh. Keeps its PRECISE skip
-    on backends without cross-process collectives (the loopback tests above
-    carry the distributed coverage there); on capable backends the old
-    assertions apply unchanged."""
-    with open(os.path.join(REPO, "tests/inputs/ci.json")) as f:
-        config = json.load(f)
-    config["NeuralNetwork"]["Training"]["num_epoch"] = 3
-    config["Visualization"] = {"create_plots": False}
-    _make_split_datasets(
-        config, tmp_path, {"train": 48, "test": 16, "validate": 16}
-    )
-
-    outs = _launch_two_process(config, tmp_path)
-
-    losses = []
-    for out in outs:
-        lines = [l for l in out.splitlines() if l.startswith("FINAL_LOSS")]
-        assert lines, out[-2000:]
-        losses.append(float(lines[-1].split()[1]))
-    # Metrics are globally psum-reduced: every process must report the SAME loss.
-    assert losses[0] == pytest.approx(losses[1], rel=1e-6), losses
-
-    # rank-0-only checkpoint exists
-    logdirs = os.listdir(tmp_path / "logs")
-    assert any(
-        os.path.exists(tmp_path / "logs" / d / (d + ".pk")) for d in logdirs
-    )
-
-
-@pytest.mark.mpi_skip
-@pytest.mark.slow
-def pytest_two_process_pna_convergence(tmp_path):
-    """Full PNA ci.json convergence under 2 rendezvousing processes with the
-    UNCHANGED single-process accuracy thresholds (reference CI runs its whole
-    suite via mpirun -n 2, /root/reference/.github/workflows/CI.yml:47-52) —
-    thresholds from tests/test_graphs.py THRESHOLDS['PNA']. Spawn arm:
-    precise-skips where the backend lacks multiprocess collectives."""
-    with open(os.path.join(REPO, "tests/inputs/ci.json")) as f:
-        config = json.load(f)
-    config["Visualization"] = {"create_plots": False}
-    perc_train = config["NeuralNetwork"]["Training"]["perc_train"]
-    num_samples_tot = 500
-    _make_split_datasets(
-        config, tmp_path, {
-            "train": int(num_samples_tot * perc_train),
-            "test": int(num_samples_tot * (1 - perc_train) * 0.5),
-            "validate": int(num_samples_tot * (1 - perc_train) * 0.5),
-        },
-    )
-
-    outs = _launch_two_process(
-        config,
-        tmp_path,
-        extra_env={"HYDRAGNN_MP_THRESHOLDS": "0.20 0.20 0.75"},
-        timeout=900,
-    )
-    for out in outs:
-        assert any(
-            l.startswith("CONVERGENCE_OK") for l in out.splitlines()
-        ), out[-2000:]

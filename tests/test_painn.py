@@ -4,7 +4,11 @@ plain float32 reference of ``graftbench/`` (forward for PNA and GAT too, the
 yardstick the chip runs hold them to; forward and gradients for PaiNN),
 padding independence with finite gradients (a padding edge has length 0),
 equivariance, and the family through ``run_training`` / ``run_prediction``
-on the scan path and on a mesh. Values and counts, never a time."""
+on the scan path and on a mesh. Values and counts, never a time.
+
+The loaders' two pads trained alike (three families, an epoch each) is a file
+of its own, tests/test_painn_pads.py: ``--dist loadfile`` gives a file to ONE
+worker, and this one was the longest of the suite."""
 
 import copy
 import json
@@ -70,7 +74,9 @@ def _collate(model, graphs, **pads):
 
 
 def _shaken_variables(model, graphs, seed=3):
-    return shaken(init_model_variables(model, _collate(model, graphs)), seed)
+    # The initializer as ONE program: op by op it was most of a case's seconds.
+    init = jax.jit(lambda batch: init_model_variables(model, batch))
+    return shaken(init(_collate(model, graphs)), seed)
 
 
 def _per_graph(outputs, graphs):
@@ -128,7 +134,9 @@ def pytest_program_forward_matches_the_plain_reference(kind, hidden, arm, monkey
     variables = _shaken_variables(model, graphs)
     batch = _collate(model, graphs)
     assert batch.row_ptr is not None
-    got = _per_graph(model.apply(variables, batch, train=False), graphs)
+    got = _per_graph(
+        jax.jit(lambda v, b: model.apply(v, b, train=False))(variables, batch), graphs
+    )
     want = reference.forward(model, variables, graphs)
     for g, w in zip(got, want):
         for a, b in zip(g, w):
@@ -233,73 +241,6 @@ def pytest_painn_outputs_and_gradients_do_not_depend_on_the_padding():
             np.testing.assert_allclose(x, y, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(results[0][1], results[1][1], rtol=1e-5)
     _assert_trees_close(results[0][2], results[1][2], rtol=1e-4, atol_of_scale=1e-5)
-
-
-@pytest.mark.parametrize("kind", ["PNA", "GAT", "PAINN"])
-def pytest_a_loaders_tile_pad_and_power_of_two_pad_train_alike(kind, monkeypatch):
-    """One dataset behind two loaders, ``ladder_step`` absent (the pad rounds
-    up to the kernels' tile) and ``"pow2"`` named: same membership, same
-    order, fewer padding rows. On the chip's arm (sorted / CSR sums) a
-    batch's loss and every gradient agree within PaiNN's limit (1e-4; a
-    gradient to 1e-4 of its leaf's largest entry, as the padding test above
-    holds them), and so do the training loss of an epoch of the scan path's
-    stacked chunk and the evaluation after it. Rows that only the round-up
-    made carry nothing."""
-    from hydragnn_tpu.graphs.collate import loader_pad_tile
-    from hydragnn_tpu.preprocess.dataloader import GraphDataLoader
-    from hydragnn_tpu.train.train_validate_test import TrainingDriver
-    from hydragnn_tpu.train.trainer import create_train_state
-    from hydragnn_tpu.utils.optimizer import select_optimizer
-
-    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    graphs = _graphs(seed=5, sizes=tuple(range(16, 28)) * 16, box=6.0)
-    # GAT's attention dropout draws over [N_pad + E_pad, heads]: another pad is
-    # another draw, equal in law and not in value, so it is off here.
-    model = _model(kind).clone(dropout=0.0)
-    loaders = {
-        step: GraphDataLoader(
-            graphs, batch_size=96, shuffle=False, head_types=TYPES, head_dims=DIMS,
-            ladder_step=step, with_positions=model.needs_positions,
-        )
-        for step in (None, "pow2")
-    }
-    tile = loader_pad_tile()
-    (n_tile, e_tile, _), (n_pow2, e_pow2, _) = (loaders[k].pad_sizes for k in (None, "pow2"))
-    assert n_tile % tile == 0 and e_tile % tile == 0
-    assert n_tile < n_pow2 and e_tile < e_pow2 and n_pow2 & (n_pow2 - 1) == 0
-    batches = {step: next(iter(loader)) for step, loader in loaders.items()}
-    assert batches[None].senders.shape[0] == e_tile
-    assert int(batches[None].edge_mask.sum()) == int(batches["pow2"].edge_mask.sum())
-    assert batches[None].row_ptr is not None  # the CSR arm's contract
-    variables = shaken(init_model_variables(model, batches[None]), 3)
-
-    stats = {k: v for k, v in variables.items() if k != "params"}  # PNA's, GAT's norms
-    loss_and_grads = jax.jit(
-        jax.value_and_grad(lambda p, batch: _program_loss(model, p, batch, **stats))
-    )
-    results = {
-        step: loss_and_grads(variables["params"], batch) for step, batch in batches.items()
-    }
-    np.testing.assert_allclose(results[None][0], results["pow2"][0], rtol=1e-4)
-    _assert_trees_close(results[None][1], results["pow2"][1], rtol=1e-4, atol_of_scale=1e-4)
-
-    # An epoch of the scan path: one stacked chunk of the loader's two batches.
-    # The second step's loss and the evaluation after it see the first update.
-    # (The parameters themselves are not compared: a bias in front of a batch
-    # norm has a gradient of rounding alone, and Adam steps by its sign.)
-    after = {}
-    for step, loader in loaders.items():
-        opt = select_optimizer("AdamW", 1e-3)
-        fresh = jax.tree_util.tree_map(jnp.array, variables)  # the step donates its state
-        driver = TrainingDriver(model, opt, create_train_state(model, fresh, opt))
-        driver.scan_chunk = 2
-        before = driver.evaluate(loader)[0]
-        loader.reset_padding_stats()
-        train_loss, _ = driver.train_epoch(loader)
-        assert loader.padding_stats()["batches"] == 2
-        after[step] = (train_loss, driver.evaluate(loader)[0])
-        assert after[step][1] != pytest.approx(before, rel=1e-3)  # it moved
-    np.testing.assert_allclose(after[None], after["pow2"], rtol=1e-4)
 
 
 # ------------------------------------------------------------ (iv) equivariance

@@ -643,15 +643,17 @@ def timeline(request):
 
 def pytest_leaf_phases_cover_each_epoch(timeline):
     """The dispatching thread's epoch is PARTITIONED: the leaf phases
-    (``EPOCH_LEAVES``) cover 95% of every ``epoch`` span's wall on the scan
-    path, the per-step path and a mesh of four virtual devices, and each of
-    them is a child of the epoch, its train epoch or an evaluation."""
+    (``EPOCH_LEAVES``) cover 95% of an ``epoch`` span's wall (or all of it
+    but a tenth of a second; in three epochs of the four, see below) on the
+    scan path, the per-step path and a mesh of four virtual devices, and each
+    of them is a child of the epoch, its train epoch or an evaluation."""
     from hydragnn_tpu.train.train_validate_test import EPOCH_LEAVES
 
     path, _, spans = timeline
     epochs = [r for r in spans if r["name"] == "epoch"]
     assert [r["attrs"]["epoch"] for r in epochs] == [0, 1, 2, 3]
     by_id = {r["span_id"]: r for r in spans}
+    short = []  # epochs whose leaves leave too much of them uncovered
     for ep in epochs:
         lo, hi = ep["ts"], ep["ts"] + ep["dur_s"]
         leaves = [
@@ -661,12 +663,23 @@ def pytest_leaf_phases_cover_each_epoch(timeline):
         ]
         assert {r["name"] for r in leaves} == set(EPOCH_LEAVES), path
         covered = sum(r["dur_s"] for r in leaves)
-        assert covered >= 0.95 * ep["dur_s"], (path, ep["attrs"], covered, ep["dur_s"])
+        # What no leaf covers is glue and the start of a feed's two threads: on
+        # the mesh of four 13-36 ms of a 0.75 s epoch on a quiet host and 17-57
+        # beside six busy processes (PR 47: eight runs; 5% is 38 ms), a cost
+        # that does not grow with the epoch. So 5% of the epoch OR a tenth of
+        # a second, two of this run's slowed collations, in three epochs of
+        # the four: a phase left out of ``EPOCH_LEAVES`` is longer than that
+        # here and is missing from every epoch; a host's stall falls in one
+        # (seen once in 60 quiet epochs: 102 ms, in an epoch 0.25 s longer than
+        # the others).
+        if ep["dur_s"] - covered > max(0.05 * ep["dur_s"], 0.1):
+            short.append((path, ep["attrs"], covered, ep["dur_s"]))
         for r in leaves:  # each hangs off this epoch, directly or by one container
             parent = by_id[r["parent_id"]]
             assert parent is ep or by_id[parent["parent_id"]] is ep, (path, r["name"])
         # The four cumulative jax/*_s counters as they stood at the opening.
         assert {"jax_trace_s", "jax_lower_s", "jax_compile_s", "jax_cache_load_s"} <= set(ep["attrs"])
+    assert len(short) <= 1, short
     splits = [r["attrs"]["split"] for r in spans if r["name"] == "evaluate"]
     assert splits == ["val", "test"] * 4
 

@@ -53,7 +53,9 @@ def pytest_remat_transparent(conv):
                         [1.0, 1.0], 2, **kwargs)
     rem = create_model(conv, 1, 8, (1, 1), ("graph", "node"), HEADS,
                        [1.0, 1.0], 2, remat=True, **kwargs)
-    v = init_model_variables(base, batch)
+    # Whole stacks under jit, each model one program (loss and gradients):
+    # op by op every primitive of every shape compiles alone.
+    v = jax.jit(lambda batch: init_model_variables(base, batch))(batch)
 
     def loss_fn(model, params):
         outs = model.apply({"params": params, "batch_stats": v.get("batch_stats", {})},
@@ -63,12 +65,10 @@ def pytest_remat_transparent(conv):
         return loss
 
     # remat model must accept the same params pytree
-    l0 = float(loss_fn(base, v["params"]))
-    l1 = float(loss_fn(rem, v["params"]))
-    assert l0 == pytest.approx(l1, rel=1e-6)
+    l0, g0 = jax.jit(jax.value_and_grad(lambda p: loss_fn(base, p)))(v["params"])
+    l1, g1 = jax.jit(jax.value_and_grad(lambda p: loss_fn(rem, p)))(v["params"])
+    assert float(l0) == pytest.approx(float(l1), rel=1e-6)
 
-    g0 = jax.grad(lambda p: loss_fn(base, p))(v["params"])
-    g1 = jax.grad(lambda p: loss_fn(rem, p))(v["params"])
     for a, b in zip(jax.tree_util.tree_leaves(g0), jax.tree_util.tree_leaves(g1)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                    atol=1e-6)

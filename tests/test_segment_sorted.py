@@ -255,10 +255,12 @@ def pytest_sorted_path_under_graph_shard_map(monkeypatch):
         )
         return total, mean, std, count
 
-    sharded = jax.shard_map(
+    # Under jit, as every program path runs it (op by op the mapped body and
+    # its backward were 60 s of this file's 100).
+    sharded = jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P("graph"), P("graph")),
         out_specs=(P(), P(), P(), P()), check_vma=False,
-    )
+    ))
     out = sharded(data, ids)
     for a, b in zip(ref, out):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
@@ -267,5 +269,5 @@ def pytest_sorted_path_under_graph_shard_map(monkeypatch):
         total, mean, std, _ = sharded(d_, ids)
         return jnp.sum(total * 0.3 + mean * 1.7 - std * 0.9)
 
-    g = jax.grad(loss)(data)
+    g = jax.jit(jax.grad(loss))(data)
     assert bool(jnp.all(jnp.isfinite(g)))

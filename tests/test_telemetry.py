@@ -583,7 +583,10 @@ def pytest_span_totals_equal_collected_durations_from_two_threads():
         assert not t.is_alive()
     by_name = {}
     for r in telemetry.collected_records():
-        if r["kind"] == "span":
+        # The spans this test opened: a garbage collection of 1 ms or more is
+        # a ``gc`` record too once any earlier test of this process installed
+        # the hook (a loaded host makes them that long), marked ``retro``.
+        if r["kind"] == "span" and not r.get("retro"):
             by_name.setdefault(r["name"], []).append(r["dur_s"])
     assert {k: len(v) for k, v in by_name.items()} == {
         "left": 200, "right": 200, "shared": 400,
