@@ -7,10 +7,12 @@ Architecture config block. The reference seeds torch.manual_seed(0) at creation
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from flax.core import FrozenDict
 
 from ..graphs.batch import GraphBatch
 from ..graphs.collate import collate_graphs
@@ -144,7 +146,7 @@ def create_model(
         hidden_dim=hidden_dim,
         output_dim=tuple(output_dim),
         output_type=tuple(output_type),
-        config_heads=output_heads,
+        config_heads=_frozen(output_heads),
         num_conv_layers=num_conv_layers,
         task_weights=normalize_task_weights(task_weights),
         freeze_conv=bool(freeze_conv),
@@ -157,12 +159,31 @@ def create_model(
     )
 
 
+def _frozen(value):
+    """A configuration block as a hashable value (dicts frozen, lists as
+    tuples): the model that carries it can then key a compiled program."""
+    if isinstance(value, (dict, FrozenDict)):
+        return FrozenDict({k: _frozen(v) for k, v in value.items()})
+    if isinstance(value, (list, tuple)):
+        return tuple(_frozen(v) for v in value)
+    return value
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _init_program(model: HydraGNN, batch: GraphBatch, seed):
+    rngs = {"params": jax.random.PRNGKey(seed), "dropout": jax.random.PRNGKey(seed + 1)}
+    return model.init(rngs, batch, train=False)
+
+
 @telemetry.setup_phase("init_variables", until_ready=True)
 def init_model_variables(
     model: HydraGNN, example_batch: GraphBatch, seed: int = 0
 ) -> Dict[str, Any]:
-    rngs = {"params": jax.random.PRNGKey(seed), "dropout": jax.random.PRNGKey(seed + 1)}
-    return model.init(rngs, example_batch, train=False)
+    """The model's variables, drawn by ONE compiled program: ``model.init``
+    is traced with the batch as its argument, so a model of any family costs
+    one compile request and not one a primitive (PNA: 149), and an equal
+    model over equal shapes (a reload, a restart inside one process) none."""
+    return _init_program(model, example_batch, int(seed))
 
 
 def make_example_batch(

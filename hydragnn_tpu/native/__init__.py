@@ -33,11 +33,16 @@ _tried = False
 
 
 def _compile() -> bool:
+    # Built under a name of this process's own and installed by a rename:
+    # processes that start together in a fresh checkout (six test workers)
+    # each build, and none may load a binary another is still writing.
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO,
+        "g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp,
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         return True
     except (subprocess.SubprocessError, FileNotFoundError) as e:
         detail = getattr(e, "stderr", b"") or b""

@@ -101,10 +101,15 @@ class RawDataLoader:
         for serial_data_name, dataset in zip(
             self.serial_data_name_list, self.dataset_list
         ):
-            with open(os.path.join(serialized_dir, serial_data_name), "wb") as f:
+            # Installed whole by a rename: another process that trains the same
+            # dataset may be reading the file this one is writing again.
+            path = os.path.join(serialized_dir, serial_data_name)
+            tmp = f"{path}.{os.getpid()}.tmp"
+            with open(tmp, "wb") as f:
                 pickle.dump(self.minmax_node_feature, f)
                 pickle.dump(self.minmax_graph_feature, f)
                 pickle.dump(dataset, f)
+            os.replace(tmp, path)
 
     # --------------------------------------------------------------- parsing
     def _parse_file(self, filepath):

@@ -584,7 +584,7 @@ def setup_phase(name: str, until_ready: bool = False):
     """Decorator: every call of the function runs under the span
     ``setup.<name>``; with ``until_ready`` the span closes when the result is
     on the device, as a clock round ``jax.block_until_ready(fn(...))`` would
-    (the eager initializers: their seconds are the work, not its dispatch).
+    (the initializers: their seconds are the work, not its dispatch).
     The set-up account is opened INSIDE the functions every
     entry point calls before its first step (``run_training``, the
     benchmark's drivers, a library user), so no caller needs a clock of its
@@ -601,7 +601,14 @@ def setup_phase(name: str, until_ready: bool = False):
                 if until_ready:
                     import jax
 
-                    result = jax.block_until_ready(result)
+                    # Under an outer trace (``jax.eval_shape`` of the
+                    # initializer) there is nothing to wait for, and a tracer
+                    # refuses by printing the whole program it came from.
+                    if not any(
+                        isinstance(leaf, jax.core.Tracer)
+                        for leaf in jax.tree_util.tree_leaves(result)
+                    ):
+                        result = jax.block_until_ready(result)
                 return result
 
         return in_phase

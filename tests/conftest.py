@@ -16,19 +16,40 @@ Every test has a time limit of its own (``TIME_LIMIT_S``, or what its
 ``@pytest.mark.time_limit(seconds)`` says): a test that runs into it FAILS by
 name with every thread's stack on stderr and the run goes on, where a test
 that ran for ever used to cost the whole run its clock.
+
+Python's bytecode cache is ON for the session and its children, under the
+temporary directory and never in a checkout.
+
+``program`` and ``forward`` are how a test runs a whole stack: under ``jit``,
+traced where the test's switches stand (``from tests.conftest import ...``).
 """
 
 import faulthandler
 import os
 import signal
 import sys
+import tempfile
 import threading
 
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "")
-    + " --xla_force_host_platform_device_count="
-    + os.environ.get("HYDRAGNN_HOST_DEVICES", "8")
+# Python's bytecode cache, for this process, the workers and every interpreter
+# a test starts: where the environment turns it off (PYTHONDONTWRITEBYTECODE)
+# each of them compiles every module of jax, flax and this package from source,
+# 8-9 s of CPU an interpreter. Kept outside the checkout, under each source's
+# own path, so no tree gains a ``__pycache__``.
+os.environ.pop("PYTHONDONTWRITEBYTECODE", None)
+os.environ.setdefault(
+    "PYTHONPYCACHEPREFIX", os.path.join(tempfile.gettempdir(), "hydragnn_tpu_pycache")
 )
+sys.dont_write_bytecode = False
+sys.pycache_prefix = os.environ["PYTHONPYCACHEPREFIX"]
+
+if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    # (a test file that imports this module's helpers runs it a second time)
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count="
+        + os.environ.get("HYDRAGNN_HOST_DEVICES", "8")
+    )
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import jax
@@ -37,6 +58,23 @@ import pytest
 # HYDRAGNN_TPU_TESTS=1 leaves the real accelerator as the default backend.
 if os.environ.get("HYDRAGNN_TPU_TESTS") != "1":
     jax.config.update("jax_platforms", "cpu")
+
+
+def program(function, **jit_options):
+    """``jax.jit`` of ``function`` through a function object of its own: a
+    program traced NOW, under whatever the test has set. JAX keys its traces
+    by the function OBJECT and by nothing the trace read on its way (an
+    environment variable, ``platform_override``, a monkeypatched attribute):
+    the same object jitted on both sides of such a switch runs the first
+    side's executable twice, and a comparison of the two compares nothing.
+    Op by op outside ``jit`` every primitive of every shape compiles alone,
+    which is why whole stacks are not run that way here."""
+    return jax.jit(lambda *args, **kwargs: function(*args, **kwargs), **jit_options)
+
+
+def forward(model, variables, batch):
+    """The evaluation forward as ONE program, traced at this call."""
+    return program(lambda v, b: model.apply(v, b, train=False))(variables, batch)
 
 
 # One test's seconds, its function-scoped fixtures included. A subprocess a

@@ -21,6 +21,7 @@ from hydragnn_tpu.graphs.csr import build_row_ptr
 from hydragnn_tpu.ops import aggregate as agg
 from hydragnn_tpu.ops import certify
 from hydragnn_tpu.ops import segment as seg
+from tests.conftest import program
 
 ROUTES = ("sorted", "csr")
 # The prefix sums' error at these sizes (ops/segment_sorted.py: compensated,
@@ -49,23 +50,40 @@ def _problem(rng, route, e=300, n=40, f=17, padding=40):
     )
 
 
+def _run(fn, *args):
+    """``fn(*args)`` as ONE compiled program, traced at THIS call under the
+    route the test has set (``tests/conftest.py`` ``program``), its outputs
+    on the host: both sides of a comparison go the same way."""
+    return jax.tree_util.tree_map(np.asarray, program(fn)(*args))
+
+
+def _arms(fn, *args):
+    """The arms (``telemetry/scopes.py`` ``AGG_ARMS``) in the scopes of
+    ``fn``'s lowering as the switches stand now."""
+    text = program(fn).lower(*args).as_text(debug_info=True)
+    return set(re.findall(r"hydragnn\.agg\.\w+\.(\w+)", text))
+
+
+def _xla_stats(ids, n, mask=None):
+    """``d -> (sum, mean, std, count)`` by the masked XLA segment ops."""
+    return lambda d: (
+        seg.segment_sum(d, ids, n, mask=mask), seg.segment_mean(d, ids, n, mask=mask),
+        seg.segment_std(d, ids, n, mask=mask), seg.segment_count(ids, n, mask=mask),
+    )
+
+
 @pytest.mark.parametrize("route", ROUTES, indirect=True)
 def pytest_fused_stats_match_xla(route):
     data, ids, mask, n, row_ptr, real = _problem(
         np.random.default_rng(1), route, e=257, n=33, f=5
     )
-    total, mean, std, count = agg.fused_segment_stats(
-        data, ids, n, mask=mask, row_ptr=row_ptr
+    total, mean, std, count = _run(
+        lambda d: agg.fused_segment_stats(d, ids, n, mask=mask, row_ptr=row_ptr), data
     )
-    for got, ref in (
-        (total, seg.segment_sum), (mean, seg.segment_mean), (std, seg.segment_std)
-    ):
-        np.testing.assert_allclose(
-            got[real], ref(data, ids, n, mask=mask)[real], rtol=_RTOL, atol=_ATOL
-        )
-    np.testing.assert_array_equal(
-        count[real], seg.segment_count(ids, n, mask=mask)[real]
-    )
+    *refs, ref_count = _run(_xla_stats(ids, n, mask), data)
+    for got, ref in zip((total, mean, std), refs):
+        np.testing.assert_allclose(got[real], ref[real], rtol=_RTOL, atol=_ATOL)
+    np.testing.assert_array_equal(count[real], ref_count[real])
     # The contract's other half: the masked rows ARE counted, in the padding
     # segment, and nowhere else.
     assert float(count[n - 1]) == 40.0
@@ -89,11 +107,11 @@ def pytest_fused_stats_gradient_matches_xla(route):
         std = seg.segment_std(d, ids, n, mask=mask)
         return jnp.sum(w * mean * 1.3) + jnp.sum(w * std * 0.7)
 
-    g_fused = jax.grad(fused_loss)(data)
+    g_fused = _run(jax.grad(fused_loss), data)
     np.testing.assert_allclose(
-        g_fused, jax.grad(xla_loss)(data), rtol=1e-4, atol=1e-5
+        g_fused, _run(jax.grad(xla_loss), data), rtol=1e-4, atol=1e-5
     )
-    assert not np.asarray(g_fused)[~np.asarray(mask)].any()
+    assert not g_fused[~np.asarray(mask)].any()
 
 
 @pytest.mark.parametrize("route", ROUTES, indirect=True)
@@ -104,13 +122,19 @@ def pytest_pna_aggregate_matches_the_xla_composition(route, monkeypatch):
         np.random.default_rng(3), route, e=120, n=16, f=8, padding=14
     )
     aggregators = ("mean", "min", "max", "std")
-    got, cnt = agg.pna_aggregate(
-        data, ids, n, aggregators, mask=mask, row_ptr=row_ptr
-    )
+
+    def bundle(d):
+        return agg.pna_aggregate(d, ids, n, aggregators, mask=mask, row_ptr=row_ptr)
+
+    got, cnt = _run(bundle, data)
+    on = _arms(bundle, data)
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "0")
-    want, cnt_xla = agg.pna_aggregate(
-        data, ids, n, aggregators, mask=mask, row_ptr=row_ptr
-    )
+    want, cnt_xla = _run(bundle, data)
+    # Each side was traced on its own arm (the prefix sums, with the scan
+    # kernel for the extrema where the boundaries are given, against XLA's
+    # segment ops), and not one program against itself.
+    assert on == {"sorted": {"sorted", "xla"}, "csr": {"csr", "pallas_csr"}}[route]
+    assert _arms(bundle, data) == {"xla"}
     np.testing.assert_allclose(got[real], want[real], rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(cnt[real], cnt_xla[real])
 
@@ -136,10 +160,10 @@ def pytest_centered_std_beats_uncentered_on_degenerate_segments(route):
         if len(rows):
             ref[s] = np.sqrt(rows.var(axis=0) + 1e-5)
 
-    _, _, std_fused, _ = agg.fused_segment_stats(data, ids, n, row_ptr=row_ptr)
-    std_xla = seg.segment_std(data, ids, n)
-    err_fused = float(np.abs(np.asarray(std_fused, np.float64) - ref).max())
-    err_xla = float(np.abs(np.asarray(std_xla, np.float64) - ref).max())
+    std_fused = _run(lambda d: agg.fused_segment_stats(d, ids, n, row_ptr=row_ptr)[2], data)
+    std_xla = _run(lambda d: seg.segment_std(d, ids, n), data)
+    err_fused = float(np.abs(std_fused.astype(np.float64) - ref).max())
+    err_xla = float(np.abs(std_xla.astype(np.float64) - ref).max())
     assert err_fused < 1e-4, err_fused
     assert err_fused < err_xla  # strictly better than the uncentered form
 
@@ -152,39 +176,35 @@ def pytest_fused_dropin_wrappers_match_xla(route):
     rng = np.random.default_rng(1)
     data, ids, mask, n, row_ptr, real = _problem(rng, route)
 
-    np.testing.assert_allclose(
-        agg.fused_segment_sum(data, ids, n, mask=mask, row_ptr=row_ptr)[real],
-        seg.segment_sum(data, ids, n, mask=mask)[real], rtol=_RTOL, atol=_ATOL,
+    def fused_sum(d):
+        return agg.fused_segment_sum(d, ids, n, mask=mask, row_ptr=row_ptr)
+
+    got_sum, got_mean, (_, count) = _run(
+        lambda d: (
+            fused_sum(d),
+            agg.fused_segment_mean(d, ids, n, mask=mask, row_ptr=row_ptr),
+            agg.fused_segment_sum_count(d, ids, n, mask=mask, row_ptr=row_ptr),
+        ),
+        data,
     )
-    np.testing.assert_allclose(
-        agg.fused_segment_mean(data, ids, n, mask=mask, row_ptr=row_ptr)[real],
-        seg.segment_mean(data, ids, n, mask=mask)[real], rtol=_RTOL, atol=_ATOL,
-    )
-    total, count = agg.fused_segment_sum_count(
-        data, ids, n, mask=mask, row_ptr=row_ptr
-    )
-    np.testing.assert_array_equal(
-        count[real], seg.segment_count(ids, n, mask=mask)[real]
-    )
+    ref_sum, ref_mean, _, ref_count = _run(_xla_stats(ids, n, mask), data)
+    np.testing.assert_allclose(got_sum[real], ref_sum[real], rtol=_RTOL, atol=_ATOL)
+    np.testing.assert_allclose(got_mean[real], ref_mean[real], rtol=_RTOL, atol=_ATOL)
+    np.testing.assert_array_equal(count[real], ref_count[real])
 
     # 3-D ([E, h, f], the trailing dims flattened inside); no mask.
     d3 = jnp.asarray(rng.normal(size=(300, 3, 5)).astype(np.float32))
     np.testing.assert_allclose(
-        agg.fused_segment_sum(d3, ids, n, row_ptr=row_ptr),
-        seg.segment_sum(d3, ids, n), rtol=_RTOL, atol=_ATOL,
+        _run(lambda d: agg.fused_segment_sum(d, ids, n, row_ptr=row_ptr), d3),
+        _run(lambda d: seg.segment_sum(d, ids, n), d3), rtol=_RTOL, atol=_ATOL,
     )
 
     # bf16 in → bf16 out (mixed-precision dtype flow preserved).
-    out = agg.fused_segment_sum(
-        data.astype(jnp.bfloat16), ids, n, mask=mask, row_ptr=row_ptr
-    )
-    assert out.dtype == jnp.bfloat16
+    assert jax.eval_shape(fused_sum, data.astype(jnp.bfloat16)).dtype == jnp.bfloat16
 
     # Gradients flow (gather backward), masked rows get zero cotangent.
-    g = jax.grad(
-        lambda d: agg.fused_segment_sum(d, ids, n, mask=mask, row_ptr=row_ptr).sum()
-    )(data)
-    g_ref = jax.grad(lambda d: seg.segment_sum(d, ids, n, mask=mask).sum())(data)
+    g = _run(jax.grad(lambda d: fused_sum(d).sum()), data)
+    g_ref = _run(jax.grad(lambda d: seg.segment_sum(d, ids, n, mask=mask).sum()), data)
     np.testing.assert_allclose(g, g_ref, rtol=1e-5, atol=1e-5)
 
 
@@ -196,20 +216,19 @@ def pytest_fused_segment_softmax_matches_xla(route):
     _, ids, mask, n, row_ptr, _ = _problem(rng, route, e=200, n=30, f=1, padding=25)
     logits = jnp.asarray(rng.normal(size=(200, 6)).astype(np.float32) * 3)
 
-    a = agg.fused_segment_softmax(logits, ids, n, mask=mask, row_ptr=row_ptr)
-    b = seg.segment_softmax(logits, ids, n, mask=mask)
-    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
-    assert float(jnp.where(mask[:, None], a, 0.0).sum()) > 0
-    assert not bool(jnp.any(jnp.where(~mask[:, None], a, 0.0) != 0))
+    def fused(l):
+        return agg.fused_segment_softmax(l, ids, n, mask=mask, row_ptr=row_ptr)
 
-    ga = jax.grad(lambda l: (
-        agg.fused_segment_softmax(l, ids, n, mask=mask, row_ptr=row_ptr) ** 2
-    ).sum())(logits)
-    gb = jax.grad(lambda l: (seg.segment_softmax(l, ids, n, mask=mask) ** 2).sum())(logits)
+    a = _run(fused, logits)
+    b = _run(lambda l: seg.segment_softmax(l, ids, n, mask=mask), logits)
+    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    kept = np.asarray(mask)
+    assert a[kept].sum() > 0 and not a[~kept].any()
+
+    ga = _run(jax.grad(lambda l: (fused(l) ** 2).sum()), logits)
+    gb = _run(jax.grad(lambda l: (seg.segment_softmax(l, ids, n, mask=mask) ** 2).sum()), logits)
     np.testing.assert_allclose(ga, gb, rtol=1e-4, atol=1e-6)
-    text = jax.jit(
-        lambda l: agg.fused_segment_softmax(l, ids, n, mask=mask, row_ptr=row_ptr)
-    ).lower(logits).as_text(debug_info=True)
+    text = jax.jit(fused).lower(logits).as_text(debug_info=True)
     assert f"hydragnn.agg.softmax.{route}" in text
 
 
@@ -249,7 +268,7 @@ def pytest_fused_ops_differentiable_under_shard_map(route):
         check_vma=False,
     )
     g = jax.jit(jax.grad(lambda l: f(l, ids, row_ptr)))(logits)
-    g_one = jax.grad(lambda l: sum(terms(l, ids, use(row_ptr), None)))(logits)
+    g_one = _run(jax.grad(lambda l: sum(terms(l, ids, use(row_ptr), None))), logits)
     np.testing.assert_allclose(g, g_one, rtol=1e-4, atol=1e-5)
 
 
@@ -311,7 +330,7 @@ def pytest_gather_sorted_is_the_gather_and_its_gradient_the_sorted_sum(
     else:
         np.testing.assert_allclose(grad, truth, rtol=_RTOL, atol=_ATOL)
     # bf16 table in, bf16 gradient out (the sum itself runs in float32).
-    half = jax.grad(lambda t: jnp.sum(got(t).astype(jnp.float32) * weights))(
+    half = jax.jit(jax.grad(lambda t: jnp.sum(got(t).astype(jnp.float32) * weights)))(
         table.astype(jnp.bfloat16)
     )
     assert half.dtype == jnp.bfloat16
@@ -350,14 +369,16 @@ def pytest_gather_sorted_reduces_the_table_gradient_once_under_shard_map(route):
             )
             return jax.jit(jax.grad(lambda t: f(t, ids, weights, row_ptr)))
 
-        program = sharded(lambda t, i, ptr: agg.gather_sorted(t, i, use(ptr), "graph"))
-        grad = program(table)
+        compiled = sharded(
+            lambda t, i, ptr: agg.gather_sorted(t, i, use(ptr), "graph")
+        ).lower(table).compile()  # compiled once: run here, read below
+        grad = compiled(table)
         plain = sharded(lambda t, i, ptr: t[i])(table)
         np.testing.assert_allclose(grad, plain, rtol=_RTOL, atol=_ATOL)
         np.testing.assert_allclose(
             grad, _segment_sum_f64(weights, ids_np, n), rtol=_RTOL, atol=_ATOL
         )
-        text = program.lower(table).compile().as_text()
+        text = compiled.as_text()
         reduces = [
             line.split("metadata=")[1] for line in text.splitlines()
             if re.search(r"\sall-reduce\(", line.split("metadata=")[0])
@@ -448,7 +469,7 @@ def pytest_pna_aggregate_off_the_sorted_arm_is_the_masked_xla_ops_bit_for_bit(
 ):
     """Off the sorted arm (a CPU's default) nothing stands between PNA and
     ``ops/segment.py``: values and gradient equal to the bit, ``row_ptr`` or
-    not, and no other arm's name in the program."""
+    not, and no other arm's name in the bundle."""
     monkeypatch.delenv("HYDRAGNN_SEGMENT_SORTED", raising=False)
     rng = np.random.default_rng(5)
     data, ids, mask, n, row_ptr, _ = _problem(rng, "csr", e=120, n=16, f=8)
@@ -467,17 +488,15 @@ def pytest_pna_aggregate_off_the_sorted_arm_is_the_masked_xla_ops_bit_for_bit(
         return lambda d: jnp.sum(fn(d)[0] * weights)
 
     for ptr in (row_ptr, None):
-        def program(d):
+        def bundle(d):
             return agg.pna_aggregate(d, ids, n, aggregators, mask=mask, row_ptr=ptr)
 
-        got, cnt = jax.jit(program)(data)
+        got, cnt = jax.jit(bundle)(data)
         want, want_cnt = jax.jit(composed)(data)
         assert np.array_equal(np.asarray(got), np.asarray(want))
         assert np.array_equal(np.asarray(cnt), np.asarray(want_cnt))
         assert np.array_equal(
-            np.asarray(jax.jit(jax.grad(loss(program)))(data)),
+            np.asarray(jax.jit(jax.grad(loss(bundle)))(data)),
             np.asarray(jax.jit(jax.grad(loss(composed)))(data)),
         )
-        text = jax.jit(program).lower(data).as_text(debug_info=True)
-        arms = set(re.findall(r"hydragnn\.agg\.\w+\.(\w+)", text))
-        assert arms == {"xla"}, arms
+        assert _arms(bundle, data) == {"xla"}

@@ -4,8 +4,6 @@ the CPU, through the dispatch a TPU takes (``segment_causal_attention`` under
 ``platform_override("tpu")``, undifferentiated) against the loop over query
 blocks the CPU takes and against a dense masked softmax on every row."""
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +12,7 @@ import pytest
 from hydragnn_tpu.models import token_attention
 from hydragnn_tpu.ops import block_attention
 from hydragnn_tpu.ops.segment import platform_override
+from tests.conftest import program
 
 BLOCK = token_attention.ATTN_BLOCK
 
@@ -30,12 +29,20 @@ def _dense(q, k, v, ids, scale):
     float64: the library kernel's semantics, the padding rows' included."""
     n, heads, _ = q.shape
     rep = heads // k.shape[1]
-    keep = (ids[:, None] == ids[None, :]) & np.tri(n, dtype=bool)
     out = np.empty(q.shape, np.float64)
-    for h in range(heads):
-        s = np.where(keep, q[:, h].astype(np.float64) @ k[:, h // rep].T * scale, -np.inf)
-        p = np.exp(s - s.max(axis=1, keepdims=True))
-        out[:, h] = (p / p.sum(axis=1, keepdims=True)) @ v[:, h // rep]
+    # A block of rows at a time against every key: the same numbers as the
+    # whole [n, n] table, whose 134 MB temporaries are the seconds here.
+    for lo in range(0, n, BLOCK):
+        rows = slice(lo, lo + BLOCK)
+        keep = (ids[rows, None] == ids[None, :]) & (
+            np.arange(n)[None, :] <= np.arange(n)[rows, None]
+        )
+        for h in range(heads):
+            s = np.where(
+                keep, q[rows, h].astype(np.float64) @ k[:, h // rep].T * scale, -np.inf
+            )
+            p = np.exp(s - s.max(axis=1, keepdims=True))
+            out[rows, h] = (p / p.sum(axis=1, keepdims=True)) @ v[:, h // rep]
     return out.reshape(n, -1)
 
 
@@ -59,13 +66,22 @@ def pytest_block_range_kernel_is_the_blockwise_path_and_the_dense_softmax(case, 
     )
     scale = hd ** -0.5
     args = (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(ids))
-    blockwise = np.asarray(token_attention.segment_causal_attention(*args))
-    monkeypatch.setattr(
-        token_attention, "block_range_attention",
-        functools.partial(block_attention.block_range_attention, interpret=True),
-    )
+    # Each dispatch is ONE program, traced where its arm is decided (a
+    # function object of its own each: ``tests/conftest.py`` ``program``): op
+    # by op the loop over query blocks compiles every block's every primitive
+    # alone.
+    kernel_calls = []
+
+    def interpreted(*a, **kw):
+        kernel_calls.append(a[0].shape)
+        return block_attention.block_range_attention(*a, interpret=True, **kw)
+
+    monkeypatch.setattr(token_attention, "block_range_attention", interpreted)
+    blockwise = np.asarray(program(token_attention.segment_causal_attention)(*args))
+    assert not kernel_calls  # the CPU's arm: the loop over query blocks
     with platform_override("tpu"):
-        got = np.asarray(token_attention.segment_causal_attention(*args))
+        got = np.asarray(program(token_attention.segment_causal_attention)(*args))
+    assert len(kernel_calls) == 1  # the TPU's arm: the kernel, interpreted here
     assert got.shape == (rows, heads * hd)
     assert np.abs(got - blockwise).max() < 5e-6
     assert np.abs(got - _dense(q, k, v, ids, scale)).max() < 5e-6

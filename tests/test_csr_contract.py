@@ -26,6 +26,7 @@ from hydragnn_tpu.graphs.sample import GraphSample
 from hydragnn_tpu.ops import aggregate as agg
 from hydragnn_tpu.ops import segment as seg
 from hydragnn_tpu.ops import segment_sorted as srt
+from tests.conftest import forward
 
 
 def _random_graphs(rng, count=6, fdim=3, edge_dim=None, target=True):
@@ -151,9 +152,8 @@ def pytest_model_forward_bit_exact_with_and_without_row_ptr(monkeypatch):
         edge_dim=2,
     )
     variables = init_model_variables(model, batch)
-    with_ptr = model.apply(variables, batch, train=False)
-    stripped = batch.replace(row_ptr=None, graph_ptr=None)
-    without_ptr = model.apply(variables, stripped, train=False)
+    with_ptr = forward(model, variables, batch)
+    without_ptr = forward(model, variables, batch.replace(row_ptr=None, graph_ptr=None))
     # Op-level the two variants are bit-exact (previous test); whole-program
     # XLA fusion may differ between the traces, so allow ulp-level noise.
     for a, b in zip(with_ptr, without_ptr):
@@ -245,40 +245,35 @@ def pytest_gat_self_term_parity_vs_reference_concat(monkeypatch):
     batch = collate_graphs(_random_graphs(rng), ["graph"], [1])
     heads, f = 4, 6
     conv = GATv2Conv(out_dim=f, heads=heads, negative_slope=0.05)
-    variables = conv.init(
-        jax.random.PRNGKey(0), batch.node_features, batch.senders,
-        batch.receivers, None, batch.edge_mask, batch.node_mask, train=False,
-    )
-    out_new = np.asarray(
-        conv.apply(
-            variables, batch.node_features, batch.senders, batch.receivers,
-            None, batch.edge_mask, batch.node_mask, train=False,
-            row_ptr=batch.row_ptr,
-        )
-    )
-
-    # Reference concat formulation, from the SAME parameters.
-    p = variables["params"]
+    graph = (batch.senders, batch.receivers, None, batch.edge_mask, batch.node_mask)
     x = jnp.asarray(batch.node_features)
     n = x.shape[0]
-    x_src = (x @ p["lin_src"]["kernel"] + p["lin_src"]["bias"]).reshape(
-        n, heads, f
-    )
-    x_dst = (x @ p["lin_dst"]["kernel"] + p["lin_dst"]["bias"]).reshape(
-        n, heads, f
-    )
-    s = jnp.concatenate([batch.senders, jnp.arange(n, dtype=jnp.int32)])
-    r = jnp.concatenate([batch.receivers, jnp.arange(n, dtype=jnp.int32)])
-    m = jnp.concatenate([batch.edge_mask, batch.node_mask])
-    import flax.linen as nn
+    # Each side ONE program (the initializer, the conv, the reference).
+    variables = jax.jit(
+        lambda x: conv.init(jax.random.PRNGKey(0), x, *graph, train=False)
+    )(x)
+    out_new = np.asarray(jax.jit(
+        lambda v, x: conv.apply(v, x, *graph, train=False, row_ptr=batch.row_ptr)
+    )(variables, x))
 
-    pre = nn.leaky_relu(x_src[s] + x_dst[r], 0.05)
-    logits = jnp.einsum("ehf,hf->eh", pre, p["att"])
-    alpha = seg.segment_softmax(logits, r, n, mask=m)
-    msgs = jnp.where(m[:, None, None], x_src[s] * alpha[..., None], 0.0)
-    out_ref = np.asarray(
-        seg.segment_sum(msgs, r, n).reshape(n, heads * f) + p["bias"]
-    )
+    def reference(p, x):
+        """The concat formulation, from the SAME parameters."""
+        x_src = (x @ p["lin_src"]["kernel"] + p["lin_src"]["bias"]).reshape(
+            n, heads, f
+        )
+        x_dst = (x @ p["lin_dst"]["kernel"] + p["lin_dst"]["bias"]).reshape(
+            n, heads, f
+        )
+        s = jnp.concatenate([batch.senders, jnp.arange(n, dtype=jnp.int32)])
+        r = jnp.concatenate([batch.receivers, jnp.arange(n, dtype=jnp.int32)])
+        m = jnp.concatenate([batch.edge_mask, batch.node_mask])
+        pre = nn.leaky_relu(x_src[s] + x_dst[r], 0.05)
+        logits = jnp.einsum("ehf,hf->eh", pre, p["att"])
+        alpha = seg.segment_softmax(logits, r, n, mask=m)
+        msgs = jnp.where(m[:, None, None], x_src[s] * alpha[..., None], 0.0)
+        return seg.segment_sum(msgs, r, n).reshape(n, heads * f) + p["bias"]
+
+    out_ref = np.asarray(jax.jit(reference)(variables["params"], x))
     real = np.asarray(batch.node_mask)
     np.testing.assert_allclose(
         out_new[real], out_ref[real], rtol=2e-5, atol=2e-5
@@ -303,34 +298,34 @@ def pytest_gat_isolated_node_keeps_self_attention():
     node_mask = jnp.asarray([True, True, False, False])
 
     conv = GATv2Conv(out_dim=f, heads=heads, negative_slope=0.05)
-    variables = conv.init(
-        jax.random.PRNGKey(1), x, senders, receivers, None, edge_mask,
-        node_mask, train=False,
-    )
-    p = variables["params"]
-    import flax.linen as nn
+    graph = (senders, receivers, None, edge_mask, node_mask)
+    variables = jax.jit(
+        lambda x: conv.init(jax.random.PRNGKey(1), x, *graph, train=False)
+    )(x)
 
-    x_src = (x @ p["lin_src"]["kernel"] + p["lin_src"]["bias"]).reshape(
-        n_pad, heads, f
-    )
-    x_dst = (x @ p["lin_dst"]["kernel"] + p["lin_dst"]["bias"]).reshape(
-        n_pad, heads, f
-    )
-    logit_self = jnp.einsum(
-        "nhf,hf->nh", nn.leaky_relu(x_src + x_dst, 0.05), p["att"]
-    )
+    def by_hand(p, x):
+        """(every node's self logit, its source row + bias: what a node whose
+        ``alpha_self`` is 1 keeps)."""
+        x_src = (x @ p["lin_src"]["kernel"] + p["lin_src"]["bias"]).reshape(
+            n_pad, heads, f
+        )
+        x_dst = (x @ p["lin_dst"]["kernel"] + p["lin_dst"]["bias"]).reshape(
+            n_pad, heads, f
+        )
+        logit_self = jnp.einsum(
+            "nhf,hf->nh", nn.leaky_relu(x_src + x_dst, 0.05), p["att"]
+        )
+        return logit_self, x_src.reshape(n_pad, heads * f) + p["bias"]
+
+    logit_self, want = jax.jit(by_hand)(variables["params"], x)
     # The scenario must actually cover the underflow regime on a real node.
-    assert float(logit_self[:2].min()) < -100.0
+    assert float(np.asarray(logit_self)[:2].min()) < -100.0
 
     out = np.asarray(
-        conv.apply(
-            variables, x, senders, receivers, None, edge_mask, node_mask,
-            train=False,
-        )
+        jax.jit(lambda v, x: conv.apply(v, x, *graph, train=False))(variables, x)
     )
     # alpha_self == 1 everywhere real ⇒ out = x_src (flattened) + bias.
-    want = np.asarray(x_src.reshape(n_pad, heads * f) + p["bias"])
-    np.testing.assert_allclose(out[:2], want[:2], rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(out[:2], np.asarray(want)[:2], rtol=1e-6, atol=1e-6)
 
 
 class _Rank3GATv2Conv(nn.Module):
@@ -412,13 +407,19 @@ def pytest_gat_flat_rows_match_rank3_formulation(heads, f, concat, train):
     flat, rank3 = GATv2Conv(**kw), _Rank3GATv2Conv(**kw)
     x = jnp.asarray(batch.node_features)
     graph = (batch.senders, batch.receivers, None, batch.edge_mask, batch.node_mask)
-    params = flat.init(jax.random.PRNGKey(0), x, *graph, train=False)["params"]
+    # The initializers and each formulation's loss and gradients: one
+    # program each.
+    params = jax.jit(
+        lambda x: flat.init(jax.random.PRNGKey(0), x, *graph, train=False)
+    )(x)["params"]
     # A zero bias and its zero gradient would compare as equal whatever ran.
     params = dict(params, bias=jnp.asarray(
         rng.normal(size=params["bias"].shape).astype(np.float32)
     ))
     assert jax.tree_util.tree_structure(params) == jax.tree_util.tree_structure(
-        rank3.init(jax.random.PRNGKey(0), x, *graph, train=False)["params"]
+        jax.eval_shape(
+            lambda x: rank3.init(jax.random.PRNGKey(0), x, *graph, train=False), x
+        )["params"]
     )
     weight = jnp.asarray(
         rng.normal(size=(x.shape[0], heads * f if concat else f)).astype(np.float32)
@@ -432,9 +433,9 @@ def pytest_gat_flat_rows_match_rank3_formulation(heads, f, concat, train):
             )
             return (out * weight).sum(), out
 
-        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
-            params, x
-        )
+        (_, out), grads = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+        )(params, x)
         return out, grads
 
     out, (g_params, g_x) = run(flat)
@@ -474,12 +475,10 @@ def pytest_gat_rides_sorted_path_with_zero_searchsorted(monkeypatch):
     )
     variables = init_model_variables(model, batch)
     before = srt.searchsorted_calls()
-    out = jax.jit(lambda b: model.apply(variables, b, train=False))(batch)
+    out = forward(model, variables, batch)
     jax.block_until_ready(out)
     assert srt.searchsorted_calls() == before
-    out_stripped = model.apply(
-        variables, batch.replace(row_ptr=None, graph_ptr=None), train=False
-    )
+    out_stripped = forward(model, variables, batch.replace(row_ptr=None, graph_ptr=None))
     # The segment op itself is bit-exact either way (the op-level test
     # above); at whole-program level XLA may fuse the two traces differently
     # (searchsorted present vs absent), so the model comparison allows ulp
@@ -502,13 +501,11 @@ def pytest_debug_layout_hook_fails_loudly_on_unsorted_ids(monkeypatch):
     good = jnp.asarray(np.sort(rng.integers(0, 10, 64)).astype(np.int32))
     bad = jnp.asarray(rng.permutation(np.asarray(good)).astype(np.int32))
 
-    out = agg.fused_segment_sum(data, good, 10)
-    jax.block_until_ready(out)  # valid layout: no error
+    total = jax.jit(lambda d, ids: agg.fused_segment_sum(d, ids, 10))
+    jax.block_until_ready(total(data, good))  # valid layout: no error
 
     with pytest.raises(Exception, match="sorted-layout contract"):
-        jax.block_until_ready(
-            agg.fused_segment_sum(data, bad, 10)
-        )
+        jax.block_until_ready(total(data, bad))
 
 
 def pytest_debug_layout_hook_off_by_default(monkeypatch):
@@ -518,7 +515,7 @@ def pytest_debug_layout_hook_off_by_default(monkeypatch):
     data = jnp.asarray(rng.normal(size=(32, 3)).astype(np.float32))
     bad = jnp.asarray(rng.integers(0, 8, 32).astype(np.int32))
     # Off by default: garbage in, garbage out, but NO runtime callback cost.
-    out = agg.fused_segment_sum(data, bad, 8)
+    out = jax.jit(lambda d, ids: agg.fused_segment_sum(d, ids, 8))(data, bad)
     jax.block_until_ready(out)
 
 

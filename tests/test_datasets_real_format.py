@@ -89,3 +89,48 @@ def pytest_md17_parses_rmd17_layout():
     assert len(samples) == 3
     assert samples[0].num_nodes == 12
     assert samples[0].forces.shape == (12, 3)
+
+
+def pytest_a_reader_never_sees_a_partial_serialized_file(tmp_path, monkeypatch):
+    """Raw LSMS files -> ``serialized_dataset/<name>.pkl`` again while the file
+    is in use (six test workers train the same dataset from one checkout): a
+    reader that opens it between any two of the writer's three dumps gets the
+    whole of the file that was there, never a truncated one."""
+    import pickle
+
+    from hydragnn_tpu.preprocess import raw_loader
+    from tests.deterministic_graph_data import deterministic_graph_data
+
+    raw = tmp_path / "dataset" / "unit_test"
+    os.makedirs(raw)
+    deterministic_graph_data(str(raw), number_configurations=12)
+    monkeypatch.setenv("SERIALIZED_DATA_PATH", str(tmp_path))
+    config = {
+        "name": "unit_test", "format": "unit_test", "path": {"total": str(raw)},
+        "node_features": {"name": ["x", "x2", "x3"], "dim": [1, 1, 1], "column_index": [0, 6, 7]},
+        "graph_features": {"name": ["sum_x_x2_x3"], "dim": [1], "column_index": [0]},
+    }
+    raw_loader.RawDataLoader(config).load_raw_data()
+    path = tmp_path / "serialized_dataset" / "unit_test.pkl"
+
+    def read():
+        with open(path, "rb") as f:
+            return [pickle.load(f) for _ in range(3)]  # a short file: EOFError
+
+    first = read()
+    assert len(first[2]) == 12
+    seen = []
+    dump = pickle.dump
+
+    def dump_then_read(obj, f):
+        dump(obj, f)
+        seen.append(read())
+
+    monkeypatch.setattr(raw_loader.pickle, "dump", dump_then_read)
+    raw_loader.RawDataLoader(config).load_raw_data()
+    monkeypatch.undo()
+    assert len(seen) == 3
+    for got in seen + [read()]:
+        assert np.array_equal(got[0], first[0]) and np.array_equal(got[1], first[1])
+        assert len(got[2]) == 12
+    assert os.listdir(path.parent) == ["unit_test.pkl"]  # no temporary name left

@@ -38,7 +38,7 @@ from hydragnn_tpu.ops import block_attention, selective_scan  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
 from tests.test_lfm2 import (  # noqa: E402, F401
-    _collate, _sequences, compiled, init_variables, programs,
+    _collate, _sequences, compiled, programs,
 )
 
 V, D, LAYERS = sibling.V, 32, 4  # the sibling's sequences: ids under its V
@@ -86,7 +86,7 @@ def setup():
     model = _model()
     graphs = _sequences(LENGTHS)
     batch = _collate(graphs)
-    variables = shaken(init_variables(model, batch), 45)
+    variables = shaken(init_model_variables(model, batch), 45)
     return model, graphs, batch, variables
 
 
@@ -111,7 +111,7 @@ def pytest_one_block_of_a_kind_against_the_reference(kind):
     model = _model(layers=1, attn_layer_offset=1 if kind == "mamba" else 0)
     graphs = _sequences(LENGTHS, seed=3)
     batch = _collate(graphs)
-    variables = shaken(init_variables(model, batch), 7)
+    variables = shaken(init_model_variables(model, batch), 7)
     block = variables["params"]["conv_0"]
     assert ("mamba" in block) == (kind == "mamba") and ("self_attn" in block) == (kind != "mamba")
     got = _logits(model, variables, batch)
@@ -295,7 +295,7 @@ def pytest_entry_points_refuse_what_the_family_cannot_run():
     from hydragnn_tpu.serve import InferenceEngine
 
     model = _model()
-    variables = init_variables(model, _collate(_sequences((5,))))
+    variables = init_model_variables(model, _collate(_sequences((5,))))
     with pytest.raises(ValueError, match="float32 node"):
         InferenceEngine(model, variables, precision="bf16", autostart=False)
 
@@ -426,7 +426,7 @@ def pytest_a_stack_with_no_scan_counts_none():
 
     model = sibling._model()
     graphs = _sequences((5, 9))
-    variables = init_variables(model, _collate(graphs))
+    variables = init_model_variables(model, _collate(graphs))
     with InferenceEngine(model, variables, max_batch_graphs=2, max_delay_ms=1.0,
                          bucket_ladder=[32], warmup=True) as eng:
         assert eng._scans is False

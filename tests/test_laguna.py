@@ -31,15 +31,14 @@ sys.path.insert(0, REPO)
 from graftbench.drivers.train_epochs import shaken  # noqa: E402
 from graftbench.families import laguna as plain  # noqa: E402
 from hydragnn_tpu.graphs import collate_graphs  # noqa: E402
-from hydragnn_tpu.models import create_model  # noqa: E402
+from hydragnn_tpu.models import create_model, init_model_variables  # noqa: E402
 from hydragnn_tpu.models import (  # noqa: E402
     laguna, token_attention, token_common, token_routed,
 )
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
 from tests.test_lfm2 import (  # noqa: E402, F401
-    _collate, _forward, _rows, _sequences, init_variables, loss_and_grads, loss_of,
-    programs,
+    _collate, _forward, _rows, _sequences, loss_and_grads, loss_of, programs,
 )
 
 V, D, LAYERS = sibling.V, 32, 5  # the sibling's sequences: ids under its V
@@ -72,7 +71,7 @@ def setup():
     model = _model()
     graphs = _sequences((5, 9, 12))
     batch = _collate(graphs)
-    variables = shaken(init_variables(model, batch), 33)
+    variables = shaken(init_model_variables(model, batch), 33)
     return model, graphs, batch, variables
 
 
@@ -405,24 +404,27 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     model = _model(remat=True)
     batch = _collate(_sequences((5, 9, 12)))
     opt = select_optimizer("AdamW", 1e-3)
-    state = create_train_state(model, init_variables(model, batch), opt)
+    state = create_train_state(model, init_model_variables(model, batch), opt)
     assert state.batch_stats == {} and model.counts_routing
     step = make_train_step(model, opt, donate=False)
-    used, names = used_scopes(step.lower(state, batch, jax.random.PRNGKey(0)).compile().as_text())
+    program = step.lower(state, batch, jax.random.PRNGKey(0)).compile()
+    used, names = used_scopes(program.as_text())
     assert {scopes.ATTN_FULL, scopes.ATTN_WINDOW, scopes.MOE_ROUTE, scopes.MOE_EXPERTS,
             scopes.LOSS, scopes.OPTIMIZER, scopes.TRAIN_STEP} <= used
     assert used <= scopes.VOCABULARY and not {scopes.LFM2_ATTN, scopes.LFM2_CONV} & used
     backward = [n for n in names if "transpose(" in n]
     for scope in (scopes.ATTN_FULL, scopes.ATTN_WINDOW, scopes.MOE_EXPERTS):
         assert any(scope in n for n in backward), scope
-    _, metrics = step(state, batch, jax.random.PRNGKey(0))
+    _, metrics = program(state, batch, jax.random.PRNGKey(0))  # the one read above
     assert set(metrics) == {"loss", "rmses", "count", *token_routed.COUNTERS}
     assert 0 <= float(metrics["moe_load_min"]) <= float(metrics["moe_load_max"])
     assert 0 < float(metrics["moe_rows_held"]) <= 4 * 26 * 2
 
     lfm2_model = sibling._model()  # the sibling's small model
     sbatch = _collate(_sequences((5, 9)))
-    sstate = create_train_state(lfm2_model, init_variables(lfm2_model, sbatch), opt)
+    sstate = jax.eval_shape(lambda: create_train_state(  # lowered, never run: shapes
+        lfm2_model, init_model_variables(lfm2_model, sbatch), opt
+    ))
     used, _ = used_scopes(
         make_train_step(lfm2_model, opt, donate=False)
         .lower(sstate, sbatch, jax.random.PRNGKey(0)).compile().as_text()
@@ -436,7 +438,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     )
     assert not classic.counts_routing and classic.token_cfg is None
     cbatch = collate_graphs(_sequences((5, 9)), ("node",), (1,))
-    cstate = create_train_state(classic, init_variables(classic, cbatch), opt)
+    cstate = create_train_state(classic, init_model_variables(classic, cbatch), opt)
     _, cmetrics = make_train_step(classic, opt, donate=False)(
         cstate, cbatch, jax.random.PRNGKey(0)
     )

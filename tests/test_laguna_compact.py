@@ -13,11 +13,9 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from graftbench.drivers.train_epochs import shaken  # noqa: E402
-from hydragnn_tpu.models import token_routed  # noqa: E402
+from hydragnn_tpu.models import init_model_variables, token_routed  # noqa: E402
 from tests.test_laguna import ARCH, ROUTED, _model  # noqa: E402
-from tests.test_lfm2 import (  # noqa: E402
-    _collate, _sequences, assert_bit_equal, init_variables,
-)
+from tests.test_lfm2 import _collate, _sequences, assert_bit_equal  # noqa: E402
 
 
 @pytest.mark.parametrize("where", ["rematerialized_blocks", "scanned_epoch"])
@@ -38,7 +36,7 @@ def pytest_compact_row_arrays_inside_the_model(where, monkeypatch):
     batch = _collate(_sequences((60, 70, 40)))
     rows = batch.node_features.shape[0] * ARCH["num_experts_per_tok"]
     assert token_routed._capacity(rows, 4, 16) == 256 < 320 < rows
-    variables = shaken(init_variables(model, batch), 35)
+    variables = shaken(init_model_variables(model, batch), 35)
     opt = select_optimizer("AdamW", 1e-3)
 
     def run():

@@ -10,11 +10,12 @@ whole slice; the scopes and counters; the routed layer's compact ``[C, .]``
 path against its ``[K N, .]`` fall-back, bit for bit; the family through
 ``run_training``. Values and counts, never a time.
 
-A whole stack is initialised and run under ``jit``, once a model: op by op
-outside it every primitive of every shape compiles alone, and that was a
-third of the suite's seconds (``compiled``, ``init_variables`` below, which
-the four sibling families' files import). What is about the eager path
-itself (``RoutedFFN`` run as the initializer runs it) stays eager."""
+A whole stack is run under ``jit``, once a model (the library's initializer
+is one program already): op by op outside it every primitive of every shape
+compiles alone, and that was a third of the suite's seconds (``compiled``
+below, which the four sibling families' files import). What is about the
+eager path itself (``RoutedFFN`` run as the initializer runs it) stays
+eager."""
 
 import copy
 import json
@@ -103,11 +104,6 @@ def compiled(model, purpose, function):
     return _PROGRAMS[key][1]
 
 
-def init_variables(model, batch, seed=0):
-    """``init_model_variables`` as ONE compiled program."""
-    return jax.jit(lambda batch: init_model_variables(model, batch, seed))(batch)
-
-
 def apply_routed(model, params, batch):
     """(outputs, what the routed layers sowed) of the compiled forward."""
     return compiled(model, "forward", lambda params, batch: model.apply(
@@ -148,7 +144,7 @@ def setup():
     model = _model()
     graphs = _sequences((5, 9, 12))
     batch = _collate(graphs)
-    variables = shaken(init_variables(model, batch), 31)
+    variables = shaken(init_model_variables(model, batch), 31)
     return model, graphs, batch, variables
 
 
@@ -483,10 +479,11 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     model = _model()
     batch = _collate(_sequences((5, 9, 12)))
     opt = select_optimizer("AdamW", 1e-3)
-    state = create_train_state(model, init_variables(model, batch), opt)
+    state = create_train_state(model, init_model_variables(model, batch), opt)
     assert state.batch_stats == {} and model.counts_routing
     step = make_train_step(model, opt, donate=False)
-    text = step.lower(state, batch, jax.random.PRNGKey(0)).compile().as_text()
+    program = step.lower(state, batch, jax.random.PRNGKey(0)).compile()
+    text = program.as_text()
     used = set(re.findall(r"hydragnn\.[\w.]+", " ".join(re.findall(r'op_name="([^"]*)"', text))))
     assert {scopes.LFM2_CONV, scopes.LFM2_ATTN, scopes.MOE_ROUTE, scopes.MOE_EXPERTS,
             scopes.LOSS, scopes.OPTIMIZER, scopes.TRAIN_STEP} <= used
@@ -494,7 +491,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     backward = [n for n in re.findall(r'op_name="([^"]*)"', text) if "transpose(" in n]
     assert any(scopes.MOE_EXPERTS in n for n in backward)
     assert any(scopes.LFM2_ATTN in n for n in backward)
-    new_state, metrics = step(state, batch, jax.random.PRNGKey(0))
+    new_state, metrics = program(state, batch, jax.random.PRNGKey(0))  # the one read above
     assert set(metrics) == {"loss", "rmses", "count", *token_routed.COUNTERS}
     assert 0 < float(metrics["moe_load_min"]) <= float(metrics["moe_load_max"])
     assert float(metrics["moe_rows_held"]) <= 3 * 26 * 2
@@ -505,7 +502,7 @@ def pytest_train_step_scopes_counters_and_other_families_untouched():
     )
     assert not classic.counts_routing and classic.head_loss == ()
     cbatch = collate_graphs(_sequences((5, 9)), ("node",), (1,))
-    cstate = create_train_state(classic, init_variables(classic, cbatch), opt)
+    cstate = create_train_state(classic, init_model_variables(classic, cbatch), opt)
     _, cmetrics = make_train_step(classic, opt, donate=False)(
         cstate, cbatch, jax.random.PRNGKey(0)
     )

@@ -41,7 +41,7 @@ from hydragnn_tpu.models.layers import scaled_ids  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
 from tests.test_lfm2 import (  # noqa: E402, F401
-    _collate, _sequences, apply_routed, init_variables, programs,
+    _collate, _sequences, apply_routed, programs,
 )
 
 V, D, LAYERS, WINDOW = sibling.V, 32, 4, 8  # the sibling's sequences: ids under its V
@@ -92,7 +92,7 @@ def setup():
     model = _model()
     graphs = _sequences(LENGTHS)
     batch = _collate(graphs)
-    variables = shaken(init_variables(model, batch), 41)
+    variables = shaken(init_model_variables(model, batch), 41)
     return model, graphs, batch, variables
 
 
@@ -125,7 +125,7 @@ def pytest_one_block_of_a_kind_against_the_reference(kind):
     model = _model(layers=1, layer_types=[kind], mlp_layer_types=["sparse"])
     graphs = _sequences(LENGTHS, seed=3)
     batch = _collate(graphs)
-    variables = shaken(init_variables(model, batch), 7)
+    variables = shaken(init_model_variables(model, batch), 7)
     got, routing, _ = _forward(model, variables, batch)
     other = _model(
         layers=1, mlp_layer_types=["sparse"],
@@ -519,7 +519,7 @@ def pytest_engine_counts_the_key_blocks_of_both_kinds(setup, engine, monkeypatch
     assert all(f"hydragnn_serve_{name} " in text for name in names)
     assert set(names) <= set(engine.metrics.snapshot())
     full = _model(layers=1, layer_types=["full_attention"], mlp_layer_types=["sparse"])
-    with InferenceEngine(full, shaken(init_variables(full, batch), 3), max_batch_graphs=1,
+    with InferenceEngine(full, shaken(init_model_variables(full, batch), 3), max_batch_graphs=1,
                          max_delay_ms=1.0, bucket_ladder=[32], warmup=True) as eng:
         assert eng._band_window is None
         assert flush(eng, _requests(graphs)[:1]) == (10, 10, 0)

@@ -31,7 +31,7 @@ sys.path.insert(0, REPO)
 from graftbench.drivers.train_epochs import shaken  # noqa: E402
 from graftbench.families import mistral4 as plain  # noqa: E402
 from hydragnn_tpu.graphs import GraphSample, collate_graphs  # noqa: E402
-from hydragnn_tpu.models import create_model  # noqa: E402
+from hydragnn_tpu.models import create_model, init_model_variables  # noqa: E402
 from hydragnn_tpu.models import (  # noqa: E402
     laguna, lfm2, mistral4, token_attention, token_common, token_routed,
 )
@@ -39,7 +39,7 @@ from hydragnn_tpu.models.base import HydraGNN  # noqa: E402
 from hydragnn_tpu.telemetry import scopes  # noqa: E402
 from tests import test_lfm2 as sibling  # noqa: E402
 from tests.test_lfm2 import (  # noqa: E402, F401
-    _collate, _sequences, apply_routed, compiled, init_variables, loss_and_grads, programs,
+    _collate, _sequences, apply_routed, compiled, loss_and_grads, programs,
 )
 
 V, D, LAYERS = sibling.V, 32, 3  # the sibling's sequences: ids under its V
@@ -80,7 +80,7 @@ def setup():
     model = _model()
     graphs = _sequences((5, 9, 30))  # the third runs past the 8 trained places
     batch = _collate(graphs)
-    variables = shaken(init_variables(model, batch), 39)
+    variables = shaken(init_model_variables(model, batch), 39)
     return model, graphs, batch, variables
 
 
@@ -325,7 +325,7 @@ def pytest_entry_points_refuse_what_the_family_cannot_run():
         _model(first_k_dense_replace=1, intermediate_size=0)
     dense_first = _model(first_k_dense_replace=1)
     batch = _collate(_sequences((5,)))
-    params = init_variables(dense_first, batch)["params"]
+    params = init_model_variables(dense_first, batch)["params"]
     assert "shared_experts" not in params["conv_0"] and "shared_experts" in params["conv_1"]
     assert params["conv_0"]["feed_forward"]["w1"]["kernel"].shape == (D, 48)
     with pytest.raises(ValueError, match="positions"):
@@ -563,7 +563,7 @@ def pytest_engine_counts_a_layer_past_its_capacity():
     # 8 experts, 2 a token, 2 held: C = 1.5 x 2 x 300 x 2 / 8 -> 256 rows.
     model = _model(layers=1, num_experts_held=2, experts_offset=0)
     g = _sequences((280,), seed=3)[0]
-    variables = shaken(init_variables(model, _collate([g])), 5)
+    variables = shaken(init_model_variables(model, _collate([g])), 5)
     gate = np.array(variables["params"]["conv_0"]["feed_forward"]["gate"])
     gate[:, :2] *= 30.0  # nearly every token chooses the two held experts
     variables["params"]["conv_0"]["feed_forward"]["gate"] = jnp.asarray(gate)

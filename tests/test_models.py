@@ -9,6 +9,7 @@ import pytest
 
 from hydragnn_tpu.graphs import GraphSample, collate_graphs
 from hydragnn_tpu.models import create_model, init_model_variables, multihead_rmse_loss
+from tests.conftest import forward
 
 HEADS = {
     "graph": {
@@ -40,11 +41,11 @@ def _graphs(rng, count=3, fdim=1):
     return out
 
 
-def _build(model_type, edge_dim=None):
+def _build(model_type, edge_dim=None, hidden=8):
     types = ("graph", "node", "node")
     dims = (1, 1, 1)
     model = create_model(
-        model_type, 1, 8, dims, types, HEADS, [1.0, 1.0, 1.0], 2,
+        model_type, 1, hidden, dims, types, HEADS, [1.0, 1.0, 1.0], 2,
         max_neighbours=8, edge_dim=edge_dim,
         pna_deg=[0, 0, 4, 4] if model_type == "PNA" else None,
     )
@@ -76,6 +77,81 @@ def pytest_forward_and_grad(model_type):
     assert any(np.abs(np.asarray(g)).max() > 0 for g in flat)
 
 
+def _tiny(family, width=0):
+    """(model, batch): PNA with its three heads, or LFM2's four token layers;
+    ``width`` more columns than any other test's model has."""
+    if family == "PNA":
+        model, types, dims = _build("PNA", 1, hidden=8 + width)
+        return model, collate_graphs(_graphs(np.random.default_rng(0)), types, dims, edge_dim=1)
+    from tests import test_lfm2 as token
+
+    model = token._model(intermediate_size=token.ARCH["intermediate_size"] + width)
+    return model, token._collate(token._sequences((5, 9)))
+
+
+@pytest.mark.parametrize("family", ["PNA", "LFM2"])
+def pytest_the_initializer_is_one_program(family):
+    """``init_model_variables`` asks the compiler for ONE program, whatever
+    the family (op by op PNA asked 149 times; a second request is allowed
+    for a constant the tracer folds ahead of the program), and for none the
+    next time an equal model is initialised over the same shapes: fresh
+    buffers of the same values."""
+    from hydragnn_tpu.analysis.sentinel import compile_count
+
+    # A model no other test of this process builds: an equal one initialised
+    # earlier would have left its program behind.
+    model, batch = _tiny(family, width=4)
+    before = compile_count()
+    variables = jax.block_until_ready(init_model_variables(model, batch, seed=7))
+    assert 1 <= compile_count() - before <= 2
+    # An equal model over equal shapes (a reload inside one process): none.
+    before = compile_count()
+    again = jax.block_until_ready(init_model_variables(_tiny(family, width=4)[0], batch, seed=7))
+    assert compile_count() == before
+    assert all(
+        np.array_equal(a, b) and a.unsafe_buffer_pointer() != b.unsafe_buffer_pointer()
+        for a, b in zip(jax.tree_util.tree_leaves(again), jax.tree_util.tree_leaves(variables))
+    )
+    # Another seed is another argument of the same program: other values, no
+    # compile.
+    other = jax.block_until_ready(init_model_variables(model, batch, seed=8))
+    assert compile_count() == before
+    assert not all(
+        np.array_equal(a, b)
+        for a, b in zip(jax.tree_util.tree_leaves(other), jax.tree_util.tree_leaves(variables))
+    )
+    # Asked for its shapes alone (a server sizing its template), it compiles
+    # and allocates nothing.
+    before = compile_count()
+    shapes = jax.eval_shape(lambda: init_model_variables(model, batch, seed=7))
+    assert compile_count() == before
+    assert jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), variables) == (
+        jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), shapes)
+    )
+    assert all(
+        isinstance(a, jax.ShapeDtypeStruct) for a in jax.tree_util.tree_leaves(shapes)
+    )
+
+
+@pytest.mark.parametrize("family", ["PNA", "LFM2"])
+def pytest_the_compiled_initializer_draws_what_the_eager_one_draws(family):
+    """Same tree, shapes and dtypes as ``model.init`` run op by op here, and
+    the same values (the same keys into the same initializers; a fused
+    program may round a scaled draw differently, so 1e-6 relative)."""
+    model, batch = _tiny(family)
+    got = init_model_variables(model, batch, seed=3)
+    want = model.init(
+        {"params": jax.random.PRNGKey(3), "dropout": jax.random.PRNGKey(4)}, batch, train=False,
+    )
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    for (path, g), w in zip(
+        jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)
+    ):
+        name = jax.tree_util.keystr(path)
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6, atol=0, err_msg=name)
+
+
 @pytest.mark.parametrize("model_type", ALL_MODELS)
 def pytest_padding_invariance(model_type):
     """Outputs on real rows must be identical whatever the pad sizes."""
@@ -91,8 +167,8 @@ def pytest_padding_invariance(model_type):
     )
     variables = init_model_variables(model, small)
     # train=False: eval path, deterministic (no attention dropout).
-    out_s = model.apply(variables, small, train=False)
-    out_b = model.apply(variables, big, train=False)
+    out_s = forward(model, variables, small)
+    out_b = forward(model, variables, big)
     gm = np.asarray(small.graph_mask)
     nm = np.asarray(small.node_mask)
     for o_s, o_b, t in zip(out_s, out_b, types):
@@ -111,7 +187,9 @@ def pytest_batchnorm_running_stats_update():
     graphs = _graphs(np.random.default_rng(2))
     batch = collate_graphs(graphs, types, dims)
     variables = init_model_variables(model, batch)
-    _, mut = model.apply(variables, batch, train=True, mutable=["batch_stats"])
+    _, mut = jax.jit(
+        lambda v, b: model.apply(v, b, train=True, mutable=["batch_stats"])
+    )(variables, batch)
     before = jax.tree_util.tree_leaves(variables["batch_stats"])
     after = jax.tree_util.tree_leaves(mut["batch_stats"])
     assert any(
@@ -140,7 +218,7 @@ def pytest_mlp_per_node_head():
                                   edge_attr=np.ones((n, 1), np.float32)))
     batch = collate_graphs(graphs, types, dims)
     variables = init_model_variables(model, batch)
-    (out,) = model.apply(variables, batch, train=False)
+    (out,) = forward(model, variables, batch)
     assert out.shape == (batch.num_nodes_pad, 1)
     assert np.all(np.isfinite(np.asarray(out)))
 
@@ -173,7 +251,7 @@ def pytest_conv_node_head(model_type):
         g.y_loc = np.array([[0, 1, 1 + g.num_nodes]], dtype=np.int64)
     batch = collate_graphs(graphs, types, dims)
     variables = init_model_variables(model, batch)
-    outs = model.apply(variables, batch, train=False)
+    outs = forward(model, variables, batch)
     assert outs[0].shape == (batch.num_graphs_pad, 1)
     assert outs[1].shape == (batch.num_nodes_pad, 1)
     assert all(np.all(np.isfinite(np.asarray(o))) for o in outs)

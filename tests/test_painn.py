@@ -29,6 +29,7 @@ from graftbench.families import painn as plain_painn  # noqa: E402
 from hydragnn_tpu.graphs import GraphSample, collate_graphs  # noqa: E402
 from hydragnn_tpu.models import create_model, init_model_variables  # noqa: E402
 from hydragnn_tpu.models.painn import PaiNNBlock  # noqa: E402
+from tests.conftest import forward  # noqa: E402
 
 RADIUS, F = 2.5, 16
 HEADS = {
@@ -74,9 +75,7 @@ def _collate(model, graphs, **pads):
 
 
 def _shaken_variables(model, graphs, seed=3):
-    # The initializer as ONE program: op by op it was most of a case's seconds.
-    init = jax.jit(lambda batch: init_model_variables(model, batch))
-    return shaken(init(_collate(model, graphs)), seed)
+    return shaken(init_model_variables(model, _collate(model, graphs)), seed)
 
 
 def _per_graph(outputs, graphs):
@@ -134,9 +133,7 @@ def pytest_program_forward_matches_the_plain_reference(kind, hidden, arm, monkey
     variables = _shaken_variables(model, graphs)
     batch = _collate(model, graphs)
     assert batch.row_ptr is not None
-    got = _per_graph(
-        jax.jit(lambda v, b: model.apply(v, b, train=False))(variables, batch), graphs
-    )
+    got = _per_graph(forward(model, variables, batch), graphs)
     want = reference.forward(model, variables, graphs)
     for g, w in zip(got, want):
         for a, b in zip(g, w):
@@ -230,7 +227,7 @@ def pytest_painn_outputs_and_gradients_do_not_depend_on_the_padding():
     assert loose.senders.shape[0] > 2 * int(tight.edge_mask.sum())
     results = []
     for batch in (tight, loose):
-        outputs = model.apply({"params": params}, batch, train=False)
+        outputs = forward(model, {"params": params}, batch)
         loss, grads = jax.jit(
             jax.value_and_grad(lambda p: _program_loss(model, p, batch))
         )(params)
@@ -260,11 +257,11 @@ def pytest_painn_energy_is_invariant_and_v_rotates():
         g.pos = g.pos @ q.T + shift
 
     def run(gs):
-        outputs, state = model.apply(
-            variables, _collate(model, gs), train=False,
+        outputs, state = jax.jit(lambda v, b: model.apply(
+            v, b, train=False,
             capture_intermediates=lambda m, _: isinstance(m, PaiNNBlock),
             mutable=["intermediates"],
-        )
+        ))(variables, _collate(model, gs))
         _, v = state["intermediates"]["conv_1"]["__call__"][0]
         n = sum(g.num_nodes for g in gs)
         return outputs, np.asarray(v)[:n].reshape(n, 3, F)
@@ -295,8 +292,9 @@ def pytest_painn_under_remat_and_bf16_compute():
     remat = _model(remat=True)
     again = jax.jit(jax.grad(lambda p: _program_loss(remat, p, batch)))(params)
     _assert_trees_close(again, grads, rtol=1e-5, atol_of_scale=1e-6)
-    want = model.apply({"params": params}, batch, train=False)
-    got = _apply_model(_model(compute_dtype="bfloat16"), params, {}, batch, train=False)
+    want = forward(model, {"params": params}, batch)
+    half = _model(compute_dtype="bfloat16")
+    got = jax.jit(lambda p, b: _apply_model(half, p, {}, b, train=False))(params, batch)
     assert batch.positions.dtype == np.float32
     for a, b in zip(got, want):
         assert a.dtype == jnp.float32 and np.isfinite(np.asarray(a)).all()
@@ -341,9 +339,7 @@ def pytest_painn_serves_through_the_engine_and_asks_for_positions():
     graphs = _graphs(seed=7)
     model = _model()
     variables = _shaken_variables(model, graphs)
-    want = _per_graph(
-        model.apply(variables, _collate(model, graphs), train=False), graphs
-    )
+    want = _per_graph(forward(model, variables, _collate(model, graphs)), graphs)
     engine = InferenceEngine(model, variables, max_batch_graphs=8, max_delay_ms=20.0)
     try:
         got = engine.predict([GraphSample(x=g.x, pos=g.pos, edge_index=g.edge_index)

@@ -55,10 +55,7 @@ def pytest_a_loaders_tile_pad_and_power_of_two_pad_train_alike(kind, monkeypatch
     assert batches[None].senders.shape[0] == e_tile
     assert int(batches[None].edge_mask.sum()) == int(batches["pow2"].edge_mask.sum())
     assert batches[None].row_ptr is not None  # the CSR arm's contract
-    # The initializer as ONE program: op by op, 2,000 nodes cost it 12-23 s.
-    variables = shaken(
-        jax.jit(lambda batch: init_model_variables(model, batch))(batches[None]), 3
-    )
+    variables = shaken(init_model_variables(model, batches[None]), 3)
 
     stats = {k: v for k, v in variables.items() if k != "params"}  # PNA's, GAT's norms
     loss_and_grads = jax.jit(

@@ -12,6 +12,7 @@ from graftbench import reference
 from graftbench.drivers.train_epochs import shaken
 from hydragnn_tpu.graphs import GraphSample, collate_graphs
 from hydragnn_tpu.models import create_model, init_model_variables
+from tests.conftest import forward
 
 HEADS = {
     "graph": {
@@ -71,7 +72,8 @@ def pytest_program_forward_matches_plain_reference(family, route, monkeypatch):
     # 0 or 1 (biases, BatchNorm scale, shift and running statistics).
     variables = shaken(init_model_variables(model, batch), 28)
 
-    got = [np.asarray(o) for o in model.apply(variables, batch, train=False)]
+    # The program's side is ONE program; the plain reference stays as it is.
+    got = [np.asarray(o) for o in forward(model, variables, batch)]
     want = reference.forward(model, variables, graphs)
     atol, rtol = reference.tolerance(model.conv_type)
     starts = np.concatenate([[0], np.cumsum([g.num_nodes for g in graphs])])

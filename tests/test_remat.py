@@ -55,7 +55,7 @@ def pytest_remat_transparent(conv):
                        [1.0, 1.0], 2, remat=True, **kwargs)
     # Whole stacks under jit, each model one program (loss and gradients):
     # op by op every primitive of every shape compiles alone.
-    v = jax.jit(lambda batch: init_model_variables(base, batch))(batch)
+    v = init_model_variables(base, batch)
 
     def loss_fn(model, params):
         outs = model.apply({"params": params, "batch_stats": v.get("batch_stats", {})},

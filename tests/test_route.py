@@ -340,8 +340,10 @@ def pytest_degraded_replica_drains_and_readmits():
 @pytest.mark.mpi_skip
 def pytest_correlation_id_hop_log_through_two_inprocess_replicas():
     model, variables, graphs, ladder = _fleet_parts()
-    eng_a = _engine(model, variables, ladder)
-    eng_b = _engine(model, variables, ladder)
+    # Warm: a first request would pay the forward's compile inside the
+    # router's 60 s hop wait, which six busy test workers can outlast.
+    eng_a = _engine(model, variables, ladder, warmup=True)
+    eng_b = _engine(model, variables, ladder, warmup=True)
     router = Router(
         [
             InProcessReplica("eng-a", eng_a),
@@ -377,9 +379,10 @@ def pytest_correlation_id_hop_log_through_two_inprocess_replicas():
 @pytest.mark.mpi_skip
 def pytest_router_bitexact_vs_direct_engine_at_matched_buckets():
     model, variables, graphs, ladder = _fleet_parts()
-    direct = _engine(model, variables, ladder)
-    eng_a = _engine(model, variables, ladder)
-    eng_b = _engine(model, variables, ladder)
+    # Warm, as the hop log's replicas above: no compile inside a timed wait.
+    direct = _engine(model, variables, ladder, warmup=True)
+    eng_a = _engine(model, variables, ladder, warmup=True)
+    eng_b = _engine(model, variables, ladder, warmup=True)
     router = Router(
         [
             InProcessReplica("eng-a", eng_a),
@@ -486,7 +489,9 @@ def pytest_warm_spinup_admits_only_after_hydration_with_zero_compiles(
 @pytest.mark.mpi_skip
 def pytest_router_http_end_to_end_with_http_replica():
     model, variables, graphs, ladder = _fleet_parts()
-    engine = _engine(model, variables, ladder)
+    # Warm, as the in-process replicas above: the router's hop wait answers
+    # 503 after 60 s, which a first compile under six busy workers outlasts.
+    engine = _engine(model, variables, ladder, warmup=True)
     serve = InferenceServer(engine, port=0, replica_id="r0").start_background()
     replica = HttpReplica("r0", f"http://127.0.0.1:{serve.port}")
     router = Router([replica], autostart_health=False)

@@ -14,6 +14,7 @@ of each HLO instruction, to
 CPU, tiny sizes: op names and counts, never a time."""
 
 import contextlib
+import os
 import re
 
 import jax
@@ -92,12 +93,41 @@ def _model(conv, node_type="mlp", hidden=8, **kw):
     )
 
 
+def _state_shapes(model, batch, opt):
+    """The train state ``batch`` would initialize, as shapes: a step is
+    lowered and compiled here, never run, so no weight is drawn."""
+    return jax.eval_shape(
+        lambda: create_train_state(model, init_model_variables(model, batch), opt)
+    )
+
+
+_TEXTS = {}  # what _compiled_text compiled, by everything its trace reads
+
+
+def _shapes(tree):
+    leaves, structure = jax.tree_util.tree_flatten(tree)
+    return structure, tuple((np.shape(a), str(np.asarray(a).dtype)) for a in leaves)
+
+
 def _compiled_text(conv, batch, build=make_train_step, stacked=None, **model_kw):
     """Optimized HLO of ``build``'s step; ``stacked`` is what the scanned
-    epoch is lowered for (``batch`` still initializes the model)."""
+    epoch is lowered for (``batch`` still shapes the model). A step several
+    tests read is compiled once: the key holds the model's arguments, the
+    shapes, and the two switches this file's tests turn before they trace
+    (the sorted arm's override and ``jax.named_scope`` itself)."""
+    key = (
+        conv, build, tuple(sorted(model_kw.items())), _shapes(batch), _shapes(stacked),
+        os.environ.get("HYDRAGNN_SEGMENT_SORTED"), jax.named_scope,
+    )
+    if key not in _TEXTS:
+        _TEXTS[key] = _compile_text(conv, batch, build, stacked, **model_kw)
+    return _TEXTS[key]
+
+
+def _compile_text(conv, batch, build, stacked, **model_kw):
     model = _model(conv, **model_kw)
     opt = select_optimizer("AdamW", 1e-3)
-    state = create_train_state(model, init_model_variables(model, batch), opt)
+    state = _state_shapes(model, batch, opt)
     step = build(model, opt, donate=False)
     if stacked is None:
         args = (state, batch, jax.random.PRNGKey(0))
@@ -319,7 +349,7 @@ def pytest_eval_step_root_and_per_node_head(monkeypatch):
     batch = _batch()
     model = _model("SAGE", node_type="mlp_per_node")
     opt = select_optimizer("AdamW", 1e-3)
-    state = create_train_state(model, init_model_variables(model, batch), opt)
+    state = _state_shapes(model, batch, opt)
     names = _op_names(
         make_eval_step(model).lower(state, batch).compile().as_text()
     )
@@ -474,7 +504,7 @@ def pytest_painn_eval_and_scan_roots_carry_the_geometry(monkeypatch):
     batch = _painn_batch()
     model = _model("PAINN", edge_dim=None)
     opt = select_optimizer("AdamW", 1e-3)
-    state = create_train_state(model, init_model_variables(model, batch), opt)
+    state = _state_shapes(model, batch, opt)
     assert state.batch_stats == {}  # no batch norm anywhere in this family
     names = _op_names(make_eval_step(model).lower(state, batch).compile().as_text())
     used = _used(names)
@@ -500,7 +530,7 @@ def pytest_mesh_step_on_four_devices_is_rooted_and_scoped(monkeypatch):
     batch = _batch()
     model = _model("PNA")
     opt = select_optimizer("AdamW", 1e-3)
-    state = create_train_state(model, init_model_variables(model, batch), opt)
+    state = _state_shapes(model, batch, opt)
     step = make_train_step_dp(model, opt, mesh, donate=False)
     text = step.lower(
         state, stack_batches([batch] * 4, 4), jax.random.PRNGKey(0)

@@ -7,6 +7,8 @@ the XLA route's four gathers (``aggregate._extrema_bwd``), alone and through
 
 CPU, small sizes: values and routes, never a time."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -84,25 +86,40 @@ def pytest_csr_extrema_bit_equal_to_segment_min_max(layout, f, values):
     )(data)
     assert mn.dtype == data.dtype and mx.dtype == data.dtype
     filled = (counts > 0)[:, None]
-    want_mn = np.where(filled, jax.ops.segment_min(data, ids, n), 0)
-    want_mx = np.where(filled, jax.ops.segment_max(data, ids, n), 0)
+    # The references and the XLA arm: ONE program of their own.
+    seg_mn, seg_mx, (xla_mn, xla_mx) = jax.jit(lambda d: (
+        jax.ops.segment_min(d, ids, n), jax.ops.segment_max(d, ids, n),
+        aggregate.segment_extrema(d, ids, n),
+    ))(data)
+    want_mn = np.where(filled, seg_mn, 0)
+    want_mx = np.where(filled, seg_mx, 0)
     assert np.array_equal(np.asarray(mn), want_mn)
     assert np.array_equal(np.asarray(mx), want_mx)
     assert np.isfinite(np.asarray(mn, np.float32)).all()
     if values == "negative":
         assert (np.asarray(mx)[counts > 0] < 0).all()  # no 0 fill leaked in
     # The XLA arm on the same rows (masked ids are its own convention).
-    xla_mn, xla_mx = aggregate.segment_extrema(data, ids, n)
     assert np.array_equal(np.asarray(mn), np.asarray(xla_mn))
     assert np.array_equal(np.asarray(mx), np.asarray(xla_mx))
 
 
-def _vjp(data, ids, n, row_ptr, cotangents):
-    """``d_data`` of ``segment_extrema`` on the route ``row_ptr`` selects."""
+@functools.partial(jax.jit, static_argnums=(2,))
+def _pulled(data, ids, n, row_ptr, cotangents):
+    """Forward and pull in ONE program, the ids and the boundaries its
+    ARGUMENTS as a step has them: compiled once a layout, width, dtype and
+    route, whatever the values (no test that calls it turns a trace-time
+    switch, so one function object serves them all)."""
     _, pull = jax.vjp(
         lambda d: aggregate.segment_extrema(d, ids, n, None, row_ptr), data
     )
-    return np.asarray(pull(cotangents)[0].astype(jnp.float32))
+    return pull(cotangents)[0]
+
+
+def _vjp(data, ids, n, row_ptr, cotangents):
+    """``d_data`` of ``segment_extrema`` on the route ``row_ptr`` selects."""
+    # To float32 on the host: inside the program the compiler may keep a
+    # bfloat16 gradient's extra bits through the conversion.
+    return np.asarray(_pulled(data, ids, n, row_ptr, cotangents)).astype(np.float32)
 
 
 @pytest.mark.parametrize("layout,f,values", BACKWARD_CASES)
@@ -183,8 +200,8 @@ def pytest_gradient_on_the_kernel_route_equals_the_xla_routes(
         return {a for a in ("xla", "pallas_csr") if f"agg.extrema.{a}" in text}
 
     assert arms(row_ptr) == {"pallas_csr"} and arms(None) == {"xla"}
-    value, grad = jax.value_and_grad(loss)(data, row_ptr)
-    want_value, want_grad = jax.value_and_grad(loss)(data, None)
+    value, grad = jax.jit(jax.value_and_grad(loss))(data, row_ptr)
+    want_value, want_grad = jax.jit(jax.value_and_grad(loss))(data, None)
     assert np.array_equal(np.asarray(grad), np.asarray(want_grad))
     assert np.asarray(grad)[np.asarray(mask)].any()
     assert not np.asarray(grad)[~np.asarray(mask)].any()

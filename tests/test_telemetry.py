@@ -689,15 +689,16 @@ def pytest_gc_pause_is_a_record_only_from_a_millisecond():
 def pytest_setup_account_and_jax_durations():
     """The functions every entry point calls before its first step open
     ``setup.*`` spans themselves, and the first one installs the hooks: with
-    no caller configuring anything, the totals hold the eager initializer and
-    JAX's own trace / lower / compile seconds."""
+    no caller configuring anything, the totals hold the initializer and JAX's
+    own trace / lower / compile seconds. (The initializer's seconds are no
+    longer held above the driver's: it is one program, and an equal model an
+    earlier test initialised costs it no compile at all.)"""
     loader = _loader(_dataset(np.random.default_rng(0)))
     before = telemetry.jax_seconds()
     d = _driver_for(loader)
     totals = telemetry.span_totals()
     for name in ("setup.init_variables", "setup.create_state", "setup.driver"):
         assert totals[name] > 0.0, name
-    assert totals["setup.init_variables"] > totals["setup.driver"]
     # A program this process has not compiled yet (the models above may be
     # in its caches from an earlier test).
     import jax

@@ -17,6 +17,7 @@ torch = pytest.importorskip("torch")
 from hydragnn_tpu.graphs.collate import GraphSample, collate_graphs
 from hydragnn_tpu.models.create import create_model, init_model_variables
 from hydragnn_tpu.utils.torch_import import import_torch_checkpoint
+from tests.conftest import forward
 
 IN, HID, EDGE, SHARED, HEADH = 3, 8, 2, 5, 7
 AGG_SCALE = 16  # 4 aggregators x 4 scalers
@@ -181,7 +182,7 @@ def pytest_torch_import_roundtrip_pna(tmp_path):
     np.testing.assert_allclose(our_out, ref_out, rtol=1e-5, atol=1e-5)
 
     # Full forward with imported weights runs and is finite.
-    out = model.apply(new_vars, batch, train=False)
+    out = forward(model, new_vars, batch)
     assert np.all(np.isfinite(np.asarray(out[0])))
 
 
@@ -202,7 +203,7 @@ def pytest_torch_import_node_mlp_head(tmp_path):
         new_vars["params"]["head_1"]["mlp"]["dense_0"]["kernel"],
         sd["heads_NN.1.mlp.0.0.weight"].numpy().T,
     )
-    out = model.apply(new_vars, batch, train=False)
+    out = forward(model, new_vars, batch)
     assert np.all(np.isfinite(np.asarray(out[1])))
 
 
@@ -322,7 +323,7 @@ def pytest_torch_import_other_families(family, tmp_path):
     variables = init_model_variables(model, batch, seed=0)
     new_vars, report = import_torch_checkpoint(str(path), model, variables)
     assert report["ignored"] == [], (family, report["ignored"])
-    out = model.apply(new_vars, batch, train=False)
+    out = forward(model, new_vars, batch)
     assert np.all(np.isfinite(np.asarray(out[0])))
 
 
@@ -417,7 +418,7 @@ def pytest_torch_import_conv_node_head(tmp_path):
         new_vars["batch_stats"]["node_out_bn_0"]["var"],
         sd["batch_norms_node_output.0.module.running_var"].numpy(),
     )
-    out = model.apply(new_vars, batch, train=False)
+    out = forward(model, new_vars, batch)
     assert np.all(np.isfinite(np.asarray(out[1])))
 
 
@@ -469,7 +470,7 @@ def pytest_torch_import_mlp_per_node_head(tmp_path):
     np.testing.assert_array_equal(
         p["b_0"][3], sd["heads_NN.1.mlp.3.0.bias"].numpy()
     )
-    out = model.apply(new_vars, batch, train=False)
+    out = forward(model, new_vars, batch)
     assert np.all(np.isfinite(np.asarray(out[1])))
 
 

@@ -32,6 +32,7 @@ from hydragnn_tpu.models.convs import (
 from hydragnn_tpu.utils.torch_import import _map_conv
 
 from test_torch_import import EDGE, _family_conv_sd, _lin
+from tests.conftest import forward
 
 N, F_IN, F_OUT, HEADS, MAX_DEG = 7, 3, 8, 6, 3
 
@@ -458,7 +459,7 @@ def pytest_numeric_parity_num_sharedlayers2_reference_layout(tmp_path):
         ),
     )
 
-    out = np.asarray(model.apply(new_vars, batch, train=False)[0])[:1]
+    out = np.asarray(forward(model, new_vars, batch)[0])[:1]
     np.testing.assert_allclose(
         out,
         ref.numpy(),

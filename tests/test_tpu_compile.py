@@ -695,27 +695,18 @@ def pytest_jamba_engine_at_the_guard_rung_holds_no_state_history(one_chip):
     assert "f32[512,65536]" in text and f"f32[{n},65536]" not in text
 
 
-def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monkeypatch):
-    """The scan path's program (``make_train_epoch_scan``) for the whole GATv2
-    model of ``gatv2_h64x6_md17like.train_b512`` over a stack of
-    ``SCAN_CHUNK`` batches of the cell's one shape (11264 × 215552, 513 graph
-    slots): ONE ``while`` whose condition compares the induction variable
-    with the ``count`` PARAMETER, no constant (so no trip count is known to
-    the compiler and a tail needs no program of its own), whose body holds
-    the step (the two backward scatter-adds a layer into ``f32[11264,384]``),
-    within the chip's memory."""
+def _gatv2_cell(one_chip):
+    """(model, batch, shaped) of ``gatv2_h64x6_md17like.train_b512``: the whole
+    GATv2 model and ``batch(lead, make)``, the cell's one shape (11264 ×
+    215552, 513 graph slots) under ``lead`` leading dimensions, each array
+    made by ``make(shape, dtype)``."""
     import json
 
     import numpy as np
 
     from hydragnn_tpu.graphs.batch import GraphBatch
-    from hydragnn_tpu.models.create import create_model_config, init_model_variables
-    from hydragnn_tpu.ops.segment import platform_override
-    from hydragnn_tpu.train.train_validate_test import SCAN_CHUNK
-    from hydragnn_tpu.train.trainer import create_train_state, make_train_epoch_scan
-    from hydragnn_tpu.utils.optimizer import select_optimizer
+    from hydragnn_tpu.models.create import create_model_config
 
-    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "graftbench/configs/gatv2_h64x6_md17like.json")) as f:
         arch = json.load(f)["NeuralNetwork"]["Architecture"]
@@ -723,7 +714,6 @@ def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monke
     # graph target, 21 atoms a molecule).
     arch.update(input_dim=1, output_dim=[1], output_type=["graph"], num_nodes=21)
     model = create_model_config(config=arch)
-    opt = select_optimizer("AdamW", 1e-3)
     n, e, g = 11264, 215552, 513
 
     def batch(lead, make):
@@ -742,6 +732,51 @@ def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monke
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    return model, batch, shaped
+
+
+def pytest_gatv2_initializer_at_cell_size_is_one_program_of_draws(one_chip, monkeypatch):
+    """``init_model_variables`` for the whole GATv2 model at the cell's shape,
+    for the chip: ONE program, and the draws alone. The forward that
+    ``model.init`` traces is dead code in it: no kernel call, no matrix
+    product, no scatter or gather over the 215,552 edges, and next to no
+    temporary memory (op by op it was some two hundred programs, the forward
+    run as well)."""
+    from hydragnn_tpu.models import create
+    from hydragnn_tpu.ops.segment import platform_override
+
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    model, batch, shaped = _gatv2_cell(one_chip)
+    with platform_override("tpu"):  # the library's own program, the seed an argument
+        compiled = create._init_program.lower(
+            model, batch((), shaped), shaped((), jnp.int32)
+        ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert not re.search(r"\s(dot|convolution|scatter|gather|sort)\(", text)
+    assert "215552" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+
+def pytest_gatv2_counted_scan_at_cell_size_loops_on_its_argument(one_chip, monkeypatch):
+    """The scan path's program (``make_train_epoch_scan``) for the whole GATv2
+    model of ``gatv2_h64x6_md17like.train_b512`` over a stack of
+    ``SCAN_CHUNK`` batches of the cell's one shape (11264 × 215552, 513 graph
+    slots): ONE ``while`` whose condition compares the induction variable
+    with the ``count`` PARAMETER, no constant (so no trip count is known to
+    the compiler and a tail needs no program of its own), whose body holds
+    the step (the two backward scatter-adds a layer into ``f32[11264,384]``),
+    within the chip's memory."""
+    from hydragnn_tpu.models.create import init_model_variables
+    from hydragnn_tpu.ops.segment import platform_override
+    from hydragnn_tpu.train.train_validate_test import SCAN_CHUNK
+    from hydragnn_tpu.train.trainer import create_train_state, make_train_epoch_scan
+    from hydragnn_tpu.utils.optimizer import select_optimizer
+
+    monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
+    model, batch, shaped = _gatv2_cell(one_chip)
+    opt = select_optimizer("AdamW", 1e-3)
+    n = 11264
     state = jax.tree_util.tree_map(
         lambda a: shaped(a.shape, a.dtype),
         jax.eval_shape(lambda: create_train_state(
