@@ -212,7 +212,7 @@ def _elastic_transition(train_dir: str, world_a: int = 2, world_b: int = 3) -> d
                 num_shards=world, shard_rank=rank,
             )
             mine = []
-            for _, _, idx in loader._batch_plan():
+            for _, _, idx, _ in loader._batch_plan():
                 mine.extend(np.asarray(idx).tolist())
             per_rank.append(mine)
             out.extend(mine)
@@ -227,7 +227,7 @@ def _elastic_transition(train_dir: str, world_a: int = 2, world_b: int = 3) -> d
     flat_b = []
     for rank in range(world_b):
         loader.reshard(world_b, rank)
-        for _, _, idx in loader._batch_plan():
+        for _, _, idx, _ in loader._batch_plan():
             flat_b.extend(np.asarray(idx).tolist())
     pad_b = -(-n // world_b) * world_b
 

@@ -423,6 +423,7 @@ class StreamingGraphLoader(GraphDataLoader):
         self._cache_bytes = 0
         self._merged = None
         self.generation += 1
+        self._size_buckets()  # a fixed plan's largest batch moved with it
 
     def ring_stats(self) -> Optional[dict]:
         """Decode counters of the most recent epoch's shard ring (bench)."""
@@ -436,7 +437,7 @@ class StreamingGraphLoader(GraphDataLoader):
         needs: List[List[int]] = []
         order: List[int] = []
         order_set: set = set()
-        for _pos, _bi, sample_idx in plan:
+        for _pos, _bi, sample_idx, _need in plan:
             sids = self._shard_of(np.asarray(sample_idx, np.int64))
             seen: List[int] = []
             seen_set: set = set()
@@ -477,7 +478,7 @@ class StreamingGraphLoader(GraphDataLoader):
         # resident set — collation cost identical to the in-memory loader.
         merged = self._ensure_merged_arena(order) if ring is None else None
         try:
-            for k, (pos, bi, sample_idx) in enumerate(plan):
+            for k, (pos, bi, sample_idx, need) in enumerate(plan):
                 for sid in needs[k]:
                     if sid in self._resident:
                         continue
@@ -486,6 +487,7 @@ class StreamingGraphLoader(GraphDataLoader):
                     pos,
                     bi,
                     np.asarray(sample_idx, np.int64),
+                    need,
                     self._resident,
                     merged=merged,
                 )
@@ -526,13 +528,13 @@ class StreamingGraphLoader(GraphDataLoader):
         ring = ShardRing(fetch_seq, self._decode_shard, depth=self.ring_depth)
         resident: Dict[int, Optional[_DecodedShard]] = {}
         try:
-            for k, (pos, bi, sample_idx) in enumerate(plan):
+            for k, (pos, bi, sample_idx, need) in enumerate(plan):
                 for sid in needs[k]:
                     if sid in resident:
                         continue
                     resident[sid] = self._next_from_ring(ring, sid)
                 batch = self._emit(
-                    pos, bi, np.asarray(sample_idx, np.int64), resident
+                    pos, bi, np.asarray(sample_idx, np.int64), need, resident
                 )
                 if batch is not None:
                     yield batch
@@ -561,9 +563,11 @@ class StreamingGraphLoader(GraphDataLoader):
             self._note_bad_shard(sid, reason or "corrupt")
         return payload
 
-    def _emit(self, pos, bi, sample_idx, resident, merged=None):
+    def _emit(self, pos, bi, sample_idx, need, resident, merged=None):
         """Collate one plan entry from resident shards (members of
-        quarantined shards are dropped; an emptied batch is skipped)."""
+        quarantined shards are dropped; an emptied batch is skipped; the
+        shape is chosen from the plan's ``need``, which no quarantine
+        moves, so that every process still chooses alike)."""
         sids = self._shard_of(sample_idx)
         keep = np.fromiter(
             (resident.get(int(s)) is not None for s in sids),
@@ -575,18 +579,7 @@ class StreamingGraphLoader(GraphDataLoader):
             sids = sids[keep]
         if sample_idx.size == 0:
             return None
-        n_pad, e_pad, g_pad = self._bucket_pads[bi]
-        tot_n = int(self._ns[sample_idx].sum())
-        tot_e = int(self._es[sample_idx].sum())
-        self.size_histogram.record_batch(tot_n, tot_e, len(sample_idx))
-        st = self._pad_stats
-        st["batches"] += 1
-        st["real_nodes"] += tot_n
-        st["pad_nodes"] += n_pad
-        st["real_edges"] += tot_e
-        st["pad_edges"] += e_pad
-        st["real_graphs"] += len(sample_idx)
-        st["pad_graphs"] += g_pad
+        n_pad, e_pad, g_pad = self._book_batch(bi, sample_idx, need)
         if pos is not None and pos in self._batch_cache:
             return self._batch_cache[pos]
         if merged is not None:

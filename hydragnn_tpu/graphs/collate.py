@@ -527,10 +527,11 @@ def compute_pad_sizes_from_counts(
     ns, es, batch_size: int, ladder_step: Optional[str] = None
 ) -> Tuple[int, int, int]:
     """``compute_pad_sizes`` from per-sample (num_nodes, num_edges) count
-    arrays alone — the form the loaders use (their ``_ns``/``_es`` arrays are
-    the single source of truth) and the only form the out-of-core streaming
-    loader CAN use: its pad shapes come from the GSHD index without decoding
-    a single shard (docs/DATA_PLANE.md).
+    arrays alone: the WORST-CASE shape, the one every possible batch of
+    ``batch_size`` of these graphs fits (the ``batch_size`` largest in each
+    dimension). A serving ladder's top, a test's fixture and the packer's
+    capacity are this shape; a loader's bucket runs at ``fit_pad_sizes`` of
+    it and keeps it for the batch that does not fit.
 
     This shape is chosen ONCE a bucket, so it is one compiled program whatever
     it is rounded to, and the power of two that bounds the programs where a
@@ -542,13 +543,80 @@ def compute_pad_sizes_from_counts(
     mean what they always did."""
     nodes = sorted((int(n) for n in ns), reverse=True)[:batch_size]
     edges = sorted((int(e) for e in es), reverse=True)[:batch_size]
+    n_pad = _round_up_pad(sum(nodes) + 1, ladder_step)
+    e_pad = _round_up_pad(max(sum(edges), 1) + 1, ladder_step)
+    return n_pad, e_pad, batch_size + 1
+
+
+def _round_up_pad(rows: int, ladder_step: Optional[str]) -> int:
     from .packing import round_up_step
 
-    ladder = (
-        dict(mode="mult64", step=loader_pad_tile())
-        if ladder_step is None
-        else dict(mode=ladder_step)
-    )
-    n_pad = round_up_step(sum(nodes) + 1, **ladder)
-    e_pad = round_up_step(max(sum(edges), 1) + 1, **ladder)
-    return n_pad, e_pad, batch_size + 1
+    if ladder_step is None:
+        return round_up_step(rows, mode="mult64", step=loader_pad_tile())
+    return round_up_step(rows, mode=ladder_step)
+
+
+# How many standard deviations over its mean a drawn batch's total is given
+# room for (``drawn_total_bound``).
+PAD_SIGMAS = 6
+# A fitted shape is a whole number of these parts of the worst-case shape
+# (``fit_pad_sizes``). Of 8, 10, 12, 14 and 16, twelve is the one that gave
+# the PNA cells' generator ONE set of train shapes over 28 seeded datasets
+# and the fewest rows besides (PERF.md section 6, PR 49).
+PAD_RUNGS = 12
+
+
+def drawn_total_bound(counts, batch_size: int) -> float:
+    """The total (of nodes, or of edges) that a batch of ``batch_size`` graphs
+    DRAWN from ``counts`` by a shuffle stays under: mean + ``PAD_SIGMAS``
+    standard deviations of a uniformly drawn subset's total, ``B mu`` and
+    ``B sigma^2 (N - B) / (N - 1)`` (drawn without replacement). From the
+    count array alone, which is all the streaming loader has.
+
+    Six: the total of some hundreds of bounded terms is near enough normal
+    that six deviations are passed about once in 1e9 batches (the lattice
+    buckets of the PNA cells: skewness 0.03 of a 512-graph total, ~3e-9 by
+    the saddle point), and the rungs of ``fit_pad_sizes`` add room on top. It
+    is no guarantee and needs to be none: a small batch's or a heavy-tailed
+    bucket's bound lies over the worst case, which caps it, and the batch
+    that passes it all the same is collated at the worst-case shape
+    (``GraphDataLoader._book_batch``) at the price of one more compiled
+    program, counted in ``padding_stats()['fallback_batches']``. Equal
+    counts (sigma 0) give exactly their sum."""
+    counts = np.asarray(counts, np.float64)
+    n = counts.size
+    b = min(int(batch_size), n)
+    spread = b * counts.var() * (n - b) / max(n - 1, 1)
+    return b * counts.mean() + PAD_SIGMAS * math.sqrt(spread)
+
+
+def fit_pad_sizes(
+    need_nodes: float,
+    need_edges: float,
+    worst: Tuple[int, int, int],
+    ladder_step: Optional[str] = None,
+) -> Tuple[int, int, int]:
+    """The static shape of a bucket whose batches hold up to ``need_nodes``
+    nodes and ``need_edges`` edges: in each dimension by itself the lowest
+    rung that holds the need and the padding row, a rung being a whole number
+    of ``PAD_RUNGS`` equal parts of the ``worst``-case shape
+    (``compute_pad_sizes_from_counts``) rounded up as that shape was; the top
+    rung is that shape.
+
+    Why parts of the worst case and not the need rounded up to the tile: the
+    need is a statistic of the dataset at hand, and two samples of one source
+    differ in it by about a percent, so a shape that followed it to the tile
+    would be a new set of step programs for every new sample (a cold
+    compilation cache: tens of seconds of set-up). The worst case is the
+    largest graphs' and stays put where they do; a need moves between two
+    neighbouring rungs at most, and a bucket within a twelfth of its worst
+    case (graphs of one size above all) keeps that shape to the row."""
+    fitted = []
+    for need, top in ((need_nodes, worst[0]), (need_edges, worst[1])):
+        need = max(math.ceil(need), 1) + 1
+        rungs = (
+            min(top, _round_up_pad(-(-top * rung // PAD_RUNGS), ladder_step))
+            for rung in range(max(need * PAD_RUNGS // top, 1), PAD_RUNGS + 1)
+        )
+        fitted.append(next((rows for rows in rungs if rows >= need), top))
+    return fitted[0], fitted[1], worst[2]

@@ -468,6 +468,12 @@ class ElasticTrainer:
                 "ElasticTrainer requires a single-bucket loader (one static "
                 "pad shape) — multi-bucket elastic stacking is future work"
             )
+        if hasattr(loader, "_size_buckets"):
+            # The lockstep step stacks the plan's batches by position: one
+            # shape for EVERY batch, whatever a shuffle draws.
+            from ..preprocess.dataloader import keep_worst_case_pads
+
+            keep_worst_case_pads(loader)
         self.model = model
         self.optimizer = optimizer
         self.loader = loader

@@ -8,11 +8,20 @@ multiple of the shard count by wrapping around, then dealt round-robin so every
 shard sees the same number of batches.
 
 Recompilation control vs padding waste (SURVEY.md §7 hard part #4): with
-``num_buckets=1`` the whole dataset shares one worst-case pad shape (one XLA
-compile). Datasets mixing small and large graphs can set ``num_buckets=K``:
-samples are partitioned into K node-count quantile buckets, each with its own
-pad shape — K compiles, far less padding FLOP waste. Batches are formed within
-buckets and the batch order is shuffled across buckets per epoch.
+``num_buckets=1`` the whole dataset shares one pad shape (one XLA compile).
+Datasets mixing small and large graphs can set ``num_buckets=K``: samples are
+partitioned into K node-count quantile buckets, each with its own pad shape —
+K compiles, far less padding FLOP waste. Batches are formed within buckets and
+the batch order is shuffled across buckets per epoch.
+
+A bucket's shape is sized to the batches the loader can draw, not to the
+bucket's ``batch_size`` largest graphs (``GraphDataLoader._size_buckets``):
+where the membership never changes (``shuffle=False``, ``reshuffle="batch"``)
+to the largest batch of the plan, where it is redrawn every epoch to a bound
+on a drawn batch's total (``graphs/collate.py`` ``drawn_total_bound``), both
+by whole rungs of the worst-case shape (``fit_pad_sizes``). The batch that
+does not fit is collated at the worst-case shape, which is one more compiled
+program the first time it happens and is counted (``padding_stats()``).
 """
 
 from __future__ import annotations
@@ -22,7 +31,12 @@ from typing import Iterator, List, Optional, Sequence
 import numpy as np
 
 from ..graphs.batch import GraphBatch
-from ..graphs.collate import GraphArena, compute_pad_sizes_from_counts
+from ..graphs.collate import (
+    GraphArena,
+    compute_pad_sizes_from_counts,
+    drawn_total_bound,
+    fit_pad_sizes,
+)
 from ..graphs.packing import PackCaps, SizeHistogram, first_fit_decreasing
 from ..graphs.sample import GraphSample
 
@@ -87,7 +101,22 @@ def share_eval_pads(val_loader, test_loader) -> None:
         val_loader._bucket_pads, test_loader._bucket_pads = [shared], [shared]
 
 
+def keep_worst_case_pads(loader) -> None:
+    """Hold ``loader`` to the ONE shape every possible batch fits, for a
+    consumer that stacks batches by their place in the plan and so cannot take
+    the batch that comes out at another shape (``ElasticTrainer``). Cached
+    collations go with the shapes, as in ``set_packing``."""
+    loader._fit_pads = False
+    loader._batch_cache.clear()
+    loader._cache_bytes = 0
+    loader.generation += 1
+    loader._size_buckets()
+
+
 class GraphDataLoader:
+    # False: every bucket at its worst-case shape (``keep_worst_case_pads``).
+    _fit_pads = True
+
     def __init__(
         self,
         dataset: Sequence[GraphSample],
@@ -261,7 +290,7 @@ class GraphDataLoader:
         n = int(self._ns.size)
         if n == 0:
             self._buckets = []
-            self._bucket_pads = []
+            self._bucket_pads = self._worst_pads = []
             self._pack_caps = []
             return
         sizes = self._ns  # one source of truth for per-sample node counts
@@ -283,10 +312,17 @@ class GraphDataLoader:
         # and num_buckets=1 iteration order is exactly dataset order (the
         # eval-loader guarantee documented in load_data.create_dataloaders).
         self._buckets = [np.sort(b) for b in buckets]
-        # Pad shapes from the count arrays alone (not the sample objects):
-        # the streaming subclass (datasets/stream.py) shares this method with
-        # nothing but the GSHD index in RAM.
-        self._bucket_pads = [
+        self._size_buckets()
+
+    def _size_buckets(self) -> None:
+        """Each bucket's static shape (``_bucket_pads``) and the worst-case
+        shape beside it (``_worst_pads``: what every possible batch fits, and
+        what the batch that does not fit ``_bucket_pads`` is collated at).
+        From the count arrays alone (not the sample objects): the streaming
+        subclass (datasets/stream.py) shares this with nothing but the GSHD
+        index in RAM. Called again whenever the plan's premises change
+        (``set_packing``, the streaming loader's ``reshard``)."""
+        self._worst_pads = [
             compute_pad_sizes_from_counts(
                 self._ns[b],
                 self._es[b],
@@ -295,15 +331,17 @@ class GraphDataLoader:
             )
             for b in self._buckets
         ]
-        # Packing: the bucket's worst-case pad shape becomes a CAPACITY the
-        # packer fills with however many graphs fit (bounded at 4x batch_size
-        # so G_pad stays a sane static dimension); G_pad grows to the graph
-        # capacity + the reserved padding graph.
+        self._bucket_pads = list(self._worst_pads)
         self._pack_caps = []
         if self.packing:
+            # Packing: the bucket's worst-case pad shape becomes a CAPACITY
+            # the packer fills with however many graphs fit (bounded at 4x
+            # batch_size so G_pad stays a sane static dimension); G_pad grows
+            # to the graph capacity + the reserved padding graph. Packing
+            # fills a capacity, it does not draw: nothing to fit.
             pads = []
-            for b, (n_pad, e_pad, _) in zip(self._buckets, self._bucket_pads):
-                min_n = max(1, int(sizes[b].min()))
+            for b, (n_pad, e_pad, _) in zip(self._buckets, self._worst_pads):
+                min_n = max(1, int(self._ns[b].min()))
                 g_cap = int(
                     min(
                         max(self.batch_size, (n_pad - 1) // min_n),
@@ -314,7 +352,31 @@ class GraphDataLoader:
                     PackCaps(nodes=n_pad - 1, edges=e_pad, graphs=g_cap)
                 )
                 pads.append((n_pad, e_pad, g_cap + 1))
-            self._bucket_pads = pads
+            self._bucket_pads = self._worst_pads = pads
+        elif self._fit_pads and self._fixed_membership:
+            # The plan is the same every epoch: its largest batch, a bucket.
+            needs = [[0, 0] for _ in self._buckets]
+            for _, bi, _, need in self._compute_batch_plan():
+                needs[bi] = list(map(max, needs[bi], need))
+            self._bucket_pads = [
+                fit_pad_sizes(*need, worst, self.ladder_step)
+                for need, worst in zip(needs, self._worst_pads)
+            ]
+        elif self._fit_pads:
+            self._bucket_pads = [
+                fit_pad_sizes(
+                    drawn_total_bound(self._ns[b], self.batch_size),
+                    drawn_total_bound(self._es[b], self.batch_size),
+                    worst,
+                    self.ladder_step,
+                )
+                for b, worst in zip(self._buckets, self._worst_pads)
+            ]
+
+    @property
+    def _fixed_membership(self) -> bool:
+        """Every epoch runs the same batches (in whatever order)."""
+        return not self.shuffle or self.reshuffle == "batch"
 
     # -- reference parity: sampler.set_epoch reshuffles DP shards each epoch.
     def set_epoch(self, epoch: int) -> None:
@@ -358,6 +420,7 @@ class GraphDataLoader:
             "pad_edges": 0,
             "real_graphs": 0,
             "pad_graphs": 0,
+            "fallback_batches": 0,
         }
 
     def reset_padding_stats(self) -> None:
@@ -365,9 +428,11 @@ class GraphDataLoader:
 
     def padding_stats(self) -> dict:
         """Padded-row accounting over every batch yielded since the last
-        reset: waste = share of compiled rows that carried no real
-        node/edge/graph (the serving metrics' ``padding_waste_*`` definition,
-        on the training side). Surfaced by ``bench.py --packing``."""
+        reset, at the shape each batch was really collated at: waste = share
+        of compiled rows that carried no real node/edge/graph (the serving
+        metrics' ``padding_waste_*`` definition, on the training side), and
+        ``fallback_batches``, how many did not fit their bucket's shape and
+        took the worst-case one. Surfaced by ``bench.py --packing``."""
         st = dict(self._pad_stats)
         for kind in ("nodes", "edges", "graphs"):
             pad = st[f"pad_{kind}"]
@@ -383,30 +448,37 @@ class GraphDataLoader:
 
     @property
     def pad_sizes(self):
-        """Worst-case pad shape every batch fits (elementwise max over
-        buckets — the largest-node bucket need not have the most edges)."""
-        if not self._bucket_pads:
+        """Pad shape every batch fits (elementwise max over buckets — the
+        largest-node bucket need not have the most edges): the buckets' own
+        shapes where the membership is fixed, the worst-case ones where a
+        shuffle can draw a batch past its bucket's."""
+        pads = self._bucket_pads if self._fixed_membership else self._worst_pads
+        if not pads:
             return (0, 0, 0)
-        return tuple(max(p[i] for p in self._bucket_pads) for i in range(3))
+        return tuple(max(p[i] for p in pads) for i in range(3))
 
     @property
     def num_buckets(self) -> int:
         return len(self._buckets)
 
-    def _shard(self, idx: np.ndarray, rng: Optional[np.random.Generator]):
+    def _deal(self, idx: np.ndarray, rng: Optional[np.random.Generator]):
+        """``[per_shard, num_shards]``: column ``r`` is shard ``r``'s stream
+        of a bucket's (shuffled) indices. Every loader sees every shard's
+        members, which is what lets them agree on a shape."""
         if self.shuffle and rng is not None:
             idx = idx.copy()
             rng.shuffle(idx)
-        if self.num_shards > 1:
-            # Wrap-pad so all shards get equal counts (DistributedSampler does
-            # the same duplication), then deal round-robin.
-            per_shard = -(-len(idx) // self.num_shards)
-            padded = np.resize(idx, per_shard * self.num_shards)
-            idx = padded[self.shard_rank :: self.num_shards]
-        return idx
+        # Wrap-pad so all shards get equal counts (DistributedSampler does
+        # the same duplication), then deal round-robin.
+        per_shard = -(-len(idx) // self.num_shards)
+        return np.resize(idx, per_shard * self.num_shards).reshape(
+            per_shard, self.num_shards
+        )
 
     def _batch_plan(self) -> List[tuple]:
-        """[(plan_pos, bucket_id, [sample indices])] for this epoch.
+        """[(plan_pos, bucket_id, [sample indices], need)] for this epoch;
+        ``need`` is the (nodes, edges) the batch's shape has to hold
+        (``_plan_bucket``).
 
         reshuffle="sample": membership redrawn per epoch from
         rng(seed+epoch); batch order shuffled across buckets.
@@ -430,14 +502,11 @@ class GraphDataLoader:
     def _compute_batch_plan(self) -> List[tuple]:
         if self.reshuffle == "batch" and self.shuffle:
             if self._frozen_plan is None:
-                rng = np.random.default_rng(self.seed)
-                plan = []
-                for bi, bucket in enumerate(self._buckets):
-                    idx = self._shard(np.asarray(bucket), rng)
-                    for members in self._plan_bucket(bi, idx):
-                        plan.append((bi, members))
                 self._frozen_plan = [
-                    (pos, bi, idx) for pos, (bi, idx) in enumerate(plan)
+                    (pos, *entry)
+                    for pos, entry in enumerate(
+                        self._plan_buckets(np.random.default_rng(self.seed))
+                    )
                 ]
             order = np.random.default_rng(self.seed + self.epoch).permutation(
                 len(self._frozen_plan)
@@ -448,32 +517,76 @@ class GraphDataLoader:
             if self.shuffle
             else None
         )
-        plan = []
-        for bi, bucket in enumerate(self._buckets):
-            idx = self._shard(np.asarray(bucket), rng)
-            for members in self._plan_bucket(bi, idx):
-                plan.append((bi, members))
+        plan = self._plan_buckets(rng)
         # Packed plans come out of FFD largest-bin-first; restore random
         # visit order (multi-bucket plans always reshuffled, as before).
         if rng is not None and (len(self._buckets) > 1 or self.packing):
             rng.shuffle(plan)
-        return [(None, bi, idx) for bi, idx in plan]
+        return [(None, *entry) for entry in plan]
 
-    def _plan_bucket(self, bi: int, idx: np.ndarray) -> List[np.ndarray]:
-        """Split one bucket's (sharded, shuffled) index stream into batch
-        membership arrays: fixed ``batch_size`` cuts, or — with packing —
-        first-fit-decreasing bins under the bucket's (nodes, edges, graphs)
-        capacity. The shuffled ``idx`` order is the packer's tie-break, so
-        equal-size graphs still migrate between batches across epochs."""
-        if not self.packing:
-            return [
-                idx[start : start + self.batch_size]
-                for start in range(0, len(idx), self.batch_size)
-            ]
-        bins = first_fit_decreasing(
-            self._ns[idx], self._es[idx], self._pack_caps[bi]
-        )
-        return [idx[members] for members in bins]
+    def _plan_buckets(self, rng) -> List[tuple]:
+        """[(bucket_id, members, need)], bucket after bucket."""
+        return [
+            (bi, members, need)
+            for bi, bucket in enumerate(self._buckets)
+            for members, need in self._plan_bucket(
+                bi, self._deal(np.asarray(bucket), rng)
+            )
+        ]
+
+    def _plan_bucket(self, bi: int, dealt: np.ndarray) -> List[tuple]:
+        """Split one bucket's dealt (sharded, shuffled) index streams into
+        this shard's batches, ``[(members, need)]``: fixed ``batch_size``
+        cuts, or — with packing — first-fit-decreasing bins under the
+        bucket's (nodes, edges, graphs) capacity. The shuffled order is the
+        packer's tie-break, so equal-size graphs still migrate between
+        batches across epochs.
+
+        ``need`` is the (nodes, edges) the batch's shape must hold: the
+        LARGEST shard's totals at this place of the plan, so that every
+        process gives the same step the same shape (a lockstep mesh stacks
+        them). A packed batch fits its capacity whatever it holds: None."""
+        mine = dealt[:, self.shard_rank]
+        if self.packing:
+            bins = first_fit_decreasing(
+                self._ns[mine], self._es[mine], self._pack_caps[bi]
+            )
+            return [(mine[members], None) for members in bins]
+        cuts = []
+        for start in range(0, len(dealt), self.batch_size):
+            block = dealt[start : start + self.batch_size]
+            need = (
+                int(self._ns[block].sum(axis=0).max()),
+                int(self._es[block].sum(axis=0).max()),
+            )
+            cuts.append((block[:, self.shard_rank], need))
+        return cuts
+
+    def _book_batch(self, bi: int, sample_idx: np.ndarray, need) -> tuple:
+        """The shape one batch is collated at, and the books on it: the size
+        record (feeds the ladder fitter and bench.py --packing) and the
+        padded-row accounting (cached yields included — the device executes
+        the same padded shape either way). The shape is the bucket's unless
+        ``need`` (``_plan_bucket``; None for a packed batch, which fits its
+        capacity) does not fit it with its padding row: then the bucket's
+        worst-case shape, which the driver compiles the first time it meets
+        it (``TrainingDriver`` groups batches by shape)."""
+        tot_n = int(self._ns[sample_idx].sum())
+        tot_e = int(self._es[sample_idx].sum())
+        self.size_histogram.record_batch(tot_n, tot_e, len(sample_idx))
+        st = self._pad_stats
+        pads = self._bucket_pads[bi]
+        if need is not None and (need[0] >= pads[0] or need[1] >= pads[1]):
+            pads = self._worst_pads[bi]
+            st["fallback_batches"] += 1
+        st["batches"] += 1
+        st["real_nodes"] += tot_n
+        st["pad_nodes"] += pads[0]
+        st["real_edges"] += tot_e
+        st["pad_edges"] += pads[1]
+        st["real_graphs"] += len(sample_idx)
+        st["pad_graphs"] += pads[2]
+        return pads
 
     def __len__(self) -> int:
         return len(self._batch_plan())
@@ -484,22 +597,8 @@ class GraphDataLoader:
             # contiguous arenas (the per-sample Python walk in collate_graphs
             # caps a prefetch thread well below TPU consumption rate).
             self._arena = GraphArena(self.dataset)
-        for pos, bi, sample_idx in self._batch_plan():
-            n_pad, e_pad, g_pad = self._bucket_pads[bi]
-            # Per-batch size record + padded-row accounting (cached yields
-            # included — the device executes the same padded shape either
-            # way). Feeds the ladder fitter and bench.py --packing.
-            tot_n = int(self._ns[sample_idx].sum())
-            tot_e = int(self._es[sample_idx].sum())
-            self.size_histogram.record_batch(tot_n, tot_e, len(sample_idx))
-            st = self._pad_stats
-            st["batches"] += 1
-            st["real_nodes"] += tot_n
-            st["pad_nodes"] += n_pad
-            st["real_edges"] += tot_e
-            st["pad_edges"] += e_pad
-            st["real_graphs"] += len(sample_idx)
-            st["pad_graphs"] += g_pad
+        for pos, bi, sample_idx, need in self._batch_plan():
+            n_pad, e_pad, g_pad = self._book_batch(bi, sample_idx, need)
             if pos is not None and pos in self._batch_cache:
                 yield self._batch_cache[pos]
                 continue

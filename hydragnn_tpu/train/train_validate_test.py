@@ -125,6 +125,7 @@ _EPOCH_CONTAINERS = ("epoch", "train_epoch", "evaluate")
 NO_LEAF = "(no leaf)"
 EPOCHS_KEPT = 32
 _FEED_END = object()  # what a pull returns from an exhausted feed
+
 # Batches a dispatch on the scan path: ONE constant for every model (no
 # config key, no environment variable). A chunk is handed to the chip once
 # this many batches of its shape are collated, so an epoch's first wait is
@@ -138,6 +139,13 @@ _FEED_END = object()  # what a pull returns from an exhausted feed
 SCAN_CHUNK = 4
 
 _log = logging.getLogger(__name__)
+
+
+def _pad_fallbacks(loader) -> int:
+    """``fallback_batches`` of a loader's ``padding_stats()``; 0 for a batch
+    source that keeps no such books."""
+    stats = getattr(loader, "padding_stats", None)
+    return stats()["fallback_batches"] if stats is not None else 0
 
 
 class EpochAccount:
@@ -725,6 +733,17 @@ class TrainingDriver:
         )
 
     def train_epoch(self, loader, profiler: Optional[Profiler] = None):
+        fallbacks = _pad_fallbacks(loader)
+        averages = self._train_epoch(loader, profiler)
+        # Batches of this epoch that did not fit their bucket's shape and took
+        # the worst-case one: each new such shape is one compiled program.
+        telemetry.gauge(
+            "train/pad_fallback_batches_per_epoch",
+            _pad_fallbacks(loader) - fallbacks,
+        )
+        return averages
+
+    def _train_epoch(self, loader, profiler):
         self.feed_stats.reset()
         if self.guard is not None:
             # Epoch-start last-good snapshot: the rollback target (taken

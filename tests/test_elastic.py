@@ -181,7 +181,7 @@ def pytest_loader_reshard_across_checkpoint_boundary_preserves_multiset():
     loader.set_epoch(1)
     plan = loader._batch_plan()
     all_samples = sorted(
-        int(i) for _pos, _bi, members in plan for i in members
+        int(i) for _pos, _bi, members, _need in plan for i in members
     )
     assert all_samples == sorted(range(len(loader.dataset)))  # sanity
     for n_workers, m_workers in [(2, 1), (1, 2), (3, 2)]:

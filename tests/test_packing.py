@@ -206,23 +206,23 @@ def pytest_loader_packing_denser_capacity_respected_deterministic():
     assert len(packed) < len(plain), "packing must shrink the batch count"
     caps = packed._pack_caps[0]
     plan = packed._batch_plan()
-    seen = np.concatenate([idx for _, _, idx in plan])
+    seen = np.concatenate([idx for _, _, idx, _ in plan])
     assert sorted(seen.tolist()) == list(range(len(graphs)))
     ns = packed._ns
     es = packed._es
-    for _, bi, idx in plan:
+    for _, bi, idx, _ in plan:
         assert ns[idx].sum() <= caps.nodes
         assert es[idx].sum() <= caps.edges
         assert len(idx) <= caps.graphs
     # Same seed + epoch -> identical plan across loader instances; a new
     # epoch redraws batch order/ties.
     _, _, packed2 = _loader_pair()
-    assert [i.tolist() for _, _, i in packed2._batch_plan()] == [
-        i.tolist() for _, _, i in plan
+    assert [i.tolist() for _, _, i, _ in packed2._batch_plan()] == [
+        i.tolist() for _, _, i, _ in plan
     ]
     packed.set_epoch(1)
-    assert [i.tolist() for _, _, i in packed._batch_plan()] != [
-        i.tolist() for _, _, i in plan
+    assert [i.tolist() for _, _, i, _ in packed._batch_plan()] != [
+        i.tolist() for _, _, i, _ in plan
     ]
 
 
@@ -233,7 +233,7 @@ def pytest_loader_packed_batches_bit_exact_vs_unpacked_collation():
     graphs, _, packed = _loader_pair(n_graphs=48)
     plan = packed._batch_plan()
     packed._arena = GraphArena(packed.dataset)
-    for _, bi, idx in plan[:4]:
+    for _, bi, idx, _ in plan[:4]:
         n_pad, e_pad, g_pad = packed._bucket_pads[bi]
         via_loader = packed._arena.collate(
             idx,
@@ -325,10 +325,10 @@ def pytest_loader_packing_quarantine_and_fault_drill_interaction():
     assert len(loader.quarantined) == 3
     assert len(loader.dataset) == 57
     plan = loader._batch_plan()
-    seen = np.concatenate([idx for _, _, idx in plan])
+    seen = np.concatenate([idx for _, _, idx, _ in plan])
     assert sorted(seen.tolist()) == list(range(57))
     caps = loader._pack_caps[0]
-    for _, bi, idx in plan:
+    for _, bi, idx, _ in plan:
         assert loader._ns[idx].sum() <= caps.nodes
     for batch in loader:  # collation runs clean over the packed survivors
         assert bool(np.isfinite(batch.node_features).all())

@@ -214,9 +214,10 @@ def pytest_streamed_and_in_memory_loaders_round_their_pads_alike(tmp_path, ladde
     mem = GraphDataLoader(samples, **common)
     st = StreamingGraphLoader(corpus, **common)
     assert mem.num_buckets == st.num_buckets == 2
-    assert mem._bucket_pads == st._bucket_pads
+    assert mem._bucket_pads == st._bucket_pads and mem._worst_pads == st._worst_pads
     tile = loader_pad_tile()
-    for n_pad, e_pad, _ in mem._bucket_pads:
+    # Rungs of the worst case, rounded as it is: the fitted shapes' too.
+    for n_pad, e_pad, _ in mem._bucket_pads + mem._worst_pads:
         assert n_pad > 4 * tile and e_pad > 4 * tile
         power_of_two = n_pad & (n_pad - 1) == 0 and e_pad & (e_pad - 1) == 0
         if ladder_step is None:
@@ -229,6 +230,74 @@ def pytest_streamed_and_in_memory_loaders_round_their_pads_alike(tmp_path, ladde
     assert bm.node_features.shape[0] in {p[0] for p in mem._bucket_pads}
     assert np.array_equal(bm.senders, bs.senders)
     assert np.array_equal(bm.node_features, bs.node_features)
+
+
+@pytest.mark.parametrize(
+    "knobs",
+    [
+        dict(shuffle=True, num_buckets=2),
+        dict(shuffle=True, num_buckets=2, reshuffle="batch"),
+        dict(shuffle=False),
+        dict(shuffle=True, num_buckets=2, num_shards=2, shard_rank=1),
+    ],
+    ids=["drawn", "frozen", "unshuffled", "shard-1-of-2"],
+)
+def pytest_streamed_and_in_memory_loaders_size_and_choose_alike(tmp_path, knobs, monkeypatch):
+    """The streaming loader sizes its buckets from the GSHD index as the
+    in-memory loader does from its samples (the same bound, the same plan,
+    the same rungs), and with the bound cut to the mean both send the same
+    batches to the worst-case shape, bit-identical, and count them alike; a
+    ``reshard`` sizes a fixed plan anew."""
+    import jax
+
+    from hydragnn_tpu.datasets import shards
+    from hydragnn_tpu.datasets.stream import StreamingGraphLoader
+    from hydragnn_tpu.graphs import collate
+    from hydragnn_tpu.graphs.sample import GraphSample
+    from hydragnn_tpu.preprocess.dataloader import GraphDataLoader
+
+    rng = np.random.default_rng(3)
+    samples = []
+    for n in rng.choice((8, 12, 12, 16, 18, 24, 24, 36), size=768):
+        n = int(n)
+        samples.append(GraphSample(
+            x=rng.standard_normal((n, 4)).astype(np.float32),
+            pos=rng.standard_normal((n, 3)).astype(np.float32),
+            edge_index=rng.integers(0, n, size=(2, 4 * n - int(rng.integers(0, n)))).astype(np.int64),
+            y=rng.standard_normal((1,)).astype(np.float32),
+            y_loc=np.asarray([[0, 1]], np.int64),
+        ))
+    corpus = str(tmp_path / "corpus")
+    shards.write_gshd(corpus, samples, shard_size=96, name="t")
+    common = dict(  # multiples of 64 rows: the tile is wide beside 128 graphs' spread
+        batch_size=128, seed=4, head_types=("graph",), head_dims=(1,),
+        with_positions=False, ladder_step="mult64", **knobs,
+    )
+    fitted = {}
+    for tight in (False, True):
+        if tight:
+            monkeypatch.setattr(collate, "PAD_SIGMAS", 0)
+            monkeypatch.setattr(collate, "PAD_RUNGS", 4096)
+        mem = GraphDataLoader(samples, **common)
+        st = StreamingGraphLoader(corpus, **common)
+        assert mem._bucket_pads == st._bucket_pads and mem._worst_pads == st._worst_pads
+        assert all(f < w for f, w in zip(mem._bucket_pads, mem._worst_pads))
+        fitted[tight] = mem._bucket_pads
+        for epoch in (0, 1):
+            mem.set_epoch(epoch)
+            st.set_epoch(epoch)
+            for bm, bs in zip(mem, st, strict=True):
+                for x, y in zip(jax.tree_util.tree_leaves(bm), jax.tree_util.tree_leaves(bs), strict=True):
+                    assert np.array_equal(np.asarray(x), np.asarray(y))
+        assert mem.padding_stats() == st.padding_stats()
+        fell = mem.padding_stats()["fallback_batches"]
+        # Only a drawn batch can pass its bucket's shape.
+        assert (fell > 0) == (tight and knobs["shuffle"] and "reshuffle" not in knobs)
+    assert fitted[True] != fitted[False]
+    if not knobs["shuffle"]:
+        st.reshard(2, 0)  # other batches: a fixed plan's shape follows them
+        other = GraphDataLoader(samples, num_shards=2, shard_rank=0, **common)
+        assert st._bucket_pads == other._bucket_pads != fitted[True]
 
 
 # -------------------------------------------------------------- prefetch ring
@@ -352,7 +421,7 @@ def pytest_rank_views_disjoint_and_conserved_across_reshard(tmp_path):
         for rank in range(world):
             loader.reshard(world, rank)
             mine = []
-            for _, _, idx in loader._batch_plan():
+            for _, _, idx, _ in loader._batch_plan():
                 mine.extend(np.asarray(idx).tolist())
             per_rank.append(mine)
             flat.extend(mine)

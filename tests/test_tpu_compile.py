@@ -221,25 +221,28 @@ def _combiners(text):
 @pytest.mark.parametrize("f", [256, 1], ids=["hidden_256", "input_layer_1"])
 def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monkeypatch, f):
     """One ``PNAConv``, forward and backward, at the large bucket of the cell
-    ``pna_multihead_h256.train_b512`` (18944 × 401920; a hidden layer's 256
-    columns, the input layer's one) on the CSR route the chip takes: min and
+    ``pna_multihead_h256.train_b512`` (14336 × 268288 since PR 49: a rung of
+    the worst case 18944 × 401920, whole kernel blocks like it; a hidden
+    layer's 256 columns, the input layer's one) on the CSR route the chip takes: min and
     max come from ONE Mosaic kernel in the forward and their cotangents go
     down the rows in ONE in the backward, both under
     ``hydragnn.agg.extrema.pallas_csr``; no ``[E, f]`` row gather is left
     under that scope; and no scatter that combines by minimum or maximum is
-    left anywhere (the one scatter into ``f32[18944,f]`` that stays is the
+    left anywhere (the one scatter into ``f32[14336,f]`` that stays is the
     centered sum of squares of ``std``).
 
-    PR 46: of the two backward scatter-adds into ``f32[18944,256]`` under
-    ``hydragnn.gather`` only the senders' keeps a ``sort`` (``x[receivers]``
-    goes back told its ids are sorted); the input layer's one column goes
-    back down the prefix sums, so one scatter is left there, the senders'."""
+    PR 46: of the two backward scatter-adds into ``f32[14336,256]`` under
+    ``hydragnn.gather`` only the senders' is undeclared (``x[receivers]``
+    goes back told its ids are sorted; at the shape before PR 49 the compiler
+    sorted the senders' indices itself, at this one it does not); the input
+    layer's one column goes back down the prefix sums, so one scatter is left
+    there, the senders'."""
     from hydragnn_tpu.models.convs import PNAConv
     from hydragnn_tpu.ops import segment as seg
     from hydragnn_tpu.telemetry import scopes
 
     monkeypatch.setenv("HYDRAGNN_SEGMENT_SORTED", "1")
-    n, e = 18944, 401920
+    n, e = 14336, 268288
     conv = PNAConv(out_dim=256, deg_avg_log=2.5, deg_avg_lin=14.0, edge_dim=1)
 
     def shaped(shape, dtype=jnp.float32):
@@ -314,9 +317,10 @@ def pytest_pna_conv_at_cell_size_scans_its_extrema_in_one_kernel(one_chip, monke
         assert len(adds) == 1 and narrow in text and wide not in text, adds
         assert windows, "the one-column sum's cumsum is gone"
     assert not _sorts(text, "hydragnn.agg.stats")
-    # what a sort looks like here: the senders' scatter-add has its own (one
-    # column is scattered as rank 1, and no index is sorted for that)
-    assert len(_sorts(text, scopes.GATHER)) == (f > 1), _sorts(text, scopes.GATHER)
+    # At 18944 × 401920 (the shape before PR 49) the senders' undeclared
+    # scatter-add had its indices sorted for it (one ``sort`` under the
+    # gather's scope); at this shape the chip's compiler sorts for none.
+    assert not _sorts(text, scopes.GATHER), _sorts(text, scopes.GATHER)
     back = _backward_scatters(text, scopes.GATHER)
     assert len(back) == (2 if f > 1 else 1), back
     assert all(re.search(rf"= f32\[{n}(,{f})?\]", line) for line in back), back
