@@ -8,8 +8,9 @@ flush's host time goes is read here: the cell's pool, engine, warm-up and 64
 clients exactly as ``graftbench/drivers/serve_closed.py`` builds them (its
 functions, imported), ``--seconds`` of the loop with tracing off, then every
 clock of ``ServeMetrics.latency`` as it moved over the window, as a mean in
-ms (``prepare``, ``queue_wait``, ``e2e`` a request; ``collate``, ``h2d``,
-``device`` a flush; a clock the engine has not: null), the counters beside
+ms (``prepare``, ``queue_wait``, ``e2e`` a request; ``collate``, ``handoff``,
+``h2d``, ``device``, ``d2h``, ``resolve``, ``turnaround`` a flush; a clock the
+engine has not: null), the counters beside
 them and the loop's own rate and percentiles. One JSON line, also written to
 ``chiprun_out/``:
 
@@ -32,7 +33,7 @@ sys.path.insert(0, ROOT)
 
 CELL = "pna_multihead_h256.serve_closed_lattice"
 A_REQUEST = ("prepare", "queue_wait", "e2e")
-A_FLUSH = ("collate", "h2d", "device")
+A_FLUSH = ("collate", "handoff", "h2d", "device", "d2h", "resolve", "turnaround")
 
 
 def clocks(engine) -> dict:

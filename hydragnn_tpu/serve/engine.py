@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import hashlib
 import json
+import logging
 import os
 import pickle
 import queue
@@ -84,8 +85,20 @@ from ..graphs.collate import (
 from ..graphs.packing import PackCaps, first_fit_decreasing
 from ..graphs.sample import GraphSample
 from ..telemetry import graftel as telemetry
+from ..telemetry.stall import RUN_DELAY, StallAccount
 from ..train.pipeline import DeviceFeed
 from .metrics import ServeMetrics
+
+_log = logging.getLogger(__name__)
+
+# A flush's clock marks in the order they are read (``_BatchWork.marks``:
+# ``time.perf_counter()`` readings, one clock, carried from thread to
+# thread); the parts of its cycle follow from them by subtraction
+# (``flush_parts``).
+FLUSH_MARKS = (
+    "first_queued", "taken", "collated", "h2d_start", "h2d_end", "exec_start",
+    "launch_start", "launch_end", "ready", "d2h_end", "resolved",
+)
 
 
 class BackpressureError(RuntimeError):
@@ -176,9 +189,12 @@ def _token_forward(model):
 
 class _Outputs(list):
     """A flush's per-head host arrays; for a routed token family also the
-    experts every node chose (``routing``), which ride along to the demux."""
+    experts every node chose (``routing``), which ride along to the demux;
+    ``clocks`` are ``_execute``'s three readings of its launch
+    (``launch_start``, ``launch_end``, ``ready``: the flush's marks)."""
 
     routing: Optional[np.ndarray] = None
+    clocks: Optional[Dict[str, float]] = None
 
 
 class _Future:
@@ -239,8 +255,9 @@ class _Request:
     is read once, never written and not kept. ``t_submit`` is taken after
     ``_validate`` and BEFORE the preparation, ``t_queued`` after it, so
     ``prepare`` (``t_queued - t_submit``) + ``queue_wait`` (the flush -
-    ``t_queued``) + ``collate`` + ``h2d`` + ``device`` add to ``e2e`` (the
-    resolution - ``t_submit``) with no second counted twice."""
+    ``t_queued``) + the flush's ``collate`` + ``handoff`` + ``h2d`` +
+    ``device`` + ``d2h`` add to ``e2e`` (``_resolve``'s start -
+    ``t_submit``) with no second counted twice (serve/metrics.py)."""
 
     graph: PreparedGraph
     future: _Future
@@ -251,12 +268,37 @@ class _Request:
 
 @dataclass
 class _BatchWork:
-    """One flushed micro-batch between the collation and dispatch stages."""
+    """One flushed micro-batch between the collation and dispatch stages.
+    ``flush_id`` is what the spans of one flush share (as ``request_id`` is
+    what the spans of one request share); ``marks`` are its clock readings
+    (``FLUSH_MARKS``), each written once by the thread that holds the work."""
 
     requests: List[_Request]
     node_start: np.ndarray  # per-request node offsets into the padded batch
     batch: Any  # host GraphBatch
     fallback: bool  # shape came from pow2 fallback, not the ladder
+    flush_id: int = 0
+    marks: Dict[str, float] = field(default_factory=dict)
+
+
+def flush_parts(marks: Dict[str, float]) -> Dict[str, float]:
+    """Seconds of each part of one flush's cycle from its marks: ``handoff``
+    is the two queues of the ``DeviceFeed`` (collated -> the transfer thread,
+    transferred -> the dispatcher), ``lookup`` is ``_executable_for`` and the
+    weights' read, ``launch`` the executable call's return, ``device_wait``
+    the ``block_until_ready`` after it."""
+    m = marks
+    return {
+        "fill": m["taken"] - m["first_queued"],
+        "collate": m["collated"] - m["taken"],
+        "handoff": (m["h2d_start"] - m["collated"]) + (m["exec_start"] - m["h2d_end"]),
+        "h2d": m["h2d_end"] - m["h2d_start"],
+        "lookup": m["launch_start"] - m["exec_start"],
+        "launch": m["launch_end"] - m["launch_start"],
+        "device_wait": m["ready"] - m["launch_end"],
+        "d2h": m["d2h_end"] - m["ready"],
+        "resolve": m["resolved"] - m["d2h_end"],
+    }
 
 
 _SHUTDOWN = object()
@@ -582,6 +624,16 @@ class InferenceEngine:
         # restart the OLD batcher must stop consuming the shared request
         # queue before the new one starts (two live batchers would race).
         self._gen_stop: Optional[threading.Event] = None
+        # Flush identifiers: drawn by the batcher alone, one a collated bin
+        # (``serve/await`` and ``serve/fill`` carry the one drawn next).
+        self._flush_seq = 0  # guarded-by: none(written by the batcher alone, in _collate; the _gen_stop protocol keeps ONE batcher on the queue at a time)
+        # The engine's own seconds between two forwards and the wait for
+        # the forward, against the rung's median (``serve/flush_stall``: the
+        # rule is telemetry/stall.py's).
+        self._flush_account = StallAccount(
+            "flush", "serve/flush_stall", "serve/flush_stalls", "flush_stall",
+            _log, dispatch=("lookup", "launch"), wait=("device_wait",),
+        )
 
         if warmup and self._ladder:
             self.warmup()
@@ -970,28 +1022,44 @@ class InferenceEngine:
         q = self._queue
         while True:
             try:
-                first = q.get(timeout=0.05)
+                first = q.get_nowait()
             except queue.Empty:
-                if self._closing.is_set() or stop.is_set():
-                    return
-                continue
+                # The engine holds no request: ONE span from here to the
+                # first one's arrival, however many polls that takes (the
+                # callers' turn in a closed loop; closed on shutdown too).
+                with telemetry.span("serve/await", flush_id=self._flush_seq + 1):
+                    first = None
+                    while first is None:
+                        try:
+                            first = q.get(timeout=0.05)
+                        except queue.Empty:
+                            if self._closing.is_set() or stop.is_set():
+                                return
             if first is _SHUTDOWN:
                 return
             entries = [first]
             saw_shutdown = False
-            deadline = time.perf_counter() + self.max_delay_ms / 1000.0
-            while len(entries) < self.max_batch_graphs:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    nxt = q.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if nxt is _SHUTDOWN:
-                    saw_shutdown = True
-                    break
-                entries.append(nxt)
+            # From the first request to the flush, by size or by deadline.
+            with telemetry.span("serve/fill", flush_id=self._flush_seq + 1) as fill:
+                deadline = time.perf_counter() + self.max_delay_ms / 1000.0
+                while len(entries) < self.max_batch_graphs:
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        nxt = q.get(timeout=remaining)
+                    except queue.Empty:
+                        break
+                    if nxt is _SHUTDOWN:
+                        saw_shutdown = True
+                        break
+                    entries.append(nxt)
+                fill.attrs.update(
+                    requests=len(entries),
+                    reason="shutdown" if saw_shutdown
+                    else "size" if len(entries) >= self.max_batch_graphs
+                    else "deadline",
+                )
             # ONE ladder snapshot per flush: bin planning and bucket
             # selection below must agree on the rung set, even if
             # swap_ladder publishes a new ladder mid-flush.
@@ -1065,6 +1133,8 @@ class InferenceEngine:
         entries: List[_Request],
         ladder: Optional[List[Tuple[int, int]]] = None,
     ) -> _BatchWork:
+        self._flush_seq += 1
+        flush_id = self._flush_seq
         t0 = time.perf_counter()
         # Queue wait ends at the FLUSH (now), before collation starts — the
         # stage decomposition must not double-count collate seconds.
@@ -1073,7 +1143,8 @@ class InferenceEngine:
         # "pack bin" stage of the correlation trail: this span names every
         # request collated into the bin (docs/OBSERVABILITY.md).
         with telemetry.span(
-            "serve/collate", request_ids=[r.request_id for r in entries]
+            "serve/collate", flush_id=flush_id,
+            request_ids=[r.request_id for r in entries],
         ):
             graphs = [r.graph for r in entries]
             node_start = np.zeros(len(graphs) + 1, np.int64)
@@ -1091,7 +1162,8 @@ class InferenceEngine:
                 edge_dim=self._edge_dim,
                 with_positions=self._with_positions,
             )
-        self.metrics.observe("collate", time.perf_counter() - t0)
+        collated = time.perf_counter()
+        self.metrics.observe("collate", collated - t0)
         self.metrics.record_batch(
             len(entries), self.max_batch_graphs, tot_nodes, n_pad,
             tot_edges, e_pad,
@@ -1103,6 +1175,11 @@ class InferenceEngine:
             node_start=node_start[:-1],
             batch=batch,
             fallback=fallback,
+            flush_id=flush_id,
+            marks={
+                "first_queued": min(r.t_queued for r in entries),
+                "taken": t0, "collated": collated,
+            },
         )
 
     def _transfer(self, work: _BatchWork):
@@ -1110,13 +1187,15 @@ class InferenceEngine:
         batch k+1 commits over DMA while batch k executes."""
         import jax
 
-        t0 = time.perf_counter()
+        t0 = work.marks["h2d_start"] = time.perf_counter()
         with telemetry.span(
-            "serve/h2d", request_ids=[r.request_id for r in work.requests]
+            "serve/h2d", flush_id=work.flush_id,
+            request_ids=[r.request_id for r in work.requests],
         ):
             dev = jax.device_put(work.batch)
             jax.block_until_ready(dev)
-        self.metrics.observe("h2d", time.perf_counter() - t0)
+        done = work.marks["h2d_end"] = time.perf_counter()
+        self.metrics.observe("h2d", done - t0)
         self.metrics.count(
             "h2d_bytes_total",
             sum(
@@ -1193,28 +1272,40 @@ class InferenceEngine:
         against. The weight reference is
         read ONCE here, so the whole batch — and every response demuxed from
         it — belongs to exactly one version even while a swap publishes a
-        new one concurrently."""
+        new one concurrently. The launch is told from the wait for it by
+        two clock readings round the executable's call (``_Outputs.clocks``:
+        the train loop's pair, its ``_run_step``)."""
         import jax
 
         params, bstats, version = self._current_weights()
         exe = self._executable_for(dev_batch, params, bstats)
-        t0 = time.perf_counter()
+        launch_start = time.perf_counter()
         outputs = exe(params, bstats, dev_batch)
+        launch_end = time.perf_counter()
         outputs = jax.block_until_ready(outputs)
-        self.metrics.observe("device", time.perf_counter() - t0)
+        ready = time.perf_counter()
+        self.metrics.observe("device", ready - launch_start)
         routing = None
         if self._token_cfg is not None:
             outputs, routing = outputs
+        # (``serve/device``'s child: it is that span that carries the
+        # flush's identifier.)
         with telemetry.span("serve/d2h"):
             host = _Outputs(np.asarray(o) for o in outputs)
             if routing is not None:
                 host.routing = np.asarray(routing)
+        host.clocks = {
+            "launch_start": launch_start, "launch_end": launch_end, "ready": ready,
+        }
         return host, version
 
     def _dispatch_loop(self) -> None:
         # Explicit context handoff: the dispatcher's device spans parent to
         # this incarnation's pipeline context (docs/OBSERVABILITY.md).
         telemetry.attach(self._pipeline_ctx)
+        # The flush before this one, as ``_book_flush`` left it: a new
+        # pipeline incarnation (a worker restart) starts with none.
+        previous = None
         try:
             # The batcher's shutdown marker ends the feed iteration; every
             # batch flushed before it is still executed and resolved here.
@@ -1225,13 +1316,24 @@ class InferenceEngine:
                 # failures (per-request slicing/denormalization) are
                 # BATCH-scoped: fail this batch's futures, keep serving.
                 with telemetry.span(
-                    "serve/device",
+                    "serve/device", flush_id=work.flush_id,
                     request_ids=[r.request_id for r in work.requests],
-                ):
+                ) as device:
+                    # The dispatcher's turn across the program and its copy
+                    # to the host (None for what the platform does not
+                    # count); the launch and the wait are the flush's marks.
+                    sched0 = telemetry.thread_sched()
+                    work.marks["exec_start"] = time.perf_counter()
                     outputs, version = self._execute(dev_batch)
+                    run_delay_s, nivcsw = telemetry.sched_since(sched0)
+                    device.attrs.update(run_delay_s=run_delay_s, nivcsw=nivcsw)
+                if run_delay_s is not None:
+                    telemetry.counter("host/run_delay_s", run_delay_s)
                 try:
-                    self._resolve(work, outputs, version)
+                    with telemetry.span("serve/resolve", flush_id=work.flush_id):
+                        self._resolve(work, outputs, version)
                 except Exception as e:  # noqa: BLE001 — batch-scoped
+                    previous = None
                     for req in work.requests:
                         self._reject(req, e)
                     self.metrics.count("errors_total")
@@ -1240,8 +1342,85 @@ class InferenceEngine:
                         "resolution_failure",
                         [r.request_id for r in work.requests],
                     )
+                else:
+                    previous = self._book_flush(
+                        work, getattr(outputs, "clocks", None), previous,
+                        run_delay_s,
+                    )
         except BaseException as e:  # noqa: BLE001 — re-raised at callers
             self._fail(e)
+
+    def _book_flush(
+        self, work: _BatchWork, clocks: Optional[Dict[str, float]],
+        previous: Optional[Dict[str, float]], run_delay_s: Optional[float],
+    ) -> Optional[Dict[str, float]]:
+        """The account of one flush, once ``_resolve`` has set its last
+        reply: ONE retroactive ``serve/flush`` record from its first
+        request's entering the queue to now, whose ``marks`` are the offsets
+        in seconds from its start (``t0``: that start on
+        ``time.perf_counter()``, the marks' one clock; the record's ``ts`` is
+        the same start on graftel's wall clock, so ``ts`` + a mark's offset
+        places the mark among the real spans). With the flush before it
+        (``previous``: its marks) come ``await_s`` (this flush's first request
+        entered the queue that long after the last one's replies were set:
+        the engine held no request) and ``turnaround_s`` (``launch_end`` - the previous
+        ``ready``: the host time the chip sees as idle between two forwards);
+        both None for an incarnation's first flush. The same marks feed the
+        stage clocks an operator has without a trace (``handoff``: the two
+        queues and the lookup, everything between the stages that had a clock
+        and the ``device`` clock's start; ``d2h``; ``resolve``;
+        ``turnaround``) and the rung's stall account. Returns the marks; None
+        (no record, no account) where ``_execute`` gave no ``clocks``: a seam
+        that put its own outputs in their place."""
+        if clocks is None:
+            return None
+        marks = work.marks
+        marks.update(clocks, resolved=time.perf_counter())
+        resolved_wall = time.time()  # the same moment on the spans' clock
+        parts = flush_parts(marks)
+        observe = self.metrics.observe
+        observe("handoff", parts["handoff"] + parts["lookup"])
+        observe("d2h", parts["d2h"])
+        observe("resolve", parts["resolve"])
+        rung = f"{work.batch.num_nodes_pad}x{work.batch.num_edges_pad}"
+        waited = turnaround = None
+        if previous is not None:
+            waited = max(marks["first_queued"] - previous["resolved"], 0.0)
+            turnaround = max(marks["launch_end"] - previous["ready"], 0.0)
+            observe("turnaround", turnaround)
+        t0 = marks["first_queued"]
+        telemetry.record_span(
+            "serve/flush", marks["resolved"] - t0, parent=self._pipeline_ctx,
+            end_ts=resolved_wall,
+            flush_id=work.flush_id, rung=rung, requests=len(work.requests),
+            t0=t0, marks={k: round(marks[k] - t0, 6) for k in FLUSH_MARKS},
+            await_s=waited, turnaround_s=turnaround,
+        )
+        if previous is not None:
+            # The account is over the ENGINE's seconds: of the turnaround,
+            # not those in which it had set every reply and waited for its
+            # next batch (the callers' turn, a pause in the traffic, a
+            # deadline: an idle engine is not a stalled one). What is left
+            # is the last flush's copy to the host and demux and this one's
+            # way from the batcher to its launch.
+            idle = max(marks["taken"] - previous["resolved"], 0.0)
+            before = flush_parts(previous)
+            seconds = {
+                **{k: parts[k] for k in (
+                    "collate", "handoff", "h2d", "lookup", "launch", "device_wait",
+                )},
+                "d2h": before["d2h"], "resolve": before["resolve"],
+            }
+            if run_delay_s is not None:
+                seconds[RUN_DELAY] = run_delay_s
+            self._flush_account.book(
+                work.flush_id,
+                max(turnaround - idle, 0.0) + parts["device_wait"], seconds,
+                key=rung, flush_id=work.flush_id, rung=rung,
+                requests=len(work.requests), turnaround_s=round(turnaround, 4),
+                await_s=round(waited, 4), fill_s=round(parts["fill"], 4),
+            )
+        return marks
 
     def _count_routing(self, routing: np.ndarray, real: int) -> None:
         """A flush's routing in the engine's counters (serve/metrics.py) and
@@ -1313,7 +1492,9 @@ class InferenceEngine:
     def _resolve(
         self, work: _BatchWork, outputs: List[np.ndarray], version: str
     ) -> None:
-        now = time.perf_counter()
+        # ``e2e`` ends HERE, where the demux begins, and so does the flush's
+        # ``d2h``: one reading for both, so the stages add to ``e2e``.
+        now = work.marks["d2h_end"] = time.perf_counter()
         batch_had_nonfinite = False
         routing = getattr(outputs, "routing", None)
         if self._token_cfg is not None:

@@ -146,25 +146,46 @@ class LatencyHistogram:
 class ServeMetrics:
     """All counters/histograms of one ``InferenceEngine``.
 
-    Latency stages (per docs/SERVING.md):
+    Latency stages (per docs/SERVING.md), one observation a request:
       prepare    — submit(), on the caller's thread: the request made ready
                    for concatenation (float32 / int32 arrays, the edges
                    stable-sorted by receiver); one observation an ADMITTED
                    request, so its count is ``requests_total``;
       queue_wait — the prepared request entering the queue to its joining a
                    flushed micro-batch;
+      e2e        — submit() to the moment ``_resolve`` begins to set the
+                   flush's replies (the demux after it is ``resolve``);
+    and one observation a FLUSH, from the flush's clock marks (serve/engine.py
+    ``FLUSH_MARKS``; the same readings as its ``serve/flush`` record):
       collate    — host assembly of the micro-batch into its padded arrays;
+      handoff    — what lies between the stages around it and under no other
+                   clock: the two queues of the ``DeviceFeed`` (collated ->
+                   the transfer thread, transferred -> the dispatcher) and
+                   the executable's lookup before the ``device`` clock starts;
       h2d        — blocking device_put wire time (pipeline transfer thread);
-      device     — compiled executable dispatch + readback;
-      e2e        — submit() to future resolution.
+      device     — compiled executable launch + ``block_until_ready``;
+      d2h        — the outputs' copy to the host, to ``_resolve``'s start;
+      resolve    — ``_resolve``: the flush's counters, each reply sliced and
+                   set (on the dispatcher's thread, in series with the next
+                   flush's launch);
+      turnaround — the executable call's return less the PREVIOUS flush's
+                   ``ready``: the host time the chip sees as idle between two
+                   forwards (none for a pipeline incarnation's first flush).
 
     ``t_submit`` sits in ``submit()`` after validation and BEFORE the
     preparation (serve/engine.py ``_Request``): ``e2e`` holds ``prepare``,
-    ``queue_wait`` starts where ``prepare`` ends, and the five stages above
-    ``e2e`` add to it (less the demux) with no second counted twice.
+    ``queue_wait`` starts where ``prepare`` ends, and for every request
+    ``prepare + queue_wait`` + its flush's ``collate + handoff + h2d + device
+    + d2h`` = ``e2e``, each second under exactly one clock
+    (tests/test_telemetry.py holds it to a millisecond). ``resolve`` and
+    ``turnaround`` are outside it: the first follows ``e2e``, the second
+    overlaps the others.
     """
 
-    _STAGES = ("prepare", "queue_wait", "collate", "h2d", "device", "e2e")
+    _STAGES = (
+        "prepare", "queue_wait", "collate", "handoff", "h2d", "device", "d2h",
+        "resolve", "turnaround", "e2e",
+    )
 
     def __init__(self):
         self._lock = tsan.instrument_lock(

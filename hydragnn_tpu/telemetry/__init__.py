@@ -55,18 +55,22 @@ from .graftel import (
     record_span,
     render_prometheus,
     reset,
+    sched_since,
     setup_phase,
     snapshot_records,
     span,
     span_totals,
+    thread_sched,
     timer_credit,
     timer_totals,
 )
+from .stall import StallAccount
 
 __all__ = [
     "SCHEMA_EVENTS",
     "SCHEMA_FLIGHT",
     "Context",
+    "StallAccount",
     "attach",
     "clear_counters",
     "collected_records",
@@ -93,12 +97,14 @@ __all__ = [
     "record_span",
     "render_prometheus",
     "reset",
+    "sched_since",
     "scopes",
     "setup_phase",
     "snapshot_records",
     "span",
     "span_counts",
     "span_totals",
+    "thread_sched",
     "timer_credit",
     "timer_totals",
     "validate_chrome_trace",

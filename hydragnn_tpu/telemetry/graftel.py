@@ -16,7 +16,10 @@ is the hub they all emit into:
   run's host seconds went is readable with collection off and after the ring
   has turned over (``span_totals()``). A span keeps its seconds as
   ``.dur_s``: the one clock pair of its region, which ``FeedStats`` and
-  ``Timer`` are credited from. Same-thread nesting rides a thread-local
+  ``Timer`` are credited from. Its attributes may be set while it is open
+  (``span.attrs[...] = ...``; ``span.elapsed_s()`` reads its clock): that is
+  how a ``device_step`` record carries clock readings taken inside it with no
+  child span. Same-thread nesting rides a thread-local
   context stack; CROSS-thread propagation is explicit — a producer captures
   ``current()`` (or a span's ``.ctx``) and the consumer thread calls
   ``attach(ctx)`` (the DeviceFeed pipeline and the serve dispatcher do this),
@@ -82,6 +85,11 @@ from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
 from ..analysis import tsan
+
+try:  # the per-thread involuntary switches of ``thread_sched``
+    import resource
+except ImportError:  # a platform without it
+    resource = None
 
 SCHEMA_EVENTS = "hydragnn-graftel-events/v1"
 SCHEMA_FLIGHT = "hydragnn-flightrec/v1"
@@ -208,7 +216,8 @@ class span:
     loops, so one small allocation per use. ``dur_s`` holds the region's
     seconds once it has closed (None before), recording on or off: the
     consumer loops credit ``FeedStats`` from it, so a region has ONE clock
-    pair."""
+    pair. ``attrs`` is the record's own dict: what is set on it while the
+    span is open (``span.attrs["wait_s"] = ...``) is in the record."""
 
     __slots__ = (
         "name", "attrs", "ctx", "dur_s", "_parent", "_t0", "_wall0", "_jax",
@@ -260,6 +269,12 @@ class span:
         self._wall0 = time.time()
         self._t0 = time.perf_counter()
         return self
+
+    def elapsed_s(self) -> float:
+        """Seconds since the span opened, on the clock ``dur_s`` is taken
+        from: a reading taken inside the region splits it with no child span
+        (the train loop's dispatch and wait, the engine's launch and wait)."""
+        return time.perf_counter() - self._t0
 
     def __exit__(self, *exc):
         dur = self.dur_s = time.perf_counter() - self._t0
@@ -346,6 +361,60 @@ def event(name: str, request_id: Optional[str] = None, **attrs: Any) -> None:
     if attrs:
         rec["attrs"] = attrs
     _record(rec)
+
+
+# --------------------------------------------------------- the thread's turn
+_SCHEDSTAT = "/proc/thread-self/schedstat"
+# The path once it was found missing: a kernel without the file does not grow
+# it, and the failing ``open`` is not asked again. On a sandboxed kernel (the
+# machine with the benchmark's chips) a system call is slow enough for the
+# thread to lose the GIL while it is out, and to wait for a busy feed or
+# client thread to hand it back: ~0.45 ms a call on the dispatching thread,
+# 1% of the PNA train cell (PERF.md section 6, PR 50).
+_no_schedstat: Optional[str] = None
+
+
+def thread_sched():
+    """``(run_delay_s, involuntary_switches)`` of the CALLING thread as the
+    kernel counts them, both cumulative: the seconds it was runnable and not
+    run (the second field of ``/proc/thread-self/schedstat``) and the times
+    it was switched out against its will (``RUSAGE_THREAD``'s ``ru_nivcsw``).
+    Two readings round a region tell a descheduled host thread from a slow
+    program. A part the platform does not count is None, never 0 (a
+    sandboxed kernel without ``schedstat`` gives ``(None, switches)``); None
+    where it counts neither. One file read: for the two ends of a chunk, an
+    evaluation step, an epoch or a flush, never a request or a batch."""
+    global _no_schedstat
+    delay = None
+    if _SCHEDSTAT != _no_schedstat:
+        try:
+            with open(_SCHEDSTAT) as f:
+                delay = int(f.read().split()[1]) * 1e-9
+        except FileNotFoundError:
+            _no_schedstat = _SCHEDSTAT
+        except (OSError, IndexError, ValueError):
+            pass
+    switches = None
+    try:
+        switches = resource.getrusage(resource.RUSAGE_THREAD).ru_nivcsw
+    except (AttributeError, OSError, ValueError):  # no module, no RUSAGE_THREAD
+        pass
+    if delay is None and switches is None:
+        return None
+    return delay, switches
+
+
+def sched_since(before):
+    """``(run_delay_s, nivcsw)`` the calling thread gained since ``before``
+    (an earlier ``thread_sched()`` of the same thread); a part the platform
+    does not count is None: not counted, which is not a count of nothing."""
+    after = thread_sched()
+    if before is None or after is None:
+        return None, None
+    return tuple(
+        None if a is None or b is None else max(a - b, 0)
+        for a, b in zip(after, before)
+    )
 
 
 # ----------------------------------------------------------- metric registry

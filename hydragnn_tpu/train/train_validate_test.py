@@ -12,9 +12,7 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
-import statistics
 import time
-from collections import deque
 from typing import List, Optional
 
 import jax
@@ -27,6 +25,7 @@ from ..models.base import HydraGNN
 from ..models.token_routed import COUNTERS
 from ..utils.optimizer import ReduceLROnPlateau, get_learning_rate, set_learning_rate
 from ..telemetry import graftel as telemetry
+from ..telemetry.stall import RUN_DELAY, StallAccount
 from ..utils.print_utils import iterate_tqdm, print_distributed
 from ..utils.profile import Profiler
 from ..utils.time_utils import Timer
@@ -108,12 +107,6 @@ def _post_supervisor_heartbeat(epoch: Optional[int] = None) -> None:
         pass  # missed beat == the supervisor's deadline does its job
 
 
-# An epoch this many times the median of the epochs before it (at least
-# EPOCH_STALL_HISTORY of them), and at least EPOCH_STALL_MIN_S longer, is a
-# stall: it says so itself, profiler or none (``EpochAccount``).
-EPOCH_STALL_RATIO = 1.5
-EPOCH_STALL_HISTORY = 3
-EPOCH_STALL_MIN_S = 0.1
 # The leaf phases that partition an ``epoch`` span on the dispatching thread;
 # what they leave of its wall lay under no leaf. ``train_epoch`` and
 # ``evaluate`` contain them.
@@ -123,7 +116,10 @@ EPOCH_LEAVES = (
 )
 _EPOCH_CONTAINERS = ("epoch", "train_epoch", "evaluate")
 NO_LEAF = "(no leaf)"
-EPOCHS_KEPT = 32
+# What a step's two clock readings book beside the span names: the seconds
+# from a ``device_step`` / ``eval_step`` span's opening to ``_dispatch``'s
+# return, and those of the blocking readback after it.
+DISPATCH_S, WAIT_S = "dispatch_s", "wait_s"
 _FEED_END = object()  # what a pull returns from an exhausted feed
 
 # Batches a dispatch on the scan path: ONE constant for every model (no
@@ -148,79 +144,91 @@ def _pad_fallbacks(loader) -> int:
     return stats()["fallback_batches"] if stats is not None else 0
 
 
-class EpochAccount:
+class EpochAccount(StallAccount):
     """The last epochs' ``epoch`` walls, and each span name's seconds in
     them: the difference of graftel's running totals across the epoch, all
-    threads. Kept by the driver, so it lives across ``train_validate_test``
-    calls (a caller may run one epoch a call). An epoch far over the median
-    of those before it emits ``train/epoch_stall`` with where its seconds
-    went, dumps the flight recorder and logs one warning line."""
+    threads, beside the dispatching thread's own three (every step's dispatch
+    and readback wait, and how long the thread was runnable and not run).
+    Kept by the driver, so it lives across ``train_validate_test`` calls (a
+    caller may run one epoch a call). An epoch far over the median of those
+    before it emits ``train/epoch_stall`` with where its seconds went, dumps
+    the flight recorder and logs one warning line (the rule:
+    ``telemetry/stall.py``)."""
 
     def __init__(self):
-        self.walls: deque = deque(maxlen=EPOCHS_KEPT)
-        self.seconds: deque = deque(maxlen=EPOCHS_KEPT)
+        super().__init__(
+            "epoch", "train/epoch_stall", "train/epoch_stalls", "epoch_stall",
+            _log, dispatch=(DISPATCH_S,), wait=(WAIT_S,),
+            containers=_EPOCH_CONTAINERS,
+        )
+        self.open()
 
-    def close(self, epoch: int, wall_s: float, before: dict) -> None:
-        """Book one epoch (``before``: ``telemetry.span_totals()`` as the
-        epoch opened), and report it if it stalled."""
-        after = telemetry.span_totals()
+    def open(self) -> None:
+        """An epoch begins: the totals and the thread's turn as they stand."""
+        self._before = telemetry.span_totals()
+        self._sched0 = telemetry.thread_sched()
+        self._turn = {"run_delay_s": None, "nivcsw": None}
+        self._steps = {DISPATCH_S: 0.0, WAIT_S: 0.0}
+        self._longest = None
+
+    def note_step(self, kind, index, dispatch_s, wait_s, run_delay_s, nivcsw):
+        """One ``device_step`` / ``eval_step`` of the open epoch; the longest
+        is kept whole (a stalled epoch names it)."""
+        self._steps[DISPATCH_S] += dispatch_s
+        self._steps[WAIT_S] += wait_s
+        if self._longest is None or dispatch_s + wait_s > self._longest[0]:
+            self._longest = (
+                dispatch_s + wait_s,
+                dict(
+                    span=kind, index=index, dispatch_s=round(dispatch_s, 4),
+                    wait_s=round(wait_s, 4), nivcsw=nivcsw,
+                    run_delay_s=None if run_delay_s is None else round(run_delay_s, 4),
+                ),
+            )
+
+    def thread_turn(self) -> dict:
+        """``run_delay_s`` / ``nivcsw`` of the calling thread since ``open``:
+        the ``epoch`` span's attributes, read as it closes (None for what the
+        platform does not count)."""
+        delay, switches = telemetry.sched_since(self._sched0)
+        self._turn = {
+            "run_delay_s": None if delay is None else round(delay, 6),
+            "nivcsw": switches,
+        }
+        return self._turn
+
+    def close(self, epoch: int, wall_s: float) -> None:
+        """Book the epoch opened by ``open``, and report it if it stalled."""
+        before = self._before
         seconds = {
             name: total - before.get(name, 0.0)
-            for name, total in after.items()
+            for name, total in telemetry.span_totals().items()
             if total > before.get(name, 0.0)
         }
         seconds[NO_LEAF] = max(
             wall_s - sum(seconds.get(name, 0.0) for name in EPOCH_LEAVES), 0.0
         )
-        if len(self.walls) >= EPOCH_STALL_HISTORY:
-            median = statistics.median(self.walls)
-            if wall_s > EPOCH_STALL_RATIO * median and (
-                wall_s - median >= EPOCH_STALL_MIN_S
-            ):
-                self._report(epoch, wall_s, median, seconds)
-        self.walls.append(wall_s)
-        self.seconds.append(seconds)
-
-    def _report(self, epoch, wall_s, median, seconds) -> None:
-        usual = {
-            name: statistics.median(s.get(name, 0.0) for s in self.seconds)
-            for name in seconds
-        }
-        excess = sorted(
-            (
-                (seconds[name] - usual[name], name)
-                for name in seconds if name not in _EPOCH_CONTAINERS
-            ),
-            reverse=True,
+        seconds.update(self._steps)
+        if self._turn["run_delay_s"] is not None:
+            seconds[RUN_DELAY] = self._turn["run_delay_s"]
+        self.book(
+            epoch, wall_s, seconds, epoch=epoch, nivcsw=self._turn["nivcsw"],
+            dispatch_s=round(self._steps[DISPATCH_S], 4),
+            wait_s=round(self._steps[WAIT_S], 4),
+            longest_step=self._longest[1] if self._longest else None,
         )
+
+    def _extra(self, seconds, usual):
         # The leaves partition the dispatching thread's wall: the one that
         # grew most is where that thread was; the other names say why.
         phase = max(
             EPOCH_LEAVES + (NO_LEAF,),
             key=lambda name: seconds.get(name, 0.0) - usual.get(name, 0.0),
         )
-        stall = dict(
-            epoch=epoch, wall_s=round(wall_s, 4), median_s=round(median, 4),
-            phase=phase, no_leaf_s=round(seconds[NO_LEAF], 4),
-            seconds={k: round(v, 4) for k, v in sorted(seconds.items())},
-            excess=[
-                [name, round(over, 4), round(usual[name], 4)]
-                for over, name in excess[:3]
-            ],
-        )
-        telemetry.counter("train/epoch_stalls")
-        telemetry.event("train/epoch_stall", **stall)
-        telemetry.flight_dump("epoch_stall", extra=stall)
-        _log.warning(
-            "epoch %d took %.3f s against a median of %.3f s; on the "
-            "dispatching thread the excess is in %s; largest excesses over "
-            "their own medians: %s; %.3f s under no leaf phase",
-            epoch, wall_s, median, phase,
-            ", ".join(
-                f"{name} +{over:.3f} s ({seconds[name]:.3f} against {usual[name]:.3f})"
-                for over, name in excess[:3]
-            ),
-            seconds[NO_LEAF],
+        return (
+            dict(phase=phase, no_leaf_s=round(seconds[NO_LEAF], 4)),
+            f"; on the dispatching thread the excess is in {phase} "
+            f"({seconds[NO_LEAF]:.3f} s under no leaf phase)",
         )
 
 
@@ -575,6 +583,49 @@ class TrainingDriver:
             tuple(t.shape for t in batch.targets),
         )
 
+    def _run_step(self, step, metrics, reply: int, program, fn, shape_key, *args):
+        """One compiled program under the OPEN span ``step`` (a
+        ``device_step`` or an ``eval_step``), its dispatch told from its
+        wait: the clock is read once between ``_dispatch``'s return and the
+        blocking readback (``metrics.update`` of ``out[reply]``), and the
+        thread's turn (``telemetry.thread_sched``) at the two ends. The
+        record carries ``dispatch_s`` (span open -> ``_dispatch`` returned:
+        cache lookup, argument handling, launch), ``wait_s`` (the readback;
+        the two add to ``dur_s`` to the clock's grain), ``run_delay_s`` and
+        ``nivcsw`` (None for what the platform does not count); no span opens
+        in here (a child on the dispatching thread would be the leaf the
+        device's programs are booked to). The caller books the closed span
+        (``_book_step``)."""
+        sched0 = telemetry.thread_sched()
+        out = self._dispatch(program, fn, shape_key, *args)
+        dispatch_s = step.elapsed_s()
+        metrics.update(out[reply])
+        run_delay_s, nivcsw = telemetry.sched_since(sched0)
+        step.attrs.update(
+            dispatch_s=dispatch_s, wait_s=step.elapsed_s() - dispatch_s,
+            run_delay_s=run_delay_s, nivcsw=nivcsw,
+        )
+        return out
+
+    def _book_step(self, step) -> None:
+        """A closed ``device_step`` / ``eval_step`` in the running accounts:
+        its seconds to ``FeedStats.step_s`` (the span's one clock pair), its
+        split to the counters ``train/dispatch_s``, ``train/readback_wait_s``
+        and, where the platform counts it, ``host/run_delay_s`` (live with
+        collection off, in Prometheus)
+        and to the open epoch's account. After the span, so that nothing but
+        the program lies between the readback's end and the span's."""
+        self.feed_stats.credit("step_s", step.dur_s)
+        a = step.attrs
+        telemetry.counter("train/dispatch_s", a["dispatch_s"])
+        telemetry.counter("train/readback_wait_s", a["wait_s"])
+        if a["run_delay_s"] is not None:
+            telemetry.counter("host/run_delay_s", a["run_delay_s"])
+        self.epoch_account.note_step(
+            step.name, a.get("index"), a["dispatch_s"], a["wait_s"],
+            a["run_delay_s"], a["nivcsw"],
+        )
+
     # ----------------------------------------------------------- device feed
     def _sharding_tree(self, batch):
         """NamedSharding tree matching the placement the sharded step expects
@@ -776,13 +827,12 @@ class TrainingDriver:
                     self._pulls(iterate_tqdm(batches, self.verbosity))
                 ):
                     with telemetry.span("device_step", index=bi) as step:
-                        self.state, m = self._dispatch(
-                            "train_step", self.train_step,
+                        self.state, m = self._run_step(
+                            step, metrics, 1, "train_step", self.train_step,
                             self._dispatch_shape_key(batch),
                             self.state, batch, self.rng,
                         )
-                        metrics.update(m)
-                    self.feed_stats.credit("step_s", step.dur_s)
+                    self._book_step(step)
                     self._after_update(m)
                     if profiler:
                         profiler.step()
@@ -865,13 +915,12 @@ class TrainingDriver:
                         perm = jnp.asarray(np.concatenate(
                             [rng.permutation(steps), np.arange(steps, slots)]
                         ))
-                        self.state, m = self._dispatch(
-                            "perm_scan", self._perm_scan,
+                        self.state, m = self._run_step(
+                            step, metrics, 1, "perm_scan", self._perm_scan,
                             self._dispatch_shape_key(stacked),
                             self.state, stacked, perm, count, self.rng,
                         )
-                        metrics.update(m)
-                    self.feed_stats.credit("step_s", step.dur_s)
+                    self._book_step(step)
                     self._after_update(m)
             cached["warm"] = True
             self._credit_timers("train")
@@ -960,13 +1009,12 @@ class TrainingDriver:
         the first (timed) epoch's bookkeeping stays O(1) per chunk."""
         stacked, count = payload
         with telemetry.span("device_step", index=index, steps=steps) as step:
-            self.state, m = self._dispatch(
-                "epoch_scan", self.epoch_scan,
+            self.state, m = self._run_step(
+                step, metrics, 1, "epoch_scan", self.epoch_scan,
                 self._dispatch_shape_key(stacked),
                 self.state, stacked, count, self.rng,
             )
-            metrics.update(m)
-        self.feed_stats.credit("step_s", step.dur_s)
+        self._book_step(step)
         self._after_update(m)
         if sink is not None:
             nbytes = self._tree_nbytes(payload)
@@ -1035,13 +1083,12 @@ class TrainingDriver:
                 with telemetry.span(
                     "eval_step", index=ei, cached=True
                 ) as step:
-                    m, outputs = self._dispatch(
-                        "eval_step", self.eval_step,
+                    m, outputs = self._run_step(
+                        step, metrics, 0, "eval_step", self.eval_step,
                         self._dispatch_shape_key(dev_b),
                         self.state, dev_b,
                     )
-                    metrics.update(m)
-                self.feed_stats.credit("step_s", step.dur_s)
+                self._book_step(step)
                 if return_values:
                     consume(host_b, outputs)
             self._credit_timers("eval")
@@ -1066,13 +1113,12 @@ class TrainingDriver:
             try:
                 for ei, (batch, dev_b) in enumerate(self._pulls(batches)):
                     with telemetry.span("eval_step", index=ei) as step:
-                        m, outputs = self._dispatch(
-                            "eval_step", self.eval_step,
+                        m, outputs = self._run_step(
+                            step, metrics, 0, "eval_step", self.eval_step,
                             self._dispatch_shape_key(dev_b),
                             self.state, dev_b,
                         )
-                        metrics.update(m)
-                    self.feed_stats.credit("step_s", step.dur_s)
+                    self._book_step(step)
                     if return_values:
                         consume(batch, outputs)
                     if sink is not None:
@@ -1165,11 +1211,13 @@ def train_validate_test(
         checkpointer = AsyncCheckpointer()
     try:
         for epoch in range(start_epoch, num_epoch):
-            totals0 = telemetry.span_totals()
+            driver.epoch_account.open()
             # The dispatching thread's epoch, partitioned into leaf phases
             # (EPOCH_LEAVES; docs/OBSERVABILITY.md "The host's timeline").
             # The span carries JAX's cumulative trace / lower / compile /
-            # cache-load seconds as they stood when it opened.
+            # cache-load seconds as they stood when it opened and, set as it
+            # closes, how long this thread was runnable and not run over its
+            # whole wall (``run_delay_s``, ``nivcsw``).
             with telemetry.span(
                 "epoch", epoch=epoch, **telemetry.jax_seconds()
             ) as epoch_span:
@@ -1328,7 +1376,8 @@ def train_validate_test(
                             epoch=epoch + 1,
                             stall_s=round(stall, 4),
                         )
-            driver.epoch_account.close(epoch, epoch_span.dur_s, totals0)
+                epoch_span.attrs.update(driver.epoch_account.thread_turn())
+            driver.epoch_account.close(epoch, epoch_span.dur_s)
     finally:
         if checkpointer is not None:
             # Run-exit wait barrier: every queued write lands before the run

@@ -777,3 +777,419 @@ def pytest_injected_stall_in_sixth_epoch_names_its_phase(tmp_path, caplog, stall
     assert doc["trigger"] == "epoch_stall" and doc["extra"]["phase"] == "feed_wait"
     (warning,) = warnings
     assert "feed_wait" in warning.getMessage() and "epoch 5" in warning.getMessage()
+
+
+def _sleep_on_call(monkeypatch, owner, name, seconds):
+    """``owner.name`` sleeps ``seconds(n)`` before its ``n``-th call (from
+    1), where that is more than nothing."""
+    import time
+
+    plain, calls = getattr(owner, name), [0]
+
+    def slow(*args, **kwargs):
+        calls[0] += 1
+        if seconds(calls[0]) > 0.0:
+            time.sleep(seconds(calls[0]))
+        return plain(*args, **kwargs)
+
+    monkeypatch.setattr(owner, name, slow)
+
+
+@pytest.mark.parametrize("stalled", ["dispatch", "wait"])
+def pytest_a_stalled_step_is_told_as_its_dispatch_or_its_wait(
+    tmp_path, caplog, monkeypatch, stalled
+):
+    """The same eight epochs with a sleep planted in the sixth epoch's chunk:
+    inside ``_dispatch`` it is reported as the dispatch, between its return
+    and the readback as the wait. Exactly ONE ``train/epoch_stall``, one dump
+    and one line; its phase is ``device_step``, whose longest step carries
+    the seconds; the verdict names the part and says what the platform's
+    count of the thread's run delay was, or that there is none. (Every
+    epoch's head sleeps 0.3 s, so that a stall is 0.2 s or more over the
+    median and a loaded host's own jitter raises none; the planted one is
+    0.8 s.)"""
+    from hydragnn_tpu.train.train_validate_test import EpochMetrics
+
+    telemetry.configure(run_dir=str(tmp_path))
+    graphs = _dataset(np.random.default_rng(0), count=24)
+    loaders = (
+        _loader(graphs[:16], shuffle=True), _loader(graphs[16:20]),
+        _loader(graphs[20:]),
+    )
+    d = _driver_for(loaders[0])
+    # ``set_epoch`` is called thrice in an epoch's head (one a loader); one
+    # chunk and two evaluation steps an epoch: the sixth epoch's chunk is the
+    # 16th program dispatched, and the 16th readback.
+    _sleep_on_call(monkeypatch, GraphDataLoader, "set_epoch", lambda n: 0.1)
+    owner, name = {
+        "dispatch": (d, "_dispatch"), "wait": (EpochMetrics, "update"),
+    }[stalled]
+    _sleep_on_call(monkeypatch, owner, name, lambda n: 0.8 if n == 16 else 0.0)
+    with caplog.at_level("WARNING", logger="hydragnn_tpu.train.train_validate_test"):
+        _epochs_one_call_each(d, loaders, 8)
+    records = telemetry.snapshot_records()
+    (attrs,) = [r["attrs"] for r in records if r["name"] == "train/epoch_stall"]
+    (dump,) = glob.glob(str(tmp_path / "flightrec_*_epoch_stall.json"))
+    (said,) = [
+        r.getMessage() for r in caplog.records if "against a median" in r.getMessage()
+    ]
+    assert telemetry.counter_value("train/epoch_stalls") == 1
+    counted = telemetry.thread_sched()[0] is not None
+    epochs = [r["attrs"] for r in records if r["name"] == "epoch"]
+    assert len(epochs) == 8 and all(
+        (a["run_delay_s"] is not None) == counted and a["nivcsw"] >= 0
+        for a in epochs
+    )
+    part = {"dispatch": "dispatch_s", "wait": "wait_s"}[stalled]
+    assert attrs["epoch"] == 5 and attrs["phase"] == "device_step"
+    assert attrs["wall_s"] > 0.8 > 1.5 * attrs["median_s"]
+    assert attrs["held_by"] == stalled and attrs["held_s"] >= 0.7
+    assert attrs[part] >= 0.79 and attrs["no_leaf_s"] < 0.05
+    assert attrs["dispatch_s"] + attrs["wait_s"] == pytest.approx(
+        attrs["seconds"]["device_step"] + attrs["seconds"]["eval_step"], abs=5e-3
+    )
+    assert (attrs["run_delay_s"] is not None) == counted
+    longest = attrs["longest_step"]
+    assert longest["span"] == "device_step" and longest[part] >= 0.79
+    assert telemetry.validate_flight_file(dump) == []
+    with open(dump) as f:
+        doc = json.load(f)
+    assert doc["trigger"] == "epoch_stall" and doc["extra"]["held_by"] == stalled
+    assert said.startswith("epoch 5 ") and "device_step" in said
+    assert {"dispatch": "the dispatch was slow", "wait": "its wake-up was slow"}[
+        stalled
+    ] in said
+    assert ("the thread's run delay 0." in said) == counted
+    assert ("run delay is not counted on this host" in said) != counted
+
+
+def pytest_a_step_record_tells_its_dispatch_from_its_wait(monkeypatch, tmp_path):
+    """Every ``device_step`` and ``eval_step`` record carries ``dispatch_s``
+    and ``wait_s``, which add to its ``dur_s``, and the thread's turn
+    (``run_delay_s``, ``nivcsw``); nothing opens under it; the running
+    counters move with collection off. What the platform does not count
+    reads None, never 0: without ``/proc/thread-self/schedstat`` (the
+    sandboxed kernel of the machine with the chips) the run delay alone,
+    without ``resource`` too ``thread_sched`` itself; the counter
+    ``host/run_delay_s`` then stays where it was and nothing else changes."""
+    from hydragnn_tpu.telemetry import graftel
+
+    assert len(telemetry.thread_sched()) == 2
+    loader = _loader(_dataset(np.random.default_rng(0)))
+    d = _driver_for(loader)
+    d.train_epoch(loader)  # the scan path, collection off
+    assert telemetry.collected_records() == []
+    assert telemetry.counter_value("train/dispatch_s") > 0.0
+    waited = telemetry.counter_value("train/readback_wait_s")
+    assert waited > 0.0 and "host/run_delay_s" in telemetry.counters_snapshot("host/")
+    assert "hydragnn_train_readback_wait_s_total" in telemetry.render_prometheus()
+
+    # A kernel's count is read where it is; a file once found missing is
+    # not asked for again (on a sandboxed kernel the failing call is slow
+    # enough to cost the dispatching thread the GIL).
+    there, absent = tmp_path / "schedstat", tmp_path / "no-such-file"
+    there.write_text("7 2500000000 3\n")
+    monkeypatch.setattr(graftel, "_SCHEDSTAT", str(there))
+    assert telemetry.thread_sched()[0] == 2.5
+    monkeypatch.setattr(graftel, "_SCHEDSTAT", str(absent))
+    delay, switches = telemetry.thread_sched()
+    assert delay is None and switches >= 0
+    absent.write_text("7 2500000000 3\n")
+    assert telemetry.thread_sched()[0] is None
+    since = telemetry.sched_since((None, 0))
+    assert since[0] is None and since[1] >= switches
+    delayed = telemetry.counter_value("host/run_delay_s")
+    telemetry.configure(collect=True)
+    d.train_epoch(loader)
+    with monkeypatch.context() as neither:
+        neither.setattr(graftel, "resource", None)
+        assert telemetry.thread_sched() is None
+        assert telemetry.sched_since(None) == (None, None)
+        assert telemetry.sched_since((0.0, 0)) == (None, None)
+        d.evaluate(loader)
+    assert telemetry.counter_value("host/run_delay_s") == delayed
+    spans = [r for r in telemetry.collected_records() if r["kind"] == "span"]
+    steps = [r for r in spans if r["name"] in ("device_step", "eval_step")]
+    assert {r["name"] for r in steps} == {"device_step", "eval_step"}
+    # (A collection's retroactive ``gc`` record was never open there.)
+    parents = {r["parent_id"] for r in spans if not r.get("retro")}
+    apart = []
+    for r in steps:
+        a = r["attrs"]
+        assert a["dispatch_s"] > 0.0 and a["wait_s"] >= 0.0
+        apart.append(r["dur_s"] - (a["dispatch_s"] + a["wait_s"]))
+        assert a["run_delay_s"] is None
+        assert (a["nivcsw"] is None) == (r["name"] == "eval_step")
+        assert r["span_id"] not in parents, "a span opened under a step"
+    # The two add to the span to the clock's grain: four attributes are set
+    # between the last reading and the span's own (on a loaded host the
+    # interpreter may hand the thread's turn away just there, once).
+    assert min(apart) >= 0.0 and sorted(apart)[len(apart) // 2] < 1e-3, apart
+    assert max(apart) < 2e-2, apart
+    assert telemetry.counter_value("train/readback_wait_s") > waited
+
+
+def pytest_the_stall_rule_alone_on_a_wall_and_named_seconds(tmp_path, caplog):
+    """``StallAccount`` with no loop round it: history of three before it
+    judges, 1.5 x the median AND 0.1 s over it, by key, each name's excess
+    over its own median, the verdict's three answers, and what it says of a
+    cycle whose keeper books no run delay (a platform that counts none)."""
+    import logging
+
+    telemetry.configure(run_dir=str(tmp_path))
+    account = telemetry.StallAccount(
+        "cycle", "test/stall", "test/stalls", "test_stall",
+        logging.getLogger("test.stall"), dispatch=("launch",), wait=("wait",),
+    )
+    quiet = {"launch": 0.01, "wait": 0.2, "other": 0.05, "run_delay_s": 0.0}
+    # Two cycles of history are not enough; a third key has none of its own.
+    assert account.book(0, 9.0, quiet) is None and account.book(1, 0.3, quiet) is None
+    assert all(account.book(k, 0.3, quiet) is None for k in range(2, 12))
+    assert account.book(0, 9.0, quiet, key="other rung") is None
+    # 0.42 is 1.4 x the median; 0.04 against tiny cycles is not 0.1 s over.
+    assert account.book(4, 0.42, quiet) is None
+    tiny = telemetry.StallAccount("c", "t/s", "t/n", "t", logging.getLogger("test.stall"))
+    assert [tiny.book(k, w, {}) for k, w in enumerate((0.01, 0.01, 0.01, 0.05))] == [None] * 4
+    with caplog.at_level("WARNING", logger="test.stall"):
+        slow_wait = account.book(5, 0.8, dict(quiet, wait=0.7), note="kept")
+        slow_launch = account.book(6, 0.8, dict(quiet, launch=0.51))
+        not_run = account.book(7, 0.8, dict(quiet, wait=0.7, run_delay_s=0.4))
+        elsewhere = account.book(8, 0.8, dict(quiet, other=0.55))
+    assert [s["held_by"] for s in (slow_wait, slow_launch, not_run, elsewhere)] == [
+        "wait", "dispatch", "host_thread", None,
+    ]
+    assert slow_wait["note"] == "kept" and slow_wait["median_s"] == 0.3
+    assert slow_wait["excess"][0] == ["wait", 0.5, 0.2] and slow_wait["held_s"] == 0.5
+    assert not_run["run_delay_s"] == 0.4 and not_run["held_s"] == 0.4
+    assert elsewhere["excess"][0][0] == "other"
+    said = [r.getMessage() for r in caplog.records]
+    assert len(said) == 4 and "cycle 5 took 0.800 s against a median of 0.300 s" in said[0]
+    assert "its wake-up was slow" in said[0] and "the dispatch was slow" in said[1]
+    assert "runnable and not run" in said[2] and "neither the thread's turn" in said[3]
+    assert "the thread's run delay 0.400 s against 0.000" in said[2]
+    # A platform that counts no run delay: the keeper books none, the event
+    # says None and the line "not counted", never a measured zero.
+    uncounted = {k: v for k, v in quiet.items() if k != "run_delay_s"}
+    blind = telemetry.StallAccount(
+        "cycle", "test/stall", "test/stalls", "test_stall",
+        logging.getLogger("test.stall"), dispatch=("launch",), wait=("wait",),
+    )
+    assert all(blind.book(k, 0.3, uncounted) is None for k in range(3))
+    caplog.clear()
+    with caplog.at_level("WARNING", logger="test.stall"):
+        unseen = blind.book(3, 0.8, dict(uncounted, wait=0.7))
+    assert unseen["run_delay_s"] is None and unseen["held_by"] == "wait"
+    assert "run_delay_s" not in unseen["seconds"]
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert "run delay is not counted on this host" in line and "run delay 0." not in line
+    assert telemetry.counter_value("test/stalls") == 5
+    dumps = glob.glob(str(tmp_path / "flightrec_*_test_stall.json"))
+    assert len(dumps) == 5 and telemetry.validate_flight_file(dumps[0]) == []
+
+
+# ------------------------------------------------- the engine's flush account
+def _flush_records(engine, graphs, flushes, before_each=None):
+    """``flushes`` full flushes of two requests through ``engine``, one
+    after the other (a closed loop of one caller; the engines here flush by
+    size, their deadline is 2 s); the records collected."""
+    for k in range(flushes):
+        if before_each is not None:
+            before_each(k)
+        for fut in [engine.submit(g) for g in graphs[:2]]:
+            fut.result(timeout=60.0)
+    return telemetry.collected_records()
+
+
+def pytest_every_flush_has_one_account_on_one_clock():
+    """One retroactive ``serve/flush`` a flush, whose ``flush_id`` its
+    ``serve/collate`` / ``serve/h2d`` / ``serve/device`` / ``serve/resolve``
+    spans carry (``serve/d2h`` is ``serve/device``'s child); its marks are
+    monotone on one clock and lie where the real spans do; the launch and the
+    wait are told apart ONCE, in the marks (``serve/device`` carries the
+    dispatcher's turn); and the stage clocks add up: ``prepare + queue_wait``
+    of a flush's requests and its ``collate + handoff + h2d + device + d2h``
+    are the requests' ``e2e``."""
+    from hydragnn_tpu.serve.engine import FLUSH_MARKS, flush_parts
+
+    telemetry.configure(collect=True)
+    engine, graphs = _serve_engine(max_batch_graphs=2, max_delay_ms=2000.0)
+    try:
+        recs = _flush_records(engine, graphs, 5)
+        snap = engine.metrics.snapshot()["latency_ms"]
+    finally:
+        engine.close()
+    flushes = [r for r in recs if r["name"] == "serve/flush"]
+    assert [r["attrs"]["flush_id"] for r in flushes] == [1, 2, 3, 4, 5]
+    assert all(r.get("retro") and r["attrs"]["requests"] == 2 for r in flushes)
+    for stage in ("collate", "h2d", "device", "resolve"):
+        ids = [r["attrs"]["flush_id"] for r in recs if r["name"] == "serve/" + stage]
+        assert ids == [1, 2, 3, 4, 5], stage
+    devices = [r for r in recs if r["name"] == "serve/device"]
+    copies = [r for r in recs if r["name"] == "serve/d2h"]
+    assert [r["parent_id"] for r in copies] == [r["span_id"] for r in devices]
+    every_part = 0.0
+    for k, r in enumerate(flushes):
+        a = r["attrs"]
+        offsets = [a["marks"][name] for name in FLUSH_MARKS]
+        assert offsets[0] == 0.0 and offsets == sorted(offsets), offsets
+        assert r["dur_s"] == pytest.approx(offsets[-1], abs=1e-5)
+        assert (a["turnaround_s"] is None) == (a["await_s"] is None) == (k == 0)
+        parts = flush_parts(a["marks"])
+        every_part += 2 * sum(
+            parts[name] for name in
+            ("collate", "handoff", "lookup", "h2d", "launch", "device_wait", "d2h")
+        )
+        if k:
+            before = flushes[k - 1]["attrs"]
+            gap = a["t0"] - before["t0"]
+            assert a["turnaround_s"] == pytest.approx(
+                gap + a["marks"]["launch_end"] - before["marks"]["ready"], abs=1e-5
+            )
+            assert a["await_s"] == pytest.approx(gap - before["marks"]["resolved"], abs=1e-5)
+    counted = telemetry.thread_sched()[0] is not None
+    for r, flush in zip(devices, flushes):
+        a, marks = r["attrs"], flush["attrs"]["marks"]
+        assert set(a) == {"flush_id", "request_ids", "run_delay_s", "nivcsw"}
+        assert (a["run_delay_s"] is not None) == counted and a["nivcsw"] >= 0
+        # The record's ``ts`` is its start on the spans' clock: the marks the
+        # dispatcher read inside ``serve/device`` lie inside that span.
+        opened = r["ts"] - flush["ts"]
+        assert opened - 1e-3 <= marks["exec_start"] <= marks["launch_start"]
+        assert marks["d2h_end"] <= opened + r["dur_s"] + 1e-3
+    # The stage identity, summed over the window's ten requests: the two
+    # per-request clocks, and each flush's clocks once a request of it.
+    assert snap["e2e"]["count"] == 10 and snap["turnaround"]["count"] == 4
+    per_request = snap["prepare"]["sum_s"] + snap["queue_wait"]["sum_s"]
+    per_flush = sum(
+        snap[s]["sum_s"] for s in ("collate", "handoff", "h2d", "device", "d2h")
+    )
+    assert per_request + 2 * per_flush == pytest.approx(snap["e2e"]["sum_s"], abs=1e-3)
+    assert 2 * per_flush == pytest.approx(every_part, abs=1e-3)
+
+
+def pytest_await_is_one_span_across_empty_polls_and_closes_with_the_engine():
+    telemetry.configure(collect=True)
+    engine, graphs = _serve_engine(max_batch_graphs=2, max_delay_ms=2000.0)
+    try:
+        import time
+
+        time.sleep(0.25)  # five empty polls of 50 ms
+        _flush_records(engine, graphs, 1)
+    finally:
+        engine.close()
+    recs = telemetry.collected_records()
+    waits = [r for r in recs if r["name"] == "serve/await"]
+    (fill,) = [r for r in recs if r["name"] == "serve/fill"]
+    # One from the start to the first request, one open when the engine closed.
+    assert [r["attrs"]["flush_id"] for r in waits] == [1, 2]
+    assert waits[0]["dur_s"] >= 0.25
+    assert fill["attrs"] == {"flush_id": 1, "requests": 2, "reason": "size"}
+    assert fill["ts"] >= waits[0]["ts"] + waits[0]["dur_s"] - 1e-3
+
+
+class _Lazy:
+    """An output the host has to wait for: what a slow program looks like
+    to ``jax.block_until_ready``."""
+
+    def __init__(self, value, seconds):
+        self.value, self.seconds = value, seconds
+
+    def block_until_ready(self):
+        import time
+
+        time.sleep(self.seconds)
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.value, dtype=dtype)
+
+
+def _slow_engine(seconds):
+    """An engine of two-request flushes whose every forward makes the host
+    wait ``seconds()`` for its outputs (0.4 s where the tests below plant
+    nothing: a stall is then 0.2 s or more over the median, and a loaded
+    host's own jitter raises none)."""
+    engine, graphs = _serve_engine(max_batch_graphs=2, max_delay_ms=2000.0)
+    plain = engine._executable_for
+
+    def planted(dev_batch, params, bstats):
+        exe = plain(dev_batch, params, bstats)
+        return lambda *args: [
+            _Lazy(out, 0.0 if head else seconds())
+            for head, out in enumerate(exe(*args))
+        ]
+
+    engine._executable_for = planted
+    return engine, graphs
+
+
+def pytest_a_slow_program_raises_one_flush_stall_held_by_the_wait(tmp_path, caplog):
+    """After four flushes of its rung, one whose program takes 0.6 s longer
+    raises ONE ``serve/flush_stall`` (counter, flight dump, one warning line)
+    through the rule the epochs use: it names ``device_wait``, is held by the
+    wait, and says what the dispatcher's run delay was, or that the platform
+    counts none."""
+    telemetry.configure(run_dir=str(tmp_path))
+    wait_s = {"now": 0.4}
+    engine, graphs = _slow_engine(lambda: wait_s["now"])
+    try:
+        with caplog.at_level("WARNING", logger="hydragnn_tpu.serve.engine"):
+            telemetry.configure(collect=True)
+            recs = _flush_records(
+                engine, graphs, 6,
+                lambda k: wait_s.update(now=1.0 if k == 5 else 0.4),
+            )
+    finally:
+        engine.close()
+    (a,) = [r["attrs"] for r in recs if r["name"] == "serve/flush_stall"]
+    assert a["flush_id"] == 6 and a["rung"] in engine.metrics.snapshot()["per_bucket"]
+    assert a["excess"][0][0] == "device_wait" and a["excess"][0][1] >= 0.59
+    assert a["wall_s"] >= 1.0 > 1.5 * a["median_s"]
+    assert a["held_by"] == "wait" and a["held_s"] >= 0.59
+    counted = telemetry.thread_sched()[0] is not None
+    assert (a["run_delay_s"] is not None) == counted
+    assert set(a["seconds"]) - {"run_delay_s"} == {
+        "d2h", "resolve", "collate", "handoff", "h2d", "lookup", "launch",
+        "device_wait",
+    }
+    assert telemetry.counter_value("serve/flush_stalls") == 1
+    (dump,) = glob.glob(str(tmp_path / "flightrec_*_flush_stall.json"))
+    assert telemetry.validate_flight_file(dump) == []
+    (said,) = [r.getMessage() for r in caplog.records]
+    assert said.startswith("flush 6 took") and "against a median" in said
+    assert "device_wait" in said and "its wake-up was slow" in said
+    assert ("run delay is not counted on this host" in said) != counted
+
+
+def pytest_an_idle_engine_is_not_a_stalled_one(tmp_path, caplog):
+    """Open-loop traffic with pauses: a client 0.5 s late (``await``) and a
+    flush that waits 0.3 s for its second request (``fill``) are the
+    callers' seconds, not the engine's. The records say how long each was;
+    no ``serve/flush_stall`` is raised, counted, dumped or logged."""
+    import time
+
+    telemetry.configure(run_dir=str(tmp_path), collect=True)
+    engine, graphs = _slow_engine(lambda: 0.4)
+    try:
+        with caplog.at_level("WARNING", logger="hydragnn_tpu.serve.engine"):
+            _flush_records(
+                engine, graphs, 5, lambda k: time.sleep(0.5 if k == 4 else 0.0)
+            )
+            first = engine.submit(graphs[0])
+            time.sleep(0.3)
+            for fut in (first, engine.submit(graphs[1])):
+                fut.result(timeout=60.0)
+            recs = _flush_records(engine, graphs, 1)
+    finally:
+        engine.close()
+    flushes = {
+        r["attrs"]["flush_id"]: r["attrs"] for r in recs if r["name"] == "serve/flush"
+    }
+    assert sorted(flushes) == [1, 2, 3, 4, 5, 6, 7]
+    assert flushes[5]["await_s"] >= 0.49 and flushes[5]["turnaround_s"] >= 0.49
+    assert flushes[6]["marks"]["taken"] >= 0.29 and flushes[6]["turnaround_s"] >= 0.29
+    assert all(flushes[k]["turnaround_s"] < 0.2 for k in (2, 3, 4, 7))
+    assert [r for r in recs if r["name"] == "serve/flush_stall"] == []
+    assert telemetry.counter_value("serve/flush_stalls") == 0
+    assert glob.glob(str(tmp_path / "flightrec_*_flush_stall.json")) == []
+    assert [r.getMessage() for r in caplog.records] == []
