@@ -1070,7 +1070,9 @@ class TrainingDriver:
         # so caching changes nothing semantically) keep their batches device-
         # resident after the first evaluate() — the per-epoch validation pass
         # then skips collation and host->device transfer entirely. Host
-        # copies ride along for consume()'s masks/targets.
+        # copies ride along for consume()'s masks/targets. With or without a
+        # mesh: what is held on one is the feed's own pair, the host-local
+        # stacked group of _device_groups and its sharded global array.
         gen = getattr(loader, "generation", None)
         cached = self._eval_cache.get(id(loader))
         if cached is not None and cached.get("generation") != gen:
@@ -1092,10 +1094,10 @@ class TrainingDriver:
                 if return_values:
                     consume(host_b, outputs)
             self._credit_timers("eval")
+            steps = cached_steps = len(cached["batches"])
         else:
             cacheable = (
-                self.mesh is None
-                and getattr(loader, "shuffle", True) is False
+                getattr(loader, "shuffle", True) is False
                 and id(loader) not in self._eval_cache
             )
             sink: Optional[dict] = {"items": [], "bytes": 0} if cacheable else None
@@ -1104,14 +1106,17 @@ class TrainingDriver:
             # test() lists) with its device copy — which on a mesh is the
             # same GLOBAL [D_global, ...] lift train_epoch performs. The
             # cache sink reuses that same device copy: one transfer per
-            # batch, cache build included.
+            # batch (per group on a mesh), cache build included. The budget
+            # counts the host copy's bytes: this process's local rows.
             batches = DeviceFeed(
                 self._device_groups(loader) if self.mesh is not None else iter(loader),
                 transfer=lambda b: (b, self._put_timed(b)),
                 ctx=ctx,
             )
+            steps = cached_steps = 0
             try:
                 for ei, (batch, dev_b) in enumerate(self._pulls(batches)):
+                    steps += 1
                     with telemetry.span("eval_step", index=ei) as step:
                         m, outputs = self._run_step(
                             step, metrics, 0, "eval_step", self.eval_step,
@@ -1137,7 +1142,14 @@ class TrainingDriver:
                     "loader": loader,
                     "generation": gen,
                     "batches": sink["items"] if sink is not None else None,
+                    "bytes": sink["bytes"] if sink is not None else 0,
                 }
+        # The pass's steps, those of them served from the cache (all or
+        # none), and what every evaluation loader's cache holds by now.
+        telemetry.gauge("eval/steps_per_pass", steps)
+        telemetry.gauge("eval/cached_steps_per_pass", cached_steps)
+        held = sum(c["bytes"] for c in self._eval_cache.values())
+        telemetry.gauge("eval/cache_mb", round(held / (1 << 20), 4))
 
         loss, rmses = metrics.averages()
         if return_values:

@@ -45,7 +45,14 @@ def _dataset(rng, count=26, lo=4, hi=12):
     return graphs
 
 
-def _driver_for(loader):
+def _mesh4():
+    """A data mesh over four of the forced host devices (tests/conftest.py)."""
+    from hydragnn_tpu.parallel.distributed import make_mesh
+
+    return make_mesh(data_axis=4, graph_axis=1, devices=jax.devices()[:4])
+
+
+def _driver_for(loader, layout="one_device"):
     """Deterministic driver: create_model/init_model_variables are seeded, so
     two calls with the same loader yield bit-identical initial states."""
     model = create_model("SAGE", 1, 8, (1,), ("graph",), HEADS, [1.0], 2)
@@ -53,7 +60,13 @@ def _driver_for(loader):
     variables = init_model_variables(model, example)
     opt = select_optimizer("AdamW", 5e-3)
     state = create_train_state(model, variables, opt)
-    return TrainingDriver(model, opt, state)
+    return TrainingDriver(
+        model, opt, state, mesh=_mesh4() if layout == "mesh4" else None
+    )
+
+
+# An evaluation loader's cache does not ask whether the driver has a mesh.
+LAYOUTS = pytest.mark.parametrize("layout", ["one_device", "mesh4"])
 
 
 class _ActiveProf:
@@ -468,13 +481,12 @@ def pytest_scan_cache_generation_invalidation(monkeypatch):
     assert driver._scan_cache[id(loader)]["generation"] == loader.generation
 
 
-def pytest_eval_cache_generation_invalidation(monkeypatch):
+@LAYOUTS
+def pytest_eval_cache_generation_invalidation(monkeypatch, layout):
     ds = _dataset(np.random.default_rng(5))
-    train = GraphDataLoader(ds, batch_size=4, shuffle=True)
-    train.set_head_spec(("graph",), (1,))
     ev = GraphDataLoader(ds, batch_size=4, shuffle=False)
     ev.set_head_spec(("graph",), (1,))
-    driver = _driver_for(train)
+    driver = _driver_for(ev, layout)
 
     calls = {"n": 0}
     real_iter = GraphDataLoader.__iter__
@@ -493,7 +505,9 @@ def pytest_eval_cache_generation_invalidation(monkeypatch):
     loss_c, _ = driver.evaluate(ev)
     assert calls["n"] == 2, "stale eval cache replayed after set_head_spec"
     assert driver._eval_cache[id(ev)]["generation"] == ev.generation
-    assert np.isfinite(loss_c)
+    assert loss_c == loss_a  # the same spec again: the same batches rebuilt
+    driver.evaluate(ev)
+    assert calls["n"] == 2  # and held again
 
 
 def pytest_driver_cache_skips_fixed_order_batch_loader():
@@ -557,14 +571,16 @@ def pytest_cache_build_single_transfer_per_chunk(monkeypatch, scan_chunk):
     assert count["n"] == 0, "steady cached epoch still transferred batches"
 
 
-def pytest_eval_cache_build_single_transfer(monkeypatch):
+@LAYOUTS
+def pytest_eval_cache_build_single_transfer(monkeypatch, layout):
+    """One ``device_put`` a step while the cache is built (on the mesh a step
+    is a stacked group of four batches, the last one padded), none after."""
     ds = _dataset(np.random.default_rng(8))
-    train = GraphDataLoader(ds, batch_size=4, shuffle=True)
-    train.set_head_spec(("graph",), (1,))
     ev = GraphDataLoader(ds, batch_size=4, shuffle=False)
     ev.set_head_spec(("graph",), (1,))
-    driver = _driver_for(train)
-    n_batches = len(ev)
+    driver = _driver_for(ev, layout)
+    n_steps = len(ev) if layout == "one_device" else -(-len(ev) // 4)
+    assert len(ev) % 4, "the mesh case wants a padded last group"
 
     count = {"n": 0}
     real_put = jax.device_put
@@ -576,7 +592,8 @@ def pytest_eval_cache_build_single_transfer(monkeypatch):
 
     monkeypatch.setattr(jax, "device_put", counting_put)
     driver.evaluate(ev)
-    assert count["n"] == n_batches
+    assert count["n"] == n_steps
+    assert len(driver._eval_cache[id(ev)]["batches"]) == n_steps
     count["n"] = 0
     driver.evaluate(ev)  # cached replay: zero transfers
     assert count["n"] == 0
@@ -613,11 +630,7 @@ def _timeline_run(path):
         return ld
 
     train = loader(ds[:48], True)
-    mesh = None
-    if path == "mesh4":
-        from hydragnn_tpu.parallel.distributed import make_mesh
-
-        mesh = make_mesh(data_axis=4, graph_axis=1, devices=jax.devices()[:4])
+    mesh = _mesh4() if path == "mesh4" else None
     model = create_model("SAGE", 1, 8, (1,), ("graph",), HEADS, [1.0], 2)
     variables = init_model_variables(model, next(iter(train)))
     opt = select_optimizer("AdamW", 5e-3)
@@ -738,6 +751,33 @@ def pytest_feed_wait_is_credited_on_every_path(timeline):
             if r["name"] == "feed_drain" and r["parent_id"] == last["span_id"]
         ]
         assert len(drains) == 1
+
+
+def pytest_evaluations_after_the_first_epoch_read_the_cache(timeline):
+    """Validation and test loaders never shuffle, so from the second epoch on
+    an ``evaluate`` span holds ``eval_step`` children alone, each marked
+    ``cached``: no feed is started (no ``feed_wait``, ``feed_drain``, ``collate``
+    or ``h2d`` hangs off it), on one device and on the mesh alike."""
+    path, _, spans = timeline
+    evaluations = [r for r in spans if r["name"] == "evaluate"]
+    assert len(evaluations) == 8
+    for group, cached in ((evaluations[:2], False), (evaluations[2:], True)):
+        ids = {r["span_id"] for r in group}
+        names = [
+            r["name"] for r in spans
+            if r.get("parent_id") in ids and not r.get("retro")
+        ]
+        steps = [
+            r for r in spans
+            if r["name"] == "eval_step" and r.get("parent_id") in ids
+        ]
+        assert steps, path
+        assert all(bool(r["attrs"].get("cached")) is cached for r in steps), path
+        feed = set(names) - {"eval_step"}
+        if cached:
+            assert feed == set(), (path, feed)
+        else:
+            assert {"feed_wait", "feed_drain", "h2d"} <= feed, (path, feed)
 
 
 def pytest_scan_path_feed_wait_equals_its_spans():

@@ -5,11 +5,15 @@ epochs). Opt-in because it mildly changes SGD semantics vs the reference's
 DistributedSampler membership reshuffle (default reshuffle="sample",
 /root/reference/hydragnn/preprocess/load_data.py:57-70)."""
 
+import jax
 import numpy as np
+import pytest
 
+from hydragnn_tpu import telemetry
 from hydragnn_tpu.graphs import GraphSample
 from hydragnn_tpu.graphs.collate import GraphArena
 from hydragnn_tpu.models import create_model, init_model_variables
+from hydragnn_tpu.parallel.distributed import make_mesh
 from hydragnn_tpu.preprocess.dataloader import GraphDataLoader
 from hydragnn_tpu.train.train_validate_test import TrainingDriver
 from hydragnn_tpu.train.trainer import create_train_state
@@ -100,13 +104,26 @@ def pytest_invalid_reshuffle_rejected():
         GraphDataLoader([], batch_size=4, reshuffle="epoch")
 
 
-def _driver_for(loader):
+def _driver_for(loader, layout="one_device"):
     model = create_model("SAGE", 1, 8, (1,), ("graph",), HEADS, [1.0], 2)
     example = next(iter(loader))
     variables = init_model_variables(model, example)
     opt = select_optimizer("AdamW", 5e-3)
     state = create_train_state(model, variables, opt)
-    return TrainingDriver(model, opt, state)
+    mesh = None
+    if layout == "mesh4":  # four of the forced host devices (tests/conftest.py)
+        mesh = make_mesh(data_axis=4, graph_axis=1, devices=jax.devices()[:4])
+    return TrainingDriver(model, opt, state, mesh=mesh)
+
+
+# An evaluation loader's cache does not ask whether the driver has a mesh.
+LAYOUTS = pytest.mark.parametrize("layout", ["one_device", "mesh4"])
+
+
+def _eval_loader(ds, batch_size=5):
+    ev = GraphDataLoader(ds, batch_size=batch_size, shuffle=False)
+    ev.set_head_spec(("graph",), (1,))
+    return ev
 
 
 def pytest_driver_device_cache_replays_without_loader(monkeypatch):
@@ -161,30 +178,81 @@ def pytest_driver_cache_respects_budget(monkeypatch):
     assert np.isfinite(l0) and np.isfinite(l1)
 
 
-def pytest_eval_cache_identical_metrics_single_pass(monkeypatch):
-    rng = np.random.default_rng(5)
-    ds = _dataset(rng)
-    train = GraphDataLoader(ds, batch_size=5, shuffle=True)
-    train.set_head_spec(("graph",), (1,))
-    ev = GraphDataLoader(ds, batch_size=5, shuffle=False)
-    ev.set_head_spec(("graph",), (1,))
-    driver = _driver_for(train)
+@LAYOUTS
+def pytest_eval_cache_identical_metrics_single_pass(monkeypatch, layout):
+    """The second ``evaluate()`` reads the cache alone (no loader, no ``h2d``
+    span) and every pass returns the first one's numbers and rows, which are
+    an uncached driver's (``HYDRAGNN_DEVICE_CACHE_MB=0``), bit for bit. 30
+    graphs in batches of 5 are six batches: on the mesh a group of four and a
+    group of two padded with two empty batches."""
+    ds = _dataset(np.random.default_rng(5))
+    ev = _eval_loader(ds)
+    driver = _driver_for(ev, layout)
 
-    loss_a, rmses_a = driver.evaluate(ev)
-    assert driver._eval_cache.get(id(ev)), "eval cache not built"
+    telemetry.reset()
+    try:
+        loss_a, rmses_a = driver.evaluate(ev)
+        assert driver._eval_cache[id(ev)]["batches"], "eval cache not built"
+        steps = 6 if layout == "one_device" else 2
+        gauges = telemetry.gauges_snapshot()
+        assert gauges["eval/steps_per_pass"] == steps
+        assert gauges["eval/cached_steps_per_pass"] == 0
+        assert gauges["eval/cache_mb"] > 0
 
-    def boom(self):
-        raise AssertionError("eval loader iterated despite device cache")
+        def boom(self):
+            raise AssertionError("eval loader iterated despite device cache")
 
-    monkeypatch.setattr(GraphDataLoader, "__iter__", boom)
-    loss_b, rmses_b = driver.evaluate(ev)
-    assert loss_a == loss_b and rmses_a == rmses_b
+        monkeypatch.setattr(GraphDataLoader, "__iter__", boom)
+        h2d = telemetry.counter_value("span_n/h2d")
+        assert h2d == steps  # one transfer a step, the cache's build included
+        loss_b, rmses_b = driver.evaluate(ev)
+        assert loss_a == loss_b and rmses_a == rmses_b
+        assert telemetry.counter_value("span_n/h2d") == h2d
+        gauges = telemetry.gauges_snapshot()
+        assert gauges["eval/cached_steps_per_pass"] == steps
+        assert gauges["eval/steps_per_pass"] == steps
 
-    # return_values path rides the cached host copies.
-    monkeypatch.undo()
-    loss_c, rmses_c, tv, pv = driver.evaluate(ev, return_values=True)
-    assert loss_c == loss_a
+        # return_values path rides the cached host copies.
+        loss_c, rmses_c, tv, pv = driver.evaluate(ev, return_values=True)
+    finally:
+        telemetry.reset()
+    assert loss_c == loss_a and rmses_c == rmses_a
     assert tv[0].shape == pv[0].shape and tv[0].shape[0] == len(ds)
+
+    monkeypatch.undo()  # the uncached driver reads the loader, every pass
+    monkeypatch.setenv("HYDRAGNN_DEVICE_CACHE_MB", "0")
+    plain = _driver_for(ev, layout)
+    ev2 = _eval_loader(ds)
+    for _ in range(2):
+        loss_p, rmses_p, tv_p, pv_p = plain.evaluate(ev2, return_values=True)
+        assert plain._eval_cache[id(ev2)]["batches"] is None
+        assert loss_p == loss_a and rmses_p == rmses_a
+        np.testing.assert_array_equal(tv_p[0], tv[0])
+        np.testing.assert_array_equal(pv_p[0], pv[0])
+
+
+@LAYOUTS
+def pytest_eval_cache_respects_budget(monkeypatch, layout):
+    """``pytest_driver_cache_respects_budget``'s evaluation twin: a budget of
+    0 pins the verdict (nothing held, the loader's reference kept) and the
+    plain path still evaluates, pass after pass, through the loader."""
+    monkeypatch.setenv("HYDRAGNN_DEVICE_CACHE_MB", "0")
+    ds = _dataset(np.random.default_rng(4))
+    ev = _eval_loader(ds)
+    driver = _driver_for(ev, layout)
+    telemetry.reset()
+    try:
+        l0 = driver.evaluate(ev)[0]
+        verdict = driver._eval_cache[id(ev)]
+        assert verdict["batches"] is None and verdict["loader"] is ev
+        l1 = driver.evaluate(ev)[0]
+        assert driver._eval_cache[id(ev)] is verdict
+        gauges = telemetry.gauges_snapshot()
+    finally:
+        telemetry.reset()
+    assert np.isfinite(l0) and l0 == l1
+    assert gauges["eval/cached_steps_per_pass"] == 0
+    assert gauges["eval/steps_per_pass"] > 0 and gauges["eval/cache_mb"] == 0
 
 
 def pytest_config_completion_defaults_reshuffle():
